@@ -5,13 +5,16 @@ declared as mesh axes, and XLA inserts every collective.
 This is the tier the eager examples point at for performance; it has
 no reference analog (the reference is process-per-rank only, this is
 the TPU-first redesign). Shows: mesh construction (dp/fsdp/tp/sp/pp),
-``make_train_step`` (scan-over-layers Llama-family model, remat,
-sharded optimizer state) or the pipelined factories
-(``--pp N --pp-schedule gpipe|1f1b``), synthetic token stream, loss
-logging, and a final-checkpoint save via ``orbax`` when available.
+``make_train_step`` (scan-over-layers Llama-family model, remat) or
+the pipelined factories (``--pp N --pp-schedule gpipe|1f1b``),
+synthetic token stream, loss logging, and a final-checkpoint save via
+``orbax`` when available. The factory pins the layout of params and
+optimizer state (``param_specs``) on both the init and the step, so the
+state is born sharded however the init is called.
 
-Run (any device count; axes auto-fold to what exists):
-  python examples/lm_pretrain.py --steps 20 --dp 2 --tp 2
+Run (the axis sizes must multiply to the device count; ``--dp``
+defaults to "all the rest"):
+  python examples/lm_pretrain.py --steps 20 --tp 2
 CPU smoke (8 virtual devices):
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/lm_pretrain.py --platform cpu --steps 2 --tiny
@@ -66,10 +69,12 @@ def main():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from horovod_tpu.common.compile_cache import use_compile_cache
     from horovod_tpu.models import TransformerConfig, make_train_step
     from horovod_tpu.parallel import (build_mesh, make_pp_train_step,
                                       make_pp_train_step_1f1b)
 
+    use_compile_cache()
     if args.ep != 1 and not args.moe:
         ap.error("--ep needs --moe (the axis only shards experts)")
     mesh = build_mesh(dp=args.dp, fsdp=args.fsdp, tp=args.tp, sp=args.sp,

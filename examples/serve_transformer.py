@@ -51,8 +51,11 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from horovod_tpu.common.compile_cache import use_compile_cache
     from horovod_tpu.models import TransformerConfig, init_transformer
     from horovod_tpu.serve import ServeConfig, ServeEngine, make_trace
+
+    use_compile_cache()
 
     cfg = (TransformerConfig.tiny(dtype=jnp.float32, remat=False)
            if args.tiny else

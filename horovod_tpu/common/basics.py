@@ -178,26 +178,21 @@ def load_library() -> ctypes.CDLL:
                 f"HOROVOD_NATIVE_LIB={override} does not exist; build it "
                 "first (e.g. make -C native SAN=tsan)")
         return _declare_abi(ctypes.CDLL(override), override)
-    path = next((p for p in _LIB_CANDIDATES if os.path.exists(p)), None)
-    # Always (re)run make when the source tree is present: make is a
-    # no-op when the .so is current, and this keeps stale binaries from
-    # silently shadowing native source edits.
+    # With the source tree present the core is built from it or not at
+    # all: make is a no-op when the .so is current, and a failed build
+    # raises — a .so left over from another state of the tree (the chip
+    # tool copies the disk, stale binaries included) must never stand
+    # in for sources that no longer compile.
     if os.path.exists(os.path.join(_REPO_ROOT, "native", "Makefile")):
         try:
             _build_native()
-            path = next(p for p in _LIB_CANDIDATES if os.path.exists(p))
-        except Exception as e:
-            if path is None:
-                raise
-            # A stale prebuilt .so may predate ABI changes in this source
-            # tree — fall back only after the version check below
-            # confirms compatibility, and never silently.
-            import warnings
-            warnings.warn(
-                f"horovod_tpu: rebuilding the native core failed ({e}); "
-                f"falling back to existing {path}, which may be stale",
-                RuntimeWarning)
-    elif path is None:
+        except subprocess.CalledProcessError as e:
+            raise OSError(
+                "horovod_tpu: building the native core failed "
+                f"(make -C native, rc={e.returncode}):\n"
+                + e.stderr.decode(errors="replace")[-2000:]) from e
+    path = next((p for p in _LIB_CANDIDATES if os.path.exists(p)), None)
+    if path is None:
         raise OSError("horovod_tpu native core not found and no source tree "
                       "to build it from")
     return _declare_abi(ctypes.CDLL(path), path)

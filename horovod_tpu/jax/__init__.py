@@ -49,6 +49,23 @@ from horovod_tpu.functions import (  # noqa: F401
 AxisName = Union[str, tuple]
 
 
+def _pvary(tree: Any, axis_name: Optional[AxisName]) -> Any:
+    """Promote every leaf to device-varying over ``axis_name`` (no-op
+    leaf-wise where already varying, outside a manual-axes trace, under
+    ``check_vma=False``, and on the eager tier's ``axis_name=None``)."""
+    import jax
+    from jax import lax
+    if axis_name is None:
+        return tree
+    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+
+    def one(a):
+        vma = jax.typeof(a).vma
+        return lax.pcast(a, tuple(ax for ax in axes if ax not in vma),
+                         to="varying")
+    return jax.tree.map(one, tree)
+
+
 def allreduce_gradients(grads: Any, *, axis_name: Optional[AxisName] = None,
                         op: ReduceOp = Average,
                         compression=Compression.none,
@@ -62,9 +79,13 @@ def allreduce_gradients(grads: Any, *, axis_name: Optional[AxisName] = None,
     axes typing, autodiff cotangents of *replicated* parameters are
     already globally correct (the mean-vs-sum choice lives in the loss
     — see :func:`distributed_value_and_grad`), and an explicit psum on
-    them would double-count. With ``compression``, reduced leaves ride
-    the quantized reduce-scatter + all-gather of
-    :mod:`horovod_tpu.ops.quantized` (narrow bytes on both hops).
+    them would double-count. Under ``shard_map(check_vma=False)``
+    nothing carries that type and autodiff leaves cotangents
+    rank-local, so every leaf is reduced. With ``compression``,
+    reduced leaves ride the quantized reduce-scatter + all-gather of
+    :mod:`horovod_tpu.ops.quantized` (narrow bytes on both hops); its
+    results are typed varying, so the enclosing ``shard_map`` needs
+    ``check_vma=False`` to return them replicated.
     Eager (no ``axis_name``): one grouped allreduce over all leaves via
     the native-negotiated runtime, so fusion batches small gradients;
     ``compression`` maps to the framework cast (bf16/fp16) or the
@@ -89,18 +110,6 @@ def allreduce_gradients(grads: Any, *, axis_name: Optional[AxisName] = None,
         from jax import lax
         axes = ({axis_name} if isinstance(axis_name, str)
                 else set(axis_name))
-
-        def leaf_varies(g):
-            # Legacy jax (no VMA types): every shard_map value is
-            # implicitly varying, so always reduce. Keyed on the same
-            # HAS_VMA flag as distributed_value_and_grad — the two
-            # sites must agree or gradients silently go unreduced.
-            from horovod_tpu.common import jax_compat
-            vma = (getattr(jax.typeof(g), "vma", frozenset())
-                   if jax_compat.HAS_VMA and hasattr(jax, "typeof")
-                   else axes)
-            return bool(axes & set(vma))
-
         if codec == "int8":
             # int8 has no cast form to fall back on: anything the
             # quantized path can't express is an error up front.
@@ -115,6 +124,15 @@ def allreduce_gradients(grads: Any, *, axis_name: Optional[AxisName] = None,
                     "in-jit compression=int8 reduces over a single "
                     f"named axis; got {axis_name!r} — reshape the mesh "
                     "or reduce axis-by-axis")
+
+        # axis_index is varying by construction: an empty vma on it
+        # means the enclosing shard_map runs with check_vma=False.
+        vma_tracked = all(
+            ax in jax.typeof(lax.axis_index(ax)).vma for ax in axes)
+
+        def leaf_varies(g):
+            return not vma_tracked or bool(axes & jax.typeof(g).vma)
+
         if (codec != "none" and op in (Average, Sum)
                 and isinstance(axis_name, str)):
             from horovod_tpu.ops.quantized import quantized_allreduce
@@ -241,12 +259,8 @@ def distributed_optimizer(optimizer, *,
     def init_ef(params):
         import jax
         import jax.numpy as jnp
-        from horovod_tpu.common.jax_compat import pcast_varying
-        axes = ((axis_name,) if isinstance(axis_name, str)
-                else tuple(axis_name))
-        return jax.tree.map(
-            lambda p: pcast_varying(jnp.zeros(p.shape, jnp.float32), axes),
-            params)
+        return _pvary(jax.tree.map(
+            lambda p: jnp.zeros(p.shape, jnp.float32), params), axis_name)
 
     if backward_passes_per_step == 1:
         if use_ef:
@@ -276,37 +290,13 @@ def distributed_optimizer(optimizer, *,
 
     n = backward_passes_per_step
 
-    def _pvary_missing(t):
-        """Promote every leaf to device-varying over ``axis_name``
-        (no-op leaf-wise where already varying, or outside a manual-
-        axes trace). Keeps the accumulator's VMA type STABLE between
-        init and update so the canonical lax.scan-over-microbatches
-        carry typechecks."""
-        if axis_name is None:
-            return t
-        from jax import lax
-        axes = ((axis_name,) if isinstance(axis_name, str)
-                else tuple(axis_name))
-
-        def one(a):
-            if not hasattr(jax, "typeof"):
-                return a  # legacy jax: no VMA types to stabilise
-            vma = getattr(jax.typeof(a), "vma", None)
-            if vma is None:
-                return a
-            missing = tuple(ax for ax in axes if ax not in vma)
-            if not missing:
-                return a
-            try:
-                return lax.pcast(a, missing, to="varying")
-            except Exception:  # outside shard_map: axis not in scope
-                return a
-        return jax.tree.map(one, t)
-
     def init_acc(params):
         state = {"inner": optimizer.init(params),
-                 "acc": _pvary_missing(
-                     jax.tree.map(jnp.zeros_like, params)),
+                 # Varying from the start: keeps the accumulator's
+                 # VMA type STABLE between init and update, so the
+                 # canonical lax.scan-over-microbatches carry typechecks.
+                 "acc": _pvary(jax.tree.map(jnp.zeros_like, params),
+                               axis_name),
                  "count": jnp.zeros((), jnp.int32)}
         if use_ef:
             state["ef"] = init_ef(params)
@@ -323,8 +313,8 @@ def distributed_optimizer(optimizer, *,
         return new_updates, zero_acc, new_inner, ef
 
     def update_acc(updates, state, params=None, **extra):
-        acc = _pvary_missing(
-            jax.tree.map(jnp.add, state["acc"], updates))
+        acc = _pvary(jax.tree.map(jnp.add, state["acc"], updates),
+                     axis_name)
         count = state["count"] + 1
         ef = state.get("ef")
 
@@ -394,25 +384,23 @@ def distributed_value_and_grad(fun: Callable, argnums=0, *,
             raise ValueError(
                 "in-jit distributed_value_and_grad supports Average/Sum")
 
-        from horovod_tpu.common import jax_compat
         from horovod_tpu import compression as compression_lib
 
-        if (not jax_compat.HAS_VMA
-                or compression_lib.in_jit_codec(compression) != "none"):
-            # Legacy jax: without VMA-typed transposes, grad-of-pmean
-            # does not propagate the averaged cotangent back to
-            # replicated params. Take the explicit formulation —
-            # local grads, then reduce both loss and grads (the
-            # reduce_leaf legacy branch always psums). Compression
-            # takes the same route on ANY jax: grads must exist
-            # explicitly before the collective for the quantized
-            # reduce-scatter + all-gather to ride them (autodiff of a
-            # pmean'd loss never materializes an interceptable
-            # gradient allreduce).
+        if compression_lib.in_jit_codec(compression) != "none":
+            # Grads must exist explicitly before the collective for
+            # the quantized reduce-scatter + all-gather to ride them
+            # (autodiff of a pmean'd loss never materializes an
+            # interceptable gradient allreduce): local grads, then
+            # reduce both loss and grads. The differentiated args are
+            # cast varying first — under VMA typing the cotangent of a
+            # replicated arg arrives already psummed, uncompressed.
             lvg = jax.value_and_grad(fun, argnums=argnums,
                                      has_aux=has_aux)
+            nums = (argnums,) if isinstance(argnums, int) else argnums
 
-            def legacy_wrapped(*args, **kwargs):
+            def local_wrapped(*args, **kwargs):
+                args = tuple(_pvary(a, axis_name) if i in nums else a
+                             for i, a in enumerate(args))
                 value, grads = lvg(*args, **kwargs)
                 loss = value[0] if has_aux else value
                 loss = (lax.pmean(loss, axis_name) if op == Average
@@ -423,7 +411,7 @@ def distributed_value_and_grad(fun: Callable, argnums=0, *,
                     compression=compression, name=name)
                 return value, grads
 
-            return legacy_wrapped
+            return local_wrapped
 
         def global_fun(*args, **kwargs):
             out = fun(*args, **kwargs)

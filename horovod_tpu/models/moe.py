@@ -18,6 +18,7 @@ auxiliary loss (Switch/GShard form).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 import threading
@@ -186,25 +187,17 @@ def moe_ffn_island(x, lp, cfg: MoEConfig, mesh, *, codec: str = "int8"):
     int8 error-bound tests compare against). The expert SwiGLU and the
     combine weighting are byte-for-byte the GSPMD path's math.
 
-    Requires ``B % ep == 0`` and ``E % ep == 0``. On legacy jax the
-    island must be spelled full-manual (the embed-island generation
-    gate), which is legal only when every non-``ep`` mesh axis is
-    size 1 — :func:`make_moe_ffn` enforces that at build time.
+    Requires ``B % ep == 0`` and ``E % ep == 0``.
 
     Capacity overflow is handled exactly like the GSPMD path (dropped
     tokens ride the residual stream); :func:`moe_routing_stats` is the
     telemetry face of the same routing math.
     """
-    from horovod_tpu.common import jax_compat
-    from horovod_tpu.common.jax_compat import shard_map
-    from horovod_tpu.ops.quantized import quantized_alltoall
-
     ep = mesh.shape.get("ep", 1) if mesh is not None else 1
     if ep <= 1:
         return moe_ffn(x, lp, cfg)       # no exchange to quantize
     E = cfg.n_experts
-    B, T, D = x.shape
-    C = capacity(cfg, T)
+    B = x.shape[0]
     if E % ep:
         raise ValueError(
             f"moe_ffn_island: n_experts={E} must divide by the ep axis "
@@ -213,10 +206,24 @@ def moe_ffn_island(x, lp, cfg: MoEConfig, mesh, *, codec: str = "int8"):
         raise ValueError(
             f"moe_ffn_island: batch {B} must divide by the ep axis "
             f"size {ep} (token rows are batch-sharded over ep)")
+    return _jitted_island(cfg, mesh, codec)(
+        x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"])
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_island(cfg: MoEConfig, mesh, codec: str):
+    """The island of :func:`moe_ffn_island`, built once per
+    ``(cfg, mesh, codec)`` so eager callers hit jit's cache instead of
+    compiling a fresh closure every call."""
+    from horovod_tpu.ops.quantized import quantized_alltoall
+
+    E = cfg.n_experts
+    ep = mesh.shape["ep"]
     e_loc = E // ep
 
     def island(xl, router, wg, wu, wd):
-        b_loc = xl.shape[0]
+        b_loc, T, D = xl.shape
+        C = capacity(cfg, T)
         dispatch, combine, probs, top1, _sel, _within = _route(
             xl, router, cfg, C)
 
@@ -248,19 +255,13 @@ def moe_ffn_island(x, lp, cfg: MoEConfig, mesh, *, codec: str = "int8"):
         y = jnp.einsum("btec,ebcd->btd", combine.astype(xl.dtype), xfull)
         return y.astype(xl.dtype), aux
 
-    # Modern jax: partial-manual over ep only (dp/fsdp/tp ride
-    # auto/GSPMD). Legacy jax cannot lower partial-manual (the
-    # embed-island gate); full-manual is correct because make_moe_ffn
-    # guarantees every non-ep axis is size 1 there.
-    axis_names = {"ep"} if jax_compat.HAS_NEW_SHARD_MAP else None
-    # check_vma=False: the VMA checker cannot see that the pmean'd aux
-    # is replicated over ep (same limitation as the embed island).
-    return shard_map(
+    # Partial-manual over ep only (dp/fsdp/tp ride auto/GSPMD). Jitted
+    # so eager callers run the same compiled island as jitted ones:
+    # jax's op-by-op eager shard_map rounds the pmean differently.
+    return jax.jit(jax.shard_map(
         island, mesh=mesh,
         in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep")),
-        out_specs=(P("ep"), P()),
-        axis_names=axis_names, check_vma=False)(
-        x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"])
+        out_specs=(P("ep"), P()), axis_names={"ep"}))
 
 
 def resolve_moe_knobs(dispatch: Optional[str] = None,
@@ -293,12 +294,9 @@ def make_moe_ffn(cfg: MoEConfig, mesh, *, dispatch: Optional[str] = None,
     so "island at compression=none is bitwise-identical to GSPMD"
     holds by construction, and only a genuinely narrow wire pays the
     island's restructuring. ``dispatch="island"`` with a lossy codec
-    builds :func:`moe_ffn_island`; build-time failures (legacy jax
-    with a non-ep axis > 1, E not divisible by ep) raise HERE with
-    the mesh in hand, not mid-trace.
+    builds :func:`moe_ffn_island`; build-time failures (E not
+    divisible by ep) raise HERE with the mesh in hand, not mid-trace.
     """
-    from horovod_tpu.common import jax_compat
-
     d, c = resolve_moe_knobs(dispatch, codec)
     ep = mesh.shape.get("ep", 1) if mesh is not None else 1
     if d == "gspmd" or c == "none" or ep <= 1:
@@ -307,15 +305,6 @@ def make_moe_ffn(cfg: MoEConfig, mesh, *, dispatch: Optional[str] = None,
         raise ValueError(
             f"moe_dispatch='island': n_experts={cfg.n_experts} must "
             f"divide by ep={ep}")
-    if not jax_compat.HAS_NEW_SHARD_MAP:
-        bad = [(ax, sz) for ax, sz in mesh.shape.items()
-               if ax != "ep" and sz > 1]
-        if bad:
-            raise ValueError(
-                "moe_dispatch='island' on legacy jax runs the island "
-                f"full-manual (the embed-island generation gate); mesh "
-                f"axes {bad} must be size 1 there. Use an ep-only mesh "
-                "or moe_dispatch='gspmd'.")
     return lambda x, lp: moe_ffn_island(x, lp, cfg, mesh, codec=c)
 
 

@@ -280,8 +280,6 @@ def embed_lookup(embed, tokens, dtype, mesh: Optional[Mesh],
     the model dtype, nothing table-sized to compress.
     """
     from horovod_tpu import compression as compression_lib
-    from horovod_tpu.common import jax_compat
-    from horovod_tpu.common.jax_compat import shard_map
 
     codec = compression_lib.in_jit_codec(compression)
     V, D = embed.shape
@@ -289,14 +287,6 @@ def embed_lookup(embed, tokens, dtype, mesh: Optional[Mesh],
     fsdp = mesh.shape.get("fsdp", 1) if mesh is not None else 1
     if tp * fsdp == 1:
         return embed.astype(dtype)[tokens]
-    if not jax_compat.HAS_NEW_SHARD_MAP:
-        # Legacy jax: the partial-manual island lowers axis_index to a
-        # PartitionId op the old SPMD partitioner rejects. Take the
-        # global-view gather — the table is replicated for the lookup
-        # (the cost this island exists to avoid), but EXPLICITLY so:
-        # an annotated reshard is a planned all-gather, not the
-        # partitioner's "involuntary full rematerialization" red flag.
-        return _replicated_table_lookup(embed, tokens, dtype, mesh, codec)
     if V % tp or D % fsdp:
         import warnings
         warnings.warn(
@@ -327,9 +317,10 @@ def embed_lookup(embed, tokens, dtype, mesh: Optional[Mesh],
     # check_vma=False: the VMA checker cannot infer that a tiled
     # all_gather's output is replicated over the gathered axis (same
     # limitation as the ring_flash island in ring_attention.py).
-    out = shard_map(island, mesh=mesh,
-                    in_specs=(P("tp", "fsdp"), P()), out_specs=P(),
-                    axis_names={"tp", "fsdp"}, check_vma=False)(embed, tokens)
+    out = jax.shard_map(island, mesh=mesh,
+                        in_specs=(P("tp", "fsdp"), P()), out_specs=P(),
+                        axis_names={"tp", "fsdp"},
+                        check_vma=False)(embed, tokens)
     return out.astype(dtype)
 
 
@@ -514,10 +505,8 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer=None, *,
         return _make_quantized_train_step(cfg, mesh, optimizer,
                                           compression, codec)
 
-    specs = param_specs(cfg)
-
     def init_state(key):
-        params = init_params(cfg, key, mesh)
+        params = init_params(cfg, key)
         opt_state = optimizer.init(params)
         return {"params": params, "opt": opt_state, "step": jnp.zeros((), jnp.int32)}
 
@@ -530,21 +519,56 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer=None, *,
         return {"params": params, "opt": new_opt,
                 "step": state["step"] + 1}, loss
 
-    param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+    param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                            param_specs(cfg),
                             is_leaf=lambda x: isinstance(x, P))
     batch_sh = {"tokens": NamedSharding(mesh, P(("dp", "fsdp"), None))}
-
-    # Donating state needs the compiler to alias in/out buffers; with
-    # inferred out_shardings legacy XLA can pick a different output
-    # sharding and abort with an aliasing size mismatch (modern jax
-    # reshards around the alias). Skip donation there — compat mode
-    # pays one state copy per step, correctness first.
-    from horovod_tpu.common import jax_compat
-    donate = (0,) if jax_compat.HAS_NEW_SHARD_MAP else ()
-    jit_step = jax.jit(step, donate_argnums=donate,
-                       in_shardings=(None, batch_sh),
-                       out_shardings=(None, NamedSharding(mesh, P())))
+    init_state, jit_step = jit_sharded_state(
+        init_state, step, mesh, param_sh, batch_sh, donate=True)
     return init_state, jit_step, param_sh
+
+
+def jit_sharded_state(init, step, mesh: Mesh, param_sh, batch_sh=None, *,
+                      ef_sh=None, donate: bool = False):
+    """Jit ``init(key)`` and ``step(state, batch)`` with the train
+    state's layout pinned on both sides: ``state["params"]`` to
+    ``param_sh``, every optimizer-state leaf that mirrors a param (same
+    tree position, same shape — Adam's moments) to that param's
+    sharding, ``state["ef"]`` to ``ef_sh``, everything else replicated
+    (counts, and statistics that share the params' tree but not their
+    shapes, like Adafactor's factored rows and columns).
+
+    The factories own the layout so no caller's spelling can replicate
+    the state: an outer ``jax.jit(init_state)`` drops an in-trace
+    ``device_put``, and a ``step`` that takes the state "as it finds
+    it" then compiles FSDP in name only. ``step`` reshards whatever it
+    is handed (a restored checkpoint) to the same layout.
+    """
+    repl = NamedSharding(mesh, P())
+    p_struct = jax.tree.structure(param_sh)
+    # The key is made inside the trace so the active PRNG
+    # implementation (threefry, rbg) sets its shape.
+    abstract = jax.eval_shape(lambda: init(jax.random.PRNGKey(0)))
+
+    def like_params(node):
+        return jax.tree.structure(node) == p_struct
+
+    def pin(node):
+        if not like_params(node):
+            return repl
+        return jax.tree.map(
+            lambda leaf, param, sh: sh if leaf.shape == param.shape else repl,
+            node, abstract["params"], param_sh)
+
+    state_sh = {k: jax.tree.map(pin, v, is_leaf=like_params)
+                for k, v in abstract.items()}
+    if "ef" in abstract:
+        state_sh["ef"] = ef_sh
+    jit_init = jax.jit(init, out_shardings=state_sh)
+    jit_step = jax.jit(step, donate_argnums=(0,) if donate else (),
+                       in_shardings=(state_sh, batch_sh),
+                       out_shardings=(state_sh, repl))
+    return jit_init, jit_step
 
 
 def _make_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
@@ -595,7 +619,6 @@ def _make_dp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
     import optax
 
     from horovod_tpu import compression as compression_lib
-    from horovod_tpu.common.jax_compat import shard_map
     from horovod_tpu.common.ops_enum import Average
     from horovod_tpu.ops.quantized import quantized_allreduce
 
@@ -603,10 +626,7 @@ def _make_dp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
     use_ef = compression_lib.needs_error_feedback(compression)
 
     def init_state(key):
-        # Params replicated over dp (a dp-only mesh has no model
-        # sharding; param_specs' tp/fsdp axes may not even exist here).
-        params = jax.device_put(init_params(cfg, key, None),
-                                NamedSharding(mesh, P()))
+        params = init_params(cfg, key)
         opt_state = optimizer.init(params)
         state = {"params": params, "opt": opt_state,
                  "step": jnp.zeros((), jnp.int32)}
@@ -644,10 +664,15 @@ def _make_dp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
         params = optax.apply_updates(params, updates)
         return params, opt, ef, loss
 
-    smapped = shard_map(
+    # check_vma=False: the reduced gradients leave quantized_allreduce
+    # through an all_gather, which jax types as varying — the checker
+    # cannot see that params/opt stay replicated (see ops/quantized.py).
+    # With VMA off autodiff also leaves the gradients rank-local, which
+    # is what the explicit reduction needs.
+    smapped = jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(P(), P(), P("dp"), P("dp")),
-        out_specs=(P(), P(), P("dp"), P()))
+        out_specs=(P(), P(), P("dp"), P()), check_vma=False)
 
     def step(state, batch):
         params, opt, ef, loss = smapped(
@@ -659,10 +684,15 @@ def _make_dp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
             new_state["ef"] = ef
         return new_state, loss
 
+    # Params replicated over dp (a dp-only mesh has no model sharding;
+    # param_specs' tp/fsdp axes may not even exist here).
     param_sh = jax.tree.map(lambda s: NamedSharding(mesh, P()),
                             param_specs(cfg),
                             is_leaf=lambda x: isinstance(x, P))
-    return init_state, jax.jit(step), param_sh
+    init_state, jit_step = jit_sharded_state(
+        init_state, step, mesh, param_sh,
+        ef_sh=NamedSharding(mesh, P("dp")))
+    return init_state, jit_step, param_sh
 
 
 def _fsdp_spec_dim(spec) -> Optional[int]:
@@ -682,10 +712,7 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
     params with collectives it inserts itself — there is no hop a
     codec can ride. This variant expresses the fsdp step as a
     partial-manual ``shard_map`` island (manual over the data axes
-    ``{dp, fsdp}``; on legacy jax the island is spelled full-manual,
-    exactly the generation gate the embed island uses — legal here
-    because every non-data axis is size 1, which the dispatcher
-    enforces):
+    ``{dp, fsdp}``):
 
     * params stay fsdp-sharded on their ``param_specs`` dims (the
       ZeRO-3 layout; optimizer state and EF residuals shard with
@@ -697,8 +724,8 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
     * the gradient reduce-scatter is the explicit
       :func:`~horovod_tpu.ops.quantized.quantized_reduce_scatter`
       hop — quantize per destination shard → ``all_to_all`` →
-      multiply-only f32 fold (psum_scatter-native for bf16/fp16 where
-      the backend allows, per the jax_compat probe); fsdp-replicated
+      multiply-only f32 fold (psum_scatter-native for bf16/fp16);
+      fsdp-replicated
       leaves (norms) ride a full ``quantized_allreduce`` over fsdp;
     * when the mesh also carries ``dp > 1``, a second
       ``quantized_allreduce`` hop over ``dp`` reduces each gradient
@@ -715,8 +742,6 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
     import optax
 
     from horovod_tpu import compression as compression_lib
-    from horovod_tpu.common import jax_compat
-    from horovod_tpu.common.jax_compat import shard_map
     from horovod_tpu.common.ops_enum import Average
     from horovod_tpu.ops.quantized import (quantized_allreduce,
                                            quantized_reduce_scatter)
@@ -739,8 +764,8 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
 
     # Shard divisibility is a build-time contract (shard_map cannot pad
     # the way GSPMD does): every fsdp-sharded dim must divide by nfsdp.
-    shapes = jax.eval_shape(lambda k: init_params(cfg, k, None),
-                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    shapes = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), None))
     bad = []
 
     def _check_divisible(path, leaf, spec):
@@ -807,28 +832,19 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
             loss = lax.pmean(loss, ax)
         return loss, grads, new_ef
 
-    # Modern jax: a genuine partial-manual island — only the data axes
-    # are manual, anything else rides auto/GSPMD. Legacy jax cannot
-    # lower partial-manual (axis_index becomes a PartitionId op the old
-    # partitioner rejects — the embed-island gate), so the island is
-    # full-manual there; the dispatcher guarantees the remaining axes
-    # are size 1, which full-manual handles trivially.
-    axis_names = ({"dp", "fsdp"} & set(mesh.axis_names)
-                  if jax_compat.HAS_NEW_SHARD_MAP else None)
+    # Partial-manual: only the data axes are manual, anything else
+    # rides auto/GSPMD.
     # check_vma=False: the VMA checker cannot infer a tiled
     # all_gather's output is replicated over the gathered axis (same
     # limitation as the embed island).
-    smapped = shard_map(
+    smapped = jax.shard_map(
         island, mesh=mesh,
         in_specs=(isl_specs, P(*batch_axes), P(batch_axes)),
         out_specs=(P(), isl_specs, P(*batch_axes)),
-        axis_names=axis_names, check_vma=False)
+        axis_names={"dp", "fsdp"} & set(mesh.axis_names), check_vma=False)
 
     def init_state(key):
-        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                                 isl_specs,
-                                 is_leaf=lambda x: isinstance(x, P))
-        params = jax.device_put(init_params(cfg, key, None), shardings)
+        params = init_params(cfg, key)
         opt_state = optimizer.init(params)
         state = {"params": params, "opt": opt_state,
                  "step": jnp.zeros((), jnp.int32)}
@@ -864,4 +880,7 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
 
     param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), isl_specs,
                             is_leaf=lambda x: isinstance(x, P))
-    return init_state, jax.jit(step), param_sh
+    init_state, jit_step = jit_sharded_state(
+        init_state, step, mesh, param_sh,
+        ef_sh=NamedSharding(mesh, P(*batch_axes)))
+    return init_state, jit_step, param_sh

@@ -37,8 +37,7 @@ def axis_rank(axis_name: AxisName = "dp"):
 
 def axis_size(axis_name: AxisName = "dp") -> int:
     """Static size of the named axis (cf. ``hvd.size()``)."""
-    from horovod_tpu.common.jax_compat import axis_size as _axis_size
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)
 
 
 def _scale(x, factor):

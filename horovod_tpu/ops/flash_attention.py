@@ -101,8 +101,10 @@ def _default_blocks(t: int):
     medium sequence — grid overhead dominates small tiles (1024×1024
     at seq 1024 measures 61.6% vs 53.3% MFU for 128×128 on v5e,
     d=2048×8L) — while 512×1024 wins from ~4k up (measured at seq 8192
-    for both forward and fwd+bwd). Capped at 1024: ≥2048 blocks exceed
-    this environment's compile limits."""
+    for both forward and fwd+bwd). Capped at 1024: a 2048×2048 tile's
+    f32 scores need 21 MiB of scoped VMEM at seq 4096 against the v5e's
+    16 MiB default (Mosaic refuses it); at seq 2048 it compiles, and
+    whether it would be faster there is not measured."""
     if t <= 4096:
         b = min(1024, _round_up(t, 128))
         return b, b

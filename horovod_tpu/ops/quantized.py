@@ -2,12 +2,19 @@
 
 PR 3 compressed the host TCP ring; this module compresses the plane the
 models actually train on — the in-``jit`` collectives over NamedSharding
-meshes. Pure ``jnp`` (Pallas hard-aborts on this container's XLA-CPU),
-callable only under ``shard_map`` with the named axis fully manual.
+meshes. Pure ``jnp``, callable only under ``shard_map`` with the named
+axis manual.
+
+The reduced value leaves through ``lax.all_gather``, which jax types
+as *varying* over the axis although every rank holds the same bytes
+(the public API has no varying→invariant cast). A ``shard_map`` that
+returns it — or anything computed from it — under a replicated
+``out_specs`` therefore needs ``check_vma=False``.
 
 Codecs, mirroring ``native/src/codec.cc`` exactly:
 
-* **bf16 / fp16** — cast the wire representation down, reduce in f32.
+* **bf16 / fp16** — cast the wire representation down; the backend
+  reduces the narrow operand.
 * **int8** — blockwise-scaled: each :data:`INT8_BLOCK_ELEMS`-element
   block carries a ``absmax/127`` f32 scale; values quantize with
   round-to-nearest-even (``jnp.round`` lowers to
@@ -20,18 +27,20 @@ decomposition (arXiv:1909.09756) with both hops shipping narrow bytes:
 
 1. quantize the local value, blockwise per destination shard;
 2. reduce-scatter the narrow payload — expressed as ``lax.all_to_all``
-   of the int8/bf16 bytes plus a local f32 fold, because a reduction
-   collective cannot sum int8 encodings under per-rank scales (and the
-   legacy XLA-CPU ``AllReducePromotion`` pass aborts on sub-f32
-   ``psum_scatter`` operands); the wire bytes equal ``psum_scatter``'s;
+   of the int8 bytes plus a local f32 fold, because a reduction
+   collective cannot sum int8 encodings under per-rank scales; the
+   wire bytes equal ``psum_scatter``'s, which is what the cast codecs
+   (bf16/fp16) use directly;
 3. **requantize** the reduced shard;
 4. ``lax.all_gather`` the narrow bytes and dequantize.
 
 Determinism contract (same as ``HostAccumulate``): the fold is a fixed
 ``sum(axis=0)`` over peer order and every decode is a *multiply* by the
 scale (``q * s``, never ``q / inv``) — a constant division gets
-algebraically rewritten under jit and breaks the jit/no-jit bitwise
-identity the tests pin.
+algebraically rewritten under jit. Every codec is bitwise stable run
+to run. The cast codecs (bf16/fp16) are also bitwise jit vs op-by-op
+eager; int8 may differ from eager by an f32 ULP, where XLA contracts
+its decode multiply into the fold as an FMA.
 
 Error feedback (int8): the rank-local residual telescopes the rounding
 error across steps exactly like the host plane's EF slabs. Both
@@ -114,11 +123,6 @@ def blockwise_int8_decode(q, scales, c: int):
 # The quantized allreduce
 # ---------------------------------------------------------------------------
 
-def _axis_size(axis_name) -> int:
-    from horovod_tpu.common.jax_compat import axis_size
-    return axis_size(axis_name)
-
-
 def _check_codec(codec: str):
     if codec not in CODECS:
         raise ValueError(f"unknown in-jit codec {codec!r}; one of {CODECS}")
@@ -139,19 +143,8 @@ def _check_axis_name(axis_name, fn_name: str):
             "for multi-axis meshes).")
 
 
-def _native_cast_hop_ok(native_hop) -> bool:
-    """Whether the cast-codec reduce-scatter hop may lower as ONE
-    sub-f32 ``lax.psum_scatter`` instead of all_to_all + f32 fold.
-    ``native_hop`` None = probe (jax_compat), True/False = forced."""
-    if native_hop is not None:
-        return bool(native_hop)
-    from horovod_tpu.common.jax_compat import supports_narrow_psum_scatter
-    return supports_narrow_psum_scatter()
-
-
 def quantized_allreduce(x, op: ReduceOp = Average, axis_name: str = "dp", *,
-                        codec: str, residual: Optional[jax.Array] = None,
-                        native_hop: Optional[bool] = None):
+                        codec: str, residual: Optional[jax.Array] = None):
     """Allreduce ``x`` over ``axis_name`` with narrow bytes on both hops.
 
     Call under ``shard_map`` with ``axis_name`` manual. ``codec`` is one
@@ -170,7 +163,7 @@ def quantized_allreduce(x, op: ReduceOp = Average, axis_name: str = "dp", *,
     if codec == "none":
         y = lax.psum(x, axis_name)
         if op == Average:
-            y = y / _axis_size(axis_name)
+            y = y / lax.axis_size(axis_name)
         elif op != Sum:
             raise ValueError("quantized_allreduce supports Sum/Average")
         return (y, residual) if residual is not None else y
@@ -183,7 +176,7 @@ def quantized_allreduce(x, op: ReduceOp = Average, axis_name: str = "dp", *,
             f"cannot quantize dtype {x.dtype}; compression applies to "
             "float gradients")
 
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     orig_shape, orig_dtype = x.shape, x.dtype
     n = x.size
     xf = x.astype(jnp.float32).reshape(-1)
@@ -211,16 +204,11 @@ def quantized_allreduce(x, op: ReduceOp = Average, axis_name: str = "dp", *,
     else:
         wire = _CAST_WIRE[codec]
         w1 = v.astype(wire)
-        if _native_cast_hop_ok(native_hop):
-            # psum_scatter-native hop: the backend reduces the narrow
-            # operand itself — one collective, same wire bytes as the
-            # all_to_all spelling, summation in the wire dtype.
-            y = lax.psum_scatter(w1, axis_name,
-                                 scatter_dimension=0).astype(jnp.float32)
-        else:
-            wr = lax.all_to_all(w1, axis_name, split_axis=0, concat_axis=0,
-                                tiled=True)
-            y = wr.astype(jnp.float32).sum(axis=0)
+        # psum_scatter-native hop: the backend reduces the narrow
+        # operand itself — one collective, same wire bytes as the
+        # all_to_all spelling, summation in the wire dtype.
+        y = lax.psum_scatter(w1, axis_name,
+                             scatter_dimension=0).astype(jnp.float32)
         w2 = y.astype(wire)
         z = lax.all_gather(w2, axis_name, axis=0,
                            tiled=False).astype(jnp.float32)
@@ -245,8 +233,7 @@ def quantized_allreduce(x, op: ReduceOp = Average, axis_name: str = "dp", *,
 def quantized_reduce_scatter(x, op: ReduceOp = Sum,
                              axis_name: str = "fsdp", *, codec: str,
                              axis: int = 0,
-                             residual: Optional[jax.Array] = None,
-                             native_hop: Optional[bool] = None):
+                             residual: Optional[jax.Array] = None):
     """Reduce-scatter ``x`` over ``axis_name`` with the hop bytes
     narrowed by ``codec`` — the explicit, interceptable spelling of the
     GSPMD-inserted fsdp gradient reduce-scatter.
@@ -255,10 +242,8 @@ def quantized_reduce_scatter(x, op: ReduceOp = Sum,
     blockwise per destination shard → ``lax.all_to_all`` of the narrow
     payload (+f32 scales for int8) → fixed-order **multiply-only** f32
     fold; the wire bytes equal ``psum_scatter``'s. For the cast codecs
-    the fold may lower as ONE sub-f32 ``lax.psum_scatter`` where the
-    backend allows (``native_hop`` None = the jax_compat probe; legacy
-    XLA-CPU aborts on sub-f32 reduce collectives, so the probe keeps it
-    off there).
+    the fold lowers as ONE sub-f32 ``lax.psum_scatter`` (the backend
+    reduces the narrow operand itself).
 
     ``x``'s dim ``axis`` must divide by the axis size; this rank
     returns its slice (``x.shape`` with that dim divided). ``"none"``
@@ -276,7 +261,7 @@ def quantized_reduce_scatter(x, op: ReduceOp = Sum,
         raise TypeError(
             f"cannot quantize dtype {x.dtype}; compression applies to "
             "float gradients")
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     axis = axis % x.ndim
     if x.shape[axis] % p:
         raise ValueError(
@@ -308,13 +293,8 @@ def quantized_reduce_scatter(x, op: ReduceOp = Sum,
     else:
         wire = _CAST_WIRE[codec]
         w1 = rows.astype(wire)
-        if _native_cast_hop_ok(native_hop):
-            y = lax.psum_scatter(w1, axis_name,
-                                 scatter_dimension=0).astype(jnp.float32)
-        else:
-            wr = lax.all_to_all(w1, axis_name, split_axis=0, concat_axis=0,
-                                tiled=True)
-            y = wr.astype(jnp.float32).sum(axis=0)
+        y = lax.psum_scatter(w1, axis_name,
+                             scatter_dimension=0).astype(jnp.float32)
         if residual is not None:
             e1 = rows - w1.astype(jnp.float32)
 
@@ -437,7 +417,7 @@ def quantized_alltoall(x, axis_name: str = "ep", *, codec: str,
     if codec == "none" and bwd == "none":
         return _plain_alltoall(x, axis_name)
     _check_axis_name(axis_name, "quantized_alltoall")
-    p = _axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     if x.shape[0] != p:
         raise ValueError(
             f"quantized_alltoall: leading dim {x.shape[0]} must equal "

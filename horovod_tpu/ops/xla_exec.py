@@ -186,16 +186,40 @@ def _rank_mesh():
             "host into single-chip processes (runner/tpu.py); or use "
             "the SPMD functional API (horovod_tpu.ops) for multi-device "
             "processes")
-    return Mesh(np.asarray(jax.devices(), dtype=object), ("rank",))
+    # Position along "rank" must be the Horovod rank. hvd.init hands it
+    # to jax.distributed as the process id, but on a TPU host the
+    # backend numbers processes itself, differently from run to run
+    # (first four-chip runs, PR 21: rank 0 came up as process 3 and a
+    # broadcast from root 0 delivered another rank's tensor). So ask:
+    # one tiny gather over the process-ordered mesh tells every process
+    # which Horovod rank sits behind each device. Every process reaches
+    # this on the same (first) CALLBACK response, like any other
+    # program here.
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    devices = sorted(jax.devices(), key=lambda d: d.process_index)
+    by_process = Mesh(np.asarray(devices, dtype=object), ("rank",))
+    ranks = _make_global(np.int32(basics.get_lib().hvd_rank()),
+                         len(devices), by_process)
+    ranks = np.asarray(_local(jax.jit(
+        lambda a: a, out_shardings=NamedSharding(by_process, P()))(ranks)))
+    if sorted(ranks.tolist()) != list(range(len(devices))):
+        raise RuntimeError(
+            f"processes report Horovod ranks {ranks.tolist()}; expected a "
+            f"permutation of 0..{len(devices) - 1}")
+    return Mesh(np.asarray(devices, dtype=object)[np.argsort(ranks)],
+                ("rank",))
 
 
-def _make_global(local, size: int):
+def _make_global(local, size: int, mesh=None):
     """Assemble the (size, ...) global array whose rank-th row is this
-    process's ``local`` (shape ``local.shape``), sharded over "rank"."""
+    process's ``local`` (shape ``local.shape``), sharded over "rank"
+    (of ``mesh``; default the rank mesh)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = _rank_mesh()
+    if mesh is None:
+        mesh = _rank_mesh()
     sharding = NamedSharding(mesh, P("rank"))
     dev = mesh.local_mesh.devices.flat[0]
     local = jax.device_put(local[None], dev)

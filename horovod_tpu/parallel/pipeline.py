@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.common.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -142,14 +141,14 @@ def pipeline_apply(stage_fn: Callable, stage_params, microbatches, *,
     # ring_flash island in ring_attention.py).
     if mb_spec is None:
         mb_spec = P()
-    outs, aux_total = shard_map(island, mesh=mesh,
-                                in_specs=(_stage_specs(stage_params),
-                                          mb_spec),
-                                out_specs=(mb_spec, P()),
-                                axis_names=frozenset({axis_name})
-                                | extra_axes,
-                                check_vma=check_vma)(
-                                    stage_params, microbatches)
+    outs, aux_total = jax.shard_map(island, mesh=mesh,
+                                    in_specs=(_stage_specs(stage_params),
+                                              mb_spec),
+                                    out_specs=(mb_spec, P()),
+                                    axis_names=frozenset({axis_name})
+                                    | extra_axes,
+                                    check_vma=check_vma)(
+                                        stage_params, microbatches)
     if with_aux:
         return outs, aux_total
     return outs
@@ -184,7 +183,8 @@ def pp_param_specs(cfg, n_stages: int):
 
 def _wire_train_step(cfg, mesh: Mesh, loss_fn, optimizer):
     """Shared tail of both pp step factories: stage-reshaped params,
-    sharded init, value_and_grad step, donated jit."""
+    value_and_grad step, init and donated step jitted with the state's
+    layout pinned (:func:`~horovod_tpu.models.transformer.jit_sharded_state`)."""
     import optax
 
     from horovod_tpu.models import transformer as tr
@@ -194,9 +194,6 @@ def _wire_train_step(cfg, mesh: Mesh, loss_fn, optimizer):
 
     def init_state(key):
         params = pp_reshape_layers(tr.init_params(cfg, key), S)
-        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                                 is_leaf=lambda x: isinstance(x, P))
-        params = jax.device_put(params, shardings)
         return {"params": params, "opt": optimizer.init(params),
                 "step": jnp.zeros((), jnp.int32)}
 
@@ -211,9 +208,8 @@ def _wire_train_step(cfg, mesh: Mesh, loss_fn, optimizer):
     param_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                             is_leaf=lambda x: isinstance(x, P))
     batch_sh = {"tokens": NamedSharding(mesh, P(("dp", "fsdp"), None))}
-    jit_step = jax.jit(step, donate_argnums=(0,),
-                       in_shardings=(None, batch_sh),
-                       out_shardings=(None, NamedSharding(mesh, P())))
+    init_state, jit_step = tr.jit_sharded_state(
+        init_state, step, mesh, param_sh, batch_sh, donate=True)
     return init_state, jit_step, param_sh
 
 

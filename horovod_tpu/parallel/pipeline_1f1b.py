@@ -66,8 +66,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from horovod_tpu.common.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.parallel.pipeline import _stage_specs
@@ -258,7 +256,7 @@ def pipeline_1f1b(stage_fn: Callable, last_fn: Callable, stage_params,
     mspec = P() if mb_spec is None else mb_spec
     # check_vma=False: masked psums + pallas-containing stage_fns defeat
     # the VMA inference (same as the GPipe island).
-    return shard_map(
+    return jax.shard_map(
         island, mesh=mesh,
         in_specs=(sspec, last_repl, mspec),
         out_specs=(P(), sspec, last_repl, mspec),

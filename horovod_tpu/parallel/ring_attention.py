@@ -28,9 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.common import jax_compat
-from horovod_tpu.common.jax_compat import axis_size as _axis_size
-
 _NEG_BIG = -1e30  # finite "-inf": keeps the online-softmax guards NaN-free
 
 
@@ -39,19 +36,15 @@ def _varying_like(ts, ref, axis_name: str):
     AND every other manual axis ``ref`` (the query shard) is varying
     over. Inside a combined manual island (pp+sp pipelining) the
     fori_loop carry mixes in pp-varying activations, so declaring only
-    the ring axis would mismatch the carry's VMA types. On legacy jax
-    (no VMA type system) this is the identity."""
-    want = jax_compat.vma_of(ref) | {axis_name}
-    out = []
-    for t in ts:
-        missing = tuple(want - jax_compat.vma_of(t))
-        out.append(jax_compat.pcast_varying(t, missing))
-    return out
+    the ring axis would mismatch the carry's VMA types."""
+    want = jax.typeof(ref).vma | {axis_name}
+    return [lax.pcast(t, tuple(want - jax.typeof(t).vma), to="varying")
+            for t in ts]
 
 
 def _rotate(x, axis_name: str, shift: int = 1):
     """Pass shard-local ``x`` one hop around the ``axis_name`` ring."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm=perm)
 
@@ -96,7 +89,7 @@ def ring_self_attention(q, k, v, *, axis_name: str = "sp",
     causal masking keys off the chunk's global offset).
     """
     B, T, H, D = q.shape
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     if scale is None:
         scale = D ** -0.5
@@ -154,7 +147,7 @@ def ring_flash_attention(q, k, v, *, axis_name: str = "sp",
     from horovod_tpu.ops.flash_attention import flash_attention_with_lse
 
     B, T, H, D = q.shape
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     if scale is None:
         scale = D ** -0.5
@@ -250,7 +243,7 @@ def ulysses_attention(q, k, v, *, axis_name: str = "sp",
     attention heads — the SP design its substrate anticipated
     (SURVEY.md §2.6). Requires ``H % sp == 0``.
     """
-    sp = _axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
 
     def seq_to_heads(x):  # [B, T/sp, H, D] -> [B, T, H/sp, D]
         return lax.all_to_all(x, axis_name, split_axis=2, concat_axis=1,
@@ -307,11 +300,11 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
         # ("cannot be automatically partitioned"), including on a
         # single real chip.
         bspec = P(("dp", "fsdp"), None, "tp", None)
-        mapped = jax_compat.shard_map(fa, mesh=mesh,
-                                      in_specs=(bspec, bspec, bspec),
-                                      out_specs=bspec,
-                                      axis_names=frozenset(mesh.axis_names),
-                                      check_vma=False)
+        mapped = jax.shard_map(fa, mesh=mesh,
+                               in_specs=(bspec, bspec, bspec),
+                               out_specs=bspec,
+                               axis_names=frozenset(mesh.axis_names),
+                               check_vma=False)
         tp_size = dict(mesh.shape).get("tp", 1)
 
         def wrapped(q, k, v):
@@ -342,29 +335,14 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
                                  causal=causal)
     else:
         raise ValueError(f"unknown SP attention impl {impl!r}")
-    axis_names = frozenset({axis_name})
-    if not jax_compat.HAS_NEW_SHARD_MAP and spec == P(None, axis_name,
-                                                      None, None):
-        # Legacy jax cannot lower a PARTIAL-manual island (axis_index
-        # becomes a PartitionId op its SPMD partitioner rejects): go
-        # fully manual, which needs the other axes' placement spelled
-        # out — batch over dp/fsdp, heads over tp, the transformer's
-        # activation layout. Requires B % (dp*fsdp) == 0 and
-        # H % tp == 0, which the mesh-divisibility rules already
-        # guarantee for the model paths that reach here.
-        names = set(getattr(mesh, "axis_names", ()))
-        batch_axes = tuple(a for a in ("dp", "fsdp") if a in names)
-        head_axis = "tp" if "tp" in names else None
-        spec = P(batch_axes or None, axis_name, head_axis, None)
-        axis_names = frozenset(names)
     # VMA checking stays ON for the pure-XLA impls; pallas_call's
     # out_shape carries no varying-manual-axes annotation yet, so the
     # ring_flash island must opt out (a JAX limitation, not a missing
     # pcast — the accumulators are declared varying either way).
-    return jax_compat.shard_map(body, mesh=mesh,
-                                in_specs=(spec, spec, spec), out_specs=spec,
-                                axis_names=axis_names,
-                                check_vma=impl != "ring_flash")
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=frozenset({axis_name}),
+                         check_vma=impl != "ring_flash")
 
 
 def sequence_sharded_attention(q, k, v, mesh, *, axis_name: str = "sp",

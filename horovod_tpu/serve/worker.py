@@ -501,6 +501,9 @@ class ReplicaWorker:
 def main(argv: Optional[List[str]] = None) -> int:
     import socket
 
+    from horovod_tpu.common.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="horovod_tpu serve worker: one ServeEngine replica "
                     "behind the fleet RPC seam (see docs/serving.md)")

@@ -36,11 +36,10 @@ setup(
     package_data={"horovod_tpu.common": ["libhorovod_tpu_core.so"]},
     install_requires=["numpy", "cloudpickle", "pyyaml"],
     extras_require={
-        # >=0.6 has the modern surface (lax.pcast, shard_map
-        # axis_names); common/jax_compat.py translates down to 0.4.x
-        # (experimental shard_map, no VMA types) with reduced coverage
-        # for the Pallas and partial-manual island paths.
-        "jax": ["jax>=0.4.30", "optax"],
+        # Built and tested against jax/jaxlib 0.9.0 only: the code uses
+        # jax.shard_map(axis_names=, check_vma=), lax.pcast,
+        # lax.axis_size and jax.typeof(...).vma directly, no shim.
+        "jax": ["jax>=0.9.0", "optax"],
         "torch": ["torch"],
         "ray": ["ray"],
         "spark": ["pyspark"],

@@ -30,6 +30,17 @@ from horovod_tpu.common.exceptions import HorovodInternalError  # noqa: E402
 
 def main():
     scenario = sys.argv[1]
+    if scenario == "xla_rank_order":
+        # A TPU host numbers its processes itself, differently from run
+        # to run, whatever process id hvd.init hands jax.distributed.
+        # Stand in for that on the CPU: reverse the ids.
+        import jax
+        real_init = jax.distributed.initialize
+
+        def reversed_ids(*a, process_id, num_processes, **kw):
+            return real_init(*a, process_id=num_processes - 1 - process_id,
+                             num_processes=num_processes, **kw)
+        jax.distributed.initialize = reversed_ids
     hvd.init()
     r, s = hvd.rank(), hvd.size()
 
@@ -549,6 +560,21 @@ def main():
                                    rtol=1e-4)
         np.testing.assert_allclose(np.asarray(outs[1]), adasum_tree_model(b),
                                    rtol=1e-4)
+
+    elif scenario == "xla_rank_order":
+        # The eager XLA plane's "rank" axis must follow Horovod ranks,
+        # not jax process indices (what a sum or mean cannot see).
+        import jax
+        import jax.numpy as jnp
+
+        assert jax.process_index() == s - 1 - r, jax.process_index()
+        rows = hvd.allgather(jnp.full((1, 2), float(r), jnp.float32),
+                             name="order.ag")
+        np.testing.assert_array_equal(np.asarray(rows)[:, 0], np.arange(s))
+        for root in range(s):
+            out = hvd.broadcast(jnp.full((3,), float(r), jnp.float32),
+                                root_rank=root, name=f"order.bc.{root}")
+            np.testing.assert_array_equal(np.asarray(out), float(root))
 
     elif scenario == "xla_join":
         # CALLBACK-mode Join: joined rank synthesizes a zeros

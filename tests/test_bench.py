@@ -458,3 +458,69 @@ def test_find_regressions_trace_observability_keys_ungated():
     assert bench.find_regressions(prev, cur2) == {}
     assert "_dump_ms" in bench.UNGATED_SUFFIXES
     assert "_overhead_pct" in bench.UNGATED_SUFFIXES
+
+
+_PARENT_DRIVE = r"""
+import json, os, subprocess, sys
+sys.path.insert(0, {root!r})
+import bench
+
+real_run = subprocess.run
+
+
+def fake_run(cmd, **kw):
+    # No chip here: every worker is replaced by a stub that prints its
+    # tagged line, and the MoE one dies after printing — the parent's
+    # own code path (spawn, parse, budget gates, exit code) is real.
+    flag = cmd[-1]
+    tags = {{"--resnet-worker": 'RESNET {{"value": 123.4}}',
+            "--transformer-worker": 'TFEXTRA {{"transformer_std_mfu_pct": 1.0}}',
+            "--moe-worker": 'MOEEXTRA {{"moe_tokens_per_sec_gspmd": 2.0}}',
+            "--serve-worker": 'SERVEEXTRA {{"serve_tokens_per_sec_per_chip": 3.0}}',
+            "--elastic-chaos-worker": 'ELASTICEXTRA {{"elastic_recovery_ms": 4.0}}'}}
+    rc = 7 if flag == "--moe-worker" else 0
+    return real_run([sys.executable, "-c",
+                     "import sys; print(%r); sys.exit(%d)" % (tags[flag], rc)],
+                    **kw)
+
+
+subprocess.run = fake_run
+try:
+    bench.main()
+except SystemExit as e:
+    print("EXIT", e.code)
+print("JAX_IN_PARENT", "jax" in sys.modules)
+"""
+
+
+def test_parent_never_imports_jax_and_fails_on_dead_worker(tmp_path):
+    """One process per chip: a parent that has touched JAX holds the
+    chip and its workers cannot get it, so ``main()`` must run every
+    worker without ``jax`` ever entering its ``sys.modules``. A worker
+    that dies keeps what it printed but makes the run exit non-zero —
+    a crashed arm must not look like a skipped one."""
+    import subprocess
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, BENCH_SKIP_BUS="1")
+    proc = subprocess.run(
+        [sys.executable, "-c", _PARENT_DRIVE.format(root=root)],
+        env=env, cwd=str(tmp_path), capture_output=True, text=True,
+        timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    lines = proc.stdout.strip().splitlines()
+    assert lines[-1] == "JAX_IN_PARENT False", proc.stdout
+    assert lines[-2] == "EXIT 1", proc.stdout
+    payload = json.loads(lines[-3])
+    assert payload["value"] == 123.4
+    assert payload["extra"]["moe_tokens_per_sec_gspmd"] == 2.0
+    assert payload["extra"]["elastic_recovery_ms"] == 4.0
+    assert payload["failed_workers"] == ["--moe-worker: rc=7"]
+    assert "--moe-worker rc=7" in proc.stderr
+
+
+def test_no_swallowed_worker_failures():
+    """The three ``except Exception: pass`` that let a chip-less worker
+    print nothing and exit 0 are gone, and stay gone."""
+    src = open(bench.__file__).read()
+    assert "except Exception:\n        pass" not in src

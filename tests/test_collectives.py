@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 import horovod_tpu.ops as hops
 from horovod_tpu.common.ops_enum import Average, Sum, Min, Max, Product
 
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 
 
 def _shmap(fn, mesh, in_specs, out_specs):

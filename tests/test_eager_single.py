@@ -132,3 +132,24 @@ def test_timeline(tmp_path):
     names = {e.get("name") for e in events}
     assert any(n and n.startswith("NEGOTIATE_") for n in names), names
     assert "ALLREDUCE" in names
+
+
+def test_failed_native_build_raises_instead_of_loading_a_stale_core(
+        monkeypatch):
+    """With the sources present the core is built from them or not at
+    all: the chip tool copies the tree as it stands on disk, and a
+    ``.so`` left over from another state of it must never stand in for
+    sources that no longer compile."""
+    import subprocess
+
+    from horovod_tpu.common import basics
+
+    def failing_make():
+        raise subprocess.CalledProcessError(
+            2, ["make"], stderr=b"operations.cc:1: error: expected ';'")
+
+    monkeypatch.setattr(basics, "_build_native", failing_make)
+    monkeypatch.delenv("HOROVOD_NATIVE_LIB", raising=False)
+    assert any(os.path.exists(p) for p in basics._LIB_CANDIDATES)
+    with pytest.raises(OSError, match="building the native core failed"):
+        basics.load_library()

@@ -7,15 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.common import jax_compat
-
-if not jax_compat.HAS_NEW_SHARD_MAP:
-    # Legacy jax (<= 0.4.x): lowering the Pallas kernel on XLA-CPU
-    # aborts the process inside backend_compile (not a catchable
-    # Python error), which would take the whole test run down with it.
-    pytest.skip("Pallas flash-attention lowering aborts on legacy jax",
-                allow_module_level=True)
-
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import local_attention
 

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 
 from horovod_tpu.models import (
     TransformerConfig, init_transformer, transformer_forward, lm_loss,
@@ -84,6 +84,49 @@ def test_transformer_train_step_runs_sharded(devices):
     state, loss2 = step(state, batch)
     assert np.isfinite(float(loss1)) and np.isfinite(float(loss2))
     assert float(loss2) < float(loss1)  # overfits constant batch
+
+
+def test_train_step_adafactor_statistics_not_pinned_to_param_layout(devices):
+    """Adafactor's ``v_row``/``v_col`` share the params' tree but not
+    their shapes: only leaves that mirror a param take its sharding."""
+    import optax
+    cfg = TransformerConfig.tiny()
+    mesh = build_mesh(dp=2, fsdp=2, devices=devices[:4])
+    init_state, step, param_sh = make_train_step(
+        cfg, mesh, optax.adafactor(1e-2, min_dim_size_to_factor=32))
+    state = jax.jit(init_state)(jax.random.PRNGKey(0))
+    jax.tree.map(lambda a, sh: a.sharding.is_equivalent_to(sh, a.ndim)
+                 or pytest.fail(f"{a.shape}: {a.sharding} != {sh}"),
+                 state["params"], param_sh)
+    v_row = state["opt"][0].v_row
+    assert (jax.tree.leaves(v_row)[0].shape
+            != jax.tree.leaves(state["params"])[0].shape)
+    toks = jnp.zeros((4, 33), jnp.int32)
+    state, loss1 = step(state, {"tokens": toks})
+    state, loss2 = step(state, {"tokens": toks})
+    assert np.isfinite(float(loss1)) and float(loss2) < float(loss1)
+
+
+def test_train_step_factories_build_under_rbg_prng(devices):
+    """The factories size the abstract state from a key of the active
+    PRNG implementation (``rbg`` keys are ``(4,)``, not ``(2,)``)."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel.pipeline import make_pp_train_step
+    cfg = TransformerConfig.tiny(dtype=jnp.float32)
+    with jax.default_prng_impl("rbg"):
+        key = jax.random.PRNGKey(0)
+        assert key.shape == (4,)
+        for mesh, kw in ((build_mesh(dp=2, fsdp=2, devices=devices[:4]), {}),
+                         (build_mesh(dp=4, devices=devices[:4]),
+                          {"compression": hvd.Compression.int8}),
+                         (build_mesh(dp=2, fsdp=2, devices=devices[:4]),
+                          {"compression": hvd.Compression.int8})):
+            init_state, _, param_sh = make_train_step(cfg, mesh, **kw)
+        state = init_state(key)
+        assert (jax.tree.structure(state["params"])
+                == jax.tree.structure(param_sh))
+        make_pp_train_step(cfg, build_mesh(dp=2, pp=2, devices=devices[:4]),
+                           n_micro=2)
 
 
 # ~48s of CPU compile on the current CI box — the single heaviest

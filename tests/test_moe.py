@@ -221,10 +221,7 @@ def test_island_exact_fit_and_empty_experts(devices):
 def test_island_build_time_gates(devices):
     """Misconfigurations must raise at BUILD time with the mesh in
     hand, not mid-trace: E not divisible by ep, batch not divisible by
-    ep, and (on legacy jax) a non-ep axis > 1 under the full-manual
-    fallback."""
-    from horovod_tpu.common import jax_compat
-
+    ep."""
     mesh = build_mesh(ep=-1)
     cfg6 = moe_lib.MoEConfig(n_experts=6, top_k=1)
     with pytest.raises(ValueError, match="divide"):
@@ -232,12 +229,6 @@ def test_island_build_time_gates(devices):
     cfg, lp, x = _island_case()
     with pytest.raises(ValueError, match="batch"):
         moe_lib.moe_ffn_island(x[:5], lp, cfg, mesh, codec="int8")
-    if not jax_compat.HAS_NEW_SHARD_MAP:
-        wide = build_mesh(dp=2, ep=4)
-        cfg4 = moe_lib.MoEConfig(n_experts=8, top_k=1)
-        with pytest.raises(ValueError, match="full-manual"):
-            moe_lib.make_moe_ffn(cfg4, wide, dispatch="island",
-                                 codec="int8")
 
 
 def test_resolve_moe_knobs_env_and_validation(monkeypatch):

@@ -8,17 +8,6 @@ import numpy as np
 import optax
 import pytest
 
-from horovod_tpu.common import jax_compat
-
-if not jax_compat.HAS_NEW_SHARD_MAP:
-    # Legacy jax: the pp islands are partial-manual (axis_names={pp})
-    # and differentiate through shard_map — old SPMD partitioning
-    # rejects the axis_index lowering (PartitionId) and old shard_map
-    # autodiff raises NotImplementedError. Training-path limitation of
-    # the 0.4.x fallback, documented in common/jax_compat.py.
-    pytest.skip("pipeline islands need modern shard_map",
-                allow_module_level=True)
-
 from horovod_tpu.models import transformer as tr
 from horovod_tpu.parallel import build_mesh
 from horovod_tpu.parallel import pipeline as pl

@@ -4,8 +4,9 @@ test_host_kernels.py. Pins, on XLA-CPU shard_map meshes:
 
 * the blockwise int8 codec bitwise against a numpy reference and its
   per-block error bound (scale/2);
-* jit/no-jit + run-to-run bitwise determinism of the quantized
-  allreduce at np=1/2/4;
+* run-to-run bitwise determinism of the quantized allreduce at
+  np=1/2/4, and jit/no-jit bitwise for the cast codecs (int8 to an f32
+  ULP: XLA-CPU fuses its decode multiply into the fold as an FMA);
 * the EF telescoping identity (time-average of the quantized mean of a
   FIXED gradient converges to the true mean ~1/T);
 * narrow-dtype collective operands in the traced program (the
@@ -24,7 +25,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu.ops as hops
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from horovod_tpu.common.ops_enum import Average, Max, Sum
 from horovod_tpu.compression import Compression
 from horovod_tpu.ops.quantized import (
@@ -37,6 +38,16 @@ from horovod_tpu.ops.quantized import (
 )
 
 jax.config.update("jax_platform_name", "cpu")
+
+
+def gathered_shard_map(f, **kw):
+    """``shard_map`` for bodies that return a quantized collective's
+    result under a replicated ``out_specs``: the value leaves
+    ops/quantized.py through ``lax.all_gather``, which jax types as
+    varying although every rank holds the same bytes, so the VMA
+    checker cannot see the replication (no public varying->invariant
+    cast exists)."""
+    return shard_map(f, check_vma=False, **kw)
 
 
 def _mesh(n: int) -> Mesh:
@@ -123,20 +134,32 @@ def _det_params():
             yield pytest.param(n, codec, id=f"{codec}-{n}", marks=marks)
 
 
+def _assert_jit_matches_eager(codec, nojit, jitted):
+    if codec == "int8":
+        np.testing.assert_allclose(nojit, jitted, rtol=1e-6, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(nojit, jitted)
+
+
 @pytest.mark.parametrize("n,codec", _det_params())
 def test_allreduce_close_and_bitwise_deterministic(n, codec):
     """Value within codec tolerance of the true mean, and bitwise
-    identical jit vs no-jit and run-to-run at every mesh shape (the
-    native plane's thread-invariance contract, mesh edition)."""
+    identical run-to-run and jit vs no-jit at every mesh shape (the
+    native plane's thread-invariance contract, mesh edition). int8 alone
+    holds jit vs no-jit to a few f32 ULPs: under jit XLA-CPU contracts
+    its decode multiply into the peer fold as an FMA (single rounding),
+    which the op-by-op eager shard_map cannot (the same slack
+    test_reduce_scatter_residual_reconstructs_exactly documents). The
+    cast codecs have no multiply to contract and stay bitwise."""
     rng = np.random.RandomState(n * 31)
     xs = jnp.asarray(rng.randn(n, 3, 113).astype(np.float32))
-    f = shard_map(
+    f = gathered_shard_map(
         lambda v: quantized_allreduce(v[0], op=Average, axis_name="dp",
                                       codec=codec),
         mesh=_mesh(n), in_specs=P("dp"), out_specs=P())
     nojit = np.asarray(f(xs))
     jitted = np.asarray(jax.jit(f)(xs))
-    np.testing.assert_array_equal(nojit, jitted)
+    _assert_jit_matches_eager(codec, nojit, jitted)
     np.testing.assert_array_equal(jitted, np.asarray(jax.jit(f)(xs)))
     want = np.asarray(xs, np.float64).mean(0)
     amax = np.abs(want).max()
@@ -211,7 +234,8 @@ def test_reduce_scatter_codec_none_bitwise_psum_slice(mesh8):
 @pytest.mark.parametrize("codec", ["bf16", "int8"])
 def test_reduce_scatter_codecs_close_and_deterministic(codec):
     """Each rank's slice lands within codec tolerance of the true sum,
-    bitwise identical jit vs no-jit, on a non-leading scatter axis."""
+    bitwise run-to-run and jit vs no-jit (int8: to a few f32 ULPs, the
+    FMA slack above), on a non-leading scatter axis."""
     n = 2
     rng = np.random.RandomState(17)
     x = jnp.asarray(rng.randn(n, 3, 8, 70).astype(np.float32))
@@ -221,7 +245,8 @@ def test_reduce_scatter_codecs_close_and_deterministic(codec):
         mesh=_mesh(n), in_specs=P("dp"), out_specs=P("dp"))
     nojit = np.asarray(f(x))
     jitted = np.asarray(jax.jit(f)(x))
-    np.testing.assert_array_equal(nojit, jitted)
+    _assert_jit_matches_eager(codec, nojit, jitted)
+    np.testing.assert_array_equal(jitted, np.asarray(jax.jit(f)(x)))
     want = np.stack(np.split(np.asarray(x, np.float64).sum(0), n, axis=1))
     tol = {"bf16": 2 ** -6, "int8": 0.04}[codec]
     np.testing.assert_allclose(jitted, want,
@@ -304,7 +329,7 @@ def test_ef_telescoping_time_average_converges():
                                       codec="int8", residual=r[0])
         return out, nr[None]
 
-    f = jax.jit(shard_map(step, mesh=_mesh(n),
+    f = jax.jit(gathered_shard_map(step, mesh=_mesh(n),
                           in_specs=(P("dp"), P("dp")),
                           out_specs=(P(), P("dp"))))
     r = jnp.zeros((n, 515), jnp.float32)
@@ -327,7 +352,7 @@ def test_ef_without_residual_does_not_telescope():
     g = jnp.asarray(np.random.RandomState(11).randn(n, 515)
                     .astype(np.float32))
     true = np.asarray(g, np.float64).mean(0)
-    f = jax.jit(shard_map(
+    f = jax.jit(gathered_shard_map(
         lambda v: quantized_allreduce(v[0], op=Average, axis_name="dp",
                                       codec="int8"),
         mesh=_mesh(n), in_specs=P("dp"), out_specs=P()))
@@ -345,7 +370,8 @@ def test_ef_without_residual_does_not_telescope():
 
 def _collect_collectives(jaxpr, acc):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in ("all_to_all", "all_gather"):
+        if eqn.primitive.name in ("all_to_all", "reduce_scatter",
+                                  "all_gather"):
             acc.append((eqn.primitive.name,
                         [v.aval.dtype for v in eqn.invars]))
         for v in eqn.params.values():
@@ -359,18 +385,19 @@ def _collect_collectives(jaxpr, acc):
                                           ("bf16", jnp.bfloat16)])
 def test_traced_program_ships_narrow_collective_operands(codec, narrow):
     """The acceptance assertion: the traced quantized allreduce
-    contains a reduce-scatter hop (all_to_all) AND an all-gather whose
+    contains a reduce-scatter hop (all_to_all + fold for int8, one
+    native psum_scatter for the cast codecs) AND an all-gather whose
     payload operands are the narrow wire dtype — the compression is in
     the XLA graph, not a python-side cast."""
-    f = shard_map(
+    f = gathered_shard_map(
         lambda v: quantized_allreduce(v[0], op=Average, axis_name="dp",
                                       codec=codec),
         mesh=_mesh(2), in_specs=P("dp"), out_specs=P())
     colls = _collect_collectives(
         jax.make_jaxpr(f)(jnp.zeros((2, 600), jnp.float32)).jaxpr, [])
-    a2a = [dts for nm, dts in colls if nm == "all_to_all"]
+    rs = [dts for nm, dts in colls if nm in ("all_to_all", "reduce_scatter")]
     ag = [dts for nm, dts in colls if nm == "all_gather"]
-    assert any(narrow in dts for dts in a2a), colls
+    assert any(narrow in dts for dts in rs), colls
     assert any(narrow in dts for dts in ag), colls
 
 
@@ -409,7 +436,7 @@ def test_collectives_allreduce_accepts_compression():
                      .astype(np.float32))
     want = np.asarray(xs, np.float64).mean(0)
     for comp, tol in ((Compression.bf16, 2 ** -6), (Compression.int8, 0.04)):
-        f = jax.jit(shard_map(
+        f = jax.jit(gathered_shard_map(
             lambda v: hops.allreduce(v[0], op=Average, axis_name="dp",
                                      compression=comp),
             mesh=_mesh(n), in_specs=P("dp"), out_specs=P()))
@@ -432,7 +459,7 @@ def test_collectives_grouped_allreduce_accepts_compression():
     tree = {"a": jnp.asarray(np.random.RandomState(6).randn(n, 40)
                              .astype(np.float32)),
             "b": (jnp.ones((n, 3, 5), jnp.float32),)}
-    f = jax.jit(shard_map(
+    f = jax.jit(gathered_shard_map(
         lambda t: hops.grouped_allreduce(
             jax.tree.map(lambda v: v[0], t), op=Sum, axis_name="dp",
             compression=Compression.int8),
@@ -470,12 +497,12 @@ def test_distributed_optimizer_int8_threads_ef_state():
             acc = acc + upd["w"]
         return acc / 8, s["ef"]["w"][None]
 
-    f = jax.jit(shard_map(run, mesh=_mesh(n),
+    f = jax.jit(gathered_shard_map(run, mesh=_mesh(n),
                           in_specs=(P("dp"),), out_specs=(P(), P("dp"))))
     avg_upd, ef = f(g)
     # sgd(1.0) updates are -grad: the time-average must sit much closer
     # to -mean than one quantized shot's error scale.
-    single = jax.jit(shard_map(
+    single = jax.jit(gathered_shard_map(
         lambda v: quantized_allreduce(v[0], op=Average, axis_name="dp",
                                       codec="int8"),
         mesh=_mesh(n), in_specs=P("dp"), out_specs=P()))(g)
@@ -507,7 +534,7 @@ def test_distributed_optimizer_accumulation_with_int8():
         u2, s = opt.update({"w": v[0]}, s, p)
         return u1["w"], u2["w"], ef_after_hold[None], s["ef"]["w"][None]
 
-    f = jax.jit(shard_map(run, mesh=_mesh(n), in_specs=(P("dp"),),
+    f = jax.jit(gathered_shard_map(run, mesh=_mesh(n), in_specs=(P("dp"),),
                           out_specs=(P(), P(), P("dp"), P("dp"))))
     g = jnp.asarray(np.random.RandomState(2).randn(n, 64)
                     .astype(np.float32))
@@ -532,7 +559,7 @@ def test_value_and_grad_applies_compression():
 
     dvg = hvd.distributed_value_and_grad(
         loss_fn, axis_name="dp", compression=hvd.Compression.int8)
-    loss, g = jax.jit(shard_map(
+    loss, g = jax.jit(gathered_shard_map(
         lambda w, x: dvg(w, x[0]), mesh=_mesh(n),
         in_specs=(P(), P("dp")), out_specs=(P(), P())))(w0, xs)
     want_g = 2 * (np.asarray(w0) - np.asarray(xs)).mean(0) / 50

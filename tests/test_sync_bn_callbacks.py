@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
