@@ -381,35 +381,39 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
     B, T = x.shape[0], x.shape[1]
     pos = jnp.arange(T) + pos_offset
 
-    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, T, H, Dh)
-    kk = (h @ lp["wk"]).reshape(B, T, Hkv, Dh)
-    vv = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
-    q = _rope(q, pos, cfg.rope_theta)
-    kk = _rope(kk, pos, cfg.rope_theta)
-    if Hkv != H and not getattr(attend, "handles_gqa", False):
-        # GQA: tile kv heads up to H for impls that need square heads
-        # (flash reads grouped K/V natively and skips this copy).
-        rep = H // Hkv
-        kk = jnp.repeat(kk, rep, axis=2)
-        vv = jnp.repeat(vv, rep, axis=2)
-    o = attend(q, kk, vv).reshape(B, T, H * Dh)
-    x = x + (o @ lp["wo"]).astype(cfg.dtype)
-    x = constrain(x, ("dp", "fsdp"), "sp", None)
+    with jax.named_scope("attn"):
+        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        q = (h @ lp["wq"]).reshape(B, T, H, Dh)
+        kk = (h @ lp["wk"]).reshape(B, T, Hkv, Dh)
+        vv = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
+        q = _rope(q, pos, cfg.rope_theta)
+        kk = _rope(kk, pos, cfg.rope_theta)
+        if Hkv != H and not getattr(attend, "handles_gqa", False):
+            # GQA: tile kv heads up to H for impls that need square
+            # heads (flash reads grouped K/V natively and skips this
+            # copy).
+            rep = H // Hkv
+            kk = jnp.repeat(kk, rep, axis=2)
+            vv = jnp.repeat(vv, rep, axis=2)
+        o = attend(q, kk, vv).reshape(B, T, H * Dh)
+        x = x + (o @ lp["wo"]).astype(cfg.dtype)
+        x = constrain(x, ("dp", "fsdp"), "sp", None)
 
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        if moe_fn is None:
-            y, aux = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        if cfg.moe is not None:
+            if moe_fn is None:
+                y, aux = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
+            else:
+                y, aux = moe_fn(h, lp["moe"])
+            x = x + y.astype(cfg.dtype)
         else:
-            y, aux = moe_fn(h, lp["moe"])
-        x = x + y.astype(cfg.dtype)
-    else:
-        g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-        u = (h @ lp["w_up"]).astype(jnp.float32)
-        x = x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
-        aux = jnp.zeros((), jnp.float32)
-    x = constrain(x, ("dp", "fsdp"), "sp", None)
+            g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+            u = (h @ lp["w_up"]).astype(jnp.float32)
+            x = x + ((g * u).astype(cfg.dtype)
+                     @ lp["w_down"]).astype(cfg.dtype)
+            aux = jnp.zeros((), jnp.float32)
+        x = constrain(x, ("dp", "fsdp"), "sp", None)
     return x, aux
 
 
@@ -428,8 +432,9 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
                                    codec=cfg.moe_compression)
               if cfg.moe is not None else None)
 
-    x = embed_lookup(params["embed"], tokens, cfg.dtype, mesh)
-    x = constrain(x, ("dp", "fsdp"), "sp", None)
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], tokens, cfg.dtype, mesh)
+        x = constrain(x, ("dp", "fsdp"), "sp", None)
 
     def layer(x, lp):
         return decoder_layer(cfg, attend, constrain, x, lp,
@@ -441,9 +446,11 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
 
     x, auxes = lax.scan(layer, x, params["layers"],
                         unroll=cfg.scan_unroll)
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
-    return constrain(logits, ("dp", "fsdp"), "sp", "tp"), auxes.sum()
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = x @ params["lm_head"]
+        logits = constrain(logits, ("dp", "fsdp"), "sp", "tp")
+    return logits, auxes.sum()
 
 
 def forward(params, tokens, cfg: TransformerConfig,
@@ -459,9 +466,10 @@ def lm_loss(params, batch, cfg: TransformerConfig,
     tokens = batch["tokens"]
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
     logits, aux = forward_with_aux(params, inp, cfg, mesh)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return nll.mean() + aux
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        return nll.mean() + aux
 
 
 # ---------------------------------------------------------------------------
@@ -513,9 +521,10 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer=None, *,
     def step(state, batch):
         loss, grads = jax.value_and_grad(lm_loss)(
             state["params"], batch, cfg, mesh)
-        updates, new_opt = optimizer.update(
-            grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
         return {"params": params, "opt": new_opt,
                 "step": state["step"] + 1}, loss
 
@@ -660,8 +669,9 @@ def _make_dp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
         loss = lax.pmean(loss, "dp")
         # Identical (all-gathered) reduced grads on every shard ->
         # the replicated update keeps params bitwise in sync.
-        updates, opt = optimizer.update(grads, opt, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt = optimizer.update(grads, opt, params)
+            params = optax.apply_updates(params, updates)
         return params, opt, ef, loss
 
     # check_vma=False: the reduced gradients leave quantized_allreduce
@@ -869,9 +879,10 @@ def _make_fsdp_quantized_train_step(cfg: TransformerConfig, mesh: Mesh,
         loss, grads, new_ef = smapped(state["params"],
                                       state.get("ef", {}),
                                       batch["tokens"])
-        updates, new_opt = optimizer.update(grads, state["opt"],
-                                            state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state["opt"],
+                                                state["params"])
+            params = optax.apply_updates(state["params"], updates)
         new_state = {"params": params, "opt": new_opt,
                      "step": state["step"] + 1}
         if use_ef:

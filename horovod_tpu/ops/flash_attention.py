@@ -159,6 +159,9 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        # The kernel's name in a device trace (PERF.md §3); a Pallas
+        # backward takes "hvd_flash_bwd".
+        name="hvd_flash_fwd",
     )(q, k, v)
     return out[:, :t], lse[:, 0, :t]
 
@@ -290,7 +293,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, q_per_kv,
                residuals, g):
-    return _bwd(scale, causal, residuals, g, q_per_kv=q_per_kv)
+    with jax.named_scope("flash_bwd"):
+        return _bwd(scale, causal, residuals, g, q_per_kv=q_per_kv)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -315,7 +319,9 @@ def _flash_lse_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, out_dtype,
                    q_per_kv, residuals, g):
     g_out, g_lse = g
-    return _bwd(scale, causal, residuals, g_out, g_lse, q_per_kv=q_per_kv)
+    with jax.named_scope("flash_bwd"):
+        return _bwd(scale, causal, residuals, g_out, g_lse,
+                    q_per_kv=q_per_kv)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -107,6 +107,20 @@ def _ffn(cfg, lp, x):
     return x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
 
 
+def _gather_pages(kc_l, vc_l, tables, shape, dtype, rep):
+    """Every page of each sequence through its block table(s)
+    (``[.., W, bs, Hkv, Dh]`` -> ``shape`` = ``[B, S, Hkv, Dh]``), in
+    the queries' dtype, K/V heads repeated across the GQA group: what
+    ``kv_gather`` names in a device trace."""
+    with jax.named_scope("kv_gather"):
+        kp = kc_l[tables].reshape(shape).astype(dtype)
+        vp = vc_l[tables].reshape(shape).astype(dtype)
+        if rep > 1:
+            kp = jnp.repeat(kp, rep, axis=2)
+            vp = jnp.repeat(vp, rep, axis=2)
+    return kp, vp
+
+
 def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
                    table_width: int, compression=None):
     """Build (prefill, prefill_resume, decode, inject, verify) jitted
@@ -140,6 +154,15 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
     rep = H // Hkv
     scale = Dh ** -0.5
 
+    # Every program names its parts for a device trace (`embed`, `attn`
+    # with `kv_write` / `kv_gather` inside it, `mlp`, `head`): the
+    # benchmark's per-layer metrics find them by these names (PERF.md
+    # §3), so a rename is a change to that interface.
+    def embed(params, tokens):
+        with jax.named_scope("embed"):
+            return tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
+                                       mesh, compression)
+
     def prefill(params, kc, vc, tokens, length, block_table):
         """tokens [Tp] (bucket-padded), length scalar i32 (real prompt
         length), block_table [table_width] i32. Returns (kc, vc,
@@ -149,35 +172,42 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         assert n_blk <= table_width, (
             f"prompt bucket {Tp} needs {n_blk} blocks > table width "
             f"{table_width}")
-        x = tf_lib.embed_lookup(params["embed"], tokens[None], cfg.dtype,
-                                mesh, compression)             # [1, Tp, D]
+        x = embed(params, tokens[None])                         # [1, Tp, D]
         pos = jnp.arange(Tp, dtype=jnp.int32)[None]            # [1, Tp]
 
         def body(x, per_layer):
             lp, kc_l, vc_l = per_layer
-            q, k, v = _qkv(cfg, lp, x, pos)
-            # Pages: the padded prompt is block-aligned, so the write
-            # is a plain blockwise scatter. Bucket blocks past the
-            # allocation land on the null block (id 0) — written
-            # garbage there is never read (attention masks by length).
-            kc_l = kc_l.at[block_table[:n_blk]].set(
-                k[0].reshape(n_blk, block_size, Hkv, Dh).astype(kc_l.dtype))
-            vc_l = vc_l.at[block_table[:n_blk]].set(
-                v[0].reshape(n_blk, block_size, Hkv, Dh).astype(vc_l.dtype))
-            kk, vv = k, v
-            if rep > 1:
-                kk = jnp.repeat(kk, rep, axis=2)
-                vv = jnp.repeat(vv, rep, axis=2)
-            o = local_attention(q, kk, vv, causal=True)
-            x = x + (o.reshape(1, Tp, H * Dh) @ lp["wo"]).astype(cfg.dtype)
-            x = _ffn(cfg, lp, x)
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(cfg, lp, x, pos)
+                # Pages: the padded prompt is block-aligned, so the
+                # write is a plain blockwise scatter. Bucket blocks past
+                # the allocation land on the null block (id 0) — written
+                # garbage there is never read (attention masks by
+                # length).
+                with jax.named_scope("kv_write"):
+                    kc_l = kc_l.at[block_table[:n_blk]].set(
+                        k[0].reshape(n_blk, block_size, Hkv, Dh).astype(
+                            kc_l.dtype))
+                    vc_l = vc_l.at[block_table[:n_blk]].set(
+                        v[0].reshape(n_blk, block_size, Hkv, Dh).astype(
+                            vc_l.dtype))
+                kk, vv = k, v
+                if rep > 1:
+                    kk = jnp.repeat(kk, rep, axis=2)
+                    vv = jnp.repeat(vv, rep, axis=2)
+                o = local_attention(q, kk, vv, causal=True)
+                x = x + (o.reshape(1, Tp, H * Dh)
+                         @ lp["wo"]).astype(cfg.dtype)
+            with jax.named_scope("mlp"):
+                x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
 
         x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        x_last = jnp.take(x[0], length - 1, axis=0)            # [D]
-        logits = (x_last @ params["lm_head"]).astype(jnp.float32)
-        return kc, vc, jnp.argmax(logits).astype(jnp.int32)
+        with jax.named_scope("head"):
+            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            x_last = jnp.take(x[0], length - 1, axis=0)        # [D]
+            logits = (x_last @ params["lm_head"]).astype(jnp.float32)
+            return kc, vc, jnp.argmax(logits).astype(jnp.int32)
 
     def prefill_resume(params, kc, vc, tokens, offset, length, block_table):
         """One prefill *chunk* starting at block-aligned token
@@ -202,8 +232,7 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         Tc = tokens.shape[0]
         n_blk = Tc // block_size
         S = table_width * block_size
-        x = tf_lib.embed_lookup(params["embed"], tokens[None], cfg.dtype,
-                                mesh, compression)             # [1, Tc, D]
+        x = embed(params, tokens[None])                         # [1, Tc, D]
         pos = offset + jnp.arange(Tc, dtype=jnp.int32)[None]   # [1, Tc]
         # Chunk rows land in table slots off_blk..off_blk+n_blk. Rows
         # whose slot falls past the table (bucket padding of the last
@@ -219,38 +248,44 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
 
         def body(x, per_layer):
             lp, kc_l, vc_l = per_layer
-            q, k, v = _qkv(cfg, lp, x, pos)
-            kc_l = kc_l.at[blks].set(
-                k[0].reshape(n_blk, block_size, Hkv, Dh).astype(kc_l.dtype))
-            vc_l = vc_l.at[blks].set(
-                v[0].reshape(n_blk, block_size, Hkv, Dh).astype(vc_l.dtype))
-            # Gather every page of this sequence (its table; unused
-            # entries hold the null block) and mask by global position:
-            # key j visible to query at global position p iff j <= p.
-            # All such keys are real — the prefix was written before
-            # this chunk ran, the chunk's own keys one line up.
-            kp = kc_l[block_table].reshape(1, S, Hkv, Dh).astype(q.dtype)
-            vp = vc_l[block_table].reshape(1, S, Hkv, Dh).astype(q.dtype)
-            if rep > 1:
-                kp = jnp.repeat(kp, rep, axis=2)
-                vp = jnp.repeat(vp, rep, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                           preferred_element_type=jnp.float32) * scale
-            kpos = jnp.arange(S, dtype=jnp.int32)
-            mask = kpos[None, :] <= pos[0][:, None]            # [Tc, S]
-            s = jnp.where(mask[None, None], s, _NEG_BIG)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                           preferred_element_type=jnp.float32).astype(q.dtype)
-            x = x + (o.reshape(1, Tc, H * Dh) @ lp["wo"]).astype(cfg.dtype)
-            x = _ffn(cfg, lp, x)
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(cfg, lp, x, pos)
+                with jax.named_scope("kv_write"):
+                    kc_l = kc_l.at[blks].set(
+                        k[0].reshape(n_blk, block_size, Hkv, Dh).astype(
+                            kc_l.dtype))
+                    vc_l = vc_l.at[blks].set(
+                        v[0].reshape(n_blk, block_size, Hkv, Dh).astype(
+                            vc_l.dtype))
+                # Gather every page of this sequence (its table; unused
+                # entries hold the null block) and mask by global
+                # position: key j visible to query at global position p
+                # iff j <= p. All such keys are real — the prefix was
+                # written before this chunk ran, the chunk's own keys
+                # one line up.
+                kp, vp = _gather_pages(kc_l, vc_l, block_table,
+                                       (1, S, Hkv, Dh), q.dtype, rep)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
+                               preferred_element_type=jnp.float32) * scale
+                kpos = jnp.arange(S, dtype=jnp.int32)
+                mask = kpos[None, :] <= pos[0][:, None]        # [Tc, S]
+                s = jnp.where(mask[None, None], s, _NEG_BIG)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
+                               preferred_element_type=jnp.float32).astype(
+                                   q.dtype)
+                x = x + (o.reshape(1, Tc, H * Dh)
+                         @ lp["wo"]).astype(cfg.dtype)
+            with jax.named_scope("mlp"):
+                x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
 
         x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        x_last = jnp.take(x[0], length - 1, axis=0)            # [D]
-        logits = (x_last @ params["lm_head"]).astype(jnp.float32)
-        return kc, vc, jnp.argmax(logits).astype(jnp.int32)
+        with jax.named_scope("head"):
+            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            x_last = jnp.take(x[0], length - 1, axis=0)        # [D]
+            logits = (x_last @ params["lm_head"]).astype(jnp.float32)
+            return kc, vc, jnp.argmax(logits).astype(jnp.int32)
 
     def decode(params, kc, vc, tokens, positions, block_tables):
         """One continuous-batching step. tokens [B] (each sequence's
@@ -262,50 +297,55 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         next_tokens [B])."""
         B = tokens.shape[0]
         S = table_width * block_size
-        x = tf_lib.embed_lookup(params["embed"], tokens[:, None], cfg.dtype,
-                                mesh, compression)             # [B, 1, D]
+        x = embed(params, tokens[:, None])                      # [B, 1, D]
         pos = positions[:, None]
 
         def body(x, per_layer):
             lp, kc_l, vc_l = per_layer
-            q, k, v = _qkv(cfg, lp, x, pos)
-            # Positions past the table (a speculative draft's proposal
-            # frontier near a sequence's cap) route to the null block.
-            # The unguarded take_along_axis would CLAMP the slot and
-            # overwrite the sequence's last real block instead.
-            slot = positions // block_size                     # [B]
-            blk = jnp.take_along_axis(
-                block_tables,
-                jnp.minimum(slot, table_width - 1)[:, None], axis=1)[:, 0]
-            blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
-            phys = blk * block_size + positions % block_size   # [B]
-            flat = (-1, Hkv, Dh)
-            kc_l = kc_l.reshape(flat).at[phys].set(
-                k[:, 0].astype(kc_l.dtype)).reshape(kc_l.shape)
-            vc_l = vc_l.reshape(flat).at[phys].set(
-                v[:, 0].astype(vc_l.dtype)).reshape(vc_l.shape)
-            # Gather this batch's pages through the block tables:
-            # [B, W, bs, Hkv, Dh] -> [B, S, Hkv, Dh].
-            kp = kc_l[block_tables].reshape(B, S, Hkv, Dh).astype(q.dtype)
-            vp = vc_l[block_tables].reshape(B, S, Hkv, Dh).astype(q.dtype)
-            if rep > 1:
-                kp = jnp.repeat(kp, rep, axis=2)
-                vp = jnp.repeat(vp, rep, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                           preferred_element_type=jnp.float32) * scale
-            mask = jnp.arange(S, dtype=jnp.int32)[None] <= positions[:, None]
-            s = jnp.where(mask[:, None, None, :], s, _NEG_BIG)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                           preferred_element_type=jnp.float32).astype(q.dtype)
-            x = x + (o.reshape(B, 1, H * Dh) @ lp["wo"]).astype(cfg.dtype)
-            x = _ffn(cfg, lp, x)
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(cfg, lp, x, pos)
+                # Positions past the table (a speculative draft's
+                # proposal frontier near a sequence's cap) route to the
+                # null block. The unguarded take_along_axis would CLAMP
+                # the slot and overwrite the sequence's last real block
+                # instead.
+                with jax.named_scope("kv_write"):
+                    slot = positions // block_size             # [B]
+                    blk = jnp.take_along_axis(
+                        block_tables,
+                        jnp.minimum(slot, table_width - 1)[:, None],
+                        axis=1)[:, 0]
+                    blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
+                    phys = blk * block_size + positions % block_size  # [B]
+                    flat = (-1, Hkv, Dh)
+                    kc_l = kc_l.reshape(flat).at[phys].set(
+                        k[:, 0].astype(kc_l.dtype)).reshape(kc_l.shape)
+                    vc_l = vc_l.reshape(flat).at[phys].set(
+                        v[:, 0].astype(vc_l.dtype)).reshape(vc_l.shape)
+                # Gather this batch's pages through the block tables:
+                # [B, W, bs, Hkv, Dh] -> [B, S, Hkv, Dh].
+                kp, vp = _gather_pages(kc_l, vc_l, block_tables,
+                                       (B, S, Hkv, Dh), q.dtype, rep)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
+                               preferred_element_type=jnp.float32) * scale
+                mask = (jnp.arange(S, dtype=jnp.int32)[None]
+                        <= positions[:, None])
+                s = jnp.where(mask[:, None, None, :], s, _NEG_BIG)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
+                               preferred_element_type=jnp.float32).astype(
+                                   q.dtype)
+                x = x + (o.reshape(B, 1, H * Dh)
+                         @ lp["wo"]).astype(cfg.dtype)
+            with jax.named_scope("mlp"):
+                x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
 
         x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-        return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def verify(params, kc, vc, tokens, positions, block_tables):
         """Speculative verification (see serve/speculative.py): one
@@ -329,47 +369,50 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         emitted). Returns (kc, vc, out [B, C])."""
         B, C = tokens.shape
         S = table_width * block_size
-        x = tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
-                                mesh, compression)             # [B, C, D]
+        x = embed(params, tokens)                               # [B, C, D]
         pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
 
         def body(x, per_layer):
             lp, kc_l, vc_l = per_layer
-            q, k, v = _qkv(cfg, lp, x, pos)
-            slot = pos // block_size                           # [B, C]
-            blk = jnp.take_along_axis(
-                block_tables, jnp.minimum(slot, table_width - 1), axis=1)
-            blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
-            phys = (blk * block_size + pos % block_size).reshape(-1)
-            flat = (-1, Hkv, Dh)
-            kc_l = kc_l.reshape(flat).at[phys].set(
-                k.reshape(-1, Hkv, Dh).astype(kc_l.dtype)).reshape(
-                    kc_l.shape)
-            vc_l = vc_l.reshape(flat).at[phys].set(
-                v.reshape(-1, Hkv, Dh).astype(vc_l.dtype)).reshape(
-                    vc_l.shape)
-            kp = kc_l[block_tables].reshape(B, S, Hkv, Dh).astype(q.dtype)
-            vp = vc_l[block_tables].reshape(B, S, Hkv, Dh).astype(q.dtype)
-            if rep > 1:
-                kp = jnp.repeat(kp, rep, axis=2)
-                vp = jnp.repeat(vp, rep, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                           preferred_element_type=jnp.float32) * scale
-            kpos = jnp.arange(S, dtype=jnp.int32)
-            mask = kpos[None, None, :] <= pos[:, :, None]      # [B, C, S]
-            s = jnp.where(mask[:, None], s, _NEG_BIG)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                           preferred_element_type=jnp.float32).astype(
-                               q.dtype)
-            x = x + (o.reshape(B, C, H * Dh) @ lp["wo"]).astype(cfg.dtype)
-            x = _ffn(cfg, lp, x)
+            with jax.named_scope("attn"):
+                q, k, v = _qkv(cfg, lp, x, pos)
+                with jax.named_scope("kv_write"):
+                    slot = pos // block_size                   # [B, C]
+                    blk = jnp.take_along_axis(
+                        block_tables, jnp.minimum(slot, table_width - 1),
+                        axis=1)
+                    blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
+                    phys = (blk * block_size
+                            + pos % block_size).reshape(-1)
+                    flat = (-1, Hkv, Dh)
+                    kc_l = kc_l.reshape(flat).at[phys].set(
+                        k.reshape(-1, Hkv, Dh).astype(kc_l.dtype)).reshape(
+                            kc_l.shape)
+                    vc_l = vc_l.reshape(flat).at[phys].set(
+                        v.reshape(-1, Hkv, Dh).astype(vc_l.dtype)).reshape(
+                            vc_l.shape)
+                kp, vp = _gather_pages(kc_l, vc_l, block_tables,
+                                       (B, S, Hkv, Dh), q.dtype, rep)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
+                               preferred_element_type=jnp.float32) * scale
+                kpos = jnp.arange(S, dtype=jnp.int32)
+                mask = kpos[None, None, :] <= pos[:, :, None]  # [B, C, S]
+                s = jnp.where(mask[:, None], s, _NEG_BIG)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
+                               preferred_element_type=jnp.float32).astype(
+                                   q.dtype)
+                x = x + (o.reshape(B, C, H * Dh)
+                         @ lp["wo"]).astype(cfg.dtype)
+            with jax.named_scope("mlp"):
+                x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
 
         x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = (x @ params["lm_head"]).astype(jnp.float32)   # [B, C, V]
-        return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            logits = (x @ params["lm_head"]).astype(jnp.float32)  # [B,C,V]
+            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def inject(kc, vc, blocks, k_pages, v_pages):
         """Scatter handed-off prompt pages into this pool (the

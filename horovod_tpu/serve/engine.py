@@ -76,6 +76,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
@@ -164,6 +165,11 @@ class RequestResult:
     reason: Optional[str] = None
     deadline_class: int = 0
     retry_after_s: Optional[float] = None
+    # Engine-clock time of each token produced on THIS engine (the end
+    # of the prefill or decode span it came out of): the gaps a client
+    # sees. Not carried over RPC or a handoff, where ages travel, not
+    # times; a handed-off sequence lists the tokens it produced here.
+    token_times: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def first_token_latency_s(self) -> Optional[float]:
@@ -207,6 +213,8 @@ class _Seq:
     deadline_class: int = 0
     prefill_only: bool = False
     trace: int = 0               # distributed trace id (0 = unsampled)
+    admitted_at: Optional[float] = None   # left the queue (engine clock)
+    token_times: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def last_token(self) -> int:
@@ -546,14 +554,24 @@ class ServeEngine:
 
     def step(self) -> None:
         """One iteration: retire → expire → admit → prefill chunk(s)
-        → decode."""
-        now = self._clock()
-        self._retire_finished(now)
-        self._expire_queued(now)
-        self._admit(now)
+        → decode. Each part is a :meth:`ServeMetrics.phase` span
+        (docs/observability.md); a step with nothing to do records
+        nothing."""
+        if not self.pending:
+            return
+        m = self.metrics
+        with m.phase("serve:schedule") as ph:
+            now = ph.t0
+            m.record_step(now)
+            ph.args.update(retired=self._retire_finished(now),
+                           expired=self._expire_queued(now),
+                           admitted=self._admit(now))
+            ph.args["queue"] = len(self._queue)
+        if not (self._prefilling or self._active):
+            m.record_idle()
         self._advance_prefills()
         self._decode_once()
-        self.metrics.record_queue_depth(len(self._queue))
+        m.record_queue_depth(len(self._queue))
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> None:
         for _ in range(max_steps):
@@ -581,20 +599,40 @@ class ServeEngine:
             tokens=list(seq.generated), n_prompt=len(seq.prompt),
             submitted_at=seq.submitted_at,
             first_token_at=seq.first_token_at, finished_at=now,
-            deadline_class=seq.deadline_class)
+            deadline_class=seq.deadline_class,
+            token_times=seq.token_times)
         self._retire_ema.observe(now)
         self.metrics.record_finished()
+        # What one client saw, on one span: the gaps are between the
+        # tokens produced here (a handed-off sequence's earlier tokens
+        # and its queue wait belong to the replica that had them).
+        args: Dict[str, Any] = {"n_prompt": len(seq.prompt),
+                                "n_out": len(seq.generated)}
+        if seq.trace:
+            args["trace"] = seq.trace
+        if seq.admitted_at is not None:
+            args["queue_ms"] = 1e3 * (seq.admitted_at - seq.submitted_at)
+        if seq.first_token_at is not None:
+            args["ttft_ms"] = 1e3 * (seq.first_token_at - seq.submitted_at)
+        ts = seq.token_times
+        if len(ts) > 1:
+            args["itl_mean_ms"] = 1e3 * (ts[-1] - ts[0]) / (len(ts) - 1)
+            args["itl_max_ms"] = 1e3 * max(
+                b - a for a, b in zip(ts, ts[1:]))
+        self.metrics.record_request(seq.submitted_at, now, **args)
 
-    def _retire_finished(self, now: float) -> None:
+    def _retire_finished(self, now: float) -> int:
         still = []
         for seq in self._active:
             if seq.finished(self.cfg.eos_id):
                 self._finish(seq, now)
             else:
                 still.append(seq)
+        n_retired = len(self._active) - len(still)
         self._active = still
+        return n_retired
 
-    def _expire_queued(self, now: float) -> None:
+    def _expire_queued(self, now: float) -> int:
         keep: collections.deque[_Queued] = collections.deque()
         for req in self._queue:
             if req.deadline is not None and now > req.deadline:
@@ -612,17 +650,20 @@ class ServeEngine:
                 self.metrics.record_expired()
             else:
                 keep.append(req)
+        n_expired = len(self._queue) - len(keep)
         self._queue = keep
+        return n_expired
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> int:
         batch_was_empty = not self._active and not self._prefilling
+        n_admitted = 0
         while (self._queue and
                len(self._active) + len(self._prefilling)
                < self.cfg.max_batch):
             if self.cfg.scheduling == "static" and not batch_was_empty:
                 # Baseline scheduler: wait for the whole batch to
                 # drain before admitting again.
-                return
+                break
             req = self._queue[0]
             plen = len(req.prompt)
             # A prefill-only sequence never decodes here — it writes
@@ -656,7 +697,7 @@ class ServeEngine:
             if not self.allocator.can_alloc(need - n_match + n_revive):
                 # KV backpressure (FIFO: no overtaking, so tail
                 # latency stays predictable under load).
-                return
+                break
             self._queue.popleft()
             # Commit: nothing mutated between peek and acquire, so
             # the same blocks resolve — and hits (plus the one
@@ -681,7 +722,10 @@ class ServeEngine:
                 chain=req.chain, registered=len(matched),
                 deadline_class=req.deadline_class,
                 prefill_only=req.prefill_only,
-                trace=req.trace))
+                trace=req.trace, admitted_at=now))
+            self.metrics.record_admitted(req.submitted_at, now, req.trace)
+            n_admitted += 1
+        return n_admitted
 
     def _advance_prefills(self) -> None:
         """Run prefill chunks FIFO across admitted-but-incomplete
@@ -707,10 +751,11 @@ class ServeEngine:
                     chunk -= chunk % self.cfg.block_size
                     if chunk == 0:
                         break
-            spent += self._run_prefill_chunk(seq, chunk)
+            done_at = self._run_prefill_chunk(seq, chunk)
+            spent += chunk
             if seq.n_cached >= len(seq.prompt):
                 self._prefilling.pop(0)
-                self._complete_prefill(seq)
+                self._complete_prefill(seq, done_at)
 
     def _extend_prefix_match(self, seq: _Seq) -> None:
         """Retry the cache walk just before prefilling. Admission in a
@@ -746,33 +791,37 @@ class ServeEngine:
         if extended:
             self.metrics.record_prefix_extend(extended)
 
-    def _run_prefill_chunk(self, seq: _Seq, chunk: int) -> int:
-        import jax
-
+    def _run_prefill_chunk(self, seq: _Seq, chunk: int) -> float:
+        """Run one chunk; returns when its host sync ended (engine
+        clock: the end of its ``serve:prefill`` span)."""
         plen = len(seq.prompt)
         offset = seq.n_cached
         toks = np.zeros(pick_bucket(chunk, self._prefill_buckets), np.int32)
         toks[:chunk] = seq.prompt[offset:offset + chunk]
-        t0 = self._clock()
-        with jax.profiler.TraceAnnotation("serve:prefill"):
-            if offset == 0 and chunk == plen:
-                # Whole cold prompt: the monolithic program (exactly
-                # the pre-cache code path, and the cheaper attention —
-                # prompt-local instead of a full table gather).
-                kc, vc, tok = self._prefill_fn(
-                    self._params, self.cache.k, self.cache.v, toks,
-                    np.int32(plen), seq.table)
-            else:
-                kc, vc, tok = self._resume_fn(
-                    self._params, self.cache.k, self.cache.v, toks,
-                    np.int32(offset), np.int32(chunk), seq.table)
-            tok = int(tok)  # host sync — the step is done when this is
-        dur = self._clock() - t0
+        m = self.metrics
+        extra = {"trace": seq.trace} if seq.trace else {}
+        with m.phase("serve:prefill", device=True, n_tokens=chunk,
+                     offset=offset, **extra) as ph:
+            with TraceAnnotation("serve:prefill:dispatch"):
+                if offset == 0 and chunk == plen:
+                    # Whole cold prompt: the monolithic program (exactly
+                    # the pre-cache code path, and the cheaper attention
+                    # — prompt-local instead of a full table gather).
+                    kc, vc, tok = self._prefill_fn(
+                        self._params, self.cache.k, self.cache.v, toks,
+                        np.int32(plen), seq.table)
+                else:
+                    kc, vc, tok = self._resume_fn(
+                        self._params, self.cache.k, self.cache.v, toks,
+                        np.int32(offset), np.int32(chunk), seq.table)
+            ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
+            with TraceAnnotation("serve:prefill:sync"):
+                tok = int(tok)  # host sync — the step is done when this is
         self.cache.k, self.cache.v = kc, vc
         seq.n_cached = offset + chunk
         seq.last_prefill_tok = tok
-        self.metrics.record_prefill(t0, dur, chunk, offset=offset,
-                                    trace=seq.trace)
+        m.record_prefill(ph.t0, ph.dur, chunk, offset=offset,
+                         trace=seq.trace)
         if self.cfg.prefix_caching:
             # Publish the prompt blocks this chunk filled. A losing
             # race (hash already published by a concurrent twin) keeps
@@ -781,11 +830,13 @@ class ServeEngine:
             for i in range(seq.registered, n_full):
                 self.allocator.register(seq.blocks[i], seq.chain[i])
             seq.registered = max(seq.registered, n_full)
-        return chunk
+        return ph.end
 
-    def _complete_prefill(self, seq: _Seq) -> None:
-        now = self._clock()
+    def _complete_prefill(self, seq: _Seq, now: float) -> None:
+        """``now``: the end of the prefill span that produced the first
+        token."""
         seq.generated.append(seq.last_prefill_tok)
+        seq.token_times.append(now)
         seq.first_token_at = now
         self.metrics.record_first_token(now - seq.submitted_at)
         if seq.finished(self.cfg.eos_id):
@@ -1034,8 +1085,6 @@ class ServeEngine:
             self.allocator.free(st["blocks"])
 
     def _decode_once(self) -> None:
-        import jax
-
         if not self._active:
             return
         if self._spec is not None:
@@ -1046,26 +1095,35 @@ class ServeEngine:
             # retirement, handoff all run unchanged above/below it.
             self._spec.round()
             return
+        m = self.metrics
         n = len(self._active)
-        bucket = pick_bucket(n, self._batch_buckets)
-        tokens = np.zeros(bucket, np.int32)
-        positions = np.zeros(bucket, np.int32)
-        tables = np.zeros((bucket, self._table_width), np.int32)
-        for i, seq in enumerate(self._active):
-            tokens[i] = seq.last_token
-            positions[i] = seq.n_cached
-            tables[i] = seq.table
-        t0 = self._clock()
-        with jax.profiler.TraceAnnotation("serve:decode"):
-            kc, vc, out = self._decode_fn(
-                self._params, self.cache.k, self.cache.v, tokens,
-                positions, tables)
-            out = np.asarray(out)  # host sync
-        dur = self._clock() - t0
-        self.cache.k, self.cache.v = kc, vc
-        for i, seq in enumerate(self._active):
-            seq.n_cached += 1
-            seq.generated.append(int(out[i]))
-        self.metrics.record_decode(
-            t0, dur, n, self.cfg.max_batch,
-            traces=[s.trace for s in self._active if s.trace])
+        with m.phase("serve:decode_prep"):
+            bucket = pick_bucket(n, self._batch_buckets)
+            tokens = np.zeros(bucket, np.int32)
+            positions = np.zeros(bucket, np.int32)
+            tables = np.zeros((bucket, self._table_width), np.int32)
+            for i, seq in enumerate(self._active):
+                tokens[i] = seq.last_token
+                positions[i] = seq.n_cached
+                tables[i] = seq.table
+        # A decode step serves the whole batch, so it carries the
+        # trace ids of every sampled sequence in it (plural key).
+        traces = [s.trace for s in self._active if s.trace]
+        extra = {"traces": traces} if traces else {}
+        with m.phase("serve:decode", device=True, n_active=n,
+                     **extra) as ph:
+            with TraceAnnotation("serve:decode:dispatch"):
+                kc, vc, out = self._decode_fn(
+                    self._params, self.cache.k, self.cache.v, tokens,
+                    positions, tables)
+            ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
+            with TraceAnnotation("serve:decode:sync"):
+                out = np.asarray(out)  # host sync
+        with m.phase("serve:decode_post"):
+            self.cache.k, self.cache.v = kc, vc
+            for i, seq in enumerate(self._active):
+                seq.n_cached += 1
+                seq.generated.append(int(out[i]))
+                seq.token_times.append(ph.end)
+            m.record_decode(ph.t0, ph.dur, n, self.cfg.max_batch,
+                            traces=traces)

@@ -13,20 +13,27 @@ Surfaces:
 * :meth:`ServeMetrics.export_chrome_trace` — per-step spans in the
   chrome-tracing JSON format, viewable in the same ``chrome://tracing``
   / Perfetto UI as the host timeline (``hvd.start_timeline`` /
-  ``horovodrun --timeline-filename``). Engine steps additionally run
-  under ``jax.profiler.TraceAnnotation`` (see ``engine.py``) so device
-  traces show ``serve:prefill`` / ``serve:decode`` phases with the
-  same names — the convention :mod:`horovod_tpu.ops.xla_exec` uses for
-  collectives.
+  ``horovodrun --timeline-filename``).
+* :meth:`ServeMetrics.phase` — how the engine writes those spans: one
+  host phase is one span on the engine's clock AND a
+  ``jax.profiler.TraceAnnotation`` of the same name, opened and closed
+  at the same two points. Every program span therefore has a twin in
+  a profiler trace's host plane, and the offset between the engine's
+  clock and the trace's is the difference of any twin pair
+  (docs/observability.md lists the spans and their args).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import itertools
 import json
 import os
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: Keep at most this many latency samples per series (drop-oldest);
 #: long-running engines must not grow without bound.
@@ -40,6 +47,21 @@ def percentile(samples: List[float], q: float) -> Optional[float]:
     xs = sorted(samples)
     idx = min(len(xs) - 1, max(0, int(round(q / 100.0 * (len(xs) - 1)))))
     return xs[idx]
+
+
+class Phase:
+    """What :meth:`ServeMetrics.phase` yields: the span's start on the
+    engine's clock, its args (the caller may add to them, also after
+    the span closed) and, once closed, its duration."""
+
+    __slots__ = ("t0", "dur", "args")
+
+    def __init__(self, t0: float, args: dict):
+        self.t0, self.dur, self.args = t0, 0.0, args
+
+    @property
+    def end(self) -> float:
+        return self.t0 + self.dur
 
 
 #: Process-wide monotonic default for the per-engine ``instance``
@@ -97,7 +119,15 @@ class ServeMetrics:
         self.spec_verify_s: List[float] = []
         self.first_token_s: List[float] = []
         self.per_token_s: List[float] = []
-        self._events: List[dict] = []
+        # Drop-oldest: an engine that has run for an hour exports its
+        # most recent spans, not its first ones.
+        self._events: collections.deque = collections.deque(
+            maxlen=MAX_SAMPLES)
+        # Where the last device call's host sync ended (engine clock),
+        # and whether a step() began since: `serve:host_gap` runs from
+        # there to the next device call's first line.
+        self._device_idle_since: Optional[float] = None
+        self._stepped_since = False
         # Allocator counters are lifetime totals; baseline them here
         # so snapshots report the same window as every other counter
         # in this object (reset-to-now), not engine-lifetime numbers.
@@ -117,45 +147,85 @@ class ServeMetrics:
 
     # -- recording ---------------------------------------------------
 
-    def _span(self, name: str, t0: float, dur: float, **args) -> None:
-        # chrome-tracing "complete" event; ts/dur in microseconds.
-        # Same cap as the latency series: a long-running engine must
-        # not grow host memory step by step.
-        if len(self._events) >= MAX_SAMPLES:
-            return
-        ts = round((t0 - self.started_at) * 1e6, 1)
+    def _span(self, name: str, t0: float, dur: float, args: dict) -> None:
+        # chrome-tracing "complete" event; ts/dur in microseconds. The
+        # event keeps `args` itself, not a copy: a phase's caller may
+        # complete them after the span closed (a speculative round
+        # knows what was accepted only after its verify step).
         self._events.append({
             "name": name, "ph": "X", "pid": 0, "tid": 0,
-            "ts": ts, "dur": round(dur * 1e6, 1), "args": args})
-        if (self._allocator is not None
-                and len(self._events) < MAX_SAMPLES):
-            # Pool occupancy as a counter track next to the spans:
-            # live blocks vs warm (refcount-0 cached) blocks per step.
+            "ts": round((t0 - self.started_at) * 1e6, 1),
+            "dur": round(dur * 1e6, 1), "args": args})
+
+    @contextlib.contextmanager
+    def phase(self, name: str, device: bool = False, **args):
+        """One host phase of the engine under two clocks at once: a
+        ``TraceAnnotation(name)`` for a profiler trace's host plane and
+        a chrome span of the same name on the engine's clock, read at
+        the same two points. Yields the :class:`Phase` (``t0``, and
+        ``args`` to add to); ``dur`` is set when the block ends, and
+        the span is written then (not if the block raised).
+
+        ``device=True`` marks a phase that dispatches a device program
+        and ends in its host sync: the time since the previous such
+        phase ended is written as ``serve:host_gap`` (chrome span only,
+        after the fact), with ``across_steps`` saying whether a
+        ``step()`` began in between (then it holds the caller's time
+        too)."""
+        with TraceAnnotation(name):
+            span = Phase(self._clock(), args)
+            yield span
+            span.dur = self._clock() - span.t0
+        if device:
+            if self._device_idle_since is not None:
+                self._span("serve:host_gap", self._device_idle_since,
+                           span.t0 - self._device_idle_since,
+                           {"across_steps": self._stepped_since})
+            self._device_idle_since = span.end
+            self._stepped_since = False
+        self._span(name, span.t0, span.dur, span.args)
+
+    def record_step(self, now: float) -> None:
+        """A scheduler iteration with work to do began at ``now``:
+        pool occupancy goes on a counter track next to the spans (live
+        blocks vs warm refcount-0 cached blocks), once a step."""
+        self._stepped_since = True
+        if self._allocator is not None:
             self._events.append({
                 "name": "kv_blocks", "ph": "C", "pid": 0, "tid": 0,
-                "ts": ts, "args": {"in_use": self._allocator.n_used,
-                                   "cached": self._allocator.n_cached}})
+                "ts": round((now - self.started_at) * 1e6, 1),
+                "args": {"in_use": self._allocator.n_used,
+                         "cached": self._allocator.n_cached}})
+
+    def record_idle(self) -> None:
+        """The engine ran out of work: the wait for the next request is
+        nobody's host gap."""
+        self._device_idle_since = None
 
     def record_queue_depth(self, depth: int) -> None:
         self.queue_depth = depth
         self.max_queue_depth = max(self.max_queue_depth, depth)
 
-    def _pool_gauges(self) -> dict:
-        a = self._allocator
-        if a is None:
-            return {}
-        return {"blocks_in_use": a.n_used, "blocks_cached": a.n_cached}
+    def record_admitted(self, submitted_at: float, now: float,
+                        trace: int = 0) -> None:
+        """One request left the queue for a batch slot: its wait is the
+        ``serve:queue`` span (chrome span only, after the fact)."""
+        self._span("serve:queue", submitted_at, now - submitted_at,
+                   {"trace": trace} if trace else {})
+
+    def record_request(self, submitted_at: float, finished_at: float,
+                       **args) -> None:
+        """One finished request, submission to last token, as the
+        ``serve:request`` span (chrome span only, after the fact)."""
+        self._span("serve:request", submitted_at,
+                   finished_at - submitted_at, args)
 
     def record_prefill(self, t0: float, dur_s: float, n_tokens: int,
                        offset: int = 0, trace: int = 0) -> None:
-        """One prefill chunk of ``n_tokens`` starting at token
-        ``offset`` (0 + whole prompt = the monolithic case);
-        ``trace`` is the request's distributed trace id (0 =
-        unsampled, omitted from the span)."""
+        """Count one prefill chunk of ``n_tokens`` starting at token
+        ``offset``. The ``serve:prefill`` span itself is written by the
+        :meth:`phase` the engine ran the chunk under."""
         self.prefill_steps += 1
-        extra = {"trace": trace} if trace else {}
-        self._span("serve:prefill", t0, dur_s, n_tokens=n_tokens,
-                   offset=offset, **extra, **self._pool_gauges())
 
     def record_prefix_lookup(self, hit_tokens: int,
                              suffix_tokens: int) -> None:
@@ -174,6 +244,9 @@ class ServeMetrics:
 
     def record_decode(self, t0: float, dur_s: float, n_active: int,
                       max_batch: int, traces=None) -> None:
+        """Count one decode step of ``dur_s`` for ``n_active``
+        sequences. The ``serve:decode`` span itself is written by the
+        :meth:`phase` the engine ran the step under."""
         self.decode_steps += 1
         self.tokens_generated += n_active
         self._occupancy_sum += n_active / max_batch
@@ -181,11 +254,6 @@ class ServeMetrics:
             # Every active sequence advanced one token this step, so
             # the step wall time IS the per-token latency sample.
             self.per_token_s.append(dur_s)
-        # A decode step serves the whole batch, so it carries the
-        # trace ids of every sampled sequence in it (plural key).
-        extra = {"traces": list(traces)} if traces else {}
-        self._span("serve:decode", t0, dur_s, n_active=n_active,
-                   **extra, **self._pool_gauges())
 
     def record_spec_round(self, t0: float, draft_dur_s: float,
                           verify_dur_s: float, n_active: int,
@@ -193,8 +261,9 @@ class ServeMetrics:
                           accepted: int, emitted: int,
                           traces=None) -> None:
         """One speculative iteration: the k batched draft decode steps
-        (one span) plus the single chunked verify step, with the
-        round's proposal/acceptance tallies. Feeds the same
+        plus the single chunked verify step (one :meth:`phase` span
+        each, written by the round), with the round's
+        proposal/acceptance tallies. Feeds the same
         throughput/occupancy series a plain decode step feeds so
         tokens/sec and batch_occupancy compare across speculative and
         plain engines; the per-token latency sample is the round wall
@@ -213,12 +282,6 @@ class ServeMetrics:
             self.spec_draft_s.append(draft_dur_s)
         if len(self.spec_verify_s) < MAX_SAMPLES:
             self.spec_verify_s.append(verify_dur_s)
-        extra = {"traces": list(traces)} if traces else {}
-        self._span("serve:spec_draft", t0, draft_dur_s,
-                   n_active=n_active, proposed=proposed, **extra)
-        self._span("serve:spec_verify", t0 + draft_dur_s, verify_dur_s,
-                   accepted=accepted, emitted=emitted, **extra,
-                   **self._pool_gauges())
 
     def record_first_token(self, latency_s: float) -> None:
         # The first token comes out of prefill, not a decode step —
@@ -364,6 +427,6 @@ class ServeMetrics:
         :meth:`trace_metadata` anchor so merged fleet views can
         re-anchor the spans onto one timebase."""
         with open(path, "w") as f:
-            json.dump({"traceEvents": self._events,
+            json.dump({"traceEvents": list(self._events),
                        "displayTimeUnit": "ms",
                        "metadata": self.trace_metadata(**extra)}, f)

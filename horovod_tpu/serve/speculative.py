@@ -230,8 +230,6 @@ class SpecDecoder:
         k batched draft decode steps propose, one target verify step
         checks, host-side acceptance emits 1..k tokens per sequence
         and rewinds past rejected positions (cursor-only rollback)."""
-        import jax
-
         eng = self._eng
         active = eng._active
         if not active:
@@ -250,8 +248,11 @@ class SpecDecoder:
             frontier[i] = seq.last_token
             positions[i] = seq.n_cached
         proposals = np.zeros((n, self.k), np.int64)
-        t0 = eng._clock()
-        with jax.profiler.TraceAnnotation("serve:spec_draft"):
+        traces = [s.trace for s in active if s.trace]
+        extra = {"traces": traces} if traces else {}
+        m = eng.metrics
+        with m.phase("serve:spec_draft", device=True, n_active=n,
+                     **extra) as draft:
             for step in range(self.k):
                 kc, vc, out = self._decode_fn(
                     self._params, self.cache.k, self.cache.v, frontier,
@@ -261,7 +262,6 @@ class SpecDecoder:
                 proposals[:, step] = out[:n]
                 frontier = out.copy()
                 positions = positions + 1
-        t1 = eng._clock()
 
         # -- verify: ONE chunked target step over reserved pages ------
         chunk = np.zeros((bucket, self.k), np.int32)
@@ -272,12 +272,11 @@ class SpecDecoder:
             chunk[i, 1:] = proposals[i, :self.k - 1]
             vpos[i] = seq.n_cached
             t_tables[i] = seq.table
-        with jax.profiler.TraceAnnotation("serve:spec_verify"):
+        with m.phase("serve:spec_verify", device=True, **extra) as verify:
             kc, vc, ver = eng._verify_fn(
                 eng._params, eng.cache.k, eng.cache.v, chunk, vpos,
                 t_tables)
             ver = np.asarray(ver)
-        t2 = eng._clock()
         eng.cache.k, eng.cache.v = kc, vc
 
         # -- accept + cursor rollback, host-side ----------------------
@@ -305,16 +304,18 @@ class SpecDecoder:
             # draft is judged by.
             proposed_total += n_acc + (1 if n_acc < len(emitted) else 0)
             seq.generated.extend(emitted)
+            seq.token_times.extend([verify.end] * len(emitted))
             # The rollback: rejected chunk positions stay past the
             # cursor; table and pool untouched.
             seq.n_cached += len(emitted)
             emitted_total += len(emitted)
             accepted_total += n_acc
-        eng.metrics.record_spec_round(
-            t0, t1 - t0, t2 - t1, n, eng.cfg.max_batch,
+        draft.args["proposed"] = proposed_total
+        verify.args.update(accepted=accepted_total, emitted=emitted_total)
+        m.record_spec_round(
+            draft.t0, draft.dur, verify.dur, n, eng.cfg.max_batch,
             proposed=proposed_total, accepted=accepted_total,
-            emitted=emitted_total,
-            traces=[s.trace for s in active if s.trace])
+            emitted=emitted_total, traces=traces)
 
 
 # ---------------------------------------------------------------------------

@@ -329,6 +329,14 @@ def test_spec_metrics_snapshot_and_exposition(served_model):
     # Draft/verify spans ride the chrome trace next to decode's.
     names = {e["name"] for e in eng.metrics._events}
     assert {"serve:spec_draft", "serve:spec_verify"} <= names
+    # ... with the round's tallies, which are known only after it.
+    by = {n: [e["args"] for e in eng.metrics._events if e["name"] == n]
+          for n in names}
+    assert sum(a["proposed"] for a in by["serve:spec_draft"]) == \
+        snap["spec_proposed_total"]
+    assert sum(a["accepted"] for a in by["serve:spec_verify"]) == \
+        snap["spec_accepted_total"]
+    assert all(a["emitted"] >= 1 for a in by["serve:spec_verify"])
     # A plain engine's snapshot carries the keys too (zeros), so fleet
     # rollups can sum mixed fleets without key checks.
     plain = _mk_engine(served_model)
