@@ -1,6 +1,6 @@
 """jit'd prefill + decode step functions over the paged KV cache.
 
-Two compiled programs drive all serving traffic:
+These compiled programs drive all serving traffic:
 
 * :func:`prefill` — run one prompt (padded to a length bucket) through
   the transformer, write its K/V into the sequence's cache blocks, and
@@ -19,8 +19,10 @@ Two compiled programs drive all serving traffic:
   append its K/V at the sequence's current position through the block
   table, attend against the gathered pages, and emit the next token
   per sequence.
+* ``verify`` — the speculative chunk step: ``decode``'s addressing
+  with ``prefill_resume``'s mask over a few tokens per sequence.
 
-Both are shape-bucketed (see ``kv_cache.pick_bucket``) so the jit
+All are shape-bucketed (see ``kv_cache.pick_bucket``) so the jit
 cache holds a handful of programs total — batch membership, sequence
 lengths, and block placement all change per step without recompiling.
 
@@ -35,10 +37,19 @@ program, on ICI).
 
 Numerics match ``models.transformer`` deliberately: reused
 ``_rmsnorm``/``embed_lookup``, the same unfused q/k/v/gate/up
-projections, f32 softmax and silu, ``local_attention``'s einsum
-order — so incremental decode tracks the full-context forward to
-float tolerance, and served decode is bit-identical to single-request
-decode (same programs, row-independent math).
+projections, f32 softmax and silu — so incremental decode tracks the
+full-context forward to float tolerance, and served decode is
+bit-identical to single-request decode (same programs,
+row-independent math).
+
+Attention over the cache is one function, :func:`_attend_pages`, for
+``prefill_resume``, ``decode`` and ``verify``: the pages are gathered
+through the block table once, in the cache's dtype and with their Hkv
+heads (never repeated across the GQA group, never copied to float32),
+and contracted with the queries grouped over KV heads — the group is
+a free dimension of both dots — with float32 scores, softmax and
+accumulators. Only the monolithic ``prefill`` attends prompt-locally
+through ``local_attention``.
 """
 
 from __future__ import annotations
@@ -107,18 +118,39 @@ def _ffn(cfg, lp, x):
     return x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
 
 
-def _gather_pages(kc_l, vc_l, tables, shape, dtype, rep):
-    """Every page of each sequence through its block table(s)
-    (``[.., W, bs, Hkv, Dh]`` -> ``shape`` = ``[B, S, Hkv, Dh]``), in
-    the queries' dtype, K/V heads repeated across the GQA group: what
-    ``kv_gather`` names in a device trace."""
+def _attend_pages(q, kc_l, vc_l, tables, pos):
+    """Attention of one query chunk per sequence over all of the
+    sequence's pages: the one paged attention of ``prefill_resume``
+    (B = 1), ``decode`` (C = 1) and ``verify``.
+
+    ``q`` [B, C, H, Dh] (post-rope); ``kc_l``/``vc_l`` one layer's pool
+    [n_blocks, bs, Hkv, Dh]; ``tables`` [B, W] block ids (unused
+    entries hold the null block); ``pos`` [B, C] the queries' global
+    positions. Returns [B, C, H * Dh] in ``q``'s dtype.
+
+    Each page is read once, in the cache's dtype and with its Hkv
+    heads: the GQA group (``rep`` = H // Hkv, 1 for MHA) is a free
+    dimension of both dots, so a (sequence, KV head) pair is one
+    ``[rep * C, Dh] x [Dh, S]`` matmul, not ``rep`` vector products
+    over a repeated copy of K and V. Scores, softmax and accumulators
+    are float32; key j is visible to the query at global position p
+    iff j <= p, and every such key is real: a prefix written before
+    this call, or the chunk's own keys written by ``kv_write`` just
+    before it."""
+    B, C, H, Dh = q.shape
+    Hkv, S = kc_l.shape[2], tables.shape[1] * kc_l.shape[1]
     with jax.named_scope("kv_gather"):
-        kp = kc_l[tables].reshape(shape).astype(dtype)
-        vp = vc_l[tables].reshape(shape).astype(dtype)
-        if rep > 1:
-            kp = jnp.repeat(kp, rep, axis=2)
-            vp = jnp.repeat(vp, rep, axis=2)
-    return kp, vp
+        kp = kc_l[tables].reshape(B, S, Hkv, Dh)
+        vp = vc_l[tables].reshape(B, S, Hkv, Dh)
+    qg = q.reshape(B, C, Hkv, H // Hkv, Dh)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kp,
+                   preferred_element_type=jnp.float32) * Dh ** -0.5
+    mask = jnp.arange(S, dtype=jnp.int32) <= pos[:, :, None]     # [B, C, S]
+    s = jnp.where(mask[:, None, None], s, _NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(vp.dtype), vp,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    return o.reshape(B, C, H * Dh)
 
 
 def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
@@ -152,7 +184,6 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
                       compression=None):
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     rep = H // Hkv
-    scale = Dh ** -0.5
 
     # Every program names its parts for a device trace (`embed`, `attn`
     # with `kv_write` / `kv_gather` inside it, `mlp`, `head`): the
@@ -231,7 +262,6 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         """
         Tc = tokens.shape[0]
         n_blk = Tc // block_size
-        S = table_width * block_size
         x = embed(params, tokens[None])                         # [1, Tc, D]
         pos = offset + jnp.arange(Tc, dtype=jnp.int32)[None]   # [1, Tc]
         # Chunk rows land in table slots off_blk..off_blk+n_blk. Rows
@@ -257,25 +287,8 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
                     vc_l = vc_l.at[blks].set(
                         v[0].reshape(n_blk, block_size, Hkv, Dh).astype(
                             vc_l.dtype))
-                # Gather every page of this sequence (its table; unused
-                # entries hold the null block) and mask by global
-                # position: key j visible to query at global position p
-                # iff j <= p. All such keys are real — the prefix was
-                # written before this chunk ran, the chunk's own keys
-                # one line up.
-                kp, vp = _gather_pages(kc_l, vc_l, block_table,
-                                       (1, S, Hkv, Dh), q.dtype, rep)
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                               preferred_element_type=jnp.float32) * scale
-                kpos = jnp.arange(S, dtype=jnp.int32)
-                mask = kpos[None, :] <= pos[0][:, None]        # [Tc, S]
-                s = jnp.where(mask[None, None], s, _NEG_BIG)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                               preferred_element_type=jnp.float32).astype(
-                                   q.dtype)
-                x = x + (o.reshape(1, Tc, H * Dh)
-                         @ lp["wo"]).astype(cfg.dtype)
+                o = _attend_pages(q, kc_l, vc_l, block_table[None], pos)
+                x = x + (o @ lp["wo"]).astype(cfg.dtype)
             with jax.named_scope("mlp"):
                 x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
@@ -295,8 +308,6 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         their lane writes and reads only touch the null block and
         their outputs are discarded by the engine. Returns (kc, vc,
         next_tokens [B])."""
-        B = tokens.shape[0]
-        S = table_width * block_size
         x = embed(params, tokens[:, None])                      # [B, 1, D]
         pos = positions[:, None]
 
@@ -322,21 +333,8 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
                         k[:, 0].astype(kc_l.dtype)).reshape(kc_l.shape)
                     vc_l = vc_l.reshape(flat).at[phys].set(
                         v[:, 0].astype(vc_l.dtype)).reshape(vc_l.shape)
-                # Gather this batch's pages through the block tables:
-                # [B, W, bs, Hkv, Dh] -> [B, S, Hkv, Dh].
-                kp, vp = _gather_pages(kc_l, vc_l, block_tables,
-                                       (B, S, Hkv, Dh), q.dtype, rep)
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                               preferred_element_type=jnp.float32) * scale
-                mask = (jnp.arange(S, dtype=jnp.int32)[None]
-                        <= positions[:, None])
-                s = jnp.where(mask[:, None, None, :], s, _NEG_BIG)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                               preferred_element_type=jnp.float32).astype(
-                                   q.dtype)
-                x = x + (o.reshape(B, 1, H * Dh)
-                         @ lp["wo"]).astype(cfg.dtype)
+                o = _attend_pages(q, kc_l, vc_l, block_tables, pos)
+                x = x + (o @ lp["wo"]).astype(cfg.dtype)
             with jax.named_scope("mlp"):
                 x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
@@ -367,8 +365,7 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         outputs are compared then discarded host-side (acceptance
         truncates at max_new before any such position can be
         emitted). Returns (kc, vc, out [B, C])."""
-        B, C = tokens.shape
-        S = table_width * block_size
+        C = tokens.shape[1]
         x = embed(params, tokens)                               # [B, C, D]
         pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
 
@@ -391,19 +388,8 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
                     vc_l = vc_l.reshape(flat).at[phys].set(
                         v.reshape(-1, Hkv, Dh).astype(vc_l.dtype)).reshape(
                             vc_l.shape)
-                kp, vp = _gather_pages(kc_l, vc_l, block_tables,
-                                       (B, S, Hkv, Dh), q.dtype, rep)
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kp,
-                               preferred_element_type=jnp.float32) * scale
-                kpos = jnp.arange(S, dtype=jnp.int32)
-                mask = kpos[None, None, :] <= pos[:, :, None]  # [B, C, S]
-                s = jnp.where(mask[:, None], s, _NEG_BIG)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vp.dtype), vp,
-                               preferred_element_type=jnp.float32).astype(
-                                   q.dtype)
-                x = x + (o.reshape(B, C, H * Dh)
-                         @ lp["wo"]).astype(cfg.dtype)
+                o = _attend_pages(q, kc_l, vc_l, block_tables, pos)
+                x = x + (o @ lp["wo"]).astype(cfg.dtype)
             with jax.named_scope("mlp"):
                 x = _ffn(cfg, lp, x)
             return x, (kc_l, vc_l)
