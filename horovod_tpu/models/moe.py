@@ -13,6 +13,16 @@ Shapes (per layer): tokens ``[B, T, D]``, experts ``E``, per-group
 capacity ``C = ceil(k · T · capacity_factor / E)`` with groups = batch
 rows. Top-k (default 2) gating with the standard load-balancing
 auxiliary loss (Switch/GShard form).
+
+A configuration without a capacity (``capacity_factor=None``: OLMoE and
+the other fine-grained sparse decoders) takes :func:`moe_ffn_dropless`
+instead: the ``N·K`` (token, choice) pairs are sorted by expert, the
+experts run as one grouped matmul over the sorted rows
+(``lax.ragged_dot``, which the TPU compiler lowers to a Mosaic grouped
+matmul), and nothing is dropped. Its largest value is ``[N·K, D]``;
+the one-hot tensors above, whose size grows with ``E · C``, do not
+exist there. :func:`make_moe_ffn` picks between the two from the
+configuration alone.
 """
 
 from __future__ import annotations
@@ -38,8 +48,14 @@ MOE_DISPATCH_MODES = ("gspmd", "island")
 class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    #: ``None``: no capacity, no dropped token (the sorted dispatch).
+    capacity_factor: Optional[float] = 1.25
     aux_loss_coef: float = 0.01
+    #: Coefficient of the router z-loss, mean ``logsumexp(logits)²``.
+    z_loss_coef: float = 0.0
+    #: Renormalise the chosen gates to sum to 1 (GShard); ``False``
+    #: uses the softmax's own values (OLMoE's ``norm_topk_prob``).
+    norm_topk_prob: bool = True
 
 
 def capacity(cfg: MoEConfig, seq_len: int) -> int:
@@ -79,6 +95,17 @@ def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
     }
 
 
+def _top_k_gates(logits, cfg: MoEConfig):
+    """``(probs [.., E], gates [.., K], experts [.., K])`` of router
+    logits: softmax over all experts, the K largest, renormalised to
+    sum to 1 (GShard) unless the configuration says not to."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return probs, gates, experts
+
+
 def _route(x, router, cfg: MoEConfig, C: int):
     """GShard routing on ``x`` [B, T, D] (any batch slice): top-k
     gating, (t, k)-ordered capacity assignment, one-hot dispatch /
@@ -88,19 +115,14 @@ def _route(x, router, cfg: MoEConfig, C: int):
     island leans on exactly this property.
 
     Returns ``(dispatch [B,T,E,C], combine [B,T,E,C], probs [B,T,E],
-    top1 [B,T,E], sel [B,T,K,E], within [B,T,K,E])``.
+    top1 [B,T,E], sel [B,T,K,E], within [B,T,K,E], logits [B,T,E])``.
     """
     B, T, _D = x.shape
     E, K = cfg.n_experts, cfg.top_k
 
     logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), router)
-    probs = jax.nn.softmax(logits, axis=-1)            # [B, T, E]
-
-    # Top-k expert choice per token.
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)      # [B, T, K]
-    # Renormalize the chosen gates (GShard: combine weights sum to 1).
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    # Top-k expert choice per token: probs [B, T, E], the rest [B, T, K].
+    probs, gate_vals, gate_idx = _top_k_gates(logits, cfg)
 
     # Capacity positions: for the k-th choice, a token's slot in expert
     # e is the number of earlier (token-major, choice-major) claims on
@@ -121,7 +143,12 @@ def _route(x, router, cfg: MoEConfig, C: int):
                          gate_vals, within, slot_oh)
 
     top1 = jax.nn.one_hot(gate_idx[..., 0], E, dtype=jnp.float32)
-    return dispatch, combine, probs, top1, sel, within
+    return dispatch, combine, probs, top1, sel, within, logits
+
+
+def _z_loss(logits):
+    """Router z-loss: mean over tokens of ``logsumexp(logits)²``."""
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
 
 def _expert_ffn(xin, lp, dtype):
@@ -151,19 +178,154 @@ def moe_ffn(x, lp, cfg: MoEConfig):
     """
     E = cfg.n_experts
     C = capacity(cfg, x.shape[1])
-    dispatch, combine, probs, top1, _sel, _within = _route(
+    dispatch, combine, probs, top1, _sel, _within, logits = _route(
         x, lp["router"], cfg, C)
 
     # Load-balancing aux loss (Switch eq. 4): E * sum_e f_e * p_e with
     # f = fraction of tokens whose TOP-1 lands on e, p = mean prob.
     aux = cfg.aux_loss_coef * E * jnp.sum(
         top1.mean((0, 1)) * probs.mean((0, 1)))
+    if cfg.z_loss_coef:
+        aux = aux + cfg.z_loss_coef * _z_loss(logits)
 
     # To experts (ep all-to-all by GSPMD), run SwiGLU, and back.
     xin = jnp.einsum("btec,btd->ebcd", dispatch.astype(x.dtype), x)
     xout = _expert_ffn(xin, lp, x.dtype)
     y = jnp.einsum("btec,ebcd->btd", combine.astype(x.dtype), xout)
     return y.astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# The sorted, dropless dispatch (ISSUE 26): no capacity, no one-hot
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_sorted(x, order, inverse, K: int):
+    """Rows of ``x`` [N, D] in sorted (token, choice) order, [N·K, D].
+    ``order`` sorts the ``N·K`` pairs by expert and ``inverse`` undoes
+    it, so the cotangent is a gather too, ``g[inverse]`` summed over a
+    token's K choices: autodiff alone would scatter-add over tokens."""
+    return x[order // K]
+
+
+def _take_sorted_fwd(x, order, inverse, K):
+    return x[order // K], inverse
+
+
+def _take_sorted_bwd(K, inverse, g):
+    return (g[inverse].reshape(-1, K, g.shape[-1]).sum(1).astype(g.dtype),
+            None, None)
+
+
+_take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
+
+
+@jax.custom_vjp
+def _take_unsorted(y, order, inverse):
+    """Sorted rows ``y`` [N·K, D] back in (token, choice) order; the
+    cotangent is the gather ``g[order]``."""
+    return y[inverse]
+
+
+_take_unsorted.defvjp(lambda y, order, inverse: (y[inverse], order),
+                      lambda order, g: (g[order], None, None))
+
+
+def _router_logits(xf, router):
+    """Router logits [N, E] of tokens ``xf`` [N, D], float32 in
+    earnest: on the TPU a default-precision f32 matmul multiplies in
+    bf16, and near-ties of the top-k follow it."""
+    return jnp.dot(xf.astype(jnp.float32), router,
+                   precision=lax.Precision.HIGHEST)
+
+
+def _sorted_by_expert(experts, n_experts: int):
+    """``(order, group_sizes [E])``: the stable sort of the flat
+    ``[N·K]`` expert ids, and how many pairs fell on each expert (read
+    off the sorted ids, so no ``[N·K, E]`` one-hot exists)."""
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    ends = jnp.searchsorted(flat[order], jnp.arange(1, n_experts + 1,
+                                                    dtype=flat.dtype))
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    return order, sizes
+
+
+def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
+    """One MoE FFN block with no capacity and no dropped token. Same
+    signature and return as :func:`moe_ffn`.
+
+    Flatten to ``N = B·T`` tokens; router logits, softmax and top-k in
+    float32; stable-sort the ``N·K`` (token, choice) pairs by expert;
+    gather their rows into ``[N·K, D]``; the three SwiGLU matrices as
+    grouped matmuls over the E groups; un-sort, weight by the gates and
+    sum over K. ``aux`` is ``aux_loss_coef · E · Σ_e f_e·p_e`` with
+    ``f_e`` the share of the ``N·K`` choices that fell on expert e and
+    ``p_e`` its mean router probability, plus ``z_loss_coef`` times the
+    router z-loss.
+
+    ``token_axes`` names the mesh axes this call's tokens are one shard
+    of (inside :func:`make_moe_ffn`'s ``shard_map``): routing is per
+    token, so each shard sorts its own rows, and only the two means of
+    the auxiliary losses are taken over every shard.
+    """
+    B, T, D = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    xf = x.reshape(B * T, D)
+
+    def everywhere(v):                     # mean over all shards' tokens
+        return lax.pmean(v, token_axes) if token_axes else v
+
+    with jax.named_scope("moe_router"):
+        logits = _router_logits(xf, lp["router"])
+        probs, gates, experts = _top_k_gates(logits, cfg)
+    with jax.named_scope("moe_dispatch"):
+        order, sizes = _sorted_by_expert(experts, E)
+        inverse = jnp.argsort(order)
+        rows = _take_sorted(xf, order, inverse, K)            # [N·K, D]
+    with jax.named_scope("moe_router"):
+        f = everywhere(sizes.astype(jnp.float32) / (B * T * K))
+        aux = cfg.aux_loss_coef * E * jnp.sum(
+            f * everywhere(probs.mean(0)))
+        if cfg.z_loss_coef:
+            aux = aux + cfg.z_loss_coef * everywhere(_z_loss(logits))
+    with jax.named_scope("moe_experts"):
+        g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
+                        .astype(jnp.float32))
+        u = lax.ragged_dot(rows, lp["w_up"], sizes).astype(jnp.float32)
+        out = lax.ragged_dot((g * u).astype(x.dtype), lp["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        y = jnp.einsum(
+            "nkd,nk->nd",
+            _take_unsorted(out, order, inverse).reshape(B * T, K, D),
+            gates.astype(x.dtype))
+    return y.reshape(B, T, D).astype(x.dtype), aux
+
+
+def _dropless_over_mesh(cfg: MoEConfig, mesh):
+    """:func:`moe_ffn_dropless` for ``x`` laid out over ``mesh``: each
+    shard of the token axes (``dp``, ``fsdp``, ``sp``) routes its own
+    rows against the whole (gathered) expert matrices."""
+    shape = mesh.shape if mesh is not None else {}
+    if shape.get("ep", 1) > 1:
+        raise NotImplementedError(
+            "a MoE configuration without a capacity "
+            "(moe_capacity_factor=None) runs every expert on every chip: "
+            f"the sorted dispatch is not spread over ep={shape['ep']} yet "
+            "(ROADMAP B7). Use a mesh with ep=1, or set a capacity.")
+    axes = tuple(a for a in ("dp", "fsdp", "sp") if shape.get(a, 1) > 1)
+    if not axes:
+        return lambda x, lp: moe_ffn_dropless(x, lp, cfg)
+    batch = tuple(a for a in axes if a != "sp") or None
+    seq = "sp" if "sp" in axes else None
+
+    def shard(x, lp):
+        return moe_ffn_dropless(x, lp, cfg, token_axes=axes)
+
+    return jax.shard_map(shard, mesh=mesh,
+                         in_specs=(P(batch, seq, None), P()),
+                         out_specs=(P(batch, seq, None), P()),
+                         axis_names=set(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +386,7 @@ def _jitted_island(cfg: MoEConfig, mesh, codec: str):
     def island(xl, router, wg, wu, wd):
         b_loc, T, D = xl.shape
         C = capacity(cfg, T)
-        dispatch, combine, probs, top1, _sel, _within = _route(
+        dispatch, combine, probs, top1, _sel, _within, logits = _route(
             xl, router, cfg, C)
 
         # Aux loss from the GLOBAL f/p vectors (pmean of equal-sized
@@ -234,6 +396,8 @@ def _jitted_island(cfg: MoEConfig, mesh, codec: str):
         f = lax.pmean(top1.mean((0, 1)), "ep")
         pbar = lax.pmean(probs.mean((0, 1)), "ep")
         aux = cfg.aux_loss_coef * E * jnp.sum(f * pbar)
+        if cfg.z_loss_coef:
+            aux = aux + cfg.z_loss_coef * lax.pmean(_z_loss(logits), "ep")
 
         # Pack per-expert slabs for ALL E experts from local rows,
         # grouped by owner shard, and trade them: after the alltoall,
@@ -296,8 +460,14 @@ def make_moe_ffn(cfg: MoEConfig, mesh, *, dispatch: Optional[str] = None,
     island's restructuring. ``dispatch="island"`` with a lossy codec
     builds :func:`moe_ffn_island`; build-time failures (E not
     divisible by ep) raise HERE with the mesh in hand, not mid-trace.
+
+    A configuration without a capacity takes the sorted, dropless
+    :func:`moe_ffn_dropless` whatever the two knobs say: they choose
+    the wire of the one-hot dispatch over ``ep``, which it does not have.
     """
     d, c = resolve_moe_knobs(dispatch, codec)
+    if cfg.capacity_factor is None:
+        return _dropless_over_mesh(cfg, mesh)
     ep = mesh.shape.get("ep", 1) if mesh is not None else 1
     if d == "gspmd" or c == "none" or ep <= 1:
         return lambda x, lp: moe_ffn(x, lp, cfg)
@@ -319,35 +489,63 @@ MOE_METRIC_KEYS = (
     "moe_dispatch_overflow_tokens_total",
     "moe_dispatch_dropped_token_frac",
     "moe_dispatch_bytes_saved_pct",
+    "moe_expert_load_max_over_mean",
 )
 
 _moe_metrics: Dict[str, float] = {}
 _moe_metrics_lock = threading.Lock()
 
 
-def moe_routing_stats(x, router, cfg: MoEConfig) -> Dict[str, float]:
-    """Capacity-overflow telemetry for one batch: runs the exact
-    routing math of :func:`_route` (so the numbers describe what the
-    dispatch actually dropped, not an estimate) and returns
-
-    * ``moe_dispatch_overflow_tokens_total`` — (token, choice) claims
-      that landed past an expert's capacity this batch;
-    * ``moe_dispatch_dropped_token_frac`` — that count over the
-      ``B·T·k`` total claims.
-
-    Host-callable (no mesh needed — routing is per batch row); feed
-    the result to :func:`record_moe_stats` to accumulate into the
-    exported series.
-    """
+def routing_counts(x, router, cfg: MoEConfig):
+    """``(claims per expert [E], claims past capacity)`` of one batch
+    ``x`` [B, T, D], by the routing math of the dispatch the
+    configuration takes: :func:`_route`'s for the one-hot dispatch,
+    the top-k alone for the dropless one, which turns none away.
+    Jittable."""
+    if cfg.capacity_factor is None:
+        _, _, experts = _top_k_gates(
+            _router_logits(x.reshape(-1, x.shape[-1]), router), cfg)
+        _, sizes = _sorted_by_expert(experts, cfg.n_experts)
+        return sizes.astype(jnp.float32), jnp.zeros((), jnp.float32)
     C = capacity(cfg, x.shape[1])
-    _d, _c, _p, _t1, sel, within = _route(x, router, cfg, C)
-    claims = float(sel.sum())
-    overflow = claims - float(within.sum())
+    _d, _c, _p, _t1, sel, within, _l = _route(x, router, cfg, C)
+    return sel.sum((0, 1, 2)), sel.sum() - within.sum()
+
+
+def routing_summary(counts, overflow) -> Dict[str, float]:
+    """The exported series from :func:`routing_counts`' two values;
+    ``counts`` may carry leading (layer) dimensions, and the load ratio
+    reported is then the largest of them."""
+    claims = float(counts.sum())
+    overflow = float(overflow.sum())
     return {
         "moe_dispatch_overflow_tokens_total": overflow,
         "moe_dispatch_dropped_token_frac": (
             overflow / claims if claims else 0.0),
+        "moe_expert_load_max_over_mean": float(
+            (counts.max(-1) / jnp.maximum(counts.mean(-1), 1e-9)).max()),
     }
+
+
+def moe_routing_stats(x, router, cfg: MoEConfig) -> Dict[str, float]:
+    """Routing telemetry for one batch of one layer: runs the exact
+    routing math of the dispatch (so the numbers describe what it
+    actually dropped, not an estimate) and returns
+
+    * ``moe_dispatch_overflow_tokens_total`` — (token, choice) claims
+      that landed past an expert's capacity this batch (0 without a
+      capacity);
+    * ``moe_dispatch_dropped_token_frac`` — that count over the
+      ``B·T·k`` total claims;
+    * ``moe_expert_load_max_over_mean`` — claims on the fullest expert
+      over the mean claims per expert (1.0 is an even load).
+
+    Host-callable (no mesh needed — routing is per batch row); feed
+    the result to :func:`record_moe_stats` to accumulate into the
+    exported series. ``transformer.moe_routing_report`` gives the same
+    keys for every layer of a model on a batch of tokens.
+    """
+    return routing_summary(*routing_counts(x, router, cfg))
 
 
 def _render_moe_metrics() -> str:

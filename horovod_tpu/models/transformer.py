@@ -76,9 +76,24 @@ class TransformerConfig:
     # Mixture-of-Experts: n_experts > 0 replaces the dense SwiGLU FFN
     # with an expert-parallel MoE FFN in every layer (experts sharded
     # over the `ep` mesh axis; see models/moe.py).
+    # With n_experts > 0, d_ff is ONE expert's width.
     n_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    # None: no capacity and no dropped token — the sorted, dropless
+    # dispatch of models/moe.py (chosen by the code from this field).
+    moe_capacity_factor: Optional[float] = 1.25
+    # False: the chosen gates are used as the softmax gave them
+    # (OLMoE's norm_topk_prob); True renormalises them to sum to 1.
+    moe_norm_topk_prob: bool = True
+    # Coefficients of the two router losses lm_loss adds to the
+    # cross-entropy: load balancing, and the z-loss
+    # mean(logsumexp(router logits)²); each is summed over layers.
+    moe_aux_loss_coef: float = 0.01
+    moe_z_loss_coef: float = 0.0
+    # RMSNorm with a learned weight over the whole projected q vector
+    # and the whole projected k vector, before the split into heads
+    # and the rotary embedding (OLMoE, OLMo-2).
+    qk_norm: bool = False
     # MoE dispatch plane (ISSUE 18): None defers to the
     # HOROVOD_MOE_DISPATCH / HOROVOD_MOE_COMPRESSION env knobs
     # (docs/perf_tuning.md). "island" + a lossy codec routes the
@@ -112,7 +127,10 @@ class TransformerConfig:
             return None
         return moe_lib.MoEConfig(n_experts=self.n_experts,
                                  top_k=self.moe_top_k,
-                                 capacity_factor=self.moe_capacity_factor)
+                                 capacity_factor=self.moe_capacity_factor,
+                                 aux_loss_coef=self.moe_aux_loss_coef,
+                                 z_loss_coef=self.moe_z_loss_coef,
+                                 norm_topk_prob=self.moe_norm_topk_prob)
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +152,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "wo": P(None, "tp", "fsdp"),   # [L, H*Dh, D]
         "mlp_norm": P(None, None),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
+        layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
     if cfg.moe is not None:
         layers["moe"] = moe_lib.moe_param_specs()
     else:
@@ -180,6 +201,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
         "wo": dense(next(k), (L, H * Dh, D), H * Dh),
         "mlp_norm": jnp.ones((L, D), dt),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, H * Dh), dt)
+        layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
     if cfg.moe is not None:
         layers["moe"] = moe_lib.init_moe_params(next(k), L, D, F, cfg.moe, dt)
     else:
@@ -371,7 +395,9 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
     (y, aux)``): :func:`forward_with_aux` passes the
     :func:`moe_lib.make_moe_ffn`-selected dispatch plane; ``None``
     (pipeline/island callers, which run inside their own manual
-    regions) keeps the plain GSPMD :func:`moe_lib.moe_ffn`.
+    regions) keeps the meshless one: the plain GSPMD
+    :func:`moe_lib.moe_ffn`, or the dropless dispatch on the caller's
+    own rows for a configuration without a capacity.
 
     ``pos_offset`` shifts the rotary positions: callers running this
     layer INSIDE a manual island on a sequence SHARD (pp+sp) pass
@@ -383,8 +409,16 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
 
     with jax.named_scope("attn"):
         h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, H, Dh)
-        kk = (h @ lp["wk"]).reshape(B, T, Hkv, Dh)
+
+        def project(w, norm, heads):
+            y = h @ lp[w]
+            if cfg.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    y = _rmsnorm(y, lp[norm], cfg.norm_eps)
+            return y.reshape(B, T, heads, Dh)
+
+        q = project("wq", "q_norm", H)
+        kk = project("wk", "k_norm", Hkv)
         vv = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
         q = _rope(q, pos, cfg.rope_theta)
         kk = _rope(kk, pos, cfg.rope_theta)
@@ -403,9 +437,8 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
         h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         if cfg.moe is not None:
             if moe_fn is None:
-                y, aux = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
-            else:
-                y, aux = moe_fn(h, lp["moe"])
+                moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
+            y, aux = moe_fn(h, lp["moe"])
             x = x + y.astype(cfg.dtype)
         else:
             g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
@@ -459,10 +492,38 @@ def forward(params, tokens, cfg: TransformerConfig,
     return forward_with_aux(params, tokens, cfg, mesh)[0]
 
 
+def moe_routing_report(params, tokens, cfg: TransformerConfig
+                       ) -> Dict[str, float]:
+    """:func:`moe_lib.moe_routing_stats`' keys for every MoE layer of
+    the model on ``tokens`` [B, T]: one forward pass (no mesh) in which
+    each layer also counts the claims on its experts and those past
+    capacity. The overflow is summed over layers; the load ratio is the
+    largest layer's. Host-callable telemetry, outside the train step:
+    it runs a program of its own."""
+    moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
+
+    def counting(h, lp):
+        y, _aux = moe_fn(h, lp)
+        return y, moe_lib.routing_counts(h, lp["router"], cfg.moe)
+
+    @jax.jit
+    def run(params, tokens):
+        x = embed_lookup(params["embed"], tokens, cfg.dtype, None)
+        attend = _attention_island(cfg, None)
+        return lax.scan(
+            lambda x, lp: decoder_layer(cfg, attend, _constrainer(None), x,
+                                        lp, moe_fn=counting),
+            x, params["layers"])[1]
+
+    return moe_lib.routing_summary(*run(params, tokens))
+
+
 def lm_loss(params, batch, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None):
     """Next-token cross-entropy (f32 log-softmax) over ``batch["tokens"]``
-    [B, T+1] plus the MoE load-balancing aux term; returns scalar."""
+    [B, T+1] plus the MoE router losses, each summed over layers:
+    ``moe_aux_loss_coef`` × load balancing and ``moe_z_loss_coef`` ×
+    the router z-loss; returns scalar."""
     tokens = batch["tokens"]
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
     logits, aux = forward_with_aux(params, inp, cfg, mesh)

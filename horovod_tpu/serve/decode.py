@@ -175,6 +175,16 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
     — e.g. the benchmark's continuous and static schedulers, or a
     fleet of per-tenant engines — reuse one pair of jit closures and
     therefore one compiled program per shape bucket."""
+    unserved = [what for what, there in (
+        ("qk_norm", cfg.qk_norm),
+        ("a MoE without a capacity (moe_capacity_factor=None)",
+         cfg.moe is not None and cfg.moe.capacity_factor is None)) if there]
+    if unserved:
+        raise NotImplementedError(
+            f"the serve programs do not know {' or '.join(unserved)} yet: "
+            "their layer bodies carry neither the q/k norm nor the "
+            "dropless expert dispatch (ROADMAP B7). The configuration "
+            "trains through make_train_step.")
     return _cached_serve_fns(cfg, mesh, block_size, table_width,
                              compression)
 

@@ -321,7 +321,8 @@ def test_record_moe_stats_counters_gauges_and_export():
         moe_lib.record_moe_stats({
             "moe_dispatch_overflow_tokens_total": 2.0,
             "moe_dispatch_dropped_token_frac": 0.125,
-            "moe_dispatch_bytes_saved_pct": 74.6})
+            "moe_dispatch_bytes_saved_pct": 74.6,
+            "moe_expert_load_max_over_mean": 1.5})
         m = moe_lib.moe_metrics()
         assert m["moe_dispatch_overflow_tokens_total"] == 5.0
         assert m["moe_dispatch_dropped_token_frac"] == 0.125
