@@ -237,14 +237,17 @@ def _rmsnorm(x, w, eps):
 
 
 def _rope(x, pos, theta):
-    """Rotary embedding. x: [B, T, H, D]; pos: [T] global positions."""
+    """Rotary embedding. x: [B, T, H, D]; pos: global positions, [T]
+    shared by every row (the trainer) or [B, T], one per row (the
+    server: each sequence of a decode batch is at its own length)."""
     d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * inv[None, :]      # [T, D/2]
+    ang = pos[..., None].astype(jnp.float32) * inv             # [.., T, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., 0::2], x[..., 1::2]
-    cos = cos[None, :, None, :]
-    sin = sin[None, :, None, :]
+    # to x's rank: the batch axis a shared table lacks, and the heads'
+    at = (None,) * (x.ndim - 1 - ang.ndim) + (..., None, slice(None))
+    cos, sin = cos[at], sin[at]
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin
     y = jnp.stack([y1, y2], axis=-1).reshape(x.shape)
@@ -383,45 +386,75 @@ def _constrainer(mesh: Optional[Mesh]):
     return constrain
 
 
+def attention_inputs(cfg: TransformerConfig, lp, x, pos):
+    """A decoder block up to its attention, for :func:`decoder_layer`
+    and the serve programs (``serve/decode.py``): pre-norm, q/k/v
+    projections, the q/k norm where configured, heads, and the rotary
+    embedding at ``pos`` ([T] or [B, T]). ``x`` [B, T, D] → q
+    [B, T, H, Dh], k and v [B, T, Hkv, Dh] (no GQA repeat: what the
+    server's cache stores)."""
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    B, T = x.shape[0], x.shape[1]
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+
+    def project(w, norm, heads):
+        y = h @ lp[w]
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                y = _rmsnorm(y, lp[norm], cfg.norm_eps)
+        return y.reshape(B, T, heads, Dh)
+
+    q = project("wq", "q_norm", H)
+    k = project("wk", "k_norm", Hkv)
+    v = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
+    return _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta), v
+
+
+def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
+    """A decoder block after its attention: pre-norm, the dense SwiGLU
+    or ``moe_fn(h, lp['moe']) -> (y, aux)``, the residual. Returns
+    (x, aux), aux 0 for the dense FFN. ``moe_fn=None`` is the meshless
+    :func:`moe_lib.make_moe_ffn`: the plain GSPMD
+    :func:`moe_lib.moe_ffn`, or the dropless dispatch on the caller's
+    own rows for a configuration without a capacity."""
+    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.moe is not None:
+        if moe_fn is None:
+            moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
+        y, aux = moe_fn(h, lp["moe"])
+        return x + y.astype(cfg.dtype), aux
+    g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+    u = (h @ lp["w_up"]).astype(jnp.float32)
+    x = x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
+    return x, jnp.zeros((), jnp.float32)
+
+
 def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
                   pos_offset=0, moe_fn=None):
     """One pre-norm decoder block (attention + FFN/MoE) on ``x``
     [B, T, D]; ``lp`` is this layer's param dict (no leading L dim).
     Returns (x, aux_loss) — aux is 0 for dense FFN, the load-balancing
     term for MoE. Module-level so both the layer scan and the pipeline
-    stage function build on it.
+    stage function build on it; the serve programs run its two halves,
+    :func:`attention_inputs` and :func:`ffn_block`, around their own
+    attention.
 
-    ``moe_fn`` overrides the MoE FFN call (``fn(h, lp['moe']) ->
-    (y, aux)``): :func:`forward_with_aux` passes the
+    ``moe_fn`` overrides the MoE FFN call (see :func:`ffn_block`):
+    :func:`forward_with_aux` passes the
     :func:`moe_lib.make_moe_ffn`-selected dispatch plane; ``None``
     (pipeline/island callers, which run inside their own manual
-    regions) keeps the meshless one: the plain GSPMD
-    :func:`moe_lib.moe_ffn`, or the dropless dispatch on the caller's
-    own rows for a configuration without a capacity.
+    regions) keeps the meshless one.
 
     ``pos_offset`` shifts the rotary positions: callers running this
     layer INSIDE a manual island on a sequence SHARD (pp+sp) pass
     ``axis_index("sp") * local_T`` so every shard embeds its global
     positions; the flat path's T is already global and keeps 0."""
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
     B, T = x.shape[0], x.shape[1]
     pos = jnp.arange(T) + pos_offset
 
     with jax.named_scope("attn"):
-        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-
-        def project(w, norm, heads):
-            y = h @ lp[w]
-            if cfg.qk_norm:
-                with jax.named_scope("qk_norm"):
-                    y = _rmsnorm(y, lp[norm], cfg.norm_eps)
-            return y.reshape(B, T, heads, Dh)
-
-        q = project("wq", "q_norm", H)
-        kk = project("wk", "k_norm", Hkv)
-        vv = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
-        q = _rope(q, pos, cfg.rope_theta)
-        kk = _rope(kk, pos, cfg.rope_theta)
+        q, kk, vv = attention_inputs(cfg, lp, x, pos)
         if Hkv != H and not getattr(attend, "handles_gqa", False):
             # GQA: tile kv heads up to H for impls that need square
             # heads (flash reads grouped K/V natively and skips this
@@ -429,23 +462,12 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
             rep = H // Hkv
             kk = jnp.repeat(kk, rep, axis=2)
             vv = jnp.repeat(vv, rep, axis=2)
-        o = attend(q, kk, vv).reshape(B, T, H * Dh)
+        o = attend(q, kk, vv).reshape(B, T, H * cfg.head_dim)
         x = x + (o @ lp["wo"]).astype(cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
 
     with jax.named_scope("mlp"):
-        h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.moe is not None:
-            if moe_fn is None:
-                moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
-            y, aux = moe_fn(h, lp["moe"])
-            x = x + y.astype(cfg.dtype)
-        else:
-            g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-            u = (h @ lp["w_up"]).astype(jnp.float32)
-            x = x + ((g * u).astype(cfg.dtype)
-                     @ lp["w_down"]).astype(cfg.dtype)
-            aux = jnp.zeros((), jnp.float32)
+        x, aux = ffn_block(cfg, lp, x, moe_fn)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
     return x, aux
 

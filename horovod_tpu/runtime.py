@@ -15,6 +15,7 @@ planning, caching, stall detection and the host (TCP) data plane
 
 from __future__ import annotations
 
+import atexit
 import ctypes
 import os
 import threading
@@ -96,6 +97,7 @@ class Runtime:
         # global errors roll no epoch but every process re-inits once).
         self._xla_world_seq = 0
         self._xla_world_epoch_tag: Optional[str] = None
+        self._xla_world_key: Optional[str] = None  # of the live world
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -210,6 +212,10 @@ class Runtime:
                 self._xla_world_epoch_tag = tag
                 self._xla_world_seq = 0
             key = f"xla_coord_addr.{tag or 0}.{self._xla_world_seq}"
+            if self._xla_world_key is None and os.environ.get(
+                    "HOROVOD_ELASTIC_ID"):
+                atexit.register(self._leave_jax_distributed_at_exit)
+            self._xla_world_key = key
             if topo.rank == 0:
                 host = os.environ.get("HOROVOD_CONTROLLER_HOST")
                 if not host:
@@ -289,6 +295,34 @@ class Runtime:
         from horovod_tpu.ops import xla_exec
         xla_exec.invalidate_world()
         self._jax_dist_up = False
+
+    def _leave_jax_distributed_at_exit(self) -> None:
+        """Order the end of an elastic XLA world (registered after
+        jax's own exit handler, so run before it). The coordination
+        service lives in rank 0's process and a recoverable world has
+        no shutdown barrier: a rank 0 that exits first takes the service
+        from under its peers, whose clients then end their process with
+        LOG(FATAL) (xla client.h, at ``ShutdownTask``) and a non-zero
+        code after the work is done. So a peer leaves and says so in
+        the launcher's KV store, and rank 0 waits for every peer's word
+        before it exits: as long as a peer may take to come up, and no
+        longer for one that is gone."""
+        if not self._jax_dist_up:
+            return
+        import jax
+        from horovod_tpu.runner.http_kv import kv_put, kv_wait
+        rdv = os.environ.get("HOROVOD_RENDEZVOUS_ADDR")
+        topo, key = self.topology, self._xla_world_key
+        try:
+            if topo.rank != 0:
+                jax.distributed.shutdown()
+                kv_put(rdv, "global", f"{key}.left.{topo.rank}", b"1")
+            else:
+                timeout = float(os.environ.get("HOROVOD_START_TIMEOUT", "120"))
+                for rank in range(1, topo.size):
+                    kv_wait(rdv, "global", f"{key}.left.{rank}", timeout)
+        except Exception:
+            pass  # the peer or the launcher is gone: exit all the same
 
     @staticmethod
     def _force_reset_jax_dist_state() -> None:

@@ -35,12 +35,16 @@ decode exercises :mod:`horovod_tpu.ops.collectives`' data plane on the
 hot loop (the EQuARX property: collectives stay inside the XLA
 program, on ICI).
 
-Numerics match ``models.transformer`` deliberately: reused
-``_rmsnorm``/``embed_lookup``, the same unfused q/k/v/gate/up
-projections, f32 softmax and silu — so incremental decode tracks the
-full-context forward to float tolerance, and served decode is
-bit-identical to single-request decode (same programs,
-row-independent math).
+Every program runs the same layers, written once (``layers`` in
+:func:`_cached_serve_fns`) around ``models.transformer``'s
+``attention_inputs`` and ``ffn_block``, the two halves of the trainer's
+``decoder_layer``. A program differs from another only in the positions
+of its queries, in how a layer's new K/V is written (whole blocks
+through block ids, or single rows at physical slots) and in which
+attention it runs (prompt-local, or :func:`_attend_pages` over the
+block tables). So incremental decode tracks the full-context forward
+to float tolerance, and served decode is bit-identical to
+single-request decode (same programs, row-independent math).
 
 Attention over the cache is one function, :func:`_attend_pages`, for
 ``prefill_resume``, ``decode`` and ``verify``: the pages are gathered
@@ -49,7 +53,7 @@ heads (never repeated across the GQA group, never copied to float32),
 and contracted with the queries grouped over KV heads — the group is
 a free dimension of both dots — with float32 scores, softmax and
 accumulators. Only the monolithic ``prefill`` attends prompt-locally
-through ``local_attention``.
+(:func:`_attend_prompt`).
 """
 
 from __future__ import annotations
@@ -61,7 +65,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK
@@ -69,53 +72,16 @@ from horovod_tpu.serve.kv_cache import NULL_BLOCK
 _NEG_BIG = -1e30  # matches ring_attention's finite "-inf"
 
 
-def _rope_at(x, pos, theta):
-    """Rotary embedding at explicit per-(batch, seq) positions.
-
-    ``x``: [B, T, H, D]; ``pos``: [B, T] int32. Unlike the training
-    forward's ``_rope`` (one shared position vector), every batch row
-    carries its own positions — in a decode batch each sequence is at
-    a different length.
-    """
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[..., None].astype(jnp.float32) * inv          # [B, T, D/2]
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    y1 = x1 * cos - x2 * sin
-    y2 = x2 * cos + x1 * sin
-    return jnp.stack([y1, y2], axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def _qkv(cfg, lp, x, pos):
-    """Pre-norm + q/k/v projections + rope (same unfused matmuls and
-    dtype discipline as ``decoder_layer``). k/v keep Hkv heads — the
-    cache stores pre-GQA-repeat, post-rope K/V."""
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    B, T = x.shape[0], x.shape[1]
-    h = tf_lib._rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, T, H, Dh)
-    k = (h @ lp["wk"]).reshape(B, T, Hkv, Dh)
-    v = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
-    return (_rope_at(q, pos, cfg.rope_theta),
-            _rope_at(k, pos, cfg.rope_theta), v)
-
-
-def _ffn(cfg, lp, x):
-    """Post-attention FFN block, decoder_layer's exact math. MoE
-    configs take the GSPMD :func:`moe_lib.moe_ffn` (experts stay
-    ep-sharded by the weight specs; the quantized-dispatch island is a
-    training-path construct — decode's T=1 slabs are too narrow to pay
-    for restructuring, see docs/serving.md). The aux loss is routing
-    telemetry only at serve time and is dropped."""
-    h = tf_lib._rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        y, _aux = moe_lib.moe_ffn(h, lp["moe"], cfg.moe)
-        return x + y.astype(cfg.dtype)
-    g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-    u = (h @ lp["w_up"]).astype(jnp.float32)
-    return x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
+def _attend_prompt(q, k, v):
+    """Causal attention of a whole prompt over itself (the monolithic
+    ``prefill``): q [1, T, H, Dh], k/v [1, T, Hkv, Dh] as projected,
+    repeated across the GQA group for ``local_attention``. Returns
+    [1, T, H * Dh]."""
+    rep = q.shape[2] // k.shape[2]
+    if rep > 1:
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    return local_attention(q, k, v, causal=True).reshape(*q.shape[:2], -1)
 
 
 def _attend_pages(q, kc_l, vc_l, tables, pos):
@@ -181,10 +147,10 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
          cfg.moe is not None and cfg.moe.capacity_factor is None)) if there]
     if unserved:
         raise NotImplementedError(
-            f"the serve programs do not know {' or '.join(unserved)} yet: "
-            "their layer bodies carry neither the q/k norm nor the "
-            "dropless expert dispatch (ROADMAP B7). The configuration "
-            "trains through make_train_step.")
+            f"the serve programs do not serve {' or '.join(unserved)} yet: "
+            "no reference holds a served model of that kind to anything "
+            "(ROADMAP B7). The configuration trains through "
+            "make_train_step.")
     return _cached_serve_fns(cfg, mesh, block_size, table_width,
                              compression)
 
@@ -192,8 +158,7 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
 @functools.lru_cache(maxsize=64)
 def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
                       compression=None):
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    rep = H // Hkv
+    Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
 
     # Every program names its parts for a device trace (`embed`, `attn`
     # with `kv_write` / `kv_gather` inside it, `mlp`, `head`): the
@@ -203,6 +168,73 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         with jax.named_scope("embed"):
             return tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
                                        mesh, compression)
+
+    def layers(params, kc, vc, x, pos, write, attend):
+        """The layers of every program, over ``x`` [B, T, D] (embedded
+        tokens) at ``pos`` [B, T] (their global positions). Called in
+        the scan's body: ``write(kc_l, vc_l, k, v) -> (kc_l, vc_l)``
+        puts a layer's new K/V [B, T, Hkv, Dh] into its pool
+        (``write_blocks`` or ``write_rows`` at the program's
+        addresses); ``attend(q, k, v, kc_l, vc_l) -> [B, T, H * Dh]``
+        is prompt-local or over the pool just written. Returns
+        (kc, vc, x)."""
+        def body(x, per_layer):
+            lp, kc_l, vc_l = per_layer
+            with jax.named_scope("attn"):
+                q, k, v = tf_lib.attention_inputs(cfg, lp, x, pos)
+                with jax.named_scope("kv_write"):
+                    kc_l, vc_l = write(kc_l, vc_l, k, v)
+                o = attend(q, k, v, kc_l, vc_l)
+                x = x + (o @ lp["wo"]).astype(cfg.dtype)
+            with jax.named_scope("mlp"):
+                # the aux loss is routing telemetry only at serve time
+                x, _aux = tf_lib.ffn_block(cfg, lp, x)
+            return x, (kc_l, vc_l)
+
+        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
+        return kc, vc, x
+
+    def write_blocks(kc_l, vc_l, k, v, blks):
+        """K/V of one block-aligned chunk (B = 1) as whole blocks, at
+        the block ids ``blks`` [n_blk]. Ids past a sequence's
+        allocation are the null block (id 0): garbage written there is
+        never read (attention masks by length)."""
+        def put(pool_l, new):
+            return pool_l.at[blks].set(
+                new[0].reshape(-1, block_size, Hkv, Dh).astype(pool_l.dtype))
+        return put(kc_l, k), put(vc_l, v)
+
+    def write_rows(kc_l, vc_l, k, v, pos, block_tables):
+        """K/V of one token ([B]) or a few ([B, C], starting mid-block)
+        per sequence as single rows, at the physical slots of their
+        positions ``pos`` through ``block_tables`` [B, table_width].
+        Positions past the table (a speculative draft's proposal
+        frontier near a sequence's cap) route to the null block: the
+        unguarded take_along_axis would CLAMP the slot and overwrite
+        the sequence's last real block instead."""
+        slot = pos // block_size
+        blk = jnp.take_along_axis(
+            block_tables,
+            jnp.minimum(slot, table_width - 1).reshape(pos.shape[0], -1),
+            axis=1).reshape(pos.shape)
+        blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
+        phys = (blk * block_size + pos % block_size).reshape(-1)
+
+        def put(pool_l, new):
+            return pool_l.reshape(-1, Hkv, Dh).at[phys].set(
+                new.reshape(-1, Hkv, Dh).astype(pool_l.dtype)).reshape(
+                    pool_l.shape)
+        return put(kc_l, k), put(vc_l, v)
+
+    def emit(params, x, rows):
+        """Final norm of ``x`` [B, T, D], the rows wanted of it
+        (``rows(x) -> [..., D]``), the head, the argmax of each. (Not
+        named ``head``: that is the scope, and a lowering's locations
+        hold function names beside scope names.)"""
+        with jax.named_scope("head"):
+            x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
+            logits = (x @ params["lm_head"]).astype(jnp.float32)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def prefill(params, kc, vc, tokens, length, block_table):
         """tokens [Tp] (bucket-padded), length scalar i32 (real prompt
@@ -215,40 +247,16 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
             f"{table_width}")
         x = embed(params, tokens[None])                         # [1, Tp, D]
         pos = jnp.arange(Tp, dtype=jnp.int32)[None]            # [1, Tp]
-
-        def body(x, per_layer):
-            lp, kc_l, vc_l = per_layer
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(cfg, lp, x, pos)
-                # Pages: the padded prompt is block-aligned, so the
-                # write is a plain blockwise scatter. Bucket blocks past
-                # the allocation land on the null block (id 0) — written
-                # garbage there is never read (attention masks by
-                # length).
-                with jax.named_scope("kv_write"):
-                    kc_l = kc_l.at[block_table[:n_blk]].set(
-                        k[0].reshape(n_blk, block_size, Hkv, Dh).astype(
-                            kc_l.dtype))
-                    vc_l = vc_l.at[block_table[:n_blk]].set(
-                        v[0].reshape(n_blk, block_size, Hkv, Dh).astype(
-                            vc_l.dtype))
-                kk, vv = k, v
-                if rep > 1:
-                    kk = jnp.repeat(kk, rep, axis=2)
-                    vv = jnp.repeat(vv, rep, axis=2)
-                o = local_attention(q, kk, vv, causal=True)
-                x = x + (o.reshape(1, Tp, H * Dh)
-                         @ lp["wo"]).astype(cfg.dtype)
-            with jax.named_scope("mlp"):
-                x = _ffn(cfg, lp, x)
-            return x, (kc_l, vc_l)
-
-        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        with jax.named_scope("head"):
-            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-            x_last = jnp.take(x[0], length - 1, axis=0)        # [D]
-            logits = (x_last @ params["lm_head"]).astype(jnp.float32)
-            return kc, vc, jnp.argmax(logits).astype(jnp.int32)
+        # The padded prompt is block-aligned, so the write is a plain
+        # blockwise scatter; bucket blocks past the allocation hold the
+        # null block's id.
+        kc, vc, x = layers(
+            params, kc, vc, x, pos,
+            lambda kc_l, vc_l, k, v: write_blocks(
+                kc_l, vc_l, k, v, block_table[:n_blk]),
+            lambda q, k, v, kc_l, vc_l: _attend_prompt(q, k, v))
+        return kc, vc, emit(params, x,
+                            lambda x: jnp.take(x[0], length - 1, axis=0))
 
     def prefill_resume(params, kc, vc, tokens, offset, length, block_table):
         """One prefill *chunk* starting at block-aligned token
@@ -285,30 +293,13 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
             slot < table_width,
             jnp.take(block_table, jnp.minimum(slot, table_width - 1)),
             NULL_BLOCK)
-
-        def body(x, per_layer):
-            lp, kc_l, vc_l = per_layer
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(cfg, lp, x, pos)
-                with jax.named_scope("kv_write"):
-                    kc_l = kc_l.at[blks].set(
-                        k[0].reshape(n_blk, block_size, Hkv, Dh).astype(
-                            kc_l.dtype))
-                    vc_l = vc_l.at[blks].set(
-                        v[0].reshape(n_blk, block_size, Hkv, Dh).astype(
-                            vc_l.dtype))
-                o = _attend_pages(q, kc_l, vc_l, block_table[None], pos)
-                x = x + (o @ lp["wo"]).astype(cfg.dtype)
-            with jax.named_scope("mlp"):
-                x = _ffn(cfg, lp, x)
-            return x, (kc_l, vc_l)
-
-        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        with jax.named_scope("head"):
-            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-            x_last = jnp.take(x[0], length - 1, axis=0)        # [D]
-            logits = (x_last @ params["lm_head"]).astype(jnp.float32)
-            return kc, vc, jnp.argmax(logits).astype(jnp.int32)
+        kc, vc, x = layers(
+            params, kc, vc, x, pos,
+            lambda kc_l, vc_l, k, v: write_blocks(kc_l, vc_l, k, v, blks),
+            lambda q, k, v, kc_l, vc_l: _attend_pages(
+                q, kc_l, vc_l, block_table[None], pos))
+        return kc, vc, emit(params, x,
+                            lambda x: jnp.take(x[0], length - 1, axis=0))
 
     def decode(params, kc, vc, tokens, positions, block_tables):
         """One continuous-batching step. tokens [B] (each sequence's
@@ -320,40 +311,13 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         next_tokens [B])."""
         x = embed(params, tokens[:, None])                      # [B, 1, D]
         pos = positions[:, None]
-
-        def body(x, per_layer):
-            lp, kc_l, vc_l = per_layer
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(cfg, lp, x, pos)
-                # Positions past the table (a speculative draft's
-                # proposal frontier near a sequence's cap) route to the
-                # null block. The unguarded take_along_axis would CLAMP
-                # the slot and overwrite the sequence's last real block
-                # instead.
-                with jax.named_scope("kv_write"):
-                    slot = positions // block_size             # [B]
-                    blk = jnp.take_along_axis(
-                        block_tables,
-                        jnp.minimum(slot, table_width - 1)[:, None],
-                        axis=1)[:, 0]
-                    blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
-                    phys = blk * block_size + positions % block_size  # [B]
-                    flat = (-1, Hkv, Dh)
-                    kc_l = kc_l.reshape(flat).at[phys].set(
-                        k[:, 0].astype(kc_l.dtype)).reshape(kc_l.shape)
-                    vc_l = vc_l.reshape(flat).at[phys].set(
-                        v[:, 0].astype(vc_l.dtype)).reshape(vc_l.shape)
-                o = _attend_pages(q, kc_l, vc_l, block_tables, pos)
-                x = x + (o @ lp["wo"]).astype(cfg.dtype)
-            with jax.named_scope("mlp"):
-                x = _ffn(cfg, lp, x)
-            return x, (kc_l, vc_l)
-
-        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        with jax.named_scope("head"):
-            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-            logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        kc, vc, x = layers(
+            params, kc, vc, x, pos,
+            lambda kc_l, vc_l, k, v: write_rows(
+                kc_l, vc_l, k, v, positions, block_tables),
+            lambda q, k, v, kc_l, vc_l: _attend_pages(
+                q, kc_l, vc_l, block_tables, pos))
+        return kc, vc, emit(params, x, lambda x: x[:, 0])
 
     def verify(params, kc, vc, tokens, positions, block_tables):
         """Speculative verification (see serve/speculative.py): one
@@ -378,37 +342,13 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         C = tokens.shape[1]
         x = embed(params, tokens)                               # [B, C, D]
         pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
-
-        def body(x, per_layer):
-            lp, kc_l, vc_l = per_layer
-            with jax.named_scope("attn"):
-                q, k, v = _qkv(cfg, lp, x, pos)
-                with jax.named_scope("kv_write"):
-                    slot = pos // block_size                   # [B, C]
-                    blk = jnp.take_along_axis(
-                        block_tables, jnp.minimum(slot, table_width - 1),
-                        axis=1)
-                    blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
-                    phys = (blk * block_size
-                            + pos % block_size).reshape(-1)
-                    flat = (-1, Hkv, Dh)
-                    kc_l = kc_l.reshape(flat).at[phys].set(
-                        k.reshape(-1, Hkv, Dh).astype(kc_l.dtype)).reshape(
-                            kc_l.shape)
-                    vc_l = vc_l.reshape(flat).at[phys].set(
-                        v.reshape(-1, Hkv, Dh).astype(vc_l.dtype)).reshape(
-                            vc_l.shape)
-                o = _attend_pages(q, kc_l, vc_l, block_tables, pos)
-                x = x + (o @ lp["wo"]).astype(cfg.dtype)
-            with jax.named_scope("mlp"):
-                x = _ffn(cfg, lp, x)
-            return x, (kc_l, vc_l)
-
-        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
-        with jax.named_scope("head"):
-            x = tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-            logits = (x @ params["lm_head"]).astype(jnp.float32)  # [B,C,V]
-            return kc, vc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        kc, vc, x = layers(
+            params, kc, vc, x, pos,
+            lambda kc_l, vc_l, k, v: write_rows(
+                kc_l, vc_l, k, v, pos, block_tables),
+            lambda q, k, v, kc_l, vc_l: _attend_pages(
+                q, kc_l, vc_l, block_tables, pos))
+        return kc, vc, emit(params, x, lambda x: x)
 
     def inject(kc, vc, blocks, k_pages, v_pages):
         """Scatter handed-off prompt pages into this pool (the

@@ -1645,11 +1645,16 @@ def main():
                 out, float(s * i) + s * (s - 1) / 2.0, rtol=1e-6)
         assert hvd.steady_lock_engaged(), "lock never engaged"
         for i in range(10):
+            if i == 9:
+                # The gauges, read BEFORE the last op: a faster peer is
+                # past the loop by the time this rank returns from it,
+                # and its shape change below unlocks (the same race as
+                # the re-lock's flag read further down).
+                m = hvd.metrics()
             out = hvd.allreduce(np.full(8, float(r + i), np.float32),
                                 op=hvd.Sum, name="lp")
             np.testing.assert_allclose(
                 out, float(s * i) + s * (s - 1) / 2.0, rtol=1e-6)
-        m = hvd.metrics()
         assert m["ctrl_locked"] == 1, m
         if knob_off:
             assert m["ctrl_persistent_fires_total"] == 0, m

@@ -12,7 +12,14 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8").strip()
 os.environ.setdefault("HOROVOD_LOG_LEVEL", "warning")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# A compile cache of the tests' own, set before jax is imported and
+# inherited by every process a test spawns: the entry points' CPU
+# programs (use_compile_cache() honours the variable) stay out of
+# <checkout>/.jax_cache, which the chip runs fill and read.
+os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+    _ROOT, ".jax_cache_tests")
+sys.path.insert(0, _ROOT)
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
