@@ -40,20 +40,34 @@ Every program runs the same layers, written once (``layers`` in
 ``attention_inputs`` and ``ffn_block``, the two halves of the trainer's
 ``decoder_layer``. A program differs from another only in the positions
 of its queries, in how a layer's new K/V is written (whole blocks
-through block ids, or single rows at physical slots) and in which
-attention it runs (prompt-local, or :func:`_attend_pages` over the
-block tables). So incremental decode tracks the full-context forward
-to float tolerance, and served decode is bit-identical to
+through block ids, or single rows at a block and an offset) and in
+which attention it runs (prompt-local, or :func:`_attend_pages` over
+the block tables). So incremental decode tracks the full-context
+forward to float tolerance, and served decode is bit-identical to
 single-request decode (same programs, row-independent math).
+
+The layer scan carries the whole pool ``[L, n_blocks, bs, Hkv, Dh]``
+beside the activations and scans over (a layer's weights, its index
+``l``): a layer writes its rows at ``[l, block, offset]`` (or its
+blocks at ``[l, blocks]``) and gathers its pages through ``(l,
+table)``. The pool is donated and a scan's carry is updated where it
+lies, so a call writes the new rows and reads the pages it attends
+over. The pool must stay the carry: as the scan's ``xs`` and ``ys`` it
+cannot be written over while it is read, and every call then slices
+each layer's pool out, stacks it back into a second pool and copies
+that (two pools' worth of temporaries, a third of a decode step;
+``tests/test_tpu_lowering.py`` counts the pool-sized operations of the
+compiled programs, ``tests/test_serve_pool.py`` holds tokens and pool
+bitwise to that form).
 
 Attention over the cache is one function, :func:`_attend_pages`, for
 ``prefill_resume``, ``decode`` and ``verify``: the pages are gathered
-through the block table once, in the cache's dtype and with their Hkv
-heads (never repeated across the GQA group, never copied to float32),
-and contracted with the queries grouped over KV heads — the group is
-a free dimension of both dots — with float32 scores, softmax and
-accumulators. Only the monolithic ``prefill`` attends prompt-locally
-(:func:`_attend_prompt`).
+through the layer and the block table once, in the cache's dtype and
+with their Hkv heads (never repeated across the GQA group, never
+copied to float32), and contracted with the queries grouped over KV
+heads — the group is a free dimension of both dots — with float32
+scores, softmax and accumulators. Only the monolithic ``prefill``
+attends prompt-locally (:func:`_attend_prompt`).
 """
 
 from __future__ import annotations
@@ -84,30 +98,32 @@ def _attend_prompt(q, k, v):
     return local_attention(q, k, v, causal=True).reshape(*q.shape[:2], -1)
 
 
-def _attend_pages(q, kc_l, vc_l, tables, pos):
+def _attend_pages(q, kc, vc, l, tables, pos):
     """Attention of one query chunk per sequence over all of the
     sequence's pages: the one paged attention of ``prefill_resume``
     (B = 1), ``decode`` (C = 1) and ``verify``.
 
-    ``q`` [B, C, H, Dh] (post-rope); ``kc_l``/``vc_l`` one layer's pool
-    [n_blocks, bs, Hkv, Dh]; ``tables`` [B, W] block ids (unused
-    entries hold the null block); ``pos`` [B, C] the queries' global
-    positions. Returns [B, C, H * Dh] in ``q``'s dtype.
+    ``q`` [B, C, H, Dh] (post-rope); ``kc``/``vc`` the whole pool
+    [L, n_blocks, bs, Hkv, Dh] and ``l`` the layer (a traced scalar in
+    the layer scan); ``tables`` [B, W] block ids (unused entries hold
+    the null block); ``pos`` [B, C] the queries' global positions.
+    Returns [B, C, H * Dh] in ``q``'s dtype.
 
-    Each page is read once, in the cache's dtype and with its Hkv
-    heads: the GQA group (``rep`` = H // Hkv, 1 for MHA) is a free
-    dimension of both dots, so a (sequence, KV head) pair is one
-    ``[rep * C, Dh] x [Dh, S]`` matmul, not ``rep`` vector products
-    over a repeated copy of K and V. Scores, softmax and accumulators
-    are float32; key j is visible to the query at global position p
-    iff j <= p, and every such key is real: a prefix written before
-    this call, or the chunk's own keys written by ``kv_write`` just
-    before it."""
+    Each page is read once, through ``(l, table)`` in one gather (no
+    layer-sized slice of the pool is taken first), in the cache's dtype
+    and with its Hkv heads: the GQA group (``rep`` = H // Hkv, 1 for
+    MHA) is a free dimension of both dots, so a (sequence, KV head)
+    pair is one ``[rep * C, Dh] x [Dh, S]`` matmul, not ``rep`` vector
+    products over a repeated copy of K and V. Scores, softmax and
+    accumulators are float32; key j is visible to the query at global
+    position p iff j <= p, and every such key is real: a prefix written
+    before this call, or the chunk's own keys written by ``kv_write``
+    just before it."""
     B, C, H, Dh = q.shape
-    Hkv, S = kc_l.shape[2], tables.shape[1] * kc_l.shape[1]
+    Hkv, S = kc.shape[3], tables.shape[1] * kc.shape[2]
     with jax.named_scope("kv_gather"):
-        kp = kc_l[tables].reshape(B, S, Hkv, Dh)
-        vp = vc_l[tables].reshape(B, S, Hkv, Dh)
+        kp = kc[l, tables].reshape(B, S, Hkv, Dh)
+        vp = vc[l, tables].reshape(B, S, Hkv, Dh)
     qg = q.reshape(B, C, Hkv, H // Hkv, Dh)
     s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kp,
                    preferred_element_type=jnp.float32) * Dh ** -0.5
@@ -171,60 +187,64 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
 
     def layers(params, kc, vc, x, pos, write, attend):
         """The layers of every program, over ``x`` [B, T, D] (embedded
-        tokens) at ``pos`` [B, T] (their global positions). Called in
-        the scan's body: ``write(kc_l, vc_l, k, v) -> (kc_l, vc_l)``
-        puts a layer's new K/V [B, T, Hkv, Dh] into its pool
+        tokens) at ``pos`` [B, T] (their global positions). The scan
+        carries the whole pool beside ``x`` and hands the body the
+        layer's index ``l``: ``write(kc, vc, l, k, v) -> (kc, vc)``
+        puts layer ``l``'s new K/V [B, T, Hkv, Dh] into the pool
         (``write_blocks`` or ``write_rows`` at the program's
-        addresses); ``attend(q, k, v, kc_l, vc_l) -> [B, T, H * Dh]``
-        is prompt-local or over the pool just written. Returns
+        addresses); ``attend(q, k, v, kc, vc, l) -> [B, T, H * Dh]`` is
+        prompt-local or over the pool just written. Returns
         (kc, vc, x)."""
-        def body(x, per_layer):
-            lp, kc_l, vc_l = per_layer
+        def body(carry, per_layer):
+            x, kc, vc = carry
+            lp, l = per_layer
             with jax.named_scope("attn"):
                 q, k, v = tf_lib.attention_inputs(cfg, lp, x, pos)
                 with jax.named_scope("kv_write"):
-                    kc_l, vc_l = write(kc_l, vc_l, k, v)
-                o = attend(q, k, v, kc_l, vc_l)
+                    kc, vc = write(kc, vc, l, k, v)
+                o = attend(q, k, v, kc, vc, l)
                 x = x + (o @ lp["wo"]).astype(cfg.dtype)
             with jax.named_scope("mlp"):
                 # the aux loss is routing telemetry only at serve time
                 x, _aux = tf_lib.ffn_block(cfg, lp, x)
-            return x, (kc_l, vc_l)
+            return (x, kc, vc), None
 
-        x, (kc, vc) = lax.scan(body, x, (params["layers"], kc, vc))
+        (x, kc, vc), _ = lax.scan(
+            body, (x, kc, vc),
+            (params["layers"], jnp.arange(kc.shape[0], dtype=jnp.int32)))
         return kc, vc, x
 
-    def write_blocks(kc_l, vc_l, k, v, blks):
-        """K/V of one block-aligned chunk (B = 1) as whole blocks, at
-        the block ids ``blks`` [n_blk]. Ids past a sequence's
-        allocation are the null block (id 0): garbage written there is
-        never read (attention masks by length)."""
-        def put(pool_l, new):
-            return pool_l.at[blks].set(
-                new[0].reshape(-1, block_size, Hkv, Dh).astype(pool_l.dtype))
-        return put(kc_l, k), put(vc_l, v)
+    def write_blocks(kc, vc, l, k, v, blks):
+        """Layer ``l``'s K/V of one block-aligned chunk (B = 1) as
+        whole blocks, at the block ids ``blks`` [n_blk]. Ids past a
+        sequence's allocation are the null block (id 0): garbage
+        written there is never read (attention masks by length)."""
+        def put(pool, new):
+            return pool.at[l, blks].set(
+                new[0].reshape(-1, block_size, Hkv, Dh).astype(pool.dtype))
+        return put(kc, k), put(vc, v)
 
-    def write_rows(kc_l, vc_l, k, v, pos, block_tables):
-        """K/V of one token ([B]) or a few ([B, C], starting mid-block)
-        per sequence as single rows, at the physical slots of their
-        positions ``pos`` through ``block_tables`` [B, table_width].
-        Positions past the table (a speculative draft's proposal
-        frontier near a sequence's cap) route to the null block: the
-        unguarded take_along_axis would CLAMP the slot and overwrite
-        the sequence's last real block instead."""
+    def write_rows(kc, vc, l, k, v, pos, block_tables):
+        """Layer ``l``'s K/V of one token ([B]) or a few ([B, C],
+        starting mid-block) per sequence as single rows, at the
+        (block, offset) of their positions ``pos`` through
+        ``block_tables`` [B, table_width]. Positions past the table (a
+        speculative draft's proposal frontier near a sequence's cap)
+        route to the null block: the unguarded take_along_axis would
+        CLAMP the slot and overwrite the sequence's last real block
+        instead."""
         slot = pos // block_size
         blk = jnp.take_along_axis(
             block_tables,
             jnp.minimum(slot, table_width - 1).reshape(pos.shape[0], -1),
             axis=1).reshape(pos.shape)
-        blk = jnp.where(slot < table_width, blk, NULL_BLOCK)
-        phys = (blk * block_size + pos % block_size).reshape(-1)
+        blk = jnp.where(slot < table_width, blk, NULL_BLOCK).reshape(-1)
+        off = (pos % block_size).reshape(-1)
 
-        def put(pool_l, new):
-            return pool_l.reshape(-1, Hkv, Dh).at[phys].set(
-                new.reshape(-1, Hkv, Dh).astype(pool_l.dtype)).reshape(
-                    pool_l.shape)
-        return put(kc_l, k), put(vc_l, v)
+        def put(pool, new):
+            return pool.at[l, blk, off].set(
+                new.reshape(-1, Hkv, Dh).astype(pool.dtype))
+        return put(kc, k), put(vc, v)
 
     def emit(params, x, rows):
         """Final norm of ``x`` [B, T, D], the rows wanted of it
@@ -252,9 +272,9 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         # null block's id.
         kc, vc, x = layers(
             params, kc, vc, x, pos,
-            lambda kc_l, vc_l, k, v: write_blocks(
-                kc_l, vc_l, k, v, block_table[:n_blk]),
-            lambda q, k, v, kc_l, vc_l: _attend_prompt(q, k, v))
+            lambda kc, vc, l, k, v: write_blocks(
+                kc, vc, l, k, v, block_table[:n_blk]),
+            lambda q, k, v, kc, vc, l: _attend_prompt(q, k, v))
         return kc, vc, emit(params, x,
                             lambda x: jnp.take(x[0], length - 1, axis=0))
 
@@ -295,9 +315,9 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
             NULL_BLOCK)
         kc, vc, x = layers(
             params, kc, vc, x, pos,
-            lambda kc_l, vc_l, k, v: write_blocks(kc_l, vc_l, k, v, blks),
-            lambda q, k, v, kc_l, vc_l: _attend_pages(
-                q, kc_l, vc_l, block_table[None], pos))
+            lambda kc, vc, l, k, v: write_blocks(kc, vc, l, k, v, blks),
+            lambda q, k, v, kc, vc, l: _attend_pages(
+                q, kc, vc, l, block_table[None], pos))
         return kc, vc, emit(params, x,
                             lambda x: jnp.take(x[0], length - 1, axis=0))
 
@@ -313,10 +333,10 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         pos = positions[:, None]
         kc, vc, x = layers(
             params, kc, vc, x, pos,
-            lambda kc_l, vc_l, k, v: write_rows(
-                kc_l, vc_l, k, v, positions, block_tables),
-            lambda q, k, v, kc_l, vc_l: _attend_pages(
-                q, kc_l, vc_l, block_tables, pos))
+            lambda kc, vc, l, k, v: write_rows(
+                kc, vc, l, k, v, positions, block_tables),
+            lambda q, k, v, kc, vc, l: _attend_pages(
+                q, kc, vc, l, block_tables, pos))
         return kc, vc, emit(params, x, lambda x: x[:, 0])
 
     def verify(params, kc, vc, tokens, positions, block_tables):
@@ -344,10 +364,10 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
         pos = positions[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         kc, vc, x = layers(
             params, kc, vc, x, pos,
-            lambda kc_l, vc_l, k, v: write_rows(
-                kc_l, vc_l, k, v, pos, block_tables),
-            lambda q, k, v, kc_l, vc_l: _attend_pages(
-                q, kc_l, vc_l, block_tables, pos))
+            lambda kc, vc, l, k, v: write_rows(
+                kc, vc, l, k, v, pos, block_tables),
+            lambda q, k, v, kc, vc, l: _attend_pages(
+                q, kc, vc, l, block_tables, pos))
         return kc, vc, emit(params, x, lambda x: x)
 
     def inject(kc, vc, blocks, k_pages, v_pages):

@@ -1,7 +1,9 @@
 """The paged attention of the serve programs (PR 25):
 ``serve/decode.py`` ``_attend_pages`` reads each K/V page once, in the
 cache's dtype and with its Hkv heads, and contracts the GQA group as a
-free dimension of the dot. Checked against a plain float32
+free dimension of the dot. Since PR 30 it takes the whole pool and a
+layer: the cases read layer 1 of a three-layer pool whose other layers
+hold other numbers. Checked against a plain float32
 repeat-then-attend written out here, and, on the lowered ``decode`` of
 a GQA model, by the shapes that must not appear: no K or V repeated
 across the group, no float32 copy of the gathered pages."""
@@ -18,7 +20,17 @@ from horovod_tpu.serve.kv_cache import NULL_BLOCK
 
 BS, WIDTH, HKV, DH = 4, 5, 2, 16          # S = 20 keys a sequence
 N_BLOCKS = 1 + 4 * WIDTH
-attend_pages = jax.jit(decode_lib._attend_pages)
+LAYERS, LAYER = 3, 1
+_attend = jax.jit(decode_lib._attend_pages)
+
+
+def attend_pages(q, kc_l, vc_l, tables, pos):
+    """One layer's pool attended as layer ``LAYER`` of a whole pool,
+    the layers around it negated: nothing of them may be read."""
+    def whole(pool_l):
+        return jnp.stack([-pool_l] * LAYER + [pool_l]
+                         + [-pool_l] * (LAYERS - LAYER - 1))
+    return _attend(q, whole(kc_l), whole(vc_l), LAYER, tables, pos)
 
 
 def _case(rep, B, C, seed):
