@@ -56,6 +56,47 @@ class MoEConfig:
     #: Renormalise the chosen gates to sum to 1 (GShard); ``False``
     #: uses the softmax's own values (OLMoE's ``norm_topk_prob``).
     norm_topk_prob: bool = True
+    #: ``"softmax"`` over all experts, or ``"sigmoid"`` of each logit:
+    #: the K experts with the largest score plus the per-expert
+    #: selection bias ``lp["router_bias"]`` are chosen, and weighed by
+    #: their scores alone (dropless dispatch only).
+    scoring: str = "softmax"
+    #: The chosen gates, after ``norm_topk_prob``, times this.
+    route_scale: float = 1.0
+    #: One SwiGLU of the experts' width that every token takes, beside
+    #: the routed sum (dropless dispatch only).
+    shared_expert: bool = False
+    #: One chip's share: the router scores all ``n_experts``; the
+    #: layer holds experts ``[expert_offset, expert_offset +
+    #: experts_held)``, computes the part of the result they give and
+    #: adds nothing for the rest (``None``: it holds them all).
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if self.capacity_factor is not None and (
+                self.scoring != "softmax" or self.shared_expert
+                or self.experts_held is not None or self.route_scale != 1.0):
+            raise ValueError(
+                "sigmoid scoring, route_scale, a shared expert and a "
+                "chip's share of the experts are the dropless dispatch's "
+                "(capacity_factor=None); the one-hot dispatch has none")
+        if self.experts_held is not None and not (
+                0 < self.experts_held
+                and 0 <= self.expert_offset
+                and self.expert_offset + self.experts_held
+                <= self.n_experts):
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.experts_held}) are not among {self.n_experts}")
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose matrices the layer holds."""
+        return (self.n_experts if self.experts_held is None
+                else self.experts_held)
 
 
 def capacity(cfg: MoEConfig, seq_len: int) -> int:
@@ -63,46 +104,75 @@ def capacity(cfg: MoEConfig, seq_len: int) -> int:
                             / cfg.n_experts))
 
 
-def moe_param_specs(n_layers_leading: bool = True) -> Dict[str, Any]:
+def moe_param_specs(n_layers_leading: bool = True,
+                    cfg: Optional[MoEConfig] = None) -> Dict[str, Any]:
     """PartitionSpecs for one MoE FFN block (leading ``L`` dim when
     stacked for the layer scan): experts over ``ep``, matrix dims over
-    ``fsdp``/``tp`` like the dense FFN."""
+    ``fsdp``/``tp`` like the dense FFN. ``cfg`` adds the leaves its
+    scoring and its shared expert bring."""
     lead = (None,) if n_layers_leading else ()
-    return {
+    specs = {
         "router": P(*lead, None, None),           # [L?, D, E] replicated
         "w_gate": P(*lead, "ep", "fsdp", "tp"),   # [L?, E, D, F]
         "w_up": P(*lead, "ep", "fsdp", "tp"),
         "w_down": P(*lead, "ep", "tp", "fsdp"),   # [L?, E, F, D]
     }
+    if cfg is not None and cfg.scoring == "sigmoid":
+        specs["router_bias"] = P(*lead, None)     # [L?, E]
+    if cfg is not None and cfg.shared_expert:
+        specs.update(shared_gate=P(*lead, "fsdp", "tp"),    # [L?, D, F]
+                     shared_up=P(*lead, "fsdp", "tp"),
+                     shared_down=P(*lead, "tp", "fsdp"))    # [L?, F, D]
+    return specs
 
 
 def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
                     cfg: MoEConfig, dtype) -> Dict[str, Any]:
     kr, kg, ku, kd = jax.random.split(key, 4)
-    L, D, F, E = n_layers, d_model, d_ff, cfg.n_experts
+    L, D, F, E, Eh = n_layers, d_model, d_ff, cfg.n_experts, cfg.n_held
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
-    return {
+    params = {
         # Router in f32: small, and routing decisions are precision-
         # sensitive (standard practice).
         "router": (jax.random.normal(kr, (L, D, E), jnp.float32) * D ** -0.5),
-        "w_gate": dense(kg, (L, E, D, F), D),
-        "w_up": dense(ku, (L, E, D, F), D),
-        "w_down": dense(kd, (L, E, F, D), F),
+        "w_gate": dense(kg, (L, Eh, D, F), D),
+        "w_up": dense(ku, (L, Eh, D, F), D),
+        "w_down": dense(kd, (L, Eh, F, D), F),
     }
+    if cfg.scoring == "sigmoid":
+        # The selection bias: what load balancing moves in training,
+        # zeros at a seeded initialisation.
+        params["router_bias"] = jnp.zeros((L, E), jnp.float32)
+    if cfg.shared_expert:
+        ks = jax.random.split(jax.random.fold_in(key, 1), 3)
+        params.update(shared_gate=dense(ks[0], (L, D, F), D),
+                      shared_up=dense(ks[1], (L, D, F), D),
+                      shared_down=dense(ks[2], (L, F, D), F))
+    return params
 
 
-def _top_k_gates(logits, cfg: MoEConfig):
+def _top_k_gates(logits, cfg: MoEConfig, bias=None):
     """``(probs [.., E], gates [.., K], experts [.., K])`` of router
     logits: softmax over all experts, the K largest, renormalised to
-    sum to 1 (GShard) unless the configuration says not to."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, cfg.top_k)
+    sum to 1 (GShard) unless the configuration says not to. With
+    sigmoid scoring ``probs`` is each logit's sigmoid, the K experts
+    are those with the largest ``probs + bias`` ([E]: it chooses and
+    never weighs), and the gates are scaled by ``route_scale``."""
+    if cfg.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(probs + bias, cfg.top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, experts = jax.lax.top_k(probs, cfg.top_k)
     if cfg.norm_topk_prob:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.route_scale != 1.0:
+        gates = gates * cfg.route_scale
     return probs, gates, experts
 
 
@@ -278,7 +348,11 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
 
     with jax.named_scope("moe_router"):
         logits = _router_logits(xf, lp["router"])
-        probs, gates, experts = _top_k_gates(logits, cfg)
+        probs, gates, experts = _top_k_gates(logits, cfg,
+                                             lp.get("router_bias"))
+    if cfg.experts_held is not None:
+        return _held_experts(x, lp, cfg, gates, experts), jnp.zeros(
+            (), jnp.float32)
     with jax.named_scope("moe_dispatch"):
         order, sizes = _sorted_by_expert(experts, E)
         inverse = jnp.argsort(order)
@@ -299,7 +373,62 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
             "nkd,nk->nd",
             _take_unsorted(out, order, inverse).reshape(B * T, K, D),
             gates.astype(x.dtype))
+    if cfg.shared_expert:
+        y = y + _shared_expert(xf, lp)
     return y.reshape(B, T, D).astype(x.dtype), aux
+
+
+def _shared_expert(xf, lp):
+    """The SwiGLU every token takes, on ``xf`` [N, D]."""
+    with jax.named_scope("moe_shared"):
+        g = jax.nn.silu((xf @ lp["shared_gate"]).astype(jnp.float32))
+        u = (xf @ lp["shared_up"]).astype(jnp.float32)
+        return ((g * u).astype(xf.dtype) @ lp["shared_down"]).astype(xf.dtype)
+
+
+def held_pairs(experts, cfg: MoEConfig):
+    """``(local [N, K], held [N, K])``: each chosen expert's index
+    among the held ones, and whether it is held at all. A pair that is
+    not takes the index ``n_held``, one past the last group, so the
+    sort puts it behind every pair that counts."""
+    local = experts - cfg.expert_offset
+    held = (local >= 0) & (local < cfg.n_held)
+    return jnp.where(held, local, cfg.n_held), held
+
+
+def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
+    """One chip's part of a MoE block whose experts are spread over
+    chips (``experts_held``): of the ``N·K`` (token, choice) pairs the
+    router made over ALL experts, those on a held expert are sorted to
+    the front and run as grouped matmuls, every one of them (no
+    capacity); the others fall behind the last group, their rows are
+    never read and they add nothing, as the chip that holds their
+    expert would add it in the deployment's combine. The shared expert
+    is added here once: summed over chips, the deployment adds it on
+    one of them."""
+    B, T, D = x.shape
+    K = cfg.top_k
+    xf = x.reshape(B * T, D)
+    with jax.named_scope("moe_dispatch"):
+        local, held = held_pairs(experts, cfg)
+        order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
+        sizes = sizes[:cfg.n_held]
+        inverse = jnp.argsort(order)
+        rows = xf[order // K]                                  # [N·K, D]
+    with jax.named_scope("moe_experts"):
+        g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
+                        .astype(jnp.float32))
+        u = lax.ragged_dot(rows, lp["w_up"], sizes).astype(jnp.float32)
+        out = lax.ragged_dot((g * u).astype(x.dtype), lp["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        # what lies behind the last group is not a result: masked, not
+        # multiplied by a zero gate
+        out = jnp.where(held.reshape(-1, 1), out[inverse], 0)
+        y = jnp.einsum("nkd,nk->nd", out.reshape(B * T, K, D),
+                       gates.astype(x.dtype))
+    if cfg.shared_expert:
+        y = y + _shared_expert(xf, lp)
+    return y.reshape(B, T, D).astype(x.dtype)
 
 
 def _dropless_over_mesh(cfg: MoEConfig, mesh):
@@ -496,20 +625,46 @@ _moe_metrics: Dict[str, float] = {}
 _moe_metrics_lock = threading.Lock()
 
 
-def routing_counts(x, router, cfg: MoEConfig):
+def routing_counts(x, router, cfg: MoEConfig, bias=None):
     """``(claims per expert [E], claims past capacity)`` of one batch
     ``x`` [B, T, D], by the routing math of the dispatch the
     configuration takes: :func:`_route`'s for the one-hot dispatch,
     the top-k alone for the dropless one, which turns none away.
-    Jittable."""
+    With a chip's share of the experts: the claims on the held ones
+    [held], counted from the router's choices, and how many of them
+    :func:`_held_experts`' sort and group sizes do not run
+    (:func:`held_pairs_not_run`). ``bias`` is the selection bias of
+    sigmoid scoring. Jittable."""
     if cfg.capacity_factor is None:
         _, _, experts = _top_k_gates(
-            _router_logits(x.reshape(-1, x.shape[-1]), router), cfg)
+            _router_logits(x.reshape(-1, x.shape[-1]), router), cfg, bias)
+        if cfg.experts_held is not None:
+            local = held_pairs(experts, cfg)[0].reshape(-1)
+            claims = jnp.zeros(cfg.n_held + 1, jnp.float32).at[local].add(1.0)
+            return claims[:cfg.n_held], held_pairs_not_run(experts, cfg)
         _, sizes = _sorted_by_expert(experts, cfg.n_experts)
         return sizes.astype(jnp.float32), jnp.zeros((), jnp.float32)
     C = capacity(cfg, x.shape[1])
     _d, _c, _p, _t1, sel, within, _l = _route(x, router, cfg, C)
     return sel.sum((0, 1, 2)), sel.sum() - within.sum()
+
+
+def held_pairs_not_run(experts, cfg: MoEConfig):
+    """Of the pairs the router put on a held expert, how many
+    :func:`_held_experts` would not run through that expert: its
+    grouped matmuls run sorted place i with the matrices of the group
+    that the running sum of ``sizes`` puts i in, so a pair is run iff
+    its place lies in a group and the expert sorted there is that
+    group's. Read off the dispatch's own ``order`` and ``sizes``; the
+    held pairs are counted from the router's choices, without the
+    sort. 0 unless the sort, the sizes or a capacity lose a pair."""
+    local, held = held_pairs(experts, cfg)
+    order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
+    ends = jnp.cumsum(sizes[:cfg.n_held])
+    group = jnp.searchsorted(ends, jnp.arange(order.size, dtype=ends.dtype),
+                             side="right")
+    run = (group < cfg.n_held) & (local.reshape(-1)[order] == group)
+    return (held.sum() - run.sum()).astype(jnp.float32)
 
 
 def routing_summary(counts, overflow) -> Dict[str, float]:

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,11 +102,91 @@ class TransformerConfig:
     # keep the exact pre-existing GSPMD einsum path.
     moe_dispatch: Optional[str] = None
     moe_compression: Optional[str] = None
+    # Layers of more than one kind in one stack (ISSUE 32; the defaults
+    # describe one block repeated, as every field above does).
+    # A head size that is not d_model / n_heads (None: it is).
+    d_head: Optional[int] = None
+    # The first n_dense_layers layers keep a dense SwiGLU of width
+    # d_ff_dense while the rest are MoE layers (n_experts > 0): the
+    # parameters are then two lists of layers, params["dense_layers"]
+    # and params["layers"] (see `mixed`).
+    n_dense_layers: int = 0
+    d_ff_dense: Optional[int] = None
+    # One of "sliding" | "full" a layer. A sliding layer sees the keys
+    # j with p - attn_window < j <= p and rotates q and k; a full layer
+    # sees every j <= p and applies no rotary embedding. None: every
+    # layer is causal over everything and rotates.
+    layer_types: Optional[Tuple[str, ...]] = None
+    attn_window: Optional[int] = None
+    # RMSNorm with a gain of size head_dim over each head of q and k.
+    qk_norm_per_head: bool = False
+    # a <- a * sigmoid(u Wg) on the attention's output, before wo;
+    # u the layer's normed input, Wg [D, H * Dh].
+    attn_gate: bool = False
+    # A second RMSNorm on each branch's output, before the residual.
+    sandwich_norm: bool = False
+    # Embeddings times sqrt(d_model).
+    embed_scale: bool = False
+    # Router scoring "softmax" | "sigmoid" (models/moe.py); the chosen
+    # gates times moe_route_scale; one shared SwiGLU of width d_ff
+    # beside the routed sum.
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_shared_expert: bool = False
+    # One chip's share of the experts: the router scores all n_experts,
+    # the layer holds and runs experts [offset, offset + held) and adds
+    # nothing for the others (None: all of them).
+    moe_experts_held: Optional[int] = None
+    moe_expert_offset: int = 0
+
+    def __post_init__(self):
+        if self.layer_types is not None:
+            # a configuration file gives a list; the config is a jit key
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if (len(self.layer_types) != self.n_layers
+                    or set(self.layer_types) - {"sliding", "full"}):
+                raise ValueError(
+                    f"layer_types needs n_layers={self.n_layers} entries "
+                    f"of 'sliding' | 'full', got {self.layer_types}")
+            if "sliding" in self.layer_types and not self.attn_window:
+                raise ValueError("sliding layers need attn_window")
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError("qk_norm is over the whole vector, "
+                             "qk_norm_per_head over each head: set one")
+        if self.n_dense_layers and (self.n_experts <= 0
+                                    or not self.d_ff_dense
+                                    or self.n_dense_layers >= self.n_layers):
+            raise ValueError(
+                "n_dense_layers leads a stack of MoE layers: it needs "
+                "n_experts > 0, d_ff_dense and n_dense_layers < n_layers")
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def mixed(self) -> bool:
+        """Layers of more than one kind, or a chip's share of the
+        experts: what the trainer's one scanned block over one stack
+        does not describe, and the serve programs run layer by layer.
+        The parameters of such a configuration are LISTS of layers (one
+        dict a layer, no leading dimension) and not stacks: a program
+        that names each layer takes each layer's matrices as they lie,
+        where a slice of a stack is a copy (of 1.8 GB for the experts
+        of the configuration that brought this)."""
+        return bool(self.n_dense_layers or self.layer_types
+                    or self.moe_experts_held is not None)
+
+    def sliding(self, layer: int) -> bool:
+        return (self.layer_types is not None
+                and self.layer_types[layer] == "sliding")
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(self.sliding(i) for i in range(self.n_layers))
 
     @classmethod
     def llama3_8b(cls, **kw):
@@ -130,20 +210,20 @@ class TransformerConfig:
                                  capacity_factor=self.moe_capacity_factor,
                                  aux_loss_coef=self.moe_aux_loss_coef,
                                  z_loss_coef=self.moe_z_loss_coef,
-                                 norm_topk_prob=self.moe_norm_topk_prob)
+                                 norm_topk_prob=self.moe_norm_topk_prob,
+                                 scoring=self.moe_scoring,
+                                 route_scale=self.moe_route_scale,
+                                 shared_expert=self.moe_shared_expert,
+                                 experts_held=self.moe_experts_held,
+                                 expert_offset=self.moe_expert_offset)
 
 
 # ---------------------------------------------------------------------------
 # Parameter init + sharding specs
 # ---------------------------------------------------------------------------
 
-def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
-    """PartitionSpec pytree matching :func:`init_params`.
-
-    ``tp`` shards heads / FFN hidden / vocab; ``fsdp`` shards the
-    other matrix dim. Layer-stacked leaves carry a leading ``None``
-    (the scan dim is never sharded).
-    """
+def _block_specs(cfg: TransformerConfig, moe: bool) -> Dict[str, Any]:
+    """The specs of one stack of blocks: MoE blocks or dense ones."""
     layers: Dict[str, Any] = {
         "attn_norm": P(None, None),    # [L, D]
         "wq": P(None, "fsdp", "tp"),   # [L, D, H*Dh]
@@ -155,8 +235,16 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.qk_norm:
         layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
         layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
-    if cfg.moe is not None:
-        layers["moe"] = moe_lib.moe_param_specs()
+    if cfg.qk_norm_per_head:
+        layers["q_norm"] = P(None, None)   # [L, Dh], every head's gain
+        layers["k_norm"] = P(None, None)
+    if cfg.attn_gate:
+        layers["wg"] = P(None, "fsdp", "tp")   # [L, D, H*Dh]
+    if cfg.sandwich_norm:
+        layers["post_attn_norm"] = P(None, None)
+        layers["post_mlp_norm"] = P(None, None)
+    if moe:
+        layers["moe"] = moe_lib.moe_param_specs(cfg=cfg.moe)
     else:
         layers.update({
             # Separate gate/up/q/k/v matmuls measure FASTER than fused
@@ -167,26 +255,42 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
             "w_up": P(None, "fsdp", "tp"),
             "w_down": P(None, "tp", "fsdp"),  # [L, F, D]
         })
-    return {
+    return layers
+
+
+def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
+    """PartitionSpec pytree matching :func:`init_params`.
+
+    ``tp`` shards heads / FFN hidden / vocab; ``fsdp`` shards the
+    other matrix dim. Layer-stacked leaves carry a leading ``None``
+    (the scan dim is never sharded).
+    """
+    specs = {
         # [V, D] vocab-parallel; looked up via the explicit shard_map
         # island in :func:`embed_lookup` — a global-view gather on a
         # vocab-sharded table forces GSPMD into "involuntary full
         # rematerialization" (replicate the table, then re-partition).
         "embed": P("tp", "fsdp"),
-        "layers": layers,
+        "layers": _block_specs(cfg, cfg.moe is not None),
         "final_norm": P(None),
         "lm_head": P("fsdp", "tp"),        # [D, V]
     }
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = _block_specs(cfg, False)
+    if cfg.mixed:
+        for stack, n in (("layers", cfg.n_layers - cfg.n_dense_layers),
+                         ("dense_layers", cfg.n_dense_layers)):
+            if stack in specs:
+                specs[stack] = [jax.tree.map(
+                    lambda spec: P(*spec[1:]), specs[stack],
+                    is_leaf=lambda x: isinstance(x, P))] * n
+    return specs
 
 
-def init_params(cfg: TransformerConfig, key: jax.Array,
-                mesh: Optional[Mesh] = None) -> Dict[str, Any]:
-    """Initialise the parameter pytree (optionally already sharded onto
-    ``mesh`` so giant models never materialise replicated)."""
-    k = iter(jax.random.split(key, 16))
-    L, D, H, Hkv, Dh, F, V = (cfg.n_layers, cfg.d_model, cfg.n_heads,
-                              cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
-                              cfg.vocab_size)
+def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int):
+    """One stack of ``L`` blocks, MoE or dense of width ``F``, drawing
+    its keys from the iterator ``k`` in one fixed order."""
+    D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
     def dense(kk, shape, fan_in):
@@ -204,7 +308,15 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
         layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
-    if cfg.moe is not None:
+    if cfg.qk_norm_per_head:
+        layers["q_norm"] = jnp.ones((L, Dh), dt)
+        layers["k_norm"] = jnp.ones((L, Dh), dt)
+    if cfg.attn_gate:
+        layers["wg"] = dense(next(k), (L, D, H * Dh), D)
+    if cfg.sandwich_norm:
+        layers["post_attn_norm"] = jnp.ones((L, D), dt)
+        layers["post_mlp_norm"] = jnp.ones((L, D), dt)
+    if moe:
         layers["moe"] = moe_lib.init_moe_params(next(k), L, D, F, cfg.moe, dt)
     else:
         layers.update({
@@ -212,12 +324,37 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
             "w_up": dense(next(k), (L, D, F), D),
             "w_down": dense(next(k), (L, F, D), F),
         })
+    return layers
+
+
+def init_params(cfg: TransformerConfig, key: jax.Array,
+                mesh: Optional[Mesh] = None) -> Dict[str, Any]:
+    """Initialise the parameter pytree (optionally already sharded onto
+    ``mesh`` so giant models never materialise replicated)."""
+    k = iter(jax.random.split(key, 16))
+    D, V = cfg.d_model, cfg.vocab_size
+
+    def dense(kk, shape, fan_in):
+        return (jax.random.normal(kk, shape, jnp.float32)
+                * (fan_in ** -0.5)).astype(cfg.dtype)
+
     params = {
+        "layers": _init_blocks(cfg, k, cfg.n_layers - cfg.n_dense_layers,
+                               cfg.moe is not None, cfg.d_ff),
         "embed": dense(next(k), (V, D), D),
-        "layers": layers,
-        "final_norm": jnp.ones((D,), dt),
+        "final_norm": jnp.ones((D,), cfg.dtype),
         "lm_head": dense(next(k), (D, V), D),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"] = _init_blocks(
+            cfg, iter(jax.random.split(jax.random.fold_in(key, 1), 8)),
+            cfg.n_dense_layers, False, cfg.d_ff_dense)
+    if cfg.mixed:
+        for stack in ("layers", "dense_layers"):
+            if stack in params:
+                n = len(params[stack]["attn_norm"])
+                params[stack] = [jax.tree.map(lambda a: a[i], params[stack])
+                                 for i in range(n)]
     if mesh is not None:
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  param_specs(cfg),
@@ -386,11 +523,13 @@ def _constrainer(mesh: Optional[Mesh]):
     return constrain
 
 
-def attention_inputs(cfg: TransformerConfig, lp, x, pos):
+def attention_inputs(cfg: TransformerConfig, lp, x, pos, rotary=True):
     """A decoder block up to its attention, for :func:`decoder_layer`
     and the serve programs (``serve/decode.py``): pre-norm, q/k/v
-    projections, the q/k norm where configured, heads, and the rotary
-    embedding at ``pos`` ([T] or [B, T]). ``x`` [B, T, D] → q
+    projections, the q/k norm where configured (over the whole vector,
+    or over each head), heads, and the rotary embedding at ``pos`` ([T]
+    or [B, T]; none where ``rotary`` is false: a full layer of a
+    configuration with ``layer_types``). ``x`` [B, T, D] → q
     [B, T, H, Dh], k and v [B, T, Hkv, Dh] (no GQA repeat: what the
     server's cache stores)."""
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -402,31 +541,59 @@ def attention_inputs(cfg: TransformerConfig, lp, x, pos):
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
                 y = _rmsnorm(y, lp[norm], cfg.norm_eps)
-        return y.reshape(B, T, heads, Dh)
+        y = y.reshape(B, T, heads, Dh)
+        if cfg.qk_norm_per_head:
+            with jax.named_scope("qk_norm"):
+                y = _rmsnorm(y, lp[norm], cfg.norm_eps)
+        return y
 
     q = project("wq", "q_norm", H)
     k = project("wk", "k_norm", Hkv)
     v = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
+    if not rotary:
+        return q, k, v
     return _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta), v
+
+
+def attention_residual(cfg: TransformerConfig, lp, x, o):
+    """A decoder block after its attention ``o`` [B, T, H * Dh]: the
+    output gate and the norm on the branch where configured, the output
+    projection and the residual."""
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            # the layer's normed input once more: one value with
+            # attention_inputs' to the compiler
+            u = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+            o = o * jax.nn.sigmoid(
+                (u @ lp["wg"]).astype(jnp.float32)).astype(o.dtype)
+    y = (o @ lp["wo"]).astype(cfg.dtype)
+    if cfg.sandwich_norm:
+        y = _rmsnorm(y, lp["post_attn_norm"], cfg.norm_eps)
+    return x + y
 
 
 def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
     """A decoder block after its attention: pre-norm, the dense SwiGLU
-    or ``moe_fn(h, lp['moe']) -> (y, aux)``, the residual. Returns
-    (x, aux), aux 0 for the dense FFN. ``moe_fn=None`` is the meshless
-    :func:`moe_lib.make_moe_ffn`: the plain GSPMD
-    :func:`moe_lib.moe_ffn`, or the dropless dispatch on the caller's
-    own rows for a configuration without a capacity."""
+    or ``moe_fn(h, lp['moe']) -> (y, aux)`` (whichever the block's
+    parameters hold), the norm on the branch where configured, the
+    residual. Returns (x, aux), aux 0 for the dense FFN.
+    ``moe_fn=None`` is the meshless :func:`moe_lib.make_moe_ffn`: the
+    plain GSPMD :func:`moe_lib.moe_ffn`, or the dropless dispatch on
+    the caller's own rows for a configuration without a capacity."""
     h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
+    if "moe" in lp:
         if moe_fn is None:
             moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
         y, aux = moe_fn(h, lp["moe"])
-        return x + y.astype(cfg.dtype), aux
-    g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
-    u = (h @ lp["w_up"]).astype(jnp.float32)
-    x = x + ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
-    return x, jnp.zeros((), jnp.float32)
+        y = y.astype(cfg.dtype)
+    else:
+        g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+        u = (h @ lp["w_up"]).astype(jnp.float32)
+        y = ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
+        aux = jnp.zeros((), jnp.float32)
+    if cfg.sandwich_norm:
+        y = _rmsnorm(y, lp["post_mlp_norm"], cfg.norm_eps)
+    return x + y, aux
 
 
 def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
@@ -463,13 +630,29 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
             kk = jnp.repeat(kk, rep, axis=2)
             vv = jnp.repeat(vv, rep, axis=2)
         o = attend(q, kk, vv).reshape(B, T, H * cfg.head_dim)
-        x = x + (o @ lp["wo"]).astype(cfg.dtype)
+        x = attention_residual(cfg, lp, x, o)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
 
     with jax.named_scope("mlp"):
         x, aux = ffn_block(cfg, lp, x, moe_fn)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
     return x, aux
+
+
+def _refuse_served_only(cfg: TransformerConfig, what: str) -> None:
+    """The trainer scans ONE block over one stack of parameters and
+    holds every expert; only the serve programs (``serve/decode.py``)
+    run a stack of several kinds or a chip's share of the experts."""
+    served_only = [name for name, there in (
+        ("n_dense_layers", cfg.n_dense_layers),
+        ("layer_types", cfg.layer_types),
+        ("moe_experts_held", cfg.moe_experts_held is not None)) if there]
+    if served_only:
+        raise NotImplementedError(
+            f"{what} does not run a configuration with "
+            f"{' or '.join(served_only)}: the trainer's layer scan is one "
+            "block over one stack holding every expert (ROADMAP C5b). "
+            "The configuration is served through ServeEngine.")
 
 
 def forward_with_aux(params, tokens, cfg: TransformerConfig,
@@ -480,6 +663,7 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
     on [B, T] dims; attention heads tp-sharded by GSPMD propagation from
     the weight specs.
     """
+    _refuse_served_only(cfg, "forward_with_aux")
     constrain = _constrainer(mesh)
     attend = _attention_island(cfg, mesh)
     moe_fn = (moe_lib.make_moe_ffn(cfg.moe, mesh,
@@ -489,6 +673,8 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
 
     with jax.named_scope("embed"):
         x = embed_lookup(params["embed"], tokens, cfg.dtype, mesh)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
 
     def layer(x, lp):
@@ -587,6 +773,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer=None, *,
     those axes > 1 raise.
     """
     import optax
+    _refuse_served_only(cfg, "make_train_step")
     if optimizer is None:
         optimizer = optax.adamw(3e-4, weight_decay=0.01)
 

@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK
@@ -136,7 +137,7 @@ def _attend_pages(q, kc, vc, l, tables, pos):
 
 
 def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
-                   table_width: int, compression=None):
+                   table_width: int, compression=None, ring: int = 0):
     """Build (prefill, prefill_resume, decode, inject, verify) jitted
     closures for ``cfg`` over ``mesh``. ``table_width`` is the static
     block-table row length (blocks per sequence, worst case); caches
@@ -153,20 +154,42 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
     one table-sized transfer on the decode hot loop when the vocab-
     parallel island can't run.
 
+    A configuration whose layers are of more than one kind
+    (``cfg.mixed``: a leading dense stack, window and full attention)
+    or that holds a chip's share of the experts gets the programs of
+    :func:`_mixed_serve_fns`, over two kinds of cache; ``ring`` is the
+    positions a window layer keeps for a sequence
+    (``kv_cache.ring_width``). It has no ``inject`` and no ``verify``.
+
     Memoized: engines sharing (cfg, mesh, block geometry, compression)
     — e.g. the benchmark's continuous and static schedulers, or a
     fleet of per-tenant engines — reuse one pair of jit closures and
     therefore one compiled program per shape bucket."""
+    sigmoid_share = cfg.moe is not None and cfg.moe.scoring == "sigmoid"
     unserved = [what for what, there in (
         ("qk_norm", cfg.qk_norm),
-        ("a MoE without a capacity (moe_capacity_factor=None)",
-         cfg.moe is not None and cfg.moe.capacity_factor is None)) if there]
+        ("a softmax-routed MoE without a capacity "
+         "(moe_capacity_factor=None)",
+         cfg.moe is not None and cfg.moe.capacity_factor is None
+         and not sigmoid_share)) if there]
     if unserved:
         raise NotImplementedError(
             f"the serve programs do not serve {' or '.join(unserved)} yet: "
             "no reference holds a served model of that kind to anything "
             "(ROADMAP B7). The configuration trains through "
             "make_train_step.")
+    if cfg.mixed:
+        spread = {a: n for a, n in (mesh.shape.items() if mesh is not None
+                                    else ()) if a in ("tp", "ep") and n > 1}
+        if spread:
+            raise NotImplementedError(
+                "the serve programs of a configuration with layers of "
+                "several kinds or a chip's share of the experts run on one "
+                f"chip: a mesh with {spread} would shard its two caches "
+                "and its held experts, which nothing does yet (ROADMAP "
+                "B7(ii))")
+        return _mixed_serve_fns(cfg, block_size, table_width, ring,
+                                compression)
     return _cached_serve_fns(cfg, mesh, block_size, table_width,
                              compression)
 
@@ -392,3 +415,320 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
             jax.jit(decode, donate_argnums=(1, 2)),
             jax.jit(inject, donate_argnums=(0, 1)),
             jax.jit(verify, donate_argnums=(1, 2)))
+
+
+# ---------------------------------------------------------------------------
+# Layers of several kinds over two kinds of cache (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+def _attend_keys(q, keys, vals, key_pos, pos, window):
+    """Attention of a query chunk per sequence over keys that each
+    carry the position they hold: the one attention of the mixed
+    programs, for a prompt over itself, a block table's pages and a
+    window layer's ring.
+
+    ``q`` [B, C, H, Dh]; ``keys``/``vals`` [B, S, Hkv, Dh] in the
+    cache's dtype; ``key_pos`` [B, S] the position each key holds
+    (negative: none yet); ``pos`` [B, C] the queries' positions. Key j
+    is visible to the query at p iff ``0 <= j <= p`` and, with a
+    ``window``, ``j > p - window``. Grouped over KV heads with float32
+    scores, softmax and accumulators, as :func:`_attend_pages`; a chunk
+    (C > 1) goes one KV head at a time, so that its scores are
+    ``[H / Hkv, C, S]`` and not ``H`` times ``[C, S]`` at once.
+    Returns [B, C, H * Dh]."""
+    B, C, H, Dh = q.shape
+    Hkv = keys.shape[2]
+    mask = (key_pos[:, None, :] >= 0) & (key_pos[:, None, :] <= pos[:, :, None])
+    if window is not None:
+        mask &= key_pos[:, None, :] > pos[:, :, None] - window   # [B, C, S]
+
+    def heads(qg, kp, vp):
+        """qg [B, C, G, R, Dh] over kp/vp [B, S, G, Dh], G KV heads."""
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kp,
+                       preferred_element_type=jnp.float32) * Dh ** -0.5
+        s = jnp.where(mask[:, None, None], s, _NEG_BIG)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(vp.dtype), vp,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    qg = q.reshape(B, C, Hkv, H // Hkv, Dh)
+    if C == 1:
+        return heads(qg, keys, vals).reshape(B, C, H * Dh)
+    o = lax.map(lambda a: heads(a[0][:, :, None], a[1][:, :, None],
+                                a[2][:, :, None])[:, :, 0],
+                (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(keys, 2, 0),
+                 jnp.moveaxis(vals, 2, 0)))              # [Hkv, B, C, R, Dh]
+    return jnp.moveaxis(o, 0, 2).reshape(B, C, H * Dh)
+
+
+def ring_positions(frontier, ring: int):
+    """The position each of a ring's ``ring`` places holds once
+    ``frontier`` [B] positions of a sequence have been written (position
+    p lies at ``p % ring``): the newest ``p < frontier`` with that
+    remainder, negative where none was written yet. A retired slot's
+    ring needs no cleaning: a new sequence's frontier starts at 0 and
+    what lies beyond it reads as a position the mask refuses."""
+    r = jnp.arange(ring, dtype=jnp.int32)[None]
+    last = frontier[:, None] - 1
+    return last - (last - r) % ring
+
+
+def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
+                   compression=None, head=None):
+    """(prefill, prefill_resume, decode, held_experts_counts), not
+    jitted, of a configuration with layers of several kinds (the last
+    is :func:`moe_share_report`'s). The caches are pairs
+    ``kc = (pool, rings)``: ``pool`` [n_full, n_blocks, bs, Hkv, Dh] is
+    the full layers' paged pool behind the block tables, ``rings``
+    [n_window, n_slots, ring, Hkv, Dh] the window layers', one ring a
+    batch slot (slot 0 is the null slot, as block 0 is the null block).
+    An address is a pair too: ``(block_table, slot)``.
+
+    The layers are a Python loop: each knows its kind, its stack and
+    its place in its cache when the program is traced. ``head`` maps
+    float32 logits to what a program returns (None: their argmax, the
+    next token; the tests read the logits themselves)."""
+    Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
+    window = cfg.attn_window
+    if head is None:
+        def head(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # layer -> (its list, its index there, sliding?, its index in its cache)
+    plan, n_full, n_win = [], 0, 0
+    for i in range(cfg.n_layers):
+        stack, j = (("dense_layers", i) if i < cfg.n_dense_layers
+                    else ("layers", i - cfg.n_dense_layers))
+        if cfg.sliding(i):
+            plan.append((stack, j, True, n_win))
+            n_win += 1
+        else:
+            plan.append((stack, j, False, n_full))
+            n_full += 1
+    rotary_everywhere = cfg.layer_types is None
+
+    def embed(params, tokens):
+        with jax.named_scope("embed"):
+            x = tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
+                                    None, compression)
+            if cfg.embed_scale:
+                x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+            return x
+
+    def layers(params, kc, vc, x, pos, write, attend, moe_fn=None):
+        """Every layer over ``x`` [B, T, D] at ``pos`` [B, T].
+        ``write(cache, c, sliding, new) -> cache`` puts a layer's new K
+        or V into place ``c`` of its kind's cache, and ``attend(q, k,
+        v, kc, vc, c, sliding) -> [B, T, H * Dh]`` attends, as in
+        :func:`_cached_serve_fns`."""
+        for stack, j, sliding, c in plan:
+            lp = params[stack][j]
+            kind = "attn_window" if sliding else "attn_full"
+            with jax.named_scope("attn"):
+                q, k, v = tf_lib.attention_inputs(
+                    cfg, lp, x, pos, rotary=sliding or rotary_everywhere)
+                with jax.named_scope(kind):
+                    with jax.named_scope("kv_write"):
+                        kc, vc = (write(kc, c, sliding, k),
+                                  write(vc, c, sliding, v))
+                    o = attend(q, k, v, kc, vc, c, sliding)
+                x = tf_lib.attention_residual(cfg, lp, x, o)
+            with jax.named_scope("mlp"):
+                x, _aux = tf_lib.ffn_block(cfg, lp, x, moe_fn)
+        return kc, vc, x
+
+    def put(cache, sliding, at, new):
+        """``new`` rows at ``at`` of the pool or of the rings."""
+        pool, rings = cache
+        if sliding:
+            return pool, rings.at[at].set(
+                new.reshape(-1, Hkv, Dh).astype(rings.dtype))
+        return pool.at[at].set(new.astype(pool.dtype)), rings
+
+    def emit(params, x, rows):
+        with jax.named_scope("head"):
+            x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
+            return head((x @ params["lm_head"]).astype(jnp.float32))
+
+    def chunk_program(params, kc, vc, tokens, offset, length, address,
+                      local: bool):
+        """A chunk of one sequence at ``offset`` (B = 1): whole blocks
+        into the pool, rows into the slot's ring. ``local``: the chunk
+        is the whole prompt and attends over itself."""
+        table, slot = address
+        Tc = tokens.shape[0]
+        assert Tc <= ring or not n_win, (
+            f"a chunk of {Tc} does not fit a ring of {ring}")
+        x = embed(params, tokens[None])
+        pos = offset + jnp.arange(Tc, dtype=jnp.int32)[None]    # [1, Tc]
+        blk = offset // block_size + jnp.arange(Tc // block_size,
+                                                dtype=jnp.int32)
+        blks = jnp.where(
+            blk < table_width,
+            jnp.take(table, jnp.minimum(blk, table_width - 1)), NULL_BLOCK)
+        held = ring_positions(offset[None] + Tc, ring) if n_win else None
+        S = table_width * block_size
+
+        def write(cache, c, sliding, new):
+            if sliding:
+                return put(cache, True, (c, slot, pos[0] % ring), new)
+            return put(cache, False, (c, blks),
+                       new[0].reshape(-1, block_size, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc, c, sliding):
+            w = window if sliding else None
+            if local:
+                return _attend_keys(q, k, v, pos, pos, w)
+            if sliding:
+                return _attend_keys(q, kc[1][c, slot][None],
+                                    vc[1][c, slot][None], held, pos, w)
+            with jax.named_scope("kv_gather"):
+                kp = kc[0][c, table].reshape(1, S, Hkv, Dh)
+                vp = vc[0][c, table].reshape(1, S, Hkv, Dh)
+            return _attend_keys(q, kp, vp,
+                                jnp.arange(S, dtype=jnp.int32)[None], pos,
+                                None)
+
+        kc, vc, x = layers(params, kc, vc, x, pos, write, attend)
+        return kc, vc, emit(params, x,
+                            lambda x: jnp.take(x[0], length - 1, axis=0))
+
+    def prefill(params, kc, vc, tokens, length, address):
+        """A whole prompt, tokens [Tp] bucket-padded. Returns (kc, vc,
+        first token)."""
+        assert tokens.shape[0] // block_size <= table_width
+        return chunk_program(params, kc, vc, tokens, jnp.int32(0), length,
+                             address, local=True)
+
+    def prefill_resume(params, kc, vc, tokens, offset, length, address):
+        """One chunk at the block-aligned ``offset``, over what earlier
+        chunks left in the two caches and its own keys."""
+        return chunk_program(params, kc, vc, tokens, offset, length,
+                             address, local=False)
+
+    def decode(params, kc, vc, tokens, positions, address):
+        """One step of the batch: tokens [B], positions [B], address
+        ``(block_tables [B, table_width], slots [B])``. A padded row
+        carries token 0, position 0, an all-null table and the null
+        slot."""
+        tables, slots = address
+        B = tokens.shape[0]
+        x = embed(params, tokens[:, None])
+        pos = positions[:, None]
+        blk_i = positions // block_size
+        blk = jnp.take_along_axis(
+            tables, jnp.minimum(blk_i, table_width - 1)[:, None], axis=1)[:, 0]
+        blk = jnp.where(blk_i < table_width, blk, NULL_BLOCK)
+        S = table_width * block_size
+
+        def by_slot(rows, n_slots):
+            """``rows`` [B, ...] laid out by ring: row i at ``slots[i]``,
+            zeros at the slots that are not in the batch."""
+            return jnp.zeros((n_slots,) + rows.shape[1:],
+                             rows.dtype).at[slots].set(rows)
+
+        def write(cache, c, sliding, new):
+            if sliding:
+                return put(cache, True, (c, slots, positions % ring), new)
+            return put(cache, False, (c, blk, positions % block_size),
+                       new.reshape(-1, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc, c, sliding):
+            if sliding:
+                # Every ring of the layer where it lies, the queries
+                # carried to their slots and the results back: the
+                # rings are then read once, by the two dots, and not
+                # gathered first into a copy the size of the batch's
+                # share of them (a gather through (layer, slots) that
+                # the compiler made of all layers' rings in slabs, at
+                # a twelfth of the memory bandwidth). A slot that is
+                # not in the batch has written nothing (frontier 0):
+                # every key of its ring is refused and its row is not
+                # read back.
+                n_slots = kc[1].shape[1]
+                at = by_slot(pos + 1, n_slots)                  # [S, 1]
+                o = _attend_keys(by_slot(q, n_slots), kc[1][c], vc[1][c],
+                                 ring_positions(at[:, 0], ring), at - 1,
+                                 window)
+                return o[slots]
+            with jax.named_scope("kv_gather"):
+                kp = kc[0][c, tables].reshape(B, S, Hkv, Dh)
+                vp = vc[0][c, tables].reshape(B, S, Hkv, Dh)
+            return _attend_keys(q, kp, vp,
+                                jnp.arange(S, dtype=jnp.int32)[None], pos,
+                                None)
+
+        kc, vc, x = layers(params, kc, vc, x, pos, write, attend)
+        return kc, vc, emit(params, x, lambda x: x[:, 0])
+
+    def held_experts_counts(params, tokens):
+        """The claims on each held expert of every MoE layer [n_moe,
+        held], and the claims of each layer that the dispatch's sort
+        and group sizes would not run [n_moe], when ``tokens`` [B, T]
+        run as B prompts over themselves (no cache): the routing the
+        serve programs' dispatch acts on."""
+        counts, not_run = [], []
+
+        def counting(h, lp):
+            c, lost = moe_lib.routing_counts(
+                h, lp["router"], cfg.moe, lp.get("router_bias"))
+            counts.append(c)
+            not_run.append(lost)
+            return moe_lib.make_moe_ffn(cfg.moe, None)(h, lp)
+
+        B, T = tokens.shape
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+        layers(params, None, None, embed(params, tokens), pos,
+               lambda cache, c, sliding, new: cache,
+               lambda q, k, v, kc, vc, c, sliding: _attend_keys(
+                   q, k, v, pos, pos, window if sliding else None),
+               counting)
+        return jnp.stack(counts), jnp.stack(not_run)
+
+    return prefill, prefill_resume, decode, held_experts_counts
+
+
+@functools.lru_cache(maxsize=16)
+def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
+                     compression=None):
+    prefill, prefill_resume, decode, _ = mixed_programs(
+        cfg, block_size, table_width, ring, compression)
+
+    def unserved(what):
+        def refuse(*args, **kwargs):
+            raise NotImplementedError(
+                f"{what} is not built for a configuration with layers of "
+                "several kinds or a chip's share of the experts: a window "
+                "layer's ring is not pages another engine or a draft "
+                "could be handed (ROADMAP B9)")
+        return refuse
+
+    return (jax.jit(prefill, donate_argnums=(1, 2)),
+            jax.jit(prefill_resume, donate_argnums=(1, 2)),
+            jax.jit(decode, donate_argnums=(1, 2)),
+            unserved("inject"), unserved("verify"))
+
+
+def moe_share_report(params, tokens, cfg, block_size: int = 16):
+    """Routing counters of a served MoE that holds a chip's share of the
+    experts, on ``tokens`` [B, T] (B prompts of T, or B single tokens):
+    a program of its own, for set-up. ``moe_local_pair_share`` (pairs
+    on held experts over all ``B·T·K`` pairs of a layer; the share of
+    the experts held, if the router is even),
+    ``moe_held_experts_touched_mean`` (held experts with at least one
+    pair, mean over layers), ``moe_expert_load_max_over_mean`` over the
+    held experts (the largest layer's) and
+    ``moe_dispatch_dropped_token_frac`` (pairs on held experts, as the
+    router chose them, that the dispatch's sort and group sizes do not
+    run through their expert: ``moe.held_pairs_not_run``)."""
+    count = mixed_programs(cfg, block_size, 1, 0)[3]
+    counts, not_run = jax.jit(count)(params, jnp.asarray(tokens, jnp.int32))
+    pairs = tokens.shape[0] * tokens.shape[1] * cfg.moe.top_k
+    summary = moe_lib.routing_summary(counts, not_run)
+    return {
+        "moe_local_pair_share": float(counts.sum(-1).mean()) / pairs,
+        "moe_held_experts_touched_mean": float((counts > 0).sum(-1).mean()),
+        "moe_expert_load_max_over_mean":
+            summary["moe_expert_load_max_over_mean"],
+        "moe_dispatch_dropped_token_frac":
+            summary["moe_dispatch_dropped_token_frac"],
+    }

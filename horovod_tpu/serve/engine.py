@@ -80,7 +80,8 @@ from jax.profiler import TraceAnnotation
 
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
-    BlockAllocator, hash_chain, init_kv_cache, pick_bucket,
+    NULL_SLOT, BlockAllocator, hash_chain, init_kv_cache, pick_bucket,
+    ring_width,
 )
 from horovod_tpu.serve.metrics import ServeMetrics
 
@@ -215,6 +216,9 @@ class _Seq:
     trace: int = 0               # distributed trace id (0 = unsampled)
     admitted_at: Optional[float] = None   # left the queue (engine clock)
     token_times: List[float] = dataclasses.field(default_factory=list)
+    slot: int = 0                # its ring in the window layers' cache
+    #                              (a configuration with two kinds of
+    #                              cache; kv_cache.KVCache)
 
     @property
     def last_token(self) -> int:
@@ -369,16 +373,34 @@ class ServeEngine:
                 f"largest prefill bucket {max(self._prefill_buckets)} "
                 f"needs {max(self._prefill_buckets) // bs} blocks but "
                 f"the block table holds {self._table_width}")
-        pick_bucket(cfg.max_prompt, self._prefill_buckets)
         pick_bucket(cfg.max_batch, self._batch_buckets)
-        if cfg.prefill_chunk is not None:
+        if cfg.prefill_chunk is None:
+            pick_bucket(cfg.max_prompt, self._prefill_buckets)
+        else:
             # Chunks must start block-aligned (the resume fn's page
-            # writes are blockwise) and fit a bucket.
+            # writes are blockwise) and fit a bucket; no program longer
+            # than a chunk ever runs, so no bucket need hold max_prompt.
             if cfg.prefill_chunk < bs or cfg.prefill_chunk % bs:
                 raise ValueError(
                     f"prefill_chunk {cfg.prefill_chunk} must be a "
                     f"positive multiple of block_size {bs}")
             pick_bucket(cfg.prefill_chunk, self._prefill_buckets)
+        # Two kinds of cache (a configuration with layers of several
+        # kinds, or a chip's share of the experts): what is not built
+        # for it is refused here, by name.
+        self._two_caches = model_cfg.mixed
+        if self._two_caches:
+            refused = [what for what, there in (
+                ("prefix_caching (a page behind a window cannot be mapped "
+                 "into another sequence)", cfg.prefix_caching),
+                ("speculative decoding (draft/spec_k)",
+                 cfg.draft is not None)) if there]
+            if refused:
+                raise NotImplementedError(
+                    "a configuration with layers of several kinds or a "
+                    "chip's share of the experts is served without "
+                    + " and without ".join(refused)
+                    + " (ROADMAP B9); set prefix_caching=False, draft=None")
 
         # Inject pad-width menu, in BLOCK units: the prefill buckets
         # (prompt-only handoffs keep their existing programs) plus the
@@ -394,12 +416,22 @@ class ServeEngine:
             # (+1 for the reserved null block).
             n_blocks = cfg.max_batch * self._table_width + 1
         self.allocator = BlockAllocator(n_blocks, bs)
+        # A window layer keeps a ring of this many positions for each
+        # batch slot, whatever the sequence's length: the full layers
+        # alone draw on the allocator.
+        ring = (ring_width(model_cfg.attn_window,
+                           cfg.prefill_chunk or max(self._prefill_buckets),
+                           bs)
+                if model_cfg.n_window_layers else 0)
+        self._free_slots = list(range(cfg.max_batch, 0, -1))
         self.cache = init_kv_cache(model_cfg, n_blocks, bs, mesh=mesh,
-                                   dtype=cfg.cache_dtype)
+                                   dtype=cfg.cache_dtype,
+                                   n_slots=cfg.max_batch, ring=ring)
         (self._prefill_fn, self._resume_fn, self._decode_fn,
          self._inject_fn, self._verify_fn) = decode_lib.make_serve_fns(
              model_cfg, mesh, block_size=bs,
-             table_width=self._table_width, compression=cfg.compression)
+             table_width=self._table_width, compression=cfg.compression,
+             ring=ring)
         # Jitted page gather for handoff export — the twin of the
         # inject scatter. Op-by-op fancy indexing pays a full dispatch
         # per export (measured ~3x the compiled gather on the bench
@@ -461,6 +493,8 @@ class ServeEngine:
         prompt = list(prompt)
         max_new = (self.cfg.max_new_tokens if max_new_tokens is None
                    else max_new_tokens)
+        if prefill_only:
+            self._refuse_two_caches("a prefill-only request (handoff)")
         validate_request(self.cfg, self.model_cfg,
                          self.allocator.n_blocks, prompt, max_new,
                          deadline_class)
@@ -569,6 +603,10 @@ class ServeEngine:
             ph.args["queue"] = len(self._queue)
         if not (self._prefilling or self._active):
             m.record_idle()
+        if self.cache.ring:
+            m.kv_window_blocks_in_use = (
+                (self.cfg.max_batch - len(self._free_slots))
+                * self.cache.ring // self.cfg.block_size)
         self._advance_prefills()
         self._decode_once()
         m.record_queue_depth(len(self._queue))
@@ -592,6 +630,8 @@ class ServeEngine:
 
     def _finish(self, seq: _Seq, now: float) -> None:
         self.allocator.free(seq.blocks)
+        if self._two_caches:
+            self._free_slots.append(seq.slot)
         if self._spec is not None:
             self._spec.drop(seq.rid)
         self._results[seq.rid] = RequestResult(
@@ -722,7 +762,8 @@ class ServeEngine:
                 chain=req.chain, registered=len(matched),
                 deadline_class=req.deadline_class,
                 prefill_only=req.prefill_only,
-                trace=req.trace, admitted_at=now))
+                trace=req.trace, admitted_at=now,
+                slot=self._free_slots.pop() if self._two_caches else 0))
             self.metrics.record_admitted(req.submitted_at, now, req.trace)
             n_admitted += 1
         return n_admitted
@@ -809,17 +850,19 @@ class ServeEngine:
                     # — prompt-local instead of a full table gather).
                     kc, vc, tok = self._prefill_fn(
                         self._params, self.cache.k, self.cache.v, toks,
-                        np.int32(plen), seq.table)
+                        np.int32(plen), self._address(seq))
                 else:
                     kc, vc, tok = self._resume_fn(
                         self._params, self.cache.k, self.cache.v, toks,
-                        np.int32(offset), np.int32(chunk), seq.table)
+                        np.int32(offset), np.int32(chunk),
+                        self._address(seq))
             ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
             with TraceAnnotation("serve:prefill:sync"):
                 tok = int(tok)  # host sync — the step is done when this is
         self.cache.k, self.cache.v = kc, vc
         seq.n_cached = offset + chunk
         seq.last_prefill_tok = tok
+        self._record_window_positions(offset + len(toks))
         m.record_prefill(ph.t0, ph.dur, chunk, offset=offset,
                          trace=seq.trace)
         if self.cfg.prefix_caching:
@@ -831,6 +874,28 @@ class ServeEngine:
                 self.allocator.register(seq.blocks[i], seq.chain[i])
             seq.registered = max(seq.registered, n_full)
         return ph.end
+
+    def _address(self, seq: _Seq):
+        """Where a sequence's K/V lies, as the serve programs take it:
+        its block table, and with two kinds of cache its slot too."""
+        if self._two_caches:
+            return seq.table, np.int32(seq.slot)
+        return seq.table
+
+    def _record_window_positions(self, written: int) -> None:
+        """``written`` positions of one sequence have gone into every
+        window layer's ring, which keeps the newest ``ring`` of them."""
+        if self.cache.ring:
+            self.metrics.record_window_positions(
+                min(written, self.cache.ring))
+
+    def _refuse_two_caches(self, what: str) -> None:
+        if self._two_caches:
+            raise NotImplementedError(
+                f"{what} moves a sequence's pages between engines; a "
+                "configuration with layers of several kinds keeps its "
+                "window layers' keys in per-slot rings, which are not "
+                "pages and are not moved yet (ROADMAP B9)")
 
     def _complete_prefill(self, seq: _Seq, now: float) -> None:
         """``now``: the end of the prefill span that produced the first
@@ -870,6 +935,7 @@ class ServeEngine:
         rides whole — its bytes past ``n_cached`` are never attended
         to, the same null-padding contract decode relies on), then
         free the local reservation."""
+        self._refuse_two_caches("export (migrate)")
         n_blk = self.allocator.blocks_for_tokens(seq.n_cached)
         width = pick_bucket(n_blk, self._inject_widths)
         idx = np.zeros(width, np.int32)   # pad gathers the null block
@@ -961,6 +1027,7 @@ class ServeEngine:
         results — an abort (or a dropped peer connection mid-stream)
         simply returns the reservation, which is what makes a
         mid-transfer reset resolve exactly-once at the router."""
+        self._refuse_two_caches("inject")
         if meta["block_size"] != self.cfg.block_size:
             raise ValueError(
                 f"handoff block_size {meta['block_size']} != engine "
@@ -1102,10 +1169,13 @@ class ServeEngine:
             tokens = np.zeros(bucket, np.int32)
             positions = np.zeros(bucket, np.int32)
             tables = np.zeros((bucket, self._table_width), np.int32)
+            slots = np.full(bucket, NULL_SLOT, np.int32)    # padded rows'
             for i, seq in enumerate(self._active):
                 tokens[i] = seq.last_token
                 positions[i] = seq.n_cached
                 tables[i] = seq.table
+                slots[i] = seq.slot
+            address = (tables, slots) if self._two_caches else tables
         # A decode step serves the whole batch, so it carries the
         # trace ids of every sampled sequence in it (plural key).
         traces = [s.trace for s in self._active if s.trace]
@@ -1115,12 +1185,13 @@ class ServeEngine:
             with TraceAnnotation("serve:decode:dispatch"):
                 kc, vc, out = self._decode_fn(
                     self._params, self.cache.k, self.cache.v, tokens,
-                    positions, tables)
+                    positions, address)
             ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
             with TraceAnnotation("serve:decode:sync"):
                 out = np.asarray(out)  # host sync
         with m.phase("serve:decode_post"):
             self.cache.k, self.cache.v = kc, vc
+            self._record_window_positions(int(positions.max()) + 1)
             for i, seq in enumerate(self._active):
                 seq.n_cached += 1
                 seq.generated.append(int(out[i]))

@@ -41,6 +41,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 NULL_BLOCK = 0
+#: The ring of a window layer that padded batch rows write to.
+NULL_SLOT = 0
 
 
 def block_hash(parent: bytes, tokens) -> bytes:
@@ -270,12 +272,22 @@ class BlockAllocator:
 class KVCache:
     """Device-side paged cache: one K and one V array per model,
     layer-stacked on the leading dim to match the transformer's
-    scan-over-layers parameter layout."""
+    scan-over-layers parameter layout.
+
+    A configuration with window layers (``cfg.mixed``) has **two kinds
+    of cache**, and ``k`` and ``v`` are each a pair ``(pool, rings)``:
+    the pool above for its full layers alone, and for its window layers
+    ``rings`` [n_window, n_slots + 1, ring, Hkv, Dh], one ring of
+    ``ring`` positions a batch slot (slot 0 the null slot). Position p
+    of a sequence lies at ``p % ring`` of its slot's ring, so a window
+    layer keeps ``ring`` positions a sequence however long it grows,
+    and takes nothing from the allocator."""
 
     k: Any  # [L, n_blocks, block_size, Hkv, Dh]
     v: Any  # [L, n_blocks, block_size, Hkv, Dh]
     block_size: int
     n_blocks: int
+    ring: int = 0   # positions a window layer keeps a sequence
 
     @property
     def max_blocks_per_seq(self) -> int:
@@ -283,10 +295,23 @@ class KVCache:
         return self.n_blocks
 
 
+def ring_width(window: int, chunk: int, block_size: int) -> int:
+    """Positions a window layer's ring keeps: a chunk's last query sees
+    ``window`` keys back from itself and its first query ``window``
+    back from the chunk's start, ``window + chunk - 1`` positions in
+    all, which must all lie in the ring once the chunk is written
+    (padding of its bucket included); a block more, so the width stays
+    a whole number of blocks."""
+    return window + chunk + block_size
+
+
 def init_kv_cache(cfg, n_blocks: int, block_size: int,
                   mesh: Optional[Any] = None,
-                  dtype: Optional[Any] = None) -> KVCache:
-    """Allocate the zeroed block pool on device.
+                  dtype: Optional[Any] = None, *, n_slots: int = 0,
+                  ring: int = 0) -> KVCache:
+    """Allocate the zeroed block pool on device (and, for a
+    configuration with layers of several kinds, the window layers'
+    rings for ``n_slots`` batch slots: see :class:`KVCache`).
 
     With a mesh, KV heads are sharded over ``tp`` (matching the
     tp-sharded ``wk``/``wv`` projections so the decode step's cache
@@ -299,6 +324,16 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     dtype = dtype or cfg.dtype
+    if cfg.mixed:
+        n_win = cfg.n_window_layers
+        tail = (cfg.n_kv_heads, cfg.head_dim)
+
+        def both():
+            return (jnp.zeros((cfg.n_layers - n_win, n_blocks, block_size)
+                              + tail, dtype),
+                    jnp.zeros((n_win, n_slots + 1, ring) + tail, dtype))
+        return KVCache(k=both(), v=both(), block_size=block_size,
+                       n_blocks=n_blocks, ring=ring)
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     sharding = None

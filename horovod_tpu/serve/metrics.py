@@ -109,6 +109,11 @@ class ServeMetrics:
         # evictions) live on the attached BlockAllocator.
         self.prefix_hit_tokens = 0
         self.prefix_prefill_tokens = 0
+        # The second kind of cache (a configuration with window
+        # layers; kv_cache.KVCache): the most positions a window layer
+        # held for one sequence, and the rings in use, in blocks.
+        self.kv_window_positions_max = 0
+        self.kv_window_blocks_in_use = 0
         # Speculative decoding (serve/speculative.py): proposal /
         # acceptance tallies (their ratio is the token-weighted accept
         # rate) and the per-round draft / verify wall-time series.
@@ -196,6 +201,12 @@ class ServeMetrics:
                 "ts": round((now - self.started_at) * 1e6, 1),
                 "args": {"in_use": self._allocator.n_used,
                          "cached": self._allocator.n_cached}})
+
+    def record_window_positions(self, held: int) -> None:
+        """A window layer's ring now holds ``held`` positions of one
+        sequence."""
+        self.kv_window_positions_max = max(self.kv_window_positions_max,
+                                           held)
 
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
@@ -352,6 +363,10 @@ class ServeMetrics:
                 if looked_up else 0.0),
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "prefix_prefill_tokens": self.prefix_prefill_tokens,
+            # kv_blocks_in_use (below) is the full layers' pool; these
+            # are the window layers' rings (zeros without such layers)
+            "kv_window_positions_max": self.kv_window_positions_max,
+            "kv_window_blocks_in_use": self.kv_window_blocks_in_use,
             "p50_first_token_ms": ms(percentile(self.first_token_s, 50)),
             "p99_first_token_ms": ms(percentile(self.first_token_s, 99)),
             "p50_per_token_ms": ms(percentile(self.per_token_s, 50)),

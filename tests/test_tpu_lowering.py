@@ -136,6 +136,84 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# The two-cache serve programs of a configuration with layers of several
+# kinds, small but with caches and experts too large for the compiler to
+# stage whole in fast memory (it does with small ones, and the copies it
+# then makes are not the ones looked for here): instructions of the optimised HLO whose result is as large as
+# the full layers' pool, the window layers' rings, or one layer's
+# experts, by opcode.
+_MIXED_DRIVER = r"""
+import collections, json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache, ring_width
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = SingleDeviceSharding(topo.devices[0])
+cfg = TransformerConfig(
+    vocab_size=1024, d_model=1024, n_layers=4, n_heads=16, n_kv_heads=8,
+    d_head=128, d_ff=2048, d_ff_dense=2048, n_dense_layers=1, max_seq=16384,
+    rope_theta=1e4, layer_types=("sliding", "sliding", "sliding", "full"),
+    attn_window=4096, qk_norm_per_head=True, attn_gate=True,
+    sandwich_norm=True, embed_scale=True, n_experts=64, moe_top_k=4,
+    moe_capacity_factor=None, moe_scoring="sigmoid", moe_route_scale=2.448,
+    moe_shared_expert=True, moe_experts_held=16, dtype=jnp.bfloat16,
+    remat=False)
+BS, WIDTH, SLOTS, CHUNK = 16, 512, 16, 256
+ring = ring_width(cfg.attn_window, CHUNK, BS)
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kv = on_chip(jax.eval_shape(lambda: init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS, ring=ring).k))
+prefill, resume, decode, _, _ = decode_lib.make_serve_fns(
+    cfg, None, block_size=BS, table_width=WIDTH, ring=ring)
+
+
+def shape_of(s):
+    return "bf16[%s]" % ",".join(map(str, s.shape))
+
+
+large = {{shape_of(kv[0]): "pool", shape_of(kv[1]): "rings",
+         shape_of(params["layers"][0]["moe"]["w_gate"]): "experts"}}
+out = {{"device_kind": topo.devices[0].device_kind,
+       "rings_bytes": kv[1].size * 2, "pool_bytes": kv[0].size * 2}}
+for name, fn, args in (
+        # a quarter of the slots: what a step gathers of the caches to
+        # attend over (206 MB) is then under either cache
+        ("decode", decode, (i32(4), i32(4), (i32(4, WIDTH), i32(4)))),
+        ("prefill_resume", resume,
+         (i32(CHUNK), i32(), i32(), (i32(WIDTH), i32())))):
+    compiled = fn.lower(params, kv, kv, *args).compile()
+    ops = collections.Counter()
+    for result, opcode in re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(",
+                                     compiled.as_text()):
+        if result in large:
+            ops[large[result] + " " + opcode] += 1
+    out[name] = {{"ops": ops,
+                 "kernels": compiled.as_text().count("tpu_custom_call"),
+                 "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
 def _compile_for_v5e(driver):
     proc = subprocess.run(
         [sys.executable, "-c", driver.format(root=ROOT)],
@@ -185,3 +263,33 @@ def test_serve_programs_move_no_buffer_of_the_pool_s_size_on_v5e():
         assert all("attn/kv_write" in scope
                    for scope in got["scatter_scopes"]), (program, got)
         assert got["temp_bytes"] < out["pool_bytes"], (program, got)
+
+
+def test_two_cache_serve_programs_copy_neither_cache_nor_experts_on_v5e():
+    """ISSUE 32: the programs of a configuration with layers of several
+    kinds update both caches where they lie and take each layer's
+    experts as they lie. The compiled ``decode`` and ``prefill_resume``
+    hold no copy of the pool, of the rings or of a layer's experts (a
+    slice of a stack of layers was one: 1.8 GB a step at the published
+    widths), only the writes of ``kv_write`` on the donated caches, and
+    the grouped matmuls reach Mosaic."""
+    out = _compile_for_v5e(_MIXED_DRIVER)
+    for program in ("decode", "prefill_resume"):
+        got = out[program]
+        # (a "custom-call" of the experts' shape is the compiler
+        # staging one layer's matrix in fast memory ahead of its
+        # kernel, which it does at this size and not at 604 MB)
+        assert set(got["ops"]) <= {
+            "pool parameter", "pool get-tuple-element", "pool fusion",
+            "pool scatter", "pool bitcast", "rings parameter",
+            "rings get-tuple-element", "rings fusion", "rings scatter",
+            "rings bitcast", "experts parameter",
+            "experts get-tuple-element", "experts custom-call"}, (
+                program, got)
+        assert got["kernels"] >= 9, (program, got)     # 3 a sparse layer
+        # (the writes are scatters, or at some shapes an update of a
+        # reshaped view; either way on the donated buffer:) all the
+        # program allocates, the pages it gathers to attend over and
+        # the matrices it stages among them, is under the rings' size,
+        # and with a copy of either cache it would not be
+        assert got["temp_bytes"] < out["rings_bytes"], (program, got)
