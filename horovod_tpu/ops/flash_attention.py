@@ -1,19 +1,22 @@
-"""Flash attention — a Pallas TPU kernel for the hot op.
+"""Flash attention — Pallas TPU kernels for the hot op.
 
 The reference has no device kernels of its own (it drives NCCL); on
 TPU the framework's hot op is attention, and this module implements it
-as a **fused Pallas kernel**: online-softmax over KV blocks so the
+as **fused Pallas kernels**: online-softmax over KV blocks so the
 (T, T) score matrix never materializes in HBM — scores live in VMEM a
 block at a time and the MXU sees two big matmuls per block. Forward
-saves the per-row logsumexp; backward recomputes probabilities from it
-(the standard memory-for-FLOPs trade) in plain XLA, which fuses well
-and keeps the custom_vjp exactly consistent with the kernel's math.
+(``hvd_flash_fwd``) saves the per-row logsumexp; backward recomputes
+probabilities from it (the standard memory-for-FLOPs trade), also a
+tile at a time in VMEM, in two kernels: ``hvd_flash_bwd_dkv`` (a kv
+block's dK and dV, summed over its group's query heads) and
+``hvd_flash_bwd_dq``. One backward serves every caller: both entry
+points, any length, GQA, causal or not.
 
 Used via ``TransformerConfig(sp_attention="flash")`` or directly:
 
     out = flash_attention(q, k, v, causal=True)   # [B, T, H, D] each
 
-On CPU (tests, the virtual mesh) the kernel runs in Pallas interpret
+On CPU (tests, the virtual mesh) the kernels run in Pallas interpret
 mode automatically.
 """
 
@@ -159,121 +162,225 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        # The kernel's name in a device trace (PERF.md §3); a Pallas
-        # backward takes "hvd_flash_bwd".
+        # The kernel's name in a device trace (PERF.md §3); the
+        # backward's two begin "hvd_flash_bwd".
         name="hvd_flash_fwd",
     )(q, k, v)
     return out[:, :t], lse[:, 0, :t]
 
 
-# Above this query length the backward recompute runs q-chunked: the
-# dense form materializes [B·H, Tq, Tk] f32 score/probability tensors
-# (O(T²) HBM — ~2 GB per B·H=8 at T=8192, OOM well before 32k); the
-# chunked form caps live intermediates at [B·H, chunk, Tk].
-_BWD_CHUNK_T = 4096
-_BWD_CHUNK = 1024
+def _bwd_blocks(t: int, d: int, itemsize: int):
+    """The backward's (block, sub) from the shapes: square blocks of
+    ``block`` query rows by ``block`` kv rows a grid step, the diagonal
+    ones computed as ``sub``-sized tiles (``_bwd_visit``).
+
+    Chosen on the v5e at the training cells' ``[32, 4096, 128]`` bf16,
+    ms a backward (both kernels and delta; XLA's einsums took 13.9):
+    block 512 4.06, 1024 3.64, 1024 with sub 512 **3.34** (256: 3.44,
+    128: 3.57; the dq kernel likes 512, the dkv kernel 128-256, one
+    value for both); rectangular 512x1024 / 1024x512 3.9-4.0; 2048 does
+    not fit. Large blocks win as in the forward: fewer grid steps, and
+    the MXU ran at 85 % of its peak on what a 1024 block executes.
+    What fits is set by the row blocks' bytes beside the float32
+    ``block x block`` tiles (scores, dP, dS) live at once: the v5e
+    compiler's 16 MiB of scoped VMEM admit 1024 rows of 128 in bf16 or
+    float32 and of 256 in bf16, and refuse 256 in float32, 512 in bf16
+    and any 2048 (compile-only): 512 KiB a row block, halving ``block``
+    past it. A power of two, so that ``sub`` divides it on a lane
+    boundary."""
+    block = 1024
+    while block > 128 and block * d * itemsize > 512 * 1024:
+        block //= 2
+    block = min(block, 1 << (_round_up(t, 128).bit_length() - 1))
+    return block, min(512, block)
 
 
-def _bwd(scale, causal, residuals, g, g_lse=None, q_per_kv: int = 1):
-    """Recompute-based backward from the saved logsumexp: exact same
-    probabilities the kernel computed, expressed as XLA matmul chains
-    (fused by the compiler). ``g_lse`` carries the logsumexp cotangent
-    when the caller consumed it (ring-attention block merging);
-    d lse/d q = (p @ k)·scale and d lse/d k_j = p_j · q · scale.
+def _bwd_tile(q, k, v, do, lse, delta, *, scale, hidden=None):
+    """One tile of the backward, TRANSPOSED (kv rows down, query rows
+    across): the probabilities ``P^T`` recomputed from the saved
+    log-sum-exp, and ``P^T * (dP^T - delta)``, which is dS^T but for
+    the factor ``scale`` that the callers apply once to their
+    accumulators; both float32. In this orientation the per-query
+    ``lse`` and ``delta`` rows ([1, queries], the forward's layout)
+    broadcast down the sublanes as they lie, and dV = P^T dO, dK = dS^T Q
+    are plain matmuls. ``hidden(kv row, query column)`` says which
+    entries of the tile are masked to probability 0."""
+    nt = (((1,), (1,)), ((), ()))
+    st = jax.lax.dot_general(
+        k, q, nt, preferred_element_type=jnp.float32) * scale
+    if hidden is not None:
+        st = jnp.where(
+            hidden(jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
+                   jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)),
+            NEG_INF, st)
+    pt = jnp.exp(st - lse)
+    wide = jnp.promote_types(v.dtype, do.dtype)
+    dpt = jax.lax.dot_general(v.astype(wide), do.astype(wide), nt,
+                              preferred_element_type=jnp.float32)
+    return pt, pt * (dpt - delta)
 
-    GQA (``q_per_kv > 1``): q-side tensors reshape to a [B·Hkv, rep]
-    grouping (consecutive query heads share a kv head under the
-    batch-major flattening) and dk/dv sum over the group.
 
-    Long sequences dispatch to the q-chunked form (same math, bounded
-    memory)."""
-    if residuals[0].shape[1] > _BWD_CHUNK_T:
-        return _bwd_chunked(scale, causal, residuals, g, g_lse, q_per_kv)
+def _bwd_visit(add, qi, ki, *, causal, block, sub, n_k, valid):
+    """What one (q block ``qi``, kv block ``ki``) grid step computes:
+    ``add(q rows, kv rows, hidden)`` for the parts of the square block
+    that hold a visible (query, key) pair. Causal: a block below the
+    diagonal whole and unmasked; the block on the diagonal as ``sub``-
+    sized tiles, those above the diagonal left out and only those on it
+    masked (so the MXU does 3/4 or 5/8 of a diagonal block and not all
+    of it); a block above it nothing, the forward's rule. ``valid``
+    rows of the last kv block are keys and the rest padding, which a
+    causal mask hides from every real query; without one they are
+    masked by count."""
+    whole = slice(None)
+    if not causal:
+        if valid == block:
+            add(whole, whole, None)
+        else:
+            pl.when(ki != n_k - 1)(lambda: add(whole, whole, None))
+            pl.when(ki == n_k - 1)(
+                lambda: add(whole, whole, lambda row, col: row >= valid))
+        return
+    pl.when(ki < qi)(lambda: add(whole, whole, None))
+
+    @pl.when(ki == qi)
+    def _diagonal():
+        for a in range(block // sub):
+            for c in range(a + 1):
+                add(pl.ds(a * sub, sub), pl.ds(c * sub, sub),
+                    (lambda row, col: row > col) if a == c else None)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, n_q, scale, **where):
+    """One (batch*kv-head, kv-block, group-head x q-block) grid step:
+    the kv block's dK and dV accumulate in float32 scratch over every
+    query head of its group and every q block that sees it, and are
+    written once. The GQA sum over the group happens here."""
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def add(qs, ks, hidden):
+        q, do = q_ref[0, qs], do_ref[0, qs]
+        pt, dst = _bwd_tile(q, k_ref[0, ks], v_ref[0, ks], do,
+                            lse_ref[0, :, qs], delta_ref[0, :, qs],
+                            scale=scale, hidden=hidden)
+        dv_acc[ks] += jax.lax.dot(pt.astype(do.dtype), do,
+                                  preferred_element_type=jnp.float32)
+        dk_acc[ks] += jax.lax.dot(dst.astype(q.dtype), q,
+                                  preferred_element_type=jnp.float32)
+
+    _bwd_visit(add, j % n_q, pl.program_id(1), **where)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_acc, *, scale, **where):
+    """One (batch*head, q-block, kv-block) grid step: the q block's dQ
+    = dS K accumulates in float32 scratch over the kv blocks it sees."""
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def add(qs, ks, hidden):
+        k = k_ref[0, ks]
+        _, dst = _bwd_tile(q_ref[0, qs], k, v_ref[0, ks], do_ref[0, qs],
+                           lse_ref[0, :, qs], delta_ref[0, :, qs],
+                           scale=scale, hidden=hidden)
+        # dS^T is [keys, queries]: contract the keys
+        dq_acc[qs] += jax.lax.dot_general(
+            dst.astype(k.dtype), k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _bwd_visit(add, pl.program_id(1), ki, **where)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+def _backward(scale, causal, interpret, q_per_kv, residuals, g,
+              g_lse=None):
+    """dq, dk, dv from the saved log-sum-exp, as two Pallas kernels that
+    recompute the probabilities a tile at a time in VMEM: nothing of
+    size [.., T, T] exists in HBM at any length, blocks above the
+    diagonal are skipped, the MXU takes the inputs' dtype and
+    accumulates in float32. ``g_lse`` is the log-sum-exp's cotangent
+    when the caller consumed it (ring attention's block merge): since
+    d lse / dS = P, it enters as dS = P * (dP - (delta - g_lse)) * scale
+    and both entry points share the kernels."""
     q, k, v, out, lse = residuals
-    rep = q_per_kv
-    bkv = k.shape[0]
-    t = q.shape[1]
-    d = q.shape[2]
-    as_grp = lambda x: x.astype(jnp.float32).reshape(bkv, rep, t, d)  # noqa: E731
-    gl = (None if g_lse is None
-          else g_lse.astype(jnp.float32).reshape(bkv, rep, t))
-    dq, dk, dv = _bwd_rows(
-        as_grp(q), as_grp(g), as_grp(out), lse.reshape(bkv, rep, t), gl,
-        k.astype(jnp.float32), v.astype(jnp.float32), 0, scale, causal)
-    return (dq.reshape(q.shape).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    bh, t, d = q.shape
+    block, sub = _bwd_blocks(t, d, max(q.dtype.itemsize, g.dtype.itemsize))
+    tp = _round_up(t, block)
+    n = tp // block
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
 
+    def rows(x):     # [.., t, d], zero rows up to tp
+        return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0)))
 
-def _bwd_rows(qc, doc, outc, lsec, glc, kf, vf, q_pos0, scale, causal):
-    """Gradient contributions of one block of query rows (f32 in/out):
-    the shared body of the dense and chunked backwards. ``q_pos0`` is
-    the block's global query offset for the causal mask."""
-    tk = kf.shape[1]
-    s = jnp.einsum("brqd,bkd->brqk", qc, kf) * scale
-    if causal:
-        q_pos = q_pos0 + jnp.arange(qc.shape[2])[:, None]
-        k_pos = jnp.arange(tk)[None, :]
-        s = jnp.where(k_pos > q_pos, NEG_INF, s)
-    p = jnp.exp(s - lsec[..., None])             # [bkv, rep, rows, tk]
+    def stat(x):     # [bh, t] -> the forward's (bh, 1, tp) layout
+        return jnp.pad(x, ((0, 0), (0, tp - t)))[:, None, :]
 
-    dv = jnp.einsum("brqk,brqd->bkd", p, doc)
-    dp = jnp.einsum("brqd,bkd->brqk", doc, vf)
-    delta = jnp.sum(doc * outc, axis=-1, keepdims=True)
-    ds = p * (dp - delta) * scale
-    dq = jnp.einsum("brqk,bkd->brqd", ds, kf)
-    dk = jnp.einsum("brqk,brqd->bkd", ds, qc)
-    if glc is not None:
-        dq = dq + glc[..., None] * jnp.einsum("brqk,bkd->brqd", p, kf) * scale
-        dk = dk + jnp.einsum("brq,brqk,brqd->bkd", glc, p, qc) * scale
-    return dq, dk, dv
+    args = (rows(q), rows(k), rows(v), rows(g), stat(lse), stat(delta))
+    where = dict(scale=scale, causal=causal, block=block, sub=sub, n_k=n,
+                 valid=t - (n - 1) * block)
+    # A causal step above the diagonal computes nothing: it names the
+    # block of the nearest step that does, which is then not fetched
+    # again (3.84 -> 3.71 ms a backward at the cells' shape, v5e).
+    def q_seeing(qi, ki):     # dkv: the first q block to see kv block ki
+        return jnp.maximum(qi, ki) if causal else qi
 
+    def kv_seen(ki, qi):      # dq: the last kv block q block qi sees
+        return jnp.minimum(ki, qi) if causal else ki
 
-def _bwd_chunked(scale, causal, residuals, g, g_lse, q_per_kv):
-    """The backward above with the query axis processed in
-    ``_BWD_CHUNK``-row slices under ``lax.scan``: per-step tensors are
-    [bkv, rep, chunk, tk] instead of [bkv, rep, tq, tk], so HBM stays
-    bounded for long sequences. Padding rows (q/do/out zeros, lse 0)
-    contribute exactly zero to every accumulated gradient."""
-    q, k, v, out, lse = residuals
-    rep = q_per_kv
-    bkv = k.shape[0]
-    t, d = q.shape[1], q.shape[2]
-    chunk = _BWD_CHUNK
-    pad = (-t) % chunk
+    # kv block outermost; the group's heads and their q blocks reduce.
+    q_rows = pl.BlockSpec(
+        (1, block, d),
+        lambda b, i, j: (b * q_per_kv + j // n, q_seeing(j % n, i), 0))
+    q_stat = pl.BlockSpec(
+        (1, 1, block),
+        lambda b, i, j: (b * q_per_kv + j // n, 0, q_seeing(j % n, i)))
+    kv_rows = pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, n_q=n, **where),
+        grid=(bh // q_per_kv, n, q_per_kv * n),
+        in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stat, q_stat],
+        out_specs=[kv_rows, kv_rows],
+        out_shape=[jax.ShapeDtypeStruct((k.shape[0], tp, d), k.dtype),
+                   jax.ShapeDtypeStruct((v.shape[0], tp, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32)],
+        interpret=interpret,
+        name="hvd_flash_bwd_dkv",
+    )(*args)
 
-    def prep(x):  # [bkv*rep, t, d] -> padded [bkv, rep, T, d], own dtype
-        x = x.reshape(bkv, rep, t, d)
-        return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-
-    # Padded in the INPUT dtype: the f32 cast happens per chunk inside
-    # step(), keeping the f32 working set at O(chunk), not O(T).
-    qf, do, outf = prep(q), prep(g), prep(out)
-    lseg = jnp.pad(lse.reshape(bkv, rep, t), ((0, 0), (0, 0), (0, pad)))
-    gl = (None if g_lse is None else
-          jnp.pad(g_lse.astype(jnp.float32).reshape(bkv, rep, t),
-                  ((0, 0), (0, 0), (0, pad))))
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    n = (t + pad) // chunk
-
-    def step(carry, i):
-        dk_acc, dv_acc = carry
-        sl = functools.partial(jax.lax.dynamic_slice_in_dim,
-                               start_index=i * chunk, slice_size=chunk,
-                               axis=2)
-        f32 = lambda x: sl(x).astype(jnp.float32)  # noqa: E731
-        dq_c, dk_c, dv_c = _bwd_rows(
-            f32(qf), f32(do), f32(outf), sl(lseg),
-            None if gl is None else sl(gl), kf, vf, i * chunk, scale,
-            causal)
-        return (dk_acc + dk_c, dv_acc + dv_c), dq_c.astype(q.dtype)
-
-    (dk, dv), dq_chunks = jax.lax.scan(
-        step, (jnp.zeros_like(kf), jnp.zeros_like(vf)), jnp.arange(n))
-    # [n, bkv, rep, chunk, d] -> [bkv, rep, t, d] (pad rows dropped)
-    dq = jnp.moveaxis(dq_chunks, 0, 2).reshape(
-        bkv, rep, n * chunk, d)[:, :, :t, :]
-    return (dq.reshape(q.shape), dk.astype(k.dtype), dv.astype(v.dtype))
+    q_rows = pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0))
+    q_stat = pl.BlockSpec((1, 1, block), lambda b, i, j: (b, 0, i))
+    kv_rows = pl.BlockSpec(
+        (1, block, d), lambda b, i, j: (b // q_per_kv, kv_seen(j, i), 0))
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **where),
+        grid=(bh, n, n),
+        in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stat, q_stat],
+        out_specs=q_rows,
+        out_shape=jax.ShapeDtypeStruct((bh, tp, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
+        interpret=interpret,
+        name="hvd_flash_bwd_dq",
+    )(*args)
+    return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -294,7 +401,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 def _flash_bwd(scale, causal, block_q, block_k, interpret, q_per_kv,
                residuals, g):
     with jax.named_scope("flash_bwd"):
-        return _bwd(scale, causal, residuals, g, q_per_kv=q_per_kv)
+        return _backward(scale, causal, interpret, q_per_kv, residuals, g)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -320,8 +427,8 @@ def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, out_dtype,
                    q_per_kv, residuals, g):
     g_out, g_lse = g
     with jax.named_scope("flash_bwd"):
-        return _bwd(scale, causal, residuals, g_out, g_lse,
-                    q_per_kv=q_per_kv)
+        return _backward(scale, causal, interpret, q_per_kv, residuals,
+                         g_out, g_lse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -209,61 +209,92 @@ def test_transformer_ring_flash_trains(devices):
     assert float(loss2) < float(loss)
 
 
+def _residuals(t, h, hkv, dtype=jnp.float32, out_dtype=None, causal=True):
+    """Kernel-layout inputs ([B·H, T, D] q over [B·Hkv, T, D] k/v), the
+    forward's residuals for them and a cotangent of the output."""
+    import horovod_tpu.ops.flash_attention as fa
+
+    ks = jax.random.split(jax.random.PRNGKey(t + h), 4)
+    q = (jax.random.normal(ks[0], (h, t, 32)) * 0.5).astype(dtype)
+    k = (jax.random.normal(ks[1], (hkv, t, 32)) * 0.5).astype(dtype)
+    v = (jax.random.normal(ks[2], (hkv, t, 32)) * 0.5).astype(dtype)
+    scale = 32 ** -0.5
+    out, lse = fa._fwd(q, k, v, scale=scale, causal=causal, block_q=128,
+                       block_k=128, interpret=True, out_dtype=out_dtype,
+                       q_per_kv=h // hkv)
+    g = jax.random.normal(ks[3], out.shape).astype(out.dtype)
+    return scale, (q, k, v, out, lse), g
+
+
+def _assert_grads_close(got, want, exact):
+    """``exact``: float32 operands, so the kernels' arithmetic is the
+    reference's but for the order of sums. Otherwise P and dS were
+    rounded to bf16 for the MXU and the results to their dtype: each
+    tensor within 1 % of the reference's rms."""
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape, name
+        if exact:
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5,
+                                       err_msg=name)
+        else:
+            rms = np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2))
+            assert rms < 0.01, (name, rms)
+
+
+@pytest.fixture
+def small_bwd_tiles(monkeypatch):
+    """128 x 128 backward tiles, so that a few hundred positions make
+    several of them: at 384 a causal grid has skipped tiles, tiles the
+    diagonal crosses and tiles below it; 192 and 200 end in a padded
+    tile."""
+    import horovod_tpu.ops.flash_attention as fa
+
+    monkeypatch.setattr(fa, "_bwd_blocks", lambda *shape: (256, 128))
+    return fa
+
+
+@pytest.mark.parametrize("q_per_kv", [1, 2, 4])
+@pytest.mark.parametrize("t", [192, 200, 384])
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("t", [192, 200])  # chunk-aligned and padded
-def test_chunked_backward_matches_dense(monkeypatch, causal, t):
-    """Long sequences run the q-chunked backward recompute; forcing the
-    dispatch low must reproduce the dense gradients exactly (incl. GQA
-    and a pad remainder)."""
-    import horovod_tpu.ops.flash_attention as fa
+def test_pallas_backward_matches_einsum_reference(small_bwd_tiles, causal,
+                                                  t, q_per_kv):
+    """The dq / dkv kernels against the float32 einsum backward they
+    replaced (``reference_flash_bwd.py``), from the same residuals."""
+    from reference_flash_bwd import einsum_backward
 
-    q, k, v = _qkv(b=1, t=t, h=4, d=32)
-    k = k[:, :, :2, :]  # GQA: 4 query heads over 2 kv heads
-    v = v[:, :, :2, :]
-
-    def grads():
-        def loss(q, k, v):
-            return flash_attention(
-                q, k, v, causal=causal).astype(jnp.float32).sum()
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    dense = grads()
-    monkeypatch.setattr(fa, "_BWD_CHUNK_T", 100)
-    monkeypatch.setattr(fa, "_BWD_CHUNK", 64)
-    chunked = grads()
-    for a, b in zip(dense, chunked):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=2e-5)
+    scale, res, g = _residuals(t, 4, 4 // q_per_kv, causal=causal)
+    got = small_bwd_tiles._backward(scale, causal, True, q_per_kv, res, g)
+    want = einsum_backward(scale, causal, res, g, q_per_kv=q_per_kv)
+    _assert_grads_close(got, want, exact=True)
 
 
+@pytest.mark.parametrize("dtype,out_dtype", [
+    (jnp.float32, jnp.float32),
+    (jnp.bfloat16, jnp.float32),       # ring attention's chunks
+    (jnp.bfloat16, jnp.bfloat16)])
 @pytest.mark.parametrize("t", [192, 200])
-def test_chunked_backward_matches_dense_with_lse_cotangent(monkeypatch, t):
-    """Ring attention consumes the logsumexp, so the chunked backward's
-    g_lse terms must match the dense ones too."""
-    import horovod_tpu.ops.flash_attention as fa
+@pytest.mark.parametrize("causal", [True, False])
+def test_pallas_backward_with_lse_cotangent(small_bwd_tiles, causal, t,
+                                            dtype, out_dtype):
+    """Ring attention consumes the log-sum-exp, so its cotangent has to
+    reach dq and dk: a structured one through
+    ``flash_attention_with_lse``'s own VJP, against the reference."""
     from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+    from reference_flash_bwd import einsum_backward
 
-    q, k, v = _qkv(b=1, t=t, h=2, d=32)
-    # [BH, T, D] layout (the blockwise building block's contract).
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, t, 32)  # noqa: E731
-    q, k, v = flat(q), flat(k), flat(v)
-
-    def grads():
-        def loss(q, k, v):
-            out, lse = flash_attention_with_lse(q, k, v, causal=True)
-            # Weighted lse sum gives the cotangent nontrivial structure.
-            w = jnp.arange(lse.size, dtype=jnp.float32).reshape(lse.shape)
-            return (out.astype(jnp.float32).sum()
-                    + (w * lse).sum() / lse.size)
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-    dense = grads()
-    monkeypatch.setattr(fa, "_BWD_CHUNK_T", 100)
-    monkeypatch.setattr(fa, "_BWD_CHUNK", 64)
-    chunked = grads()
-    for a, b in zip(dense, chunked):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-5, atol=2e-5)
+    scale, res, g = _residuals(t, 2, 2, dtype, out_dtype, causal)
+    q, k, v, _, lse = res
+    g_lse = jnp.arange(lse.size, dtype=jnp.float32).reshape(
+        lse.shape) / lse.size
+    _, vjp = jax.vjp(
+        lambda q, k, v: flash_attention_with_lse(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            out_dtype=out_dtype), q, k, v)
+    got = vjp((g, g_lse))
+    want = einsum_backward(scale, causal, res, g, g_lse)
+    assert [x.dtype for x in got] == [dtype] * 3
+    _assert_grads_close(got, want, exact=dtype == jnp.float32)
 
 
 @pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128)])

@@ -20,7 +20,8 @@ from horovod_tpu.parallel import build_mesh
 from horovod_tpu.serve import decode as decode_lib
 
 TRAIN_SCOPES = ("embed", "attn", "mlp", "head", "loss", "optimizer",
-                "hvd_flash_fwd", "flash_bwd")
+                "hvd_flash_fwd", "flash_bwd", "hvd_flash_bwd_dkv",
+                "hvd_flash_bwd_dq")
 BS, WIDTH = 8, 3
 CFG = TransformerConfig.tiny(dtype=jnp.float32, sp_attention="flash",
                              remat=True, remat_policy="full")

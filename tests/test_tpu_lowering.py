@@ -9,6 +9,7 @@ subprocess: libtpu is noisy at start-up, and the drivers below rebind
 ``jax.default_backend``.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -39,19 +40,30 @@ out = {{"device_kind": topo.devices[0].device_kind}}
 one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
 
 
-def flash_fwd_bwd(seq):
+def flash_fwd_bwd(seq, batch=2, heads=16, kv_heads=8):
     # chip_smoke.py's kernel shape: [2, seq, 16/8 heads, 128] bf16.
     def f(q, k, v):
         return flash_attention(q, k, v, causal=True,
                                interpret=False).astype(jnp.float32).sum()
-    q = jax.ShapeDtypeStruct((2, seq, 16, 128), jnp.bfloat16, sharding=one)
-    kv = jax.ShapeDtypeStruct((2, seq, 8, 128), jnp.bfloat16, sharding=one)
-    return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text().count("tpu_custom_call")
+    q = jax.ShapeDtypeStruct((batch, seq, heads, 128), jnp.bfloat16,
+                             sharding=one)
+    kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, 128), jnp.bfloat16,
+                              sharding=one)
+    compiled = jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    return {{"kernels": text.count("tpu_custom_call"),
+            "score_shapes": text.count("%d,%d]" % (seq, seq)),
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 
 
 out["flash_1024"] = flash_fwd_bwd(1024)
 out["flash_8192"] = flash_fwd_bwd(8192)
+# The training cells' attention: [32, 4096, 128] queries a chip, over 16
+# kv heads (InternLM2, q_per_kv 2) and over 32 (OLMoE, q_per_kv 1).
+out["flash_cell_gqa"] = flash_fwd_bwd(4096)
+out["flash_cell_mha"] = flash_fwd_bwd(4096, kv_heads=16)
+out["flash_32768"] = flash_fwd_bwd(32768, batch=1)
 
 # Small, but with the 128-wide heads Mosaic tiles like the real ones.
 cfg = TransformerConfig(vocab_size=512, d_model=256, n_layers=2, n_heads=2,
@@ -65,7 +77,10 @@ for name, mesh in (
     batch = {{"tokens": jax.ShapeDtypeStruct((2 * mesh.devices.size, 257),
                                             jnp.int32)}}
     compiled = step.lower(state, batch).compile()
-    out[name] = compiled.as_text().count("tpu_custom_call")
+    text = compiled.as_text()
+    out[name] = text.count("tpu_custom_call")
+    out[name + "_bwd"] = [kernel in text for kernel
+                          in ("hvd_flash_bwd_dkv", "hvd_flash_bwd_dq")]
     wq = compiled.input_shardings[0][0]["params"]["layers"]["wq"]
     out[name + "_wq_shard"] = list(wq.shard_shape((2, 256, 256)))
 print("LOWERED " + json.dumps(out))
@@ -214,6 +229,7 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+@functools.lru_cache(maxsize=None)
 def _compile_for_v5e(driver):
     proc = subprocess.run(
         [sys.executable, "-c", driver.format(root=ROOT)],
@@ -232,14 +248,40 @@ def _compile_for_v5e(driver):
 
 def test_flash_kernel_and_train_step_compile_for_v5e():
     out = _compile_for_v5e(_DRIVER)
-    # Mosaic compiled the kernel (forward; the backward is XLA einsums)
+    # Mosaic compiled the forward and the backward's dkv and dq kernels
     # at the smoke's shape and at long sequence ...
-    assert out["flash_1024"] >= 1 and out["flash_8192"] >= 1, out
-    # ... and the train step reaches it on one chip and on dp2 x fsdp2,
-    # where the compiled step takes its state fsdp-sharded.
-    assert out["step_1"] >= 1 and out["step_4"] >= 1, out
+    assert out["flash_1024"]["kernels"] >= 3, out
+    assert out["flash_8192"]["kernels"] >= 3, out
+    # ... and the train step reaches all three on one chip and on
+    # dp2 x fsdp2, where the compiled step takes its state fsdp-sharded.
+    assert out["step_1"] >= 3 and out["step_4"] >= 3, out
+    assert out["step_1_bwd"] == [True, True], out
+    assert out["step_4_bwd"] == [True, True], out
     assert out["step_1_wq_shard"] == [2, 256, 256], out
     assert out["step_4_wq_shard"] == [2, 128, 256], out
+
+
+def test_flash_backward_holds_no_score_tensor_at_the_cells_shapes():
+    """The counter that says the Pallas backward engages (PR 33): at the
+    training cells' attention shapes the v5e compiler's forward +
+    backward holds three kernels, no operand or result with a
+    ``4096,4096]`` in its shape (the XLA backward wrote four, 1 GB each
+    in bf16), and under half a gigabyte of temporaries."""
+    out = _compile_for_v5e(_DRIVER)
+    for case in ("flash_cell_gqa", "flash_cell_mha"):
+        got = out[case]
+        assert got["kernels"] == 3, (case, got)
+        assert got["score_shapes"] == 0, (case, got)
+        assert got["temp_bytes"] < 0.5e9, (case, got)
+
+
+def test_flash_backward_compiles_at_32768():
+    """O(T) in HBM at every length, which is what the q-chunked XLA
+    backward existed for: a row of 32768 compiles, with nothing
+    score-sized and temporaries far under the chip's memory."""
+    got = _compile_for_v5e(_DRIVER)["flash_32768"]
+    assert got["kernels"] == 3 and got["score_shapes"] == 0, got
+    assert got["temp_bytes"] < 1e9, got
 
 
 def test_serve_programs_move_no_buffer_of_the_pool_s_size_on_v5e():
