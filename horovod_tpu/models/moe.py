@@ -270,21 +270,27 @@ def moe_ffn(x, lp, cfg: MoEConfig):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_sorted(x, order, inverse, K: int):
+def _take_sorted(x, order, inverse, K: int, held=None):
     """Rows of ``x`` [N, D] in sorted (token, choice) order, [N·K, D].
     ``order`` sorts the ``N·K`` pairs by expert and ``inverse`` undoes
     it, so the cotangent is a gather too, ``g[inverse]`` summed over a
-    token's K choices: autodiff alone would scatter-add over tokens."""
+    token's K choices: autodiff alone would scatter-add over tokens.
+    ``held`` [N, K] (a chip's share of the experts) says which pairs'
+    rows are used at all: the cotangent of a row behind the last group
+    is no result either, and is left out of the sum."""
     return x[order // K]
 
 
-def _take_sorted_fwd(x, order, inverse, K):
-    return x[order // K], inverse
+def _take_sorted_fwd(x, order, inverse, K, held=None):
+    return x[order // K], (inverse, held)
 
 
-def _take_sorted_bwd(K, inverse, g):
-    return (g[inverse].reshape(-1, K, g.shape[-1]).sum(1).astype(g.dtype),
-            None, None)
+def _take_sorted_bwd(K, res, g):
+    inverse, held = res
+    pairs = g[inverse].reshape(-1, K, g.shape[-1])
+    if held is not None:
+        pairs = jnp.where(held[..., None], pairs, 0)
+    return pairs.sum(1).astype(g.dtype), None, None, None
 
 
 _take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
@@ -350,19 +356,29 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
         logits = _router_logits(xf, lp["router"])
         probs, gates, experts = _top_k_gates(logits, cfg,
                                              lp.get("router_bias"))
+
+    def router_losses(claims):             # claims [E] of the N·K choices
+        with jax.named_scope("moe_router"):
+            f = everywhere(claims.astype(jnp.float32) / (B * T * K))
+            aux = cfg.aux_loss_coef * E * jnp.sum(
+                f * everywhere(probs.mean(0)))
+            if cfg.z_loss_coef:
+                aux = aux + cfg.z_loss_coef * everywhere(_z_loss(logits))
+            return aux
+
     if cfg.experts_held is not None:
-        return _held_experts(x, lp, cfg, gates, experts), jnp.zeros(
-            (), jnp.float32)
+        # A chip sorts by the experts it holds; the load-balancing term
+        # is over all n_experts router outputs of this chip's tokens,
+        # so the choices are counted without a sort.
+        claims = (experts[..., None] == jnp.arange(E, dtype=experts.dtype)
+                  ).sum((0, 1))
+        return (_held_experts(x, lp, cfg, gates, experts),
+                router_losses(claims))
     with jax.named_scope("moe_dispatch"):
         order, sizes = _sorted_by_expert(experts, E)
         inverse = jnp.argsort(order)
         rows = _take_sorted(xf, order, inverse, K)            # [N·K, D]
-    with jax.named_scope("moe_router"):
-        f = everywhere(sizes.astype(jnp.float32) / (B * T * K))
-        aux = cfg.aux_loss_coef * E * jnp.sum(
-            f * everywhere(probs.mean(0)))
-        if cfg.z_loss_coef:
-            aux = aux + cfg.z_loss_coef * everywhere(_z_loss(logits))
+    aux = router_losses(sizes)
     with jax.named_scope("moe_experts"):
         g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
                         .astype(jnp.float32))
@@ -405,7 +421,11 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     never read and they add nothing, as the chip that holds their
     expert would add it in the deployment's combine. The shared expert
     is added here once: summed over chips, the deployment adds it on
-    one of them."""
+    one of them.
+
+    Differentiable: gradients reach the held experts' matrices, the
+    router through the gates of held pairs, and ``x``; both row
+    movements are gathers backward too."""
     B, T, D = x.shape
     K = cfg.top_k
     xf = x.reshape(B * T, D)
@@ -414,7 +434,7 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
         order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
         sizes = sizes[:cfg.n_held]
         inverse = jnp.argsort(order)
-        rows = xf[order // K]                                  # [N·K, D]
+        rows = _take_sorted(xf, order, inverse, K, held)       # [N·K, D]
     with jax.named_scope("moe_experts"):
         g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
                         .astype(jnp.float32))
@@ -423,7 +443,8 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     with jax.named_scope("moe_combine"):
         # what lies behind the last group is not a result: masked, not
         # multiplied by a zero gate
-        out = jnp.where(held.reshape(-1, 1), out[inverse], 0)
+        out = jnp.where(held.reshape(-1, 1),
+                        _take_unsorted(out, order, inverse), 0)
         y = jnp.einsum("nkd,nk->nd", out.reshape(B * T, K, D),
                        gates.astype(x.dtype))
     if cfg.shared_expert:

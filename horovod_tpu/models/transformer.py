@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -37,6 +38,42 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.parallel.ring_attention import make_sp_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """How one kind of layer rotates q and k: plainly, pair i at the
+    frequency ``theta^(-2i/d)``, or, with a ``factor``, by YaRN (Peng et
+    al. 2023, as ``transformers`` computes it): pairs that turn more
+    than ``beta_fast`` times within ``original_max_seq`` positions keep
+    that frequency, those that turn fewer than ``beta_slow`` times take
+    it divided by ``factor``, a linear ramp between the two; cos and sin
+    times ``attention_factor``, so on q and on k alike. The frequencies
+    are fixed, whatever the row's length."""
+    theta: float
+    factor: Optional[float] = None
+    original_max_seq: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def frequencies(self, d: int) -> np.ndarray:
+        """The ``d / 2`` pairs' angles a position, float32."""
+        plain = self.theta ** -(np.arange(0, d, 2, dtype=np.float64) / d)
+        if self.factor is None:
+            return plain.astype(np.float32)
+
+        def pair_turning(times):    # the pair that turns `times` times
+            return d * math.log(self.original_max_seq
+                                / (2 * math.pi * times)) / (
+                                    2 * math.log(self.theta))
+
+        low = max(math.floor(pair_turning(self.beta_fast)), 0)
+        high = min(math.ceil(pair_turning(self.beta_slow)), d - 1)
+        ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3),
+                       0.0, 1.0)
+        return (plain / self.factor * ramp
+                + plain * (1 - ramp)).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,10 +151,16 @@ class TransformerConfig:
     d_ff_dense: Optional[int] = None
     # One of "sliding" | "full" a layer. A sliding layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
-    # sees every j <= p and applies no rotary embedding. None: every
-    # layer is causal over everything and rotates.
+    # sees every j <= p and applies no rotary embedding, unless
+    # layer_rotary says otherwise. None: every layer is causal over
+    # everything and rotates.
     layer_types: Optional[Tuple[str, ...]] = None
     attn_window: Optional[int] = None
+    # How a kind of layer rotates, where it is not as above: a mapping
+    # (a configuration file's object) or pairs from "sliding" | "full"
+    # to a Rotary, to its fields, or to None for no rotary embedding
+    # (see `rotary_of`).
+    layer_rotary: Optional[Tuple[Tuple[str, Optional[Rotary]], ...]] = None
     # RMSNorm with a gain of size head_dim over each head of q and k.
     qk_norm_per_head: bool = False
     # a <- a * sigmoid(u Wg) on the attention's output, before wo;
@@ -150,6 +193,15 @@ class TransformerConfig:
                     f"of 'sliding' | 'full', got {self.layer_types}")
             if "sliding" in self.layer_types and not self.attn_window:
                 raise ValueError("sliding layers need attn_window")
+        if self.layer_rotary is not None:
+            by_kind = dict(self.layer_rotary)
+            if set(by_kind) - {"sliding", "full"}:
+                raise ValueError(
+                    "layer_rotary is by kind of layer, 'sliding' | 'full', "
+                    f"got {sorted(by_kind)}")
+            object.__setattr__(self, "layer_rotary", tuple(sorted(
+                (kind, Rotary(**how) if isinstance(how, dict) else how)
+                for kind, how in by_kind.items())))
         if self.qk_norm and self.qk_norm_per_head:
             raise ValueError("qk_norm is over the whole vector, "
                              "qk_norm_per_head over each head: set one")
@@ -183,6 +235,19 @@ class TransformerConfig:
     def sliding(self, layer: int) -> bool:
         return (self.layer_types is not None
                 and self.layer_types[layer] == "sliding")
+
+    def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
+        """How layer ``layer`` rotates q and k, None for not at all:
+        what ``layer_rotary`` says of its kind, else plainly at
+        ``rope_theta``, but for the full layers of a stack with
+        ``layer_types``, which then take no rotary embedding."""
+        kind = "sliding" if self.sliding(layer) else "full"
+        by_kind = dict(self.layer_rotary or ())
+        if kind in by_kind:
+            return by_kind[kind]
+        if self.layer_types is not None and kind == "full":
+            return None
+        return Rotary(self.rope_theta)
 
     @property
     def n_window_layers(self) -> int:
@@ -373,14 +438,22 @@ def _rmsnorm(x, w, eps):
     return (h * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, pos, theta):
+def _rope(x, pos, rotary: Rotary):
     """Rotary embedding. x: [B, T, H, D]; pos: global positions, [T]
     shared by every row (the trainer) or [B, T], one per row (the
     server: each sequence of a decode batch is at its own length)."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if rotary.factor is None:
+        # as every program before YaRN computed it, to the instruction
+        inv = 1.0 / (rotary.theta
+                     ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(rotary.frequencies(d))
     ang = pos[..., None].astype(jnp.float32) * inv             # [.., T, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if rotary.attention_factor != 1.0:
+        cos, sin = (cos * rotary.attention_factor,
+                    sin * rotary.attention_factor)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     # to x's rank: the batch axis a shared table lacks, and the heads'
     at = (None,) * (x.ndim - 1 - ang.ndim) + (..., None, slice(None))
@@ -488,15 +561,28 @@ def embed_lookup(embed, tokens, dtype, mesh: Optional[Mesh],
     return out.astype(dtype)
 
 
-def _attention_island(cfg: TransformerConfig, mesh: Optional[Mesh]):
+def _attention_island(cfg: TransformerConfig, mesh: Optional[Mesh],
+                      window: Optional[int] = None):
     """attn(q, k, v) — ring/Ulysses shard_map island over ``sp`` when a
     mesh with sp>1 is given, plain attention otherwise (single
-    construction point: :func:`~horovod_tpu.parallel.ring_attention.make_sp_attention`)."""
+    construction point: :func:`~horovod_tpu.parallel.ring_attention.make_sp_attention`).
+    ``window``: a sliding layer's, None for attention over everything."""
     if mesh is not None and "sp" not in mesh.axis_names:
         mesh = None
     return make_sp_attention(mesh, axis_name="sp", impl=cfg.sp_attention,
                              causal=True, block_q=cfg.flash_block_q,
-                             block_k=cfg.flash_block_k)
+                             block_k=cfg.flash_block_k, window=window)
+
+
+def _attention_by_layer(cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """``attend_of(layer)``: the attention of that layer's kind, over
+    the window for a sliding layer of a stack with ``layer_types`` and
+    over everything otherwise (each kind's island built once)."""
+    full = _attention_island(cfg, mesh)
+    if not cfg.n_window_layers:
+        return lambda layer=0: full
+    windowed = _attention_island(cfg, mesh, cfg.attn_window)
+    return lambda layer=0: windowed if cfg.sliding(layer) else full
 
 
 def remat_policy_fn(cfg: TransformerConfig):
@@ -523,15 +609,15 @@ def _constrainer(mesh: Optional[Mesh]):
     return constrain
 
 
-def attention_inputs(cfg: TransformerConfig, lp, x, pos, rotary=True):
+def attention_inputs(cfg: TransformerConfig, lp, x, pos, layer: int = 0):
     """A decoder block up to its attention, for :func:`decoder_layer`
     and the serve programs (``serve/decode.py``): pre-norm, q/k/v
     projections, the q/k norm where configured (over the whole vector,
     or over each head), heads, and the rotary embedding at ``pos`` ([T]
-    or [B, T]; none where ``rotary`` is false: a full layer of a
-    configuration with ``layer_types``). ``x`` [B, T, D] → q
-    [B, T, H, Dh], k and v [B, T, Hkv, Dh] (no GQA repeat: what the
-    server's cache stores)."""
+    or [B, T]) as layer ``layer``'s kind takes it
+    (``cfg.rotary_of(layer)``: every layer alike without
+    ``layer_types``). ``x`` [B, T, D] → q [B, T, H, Dh], k and v
+    [B, T, Hkv, Dh] (no GQA repeat: what the server's cache stores)."""
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     B, T = x.shape[0], x.shape[1]
     h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -550,9 +636,10 @@ def attention_inputs(cfg: TransformerConfig, lp, x, pos, rotary=True):
     q = project("wq", "q_norm", H)
     k = project("wk", "k_norm", Hkv)
     v = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
-    if not rotary:
+    rotary = cfg.rotary_of(layer)
+    if rotary is None:
         return q, k, v
-    return _rope(q, pos, cfg.rope_theta), _rope(k, pos, cfg.rope_theta), v
+    return _rope(q, pos, rotary), _rope(k, pos, rotary), v
 
 
 def attention_residual(cfg: TransformerConfig, lp, x, o):
@@ -596,8 +683,17 @@ def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
     return x + y, aux
 
 
+def _scoped(name: str, attend):
+    """``attend`` under the scope ``name``, its backward with it."""
+    def scoped(q, k, v):
+        with jax.named_scope(name):
+            return attend(q, k, v)
+    scoped.handles_gqa = getattr(attend, "handles_gqa", False)
+    return scoped
+
+
 def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
-                  pos_offset=0, moe_fn=None):
+                  pos_offset=0, moe_fn=None, layer: int = 0):
     """One pre-norm decoder block (attention + FFN/MoE) on ``x``
     [B, T, D]; ``lp`` is this layer's param dict (no leading L dim).
     Returns (x, aux_loss) — aux is 0 for dense FFN, the load-balancing
@@ -615,13 +711,21 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
     ``pos_offset`` shifts the rotary positions: callers running this
     layer INSIDE a manual island on a sequence SHARD (pp+sp) pass
     ``axis_index("sp") * local_T`` so every shard embeds its global
-    positions; the flat path's T is already global and keeps 0."""
+    positions; the flat path's T is already global and keeps 0.
+
+    ``layer`` is the block's place in a stack of several kinds
+    (``cfg.layer_types``): its kind sets the rotary embedding and the
+    scope around ``attend``, ``attn_window`` or ``attn_full``, the names
+    the serve programs write; the caller passes that kind's ``attend``."""
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
     B, T = x.shape[0], x.shape[1]
     pos = jnp.arange(T) + pos_offset
+    if cfg.layer_types is not None:
+        attend = _scoped("attn_window" if cfg.sliding(layer)
+                         else "attn_full", attend)
 
     with jax.named_scope("attn"):
-        q, kk, vv = attention_inputs(cfg, lp, x, pos)
+        q, kk, vv = attention_inputs(cfg, lp, x, pos, layer)
         if Hkv != H and not getattr(attend, "handles_gqa", False):
             # GQA: tile kv heads up to H for impls that need square
             # heads (flash reads grouped K/V natively and skips this
@@ -639,20 +743,43 @@ def decoder_layer(cfg: TransformerConfig, attend, constrain, x, lp,
     return x, aux
 
 
-def _refuse_served_only(cfg: TransformerConfig, what: str) -> None:
-    """The trainer scans ONE block over one stack of parameters and
-    holds every expert; only the serve programs (``serve/decode.py``)
-    run a stack of several kinds or a chip's share of the experts."""
-    served_only = [name for name, there in (
-        ("n_dense_layers", cfg.n_dense_layers),
-        ("layer_types", cfg.layer_types),
-        ("moe_experts_held", cfg.moe_experts_held is not None)) if there]
-    if served_only:
+def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
+    """An entry point that takes one stack of one block (the pipeline's
+    stage scan, the quantized steps' islands) refuses, by name, a
+    configuration whose layers are a list of several kinds or that
+    holds a chip's share of the experts (``cfg.mixed``): ROADMAP C5b."""
+    if cfg.mixed:
         raise NotImplementedError(
-            f"{what} does not run a configuration with "
-            f"{' or '.join(served_only)}: the trainer's layer scan is one "
-            "block over one stack holding every expert (ROADMAP C5b). "
-            "The configuration is served through ServeEngine.")
+            f"{what} does not run a configuration with layer_types, "
+            "n_dense_layers or moe_experts_held: it takes one stack of one "
+            "block, and such a configuration's layers are a list (ROADMAP "
+            "C5b). make_train_step without compression= trains it on a "
+            "dp mesh.")
+
+
+def _refuse_mixed_off_dp(cfg: TransformerConfig, what: str, mesh) -> None:
+    """:func:`forward_with_aux`, :func:`lm_loss`, :func:`make_train_step`
+    and :func:`moe_routing_report` run a ``cfg.mixed`` configuration as
+    a loop over its layers, over ``dp`` alone: ``tp``, ``sp``, ``ep``,
+    ``pp`` and ``fsdp`` over the lists are not shown to shard, and are
+    refused by name (ROADMAP C5b)."""
+    if not cfg.mixed or mesh is None:
+        return
+    sharded = [f"{axis}={size}" for axis, size in dict(mesh.shape).items()
+               if axis != "dp" and size > 1]
+    if sharded:
+        raise NotImplementedError(
+            f"{what} runs a configuration with layer_types, n_dense_layers "
+            f"or moe_experts_held over dp alone, not over {sharded}: the "
+            "window has no ring or Ulysses island (sp), the held experts "
+            "no exchange (ep), and the lists of layers are not shown to "
+            "shard over tp, fsdp or pp (ROADMAP C5b)")
+
+
+def _stack_of(params):
+    """A mixed configuration's layers from the first down, leading
+    dense ones and then the rest: the server's two lists, as one."""
+    return list(params.get("dense_layers", ())) + list(params["layers"])
 
 
 def forward_with_aux(params, tokens, cfg: TransformerConfig,
@@ -662,10 +789,15 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
     With a mesh: activations constrained to ``P(('dp','fsdp'), 'sp')``
     on [B, T] dims; attention heads tp-sharded by GSPMD propagation from
     the weight specs.
+
+    One block scanned over one stack of parameters; a configuration of
+    several kinds of layer (``cfg.mixed``) is a loop over its lists
+    instead, each layer checkpointed by itself, with its kind's
+    attention and rotary embedding.
     """
-    _refuse_served_only(cfg, "forward_with_aux")
+    _refuse_mixed_off_dp(cfg, "forward_with_aux", mesh)
     constrain = _constrainer(mesh)
-    attend = _attention_island(cfg, mesh)
+    attend_of = _attention_by_layer(cfg, mesh)
     moe_fn = (moe_lib.make_moe_ffn(cfg.moe, mesh,
                                    dispatch=cfg.moe_dispatch,
                                    codec=cfg.moe_compression)
@@ -677,21 +809,33 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
             x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
 
-    def layer(x, lp):
-        return decoder_layer(cfg, attend, constrain, x, lp,
-                             moe_fn=moe_fn)
+    def layer(x, lp, i=0):
+        return decoder_layer(cfg, attend_of(i), constrain, x, lp,
+                             moe_fn=moe_fn, layer=i)
 
-    if cfg.remat:
-        layer = jax.checkpoint(layer, policy=remat_policy_fn(cfg),
-                               prevent_cse=cfg.remat_prevent_cse)
-
-    x, auxes = lax.scan(layer, x, params["layers"],
-                        unroll=cfg.scan_unroll)
+    if cfg.mixed:
+        aux = jnp.zeros((), jnp.float32)
+        for i, lp in enumerate(_stack_of(params)):
+            one = functools.partial(layer, i=i)
+            if cfg.remat:
+                # no scan stands between this layer's recomputation and
+                # its forward: CSE would merge them and keep what remat
+                # gives up
+                one = jax.checkpoint(one, policy=remat_policy_fn(cfg),
+                                     prevent_cse=True)
+            x, a = one(x, lp)
+            aux = aux + a
+    else:
+        if cfg.remat:
+            layer = jax.checkpoint(layer, policy=remat_policy_fn(cfg),
+                                   prevent_cse=cfg.remat_prevent_cse)
+        x, auxes = lax.scan(layer, x, params["layers"],
+                            unroll=cfg.scan_unroll)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
         logits = x @ params["lm_head"]
         logits = constrain(logits, ("dp", "fsdp"), "sp", "tp")
-    return logits, auxes.sum()
+    return logits, aux if cfg.mixed else auxes.sum()
 
 
 def forward(params, tokens, cfg: TransformerConfig,
@@ -705,25 +849,45 @@ def moe_routing_report(params, tokens, cfg: TransformerConfig
     """:func:`moe_lib.moe_routing_stats`' keys for every MoE layer of
     the model on ``tokens`` [B, T]: one forward pass (no mesh) in which
     each layer also counts the claims on its experts and those past
-    capacity. The overflow is summed over layers; the load ratio is the
-    largest layer's. Host-callable telemetry, outside the train step:
-    it runs a program of its own."""
+    capacity (with a chip's share of the experts: the claims on the
+    held ones, and those its dispatch would not run). The overflow is
+    summed over layers; the load ratio is the largest layer's. A
+    configuration that holds a share also reports
+    ``moe_local_pair_share``: the pairs on held experts over all
+    ``B·T·K`` pairs of a layer, the share of the experts held if the
+    router is even. Host-callable telemetry, outside the train step: it
+    runs a program of its own."""
     moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
 
     def counting(h, lp):
         y, _aux = moe_fn(h, lp)
-        return y, moe_lib.routing_counts(h, lp["router"], cfg.moe)
+        return y, moe_lib.routing_counts(h, lp["router"], cfg.moe,
+                                         lp.get("router_bias"))
 
     @jax.jit
     def run(params, tokens):
         x = embed_lookup(params["embed"], tokens, cfg.dtype, None)
-        attend = _attention_island(cfg, None)
-        return lax.scan(
-            lambda x, lp: decoder_layer(cfg, attend, _constrainer(None), x,
-                                        lp, moe_fn=counting),
-            x, params["layers"])[1]
+        attend_of = _attention_by_layer(cfg, None)
+        if not cfg.mixed:
+            return lax.scan(
+                lambda x, lp: decoder_layer(cfg, attend_of(),
+                                            _constrainer(None), x, lp,
+                                            moe_fn=counting),
+                x, params["layers"])[1]
+        counted = []
+        for i, lp in enumerate(_stack_of(params)):
+            x, counts = decoder_layer(cfg, attend_of(i), _constrainer(None),
+                                      x, lp, moe_fn=counting, layer=i)
+            if "moe" in lp:
+                counted.append(counts)
+        return tuple(jnp.stack(c) for c in zip(*counted))
 
-    return moe_lib.routing_summary(*run(params, tokens))
+    counts, overflow = run(params, tokens)
+    report = moe_lib.routing_summary(counts, overflow)
+    if cfg.moe_experts_held is not None:
+        report["moe_local_pair_share"] = float(counts.sum(-1).mean()) / (
+            tokens.shape[0] * tokens.shape[1] * cfg.moe_top_k)
+    return report
 
 
 def lm_loss(params, batch, cfg: TransformerConfig,
@@ -773,15 +937,16 @@ def make_train_step(cfg: TransformerConfig, mesh: Mesh, optimizer=None, *,
     those axes > 1 raise.
     """
     import optax
-    _refuse_served_only(cfg, "make_train_step")
     if optimizer is None:
         optimizer = optax.adamw(3e-4, weight_decay=0.01)
 
     from horovod_tpu import compression as compression_lib
     codec = compression_lib.in_jit_codec(compression)
     if codec != "none":
+        _refuse_mixed(cfg, f"make_train_step(compression={codec!r})")
         return _make_quantized_train_step(cfg, mesh, optimizer,
                                           compression, codec)
+    _refuse_mixed_off_dp(cfg, "make_train_step", mesh)
 
     def init_state(key):
         params = init_params(cfg, key)

@@ -36,13 +36,18 @@ NEG_INF = -1e30
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
                 scale: float, causal: bool, block_q: int, block_k: int,
-                seq_len: int):
+                seq_len: int, window: Optional[int] = None):
     """One (batch*head, q-block, kv-block) grid step of the online
-    softmax. Scratch (acc, m, l) persists across the kv dimension."""
+    softmax. Scratch (acc, m, l) persists across the kv dimension.
+    With a ``window`` the kv dimension of the grid holds only as many
+    steps as a q block has kv blocks that are not wholly behind it, and
+    step j is the j-th of them (``_fwd_first_kv``)."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = ki = pl.program_id(2)
+    if window is not None:
+        ki = _fwd_first_kv(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
@@ -74,6 +79,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         mask = k_pos >= seq_len                     # padded kv rows
         if causal:
             mask = mask | (k_pos > q_pos)
+        if window is not None:
+            mask = mask | (k_pos <= q_pos - window)
         s = jnp.where(mask, NEG_INF, s)
 
         m_prev = m_scr[:]                            # [bq, 1]
@@ -87,7 +94,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:]
         safe_l = jnp.where(l > 0, l, 1.0)        # fully-masked (pad) rows
@@ -99,7 +106,17 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def _default_blocks(t: int):
+def _fwd_first_kv(qi, block_q: int, block_k: int, window: int):
+    """The first kv block that holds a key some query of q block ``qi``
+    sees through the window (``qi`` a Python or a traced integer): the
+    block of the key ``window - 1`` before the q block's first query."""
+    first_key = qi * block_q - window + 1
+    if isinstance(qi, int):
+        return max(first_key, 0) // block_k
+    return jnp.maximum(first_key, 0) // block_k
+
+
+def _default_blocks(t: int, window: Optional[int] = None):
     """Shape-derived tile sizes. Sequence-spanning blocks win through
     medium sequence — grid overhead dominates small tiles (1024×1024
     at seq 1024 measures 61.6% vs 53.3% MFU for 128×128 on v5e,
@@ -107,7 +124,18 @@ def _default_blocks(t: int):
     for both forward and fwd+bwd). Capped at 1024: a 2048×2048 tile's
     f32 scores need 21 MiB of scoped VMEM at seq 4096 against the v5e's
     16 MiB default (Mosaic refuses it); at seq 2048 it compiles, and
-    whether it would be faster there is not measured."""
+    whether it would be faster there is not measured.
+
+    With a ``window`` (shorter than ``t``: the callers drop one that is
+    not): square blocks of the window's size, so that a q block's grid
+    is two kv blocks, up to the same 1024. On the v5e at ``[32, 8192,
+    128]`` bf16 with window 1024, ms a forward: 1024x1024 **3.59**,
+    512x1024 3.71, 512x512 4.25, 256x512 4.55, 1024x512 5.30, 256x256
+    6.39 (6.53 without a window): fewer and larger steps win again,
+    though half of each of the two blocks is masked."""
+    if window is not None:
+        b = min(1024, _round_up(window, 128))
+        return b, b
     if t <= 4096:
         b = min(1024, _round_up(t, 128))
         return b, b
@@ -115,7 +143,7 @@ def _default_blocks(t: int):
 
 
 def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
-         out_dtype=None, q_per_kv: int = 1):
+         out_dtype=None, q_per_kv: int = 1, window: Optional[int] = None):
     """q: [BH, T, D]; k/v: [B·Hkv, T, D] with BH = B·Hkv·q_per_kv ->
     (out [BH, T, D], lse [BH, T]).
 
@@ -136,15 +164,38 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         seq_len=t)
+
+    def kv_block(i, j):
+        return j
+
+    if window is not None:
+        # Only the kv blocks between the window's edge and the diagonal
+        # are steps of the grid: what lies wholly behind the window is
+        # neither fetched nor computed, and costs no grid step. A step
+        # past the diagonal (a q block near the start sees fewer blocks)
+        # names the diagonal's block again, which is not fetched twice.
+        def first(i):
+            return _fwd_first_kv(i, bq, bk, window)
+
+        def last(i):
+            return ((i + 1) * bq - 1) // bk
+
+        grid = grid[:2] + (max(last(i) - first(i) + 1
+                               for i in range(tp // bq)),)
+        kernel = functools.partial(kernel, window=window)
+
+        def kv_block(i, j):
+            return jnp.minimum(first(i) + j, last(i))
+
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d),
-                         lambda b, i, j: (b // q_per_kv, j, 0)),
+                         lambda b, i, j: (b // q_per_kv, kv_block(i, j), 0)),
             pl.BlockSpec((1, bk, d),
-                         lambda b, i, j: (b // q_per_kv, j, 0)),
+                         lambda b, i, j: (b // q_per_kv, kv_block(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -187,7 +238,11 @@ def _bwd_blocks(t: int, d: int, itemsize: int):
     float32 and of 256 in bf16, and refuse 256 in float32, 512 in bf16
     and any 2048 (compile-only): 512 KiB a row block, halving ``block``
     past it. A power of two, so that ``sub`` divides it on a lane
-    boundary."""
+    boundary.
+
+    A window changes neither: at ``[32, 8192, 128]`` with window 1024,
+    forward + backward took 8.70 ms at sub 512, 9.10 at 256 and 9.59 at
+    128 (the edge block is tiled as the diagonal one is)."""
     block = 1024
     while block > 128 and block * d * itemsize > 512 * 1024:
         block //= 2
@@ -220,17 +275,70 @@ def _bwd_tile(q, k, v, do, lse, delta, *, scale, hidden=None):
     return pt, pt * (dpt - delta)
 
 
-def _bwd_visit(add, qi, ki, *, causal, block, sub, n_k, valid):
+def _bwd_tiles(below: int, block: int, sub: int, window: Optional[int]):
+    """What a causal backward computes of the square block ``below``
+    blocks under the diagonal (0: the one on it): a list of ``(q rows,
+    kv rows, hidden)`` for :func:`_bwd_tile`. A block with every pair
+    visible is one entry, whole and unmasked; a block that the diagonal
+    or the window's edge crosses is its ``sub``-sized tiles, those with
+    no visible pair left out and only those that an edge crosses
+    masked (so the MXU does 3/4 or 5/8 of such a block and not all of
+    it). Key c of the block is hidden from its query a above the
+    diagonal, ``c > a + below * block``, and behind the window, ``c <=
+    a + below * block - window``; ``hidden`` takes the kv row and the
+    query column inside a tile."""
+    above = below * block                       # c > a + above: not yet
+    behind = None if window is None else below * block - window
+    if block - 1 <= above and (behind is None or block - 1 + behind < 0):
+        return [(slice(None), slice(None), None)]
+
+    def rows_over(d):                           # kv rows > query col + d
+        return (lambda row, col: row > col) if d == 0 else (
+            lambda row, col: row > col + d)
+
+    def rows_upto(d):                           # kv rows <= query col + d
+        return lambda row, col: row <= col + d
+
+    tiles = []
+    for a in range(0, block, sub):
+        for c in range(0, block, sub):
+            if c > a + sub - 1 + above or (
+                    behind is not None and c + sub - 1 <= a + behind):
+                continue
+            masks = []
+            if c + sub - 1 > a + above:
+                masks.append(rows_over(a + above - c))
+            if behind is not None and c <= a + sub - 1 + behind:
+                masks.append(rows_upto(a + behind - c))
+            hidden = None
+            if len(masks) == 1:
+                hidden = masks[0]
+            elif masks:
+                hidden = (lambda m: lambda row, col: m[0](row, col)
+                          | m[1](row, col))(masks)
+            tiles.append((pl.ds(a, sub), pl.ds(c, sub), hidden))
+    return tiles
+
+
+def _bwd_blocks_below(block: int, window: int) -> int:
+    """How many blocks under the diagonal hold a pair inside the
+    window: the block ``below`` is wholly behind it once ``window <=
+    (below - 1) * block + 1``."""
+    return (window - 2) // block + 1
+
+
+def _bwd_visit(add, qi, ki, *, causal, block, sub, n_k, valid, window=None):
     """What one (q block ``qi``, kv block ``ki``) grid step computes:
     ``add(q rows, kv rows, hidden)`` for the parts of the square block
-    that hold a visible (query, key) pair. Causal: a block below the
-    diagonal whole and unmasked; the block on the diagonal as ``sub``-
-    sized tiles, those above the diagonal left out and only those on it
-    masked (so the MXU does 3/4 or 5/8 of a diagonal block and not all
-    of it); a block above it nothing, the forward's rule. ``valid``
-    rows of the last kv block are keys and the rest padding, which a
-    causal mask hides from every real query; without one they are
-    masked by count."""
+    that hold a visible (query, key) pair (:func:`_bwd_tiles`). Causal:
+    a block below the diagonal whole and unmasked, the block on the
+    diagonal as tiles, a block above it nothing, the forward's rule.
+    With a ``window`` the grid only holds steps from the diagonal down
+    to the last block the window reaches, each whole or as tiles by its
+    distance from the diagonal; a step that falls off the sequence
+    computes nothing. ``valid`` rows of the last kv block are keys and
+    the rest padding, which a causal mask hides from every real query;
+    without one they are masked by count."""
     whole = slice(None)
     if not causal:
         if valid == block:
@@ -240,22 +348,28 @@ def _bwd_visit(add, qi, ki, *, causal, block, sub, n_k, valid):
             pl.when(ki == n_k - 1)(
                 lambda: add(whole, whole, lambda row, col: row >= valid))
         return
-    pl.when(ki < qi)(lambda: add(whole, whole, None))
-
-    @pl.when(ki == qi)
-    def _diagonal():
-        for a in range(block // sub):
-            for c in range(a + 1):
-                add(pl.ds(a * sub, sub), pl.ds(c * sub, sub),
-                    (lambda row, col: row > col) if a == c else None)
+    if window is None:
+        pl.when(ki < qi)(lambda: add(whole, whole, None))
+        cases = [(0, ki == qi)]
+    else:
+        on_sequence = (ki >= 0) & (qi < n_k)
+        cases = [(below, (qi - ki == below) & on_sequence)
+                 for below in range(_bwd_blocks_below(block, window) + 1)]
+    for below, here in cases:
+        @pl.when(here)
+        def _tiles(below=below):
+            for qs, ks, hidden in _bwd_tiles(below, block, sub, window):
+                add(qs, ks, hidden)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, n_q, scale, **where):
     """One (batch*kv-head, kv-block, group-head x q-block) grid step:
     the kv block's dK and dV accumulate in float32 scratch over every
-    query head of its group and every q block that sees it, and are
-    written once. The GQA sum over the group happens here."""
+    query head of its group and every q block that sees it (``n_q`` a
+    head: all of them, or with a window those from the diagonal down to
+    its edge), and are written once. The GQA sum over the group happens
+    here."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -273,7 +387,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[ks] += jax.lax.dot(dst.astype(q.dtype), q,
                                   preferred_element_type=jnp.float32)
 
-    _bwd_visit(add, j % n_q, pl.program_id(1), **where)
+    qi, ki = j % n_q, pl.program_id(1)
+    if where["window"] is not None:
+        qi = ki + qi
+    _bwd_visit(add, qi, ki, **where)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -284,10 +401,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_acc, *, scale, **where):
     """One (batch*head, q-block, kv-block) grid step: the q block's dQ
-    = dS K accumulates in float32 scratch over the kv blocks it sees."""
-    ki = pl.program_id(2)
+    = dS K accumulates in float32 scratch over the kv blocks it sees
+    (with a window the steps are the blocks from its edge up to the
+    diagonal)."""
+    step = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -301,15 +420,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dst.astype(k.dtype), k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _bwd_visit(add, pl.program_id(1), ki, **where)
+    qi, ki = pl.program_id(1), step
+    if where["window"] is not None:
+        ki = qi - (pl.num_programs(2) - 1) + step
+    _bwd_visit(add, qi, ki, **where)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _backward(scale, causal, interpret, q_per_kv, residuals, g,
-              g_lse=None):
+              g_lse=None, window=None):
     """dq, dk, dv from the saved log-sum-exp, as two Pallas kernels that
     recompute the probabilities a tile at a time in VMEM: nothing of
     size [.., T, T] exists in HBM at any length, blocks above the
@@ -335,27 +457,37 @@ def _backward(scale, causal, interpret, q_per_kv, residuals, g,
 
     args = (rows(q), rows(k), rows(v), rows(g), stat(lse), stat(delta))
     where = dict(scale=scale, causal=causal, block=block, sub=sub, n_k=n,
-                 valid=t - (n - 1) * block)
-    # A causal step above the diagonal computes nothing: it names the
-    # block of the nearest step that does, which is then not fetched
-    # again (3.84 -> 3.71 ms a backward at the cells' shape, v5e).
+                 valid=t - (n - 1) * block, window=window)
+    # With a window the steps are the blocks between the diagonal and
+    # the window's edge alone: what lies wholly behind it is no step of
+    # either grid. ``m`` steps a head (dkv) or a q block (dq).
+    m = n if window is None else min(n, _bwd_blocks_below(block, window) + 1)
+
+    # A causal step above the diagonal computes nothing, nor does a
+    # windowed one that falls off the sequence: it names the block of
+    # the nearest step that does, which is then not fetched again
+    # (3.84 -> 3.71 ms a backward at the cells' shape, v5e).
     def q_seeing(qi, ki):     # dkv: the first q block to see kv block ki
+        if window is not None:              # qi counts from the diagonal
+            return jnp.minimum(ki + qi, n - 1)
         return jnp.maximum(qi, ki) if causal else qi
 
     def kv_seen(ki, qi):      # dq: the last kv block q block qi sees
+        if window is not None:              # ki counts from the edge
+            return jnp.maximum(qi - (m - 1) + ki, 0)
         return jnp.minimum(ki, qi) if causal else ki
 
     # kv block outermost; the group's heads and their q blocks reduce.
     q_rows = pl.BlockSpec(
         (1, block, d),
-        lambda b, i, j: (b * q_per_kv + j // n, q_seeing(j % n, i), 0))
+        lambda b, i, j: (b * q_per_kv + j // m, q_seeing(j % m, i), 0))
     q_stat = pl.BlockSpec(
         (1, 1, block),
-        lambda b, i, j: (b * q_per_kv + j // n, 0, q_seeing(j % n, i)))
+        lambda b, i, j: (b * q_per_kv + j // m, 0, q_seeing(j % m, i)))
     kv_rows = pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_q=n, **where),
-        grid=(bh // q_per_kv, n, q_per_kv * n),
+        functools.partial(_bwd_dkv_kernel, n_q=m, **where),
+        grid=(bh // q_per_kv, n, q_per_kv * m),
         in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stat, q_stat],
         out_specs=[kv_rows, kv_rows],
         out_shape=[jax.ShapeDtypeStruct((k.shape[0], tp, d), k.dtype),
@@ -372,7 +504,7 @@ def _backward(scale, causal, interpret, q_per_kv, residuals, g,
         (1, block, d), lambda b, i, j: (b // q_per_kv, kv_seen(j, i), 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **where),
-        grid=(bh, n, n),
+        grid=(bh, n, m),
         in_specs=[q_rows, kv_rows, kv_rows, q_rows, q_stat, q_stat],
         out_specs=q_rows,
         out_shape=jax.ShapeDtypeStruct((bh, tp, d), q.dtype),
@@ -383,52 +515,70 @@ def _backward(scale, causal, interpret, q_per_kv, residuals, g,
     return dq[:, :t], dk[:, :t], dv[:, :t]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret, q_per_kv):
+def _checked_window(window: Optional[int], causal: bool, t: int):
+    """``window`` as the kernels take it: None where it hides nothing."""
+    if window is None:
+        return None
+    if not causal:
+        raise NotImplementedError(
+            "flash attention with a window and causal=False: the window "
+            "is the causal one, p - window < j <= p; no kernel here hides "
+            "keys ahead of a window's end without hiding all keys ahead")
+    if window < 1:
+        raise ValueError(f"attention window {window} holds no key")
+    return None if window >= t else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, q_per_kv,
+           window):
     out, _ = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, interpret=interpret, q_per_kv=q_per_kv)
+                  block_k=block_k, interpret=interpret, q_per_kv=q_per_kv,
+                  window=window)
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-               q_per_kv):
+               q_per_kv, window):
     out, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
                     block_k=block_k, interpret=interpret,
-                    q_per_kv=q_per_kv)
+                    q_per_kv=q_per_kv, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, q_per_kv,
+def _flash_bwd(scale, causal, block_q, block_k, interpret, q_per_kv, window,
                residuals, g):
     with jax.named_scope("flash_bwd"):
-        return _backward(scale, causal, interpret, q_per_kv, residuals, g)
+        return _backward(scale, causal, interpret, q_per_kv, residuals, g,
+                         window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_lse(q, k, v, scale, causal, block_q, block_k, interpret,
-               out_dtype, q_per_kv):
+               out_dtype, q_per_kv, window):
     return _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
                 block_k=block_k, interpret=interpret, out_dtype=out_dtype,
-                q_per_kv=q_per_kv)
+                q_per_kv=q_per_kv, window=window)
 
 
 def _flash_lse_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                   out_dtype, q_per_kv):
+                   out_dtype, q_per_kv, window):
     out, lse = _fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
                     block_k=block_k, interpret=interpret,
-                    out_dtype=out_dtype, q_per_kv=q_per_kv)
+                    out_dtype=out_dtype, q_per_kv=q_per_kv, window=window)
     return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, out_dtype,
-                   q_per_kv, residuals, g):
+                   q_per_kv, window, residuals, g):
     g_out, g_lse = g
     with jax.named_scope("flash_bwd"):
         return _backward(scale, causal, interpret, q_per_kv, residuals,
-                         g_out, g_lse)
+                         g_out, g_lse, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -439,28 +589,31 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
                              interpret: Optional[bool] = None,
-                             out_dtype=None):
+                             out_dtype=None, window: Optional[int] = None):
     """``[BH, T, D]``-layout flash attention returning ``(out, lse)``
     — the building block for blockwise composition (ring attention
     merges per-chunk results by logsumexp weighting). Differentiable
     in both outputs. ``out_dtype=jnp.float32`` keeps chunk outputs at
-    merge precision (callers that round once at the end)."""
+    merge precision (callers that round once at the end). ``window``
+    as :func:`flash_attention` has it."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    dq, dk = _default_blocks(q.shape[1])
+    window = _checked_window(window, causal, q.shape[1])
+    dq, dk = _default_blocks(q.shape[1], window)
     return _flash_lse(q, k, v, float(scale), causal,
                       dq if block_q is None else block_q,
                       dk if block_k is None else block_k, interpret,
-                      jnp.dtype(out_dtype) if out_dtype else None, 1)
+                      jnp.dtype(out_dtype) if out_dtype else None, 1, window)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Fused attention over ``[B, T, H, D]`` q with ``[B, T, Hkv, D]``
     k/v, ``H % Hkv == 0`` — **GQA runs natively**: grouped K/V are read
     by index-map inside the kernel, never materialized per query head
@@ -472,7 +625,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
     at seq 8192, with the causal block skip, 512×1024 is fastest for
     BOTH forward and fwd+bwd — 1.6× the old 128×128 tiles, whose grid
     overhead dwarfs their cache friendliness). Pass explicit values to
-    override."""
+    override.
+
+    ``window`` (causal only): a query at p sees the keys j with
+    ``p - window < j <= p``. Blocks wholly behind the window are
+    neither fetched nor computed, forward and backward: the work is
+    the window's pairs and not the triangle's. ``None``, or a window
+    that reaches the row's start from its end, is plain causal
+    attention, the same kernels instruction for instruction."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
@@ -487,8 +647,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
 
-    dq, dk = _default_blocks(t)
+    window = _checked_window(window, causal, t)
+    dq, dk = _default_blocks(t, window)
     out = _flash(to_bh(q), to_bh(k), to_bh(v), float(scale), causal,
                  dq if block_q is None else block_q,
-                 dk if block_k is None else block_k, interpret, h // hkv)
+                 dk if block_k is None else block_k, interpret, h // hkv,
+                 window)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)

@@ -189,6 +189,8 @@ def _wire_train_step(cfg, mesh: Mesh, loss_fn, optimizer):
 
     from horovod_tpu.models import transformer as tr
 
+    tr._refuse_mixed(cfg, "the pipeline's train step (make_pp_train_step, "
+                     "make_pp_train_step_1f1b)")
     S = mesh.shape["pp"]
     specs = pp_param_specs(cfg, S)
 

@@ -217,9 +217,11 @@ def ring_flash_attention(q, k, v, *, axis_name: str = "sp",
 
 
 def local_attention(q, k, v, *, causal: bool = True,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Plain (single-device-sequence) attention with the same layout,
-    used when ``sp == 1`` and as the reference for ring tests."""
+    used when ``sp == 1`` and as the reference for ring tests. A
+    ``window`` (causal only) hides the keys ``j <= p - window`` too."""
     B, T, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
@@ -227,6 +229,8 @@ def local_attention(q, k, v, *, causal: bool = True,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         mask = jnp.tril(jnp.ones((T, k.shape[1]), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones_like(mask), -window)
         s = jnp.where(mask[None, None], s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
@@ -262,7 +266,8 @@ def ulysses_attention(q, k, v, *, axis_name: str = "sp",
 
 def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
                       causal: bool = True, spec=None,
-                      block_q=None, block_k=None):
+                      block_q=None, block_k=None,
+                      window: Optional[int] = None):
     """Build ``attend(q, k, v)``: ring/Ulysses attention as a
     partial-manual ``shard_map`` island inside an outer GSPMD program.
 
@@ -271,6 +276,10 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
     control (``axis_names={axis_name}``). The single construction point
     for the island — the model layer and the functional API both route
     through here.
+
+    ``window`` (causal): a query sees the ``window`` keys up to its own
+    (a sliding layer of a stack of several kinds). Built for a sequence
+    on one chip, ``flash`` and ``local``; over ``sp`` it is refused.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -278,6 +287,11 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
         spec = P(None, axis_name, None, None)
     sp1 = mesh is None or \
         dict(getattr(mesh, "shape", {})).get(axis_name, 1) == 1
+    if window is not None and not (causal and sp1):
+        raise NotImplementedError(
+            "attention with a window is built for causal attention over a "
+            f"sequence on one chip ({axis_name}=1): the ring and Ulysses "
+            "islands pass whole chunks and know no window")
     if impl == "flash":
         if not sp1:
             raise NotImplementedError(
@@ -287,6 +301,8 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
         blocks = {k: v for k, v in
                   (("block_q", block_q), ("block_k", block_k))
                   if v is not None}
+        if window is not None:
+            blocks["window"] = window
         fa = functools.partial(flash_attention, causal=causal, **blocks)
         fa.handles_gqa = True  # native grouped K/V; no pre-tiling needed
         if mesh is None:
@@ -320,6 +336,9 @@ def make_sp_attention(mesh, *, axis_name: str = "sp", impl: str = "ring",
         wrapped.handles_gqa = True
         return wrapped
     if impl == "local" or sp1:
+        if window is not None:
+            return functools.partial(local_attention, causal=causal,
+                                     window=window)
         return functools.partial(local_attention, causal=causal)
     if impl == "ring":
         body = functools.partial(ring_self_attention, axis_name=axis_name,

@@ -504,8 +504,6 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         else:
             plan.append((stack, j, False, n_full))
             n_full += 1
-    rotary_everywhere = cfg.layer_types is None
-
     def embed(params, tokens):
         with jax.named_scope("embed"):
             x = tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
@@ -520,12 +518,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         or V into place ``c`` of its kind's cache, and ``attend(q, k,
         v, kc, vc, c, sliding) -> [B, T, H * Dh]`` attends, as in
         :func:`_cached_serve_fns`."""
-        for stack, j, sliding, c in plan:
+        for i, (stack, j, sliding, c) in enumerate(plan):
             lp = params[stack][j]
             kind = "attn_window" if sliding else "attn_full"
             with jax.named_scope("attn"):
-                q, k, v = tf_lib.attention_inputs(
-                    cfg, lp, x, pos, rotary=sliding or rotary_everywhere)
+                q, k, v = tf_lib.attention_inputs(cfg, lp, x, pos, i)
                 with jax.named_scope(kind):
                     with jax.named_scope("kv_write"):
                         kc, vc = (write(kc, c, sliding, k),

@@ -13,7 +13,33 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def einsum_backward(scale, causal, residuals, g, g_lse=None, q_per_kv=1):
+def _hidden(t, causal, window):
+    """[T, T] (query, key): the keys a query does not see, ahead of it
+    (``causal``) and, with a ``window``, the ``j <= p - window``."""
+    q_pos = jnp.arange(t)[:, None]
+    k_pos = jnp.arange(t)[None, :]
+    hidden = k_pos > q_pos if causal else jnp.zeros((t, t), bool)
+    if window is not None:
+        hidden |= k_pos <= q_pos - window
+    return hidden
+
+
+def dense_forward(scale, causal, q, k, v, q_per_kv=1, window=None):
+    """(out [B·H, T, D], lse [B·H, T]) in float32 from the whole score
+    tensor: what the forward kernel is held to where a window is set."""
+    bkv, t, d = k.shape
+    qc = q.astype(jnp.float32).reshape(bkv, q_per_kv, t, d)
+    s = jnp.einsum("brqd,bkd->brqk", qc, k.astype(jnp.float32)) * scale
+    s = jnp.where(_hidden(t, causal, window), NEG_INF, s)
+    lse = jnp.log(jnp.sum(jnp.exp(s - s.max(-1, keepdims=True)), -1)
+                  ) + s.max(-1)
+    out = jnp.einsum("brqk,bkd->brqd", jnp.exp(s - lse[..., None]),
+                     v.astype(jnp.float32))
+    return out.reshape(q.shape), lse.reshape(q.shape[0], t)
+
+
+def einsum_backward(scale, causal, residuals, g, g_lse=None, q_per_kv=1,
+                    window=None):
     """(dq, dk, dv) in float32 from the forward's residuals
     ``(q, k, v, out, lse)`` (q, out ``[B·H, T, D]``; k, v ``[B·Hkv, T,
     D]``; lse ``[B·H, T]``), the output's cotangent ``g`` and, when the
@@ -22,7 +48,8 @@ def einsum_backward(scale, causal, residuals, g, g_lse=None, q_per_kv=1):
 
     GQA (``q_per_kv > 1``): q-side tensors reshape to a [B·Hkv, rep]
     grouping (consecutive query heads share a kv head under the
-    batch-major flattening) and dk/dv sum over the group."""
+    batch-major flattening) and dk/dv sum over the group. ``window``:
+    the same mask the windowed kernels apply (:func:`_hidden`)."""
     q, k, v, out, lse = residuals
     rep = q_per_kv
     bkv, t, d = k.shape
@@ -31,10 +58,8 @@ def einsum_backward(scale, causal, residuals, g, g_lse=None, q_per_kv=1):
     kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
 
     s = jnp.einsum("brqd,bkd->brqk", qc, kf) * scale
-    if causal:
-        q_pos = jnp.arange(t)[:, None]
-        k_pos = jnp.arange(t)[None, :]
-        s = jnp.where(k_pos > q_pos, NEG_INF, s)
+    if causal or window is not None:
+        s = jnp.where(_hidden(t, causal, window), NEG_INF, s)
     p = jnp.exp(s - lse.reshape(bkv, rep, t)[..., None])
 
     dv = jnp.einsum("brqk,brqd->bkd", p, doc)

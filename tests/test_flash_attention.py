@@ -297,6 +297,64 @@ def test_pallas_backward_with_lse_cotangent(small_bwd_tiles, causal, t,
     _assert_grads_close(got, want, exact=dtype == jnp.float32)
 
 
+@pytest.mark.parametrize("q_per_kv", [1, 4, 8])
+@pytest.mark.parametrize("window", [64, 128, 200])
+@pytest.mark.parametrize("t", [192, 200, 384])
+def test_window_kernels_match_the_dense_reference(monkeypatch, t, window,
+                                                  q_per_kv):
+    """The three kernels with a window (ISSUE 34), forward, dq, dk, dv
+    and the log-sum-exp's cotangent, against the dense form with the
+    same mask. Blocks of 128 with tiles of 64, so that a window of 64
+    is smaller than a block, 128 equal to one and 200 no multiple of
+    one: at 384 the grids hold blocks wholly behind the window (never
+    visited), blocks its edge crosses, and whole ones; 192 and 200 end
+    in a padded block. A window that reaches the row's start is no
+    window."""
+    import horovod_tpu.ops.flash_attention as fa
+    from reference_flash_bwd import dense_forward, einsum_backward
+
+    monkeypatch.setattr(fa, "_bwd_blocks", lambda *shape: (128, 64))
+    h = 8
+    ks = jax.random.split(jax.random.PRNGKey(t + window), 5)
+    q = jax.random.normal(ks[0], (h, t, 32)) * 0.5
+    k = jax.random.normal(ks[1], (h // q_per_kv, t, 32)) * 0.5
+    v = jax.random.normal(ks[2], (h // q_per_kv, t, 32)) * 0.5
+    g = jax.random.normal(ks[3], q.shape)
+    g_lse = jax.random.normal(ks[4], (h, t))
+    scale = 32 ** -0.5
+    w = fa._checked_window(window, True, t)
+    assert (w is None) == (window >= t)
+    out, lse = fa._fwd(q, k, v, scale=scale, causal=True, block_q=128,
+                       block_k=128, interpret=True, q_per_kv=q_per_kv,
+                       window=w)
+    want_out, want_lse = dense_forward(scale, True, q, k, v, q_per_kv,
+                                       window)
+    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+    res = (q, k, v, out, lse)
+    got = fa._backward(scale, True, True, q_per_kv, res, g, g_lse, window=w)
+    want = einsum_backward(scale, True, res, g, g_lse, q_per_kv,
+                           window=window)
+    _assert_grads_close(got, want, exact=True)
+
+
+def test_a_window_as_long_as_the_row_is_no_window_bitwise():
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(ks[0], (1, 200, 4, 32))
+    k, v = (jax.random.normal(kk, (1, 200, 2, 32)) for kk in ks[1:])
+
+    def run(**kw):
+        return jax.value_and_grad(
+            lambda q, k, v: flash_attention(q, k, v, **kw).sum(),
+            (0, 1, 2))(q, k, v)
+
+    for a, b in zip(jax.tree.leaves(run()),
+                    jax.tree.leaves(run(window=200))):
+        np.testing.assert_array_equal(a, b)
+
+
 @pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128)])
 def test_causal_block_skip_multiblock_grid(bq, bk):
     """The causal block-skip branch with a REAL multi-block kv grid

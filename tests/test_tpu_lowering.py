@@ -40,11 +40,34 @@ out = {{"device_kind": topo.devices[0].device_kind}}
 one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
 
 
-def flash_fwd_bwd(seq, batch=2, heads=16, kv_heads=8):
+def program_digest(text):
+    # The compiled program without what moves when a line of the source
+    # moves: op metadata, the tables of files, functions and frames, and
+    # the debug locations inside each Mosaic kernel's serialized MLIR
+    # (the kernel is parsed and printed without them).
+    import base64, hashlib, re
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        with ir.Context() as ctx:
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(base64.b64decode(match.group(1))
+                                  ).operation.get_asm(enable_debug_info=False)
+        return '"body":"' + hashlib.sha256(asm.encode()).hexdigest() + '"'
+
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', kernel, text)
+    text = re.sub(r", metadata=\{{[^}}]*\}}", "", text)
+    text = re.sub(r"\nFileNames\n.*?\n\n\n", "\n", text, flags=re.S)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def flash_fwd_bwd(seq, batch=2, heads=16, kv_heads=8, window=None):
     # chip_smoke.py's kernel shape: [2, seq, 16/8 heads, 128] bf16.
     def f(q, k, v):
-        return flash_attention(q, k, v, causal=True,
-                               interpret=False).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               window=window).astype(jnp.float32).sum()
     q = jax.ShapeDtypeStruct((batch, seq, heads, 128), jnp.bfloat16,
                              sharding=one)
     kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, 128), jnp.bfloat16,
@@ -54,7 +77,8 @@ def flash_fwd_bwd(seq, batch=2, heads=16, kv_heads=8):
     text = compiled.as_text()
     return {{"kernels": text.count("tpu_custom_call"),
             "score_shapes": text.count("%d,%d]" % (seq, seq)),
-            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "digest": program_digest(text)}}
 
 
 out["flash_1024"] = flash_fwd_bwd(1024)
@@ -64,6 +88,11 @@ out["flash_8192"] = flash_fwd_bwd(8192)
 out["flash_cell_gqa"] = flash_fwd_bwd(4096)
 out["flash_cell_mha"] = flash_fwd_bwd(4096, kv_heads=16)
 out["flash_32768"] = flash_fwd_bwd(32768, batch=1)
+# The window cell's attention: [32, 8192, 128] over 4 kv heads, a
+# sliding layer's window and a full layer's none.
+out["flash_window_cell"] = flash_fwd_bwd(8192, batch=1, heads=32,
+                                         kv_heads=4, window=1024)
+out["flash_full_cell"] = flash_fwd_bwd(8192, batch=1, heads=32, kv_heads=4)
 
 # Small, but with the 128-wide heads Mosaic tiles like the real ones.
 cfg = TransformerConfig(vocab_size=512, d_model=256, n_layers=2, n_heads=2,
@@ -273,6 +302,44 @@ def test_flash_backward_holds_no_score_tensor_at_the_cells_shapes():
         assert got["kernels"] == 3, (case, got)
         assert got["score_shapes"] == 0, (case, got)
         assert got["temp_bytes"] < 0.5e9, (case, got)
+
+
+def test_windowed_flash_compiles_to_three_kernels_at_the_window_cells_shape():
+    """ISSUE 34: at ``[32, 8192, 128]`` over 4 kv heads with a window
+    of 1024 the v5e compiler's forward + backward is exactly three
+    kernels, nothing with an ``8192,8192]`` in its shape and under half
+    a gigabyte of temporaries; so is a full layer's at that shape."""
+    out = _compile_for_v5e(_DRIVER)
+    for case in ("flash_window_cell", "flash_full_cell"):
+        got = out[case]
+        assert got["kernels"] == 3, (case, got)
+        assert got["score_shapes"] == 0, (case, got)
+        assert got["temp_bytes"] < 0.5e9, (case, got)
+    assert out["flash_window_cell"]["digest"] != \
+        out["flash_full_cell"]["digest"]
+
+
+# sha256 of the v5e-compiled forward + backward WITHOUT a window, taken
+# on the tree before the window was built (PR 33's; jax 0.9.0, libtpu
+# 0.0.34), with everything that names a source line stripped
+# (``program_digest`` in the driver).
+_BEFORE_THE_WINDOW = {
+    "flash_cell_gqa":
+        "822bf4bc03a7552bc9f8ab0a7f3d78bb673ce105a1ff4868bf64448dccbf9c12",
+    "flash_cell_mha":
+        "3262fd4f61149693568e5f3d551929241aedd981aac1c126d1861539de8cc7b4",
+    "flash_8192":
+        "6abca45e758bd5fcd331d8871b23aca5d6af3621382c0b034c0f49fd85b9493f",
+}
+
+
+def test_without_a_window_the_kernels_are_the_ones_before_it():
+    """``window=None`` compiles to the kernels and the program that the
+    training cells ran before ``ops/flash_attention.py`` knew a window:
+    the existing cells' attention did not move."""
+    out = _compile_for_v5e(_DRIVER)
+    for case, digest in _BEFORE_THE_WINDOW.items():
+        assert out[case]["digest"] == digest, case
 
 
 def test_flash_backward_compiles_at_32768():

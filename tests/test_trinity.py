@@ -360,8 +360,27 @@ def test_what_is_not_built_for_two_caches_is_refused_by_name(devices):
         with pytest.raises(NotImplementedError, match=axis):
             decode_lib.make_serve_fns(cfg, mesh, block_size=BS,
                                       table_width=4, ring=RING)
-    with pytest.raises(NotImplementedError, match="make_train_step"):
-        make_train_step(cfg, build_mesh(devices=devices[:1], dp=1))
+    # The trainer runs such a stack since ISSUE 34 (tests/test_mellum2.py
+    # holds it to the references); what it still refuses, by name:
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel.pipeline import make_pp_train_step
+
+    init, step, _ = make_train_step(cfg, build_mesh(devices=devices[:1],
+                                                    dp=1))
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (1, 33)), jnp.int32)
+    assert np.isfinite(float(step(init(jax.random.PRNGKey(0)),
+                                  {"tokens": tokens})[1]))
+    for axis in ("tp", "sp", "ep", "fsdp"):
+        with pytest.raises(NotImplementedError, match=axis):
+            make_train_step(cfg, build_mesh(devices=devices[:2],
+                                            **{axis: 2}))
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        make_pp_train_step(cfg, build_mesh(devices=devices[:2], pp=2),
+                           n_micro=2)
+    with pytest.raises(NotImplementedError, match="compression"):
+        make_train_step(cfg, build_mesh(devices=devices[:2], dp=2),
+                        compression=hvd.Compression.bf16)
     # OLMoE's kind stays refused: softmax routing without a capacity
     with pytest.raises(NotImplementedError, match="softmax"):
         decode_lib.make_serve_fns(
@@ -443,6 +462,44 @@ def test_the_existing_programs_did_not_move(config):
                kept._reference(cfg, None, "decode"))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+# sha256 of the lowered StableHLO of three tiny train steps shaped as the
+# three training cells' (a dense GQA decoder on one device and on dp2 x
+# fsdp2, a dropless sparse decoder with q/k norm), flash attention and
+# remat_policy full, taken on the tree before the trainer ran stacks of
+# several kinds (PR 33's; jax 0.9.0).
+_TRAIN_STEPS = {
+    "dense": (dict(n_kv_heads=2), dict(dp=1), 1,
+              "94e2c6b2d23d0437b122b5233fb386552764e05c6dc78307e74da7cc679045df"),
+    "dense_dp2_fsdp2": (
+        dict(n_kv_heads=2), dict(dp=2, fsdp=2), 4,
+        "40f2cefb81b57a21406190dbac7a412bf63f3b9ced5eecc4db720604aaf6e7bf"),
+    "sparse_dropless": (
+        dict(n_kv_heads=4, qk_norm=True, n_experts=8, moe_top_k=2, d_ff=32,
+             moe_capacity_factor=None, moe_norm_topk_prob=False,
+             moe_z_loss_coef=0.001), dict(dp=1), 1,
+        "e853534e70b81a21f9927475cb4aa872ca13151269473339c04d80b568831e8b"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TRAIN_STEPS))
+def test_the_existing_train_steps_did_not_move(devices, case):
+    """The scanned path of a configuration of one kind of layer is what
+    it was: the window in the flash kernels, the rotary embedding by
+    kind of layer, the loop over a mixed configuration's lists and the
+    held experts' gradients changed no operation of it."""
+    import hashlib
+
+    fields, axes, n, want = _TRAIN_STEPS[case]
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, sp_attention="flash",
+                                 remat_policy="full", **fields)
+    init, step, _ = make_train_step(
+        cfg, build_mesh(devices=devices[:n], **axes))
+    state = jax.eval_shape(init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    text = step.lower(state, {"tokens": jax.ShapeDtypeStruct(
+        (2 * n, 65), jnp.int32)}).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
 def test_the_two_copies_of_the_reference_are_one_text():
