@@ -14,7 +14,7 @@ of them ends the run non-zero and prints no result:
   falling, the compiled step holds a Mosaic ``tpu_custom_call``, the
   state is sharded as ``param_specs`` says;
 * kernel — ``flash_attention`` forward and gradient against a float32
-  ``local_attention`` on the same inputs;
+  ``local_attention`` on the same inputs, at each of ``KERNEL_SHAPES``;
 * server — a ``ServeEngine`` over the same widths answers eight
   requests; first token checked against ``transformer_forward``;
 * eager — ``hvd.init()``, one bf16 device-array ``hvd.allreduce``
@@ -48,6 +48,11 @@ KERNEL_FWD_TOL = 2 ** -7      # flash output vs f32 reference
 KERNEL_GRAD_TOL = 2 ** -6     # dq/dk/dv: the backward's XLA einsums run
 #                               at the TPU's default (bf16-pass) precision
 FIRST_TOKEN_TOL = 2 ** -5     # served token's reference logit vs the max
+
+# The kernel phase's shapes: the trainer's, and a cold prompt of the
+# benchmark's batch cell as `serve/decode.py::_attend_prompt` hands it
+# to the kernel (one row, GQA 4, a length that pads inside the kernel).
+KERNEL_SHAPES = ({}, dict(batch=1, seq=1536, heads=32, kv_heads=8))
 
 
 def _obs(phase: str, **kv) -> dict:
@@ -320,7 +325,8 @@ def run(cfg, mesh, *, on_chip: bool, trainer=None, kernel=None,
     """All four phases in order. Any exception propagates: no phase is
     optional. The keyword dicts resize the phases (the CPU test)."""
     phase_trainer(cfg, mesh, on_chip=on_chip, **(trainer or {}))
-    phase_kernel(**(kernel or {}))
+    for shape in ([kernel] if kernel else KERNEL_SHAPES):
+        phase_kernel(**shape)
     phase_server(cfg, **(server or {}))
     phase_eager()
 

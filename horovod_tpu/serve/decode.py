@@ -67,7 +67,13 @@ with their Hkv heads (never repeated across the GQA group, never
 copied to float32), and contracted with the queries grouped over KV
 heads — the group is a free dimension of both dots — with float32
 scores, softmax and accumulators. Only the monolithic ``prefill``
-attends prompt-locally (:func:`_attend_prompt`).
+attends prompt-locally (:func:`_attend_prompt`): past 512 tokens
+through the Pallas flash forward of ``ops/flash_attention.py``, the
+kernel the trainer runs, with the group as the kernel's index map and
+the float32 scores a tile at a time in VMEM, so a long cold prompt
+holds no ``[H, T, T]`` tensor and no repeated K or V in HBM either; up
+to 512, where that tensor is small and XLA's fusions of it are the
+faster, in the dense form.
 """
 
 from __future__ import annotations
@@ -78,25 +84,87 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
+from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK
 
 _NEG_BIG = -1e30  # matches ring_attention's finite "-inf"
 
 
-def _attend_prompt(q, k, v):
+#: The longest prompt that attends through the dense form (scores as
+#: one float32 ``[H, T, T]`` tensor, K and V repeated across the GQA
+#: group). Up to here the tensor is at most 33 MB and XLA's fusions of
+#: it are faster than the kernel's 32 grid steps of float32 dots: on
+#: the v5e at ``[1, T, 32 / 8, 128]`` bf16, ms a layer dense / flash
+#: (``tools/prefill_attn_sweep.py``): 128 0.047 / 0.062, 256 0.048 /
+#: 0.088, 512 0.079 / 0.123, and ``prefill_p50_ms.chat`` read 16.1-16.3
+#: dense and 16.6-16.8 through the kernel. Past it the scores leave fast
+#: memory: 1024 0.44 / 0.18, 2048 1.61 / 0.54 alone, and several times
+#: that inside ``prefill`` (PERF.md, PR 35).
+_DENSE_PROMPT = 512
+
+
+def _prompt_block(t: int) -> int:
+    """The flash forward's square tile for a prompt of ``t`` tokens:
+    the fewest blocks of at most 1024 that cover it, evenly sized, in
+    multiples of 128. Up to 1024 that is the kernel's own default, one
+    sequence-spanning block; past it the default would pad every length
+    to a multiple of 1024 and run three 1024-blocks for any ``t`` up to
+    2048, where two blocks of half the length cover it with no padding
+    and a causal grid of three. On the v5e at ``[1, t, 32 / 8, 128]``
+    bf16, ms a layer (``tools/prefill_attn_sweep.py``): 1280 **0.33**
+    (block 640) against 0.58 (1024) and 0.50 (512); 1536 **0.41** (768)
+    against 0.57 and 0.49; 1792 **0.50** (896) against 0.56 and 0.77;
+    2048 0.54 (1024) against 0.76 (512)."""
+    n = -(-t // 1024)
+    return -(-t // (128 * n)) * 128
+
+
+def _attend_prompt(q, k, v, mesh=None):
     """Causal attention of a whole prompt over itself (the monolithic
-    ``prefill``): q [1, T, H, Dh], k/v [1, T, Hkv, Dh] as projected,
-    repeated across the GQA group for ``local_attention``. Returns
-    [1, T, H * Dh]."""
-    rep = q.shape[2] // k.shape[2]
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    return local_attention(q, k, v, causal=True).reshape(*q.shape[:2], -1)
+    ``prefill``): q [1, T, H, Dh], k/v [1, T, Hkv, Dh] as projected.
+    Returns [1, T, H * Dh]. One mathematics in two forms, chosen by
+    ``T`` alone (the shape is what the code observes): float32 scores,
+    softmax and accumulators over the inputs' dtype, and the keys of a
+    bucket's padding (positions >= ``length``) seen only by padded
+    queries, which ``emit`` never reads.
+
+    A short prompt (``T <= _DENSE_PROMPT``) is ``local_attention`` over
+    K and V repeated across the GQA group. A longer one is the Pallas
+    flash forward (``ops/flash_attention.py``): the group is an index
+    map of the kernel, so K and V are read with their Hkv heads and
+    never repeated, and scores, softmax statistics and the accumulator
+    are tiles in VMEM, so no ``[H, T, T]`` tensor reaches HBM and
+    blocks above the diagonal are skipped. Any ``T`` is padded to the
+    kernel's blocks inside it and the padded keys are masked there.
+
+    Over a ``mesh`` the kernel runs as an island manual over every
+    axis, its heads sharded over ``tp`` as the projections leave them
+    and replicated over the rest (GSPMD cannot partition a Mosaic
+    call; the dense form it partitions by itself); where ``tp`` does
+    not divide Hkv, K and V are repeated up to H first, as the
+    trainer's island does."""
+    t, rep = q.shape[1], q.shape[2] // k.shape[2]
+    if t <= _DENSE_PROMPT:
+        return local_attention(q, jnp.repeat(k, rep, axis=2),
+                               jnp.repeat(v, rep, axis=2),
+                               causal=True).reshape(*q.shape[:2], -1)
+    block = _prompt_block(t)
+    attend = functools.partial(flash_attention, causal=True, block_q=block,
+                               block_k=block)
+    if mesh is not None:
+        if k.shape[2] % mesh.shape.get("tp", 1):
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        heads = P(None, None, "tp" if "tp" in mesh.axis_names else None)
+        attend = jax.shard_map(
+            attend, mesh=mesh, in_specs=(heads, heads, heads),
+            out_specs=heads, axis_names=frozenset(mesh.axis_names),
+            check_vma=False)
+    return attend(q, k, v).reshape(*q.shape[:2], -1)
 
 
 def _attend_pages(q, kc, vc, l, tables, pos):
@@ -297,7 +365,7 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
             params, kc, vc, x, pos,
             lambda kc, vc, l, k, v: write_blocks(
                 kc, vc, l, k, v, block_table[:n_blk]),
-            lambda q, k, v, kc, vc, l: _attend_prompt(q, k, v))
+            lambda q, k, v, kc, vc, l: _attend_prompt(q, k, v, mesh))
         return kc, vc, emit(params, x,
                             lambda x: jnp.take(x[0], length - 1, axis=0))
 

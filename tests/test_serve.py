@@ -521,6 +521,51 @@ def test_served_decode_matches_full_forward(served_model):
         assert got == ref
 
 
+def _prompt_attention_cases():
+    for kv_heads, group in ((1, "gqa4"), (4, "mha")):
+        # two short lengths, which keep the dense form itself; past 512
+        # the kernel: a bucket that is one tile, a length that pads
+        # inside it, and one past 1024, two tiles a side
+        # (``_prompt_block``)
+        for length in (16, 384, 640, 700, 1100):
+            yield pytest.param(4, kv_heads, length, None,
+                               id=f"{group}-{length}")
+    # heads sharded over tp as the projections leave them; and a tp
+    # that does not divide the KV heads, which are then repeated
+    yield pytest.param(8, 2, 640, dict(tp=2), id="gqa4-640-tp2")
+    yield pytest.param(8, 2, 640, dict(dp=2, tp=4), id="gqa4-640-dp2tp4")
+
+
+@pytest.mark.parametrize("heads,kv_heads,length,mesh_axes",
+                         list(_prompt_attention_cases()))
+def test_prompt_attention_is_local_attention_over_repeated_kv(
+        heads, kv_heads, length, mesh_axes, devices):
+    """``prefill``'s attention (past 512 tokens the Pallas flash
+    forward, the GQA group an index map, interpreted here) against the
+    form it replaced there, which stays the plain reference:
+    ``local_attention`` over K and V repeated across the group, with
+    its ``[H, T, T]`` scores."""
+    from horovod_tpu.parallel import build_mesh
+    from horovod_tpu.parallel.ring_attention import local_attention
+    from horovod_tpu.serve.decode import _attend_prompt
+
+    mesh = None
+    if mesh_axes:
+        n = int(np.prod(list(mesh_axes.values())))
+        mesh = build_mesh(devices=devices[:n], **mesh_axes)
+    keys = jax.random.split(jax.random.PRNGKey(length), 3)
+    q, k, v = (jax.random.normal(key, (1, length, h, 16), jnp.float32) * 0.5
+               for key, h in zip(keys, (heads, kv_heads, kv_heads)))
+    rep = heads // kv_heads
+    want = local_attention(q, jnp.repeat(k, rep, axis=2),
+                           jnp.repeat(v, rep, axis=2), causal=True)
+    got = jax.jit(lambda q, k, v: _attend_prompt(q, k, v, mesh))(q, k, v)
+    assert got.shape == (1, length, heads * 16) and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want.reshape(got.shape)),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_eos_stops_early(served_model):
     cfg, params = served_model
     probe = _mk_engine(served_model).generate([[1, 2, 3]], 8)[0]
@@ -688,6 +733,37 @@ def test_prefix_cache_and_chunked_bitwise_parity(served_model):
     assert cached == ref
     assert chunked == ref
     assert chunked_nocache == ref
+
+
+def test_long_cold_prompt_through_the_kernel_matches_chunked_prefill():
+    """A cold prompt past ``_DENSE_PROMPT`` is one monolithic
+    ``prefill`` that attends through the flash forward (interpreted
+    here); the same prompt in chunks runs ``prefill_resume``'s paged
+    attention over the pool. Two attentions that share no code give
+    the same tokens, and the first is the full forward's."""
+    from horovod_tpu.serve.decode import _DENSE_PROMPT
+
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, remat=False,
+                                 max_seq=1024)
+    params = init_transformer(cfg, jax.random.PRNGKey(0))
+    prompt = np.random.RandomState(35).randint(1, 256, size=600).tolist()
+    assert len(prompt) > _DENSE_PROMPT
+    kw = dict(max_batch=2, block_size=16, max_prompt=640, max_new_tokens=4,
+              prefill_buckets=(128, 640), prefix_caching=False)
+
+    def serve(**more):
+        eng = ServeEngine(cfg, params, ServeConfig(**kw, **more),
+                          clock=FakeClock())
+        return eng.generate([prompt], 4)[0], eng.metrics.prefill_steps
+
+    whole, calls = serve()
+    assert calls == 1
+    chunked, calls = serve(prefill_chunk=128)
+    assert calls == 5
+    assert whole == chunked
+    logits = transformer_forward(params, jnp.asarray([prompt], jnp.int32),
+                                 cfg)[0, -1]
+    assert whole[0] == int(jnp.argmax(logits.astype(jnp.float32)))
 
 
 def test_chunked_prefill_interleaves_with_decode(served_model):

@@ -12,8 +12,11 @@ subprocess: libtpu is noisy at start-up, and the drivers below rebind
 import functools
 import json
 import os
+import re
 import subprocess
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -162,6 +165,24 @@ pool = "bf16[%d,%d,%d,%d,%d]" % kv.shape
 layer = "bf16[1,%d,%d,%d,%d]" % kv.shape[1:]
 out = {{"device_kind": topo.devices[0].device_kind,
        "pool_bytes": kv.size * kv.dtype.itemsize}}
+
+
+def prompt_attention(compiled, bucket):
+    # What `prefill` attends with: the Mosaic calls by name, with the
+    # shapes they take, and whatever holds a bucket x bucket square a
+    # head (scores, their mask, their bf16 copy).
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    return {{"kernels": [re.search(r'op_name="([^"]*)"', ln).group(1)
+                        for ln in calls],
+            "kernel_operands": [re.search(
+                r"operand_layout_constraints=\{{((?:[^{{}}]|\{{[^{{}}]*\}})*)\}}",
+                ln).group(1)
+                for ln in calls],
+            "score_shapes": text.count("32,%d,%d]" % (bucket, bucket)),
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+
+
 for name, fn, args in (
         ("decode", decode, (i32(8), i32(8), i32(8, WIDTH))),
         ("prefill", prefill, (i32(256), i32(), i32(WIDTH)))):
@@ -176,6 +197,19 @@ for name, fn, args in (
                     re.search(r'op_name="([^"]*)"', rest).group(1))
     out[name] = {{"ops": ops, "scatter_scopes": scatter_scopes,
                  "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+    if name == "prefill":
+        out["prefill_256"] = prompt_attention(compiled, 256)
+# ... `prefill` again at the chat cell's largest bucket, and at the
+# batch cell's largest under `batch-prefill`'s table (132 blocks a
+# sequence: 2048 + 64 tokens).
+for bucket, width in ((1024, WIDTH), (2048, 132)):
+    kv = sds((cfg.n_layers, 16 * width + 1, BS, cfg.n_kv_heads,
+              cfg.head_dim), cfg.dtype)
+    prefill = decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                        table_width=width)[0]
+    out["prefill_%d" % bucket] = prompt_attention(
+        prefill.lower(params, kv, kv, i32(bucket), i32(),
+                      i32(width)).compile(), bucket)
 print("LOWERED " + json.dumps(out))
 """
 
@@ -372,6 +406,37 @@ def test_serve_programs_move_no_buffer_of_the_pool_s_size_on_v5e():
         assert all("attn/kv_write" in scope
                    for scope in got["scatter_scopes"]), (program, got)
         assert got["temp_bytes"] < out["pool_bytes"], (program, got)
+
+
+@pytest.mark.parametrize("bucket", [1024, 2048])
+def test_prefill_attends_through_the_flash_forward_on_v5e(bucket):
+    """ISSUE 35: the cold prefill of `mistral-7b-v0.3-16l` at the chat
+    cell's largest bucket and the batch cell's largest is compiled with
+    ONE Mosaic call, ``hvd_flash_fwd`` under ``attn``, that takes the
+    queries' 32 heads and K and V with their 8 (the group is the
+    kernel's index map: no copy of them repeated to 32), holds nothing
+    with a ``[32, bucket, bucket]`` shape (the float32 scores, their
+    mask and their bf16 copy), and allocates under 0.3 GB of temporaries:
+    0.07 GB at 2048, where the dense form had 1.12 GB."""
+    got = _compile_for_v5e(_SERVE_DRIVER)["prefill_%d" % bucket]
+    assert len(got["kernels"]) == 1, got
+    assert re.search(r"^jit\(prefill\)/.*\battn/hvd_flash_fwd\b",
+                     got["kernels"][0]), got
+    assert re.findall(r"bf16\[(\d+),(\d+),128\]",
+                      got["kernel_operands"][0]) == [
+        ("32", str(bucket)), ("8", str(bucket)), ("8", str(bucket))], got
+    assert got["score_shapes"] == 0, got
+    assert got["temp_bytes"] < 0.3e9, got
+
+
+def test_a_short_prefill_keeps_the_dense_form_on_v5e():
+    """... and the chat cell's commonest bucket, 256, keeps the dense
+    form (``decode._DENSE_PROMPT``: there the chip ran it faster than
+    the kernel): no Mosaic call, ``[32, 256, 256]`` scores, 8 MB of them in
+    float32, which the compiler does not even count as temporaries."""
+    got = _compile_for_v5e(_SERVE_DRIVER)["prefill_256"]
+    assert got["kernels"] == [] and got["score_shapes"] > 0, got
+    assert got["temp_bytes"] < 0.01e9, got
 
 
 def test_two_cache_serve_programs_copy_neither_cache_nor_experts_on_v5e():
