@@ -76,7 +76,6 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
@@ -843,7 +842,7 @@ class ServeEngine:
         extra = {"trace": seq.trace} if seq.trace else {}
         with m.phase("serve:prefill", device=True, n_tokens=chunk,
                      offset=offset, **extra) as ph:
-            with TraceAnnotation("serve:prefill:dispatch"):
+            with ph.dispatch():
                 if offset == 0 and chunk == plen:
                     # Whole cold prompt: the monolithic program (exactly
                     # the pre-cache code path, and the cheaper attention
@@ -856,15 +855,12 @@ class ServeEngine:
                         self._params, self.cache.k, self.cache.v, toks,
                         np.int32(offset), np.int32(chunk),
                         self._address(seq))
-            ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
-            with TraceAnnotation("serve:prefill:sync"):
-                tok = int(tok)  # host sync — the step is done when this is
+            tok = ph.read(tok, int)  # host sync: the step is done now
         self.cache.k, self.cache.v = kc, vc
         seq.n_cached = offset + chunk
         seq.last_prefill_tok = tok
         self._record_window_positions(offset + len(toks))
-        m.record_prefill(ph.t0, ph.dur, chunk, offset=offset,
-                         trace=seq.trace)
+        m.record_prefill()
         if self.cfg.prefix_caching:
             # Publish the prompt blocks this chunk filled. A losing
             # race (hash already published by a concurrent twin) keeps
@@ -1182,13 +1178,11 @@ class ServeEngine:
         extra = {"traces": traces} if traces else {}
         with m.phase("serve:decode", device=True, n_active=n,
                      **extra) as ph:
-            with TraceAnnotation("serve:decode:dispatch"):
+            with ph.dispatch():
                 kc, vc, out = self._decode_fn(
                     self._params, self.cache.k, self.cache.v, tokens,
                     positions, address)
-            ph.args["dispatch_ms"] = (self._clock() - ph.t0) * 1e3
-            with TraceAnnotation("serve:decode:sync"):
-                out = np.asarray(out)  # host sync
+            out = ph.read(out, np.asarray)  # host sync
         with m.phase("serve:decode_post"):
             self.cache.k, self.cache.v = kc, vc
             self._record_window_positions(int(positions.max()) + 1)
@@ -1196,5 +1190,4 @@ class ServeEngine:
                 seq.n_cached += 1
                 seq.generated.append(int(out[i]))
                 seq.token_times.append(ph.end)
-            m.record_decode(ph.t0, ph.dur, n, self.cfg.max_batch,
-                            traces=traces)
+            m.record_decode(ph.dur, n, self.cfg.max_batch)

@@ -20,24 +20,58 @@ Surfaces:
   at the same two points. Every program span therefore has a twin in
   a profiler trace's host plane, and the offset between the engine's
   clock and the trace's is the difference of any twin pair
-  (docs/observability.md lists the spans and their args).
+  (docs/observability.md lists the spans and their args). A phase
+  that calls the device is a :class:`DeviceCall`: one numbered record
+  from launch to readback, which a reader of the trace joins to the
+  program's run on the chip by that number.
+* ``serve:stall`` — a device call or a host gap many times longer
+  than its kind usually is, written with what the host was doing in it
+  (its own CPU time, the process's, the collector's pauses, what
+  compiled), counted in ``stalls_total`` and logged at WARNING.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import json
+import logging
 import os
+import statistics
+import threading
 import time
 from typing import Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
+from horovod_tpu.common.compile_cache import compile_stats
+
 #: Keep at most this many latency samples per series (drop-oldest);
 #: long-running engines must not grow without bound.
 MAX_SAMPLES = 100_000
+
+#: A device call or a host gap is a stall when it is longer than
+#: ``STALL_FACTOR`` times the median of the last ``STALL_WINDOW`` of its
+#: kind AND than ``STALL_MIN_S``; a kind is judged once it has
+#: ``STALL_MIN_SAMPLES``. A decode step's spread is a few percent and a
+#: prompt's prefill varies eightfold with its length, so eight medians
+#: is a call that did not merely have more to do.
+STALL_WINDOW = 64
+STALL_FACTOR = 8.0
+STALL_MIN_S = 0.050
+STALL_MIN_SAMPLES = 8
+#: The median is taken again every this many samples, so that a call
+#: pays one comparison and not a sort.
+_REMEDIAN_EVERY = 8
+#: The CPU clocks are a system call each (6 us on the v5e's sandbox,
+#: where they also tick at 10 ms), so a device call does not read them:
+#: a mark is kept, taken again at the end of a device call once it is
+#: this old, and a stall's CPU time is counted from the mark.
+_CPU_MARK_S = 0.1
+
+_log = logging.getLogger("horovod_tpu")
 
 
 def percentile(samples: List[float], q: float) -> Optional[float]:
@@ -64,6 +98,157 @@ class Phase:
         return self.t0 + self.dur
 
 
+class DeviceCall(Phase):
+    """A phase that launches a device program and reads its result
+    back, as one record. ``call`` is the engine's count of such
+    phases: it is written on the span, on its annotation and on the
+    nested ones, so that a trace's reader finds the runtime's launch
+    inside this call's annotation and, through the launch's ``run_id``,
+    the program's run on the chip. The host's side is kept in parts: until
+    the jitted call returned (:meth:`dispatch`), until its result was
+    ready, and until that was copied to the host (:meth:`read`). A
+    phase of several launches (a speculative round's k draft steps)
+    sums each part."""
+
+    __slots__ = ("name", "call", "dispatch_s", "wait_s", "_clock", "_mark",
+                 "_gc0")
+
+    def __init__(self, name: str, call: int, clock, gc_seq: int, args: dict):
+        super().__init__(clock(), args)
+        self.name, self.call, self._clock = name, call, clock
+        self.dispatch_s = self.wait_s = 0.0
+        self._mark = self.t0
+        self._gc0 = gc_seq
+
+    def _lap(self) -> float:
+        now = self._clock()
+        lap, self._mark = now - self._mark, now
+        return lap
+
+    def dispatch(self) -> "_Dispatch":
+        """Around the jitted call: the ``:dispatch`` annotation, and
+        ``dispatch_ms`` when it returned."""
+        return _Dispatch(self)
+
+    def read(self, out, to_host):
+        """``to_host(out)`` once the jitted call's result ``out`` is
+        ready, as ``:wait`` and ``:readback`` inside ``:sync``: the wait
+        for the program, then the rest of the wait for the copy
+        (``np.asarray``, ``int``). The copy is asked for before the
+        wait, so that it follows the program on the device's queue as
+        it does when ``np.asarray`` meets a result that is not ready;
+        asked for after the wait it costs the host one more round trip
+        (0.1 ms a call on the v5e)."""
+        name, call = self.name, self.call
+        with TraceAnnotation(name + ":sync", call=call):
+            with TraceAnnotation(name + ":wait", call=call):
+                out.copy_to_host_async()
+                out.block_until_ready()
+            self.wait_s += self._lap()
+            with TraceAnnotation(name + ":readback", call=call):
+                return to_host(out)
+
+    def part(self) -> str:
+        """Where most of the call's time went."""
+        parts = {"dispatch": self.dispatch_s, "wait": self.wait_s,
+                 "readback": self.dur - self.dispatch_s - self.wait_s}
+        return max(parts, key=parts.get)
+
+
+class _Dispatch:
+    """The block :meth:`DeviceCall.dispatch` opens (a class and not a
+    generator: this runs once a device call)."""
+
+    __slots__ = ("_call", "_annotation")
+
+    def __init__(self, call: DeviceCall):
+        self._call = call
+        self._annotation = TraceAnnotation(call.name + ":dispatch",
+                                           call=call.call)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+        self._call.dispatch_s += self._call._lap()
+
+
+class _Typical:
+    """The running median of the last ``STALL_WINDOW`` durations of one
+    kind of span, and the limit past which one more is a stall."""
+
+    __slots__ = ("last", "seen", "median", "limit")
+
+    def __init__(self):
+        self.last: collections.deque = collections.deque(maxlen=STALL_WINDOW)
+        self.seen = 0
+        self.median = 0.0
+        self.limit = float("inf")
+
+    def add(self, dur: float) -> bool:
+        """Take one duration in; was it a stall by what came before?"""
+        stalled = dur > self.limit
+        self.last.append(dur)
+        self.seen += 1
+        if (self.seen % _REMEDIAN_EVERY == 0
+                and len(self.last) >= STALL_MIN_SAMPLES):
+            self.median = statistics.median(self.last)
+            self.limit = max(STALL_FACTOR * self.median, STALL_MIN_S)
+        return stalled
+
+
+class _GcWatch:
+    """The collector's pauses, for every engine of the process: one
+    ``gc.callbacks`` hook. Each pause is ``(start, duration,
+    generation)`` on ``time.perf_counter`` (the engine's clock unless a
+    test gave it another) and a ``serve:gc`` annotation, so that in a
+    profiler's trace an idle gap under a collection is named for it. A
+    reader notes ``seq`` at its interval's ends and asks
+    :meth:`between` for the pauses inside."""
+
+    def __init__(self):
+        self.pauses: collections.deque = collections.deque(maxlen=256)
+        self.seq = 0
+        self._t0: Optional[float] = None
+        self._annotation = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._annotation = TraceAnnotation(
+                "serve:gc", generation=info["generation"])
+            self._annotation.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.pauses.append((self._t0, time.perf_counter() - self._t0,
+                                info["generation"]))
+            self.seq += 1
+            self._t0 = None
+            self._annotation.__exit__(None, None, None)
+
+    def between(self, seq0: int, seq1: Optional[int] = None) -> list:
+        """The pauses that ended after ``seq`` read ``seq0`` and by the
+        time it read ``seq1`` (now, if left out), as far as kept."""
+        oldest = self.seq - len(self.pauses)
+        hi = self.seq if seq1 is None else seq1
+        return list(self.pauses)[max(seq0 - oldest, 0):max(hi - oldest, 0)]
+
+
+_gc_watch: Optional[_GcWatch] = None
+_gc_watch_lock = threading.Lock()
+
+
+def _watch_gc() -> _GcWatch:
+    """The process's one :class:`_GcWatch`, hooked in by the first
+    :class:`ServeMetrics`."""
+    global _gc_watch
+    with _gc_watch_lock:
+        if _gc_watch is None:
+            _gc_watch = _GcWatch()
+            gc.callbacks.append(_gc_watch)
+        return _gc_watch
+
+
 #: Process-wide monotonic default for the per-engine ``instance``
 #: label: N replicas sharing one exposition endpoint must not collide
 #: on the bare ``serve_`` series names (Prometheus reads duplicate
@@ -81,6 +266,10 @@ class ServeMetrics:
         self._alloc_base = (0, 0, 0)
         self.instance = (str(next(_instance_ids)) if instance is None
                          else str(instance))
+        # Device calls are numbered for the engine's life, not since
+        # reset(): a number names one call in any export.
+        self._calls = itertools.count(1)
+        self._gc = _watch_gc()
         self.reset()
         # Export through the process-wide telemetry endpoint: a scrape
         # of hvd.metrics_prometheus() (or the rank-0 metrics server)
@@ -102,6 +291,7 @@ class ServeMetrics:
         self.decode_steps = 0
         self.queue_depth = 0
         self.max_queue_depth = 0
+        self.stalls_total = 0
         self._occupancy_sum = 0.0
         # Token-granularity prefix-cache accounting: per admission,
         # how many prompt tokens were served out of the cache vs
@@ -130,9 +320,16 @@ class ServeMetrics:
             maxlen=MAX_SAMPLES)
         # Where the last device call's host sync ended (engine clock),
         # and whether a step() began since: `serve:host_gap` runs from
-        # there to the next device call's first line.
+        # there to the next device call's first line; with it the
+        # collector's count at that point, for a gap that stalls.
         self._device_idle_since: Optional[float] = None
+        self._idle_gc = 0
         self._stepped_since = False
+        # (engine clock, this thread's CPU seconds, the process's)
+        self._cpu_mark = (self.started_at, time.thread_time(),
+                          time.process_time())
+        self._typical: Dict[str, _Typical] = collections.defaultdict(
+            _Typical)
         # Allocator counters are lifetime totals; baseline them here
         # so snapshots report the same window as every other counter
         # in this object (reset-to-now), not engine-lifetime numbers.
@@ -172,23 +369,92 @@ class ServeMetrics:
         the span is written then (not if the block raised).
 
         ``device=True`` marks a phase that dispatches a device program
-        and ends in its host sync: the time since the previous such
+        and ends in its host sync, and yields a :class:`DeviceCall`:
+        the span and the annotation carry its number (``call``), the
+        span ``dispatch_ms`` and ``ready_ms`` (from its start until the
+        jitted call returned, and until the result was ready; the rest
+        is the copy to the host). The time since the previous such
         phase ended is written as ``serve:host_gap`` (chrome span only,
         after the fact), with ``across_steps`` saying whether a
         ``step()`` began in between (then it holds the caller's time
-        too)."""
-        with TraceAnnotation(name):
-            span = Phase(self._clock(), args)
+        too). Either one, when it is a stall by its kind's running
+        median, is also written as ``serve:stall`` (:meth:`_stall`)."""
+        if not device:
+            with TraceAnnotation(name):
+                span = Phase(self._clock(), args)
+                yield span
+                span.dur = self._clock() - span.t0
+            self._span(name, span.t0, span.dur, span.args)
+            return
+        call = args["call"] = next(self._calls)
+        with TraceAnnotation(name, call=call):
+            span = DeviceCall(name, call, self._clock, self._gc.seq, args)
             yield span
             span.dur = self._clock() - span.t0
-        if device:
-            if self._device_idle_since is not None:
-                self._span("serve:host_gap", self._device_idle_since,
-                           span.t0 - self._device_idle_since,
-                           {"across_steps": self._stepped_since})
-            self._device_idle_since = span.end
-            self._stepped_since = False
-        self._span(name, span.t0, span.dur, span.args)
+        args["dispatch_ms"] = span.dispatch_s * 1e3
+        args["ready_ms"] = (span.dispatch_s + span.wait_s) * 1e3
+        stalls = []
+        since = self._device_idle_since
+        if since is not None:
+            gap = span.t0 - since
+            self._span("serve:host_gap", since, gap,
+                       {"across_steps": self._stepped_since})
+            if self._typical["serve:host_gap"].add(gap):
+                stalls.append(("serve:host_gap", since, gap, "host_gap",
+                               self._gc.between(self._idle_gc, span._gc0)))
+        self._device_idle_since = end = span.end
+        self._idle_gc = self._gc.seq
+        self._stepped_since = False
+        self._span(name, span.t0, span.dur, args)
+        if self._typical[name].add(span.dur):
+            stalls.append((name, span.t0, span.dur, span.part(),
+                           self._gc.between(span._gc0)))
+        if stalls or end - self._cpu_mark[0] >= _CPU_MARK_S:
+            mark = (end, time.thread_time(), time.process_time())
+            for stall in stalls:
+                self._stall(*stall, call, self._cpu_mark, mark)
+            self._cpu_mark = mark
+
+    def _stall(self, of: str, t0: float, dur: float, part: str,
+               gc_pauses: list, call: int, mark0: tuple, mark1: tuple
+               ) -> None:
+        """One span of kind ``of`` took many times its kind's median:
+        count it, write it as ``serve:stall`` with what the host was
+        doing in it, and say so at WARNING, so that a run that stalls
+        leaves its cause whether or not anything was tracing.
+        ``part`` is where the time went (``dispatch`` | ``wait`` |
+        ``readback`` of a device call, or ``host_gap``). ``cpu_ms`` and
+        ``process_cpu_ms`` are this thread's and the process's CPU time
+        between two reads of the clocks, ``mark0`` (at most
+        ``_CPU_MARK_S`` before the stalled span began) and ``mark1``
+        (the end of the device call that found the stall), which lie
+        ``cpu_over_ms`` apart: a wait shows no CPU time of this thread,
+        a collection or a computing host as much as wall time, another
+        thread's work in ``process_cpu_ms`` alone. ``compiles`` names
+        what the compile log saw inside the span."""
+        ended = time.time() - (mark1[0] - (t0 + dur))
+        compiles = sorted({
+            str(e["fun_name"]) for e in compile_stats()["recent"]
+            if e["at"] > ended - dur and e["at"] - e["seconds"] < ended})
+        args = {
+            "of": of, "call": call, "part": part,
+            "typical_ms": self._typical[of].median * 1e3,
+            "cpu_ms": (mark1[1] - mark0[1]) * 1e3,
+            "process_cpu_ms": (mark1[2] - mark0[2]) * 1e3,
+            "cpu_over_ms": (mark1[0] - mark0[0]) * 1e3,
+            "gc_ms": sum(p[1] for p in gc_pauses) * 1e3,
+            "gc_gen": max((p[2] for p in gc_pauses), default=None),
+            "compiles": compiles}
+        self.stalls_total += 1
+        self._span("serve:stall", t0, dur, args)
+        _log.warning(
+            "serve stall: %s (call %d) took %.1f ms where %.1f ms is "
+            "typical; the time went in %s; in the %.1f ms to its call's "
+            "end: cpu %.1f ms, process cpu %.1f ms; gc %.1f ms (generation "
+            "%s), compiled %s",
+            of, call, dur * 1e3, args["typical_ms"], part,
+            args["cpu_over_ms"], args["cpu_ms"], args["process_cpu_ms"],
+            args["gc_ms"], args["gc_gen"], compiles or "nothing")
 
     def record_step(self, now: float) -> None:
         """A scheduler iteration with work to do began at ``now``:
@@ -231,11 +497,9 @@ class ServeMetrics:
         self._span("serve:request", submitted_at,
                    finished_at - submitted_at, args)
 
-    def record_prefill(self, t0: float, dur_s: float, n_tokens: int,
-                       offset: int = 0, trace: int = 0) -> None:
-        """Count one prefill chunk of ``n_tokens`` starting at token
-        ``offset``. The ``serve:prefill`` span itself is written by the
-        :meth:`phase` the engine ran the chunk under."""
+    def record_prefill(self) -> None:
+        """Count one prefill chunk. Its ``serve:prefill`` span is
+        written by the :meth:`phase` the engine ran the chunk under."""
         self.prefill_steps += 1
 
     def record_prefix_lookup(self, hit_tokens: int,
@@ -253,8 +517,8 @@ class ServeMetrics:
         self.prefix_hit_tokens += tokens
         self.prefix_prefill_tokens -= tokens
 
-    def record_decode(self, t0: float, dur_s: float, n_active: int,
-                      max_batch: int, traces=None) -> None:
+    def record_decode(self, dur_s: float, n_active: int,
+                      max_batch: int) -> None:
         """Count one decode step of ``dur_s`` for ``n_active``
         sequences. The ``serve:decode`` span itself is written by the
         :meth:`phase` the engine ran the step under."""
@@ -266,11 +530,10 @@ class ServeMetrics:
             # the step wall time IS the per-token latency sample.
             self.per_token_s.append(dur_s)
 
-    def record_spec_round(self, t0: float, draft_dur_s: float,
+    def record_spec_round(self, draft_dur_s: float,
                           verify_dur_s: float, n_active: int,
                           max_batch: int, *, proposed: int,
-                          accepted: int, emitted: int,
-                          traces=None) -> None:
+                          accepted: int, emitted: int) -> None:
         """One speculative iteration: the k batched draft decode steps
         plus the single chunked verify step (one :meth:`phase` span
         each, written by the round), with the round's
@@ -357,6 +620,9 @@ class ServeMetrics:
             "decode_steps": self.decode_steps,
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
+            # device calls and host gaps many times their kind's
+            # median, each also a `serve:stall` span and a WARNING
+            "stalls_total": self.stalls_total,
             "batch_occupancy": round(occ, 4),
             "prefix_cache_hit_rate": (
                 round(self.prefix_hit_tokens / looked_up, 4)

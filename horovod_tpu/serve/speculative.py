@@ -254,10 +254,11 @@ class SpecDecoder:
         with m.phase("serve:spec_draft", device=True, n_active=n,
                      **extra) as draft:
             for step in range(self.k):
-                kc, vc, out = self._decode_fn(
-                    self._params, self.cache.k, self.cache.v, frontier,
-                    positions, d_tables)
-                out = np.asarray(out)
+                with draft.dispatch():
+                    kc, vc, out = self._decode_fn(
+                        self._params, self.cache.k, self.cache.v, frontier,
+                        positions, d_tables)
+                out = draft.read(out, np.asarray)
                 self.cache.k, self.cache.v = kc, vc
                 proposals[:, step] = out[:n]
                 frontier = out.copy()
@@ -273,10 +274,11 @@ class SpecDecoder:
             vpos[i] = seq.n_cached
             t_tables[i] = seq.table
         with m.phase("serve:spec_verify", device=True, **extra) as verify:
-            kc, vc, ver = eng._verify_fn(
-                eng._params, eng.cache.k, eng.cache.v, chunk, vpos,
-                t_tables)
-            ver = np.asarray(ver)
+            with verify.dispatch():
+                kc, vc, ver = eng._verify_fn(
+                    eng._params, eng.cache.k, eng.cache.v, chunk, vpos,
+                    t_tables)
+            ver = verify.read(ver, np.asarray)
         eng.cache.k, eng.cache.v = kc, vc
 
         # -- accept + cursor rollback, host-side ----------------------
@@ -313,9 +315,9 @@ class SpecDecoder:
         draft.args["proposed"] = proposed_total
         verify.args.update(accepted=accepted_total, emitted=emitted_total)
         m.record_spec_round(
-            draft.t0, draft.dur, verify.dur, n, eng.cfg.max_batch,
+            draft.dur, verify.dur, n, eng.cfg.max_batch,
             proposed=proposed_total, accepted=accepted_total,
-            emitted=emitted_total, traces=traces)
+            emitted=emitted_total)
 
 
 # ---------------------------------------------------------------------------

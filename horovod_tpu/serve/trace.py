@@ -1,7 +1,7 @@
 """Request-scoped distributed tracing for the serving fleet.
 
 The serve plane already records per-step chrome spans on every engine
-(:meth:`ServeMetrics.record_prefill` / ``record_decode``), but a
+(:meth:`ServeMetrics.phase`), but a
 cross-process fleet scatters one request's life across processes with
 *different* ``perf_counter`` epochs and no shared request identity:
 you can see that *a* prefill ran on worker 2, not that it was *your*

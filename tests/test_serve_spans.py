@@ -2,8 +2,12 @@
 ``ServeMetrics.phase`` writes, where the spans begin and end against a
 fake engine clock that ticks at every read, and what a request's result
 carries of them. Same tiny geometry as tests/test_serve.py, so the jit
-cache holds one set of programs."""
+cache holds one set of programs. Since PR 36 a device call is one
+numbered record from launch to readback, and one many times longer than
+its kind's median leaves a ``serve:stall`` with its cause."""
+import gc
 import json
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +19,9 @@ from horovod_tpu.serve import engine as engine_mod
 from horovod_tpu.serve import metrics as metrics_mod
 
 TICK = 1e-3
-AFTER_THE_FACT = {"serve:host_gap", "serve:queue", "serve:request"}
+AFTER_THE_FACT = {"serve:host_gap", "serve:queue", "serve:request",
+                  "serve:stall"}
+DEVICE = ("serve:prefill", "serve:decode")
 
 
 class TickClock:
@@ -159,8 +165,11 @@ def test_device_spans_keep_their_args_and_lose_the_pool_gauges(served):
         assert d["args"].get("traces", [41]) == [41]
     assert _named(spans, "serve:decode")[0]["args"]["traces"] == [41]
     for s in _named(spans, "serve:decode") + _named(spans, "serve:prefill"):
-        # until the jitted call returned: one clock read into the span
+        # until the jitted call returned: one clock read into the span;
+        # until its result was ready: a second; the copy ends the span
         assert s["args"]["dispatch_ms"] == pytest.approx(1e3 * TICK)
+        assert s["args"]["ready_ms"] == pytest.approx(2e3 * TICK)
+        assert s["end"] - s["t0"] == pytest.approx(3 * TICK, abs=1e-6)
         assert not {"blocks_in_use", "blocks_cached"} & set(s["args"])
     # the counter track is written once a step, not once a span
     counters = [e for e in events if e["ph"] == "C"]
@@ -168,35 +177,81 @@ def test_device_spans_keep_their_args_and_lose_the_pool_gauges(served):
     assert {e["name"] for e in counters} == {"kv_blocks"}
 
 
-def test_every_span_has_a_twin_annotation_of_its_name(served_model,
-                                                      monkeypatch, tmp_path):
+class Recorder:
+    """Stands in for ``TraceAnnotation``: keeps what was opened, with
+    its stats, in ``Recorder.opened`` (the collector's ``serve:gc``,
+    which may open anywhere, left out)."""
     opened = []
 
-    class Recorder:
-        def __init__(self, name):
-            self.name = name
+    def __init__(self, name, **stats):
+        self.name, self.stats = name, stats
 
-        def __enter__(self):
-            opened.append(self.name)
+    def __enter__(self):
+        if self.name != "serve:gc":
+            Recorder.opened.append((self.name, self.stats))
 
-        def __exit__(self, *exc):
-            return False
+    def __exit__(self, *exc):
+        return False
 
+
+@pytest.fixture
+def recorded(served_model, monkeypatch, tmp_path):
+    """One request served with every annotation recorded: (the
+    annotations opened in order, the spans in order of their start)."""
+    monkeypatch.setattr(Recorder, "opened", [])
     monkeypatch.setattr(metrics_mod, "TraceAnnotation", Recorder)
-    monkeypatch.setattr(engine_mod, "TraceAnnotation", Recorder)
     eng = _engine(served_model)
     eng.submit([5, 6, 7], 3)
     eng.run_until_idle()
     spans, _ = _spans(eng, tmp_path)
-    written = [s["name"] for s in sorted(spans, key=lambda s: s["t0"])
-               if s["name"] not in AFTER_THE_FACT]
-    nested = [n for n in opened if n.count(":") == 2]
-    assert [n for n in opened if n.count(":") == 1] == written
-    assert nested == ["serve:prefill:dispatch", "serve:prefill:sync"] + [
-        "serve:decode:dispatch", "serve:decode:sync"] * 2
+    return Recorder.opened, sorted(
+        (s for s in spans if s["name"] not in AFTER_THE_FACT),
+        key=lambda s: s["t0"])
+
+
+def test_every_span_has_a_twin_annotation_of_its_name(recorded):
+    opened, spans = recorded
+    written = [s["name"] for s in spans]
+    assert [n for n, _ in opened if n.count(":") == 1] == written
+    assert [n for n, _ in opened if n.count(":") == 2] == [
+        "serve:prefill:dispatch", "serve:prefill:sync",
+        "serve:prefill:wait", "serve:prefill:readback"] + [
+        "serve:decode:dispatch", "serve:decode:sync",
+        "serve:decode:wait", "serve:decode:readback"] * 2
     assert set(written) == {"serve:schedule", "serve:prefill",
                             "serve:decode_prep", "serve:decode",
                             "serve:decode_post"}
+
+
+def test_a_device_call_has_one_number_on_its_span_twin_and_nested(recorded):
+    opened, spans = recorded
+    calls = [s["args"]["call"] for s in spans if s["name"] in DEVICE]
+    assert calls == [1, 2, 3]                    # unique and rising
+    assert not any("call" in s["args"] for s in spans
+                   if s["name"] not in DEVICE)
+    twins = [(n, st) for n, st in opened if n in DEVICE]
+    assert [st for _, st in twins] == [{"call": c} for c in calls]
+    for (name, stats) in twins:
+        nested = [st for n, st in opened if n.startswith(name + ":")
+                  and st == stats]
+        assert len(nested) == 4      # :dispatch, :sync, :wait, :readback
+    assert all(st == {} for n, st in opened if n.count(":") == 1
+               and n not in DEVICE)
+
+
+def test_two_engines_number_their_calls_apart_and_share_one_gc_hook(
+        served_model):
+    engines = [_engine(served_model) for _ in range(2)]
+    for eng in engines:
+        eng.submit([5, 6, 7], 2)
+        eng.run_until_idle()
+        calls = [e["args"]["call"] for e in eng.metrics._events
+                 if e["name"] in DEVICE]
+        assert calls == [1, 2]
+    hooks = [cb for cb in gc.callbacks
+             if isinstance(cb, metrics_mod._GcWatch)]
+    assert len(hooks) == 1
+    assert all(eng.metrics._gc is hooks[0] for eng in engines)
 
 
 def test_idle_engine_records_nothing_and_owns_no_gap(served_model, tmp_path):
@@ -229,3 +284,178 @@ def test_span_buffer_drops_the_oldest(monkeypatch):
         with m.phase("serve:decode", device=True):
             raise RuntimeError("device fell over")
     assert [e["args"]["i"] for e in m._events] == list(range(12, 20))
+
+
+# -- a stalled call leaves its cause (PR 36) ---------------------------
+
+
+class SetClock:
+    """A clock that stands still until the test moves it."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+class Result:
+    """What a jitted call returned: ready after ``wait`` seconds."""
+
+    def __init__(self, clock, wait):
+        self.clock, self.wait, self.asked = clock, wait, []
+
+    def copy_to_host_async(self):
+        self.asked.append("copy")
+
+    def block_until_ready(self):
+        self.asked.append("ready")
+        self.clock.t += self.wait
+        return self
+
+
+def _device_call(m, clock, *, gap=2e-3, dispatch=1e-3, wait=20e-3,
+                 readback=1e-3, name="serve:decode", inside=None):
+    """One device phase as the engine writes it, each part as long as
+    the test says on the clock it moves."""
+    clock.t += gap
+
+    def to_host(out):
+        clock.t += readback
+        if inside:
+            inside()
+        return out
+
+    out = Result(clock, wait)
+    with m.phase(name, device=True) as ph:
+        with ph.dispatch():
+            clock.t += dispatch
+        assert ph.read(out, to_host) is out
+    # the copy is asked for before the wait, not after it
+    assert out.asked == ["copy", "ready"]
+    return ph
+
+
+def _stalls(m):
+    return [e for e in m._events if e["name"] == "serve:stall"]
+
+
+@pytest.mark.parametrize("part,longer", [
+    ("dispatch", dict(dispatch=199e-3)),
+    ("wait", dict(wait=218e-3)),
+    ("readback", dict(readback=199e-3)),
+    ("host_gap", dict(gap=100e-3)),
+])
+def test_a_call_ten_times_its_median_is_one_stall_with_its_part(
+        part, longer, caplog):
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        for _ in range(12):
+            _device_call(m, clock)              # 22 ms, after 2 ms
+        assert m.stalls_total == 0 and not caplog.records
+        ph = _device_call(m, clock, **longer)   # 220 ms, or after 100
+        _device_call(m, clock)
+    assert m.stalls_total == 1
+    (stall,) = _stalls(m)
+    a = stall["args"]
+    of = "serve:host_gap" if part == "host_gap" else "serve:decode"
+    assert (a["of"], a["part"], a["call"]) == (of, part, ph.call)
+    assert a["typical_ms"] == pytest.approx(2.0 if part == "host_gap"
+                                            else 22.0)
+    assert stall["dur"] == pytest.approx(1e5 if part == "host_gap"
+                                         else 2.2e5)
+    assert (a["gc_ms"], a["gc_gen"], a["compiles"]) == (0.0, None, [])
+    # CPU time is counted from a mark at most 0.1 s older than the span
+    assert 0 <= a["cpu_ms"] and 0 <= a["process_cpu_ms"]
+    assert stall["dur"] / 1e3 <= a["cpu_over_ms"] <= stall["dur"] / 1e3 + (
+        100 + 22 + 2)
+    (record,) = caplog.records
+    assert record.levelno == logging.WARNING
+    assert of in record.getMessage() and part in record.getMessage()
+    # the same under both exports
+    assert m.snapshot()["stalls_total"] == 1
+    assert 'serve_stalls_total{instance="%s"} 1' % m.instance \
+        in m.prometheus()
+
+
+def test_a_call_three_times_its_median_is_no_stall(caplog):
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        for _ in range(12):
+            _device_call(m, clock)
+        _device_call(m, clock, wait=64e-3)      # 66 ms: past the floor
+        _device_call(m, clock, gap=6e-3)
+        # eight medians, and still under the floor of 50 ms
+        _device_call(m, clock, gap=40e-3)
+    assert m.stalls_total == 0 and not _stalls(m) and not caplog.records
+    assert m.snapshot()["stalls_total"] == 0
+
+
+def test_the_cpu_clocks_are_read_once_a_tenth_of_a_second_not_once_a_call(
+        monkeypatch):
+    import time as time_mod
+    reads = []
+    real = time_mod.thread_time
+    monkeypatch.setattr(time_mod, "thread_time",
+                        lambda: reads.append(1) or real())
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    for _ in range(50):
+        _device_call(m, clock)                  # 24 ms from one to the next
+    assert len(reads) == 1 + 50 // 5            # the first at reset()
+    _device_call(m, clock, wait=218e-3)
+    assert len(reads) == 1 + 50 // 5 + 1        # a stall reads them too
+
+
+def test_a_kind_is_not_judged_before_it_has_its_samples():
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    for _ in range(metrics_mod.STALL_MIN_SAMPLES - 1):
+        _device_call(m, clock)
+    _device_call(m, clock, wait=5.0)            # a first call compiles
+    assert m.stalls_total == 0
+
+
+def test_a_collection_inside_a_stalled_call_shows_on_it():
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    for _ in range(12):
+        _device_call(m, clock)
+    heap = [[i] for i in range(200_000)]        # something to walk
+    _device_call(m, clock, readback=300e-3, inside=gc.collect)
+    del heap
+    (stall,) = _stalls(m)
+    assert stall["args"]["part"] == "readback"
+    assert stall["args"]["gc_ms"] > 0 and stall["args"]["gc_gen"] == 2
+    # the pause is kept as (start, duration, generation)
+    start, dur, gen = [p for p in m._gc.pauses if p[2] == 2][-1]
+    assert dur * 1e3 <= stall["args"]["gc_ms"] + 1e-9
+    # and the next call, with no collection in it, is clean again
+    _device_call(m, clock, readback=300e-3)
+    assert _stalls(m)[-1]["args"]["gc_gen"] in (None, 0, 1)
+
+
+def test_a_compile_inside_a_stalled_call_is_named(monkeypatch):
+    import time as time_mod
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    for _ in range(12):
+        _device_call(m, clock)
+    now = time_mod.time()
+    monkeypatch.setattr(metrics_mod, "compile_stats", lambda: {"recent": [
+        {"at": now - 30.0, "kind": "compile", "fun_name": "warm_up",
+         "seconds": 2.0},
+        {"at": now + 60.0, "kind": "compile", "fun_name": "decode",
+         "seconds": 120.0}]})
+    _device_call(m, clock, dispatch=1.0)
+    (stall,) = _stalls(m)
+    assert stall["args"]["part"] == "dispatch"
+    assert stall["args"]["compiles"] == ["decode"]
+
+
+def test_the_engine_s_own_run_has_no_stall(served):
+    eng, _, _, spans, _ = served
+    assert not _named(spans, "serve:stall")
+    assert eng.metrics.snapshot()["stalls_total"] == 0
