@@ -11,6 +11,20 @@ leave immediately — the batch never drains to admit, which is where
 the throughput win over static batching comes from on mixed-length
 traffic.
 
+A decode iteration is **launched in one step and read in the next**
+(PR 37). The sampled tokens stay on the device: the call in flight's
+output array is its successor's ``tokens``, the positions advance by
+one, and a sequence that ends by its ``max_new_tokens`` ends at a step
+the host knows in advance, so step n+1 needs nothing the host reads
+from step n. In the steady state a step launches call n+1, then reads
+call n while n+1 runs, and the launch and the readback of a call cost
+the device no idle time. Whatever needs the host's view of step n
+first (a prefill, a smaller bucket, a queued request that waits for
+the slot of a sequence about to end, a migration, the end of work)
+reads the call in flight before it goes on ("drains",
+:meth:`ServeEngine._drain`); nothing is configured, the engine decides
+each step from what it observes.
+
 Admission control is two-layered:
 
 * **queue backpressure** — :meth:`submit` raises :class:`QueueFull`
@@ -47,7 +61,9 @@ blanket 503 — and rather than burning prefill FLOPs on an answer
 nobody is waiting for. The clock is injectable for tests.
 
 Fleet hooks (used by :mod:`horovod_tpu.serve.router`, all cheap
-host-side reads or bounded mutations — none of them step the engine):
+host-side reads or bounded mutations — none of them step the engine;
+the first three never touch the device, the handoff ones read the
+decode call in flight first):
 
 * :meth:`admission_snapshot` — occupancy / free KV blocks / queue
   depth, what a router polls to pick a replica;
@@ -75,6 +91,7 @@ import itertools
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from horovod_tpu.serve import decode as decode_lib
@@ -263,6 +280,37 @@ class PrefillHandoff:
         return int(self.k_pages.shape[1])
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A decode call the device has and the host has not read yet.
+    ``rows[i]`` is the sequence whose token comes out at row ``i`` of
+    ``out``, or None: a padded row, the row of a sequence that left, or
+    of one that the call before ended by ``eos_id`` (``held``: its
+    token here is discarded, and it is retired once this call is
+    read). A sequence keeps its row while calls follow one another
+    without a read in between, so ``out`` is the next call's
+    ``tokens`` as it is."""
+
+    call: Any                    # metrics.DeviceCall
+    out: Any                     # [bucket] int32, on the device
+    rows: List[Optional["_Seq"]]
+    positions: np.ndarray        # what the call was given, by row
+    tables: np.ndarray
+    slots: np.ndarray
+    held: set = dataclasses.field(default_factory=set)    # of rids
+
+    def discard(self, i: int) -> None:
+        """Row ``i``'s sequence ended at the call before this one."""
+        seq, self.rows[i] = self.rows[i], None
+        self.held.add(seq.rid)
+        args = self.call.args
+        args["n_active"] -= 1
+        if seq.trace:
+            args["traces"].remove(seq.trace)
+            if not args["traces"]:
+                del args["traces"]
+
+
 class RetireEma:
     """Inter-retirement interval EMA: the drain-rate signal behind
     every ``retry_after_s`` estimate. One implementation shared by
@@ -436,13 +484,25 @@ class ServeEngine:
         # per export (measured ~3x the compiled gather on the bench
         # payloads); widths ride the same bucket menu as inject so one
         # program per bucket serves every export.
-        import jax as _jax
-        self._export_fn = _jax.jit(lambda k, v, i: (k[:, i], v[:, i]))
+        self._export_fn = jax.jit(lambda k, v, i: (k[:, i], v[:, i]))
 
         self.metrics = ServeMetrics(clock=clock, instance=instance)
         self.metrics.attach_allocator(self.allocator)
         self._queue: collections.deque[_Queued] = collections.deque()
         self._active: List[_Seq] = []
+        # The decode call launched and not read yet (None: none). What
+        # it produces is in no host-side state until it is read.
+        self._in_flight: Optional[_InFlight] = None
+        # Where a decode call's output lies: host tokens are put there
+        # too, so that they and a predecessor's output are one kind of
+        # argument to the jitted decode, and one compiled program.
+        # Under a mesh the output comes back replicated (and what a
+        # call returns is taken up, should a compiler choose
+        # otherwise); without one it is uncommitted, as a plain
+        # device_put's result is.
+        self._tokens_sharding = (
+            None if mesh is None else jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec()))
         # Admitted sequences whose prefill has not completed: they
         # hold their block reservation and consume a batch slot, but
         # only join the decode batch once prefill finishes.
@@ -520,7 +580,10 @@ class ServeEngine:
 
     @property
     def pending(self) -> bool:
-        return bool(self._queue or self._prefilling or self._active)
+        """Work left for :meth:`step`, a decode call in flight
+        included: its tokens are nobody's until a step has read them."""
+        return bool(self._queue or self._prefilling or self._active
+                    or self._in_flight is not None)
 
     def result(self, rid: int) -> Optional[RequestResult]:
         return self._results.get(rid)
@@ -539,7 +602,10 @@ class ServeEngine:
         """Router-facing admission state: occupancy, free KV blocks,
         queue depth. Pure host-side counter reads — a router can poll
         every replica per placement decision without stepping anyone
-        or syncing a device value."""
+        or syncing a device value. ``running`` and ``kv_blocks_free``
+        may lag by the decode call in flight: a sequence whose last
+        token that call holds still counts, with its blocks, until a
+        step has read it."""
         n_run = len(self._active) + len(self._prefilling)
         return {
             "queue_depth": len(self._queue),
@@ -589,7 +655,19 @@ class ServeEngine:
         """One iteration: retire → expire → admit → prefill chunk(s)
         → decode. Each part is a :meth:`ServeMetrics.phase` span
         (docs/observability.md); a step with nothing to do records
-        nothing."""
+        nothing.
+
+        The decode call is left in flight when the step returns, and
+        the next step reads it: after it has launched that call's
+        successor (``tokens`` is the call's output on the device), or
+        before anything that needs the host's view of it. A step with
+        prefill work reads it before the prefill is dispatched, so that
+        its tokens are not stamped a prefill late, and the sequence
+        that completes its prompt joins a batch whose tokens the host
+        holds. A token is counted and stamped (``token_times``,
+        ``tokens_generated``, the end of ``serve:decode``) when the
+        host has it; a sequence that got its last token in one step's
+        read is retired at the start of the next."""
         if not self.pending:
             return
         m = self.metrics
@@ -606,6 +684,8 @@ class ServeEngine:
             m.kv_window_blocks_in_use = (
                 (self.cfg.max_batch - len(self._free_slots))
                 * self.cache.ring // self.cfg.block_size)
+        if self._prefilling:
+            self._drain("prefill")
         self._advance_prefills()
         self._decode_once()
         m.record_queue_depth(len(self._queue))
@@ -661,9 +741,13 @@ class ServeEngine:
         self.metrics.record_request(seq.submitted_at, now, **args)
 
     def _retire_finished(self, now: float) -> int:
+        # A sequence ended by eos_id while it had a row in the call
+        # launched ahead keeps its blocks and slot until that call is
+        # read: the call still writes there.
+        held = self._in_flight.held if self._in_flight is not None else ()
         still = []
         for seq in self._active:
-            if seq.finished(self.cfg.eos_id):
+            if seq.finished(self.cfg.eos_id) and seq.rid not in held:
                 self._finish(seq, now)
             else:
                 still.append(seq)
@@ -923,6 +1007,7 @@ class ServeEngine:
         package a decode replica feeds to :meth:`inject_prefilled`.
         The page copy is bitwise, so the handoff changes *where*
         decode runs, never *what* it computes."""
+        self._drain("migrate")
         return self._export_seq(self._handoff.pop(rid))
 
     def _export_seq(self, seq: _Seq) -> PrefillHandoff:
@@ -963,7 +1048,10 @@ class ServeEngine:
         """rids of RUNNING (decoding) sequences a drain could migrate
         right now: active, prefill complete, and not already finished
         (a finished-but-unretired sequence must retire HERE — exporting
-        it would decode it past its cap on the target)."""
+        it would decode it past its cap on the target). Reads the
+        decode call in flight first: the list is of what the host
+        holds."""
+        self._drain("migrate")
         return [s.rid for s in self._active
                 if not s.finished(self.cfg.eos_id)]
 
@@ -972,7 +1060,10 @@ class ServeEngine:
         :meth:`inject_prefilled` on another replica — the migrating
         half of a drain. Everything the sequence has computed (prompt
         AND generated-token K/V) moves bitwise, so the remaining
-        tokens decode to exactly what they would have been in place."""
+        tokens decode to exactly what they would have been in place.
+        The decode call in flight is read first: its token and its
+        K/V belong to what moves."""
+        self._drain("migrate")
         for i, seq in enumerate(self._active):
             if seq.rid == rid:
                 break
@@ -1024,6 +1115,10 @@ class ServeEngine:
         simply returns the reservation, which is what makes a
         mid-transfer reset resolve exactly-once at the router."""
         self._refuse_two_caches("inject")
+        # Every leg of an inject reads the decode call in flight first:
+        # the batch slots and blocks counted here, the pool scattered
+        # into and the batch joined are the host's view after it.
+        self._drain("migrate")
         if meta["block_size"] != self.cfg.block_size:
             raise ValueError(
                 f"handoff block_size {meta['block_size']} != engine "
@@ -1068,6 +1163,7 @@ class ServeEngine:
         bucket-padding contract. Chunks target disjoint block rows, so
         the committed pool state is bitwise the monolithic scatter's
         regardless of chunking. Returns pages remaining."""
+        self._drain("migrate")
         st = self._inject_staging[token]
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
@@ -1104,6 +1200,7 @@ class ServeEngine:
         the decode batch and return its rid. Registration, metrics,
         and batch membership all happen HERE — a partially-streamed
         sequence never observes any of them."""
+        self._drain("migrate")
         st = self._inject_staging[token]
         if st["cursor"] != st["n_pages"]:
             raise ValueError(
@@ -1148,46 +1245,133 @@ class ServeEngine:
             self.allocator.free(st["blocks"])
 
     def _decode_once(self) -> None:
-        if not self._active:
-            return
+        """Launch the next decode call, ahead of the read of the one in
+        flight where the host can know its batch without that call's
+        tokens: the batch of n+1 is the batch of n less the sequences
+        that reach ``max_new_tokens`` at n (one that n ends by
+        ``eos_id`` has a row in n+1 all the same, whose token is
+        discarded). Then read the call in flight. What the engine
+        observes decides, each step; nothing is configured."""
         if self._spec is not None:
             # Speculative iteration: k draft proposals per sequence,
             # one chunked target verify, host-side greedy acceptance
             # with cursor-only rollback of rejected positions. Swaps
             # ONLY this decode iteration — admission, prefill,
             # retirement, handoff all run unchanged above/below it.
+            # Acceptance is read on the host, so nothing is left in
+            # flight.
             self._spec.round()
             return
+        fl = self._in_flight
+        if fl is None:
+            self._launch(None)
+            return
+        stay = np.array([seq is not None
+                         and len(seq.generated) + 1 < seq.max_new
+                         for seq in fl.rows])
+        n = int(stay.sum())
+        leaving = sum(seq is not None for seq in fl.rows) - n
+        if n == 0:
+            self._drain("idle")
+        elif (leaving and self._queue
+              and self.cfg.scheduling == "continuous"):
+            # A sequence ends at the call in flight and a request waits
+            # for a slot: read now and launch nothing, so that the next
+            # step retires, admits and prefills, and the newcomer is in
+            # the next call. Launched ahead, that call would run a row
+            # short and the newcomer would join a step late.
+            self._drain("admit")
+        elif pick_bucket(n, self._batch_buckets) != len(fl.rows):
+            self._drain("bucket")
+            self._launch(None)
+        else:
+            self._launch(fl, stay)
+            self._read(fl)
+
+    def _launch(self, prev: Optional[_InFlight],
+                stay: Optional[np.ndarray] = None) -> None:
+        """Dispatch one decode call and leave it in flight. With
+        ``prev`` (the call in flight, not read) the rows that ``stay``
+        keep their places, ``tokens`` is ``prev``'s output where it
+        lies and a row that left is padded as padded rows are; without
+        it the batch is every unfinished sequence, packed from row 0,
+        with the tokens the host holds."""
         m = self.metrics
-        n = len(self._active)
+        if prev is None:
+            seqs = [s for s in self._active
+                    if not s.finished(self.cfg.eos_id)]
+            if not seqs:
+                return
         with m.phase("serve:decode_prep"):
-            bucket = pick_bucket(n, self._batch_buckets)
-            tokens = np.zeros(bucket, np.int32)
-            positions = np.zeros(bucket, np.int32)
-            tables = np.zeros((bucket, self._table_width), np.int32)
-            slots = np.full(bucket, NULL_SLOT, np.int32)    # padded rows'
-            for i, seq in enumerate(self._active):
-                tokens[i] = seq.last_token
-                positions[i] = seq.n_cached
-                tables[i] = seq.table
-                slots[i] = seq.slot
+            if prev is None:
+                bucket = pick_bucket(len(seqs), self._batch_buckets)
+                rows = seqs + [None] * (bucket - len(seqs))
+                tokens = np.zeros(bucket, np.int32)
+                positions = np.zeros(bucket, np.int32)
+                tables = np.zeros((bucket, self._table_width), np.int32)
+                slots = np.full(bucket, NULL_SLOT, np.int32)  # padded rows'
+                for i, seq in enumerate(seqs):
+                    tokens[i] = seq.last_token
+                    positions[i] = seq.n_cached
+                    tables[i] = seq.table
+                    slots[i] = seq.slot
+                # The same kind of argument as a predecessor's output,
+                # so that both meet one entry of the jitted decode.
+                tokens = jax.device_put(tokens, self._tokens_sharding)
+            else:
+                rows = [seq if keep else None
+                        for seq, keep in zip(prev.rows, stay)]
+                tokens = prev.out
+                positions = np.where(stay, prev.positions + 1, np.int32(0))
+                tables = np.where(stay[:, None], prev.tables, np.int32(0))
+                slots = np.where(stay, prev.slots, np.int32(NULL_SLOT))
             address = (tables, slots) if self._two_caches else tables
+        n = sum(seq is not None for seq in rows)
         # A decode step serves the whole batch, so it carries the
         # trace ids of every sampled sequence in it (plural key).
-        traces = [s.trace for s in self._active if s.trace]
+        traces = [s.trace for s in rows if s is not None and s.trace]
         extra = {"traces": traces} if traces else {}
-        with m.phase("serve:decode", device=True, n_active=n,
-                     **extra) as ph:
-            with ph.dispatch():
-                kc, vc, out = self._decode_fn(
-                    self._params, self.cache.k, self.cache.v, tokens,
-                    positions, address)
-            out = ph.read(out, np.asarray)  # host sync
+        call = m.launch("serve:decode", n_active=n, ahead=prev is not None,
+                        **extra)
+        with call.dispatch():
+            self.cache.k, self.cache.v, out = self._decode_fn(
+                self._params, self.cache.k, self.cache.v, tokens,
+                positions, address)
+        if prev is not None:
+            m.record_decode_ahead()
+        elif out.committed:
+            self._tokens_sharding = out.sharding
+        self._in_flight = _InFlight(call, out, rows, positions, tables,
+                                    slots)
+
+    def _read(self, fl: _InFlight) -> None:
+        """The host sync of a decode call: its tokens to their
+        sequences, counted and stamped at the end of the read."""
+        m = self.metrics
+        out = fl.call.read(fl.out, np.asarray)
+        m.finish(fl.call)
+        later = self._in_flight if self._in_flight is not fl else None
+        self._in_flight = later
+        now = fl.call.end
         with m.phase("serve:decode_post"):
-            self.cache.k, self.cache.v = kc, vc
-            self._record_window_positions(int(positions.max()) + 1)
-            for i, seq in enumerate(self._active):
+            self._record_window_positions(int(fl.positions.max()) + 1)
+            n = 0
+            for i, seq in enumerate(fl.rows):
+                if seq is None:
+                    continue
+                n += 1
                 seq.n_cached += 1
                 seq.generated.append(int(out[i]))
-                seq.token_times.append(ph.end)
-            m.record_decode(ph.dur, n, self.cfg.max_batch)
+                seq.token_times.append(now)
+                if (later is not None and later.rows[i] is seq
+                        and seq.finished(self.cfg.eos_id)):
+                    later.discard(i)
+            m.record_decode(fl.call.dur, n, self.cfg.max_batch)
+
+    def _drain(self, cause: str) -> None:
+        """Read the decode call in flight, if there is one, with no
+        successor launched behind it: what follows needs the host's
+        view of it (``cause``, one of ``metrics.DRAIN_CAUSES``)."""
+        if self._in_flight is not None:
+            self._read(self._in_flight)
+            self.metrics.record_decode_drain(cause)

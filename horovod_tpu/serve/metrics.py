@@ -24,6 +24,15 @@ Surfaces:
   that calls the device is a :class:`DeviceCall`: one numbered record
   from launch to readback, which a reader of the trace joins to the
   program's run on the chip by that number.
+* :meth:`ServeMetrics.launch` / :meth:`ServeMetrics.finish` — the two
+  ends of a :class:`DeviceCall` by hand, for the decode call that the
+  engine launches in one ``step()`` and reads in the next, while its
+  successor already runs (PR 37). ``serve:decode`` is then one decode
+  step as a client sees it: from the later of the call's launch and
+  the end of the previous call's read, to the end of its own read,
+  with ``ahead`` saying whether a call was in flight when it was
+  launched. ``serve:host_gap`` is written only for time in which no
+  call was in flight.
 * ``serve:stall`` — a device call or a host gap many times longer
   than its kind usually is, written with what the host was doing in it
   (its own CPU time, the process's, the collector's pauses, what
@@ -62,6 +71,12 @@ STALL_WINDOW = 64
 STALL_FACTOR = 8.0
 STALL_MIN_S = 0.050
 STALL_MIN_SAMPLES = 8
+#: Why the engine read a decode call in flight before it could launch
+#: the next one ahead (``decode_drains_<cause>_total``): a prefill was
+#: due; the batch fits a smaller bucket; nothing is left to launch; a
+#: queued request waits for the slot of a sequence that ends at the
+#: call in flight; pages are about to move in or out (export, inject).
+DRAIN_CAUSES = ("prefill", "bucket", "idle", "admit", "migrate")
 #: The median is taken again every this many samples, so that a call
 #: pays one comparison and not a sort.
 _REMEDIAN_EVERY = 8
@@ -108,22 +123,41 @@ class DeviceCall(Phase):
     the jitted call returned (:meth:`dispatch`), until its result was
     ready, and until that was copied to the host (:meth:`read`). A
     phase of several launches (a speculative round's k draft steps)
-    sums each part."""
+    sums each part.
+
+    The record has two ends, :meth:`ServeMetrics.launch` and
+    :meth:`ServeMetrics.finish`, which need not lie in one ``step()``:
+    the annotation of the call's name (its twin) is entered at the one
+    and left at the other by hand, so the twins of two neighbouring
+    decode calls overlap. ``t0``, where its span starts, is the launch,
+    or the end of the previous call's read when that came later (the
+    call was launched ahead and has been queueing behind its
+    predecessor until then)."""
 
     __slots__ = ("name", "call", "dispatch_s", "wait_s", "_clock", "_mark",
-                 "_gc0")
+                 "_gc0", "_twin", "_before_s", "_gap")
 
     def __init__(self, name: str, call: int, clock, gc_seq: int, args: dict):
+        self._twin = TraceAnnotation(name, call=call)
+        self._twin.__enter__()
         super().__init__(clock(), args)
         self.name, self.call, self._clock = name, call, clock
-        self.dispatch_s = self.wait_s = 0.0
+        self.dispatch_s = self.wait_s = self._before_s = 0.0
         self._mark = self.t0
         self._gc0 = gc_seq
+        self._gap = None
 
     def _lap(self) -> float:
         now = self._clock()
         lap, self._mark = now - self._mark, now
         return lap
+
+    def _starts_at(self, t: float) -> None:
+        """The previous call's read ended at ``t``, after this call
+        was launched: its span starts there, and what it spent in
+        dispatch lies before its span."""
+        self.t0 = self._mark = t
+        self._before_s = self.dispatch_s
 
     def dispatch(self) -> "_Dispatch":
         """Around the jitted call: the ``:dispatch`` annotation, and
@@ -148,10 +182,19 @@ class DeviceCall(Phase):
             with TraceAnnotation(name + ":readback", call=call):
                 return to_host(out)
 
+    @property
+    def ready_s(self) -> float:
+        """From the span's start until the result was ready (the
+        dispatch that lies inside the span, and the wait)."""
+        return self.dispatch_s - self._before_s + self.wait_s
+
     def part(self) -> str:
-        """Where most of the call's time went."""
-        parts = {"dispatch": self.dispatch_s, "wait": self.wait_s,
-                 "readback": self.dur - self.dispatch_s - self.wait_s}
+        """Where most of the span's time went. The ``wait`` of a call
+        launched ahead holds what the host did between the previous
+        call's read and this one's, which is one ``step()``'s own work."""
+        parts = {"dispatch": self.dispatch_s - self._before_s,
+                 "wait": self.wait_s,
+                 "readback": self.dur - self.ready_s}
         return max(parts, key=parts.get)
 
 
@@ -269,6 +312,9 @@ class ServeMetrics:
         # Device calls are numbered for the engine's life, not since
         # reset(): a number names one call in any export.
         self._calls = itertools.count(1)
+        # Device calls launched and not finished, oldest first: like
+        # the numbers, not reset() (a call may be in flight across it).
+        self._flying: List[DeviceCall] = []
         self._gc = _watch_gc()
         self.reset()
         # Export through the process-wide telemetry endpoint: a scrape
@@ -289,6 +335,11 @@ class ServeMetrics:
         self.handoffs_out = 0
         self.prefill_steps = 0
         self.decode_steps = 0
+        # Decode calls launched while their predecessor was still in
+        # flight, and the times a call in flight was read before a
+        # successor could be launched, by what stood in the way.
+        self.decode_ahead_total = 0
+        self.decode_drains: Dict[str, int] = dict.fromkeys(DRAIN_CAUSES, 0)
         self.queue_depth = 0
         self.max_queue_depth = 0
         self.stalls_total = 0
@@ -318,10 +369,11 @@ class ServeMetrics:
         # most recent spans, not its first ones.
         self._events: collections.deque = collections.deque(
             maxlen=MAX_SAMPLES)
-        # Where the last device call's host sync ended (engine clock),
-        # and whether a step() began since: `serve:host_gap` runs from
-        # there to the next device call's first line; with it the
-        # collector's count at that point, for a gap that stalls.
+        # Where the last device call's host sync ended with no other
+        # call in flight (engine clock), and whether a step() began
+        # since: `serve:host_gap` runs from there to the next device
+        # call's first line; with it the collector's count at that
+        # point, for a gap that stalls.
         self._device_idle_since: Optional[float] = None
         self._idle_gc = 0
         self._stepped_since = False
@@ -369,12 +421,14 @@ class ServeMetrics:
         the span is written then (not if the block raised).
 
         ``device=True`` marks a phase that dispatches a device program
-        and ends in its host sync, and yields a :class:`DeviceCall`:
-        the span and the annotation carry its number (``call``), the
-        span ``dispatch_ms`` and ``ready_ms`` (from its start until the
-        jitted call returned, and until the result was ready; the rest
-        is the copy to the host). The time since the previous such
-        phase ended is written as ``serve:host_gap`` (chrome span only,
+        and ends in its host sync, and yields a :class:`DeviceCall`
+        (:meth:`launch` at the block's first line, :meth:`finish` at
+        its last): the span and the annotation carry its number
+        (``call``), the span ``dispatch_ms`` (the jitted call, until it
+        returned) and ``ready_ms`` (from the span's start until the
+        result was ready; the rest is the copy to the host). The time
+        since the previous such phase ended, if no call was in flight
+        in it, is written as ``serve:host_gap`` (chrome span only,
         after the fact), with ``across_steps`` saying whether a
         ``step()`` began in between (then it holds the caller's time
         too). Either one, when it is a stall by its kind's running
@@ -386,24 +440,58 @@ class ServeMetrics:
                 span.dur = self._clock() - span.t0
             self._span(name, span.t0, span.dur, span.args)
             return
-        call = args["call"] = next(self._calls)
-        with TraceAnnotation(name, call=call):
-            span = DeviceCall(name, call, self._clock, self._gc.seq, args)
+        span = self.launch(name, **args)
+        try:
             yield span
-            span.dur = self._clock() - span.t0
-        args["dispatch_ms"] = span.dispatch_s * 1e3
-        args["ready_ms"] = (span.dispatch_s + span.wait_s) * 1e3
-        stalls = []
+        except BaseException:
+            self._flying.remove(span)
+            span._twin.__exit__(None, None, None)
+            raise
+        self.finish(span)
+
+    def launch(self, name: str, **args) -> DeviceCall:
+        """The first line of a device call: number it, enter its twin
+        annotation and read the clock. The caller dispatches inside
+        :meth:`DeviceCall.dispatch`, reads the result with
+        :meth:`DeviceCall.read`, in this ``step()`` or a later one, and
+        then calls :meth:`finish`. If no other call is in flight, the
+        time since the last one's read ended is this call's
+        ``serve:host_gap`` (written by :meth:`finish`)."""
+        call = args["call"] = next(self._calls)
+        span = DeviceCall(name, call, self._clock, self._gc.seq, args)
         since = self._device_idle_since
-        if since is not None:
-            gap = span.t0 - since
+        if since is not None and not self._flying:
+            span._gap = (since, span.t0 - since, self._stepped_since,
+                         self._gc.between(self._idle_gc, span._gc0))
+        self._device_idle_since = None
+        self._flying.append(span)
+        return span
+
+    def finish(self, span: DeviceCall) -> None:
+        """The last line of a device call, after its read: leave the
+        twin, write the span (and the host gap before its launch, if
+        it had one), move the start of a call still in flight to here,
+        and look for a stall."""
+        end = self._clock()
+        span._twin.__exit__(None, None, None)
+        span.dur = end - span.t0
+        name, call, args = span.name, span.call, span.args
+        args["dispatch_ms"] = span.dispatch_s * 1e3
+        args["ready_ms"] = span.ready_s * 1e3
+        self._flying.remove(span)
+        for other in self._flying:
+            other._starts_at(end)
+        stalls = []
+        if span._gap is not None:
+            since, gap, stepped, gc_pauses = span._gap
             self._span("serve:host_gap", since, gap,
-                       {"across_steps": self._stepped_since})
+                       {"across_steps": stepped})
             if self._typical["serve:host_gap"].add(gap):
                 stalls.append(("serve:host_gap", since, gap, "host_gap",
-                               self._gc.between(self._idle_gc, span._gc0)))
-        self._device_idle_since = end = span.end
-        self._idle_gc = self._gc.seq
+                               gc_pauses))
+        if not self._flying:
+            self._device_idle_since = end
+            self._idle_gc = self._gc.seq
         self._stepped_since = False
         self._span(name, span.t0, span.dur, args)
         if self._typical[name].add(span.dur):
@@ -517,11 +605,21 @@ class ServeMetrics:
         self.prefix_hit_tokens += tokens
         self.prefix_prefill_tokens -= tokens
 
+    def record_decode_ahead(self) -> None:
+        """A decode call was launched while its predecessor was still
+        in flight (its span says ``ahead``)."""
+        self.decode_ahead_total += 1
+
+    def record_decode_drain(self, cause: str) -> None:
+        """A decode call in flight was read with no successor launched
+        behind it, for ``cause`` (one of :data:`DRAIN_CAUSES`)."""
+        self.decode_drains[cause] += 1
+
     def record_decode(self, dur_s: float, n_active: int,
                       max_batch: int) -> None:
-        """Count one decode step of ``dur_s`` for ``n_active``
-        sequences. The ``serve:decode`` span itself is written by the
-        :meth:`phase` the engine ran the step under."""
+        """Count one decode step of ``dur_s`` whose tokens, one for
+        each of ``n_active`` sequences, the host now holds. The
+        ``serve:decode`` span itself is written by :meth:`finish`."""
         self.decode_steps += 1
         self.tokens_generated += n_active
         self._occupancy_sum += n_active / max_batch
@@ -618,6 +716,13 @@ class ServeMetrics:
             "handoffs_out": self.handoffs_out,
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
+            # of those, launched with their predecessor still in
+            # flight; and calls in flight read before a successor
+            # could be launched, in all and by cause
+            "decode_ahead_total": self.decode_ahead_total,
+            "decode_drains_total": sum(self.decode_drains.values()),
+            **{f"decode_drains_{cause}_total": n
+               for cause, n in self.decode_drains.items()},
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
             # device calls and host gaps many times their kind's

@@ -872,3 +872,236 @@ def test_ep_sharded_decode_matches(served_moe_model, devices):
                       ServeConfig(max_batch=4, block_size=8, max_prompt=16,
                                   max_new_tokens=8), mesh=mesh)
     assert eng.generate(prompts, 4) == ref
+
+
+# ---------------------------------------------------------------------------
+# Decode step n+1 launched before step n is read (PR 37)
+# ---------------------------------------------------------------------------
+
+def _sync_step(eng):
+    """A step of the engine as it was before PR 37: the decode call is
+    read in the step that launched it."""
+    eng.step()
+    eng._drain("idle")
+
+
+def _serve_staged(eng, arrivals, step):
+    """``arrivals``: {step index: [(prompt, max_new), ...]}. Submits as
+    the steps come and serves to the end; returns the tokens in the
+    order of arrival."""
+    rids, i = [], 0
+    while eng.pending or i <= max(arrivals):
+        for prompt, max_new in arrivals.get(i, ()):
+            rids.append(eng.submit(prompt, max_new))
+        step(eng)
+        i += 1
+        assert i < 500
+    return [eng.result(r).tokens for r in rids]
+
+
+def _two_cache_engines(**kw):
+    """(a maker of engines over two kinds of cache, the vocabulary)."""
+    import test_trinity as tri
+    cfg = tri.tiny()
+    params = tri.seeded(cfg)
+    return (lambda: tri.engine_for(cfg, params, **kw)), cfg.vocab_size
+
+
+@pytest.mark.parametrize("kind", ["dense", "two_caches"])
+def test_launched_ahead_the_tokens_are_the_synchronous_engine_s(
+        served_model, kind):
+    """Mixed lengths, joins into a running batch, retirements, a queue
+    that waits for a slot: the tokens are bitwise those of the engine
+    that reads each decode call in the step that launched it, and of
+    each request served alone."""
+    if kind == "dense":
+        def mk():
+            return _mk_engine(served_model, max_batch=4, max_new_tokens=16)
+        vocab, lo, hi = 256, 3, 14
+    else:
+        mk, vocab = _two_cache_engines(max_new_tokens=16)
+        lo, hi = 5, 40
+    rng = np.random.RandomState(5)
+    reqs = [(rng.randint(1, vocab, size=int(rng.randint(lo, hi))).tolist(),
+             int(n)) for n in (9, 3, 16, 5, 12, 2, 7, 11)]
+    arrivals = {0: reqs[:3], 2: reqs[3:4], 7: reqs[4:7], 15: reqs[7:]}
+    ahead, sync = mk(), mk()
+    got = _serve_staged(ahead, arrivals, lambda e: e.step())
+    want = _serve_staged(sync, arrivals, _sync_step)
+    assert got == want
+    assert [len(t) for t in got] == [n for _, n in reqs]
+    for (prompt, n), tokens in zip(reqs[:4], got):
+        assert mk().generate([prompt], n)[0] == tokens
+    a, s = ahead.metrics.snapshot(), sync.metrics.snapshot()
+    assert a["decode_ahead_total"] > 10 and s["decode_ahead_total"] == 0
+    assert a["tokens_generated"] == s["tokens_generated"] == sum(
+        n for _, n in reqs)
+    assert ahead.allocator.n_used == sync.allocator.n_used == 0
+    # a call launched ahead was never a bigger bucket's, and a drain
+    # for every cause the traffic has was counted
+    assert a["decode_drains_prefill_total"] >= 3
+    assert a["decode_drains_idle_total"] >= 1
+
+
+def _in_flight_engine(served_model, n=1, **kw):
+    """An engine with ``n`` sequences mid-decode and a call in flight."""
+    eng = _mk_engine(served_model, **kw)
+    rids = [eng.submit(p, 8) for p in _prompts(n, rng_seed=3)]
+    eng.step()                  # prefills, the first decode call launched
+    eng.step()                  # the second launched ahead, the first read
+    assert eng._in_flight is not None and eng.pending
+    assert eng.metrics.decode_ahead_total == 1
+    assert sum(eng.metrics.decode_drains.values()) == 0
+    return eng, rids
+
+
+_DRAINS = {
+    # what happens with a call in flight -> the cause counted (None: no
+    # drain, the call stays in flight and the device is not touched)
+    "prefill_due": (lambda e, r: (e.submit([9, 8, 7], 4), e.step()),
+                    "prefill"),
+    "export_running": (lambda e, r: e.export_running(r[0]), "migrate"),
+    "running_exportable": (lambda e, r: e.running_exportable(), "migrate"),
+    "inject_prefilled": (
+        lambda e, r: e.inject_prefilled(
+            _in_flight_engine((e.model_cfg, e._params))[0].export_running(0)),
+        "migrate"),
+    "admission_snapshot": (lambda e, r: e.admission_snapshot(), None),
+    "submit": (lambda e, r: e.submit([9, 8, 7], 4), None),
+    "withdraw": (lambda e, r: e.withdraw(e.submit([9, 8, 7], 4)), None),
+    "result": (lambda e, r: e.result(r[0]), None),
+    "cached_chain_len": (lambda e, r: e.cached_chain_len([b"x"]), None),
+}
+
+
+@pytest.mark.parametrize("what", sorted(_DRAINS))
+def test_what_reads_the_call_in_flight_and_what_never_does(
+        served_model, what):
+    act, cause = _DRAINS[what]
+    eng, rids = _in_flight_engine(served_model)
+    flying = eng._in_flight
+    held = [len(s.generated) for s in eng._active]
+    act(eng, rids)
+    drains = {c: n for c, n in eng.metrics.decode_drains.items() if n}
+    if cause is None:
+        assert eng._in_flight is flying and eng.pending and not drains
+        assert [len(s.generated) for s in eng._active] == held
+    else:
+        assert drains == {cause: 1}
+        assert eng._in_flight is not flying
+        assert eng.metrics.snapshot()[f"decode_drains_{cause}_total"] == 1
+    # served to the end all the same, every token there
+    eng.run_until_idle()
+    for rid in rids:
+        if eng.result(rid) is not None:
+            assert len(eng.result(rid).tokens) == 8
+    assert not eng.pending and eng._in_flight is None
+
+
+@pytest.mark.parametrize("what,cause", [
+    ("last_sequence_ends", "idle"), ("smaller_bucket", "bucket"),
+    ("a_request_waits_for_the_slot", "admit")])
+def test_drains_the_host_can_foresee(served_model, what, cause):
+    """The host knows a step ahead that a sequence reaches its
+    max_new_tokens: nothing is launched behind a call that ends the
+    last one, a smaller batch goes to its smaller bucket, and a queued
+    request is not made to wait a step for the slot."""
+    eng = _mk_engine(served_model, max_batch=2)
+    p = _prompts(3, rng_seed=8)
+    want = [_mk_engine(served_model).generate([q], n)[0]
+            for q, n in zip(p, (4, 8, 5))]
+    rids = [eng.submit(p[0], 4)]
+    if what != "last_sequence_ends":
+        rids.append(eng.submit(p[1], 8))
+    if what == "a_request_waits_for_the_slot":
+        rids.append(eng.submit(p[2], 5))
+    steps = 0
+    while eng.metrics.decode_drains[cause] == 0:
+        assert eng.pending
+        eng.step()
+        steps += 1
+        assert steps < 50
+    # the call that held request 0's last token has just been read
+    assert eng.metrics.decode_drains == {
+        **dict.fromkeys(eng.metrics.decode_drains, 0), cause: 1}
+    assert len(eng._active[0].generated) == 4 and eng.result(rids[0]) is None
+    if what == "last_sequence_ends":
+        assert eng._in_flight is None and eng.pending    # not retired yet
+    elif what == "smaller_bucket":
+        assert len(eng._in_flight.rows) == 1             # of buckets 1, 2
+        assert eng._in_flight.call.args["ahead"] is False
+    else:
+        assert eng._in_flight is None
+        eng.step()              # retires, admits, prefills, launches for 2
+        assert eng.result(rids[0]) is not None
+        assert eng._in_flight.call.args["n_active"] == 2
+    eng.run_until_idle()
+    assert [eng.result(r).tokens for r in rids] == want[:len(rids)]
+
+
+def test_eos_found_a_step_late_discards_one_row(served_model):
+    """Sequence a's token of call n is ``eos_id``; call n+1 was
+    launched with a row for it. That row's token is discarded, a's
+    blocks stay reserved until n+1 is read, and b, in the same batch,
+    never notices."""
+    pa, pb = [1, 2, 3], [7, 5, 3, 2]
+    probe = _mk_engine(served_model).generate([pa], 8)[0]
+    k = next(i for i in range(2, 7) if probe[i] not in probe[:i])
+    eos = probe[k]
+    alone_b = _mk_engine(served_model, eos_id=eos).generate([pb], 8)[0]
+    clock = FakeClock()
+    eng = _mk_engine(served_model, eos_id=eos, clock=clock)
+    a, b = eng.submit(pa, 8, trace_id=7), eng.submit(pb, 8, trace_id=9)
+    seq_a = used = None
+    while eng.result(a) is None:
+        clock.advance(1.0)
+        eng.step()
+        seq_a = seq_a or eng._active[0]
+        if seq_a.generated[-1] == eos and used is None:
+            # found at this step's read; the follower is in flight
+            # with a's row discarded, and a keeps what it holds
+            fl = eng._in_flight
+            assert fl.rows[0] is None and fl.held == {a}
+            assert fl.call.args["n_active"] == 1
+            assert fl.call.args["traces"] == [9]
+            assert seq_a in eng._active
+            assert set(seq_a.blocks) <= set(
+                blk for s in eng._active for blk in s.blocks)
+            used = eng.allocator.n_used
+            cursor = seq_a.n_cached
+    res = eng.result(a)
+    assert res.tokens == probe[:k + 1] and res.tokens[-1] == eos
+    assert len(res.token_times) == k + 1 and seq_a.n_cached == cursor
+    assert res.token_times == sorted(set(res.token_times))
+    # retired a step after the follower's read, and its blocks are back
+    assert res.finished_at == res.token_times[-1] + 2.0
+    assert eng.allocator.n_used < used
+    # ... and reusable: a newcomer takes them while b goes on
+    c = eng.submit(pa, 8)
+    eng.run_until_idle()
+    assert eng.result(b).tokens == alone_b
+    assert eng.result(c).tokens == res.tokens
+    assert eng.allocator.n_used == 0
+    assert eng.metrics.tokens_generated == (
+        2 * (k + 1) + len(alone_b))
+
+
+def test_launching_ahead_meets_no_new_entry_of_the_jitted_decode(
+        served_model):
+    """What the benchmark's warm-up relies on: one prefill and one
+    decode call of a bucket (read with nothing launched behind it) have
+    met every program and every kind of argument that a long run of
+    calls launched ahead meets."""
+    eng = _mk_engine(served_model, max_new_tokens=16)
+    for n in (1, 2, 4):
+        for p in _prompts(n, rng_seed=n):
+            eng.submit(p, 2)
+        eng.run_until_idle()
+    assert eng.metrics.decode_ahead_total == 0
+    entries = eng._decode_fn._cache_size()
+    for p in _prompts(4, rng_seed=11):
+        eng.submit(p, 16)
+    eng.run_until_idle()
+    eng.generate(_prompts(2, rng_seed=12), 9)
+    assert eng.metrics.decode_ahead_total > 20
+    assert eng._decode_fn._cache_size() == entries

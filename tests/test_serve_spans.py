@@ -4,7 +4,11 @@ fake engine clock that ticks at every read, and what a request's result
 carries of them. Same tiny geometry as tests/test_serve.py, so the jit
 cache holds one set of programs. Since PR 36 a device call is one
 numbered record from launch to readback, and one many times longer than
-its kind's median leaves a ``serve:stall`` with its cause."""
+its kind's median leaves a ``serve:stall`` with its cause. Since PR 37
+a decode call is launched in one step and read in the next, after its
+successor was launched: ``serve:decode`` is one decode step as a client
+sees it, and ``serve:host_gap`` only time in which no call was in
+flight."""
 import gc
 import json
 import logging
@@ -82,25 +86,99 @@ def test_host_gap_and_decode_tile_the_time_between_two_syncs(served):
     decodes = _named(spans, "serve:decode")
     assert len(decodes) >= 4
     gaps = {round(g["end"], 6): g for g in _named(spans, "serve:host_gap")}
+    # the first decode call follows the prefills with nothing in flight:
+    # the gap before it is the host's, inside one step
+    first = decodes[0]
+    assert first["args"]["ahead"] is False
+    gap = gaps[round(first["t0"], 6)]
+    assert gap["t0"] == pytest.approx(
+        _named(spans, "serve:prefill")[-1]["end"], abs=1e-6)
+    assert gap["args"]["across_steps"] is False
+    # a later one was launched with its predecessor in flight: it
+    # starts where that one's read ended, and no time between two reads
+    # is anybody's host gap. The one exception: request b ends, the
+    # batch of one fits a smaller bucket, and the call in flight is
+    # read before the next is launched, in the same step
+    ahead = [d["args"]["ahead"] for d in decodes]
+    assert ahead == [False, True, True, False, True]
+    for prev, cur in zip(decodes, decodes[1:]):
+        if cur["args"]["ahead"]:
+            assert cur["t0"] == pytest.approx(prev["end"], abs=1e-6)
+            assert round(cur["t0"], 6) not in gaps
+        else:
+            gap = gaps[round(cur["t0"], 6)]
+            assert gap["t0"] == pytest.approx(prev["end"], abs=1e-6)
+            assert gap["args"]["across_steps"] is False
+    # what the host does between two reads lies inside the later span:
+    # the post of the call read, the next step's schedule and, where a
+    # call was launched behind this one, its prep (and its launch)
     inner = [s for s in spans if s["name"] in (
         "serve:decode_post", "serve:schedule", "serve:decode_prep")]
-    for prev, cur in zip(decodes, decodes[1:]):
-        gap = gaps[round(cur["t0"], 6)]
-        # from the end of one device call's sync to the first line of
-        # the next one's dispatch: nothing between two syncs is missed
-        assert gap["t0"] == pytest.approx(prev["end"], abs=1e-6)
-        assert gap["args"]["across_steps"] is True
+    for cur, nxt in zip(decodes, decodes[1:]):
+        if not cur["args"]["ahead"]:
+            continue
         parts = sorted((s for s in inner
-                        if gap["t0"] <= s["t0"] and s["end"] <= gap["end"]),
+                        if cur["t0"] <= s["t0"] and s["end"] <= cur["end"]),
                        key=lambda s: s["t0"])
         assert [s["name"] for s in parts] == [
-            "serve:decode_post", "serve:schedule", "serve:decode_prep"]
+            "serve:decode_post", "serve:schedule"] + [
+            "serve:decode_prep"] * nxt["args"]["ahead"]
         for x, y in zip(parts, parts[1:]):
             assert x["end"] <= y["t0"]           # they do not overlap
-        # the gap is its three parts and the clock reads between them
-        covered = sum(s["end"] - s["t0"] for s in parts)
-        assert gap["end"] - gap["t0"] - covered == pytest.approx(
-            4 * TICK, abs=1e-6)
+
+
+def test_no_host_gap_lies_over_a_call_in_flight(served):
+    eng, _, _, spans, _ = served
+    decodes = _named(spans, "serve:decode")
+    for g in _named(spans, "serve:host_gap"):
+        for d in decodes:
+            # a decode span runs from launch (or the previous read) to
+            # its own read: a gap may touch it, not overlap it
+            assert g["end"] <= d["t0"] + 1e-6 or d["end"] <= g["t0"] + 1e-6
+    snap = eng.metrics.snapshot()
+    assert snap["decode_ahead_total"] == sum(
+        d["args"]["ahead"] for d in decodes) == len(decodes) - 2
+    assert snap["decode_steps"] == len(decodes)
+    # a call in flight was read with nothing launched behind it twice:
+    # when the batch came to fit a smaller bucket, and at the end
+    assert {c: snap[f"decode_drains_{c}_total"]
+            for c in metrics_mod.DRAIN_CAUSES} == {
+        "prefill": 0, "bucket": 1, "idle": 1, "admit": 0, "migrate": 0}
+    assert snap["decode_drains_total"] == 2
+
+
+def test_a_decode_span_ends_when_the_host_holds_its_tokens(served_model):
+    """After every step: the tokens counted and the decode spans
+    written are those the sequences hold, never those of the call in
+    flight; each token's stamp is the end of the span it came out of."""
+    clock = TickClock()
+    eng = _engine(served_model, clock=clock)
+    rids = [eng.submit([5, 6, 7, 8, 9], 6, trace_id=41),
+            eng.submit([1, 2, 3], 4, trace_id=42)]
+    seqs = {}
+    while eng.pending:
+        eng.step()
+        now = clock.t
+        seqs.update({s.rid: s for s in eng._active})
+        held = sum(len(s.generated) for s in seqs.values())
+        decodes = [e for e in eng.metrics._events
+                   if e["name"] == "serve:decode"]
+        assert eng.metrics.tokens_generated == held == len(seqs) + sum(
+            e["args"]["n_active"] for e in decodes)
+        assert eng.metrics.decode_steps == len(decodes)
+        ends = {tid: [eng.metrics.started_at + (e["ts"] + e["dur"]) * 1e-6
+                      for e in decodes if tid in e["args"]["traces"]]
+                for tid in (41, 42)}
+        for s in seqs.values():
+            assert len(s.token_times) == len(s.generated)
+            assert s.token_times[1:] == pytest.approx(ends[s.trace],
+                                                      abs=1e-6)
+            assert all(t <= now for t in s.token_times)
+        fl = eng._in_flight
+        if fl is not None:
+            # launched, numbered, and in no span and no counter yet
+            assert all(fl.call.call > e["args"]["call"] for e in decodes)
+    assert [len(eng.result(r).tokens) for r in rids] == [6, 4]
 
 
 def test_queue_prefill_and_first_token_agree(served):
@@ -165,12 +243,17 @@ def test_device_spans_keep_their_args_and_lose_the_pool_gauges(served):
         assert d["args"].get("traces", [41]) == [41]
     assert _named(spans, "serve:decode")[0]["args"]["traces"] == [41]
     for s in _named(spans, "serve:decode") + _named(spans, "serve:prefill"):
-        # until the jitted call returned: one clock read into the span;
-        # until its result was ready: a second; the copy ends the span
+        # until the jitted call returned: one clock read after launch
         assert s["args"]["dispatch_ms"] == pytest.approx(1e3 * TICK)
-        assert s["args"]["ready_ms"] == pytest.approx(2e3 * TICK)
-        assert s["end"] - s["t0"] == pytest.approx(3 * TICK, abs=1e-6)
         assert not {"blocks_in_use", "blocks_cached"} & set(s["args"])
+        # from the span's start (for a call launched ahead: the end of
+        # the read before) until its result was ready; the copy, one
+        # more read of the clock, ends the span
+        assert s["args"]["ready_ms"] == pytest.approx(
+            1e3 * (s["end"] - s["t0"] - TICK), abs=1e-3)
+        if s["name"] == "serve:prefill":
+            # launched, dispatched, ready and copied inside one block
+            assert s["end"] - s["t0"] == pytest.approx(3 * TICK, abs=1e-6)
     # the counter track is written once a step, not once a span
     counters = [e for e in events if e["ph"] == "C"]
     assert len(counters) == len(_named(spans, "serve:schedule"))
@@ -213,11 +296,13 @@ def test_every_span_has_a_twin_annotation_of_its_name(recorded):
     opened, spans = recorded
     written = [s["name"] for s in spans]
     assert [n for n, _ in opened if n.count(":") == 1] == written
-    assert [n for n, _ in opened if n.count(":") == 2] == [
-        "serve:prefill:dispatch", "serve:prefill:sync",
-        "serve:prefill:wait", "serve:prefill:readback"] + [
-        "serve:decode:dispatch", "serve:decode:sync",
-        "serve:decode:wait", "serve:decode:readback"] * 2
+    # the second decode call is launched before the first is read
+    assert [(n, st["call"]) for n, st in opened if n.count(":") == 2] == [
+        ("serve:prefill:dispatch", 1), ("serve:prefill:sync", 1),
+        ("serve:prefill:wait", 1), ("serve:prefill:readback", 1),
+        ("serve:decode:dispatch", 2), ("serve:decode:dispatch", 3)] + [
+        ("serve:decode" + part, call) for call in (2, 3)
+        for part in (":sync", ":wait", ":readback")]
     assert set(written) == {"serve:schedule", "serve:prefill",
                             "serve:decode_prep", "serve:decode",
                             "serve:decode_post"}
@@ -334,6 +419,86 @@ def _device_call(m, clock, *, gap=2e-3, dispatch=1e-3, wait=20e-3,
     # the copy is asked for before the wait, not after it
     assert out.asked == ["copy", "ready"]
     return ph
+
+
+def test_a_call_launched_ahead_spans_from_the_read_before_it_to_its_own():
+    """The two ends of a device call by hand, as the engine's decode
+    uses them: b is launched while a is in flight."""
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    _device_call(m, clock, name="serve:prefill")    # ends at +24 ms
+    t_idle = clock.t
+    clock.t += 2e-3
+    a = m.launch("serve:decode", n_active=2, ahead=False)
+    a_launched = clock.t
+    with a.dispatch():
+        clock.t += 1e-3
+    clock.t += 3e-3                  # the step returns; the next begins
+    m.record_step(clock.t)
+    b = m.launch("serve:decode", n_active=2, ahead=True)
+    with b.dispatch():
+        clock.t += 1e-3
+    out_a = Result(clock, 8e-3)
+    a.read(out_a, lambda out: out)
+    clock.t += 0.5e-3
+    m.finish(a)
+    a_end = clock.t
+    assert (a.t0, a.dur) == (a_launched, pytest.approx(13.5e-3))
+    assert a.args["dispatch_ms"] == pytest.approx(1.0)
+    assert a.args["ready_ms"] == pytest.approx(13.0)
+    # b queued behind a until here: its span starts now, its dispatch
+    # lies before it
+    assert b.t0 == a_end
+    clock.t += 2e-3                  # post, the next step's schedule
+    b.read(Result(clock, 10e-3), lambda out: out)
+    clock.t += 0.5e-3
+    m.finish(b)
+    assert b.dur == pytest.approx(12.5e-3) and b.end == clock.t
+    assert b.args["dispatch_ms"] == pytest.approx(1.0)
+    assert b.args["ready_ms"] == pytest.approx(12.0)
+    assert b.part() == "wait"
+    # one host gap, before a: none while a call was in flight
+    gaps = [e for e in m._events if e["name"] == "serve:host_gap"]
+    assert [(g["dur"], g["args"]) for g in gaps] == [
+        (pytest.approx(2e3), {"across_steps": False})]
+    assert gaps[0]["ts"] == pytest.approx((t_idle - m.started_at) * 1e6)
+    spans = [e for e in m._events if e["name"] == "serve:decode"]
+    assert [e["args"]["call"] for e in spans] == [a.call, b.call]
+    assert spans[1]["ts"] == pytest.approx(spans[0]["ts"] + spans[0]["dur"])
+    # and the next call, launched with nothing in flight, has one again
+    clock.t += 1e-3
+    c = _device_call(m, clock, gap=0.0)
+    assert len([e for e in m._events if e["name"] == "serve:host_gap"]) == 2
+
+
+def test_a_stall_of_a_call_launched_ahead_is_found_at_its_read(caplog):
+    """A step late: the call that stalls was launched in the step
+    before the one that reads it, and its span, the stall and the
+    WARNING are written when its read ends."""
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    prev = m.launch("serve:decode", ahead=False)
+    with prev.dispatch():
+        clock.t += 1e-3
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        for i in range(14):
+            cur = m.launch("serve:decode", ahead=True)
+            with cur.dispatch():
+                clock.t += 1e-3
+            # the thirteenth call runs 220 ms: seen when it is read, in
+            # the iteration after the one that launched it
+            prev.read(Result(clock, 219e-3 if i == 13 else 19e-3),
+                      lambda out: out)
+            m.finish(prev)
+            assert m.stalls_total == (1 if i == 13 else 0)
+            stalled, prev = prev, cur
+    (stall,) = _stalls(m)
+    assert stall["args"]["call"] == stalled.call == cur.call - 1
+    assert (stall["args"]["of"], stall["args"]["part"]) == (
+        "serve:decode", "wait")
+    assert stall["dur"] == pytest.approx(2.2e5)
+    assert stall["args"]["typical_ms"] == pytest.approx(20.0)
+    assert len(caplog.records) == 1
 
 
 def _stalls(m):
