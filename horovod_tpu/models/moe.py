@@ -72,10 +72,23 @@ class MoEConfig:
     #: adds nothing for the rest (``None``: it holds them all).
     experts_held: Optional[int] = None
     expert_offset: int = 0
+    #: Group-limited selection (DeepSeek-V3's ``noaux_tc``): the
+    #: experts lie in ``n_group`` equal groups, a group's score is the
+    #: sum of its two largest selection scores, and the K experts are
+    #: chosen inside the ``topk_group`` best groups (1, 1: no limit).
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if not (1 <= self.topk_group <= self.n_group
+                and self.n_experts % self.n_group == 0
+                and (self.n_group == 1 or self.top_k
+                     <= self.topk_group * self.n_experts // self.n_group)):
+            raise ValueError(
+                f"{self.topk_group} of {self.n_group} groups over "
+                f"{self.n_experts} experts do not hold top_k={self.top_k}")
         if self.capacity_factor is not None and (
                 self.scoring != "softmax" or self.shared_expert
                 or self.experts_held is not None or self.route_scale != 1.0):
@@ -161,14 +174,26 @@ def _top_k_gates(logits, cfg: MoEConfig, bias=None):
     sum to 1 (GShard) unless the configuration says not to. With
     sigmoid scoring ``probs`` is each logit's sigmoid, the K experts
     are those with the largest ``probs + bias`` ([E]: it chooses and
-    never weighs), and the gates are scaled by ``route_scale``."""
+    never weighs), and the gates are scaled by ``route_scale``. With
+    ``n_group`` > 1 the choice is made inside the ``topk_group`` groups
+    whose two largest selection scores sum highest."""
     if cfg.scoring == "sigmoid":
         probs = jax.nn.sigmoid(logits)
-        _, experts = jax.lax.top_k(probs + bias, cfg.top_k)
-        gates = jnp.take_along_axis(probs, experts, axis=-1)
+        select = probs + bias
     else:
-        probs = jax.nn.softmax(logits, axis=-1)
+        probs = select = jax.nn.softmax(logits, axis=-1)
+    if cfg.n_group > 1:
+        grouped = select.reshape(*select.shape[:-1], cfg.n_group, -1)
+        best = jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0].sum(-1)
+        _, groups = jax.lax.top_k(best, cfg.topk_group)
+        kept = (groups[..., None] == jnp.arange(cfg.n_group)).any(-2)
+        select = jnp.where(kept[..., None], grouped, -jnp.inf
+                           ).reshape(select.shape)
+    if select is probs:
         gates, experts = jax.lax.top_k(probs, cfg.top_k)
+    else:
+        _, experts = jax.lax.top_k(select, cfg.top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
     if cfg.norm_topk_prob:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     if cfg.route_scale != 1.0:

@@ -76,6 +76,10 @@ class Rotary:
                 + plain * (1 - ramp)).astype(np.float32)
 
 
+#: The kinds of layer a stack with ``layer_types`` may hold.
+LAYER_KINDS = ("sliding", "full", "kda", "mla")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32_000
@@ -181,18 +185,49 @@ class TransformerConfig:
     # nothing for the others (None: all of them).
     moe_experts_held: Optional[int] = None
     moe_expert_offset: int = 0
+    # Group-limited routing (models/moe.py: MoEConfig.n_group).
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # Two further kinds of layer_types, whose state is not cached keys
+    # (ISSUE 38; the serve programs run them, the trainer refuses):
+    # "kda", Kimi Delta Attention: q, k, v of n_heads x head_dim through
+    # a causal depthwise convolution of kda_conv taps and SiLU, a
+    # delta-rule recurrence over a state [n_heads, head_dim, head_dim] a
+    # sequence whose decay a channel is exp(kda_decay_floor *
+    # sigmoid(.)), a gated per-head RMSNorm on its output;
+    # "mla", DeepSeek-V2's latent attention without a q rank: keys and
+    # values are expanded from a cached latent of mla_kv_rank values a
+    # position beside mla_rope_dim rotated ones that every head shares
+    # (q and k are head_dim + mla_rope_dim wide, v head_dim), rotated at
+    # rope_theta in interleaved pairs, with one sigmoid gate a head.
+    kda_conv: int = 4
+    kda_decay_floor: float = -5.0
+    mla_kv_rank: int = 0
+    mla_rope_dim: int = 0
 
     def __post_init__(self):
         if self.layer_types is not None:
             # a configuration file gives a list; the config is a jit key
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if (len(self.layer_types) != self.n_layers
-                    or set(self.layer_types) - {"sliding", "full"}):
+                    or set(self.layer_types) - set(LAYER_KINDS)):
                 raise ValueError(
                     f"layer_types needs n_layers={self.n_layers} entries "
-                    f"of 'sliding' | 'full', got {self.layer_types}")
+                    f"of {' | '.join(map(repr, LAYER_KINDS))}, got "
+                    f"{self.layer_types}")
             if "sliding" in self.layer_types and not self.attn_window:
                 raise ValueError("sliding layers need attn_window")
+            if "mla" in self.layer_types and not (self.mla_kv_rank
+                                                  and self.mla_rope_dim):
+                raise ValueError("mla layers need mla_kv_rank and "
+                                 "mla_rope_dim")
+            if self.stateful and (self.n_kv_heads != self.n_heads
+                                  or self.attn_gate or self.sandwich_norm
+                                  or self.qk_norm or self.qk_norm_per_head):
+                raise ValueError(
+                    "kda and mla layers have n_heads heads of their own "
+                    "projections, norms and gates: n_kv_heads = n_heads, "
+                    "and no attn_gate, sandwich_norm or qk_norm")
         if self.layer_rotary is not None:
             by_kind = dict(self.layer_rotary)
             if set(by_kind) - {"sliding", "full"}:
@@ -235,6 +270,21 @@ class TransformerConfig:
     def sliding(self, layer: int) -> bool:
         return (self.layer_types is not None
                 and self.layer_types[layer] == "sliding")
+
+    def kind_of(self, layer: int) -> str:
+        """Layer ``layer``'s kind, one of ``LAYER_KINDS`` ("full" where
+        there are no ``layer_types``)."""
+        return "full" if self.layer_types is None else self.layer_types[layer]
+
+    def n_layers_of(self, kind: str) -> int:
+        return sum(self.kind_of(i) == kind for i in range(self.n_layers))
+
+    @property
+    def stateful(self) -> bool:
+        """Some layer keeps a state that is not cached keys and values
+        (a kda layer's recurrent state, an mla layer's latent)."""
+        return bool(self.layer_types) and bool(
+            {"kda", "mla"} & set(self.layer_types))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
         """How layer ``layer`` rotates q and k, None for not at all:
@@ -280,15 +330,20 @@ class TransformerConfig:
                                  route_scale=self.moe_route_scale,
                                  shared_expert=self.moe_shared_expert,
                                  experts_held=self.moe_experts_held,
-                                 expert_offset=self.moe_expert_offset)
+                                 expert_offset=self.moe_expert_offset,
+                                 n_group=self.moe_n_group,
+                                 topk_group=self.moe_topk_group)
 
 
 # ---------------------------------------------------------------------------
 # Parameter init + sharding specs
 # ---------------------------------------------------------------------------
 
-def _block_specs(cfg: TransformerConfig, moe: bool) -> Dict[str, Any]:
-    """The specs of one stack of blocks: MoE blocks or dense ones."""
+def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
+                 ) -> Dict[str, Any]:
+    """The specs of one stack of blocks: MoE blocks or dense ones, with
+    the attention of ``kind`` (``_init_blocks`` gives the shapes)."""
+    mat, vec = P(None, "fsdp", "tp"), P(None, None)
     layers: Dict[str, Any] = {
         "attn_norm": P(None, None),    # [L, D]
         "wq": P(None, "fsdp", "tp"),   # [L, D, H*Dh]
@@ -297,6 +352,14 @@ def _block_specs(cfg: TransformerConfig, moe: bool) -> Dict[str, Any]:
         "wo": P(None, "tp", "fsdp"),   # [L, H*Dh, D]
         "mlp_norm": P(None, None),
     }
+    if kind == "kda":
+        layers.update(conv_q=P(None, None, "tp"), conv_k=P(None, None, "tp"),
+                      conv_v=P(None, None, "tp"), wa=mat, a_log=vec,
+                      a_bias=P(None, "tp"), wbeta=mat, wz=mat, o_norm=vec)
+    if kind == "mla":
+        del layers["wk"], layers["wv"]
+        layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
+                      w_ukv=P(None, None, "tp"), wg=mat)
     if cfg.qk_norm:
         layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
         layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
@@ -343,18 +406,25 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.n_dense_layers:
         specs["dense_layers"] = _block_specs(cfg, False)
     if cfg.mixed:
-        for stack, n in (("layers", cfg.n_layers - cfg.n_dense_layers),
-                         ("dense_layers", cfg.n_dense_layers)):
+        for stack, first, n in (
+                ("layers", cfg.n_dense_layers,
+                 cfg.n_layers - cfg.n_dense_layers),
+                ("dense_layers", 0, cfg.n_dense_layers)):
             if stack in specs:
                 specs[stack] = [jax.tree.map(
-                    lambda spec: P(*spec[1:]), specs[stack],
-                    is_leaf=lambda x: isinstance(x, P))] * n
+                    lambda spec: P(*spec[1:]),
+                    _block_specs(cfg, stack == "layers"
+                                 and cfg.moe is not None,
+                                 cfg.kind_of(first + i)),
+                    is_leaf=lambda x: isinstance(x, P)) for i in range(n)]
     return specs
 
 
-def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int):
-    """One stack of ``L`` blocks, MoE or dense of width ``F``, drawing
-    its keys from the iterator ``k`` in one fixed order."""
+def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
+                 kind: str = "full"):
+    """One stack of ``L`` blocks, MoE or dense of width ``F``, with the
+    attention of ``kind``, drawing its keys from the iterator ``k`` in
+    one fixed order."""
     D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
@@ -362,14 +432,55 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int):
         return (jax.random.normal(kk, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dt)
 
-    layers = {
-        "attn_norm": jnp.ones((L, D), dt),
-        "wq": dense(next(k), (L, D, H * Dh), D),
-        "wk": dense(next(k), (L, D, Hkv * Dh), D),
-        "wv": dense(next(k), (L, D, Hkv * Dh), D),
-        "wo": dense(next(k), (L, H * Dh, D), H * Dh),
-        "mlp_norm": jnp.ones((L, D), dt),
-    }
+    def uniform(kk, shape, lo, hi):
+        return jax.random.uniform(kk, shape, jnp.float32, lo, hi)
+
+    if kind == "kda":
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            "wq": dense(next(k), (L, D, H * Dh), D),
+            "wk": dense(next(k), (L, D, H * Dh), D),
+            "wv": dense(next(k), (L, D, H * Dh), D),
+            # a tap a channel: [taps, H*Dh], the last tap the newest row
+            "conv_q": dense(next(k), (L, cfg.kda_conv, H * Dh), cfg.kda_conv),
+            "conv_k": dense(next(k), (L, cfg.kda_conv, H * Dh), cfg.kda_conv),
+            "conv_v": dense(next(k), (L, cfg.kda_conv, H * Dh), cfg.kda_conv),
+            # the decay: floor * sigmoid(exp(a_log) * (h wa + a_bias)), a
+            # head's rate in [1, 2) and biases that leave a channel
+            # between a few and some thousands of positions of memory
+            "wa": dense(next(k), (L, D, H * Dh), D),
+            "a_log": jnp.log(uniform(next(k), (L, H), 1.0, 2.0)),
+            "a_bias": uniform(next(k), (L, H * Dh), -6.0, -2.0),
+            "wbeta": dense(next(k), (L, D, H), D),
+            "wz": dense(next(k), (L, D, H * Dh), D),
+            "o_norm": jnp.ones((L, Dh), dt),
+            "wo": dense(next(k), (L, H * Dh, D), H * Dh),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
+    elif kind == "mla":
+        R, C = cfg.mla_rope_dim, cfg.mla_kv_rank
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            # a head's q: Dh without position, then R rotated
+            "wq": dense(next(k), (L, D, H * (Dh + R)), D),
+            # the latent, then the R rotated values every head shares
+            "w_dkv": dense(next(k), (L, D, C + R), D),
+            "kv_norm": jnp.ones((L, C), dt),
+            # a head's key without position, then its value
+            "w_ukv": dense(next(k), (L, C, H * 2 * Dh), C),
+            "wg": dense(next(k), (L, D, H), D),
+            "wo": dense(next(k), (L, H * Dh, D), H * Dh),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
+    else:
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            "wq": dense(next(k), (L, D, H * Dh), D),
+            "wk": dense(next(k), (L, D, Hkv * Dh), D),
+            "wv": dense(next(k), (L, D, Hkv * Dh), D),
+            "wo": dense(next(k), (L, H * Dh, D), H * Dh),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
         layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
@@ -403,6 +514,28 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
         return (jax.random.normal(kk, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(cfg.dtype)
 
+    if cfg.stateful:
+        # a layer at a time, each of its own kind and from its own key
+        def one(i):
+            sparse = cfg.moe is not None and i >= cfg.n_dense_layers
+            block = _init_blocks(
+                cfg, iter(jax.random.split(jax.random.fold_in(key, 2 + i),
+                                           16)), 1, sparse,
+                cfg.d_ff if sparse or not cfg.n_dense_layers
+                else cfg.d_ff_dense, cfg.kind_of(i))
+            return jax.tree.map(lambda a: a[0], block)
+
+        params = {
+            "layers": [one(i) for i in range(cfg.n_dense_layers,
+                                             cfg.n_layers)],
+            "embed": dense(next(k), (V, D), D),
+            "final_norm": jnp.ones((D,), cfg.dtype),
+            "lm_head": dense(next(k), (D, V), D),
+        }
+        if cfg.n_dense_layers:
+            params["dense_layers"] = [one(i)
+                                      for i in range(cfg.n_dense_layers)]
+        return _on_mesh(cfg, params, mesh)
     params = {
         "layers": _init_blocks(cfg, k, cfg.n_layers - cfg.n_dense_layers,
                                cfg.moe is not None, cfg.d_ff),
@@ -420,6 +553,10 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
                 n = len(params[stack]["attn_norm"])
                 params[stack] = [jax.tree.map(lambda a: a[i], params[stack])
                                  for i in range(n)]
+    return _on_mesh(cfg, params, mesh)
+
+
+def _on_mesh(cfg: TransformerConfig, params, mesh: Optional[Mesh]):
     if mesh is not None:
         shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
                                  param_specs(cfg),
@@ -659,6 +796,105 @@ def attention_residual(cfg: TransformerConfig, lp, x, o):
     return x + y
 
 
+# -- the two kinds of layer whose state is not cached keys (ISSUE 38) --
+# Their projections, gates and norms, for the serve programs
+# (``serve/decode.py``), which own the recurrence and the attention over
+# the latent pool as they own the attention over pages.
+
+def kda_rows(cfg: TransformerConfig, lp, x):
+    """A kda layer up to its convolution: the normed input ``h``
+    [B, T, D] and the rows ``h (Wq | Wk | Wv)`` [B, T, 3 * H * Dh] that
+    the convolution runs over (the newest ``kda_conv - 1`` of them are
+    what a sequence carries from call to call)."""
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    return h, jnp.concatenate([h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]], -1)
+
+
+def kda_conv(cfg: TransformerConfig, lp, rows, before):
+    """The causal depthwise convolution and SiLU over ``rows``
+    [B, T, 3 * H * Dh] preceded by the sequence's ``before``
+    [B, kda_conv - 1, 3 * H * Dh] (zeros at a sequence's start), and the
+    L2 norm of q and k over each head: q [B, T, H, Dh] scaled by
+    ``Dh ** -0.5``, k, v, float32."""
+    B, T = rows.shape[:2]
+    H, Dh = cfg.n_heads, cfg.head_dim
+    taps = jnp.concatenate([lp["conv_q"], lp["conv_k"], lp["conv_v"]], -1)
+    full = jnp.concatenate([before.astype(rows.dtype), rows], 1)
+    y = sum(full[:, j:j + T].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(cfg.kda_conv))
+    q, k, v = (a.reshape(B, T, H, Dh)
+               for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+
+    def unit(a):
+        return a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+    return unit(q) * Dh ** -0.5, unit(k), v
+
+
+def kda_gates(cfg: TransformerConfig, lp, h):
+    """The log-decay a channel ``g = floor * sigmoid(exp(a_log) * (h Wa
+    + a_bias))`` [B, T, H, Dh] (the floor, -5 as published, bounds it
+    below: 16 positions decay by at most e^-80, which float32 holds)
+    and the write strength ``beta = sigmoid(h Wbeta)`` [B, T, H]."""
+    B, T = h.shape[:2]
+    H, Dh = cfg.n_heads, cfg.head_dim
+    # float32 out of the matrix unit: a position's log-decay adds up
+    # over a channel's whole memory, and rounded to bf16 before the
+    # sigmoid it is 1 % off, which e^(sum g) turns into as much as a
+    # fifth of the state (chip, PR 38)
+    def f32(w):
+        return jnp.einsum("btd,df->btf", h, lp[w],
+                          preferred_element_type=jnp.float32)
+
+    a = (f32("wa") + lp["a_bias"]).reshape(B, T, H, Dh)
+    g = cfg.kda_decay_floor * jax.nn.sigmoid(
+        jnp.exp(lp["a_log"])[:, None] * a)
+    return g, jax.nn.sigmoid(f32("wbeta"))
+
+
+def kda_residual(cfg: TransformerConfig, lp, x, h, o):
+    """A kda layer after its recurrence ``o`` [B, T, H, Dh] float32:
+    RMSNorm over each head, the gate ``sigmoid(h Wz)``, the output
+    projection and the residual."""
+    B, T = x.shape[:2]
+    o = _rmsnorm(o, lp["o_norm"], cfg.norm_eps).reshape(B, T, -1)
+    o = (o * jax.nn.sigmoid((h @ lp["wz"]).astype(jnp.float32))
+         ).astype(cfg.dtype)
+    return x + (o @ lp["wo"]).astype(cfg.dtype)
+
+
+def mla_inputs(cfg: TransformerConfig, lp, x, pos):
+    """An mla layer up to its attention: the normed input ``h``, q
+    without position [B, T, H, Dh] and rotated [B, T, H, R], and what
+    the cache holds of a position, ``[RMSNorm(c) | rotated r]``
+    [B, T, C + R]. Rotation at ``rope_theta`` over interleaved pairs."""
+    B, T = x.shape[:2]
+    H, Dh, R, C = cfg.n_heads, cfg.head_dim, cfg.mla_rope_dim, cfg.mla_kv_rank
+    rotary = Rotary(cfg.rope_theta)
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    q = (h @ lp["wq"]).reshape(B, T, H, Dh + R)
+    cr = h @ lp["w_dkv"]
+    c = _rmsnorm(cr[..., :C], lp["kv_norm"], cfg.norm_eps)
+    r = _rope(cr[..., None, C:], pos, rotary)[:, :, 0]
+    return (h, q[..., :Dh], _rope(q[..., Dh:], pos, rotary),
+            jnp.concatenate([c, r], -1))
+
+
+def mla_up(cfg: TransformerConfig, lp):
+    """``(W_uk, W_uv)`` [C, H, Dh] each, out of ``w_ukv``."""
+    w = lp["w_ukv"].reshape(cfg.mla_kv_rank, cfg.n_heads, 2, cfg.head_dim)
+    return w[:, :, 0], w[:, :, 1]
+
+
+def mla_residual(cfg: TransformerConfig, lp, x, h, o):
+    """An mla layer after its attention ``o`` [B, T, H, Dh]: one
+    sigmoid gate a head, the output projection and the residual."""
+    B, T = x.shape[:2]
+    gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+    o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
+    return x + (o.reshape(B, T, -1) @ lp["wo"]).astype(cfg.dtype)
+
+
 def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
     """A decoder block after its attention: pre-norm, the dense SwiGLU
     or ``moe_fn(h, lp['moe']) -> (y, aux)`` (whichever the block's
@@ -748,6 +984,7 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
     stage scan, the quantized steps' islands) refuses, by name, a
     configuration whose layers are a list of several kinds or that
     holds a chip's share of the experts (``cfg.mixed``): ROADMAP C5b."""
+    _refuse_stateful(cfg, what)
     if cfg.mixed:
         raise NotImplementedError(
             f"{what} does not run a configuration with layer_types, "
@@ -757,12 +994,25 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
             "dp mesh.")
 
 
+def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
+    """The trainer's entry points refuse kda and mla layers by name:
+    their forward exists in the serve programs alone."""
+    if cfg.stateful:
+        raise NotImplementedError(
+            f"{what} does not run kda or mla layers: models/transformer.py"
+            "'s decoder_layer has no backward through the chunked "
+            "delta-rule scan of serve/decode.py and no latent attention "
+            "(ROADMAP B14, B8). The configuration is served through "
+            "ServeEngine.")
+
+
 def _refuse_mixed_off_dp(cfg: TransformerConfig, what: str, mesh) -> None:
     """:func:`forward_with_aux`, :func:`lm_loss`, :func:`make_train_step`
     and :func:`moe_routing_report` run a ``cfg.mixed`` configuration as
     a loop over its layers, over ``dp`` alone: ``tp``, ``sp``, ``ep``,
     ``pp`` and ``fsdp`` over the lists are not shown to shard, and are
     refused by name (ROADMAP C5b)."""
+    _refuse_stateful(cfg, what)
     if not cfg.mixed or mesh is None:
         return
     sharded = [f"{axis}={size}" for axis, size in dict(mesh.shape).items()

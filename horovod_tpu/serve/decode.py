@@ -79,6 +79,7 @@ faster, in the dense form.
 from __future__ import annotations
 
 import functools
+import types
 from typing import Any, Optional
 
 import jax
@@ -90,7 +91,7 @@ from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import local_attention
-from horovod_tpu.serve.kv_cache import NULL_BLOCK
+from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
 
 _NEG_BIG = -1e30  # matches ring_attention's finite "-inf"
 
@@ -223,11 +224,12 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
     parallel island can't run.
 
     A configuration whose layers are of more than one kind
-    (``cfg.mixed``: a leading dense stack, window and full attention)
-    or that holds a chip's share of the experts gets the programs of
-    :func:`_mixed_serve_fns`, over two kinds of cache; ``ring`` is the
-    positions a window layer keeps for a sequence
-    (``kv_cache.ring_width``). It has no ``inject`` and no ``verify``.
+    (``cfg.mixed``: a leading dense stack; window, full, kda and mla
+    layers) or that holds a chip's share of the experts gets the
+    programs of :func:`_mixed_serve_fns`, over a state a kind of layer
+    (``kv_cache.KVCache``); ``ring`` is the positions a window layer
+    keeps for a sequence (``kv_cache.ring_width``). It has no
+    ``inject`` and no ``verify``.
 
     Memoized: engines sharing (cfg, mesh, block geometry, compression)
     — e.g. the benchmark's continuous and static schedulers, or a
@@ -253,9 +255,10 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
             raise NotImplementedError(
                 "the serve programs of a configuration with layers of "
                 "several kinds or a chip's share of the experts run on one "
-                f"chip: a mesh with {spread} would shard its two caches "
-                "and its held experts, which nothing does yet (ROADMAP "
-                "B7(ii))")
+                f"chip: a mesh with {spread} would shard its caches (pages, "
+                "rings, recurrent states, the latent pool) and its held "
+                "experts, which neither decode.py's mixed_programs nor "
+                "kv_cache.init_kv_cache does yet (ROADMAP B7(ii), B8)")
         return _mixed_serve_fns(cfg, block_size, table_width, ring,
                                 compression)
     return _cached_serve_fns(cfg, mesh, block_size, table_width,
@@ -486,7 +489,7 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
 
 
 # ---------------------------------------------------------------------------
-# Layers of several kinds over two kinds of cache (ISSUE 32)
+# Layers of several kinds, each over its kind's state (ISSUE 32, 38)
 # ---------------------------------------------------------------------------
 
 def _attend_keys(q, keys, vals, key_pos, pos, window):
@@ -541,37 +544,231 @@ def ring_positions(frontier, ring: int):
     return last - (last - r) % ring
 
 
+#: Positions a block of :func:`kda_scan` holds, and of the sub-blocks
+#: inside it that share one reference point for their decays.
+_KDA_BLOCK, _KDA_SUB = 64, 16
+#: Key positions :func:`_mla_attend` gathers and attends at a time.
+_MLA_KEY_BLOCK = 1024
+_EXACT = lax.Precision.HIGHEST
+
+
+def kda_scan(q, k, v, g, beta, state, block: int = _KDA_BLOCK,
+             sub: int = _KDA_SUB):
+    """The delta-rule recurrence of a kda layer over a chunk, a block of
+    ``block`` positions at a time: the inside of a block as matrix
+    products, the state carried between blocks.
+
+    ``q``, ``k``, ``v``, ``g`` [B, T, H, Dh] float32 (``g`` the
+    log-decay a channel, <= 0), ``beta`` [B, T, H], ``state``
+    [B, H, Dh, Dh] float32. A head, with ``a_t = exp(g_t)``:
+
+        S'_t = Diag(a_t) S_{t-1}
+        S_t  = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T
+        o_t  = S_t^T q_t
+
+    Returns ``(o [B, T, H, Dh], the state after the last position)``. A
+    position with ``g = 0`` and ``beta = 0`` leaves the state as it
+    was: that is how a bucket's padding is written.
+
+    Inside a block, with ``G`` the running sum of ``g`` from the block's
+    start, ``u_j = v_j - S'_j^T k_j`` solves the unit lower-triangular
+    system ``(I + L) U = V - (K exp G) S_0``, ``L_ji = beta_i sum_d k_jd
+    k_id exp(G_jd - G_id)`` for i < j; then ``O = (Q exp G) S_0 +
+    (A beta) U`` with ``A_tj`` the same sum over ``q_t k_j`` for j <= t,
+    and ``S_C = exp(G_C) S_0 + (K exp(G_C - G) beta)^T U``. The pairwise
+    decays ``exp(G_t - G_j)`` are products ``exp(G_t - R) exp(R - G_j)``
+    about a reference ``R`` a sub-block of ``sub`` rows (``G`` at the
+    sub-block's start): the first factor is at most 1 and the second at
+    most ``exp(sub * floor)``, e^80 at the published floor of -5 a
+    position and 16 rows, which float32 holds. Everything in float32 at
+    the highest matmul precision."""
+    B, T, H, D = q.shape
+    s = min(sub, block)
+    C = min(block, -(-T // s) * s)       # whole sub-blocks
+    pad = -T % C
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    assert C % s == 0, (C, s)
+    n, na = (T + pad) // C, C // s
+
+    def blocks(a):                                   # -> [n, B, H, C, .]
+        return jnp.moveaxis(a.reshape(B, n, C, H, -1), (1, 3), (0, 2))
+
+    q, k, v, g = map(blocks, (q, k, v, g))
+    beta = blocks(beta[..., None])[..., 0]                   # [n, B, H, C]
+    G = jnp.cumsum(g, axis=-2)
+    # a sub-block's reference: G at the end of the sub-block before it
+    ends = G.reshape(n, B, H, na, s, D)[..., -1, :]
+    ref = jnp.concatenate([jnp.zeros_like(ends[..., :1, :]),
+                           ends[..., :-1, :]], -2)           # [.., na, D]
+    row = jnp.exp(G.reshape(n, B, H, na, s, D) - ref[..., None, :])
+    seen = (jnp.arange(C) // s)[None, :] <= jnp.arange(na)[:, None]
+    col = jnp.exp(jnp.where(seen[..., None],
+                            ref[..., None, :] - G[..., None, :, :],
+                            -jnp.inf))                   # [.., na, C, D]
+    kcol = k[..., None, :, :] * col
+
+    def pairs(a):              # sum_d a_t k_j exp(G_t - G_j) [.., C, C]
+        return jnp.einsum("...asd,...acd->...asc",
+                          a.reshape(n, B, H, na, s, D) * row, kcol,
+                          precision=_EXACT).reshape(n, B, H, C, C)
+
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    by_beta = beta[..., None, :]                  # beta_j on column j
+    L = jnp.where(i > j, pairs(k), 0.0) * by_beta
+    A = jnp.where(i >= j, pairs(q), 0.0) * by_beta
+
+    def row_of_inverse(t, inv):
+        """Row t of (I + L)^-1 from the rows above it."""
+        new = -jnp.einsum("...j,...jk->...k", L[..., t, :], inv,
+                          precision=_EXACT)
+        return inv.at[..., t, :].set(new.at[..., t].add(1.0))
+
+    inv = lax.fori_loop(1, C, row_of_inverse,
+                        jnp.broadcast_to(jnp.eye(C, dtype=L.dtype), L.shape))
+    decayed = jnp.exp(G)
+    solved = jnp.einsum("...tj,...jd->...td", inv,
+                        jnp.concatenate([v, k * decayed], -1),
+                        precision=_EXACT)
+    tv, tk = solved[..., :D], solved[..., D:]
+    qg = q * decayed
+    at_end = decayed[..., -1, :]                             # [n, B, H, D]
+    k_end = k * jnp.exp(G[..., -1:, :] - G) * beta[..., None]
+
+    def one_block(S, xs):
+        tv, tk, qg, A, k_end, at_end = xs
+        u = tv - jnp.einsum("...tk,...kv->...tv", tk, S, precision=_EXACT)
+        o = (jnp.einsum("...tk,...kv->...tv", qg, S, precision=_EXACT)
+             + jnp.einsum("...tj,...jv->...tv", A, u, precision=_EXACT))
+        S = at_end[..., None] * S + jnp.einsum(
+            "...tk,...tv->...kv", k_end, u, precision=_EXACT)
+        return S, o
+
+    state, o = lax.scan(one_block, state, (tv, tk, qg, A, k_end, at_end))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, T + pad, H, D)
+    return o[:, :T], state
+
+
+def kda_step(q, k, v, g, beta, state):
+    """One position of :func:`kda_scan`'s recurrence a row: ``q``,
+    ``k``, ``v``, ``g`` [N, H, Dh], ``beta`` [N, H], ``state``
+    [N, H, Dh, Dh]. Returns ``(o [N, H, Dh], the new state)``. The
+    state is read twice and written once: ``S'^T k`` and ``S'^T q`` in
+    one pass, then ``S = S' + beta k u^T`` and ``o = S'^T q + beta (k .
+    q) u``."""
+    decayed = jnp.exp(g)[..., None] * state
+    sk = jnp.einsum("nhkv,nhk->nhv", decayed, k, precision=_EXACT)
+    sq = jnp.einsum("nhkv,nhk->nhv", decayed, q, precision=_EXACT)
+    u = beta[..., None] * (v - sk)
+    o = sq + jnp.sum(k * q, -1, keepdims=True) * u
+    return o, decayed + k[..., None] * u[..., None, :]
+
+
+def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
+    """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
+    and ``qr`` [B, C, H, R] (rotated) at positions ``pos`` [B, C] over
+    ``n_blocks`` (traced) blocks of cached latents, a block of keys at a
+    time with a running softmax, so that the scores of one block
+    ``[B, H, C, block]`` are all that exists of them. ``keys_of(j) ->
+    (latent [B, K, C + R], key_pos [K])`` gives block j. Float32
+    scores, softmax and accumulators. Returns [B, C, H, Dh].
+
+    **Expanded** (a chunk's queries: many a sequence): a block's
+    latents are expanded to every head's key and value, ``c W_uk`` and
+    ``c W_uv``, and attended as keys and values are. **Absorbed** (a
+    decode step's: one a sequence): ``q W_uk^T`` is scored against the
+    latent itself and the latent is summed, then expanded once
+    (``(sum p c) W_uv``): the same function, with no ``[K, H, Dh]``
+    key or value a position."""
+    B, C, H, Dh = qn.shape
+    rank = cfg.mla_kv_rank
+    w_uk, w_uv = tf_lib.mla_up(cfg, lp)
+    scale = (Dh + cfg.mla_rope_dim) ** -0.5
+    if absorbed:
+        qn = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
+    width = rank if absorbed else Dh
+
+    def block(j, carry):
+        m, l, acc = carry
+        latent, key_pos = keys_of(j)
+        c = latent[..., :rank]
+        r = latent[..., rank:rank + cfg.mla_rope_dim]
+        if absorbed:
+            vals, summed = c, "bhqk,bkd->bhqd"           # every head's
+            s = jnp.einsum("bqhc,bkc->bhqk", qn, c,
+                           preferred_element_type=jnp.float32)
+        else:
+            vals, summed = (jnp.einsum("bkc,chd->bkhd", c, w_uv),
+                            "bhqk,bkhd->bhqd")
+            s = jnp.einsum("bqhd,bkhd->bhqk", qn,
+                           jnp.einsum("bkc,chd->bkhd", c, w_uk),
+                           preferred_element_type=jnp.float32)
+        s = (s + jnp.einsum("bqhr,bkr->bhqk", qr, r,
+                            preferred_element_type=jnp.float32)) * scale
+        seen = key_pos[None, None, :] <= pos[:, :, None]     # [B, C, K]
+        s = jnp.where(seen[:, None], s, _NEG_BIG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        acc = acc * fade[..., None] + jnp.einsum(
+            summed, p.astype(vals.dtype), vals,
+            preferred_element_type=jnp.float32)
+        return m_new, l * fade + p.sum(-1), acc
+
+    m, l, acc = lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((B, H, C), _NEG_BIG, jnp.float32),
+         jnp.zeros((B, H, C), jnp.float32),
+         jnp.zeros((B, H, C, width), jnp.float32)))
+    o = jnp.moveaxis(acc / l[..., None], 1, 2).astype(qn.dtype)
+    if absorbed:
+        o = jnp.einsum("bqhc,chd->bqhd", o, w_uv)
+    return o
+
+
 def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                    compression=None, head=None):
     """(prefill, prefill_resume, decode, held_experts_counts), not
     jitted, of a configuration with layers of several kinds (the last
-    is :func:`moe_share_report`'s). The caches are pairs
-    ``kc = (pool, rings)``: ``pool`` [n_full, n_blocks, bs, Hkv, Dh] is
-    the full layers' paged pool behind the block tables, ``rings``
-    [n_window, n_slots, ring, Hkv, Dh] the window layers', one ring a
-    batch slot (slot 0 is the null slot, as block 0 is the null block).
-    An address is a pair too: ``(block_table, slot)``.
+    is :func:`moe_share_report`'s). The caches ``kc`` and ``vc`` are
+    tuples with one array a kind of layer, in
+    ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
+    is what): pages behind the block tables for ``full`` and ``mla``
+    layers, and rings and recurrent states for ``sliding`` and ``kda``
+    layers, one a batch slot (slot 0 is the null slot, as block 0 is the
+    null block). An address is a pair too: ``(block_table, slot)``.
 
     The layers are a Python loop: each knows its kind, its stack and
-    its place in its cache when the program is traced. ``head`` maps
+    its place in its kind's arrays when the program is traced. **A
+    kind is one entry of one table** (``kinds`` below): how a chunk of
+    one sequence, and how a decode step of the batch, reads and writes
+    that kind's state around its attention. A program gives every
+    layer the same ``call``: its positions and addresses. ``head`` maps
     float32 logits to what a program returns (None: their argmax, the
     next token; the tests read the logits themselves)."""
-    Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
+    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window = cfg.attn_window
     if head is None:
         def head(logits):
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    # layer -> (its list, its index there, sliding?, its index in its cache)
-    plan, n_full, n_win = [], 0, 0
+    place = {kind: n for n, kind in enumerate(state_kinds(cfg))}
+    # layer -> (its list, its index there, its kind, its index in its cache)
+    plan, seen = [], dict.fromkeys(place, 0)
     for i in range(cfg.n_layers):
         stack, j = (("dense_layers", i) if i < cfg.n_dense_layers
                     else ("layers", i - cfg.n_dense_layers))
-        if cfg.sliding(i):
-            plan.append((stack, j, True, n_win))
-            n_win += 1
-        else:
-            plan.append((stack, j, False, n_full))
-            n_full += 1
+        kind = cfg.kind_of(i)
+        plan.append((stack, j, kind, seen[kind]))
+        seen[kind] += 1
+    n_win = seen.get("sliding", 0)
+    S = table_width * block_size
+    latent = latent_row(cfg) if "mla" in place else 0
+    # the mla layers' key blocks: whole pages, the table padded to them
+    key_block = min(_MLA_KEY_BLOCK, S) // block_size * block_size
+    key_blocks = -(-S // key_block)
+
     def embed(params, tokens):
         with jax.named_scope("embed"):
             x = tf_lib.embed_lookup(params["embed"], tokens, cfg.dtype,
@@ -580,45 +777,264 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
             return x
 
-    def layers(params, kc, vc, x, pos, write, attend, moe_fn=None):
-        """Every layer over ``x`` [B, T, D] at ``pos`` [B, T].
-        ``write(cache, c, sliding, new) -> cache`` puts a layer's new K
-        or V into place ``c`` of its kind's cache, and ``attend(q, k,
-        v, kc, vc, c, sliding) -> [B, T, H * Dh]`` attends, as in
-        :func:`_cached_serve_fns`."""
-        for i, (stack, j, sliding, c) in enumerate(plan):
+    def layers(params, kc, vc, x, call, moe_fn=None):
+        """Every layer over ``x`` [B, T, D]: ``kinds[kind][call.step](
+        call, lp, kc, vc, c, x, i) -> (kc, vc, x)`` is layer i's
+        attention, residual included, over place ``c`` of its kind's
+        arrays."""
+        for i, (stack, j, kind, c) in enumerate(plan):
             lp = params[stack][j]
-            kind = "attn_window" if sliding else "attn_full"
             with jax.named_scope("attn"):
-                q, k, v = tf_lib.attention_inputs(cfg, lp, x, pos, i)
-                with jax.named_scope(kind):
-                    with jax.named_scope("kv_write"):
-                        kc, vc = (write(kc, c, sliding, k),
-                                  write(vc, c, sliding, v))
-                    o = attend(q, k, v, kc, vc, c, sliding)
-                x = tf_lib.attention_residual(cfg, lp, x, o)
+                kc, vc, x = kinds[kind][call.step](call, lp, kc, vc, c, x, i)
             with jax.named_scope("mlp"):
                 x, _aux = tf_lib.ffn_block(cfg, lp, x, moe_fn)
         return kc, vc, x
 
-    def put(cache, sliding, at, new):
-        """``new`` rows at ``at`` of the pool or of the rings."""
-        pool, rings = cache
-        if sliding:
-            return pool, rings.at[at].set(
-                new.reshape(-1, Hkv, Dh).astype(rings.dtype))
-        return pool.at[at].set(new.astype(pool.dtype)), rings
+    def put(cache, kind, at, new):
+        """``new`` at ``at`` of ``kind``'s array of ``cache`` (None: a
+        program that keeps nothing)."""
+        if cache is None:
+            return None
+        n = place[kind]
+        return cache[:n] + (cache[n].at[at].set(
+            new.astype(cache[n].dtype)),) + cache[n + 1:]
 
     def emit(params, x, rows):
         with jax.named_scope("head"):
             x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
             return head((x @ params["lm_head"]).astype(jnp.float32))
 
+    def softmax_layer(call, lp, kc, vc, x, i, scope, write, attend):
+        """A window or full layer: ``write(cache, new) -> cache`` puts
+        the layer's new K or V in place, ``attend(q, k, v, kc, vc)``
+        attends over them."""
+        q, k, v = tf_lib.attention_inputs(cfg, lp, x, call.pos, i)
+        with jax.named_scope(scope):
+            if kc is not None:
+                with jax.named_scope("kv_write"):
+                    kc, vc = write(kc, k), write(vc, v)
+            o = attend(q, k, v, kc, vc)
+        return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
+
+    def pages(cache, c, tables, rows: int):
+        """A ``full`` layer's K or V behind the tables of ``rows``
+        sequences."""
+        with jax.named_scope("kv_gather"):
+            return cache[place["full"]][c, tables].reshape(rows, S, Hkv, Dh)
+
+    # -- a chunk of one sequence (B = 1) -----------------------------
+
+    def window_chunk(call, lp, kc, vc, c, x, i):
+        def write(cache, new):
+            return put(cache, "sliding", (c, call.slot, call.pos[0] % ring),
+                       new.reshape(-1, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc):
+            if call.local:
+                return _attend_keys(q, k, v, call.pos, call.pos, window)
+            n = place["sliding"]
+            return _attend_keys(q, kc[n][c, call.slot][None],
+                                vc[n][c, call.slot][None], call.held,
+                                call.pos, window)
+        return softmax_layer(call, lp, kc, vc, x, i, "attn_window", write,
+                             attend)
+
+    def full_chunk(call, lp, kc, vc, c, x, i):
+        def write(cache, new):
+            return put(cache, "full", (c, call.blks),
+                       new[0].reshape(-1, block_size, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc):
+            if call.local:
+                return _attend_keys(q, k, v, call.pos, call.pos, None)
+            return _attend_keys(
+                q, pages(kc, c, call.table, 1), pages(vc, c, call.table, 1),
+                jnp.arange(S, dtype=jnp.int32)[None], call.pos, None)
+        return softmax_layer(call, lp, kc, vc, x, i, "attn_full", write,
+                             attend)
+
+    def kda_chunk(call, lp, kc, vc, c, x, i):
+        """The chunk's recurrence from the state and the convolution's
+        rows the slot holds (zeros for a sequence's first chunk,
+        whatever the slot held), and both back as they are AT
+        ``length``: a bucket's padding decays nothing and writes
+        nothing."""
+        B, T = x.shape[:2]
+        n = place["kda"]
+        with jax.named_scope("attn_kda"):
+            h, rows = tf_lib.kda_rows(cfg, lp, x)
+            state = jnp.zeros((B, H, Dh, Dh), jnp.float32)
+            before = jnp.zeros((B, cfg.kda_conv - 1, rows.shape[-1]),
+                               rows.dtype)
+            if not call.local:
+                resumed = call.offset > 0
+                state = jnp.where(resumed, kc[n][c, call.slot][None], state)
+                before = jnp.where(resumed, vc[n][c, call.slot][None], before)
+            with jax.named_scope("kda_conv"):
+                q, k, v = tf_lib.kda_conv(cfg, lp, rows, before)
+            with jax.named_scope("kda_gates"):
+                g, beta = tf_lib.kda_gates(cfg, lp, h)
+                real = jnp.arange(T)[None, :, None] < call.length
+                g = jnp.where(real[..., None], g, 0.0)
+                beta = jnp.where(real, beta, 0.0)
+            with jax.named_scope("kda_scan"):
+                o, state = kda_scan(q, k, v, g, beta, state)
+            if kc is not None:
+                with jax.named_scope("state_write"):
+                    newest = lax.dynamic_slice_in_dim(
+                        jnp.concatenate([before, rows], 1)[0], call.length,
+                        cfg.kda_conv - 1)
+                    kc = put(kc, "kda", (c, call.slot), state[0])
+                    vc = put(vc, "kda", (c, call.slot), newest)
+        return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o)
+
+    def mla_chunk(call, lp, kc, vc, c, x, i):
+        """Expanded attention: over the chunk itself where it is the
+        whole prompt, else over the sequence's pages."""
+        n = place["mla"]
+        with jax.named_scope("attn_mla"):
+            h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, x, call.pos)
+            new = jnp.pad(new, ((0, 0), (0, 0), (0, latent - new.shape[-1])))
+            if kc is not None:
+                with jax.named_scope("kv_write"):
+                    kc = put(kc, "mla", (c, call.blks),
+                             new[0].reshape(-1, block_size, latent))
+            with jax.named_scope("mla_attend"):
+                if call.local:
+                    # every row's keys are its own prompt's, one block
+                    o = _mla_attend(
+                        cfg, lp, qn, qr, lambda j: (new, call.pos[0]), 1,
+                        call.pos, absorbed=False)
+                else:
+                    o = _mla_attend(
+                        cfg, lp, qn, qr, mla_pages(kc[n], c, call.table[None]),
+                        jnp.minimum(call.pos[0, -1] // key_block + 1,
+                                    key_blocks), call.pos, absorbed=False)
+        return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
+
+    def mla_pages(pool, c, tables):
+        """``keys_of`` of :func:`_mla_attend` over the pages behind
+        ``tables`` [B, W]: block j is the ``key_block`` positions from
+        ``j * key_block``, gathered when it is attended."""
+        per = key_block // block_size
+        tables = jnp.pad(tables, ((0, 0), (0, key_blocks * per
+                                           - tables.shape[1])))
+
+        def keys_of(j):
+            with jax.named_scope("kv_gather"):
+                ids = lax.dynamic_slice_in_dim(tables, j * per, per, 1)
+                return (pool[c, ids].reshape(tables.shape[0], key_block,
+                                             latent),
+                        j * key_block + jnp.arange(key_block,
+                                                   dtype=jnp.int32))
+        return keys_of
+
+    # -- a decode step of the batch (one position a row) -------------
+
+    def by_slot(call, rows, n_slots):
+        """``rows`` [B, ...] laid out by slot: row i at ``slots[i]``,
+        zeros at the slots that are not in the batch."""
+        return jnp.zeros((n_slots,) + rows.shape[1:],
+                         rows.dtype).at[call.slots].set(rows)
+
+    def window_step(call, lp, kc, vc, c, x, i):
+        def write(cache, new):
+            return put(cache, "sliding",
+                       (c, call.slots, call.positions % ring),
+                       new.reshape(-1, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc):
+            # Every ring of the layer where it lies, the queries
+            # carried to their slots and the results back: the rings
+            # are then read once, by the two dots, and not gathered
+            # first into a copy the size of the batch's share of them
+            # (a gather through (layer, slots) that the compiler made
+            # of all layers' rings in slabs, at a twelfth of the memory
+            # bandwidth). A slot that is not in the batch has written
+            # nothing (frontier 0): every key of its ring is refused
+            # and its row is not read back.
+            n = place["sliding"]
+            n_slots = kc[n].shape[1]
+            at = by_slot(call, call.pos + 1, n_slots)               # [S, 1]
+            o = _attend_keys(by_slot(call, q, n_slots), kc[n][c], vc[n][c],
+                             ring_positions(at[:, 0], ring), at - 1,
+                             window)
+            return o[call.slots]
+        return softmax_layer(call, lp, kc, vc, x, i, "attn_window", write,
+                             attend)
+
+    def full_step(call, lp, kc, vc, c, x, i):
+        def write(cache, new):
+            return put(cache, "full",
+                       (c, call.blk, call.positions % block_size),
+                       new.reshape(-1, Hkv, Dh))
+
+        def attend(q, k, v, kc, vc):
+            rows = call.tables.shape[0]
+            return _attend_keys(
+                q, pages(kc, c, call.tables, rows),
+                pages(vc, c, call.tables, rows),
+                jnp.arange(S, dtype=jnp.int32)[None], call.pos, None)
+        return softmax_layer(call, lp, kc, vc, x, i, "attn_full", write,
+                             attend)
+
+    def kda_step_layer(call, lp, kc, vc, c, x, i):
+        """One step of the recurrence on every slot's state where it
+        lies, the batch's rows carried to their slots and the results
+        back (as the window layers' rings are read): a slot that is not
+        in the batch decays by 1 and is written by 0, so its state is
+        what it was."""
+        n = place["kda"]
+        with jax.named_scope("attn_kda"):
+            h, rows = tf_lib.kda_rows(cfg, lp, x)
+            before = vc[n][c, call.slots]
+            with jax.named_scope("kda_conv"):
+                q, k, v = tf_lib.kda_conv(cfg, lp, rows, before)
+            with jax.named_scope("kda_gates"):
+                g, beta = tf_lib.kda_gates(cfg, lp, h)
+            with jax.named_scope("kda_step"):
+                n_slots = kc[n].shape[1]
+                o, state = kda_step(*(by_slot(call, a[:, 0], n_slots)
+                                      for a in (q, k, v, g, beta)),
+                                    kc[n][c])
+                o = o[call.slots][:, None]
+            with jax.named_scope("state_write"):
+                kc = put(kc, "kda", (c,), state)
+                vc = put(vc, "kda", (c, call.slots),
+                         jnp.concatenate([before, rows], 1)[:, 1:])
+        return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o)
+
+    def mla_step(call, lp, kc, vc, c, x, i):
+        """Absorbed attention over the pages behind the tables."""
+        with jax.named_scope("attn_mla"):
+            h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, x, call.pos)
+            new = jnp.pad(new, ((0, 0), (0, 0), (0, latent - new.shape[-1])))
+            with jax.named_scope("kv_write"):
+                kc = put(kc, "mla",
+                         (c, call.blk, call.positions % block_size),
+                         new[:, 0])
+            with jax.named_scope("mla_attend"):
+                o = _mla_attend(
+                    cfg, lp, qn, qr,
+                    mla_pages(kc[place["mla"]], c, call.tables),
+                    jnp.minimum(call.positions.max() // key_block + 1,
+                                key_blocks), call.pos, absorbed=True)
+        return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
+
+    #: kind of layer -> how a chunk and how a decode step run it
+    kinds = {
+        "sliding": {"chunk": window_chunk, "step": window_step},
+        "full": {"chunk": full_chunk, "step": full_step},
+        "kda": {"chunk": kda_chunk, "step": kda_step_layer},
+        "mla": {"chunk": mla_chunk, "step": mla_step},
+    }
+
     def chunk_program(params, kc, vc, tokens, offset, length, address,
                       local: bool):
         """A chunk of one sequence at ``offset`` (B = 1): whole blocks
-        into the pool, rows into the slot's ring. ``local``: the chunk
-        is the whole prompt and attends over itself."""
+        into the pages, rows into the slot's ring, the slot's state
+        carried over it. ``local``: the chunk is the whole prompt and
+        attends over itself."""
         table, slot = address
         Tc = tokens.shape[0]
         assert Tc <= ring or not n_win, (
@@ -631,29 +1047,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
             blk < table_width,
             jnp.take(table, jnp.minimum(blk, table_width - 1)), NULL_BLOCK)
         held = ring_positions(offset[None] + Tc, ring) if n_win else None
-        S = table_width * block_size
-
-        def write(cache, c, sliding, new):
-            if sliding:
-                return put(cache, True, (c, slot, pos[0] % ring), new)
-            return put(cache, False, (c, blks),
-                       new[0].reshape(-1, block_size, Hkv, Dh))
-
-        def attend(q, k, v, kc, vc, c, sliding):
-            w = window if sliding else None
-            if local:
-                return _attend_keys(q, k, v, pos, pos, w)
-            if sliding:
-                return _attend_keys(q, kc[1][c, slot][None],
-                                    vc[1][c, slot][None], held, pos, w)
-            with jax.named_scope("kv_gather"):
-                kp = kc[0][c, table].reshape(1, S, Hkv, Dh)
-                vp = vc[0][c, table].reshape(1, S, Hkv, Dh)
-            return _attend_keys(q, kp, vp,
-                                jnp.arange(S, dtype=jnp.int32)[None], pos,
-                                None)
-
-        kc, vc, x = layers(params, kc, vc, x, pos, write, attend)
+        call = types.SimpleNamespace(
+            step="chunk", local=local, pos=pos, offset=offset, length=length,
+            table=table, slot=slot, blks=blks, held=held)
+        kc, vc, x = layers(params, kc, vc, x, call)
         return kc, vc, emit(params, x,
                             lambda x: jnp.take(x[0], length - 1, axis=0))
 
@@ -666,7 +1063,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
 
     def prefill_resume(params, kc, vc, tokens, offset, length, address):
         """One chunk at the block-aligned ``offset``, over what earlier
-        chunks left in the two caches and its own keys."""
+        chunks left in the caches and its own keys."""
         return chunk_program(params, kc, vc, tokens, offset, length,
                              address, local=False)
 
@@ -676,61 +1073,24 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         carries token 0, position 0, an all-null table and the null
         slot."""
         tables, slots = address
-        B = tokens.shape[0]
         x = embed(params, tokens[:, None])
         pos = positions[:, None]
         blk_i = positions // block_size
         blk = jnp.take_along_axis(
             tables, jnp.minimum(blk_i, table_width - 1)[:, None], axis=1)[:, 0]
         blk = jnp.where(blk_i < table_width, blk, NULL_BLOCK)
-        S = table_width * block_size
-
-        def by_slot(rows, n_slots):
-            """``rows`` [B, ...] laid out by ring: row i at ``slots[i]``,
-            zeros at the slots that are not in the batch."""
-            return jnp.zeros((n_slots,) + rows.shape[1:],
-                             rows.dtype).at[slots].set(rows)
-
-        def write(cache, c, sliding, new):
-            if sliding:
-                return put(cache, True, (c, slots, positions % ring), new)
-            return put(cache, False, (c, blk, positions % block_size),
-                       new.reshape(-1, Hkv, Dh))
-
-        def attend(q, k, v, kc, vc, c, sliding):
-            if sliding:
-                # Every ring of the layer where it lies, the queries
-                # carried to their slots and the results back: the
-                # rings are then read once, by the two dots, and not
-                # gathered first into a copy the size of the batch's
-                # share of them (a gather through (layer, slots) that
-                # the compiler made of all layers' rings in slabs, at
-                # a twelfth of the memory bandwidth). A slot that is
-                # not in the batch has written nothing (frontier 0):
-                # every key of its ring is refused and its row is not
-                # read back.
-                n_slots = kc[1].shape[1]
-                at = by_slot(pos + 1, n_slots)                  # [S, 1]
-                o = _attend_keys(by_slot(q, n_slots), kc[1][c], vc[1][c],
-                                 ring_positions(at[:, 0], ring), at - 1,
-                                 window)
-                return o[slots]
-            with jax.named_scope("kv_gather"):
-                kp = kc[0][c, tables].reshape(B, S, Hkv, Dh)
-                vp = vc[0][c, tables].reshape(B, S, Hkv, Dh)
-            return _attend_keys(q, kp, vp,
-                                jnp.arange(S, dtype=jnp.int32)[None], pos,
-                                None)
-
-        kc, vc, x = layers(params, kc, vc, x, pos, write, attend)
+        call = types.SimpleNamespace(
+            step="step", pos=pos, positions=positions, tables=tables,
+            slots=slots, blk=blk)
+        kc, vc, x = layers(params, kc, vc, x, call)
         return kc, vc, emit(params, x, lambda x: x[:, 0])
 
     def held_experts_counts(params, tokens):
         """The claims on each held expert of every MoE layer [n_moe,
         held], and the claims of each layer that the dispatch's sort
         and group sizes would not run [n_moe], when ``tokens`` [B, T]
-        run as B prompts over themselves (no cache): the routing the
-        serve programs' dispatch acts on."""
+        run as B prompts over themselves (nothing kept): the routing
+        the serve programs' dispatch acts on."""
         counts, not_run = [], []
 
         def counting(h, lp):
@@ -742,11 +1102,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
 
         B, T = tokens.shape
         pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-        layers(params, None, None, embed(params, tokens), pos,
-               lambda cache, c, sliding, new: cache,
-               lambda q, k, v, kc, vc, c, sliding: _attend_keys(
-                   q, k, v, pos, pos, window if sliding else None),
-               counting)
+        call = types.SimpleNamespace(
+            step="chunk", local=True, pos=pos, length=jnp.int32(T),
+            slot=None, blks=None)
+        layers(params, None, None, embed(params, tokens), call, counting)
         return jnp.stack(counts), jnp.stack(not_run)
 
     return prefill, prefill_resume, decode, held_experts_counts
@@ -763,8 +1122,10 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
             raise NotImplementedError(
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
-                "layer's ring is not pages another engine or a draft "
-                "could be handed (ROADMAP B9)")
+                "layer's ring and a kda layer's recurrent state are not "
+                "pages another engine or a draft could be handed, and "
+                "decode.py's inject and verify know K and V pages alone "
+                "(ROADMAP B9, B14)")
         return refuse
 
     return (jax.jit(prefill, donate_argnums=(1, 2)),

@@ -187,6 +187,10 @@ class RequestResult:
     # sees. Not carried over RPC or a handoff, where ages travel, not
     # times; a handed-off sequence lists the tokens it produced here.
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # The batch slot whose rings and recurrent states the sequence held
+    # on this engine (0: none; kv_cache.KVCache): where a test or a
+    # check finds what the sequence left on the device.
+    slot: int = 0
 
     @property
     def first_token_latency_s(self) -> Optional[float]:
@@ -232,9 +236,9 @@ class _Seq:
     trace: int = 0               # distributed trace id (0 = unsampled)
     admitted_at: Optional[float] = None   # left the queue (engine clock)
     token_times: List[float] = dataclasses.field(default_factory=list)
-    slot: int = 0                # its ring in the window layers' cache
-    #                              (a configuration with two kinds of
-    #                              cache; kv_cache.KVCache)
+    slot: int = 0                # its rings and recurrent states (a
+    #                              configuration with a state by kind
+    #                              of layer; kv_cache.KVCache)
 
     @property
     def last_token(self) -> int:
@@ -432,22 +436,28 @@ class ServeEngine:
                     f"prefill_chunk {cfg.prefill_chunk} must be a "
                     f"positive multiple of block_size {bs}")
             pick_bucket(cfg.prefill_chunk, self._prefill_buckets)
-        # Two kinds of cache (a configuration with layers of several
-        # kinds, or a chip's share of the experts): what is not built
-        # for it is refused here, by name.
-        self._two_caches = model_cfg.mixed
-        if self._two_caches:
+        # A state by kind of layer (a configuration with layers of
+        # several kinds, or a chip's share of the experts): a sequence
+        # then holds a batch slot's rings and recurrent states beside
+        # its pages, and what is not built for that is refused here, by
+        # name.
+        self._slot_states = model_cfg.mixed
+        if self._slot_states:
             refused = [what for what, there in (
-                ("prefix_caching (a page behind a window cannot be mapped "
-                 "into another sequence)", cfg.prefix_caching),
-                ("speculative decoding (draft/spec_k)",
+                ("prefix_caching (a page behind a window, and a kda "
+                 "layer's state after a prefix, cannot be mapped into "
+                 "another sequence: engine._admit, kv_cache.BlockAllocator)",
+                 cfg.prefix_caching),
+                ("speculative decoding (draft/spec_k: speculative.py "
+                 "rolls back pages, not a ring or a recurrent state)",
                  cfg.draft is not None)) if there]
             if refused:
                 raise NotImplementedError(
                     "a configuration with layers of several kinds or a "
                     "chip's share of the experts is served without "
                     + " and without ".join(refused)
-                    + " (ROADMAP B9); set prefix_caching=False, draft=None")
+                    + " (ROADMAP B9, B14); set prefix_caching=False, "
+                    "draft=None")
 
         # Inject pad-width menu, in BLOCK units: the prefill buckets
         # (prompt-only handoffs keep their existing programs) plus the
@@ -460,7 +470,9 @@ class ServeEngine:
         n_blocks = cfg.n_blocks
         if n_blocks is None:
             # Worst case: every batch slot holds a maximal sequence
-            # (+1 for the reserved null block).
+            # (+1 for the reserved null block). A block is block_size
+            # rows of whatever the paged layers keep a position (K and
+            # V, or an mla layer's latent): the count is the same.
             n_blocks = cfg.max_batch * self._table_width + 1
         self.allocator = BlockAllocator(n_blocks, bs)
         # A window layer keeps a ring of this many positions for each
@@ -553,7 +565,7 @@ class ServeEngine:
         max_new = (self.cfg.max_new_tokens if max_new_tokens is None
                    else max_new_tokens)
         if prefill_only:
-            self._refuse_two_caches("a prefill-only request (handoff)")
+            self._refuse_slot_states("a prefill-only request (handoff)")
         validate_request(self.cfg, self.model_cfg,
                          self.allocator.n_blocks, prompt, max_new,
                          deadline_class)
@@ -684,6 +696,9 @@ class ServeEngine:
             m.kv_window_blocks_in_use = (
                 (self.cfg.max_batch - len(self._free_slots))
                 * self.cache.ring // self.cfg.block_size)
+        if "kda" in self.cache.kinds:
+            m.state_slots_in_use = self.cfg.max_batch - len(self._free_slots)
+            m.state_bytes = m.state_slots_in_use * self.cache.slot_bytes
         if self._prefilling:
             self._drain("prefill")
         self._advance_prefills()
@@ -709,7 +724,7 @@ class ServeEngine:
 
     def _finish(self, seq: _Seq, now: float) -> None:
         self.allocator.free(seq.blocks)
-        if self._two_caches:
+        if self._slot_states:
             self._free_slots.append(seq.slot)
         if self._spec is not None:
             self._spec.drop(seq.rid)
@@ -718,6 +733,7 @@ class ServeEngine:
             tokens=list(seq.generated), n_prompt=len(seq.prompt),
             submitted_at=seq.submitted_at,
             first_token_at=seq.first_token_at, finished_at=now,
+            slot=seq.slot,
             deadline_class=seq.deadline_class,
             token_times=seq.token_times)
         self._retire_ema.observe(now)
@@ -846,7 +862,7 @@ class ServeEngine:
                 deadline_class=req.deadline_class,
                 prefill_only=req.prefill_only,
                 trace=req.trace, admitted_at=now,
-                slot=self._free_slots.pop() if self._two_caches else 0))
+                slot=self._free_slots.pop() if self._slot_states else 0))
             self.metrics.record_admitted(req.submitted_at, now, req.trace)
             n_admitted += 1
         return n_admitted
@@ -956,9 +972,10 @@ class ServeEngine:
         return ph.end
 
     def _address(self, seq: _Seq):
-        """Where a sequence's K/V lies, as the serve programs take it:
-        its block table, and with two kinds of cache its slot too."""
-        if self._two_caches:
+        """Where a sequence's state lies, as the serve programs take
+        it: its block table, and with a state by kind of layer its
+        slot too."""
+        if self._slot_states:
             return seq.table, np.int32(seq.slot)
         return seq.table
 
@@ -969,13 +986,15 @@ class ServeEngine:
             self.metrics.record_window_positions(
                 min(written, self.cache.ring))
 
-    def _refuse_two_caches(self, what: str) -> None:
-        if self._two_caches:
+    def _refuse_slot_states(self, what: str) -> None:
+        if self._slot_states:
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
                 "configuration with layers of several kinds keeps its "
-                "window layers' keys in per-slot rings, which are not "
-                "pages and are not moved yet (ROADMAP B9)")
+                "window layers' keys in per-slot rings and its kda layers' "
+                "recurrent states by slot, which are not pages and which "
+                "migrate.py and engine.inject_* do not move yet (ROADMAP "
+                "B9, B14)")
 
     def _complete_prefill(self, seq: _Seq, now: float) -> None:
         """``now``: the end of the prefill span that produced the first
@@ -1016,7 +1035,7 @@ class ServeEngine:
         rides whole — its bytes past ``n_cached`` are never attended
         to, the same null-padding contract decode relies on), then
         free the local reservation."""
-        self._refuse_two_caches("export (migrate)")
+        self._refuse_slot_states("export (migrate)")
         n_blk = self.allocator.blocks_for_tokens(seq.n_cached)
         width = pick_bucket(n_blk, self._inject_widths)
         idx = np.zeros(width, np.int32)   # pad gathers the null block
@@ -1114,7 +1133,7 @@ class ServeEngine:
         results — an abort (or a dropped peer connection mid-stream)
         simply returns the reservation, which is what makes a
         mid-transfer reset resolve exactly-once at the router."""
-        self._refuse_two_caches("inject")
+        self._refuse_slot_states("inject")
         # Every leg of an inject reads the decode call in flight first:
         # the batch slots and blocks counted here, the pool scattered
         # into and the batch joined are the host's view after it.
@@ -1325,7 +1344,7 @@ class ServeEngine:
                 positions = np.where(stay, prev.positions + 1, np.int32(0))
                 tables = np.where(stay[:, None], prev.tables, np.int32(0))
                 slots = np.where(stay, prev.slots, np.int32(NULL_SLOT))
-            address = (tables, slots) if self._two_caches else tables
+            address = (tables, slots) if self._slot_states else tables
         n = sum(seq is not None for seq in rows)
         # A decode step serves the whole batch, so it carries the
         # trace ids of every sampled sequence in it (plural key).
@@ -1355,6 +1374,10 @@ class ServeEngine:
         now = fl.call.end
         with m.phase("serve:decode_post"):
             self._record_window_positions(int(fl.positions.max()) + 1)
+            if "mla" in self.cache.kinds:
+                m.record_latent_positions(np.array(
+                    [p + 1 for p, seq in zip(fl.positions, fl.rows)
+                     if seq is not None]))
             n = 0
             for i, seq in enumerate(fl.rows):
                 if seq is None:

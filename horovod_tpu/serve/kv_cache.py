@@ -268,31 +268,92 @@ class BlockAllocator:
                 self._lru[b] = h        # most-recently-released last
 
 
+#: The kinds of layer that keep something between calls, in the order
+#: their arrays take in :class:`KVCache` (``full`` and ``sliding`` first
+#: and in this order: the pair the window configurations' programs and
+#: their benchmark read by place).
+STATE_KINDS = ("full", "sliding", "kda", "mla")
+
+
+def state_kinds(cfg) -> Tuple[str, ...]:
+    """The kinds of a ``cfg.mixed`` configuration's layers, in
+    :data:`STATE_KINDS`' order. A configuration that may hold window
+    layers keeps both ``full`` and ``sliding`` (either may have no
+    layer: an array with a leading 0)."""
+    if not cfg.stateful:
+        return STATE_KINDS[:2]
+    return tuple(k for k in STATE_KINDS if cfg.n_layers_of(k))
+
+
 @dataclasses.dataclass
 class KVCache:
-    """Device-side paged cache: one K and one V array per model,
-    layer-stacked on the leading dim to match the transformer's
-    scan-over-layers parameter layout.
+    """What the layers keep on the device between calls.
 
-    A configuration with window layers (``cfg.mixed``) has **two kinds
-    of cache**, and ``k`` and ``v`` are each a pair ``(pool, rings)``:
-    the pool above for its full layers alone, and for its window layers
-    ``rings`` [n_window, n_slots + 1, ring, Hkv, Dh], one ring of
-    ``ring`` positions a batch slot (slot 0 the null slot). Position p
-    of a sequence lies at ``p % ring`` of its slot's ring, so a window
-    layer keeps ``ring`` positions a sequence however long it grows,
-    and takes nothing from the allocator."""
+    A configuration of one kind of layer: one K and one V array per
+    model, ``[L, n_blocks, block_size, Hkv, Dh]``, layer-stacked on the
+    leading dim to match the transformer's scan-over-layers parameter
+    layout.
 
-    k: Any  # [L, n_blocks, block_size, Hkv, Dh]
-    v: Any  # [L, n_blocks, block_size, Hkv, Dh]
+    A configuration with layers of several kinds (``cfg.mixed``) keeps
+    **a state by kind of layer**: ``kinds`` names them, and ``k`` and
+    ``v`` hold, place for place, each kind's first and second array
+    (``of(kind)`` gives the pair), stacked over that kind's layers:
+
+    * ``full``: K and V pages ``[n, n_blocks, block_size, Hkv, Dh]``
+      behind the block tables, as above;
+    * ``sliding``: K and V rings ``[n, n_slots + 1, ring, Hkv, Dh]``,
+      one ring of ``ring`` positions a batch slot; position p of a
+      sequence lies at ``p % ring``;
+    * ``kda``: the recurrent state ``[n, n_slots + 1, H, Dh, Dh]``
+      float32, and the newest ``kda_conv - 1`` rows before the
+      convolution ``[n, n_slots + 1, kda_conv - 1, 3 * H * Dh]``;
+    * ``mla``: the latent pages ``[n, n_blocks, block_size,
+      latent_row(cfg)]`` behind the block tables, and no second array
+      (``None``).
+
+    Pages are the allocator's; rings and states are addressed by batch
+    slot, slot 0 the null slot, and take nothing from the allocator
+    however long a sequence grows. A slot needs no cleaning: a new
+    sequence's first chunk starts from a zero state and an empty
+    ring."""
+
+    k: Any  # [L, n_blocks, block_size, Hkv, Dh], or an array a kind
+    v: Any
     block_size: int
     n_blocks: int
     ring: int = 0   # positions a window layer keeps a sequence
+    kinds: Tuple[str, ...] = ()
+
+    def of(self, kind: str):
+        """``(first, second)`` array of ``kind``."""
+        i = self.kinds.index(kind)
+        return self.k[i], self.v[i]
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes a batch slot holds whatever its sequence's length:
+        its rings and its recurrent state."""
+        return sum(a[:, 0].size * a.dtype.itemsize
+                   for kind in self.kinds if kind in ("sliding", "kda")
+                   for a in self.of(kind))
 
     @property
     def max_blocks_per_seq(self) -> int:
         # Shapes are static per engine: table width is the worst case.
         return self.n_blocks
+
+
+def latent_row(cfg) -> int:
+    """Values a position takes in the mla layers' pool: the ``C + R``
+    the layer caches, up to whole lanes of 128 (576 -> 640, the last 64
+    zeros). Rows of 576 are what the chip's (8, 128) tiles pad to 640
+    wherever a row is the innermost dimension; an array
+    ``[.., block, 576]`` is therefore kept with its BLOCKS innermost
+    (the layout without padding), and every program that gathers pages
+    turned the whole pool over on its way in and back on its way out,
+    1.28 GB each way a call (compiled for the v5e, PR 38). At 640 the
+    layout the gather reads is the array's own."""
+    return -(-(cfg.mla_kv_rank + cfg.mla_rope_dim) // 128) * 128
 
 
 def ring_width(window: int, chunk: int, block_size: int) -> int:
@@ -309,9 +370,9 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                   mesh: Optional[Any] = None,
                   dtype: Optional[Any] = None, *, n_slots: int = 0,
                   ring: int = 0) -> KVCache:
-    """Allocate the zeroed block pool on device (and, for a
-    configuration with layers of several kinds, the window layers'
-    rings for ``n_slots`` batch slots: see :class:`KVCache`).
+    """Allocate the zeroed block pool on device (for a configuration
+    with layers of several kinds, each kind's arrays, those addressed
+    by slot for ``n_slots`` batch slots: see :class:`KVCache`).
 
     With a mesh, KV heads are sharded over ``tp`` (matching the
     tp-sharded ``wk``/``wv`` projections so the decode step's cache
@@ -325,15 +386,27 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
 
     dtype = dtype or cfg.dtype
     if cfg.mixed:
-        n_win = cfg.n_window_layers
-        tail = (cfg.n_kv_heads, cfg.head_dim)
+        H, Dh = cfg.n_heads, cfg.head_dim
+        tail = (cfg.n_kv_heads, Dh)
+        n = {kind: cfg.n_layers_of(kind) for kind in STATE_KINDS}
+        shapes = {   # kind -> (shape, dtype) of its first and second array
+            "full": 2 * (((n["full"], n_blocks, block_size) + tail, dtype),),
+            "sliding": 2 * (((n["sliding"], n_slots + 1, ring) + tail,
+                             dtype),),
+            "kda": (((n["kda"], n_slots + 1, H, Dh, Dh), jnp.float32),
+                    ((n["kda"], n_slots + 1, cfg.kda_conv - 1, 3 * H * Dh),
+                     dtype)),
+            "mla": (((n["mla"], n_blocks, block_size, latent_row(cfg)),
+                     dtype), None),
+        }
+        kinds = state_kinds(cfg)
 
-        def both():
-            return (jnp.zeros((cfg.n_layers - n_win, n_blocks, block_size)
-                              + tail, dtype),
-                    jnp.zeros((n_win, n_slots + 1, ring) + tail, dtype))
-        return KVCache(k=both(), v=both(), block_size=block_size,
-                       n_blocks=n_blocks, ring=ring)
+        def arrays(place):
+            return tuple(shapes[kind][place]
+                         and jnp.zeros(*shapes[kind][place])
+                         for kind in kinds)
+        return KVCache(k=arrays(0), v=arrays(1), block_size=block_size,
+                       n_blocks=n_blocks, ring=ring, kinds=kinds)
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     sharding = None

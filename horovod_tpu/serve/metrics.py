@@ -355,6 +355,15 @@ class ServeMetrics:
         # held for one sequence, and the rings in use, in blocks.
         self.kv_window_positions_max = 0
         self.kv_window_blocks_in_use = 0
+        # The kinds whose state is not keys (kda, mla): the batch slots
+        # whose recurrent state a sequence holds and their bytes; the
+        # positions the latent pool held for the rows of the last
+        # decode call (their sum: what absorbed attention has to read),
+        # and the most it held for one sequence.
+        self.state_slots_in_use = 0
+        self.state_bytes = 0
+        self.kv_latent_positions_live = 0
+        self.kv_latent_positions_max = 0
         # Speculative decoding (serve/speculative.py): proposal /
         # acceptance tallies (their ratio is the token-weighted accept
         # rate) and the per-round draft / verify wall-time series.
@@ -562,6 +571,13 @@ class ServeMetrics:
         self.kv_window_positions_max = max(self.kv_window_positions_max,
                                            held)
 
+    def record_latent_positions(self, held) -> None:
+        """The latent pool holds ``held`` positions for each row of a
+        decode call (an array, the padded rows left out)."""
+        self.kv_latent_positions_live = int(held.sum())
+        self.kv_latent_positions_max = max(self.kv_latent_positions_max,
+                                           int(held.max(initial=0)))
+
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
         nobody's host gap."""
@@ -738,6 +754,12 @@ class ServeMetrics:
             # are the window layers' rings (zeros without such layers)
             "kv_window_positions_max": self.kv_window_positions_max,
             "kv_window_blocks_in_use": self.kv_window_blocks_in_use,
+            # a kda layer's states by slot, an mla layer's latent pages
+            # (zeros without such layers)
+            "state_slots_in_use": self.state_slots_in_use,
+            "state_bytes": self.state_bytes,
+            "kv_latent_positions_live": self.kv_latent_positions_live,
+            "kv_latent_positions_max": self.kv_latent_positions_max,
             "p50_first_token_ms": ms(percentile(self.first_token_s, 50)),
             "p99_first_token_ms": ms(percentile(self.first_token_s, 99)),
             "p50_per_token_ms": ms(percentile(self.per_token_s, 50)),

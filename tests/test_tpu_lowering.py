@@ -292,6 +292,86 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# The programs of a configuration whose state is not cached keys (ISSUE
+# 38), at the widths, the 64 slots and the table of the cell that brought
+# them (a dense kda layer, a sparse kda layer and a sparse mla layer;
+# fewer experts held, so that it compiles in a minute).
+_LING_DRIVER = r"""
+import collections, json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=39296, d_model=2560, n_layers=3, n_heads=32, n_kv_heads=32,
+    d_head=128, d_ff=768, d_ff_dense=6144, n_dense_layers=1, max_seq=17408,
+    rope_theta=6e6, norm_eps=1e-6, layer_types=("kda", "kda", "mla"),
+    mla_kv_rank=512, mla_rope_dim=64, n_experts=512, moe_top_k=8,
+    moe_capacity_factor=None, moe_scoring="sigmoid", moe_route_scale=2.5,
+    moe_shared_expert=True, moe_experts_held=16, moe_expert_offset=128,
+    moe_n_group=8, moe_topk_group=4, dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS, CHUNK = 16, 1088, 64, 1024
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+_, resume, decode, _, _ = decode_lib.make_serve_fns(
+    cfg, None, block_size=BS, table_width=WIDTH)
+
+
+def shape_of(s):
+    return "%s[%s]" % ({{"bfloat16": "bf16", "float32": "f32"}}[str(s.dtype)],
+                       ",".join(map(str, s.shape)))
+
+
+large = {{shape_of(kc[0]): "state", shape_of(kc[1]): "pool"}}
+out = {{"device_kind": topo.devices[0].device_kind,
+       "state_bytes": kc[0].size * 4, "pool_bytes": kc[1].size * 2}}
+for name, fn, args in (
+        ("decode", decode,
+         (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
+        # (the offset is traced: 8192 or any other is this program)
+        ("prefill_resume", resume,
+         (i32(CHUNK), i32(), i32(), (i32(WIDTH), i32())))):
+    compiled = fn.lower(params, kc, vc, *args).compile()
+    text = compiled.as_text()
+    ops = collections.Counter()
+    for result, opcode in re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text):
+        if result in large:
+            ops[large[result] + " " + opcode] += 1
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    out[name] = {{
+        "ops": ops, "aliased": len(re.findall(r"may-alias|must-alias",
+                                              aliased.group(1))),
+        # a float32 tensor with a chunk's queries against a whole table
+        "scores_of_the_table": len(re.findall(
+            r"f32\[[\d,]*(1024,17408|17408,1024)[\d,]*\]", text)),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
 @functools.lru_cache(maxsize=None)
 def _compile_for_v5e(driver):
     proc = subprocess.run(
@@ -467,3 +547,28 @@ def test_two_cache_serve_programs_copy_neither_cache_nor_experts_on_v5e():
         # the matrices it stages among them, is under the rings' size,
         # and with a copy of either cache it would not be
         assert got["temp_bytes"] < out["rings_bytes"], (program, got)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_state_and_latent_pool_are_updated_where_they_lie_on_v5e(program):
+    """ISSUE 38: decode at 64 slots and a chunk of 1024 over a table of
+    17 408 positions compile for the v5e with the recurrent state, the
+    convolution's rows and the latent pool aliased in and out; no copy
+    of the state array (570 MB here, 0.85 GB at the cell's six kda
+    layers) or of the pool (1.4 GB), only the in-place writes of
+    ``state_write`` and ``kv_write``; no float32 tensor of a chunk's
+    queries against the whole table (2.3 GB over 32 heads: the latent
+    attention goes a key block of 1024 at a time); and what a call
+    allocates is bounded: the scan's blocks and one key block's scores
+    for a chunk, one key block's gathered latents for a decode step."""
+    out = _compile_for_v5e(_LING_DRIVER)
+    got = out[program]
+    assert got["aliased"] == 3, got            # state, conv rows, pool
+    assert set(got["ops"]) <= {
+        "state parameter", "state get-tuple-element", "state bitcast",
+        "state fusion", "state dynamic-update-slice", "state scatter",
+        "pool parameter", "pool get-tuple-element", "pool bitcast",
+        "pool fusion", "pool scatter", "pool dynamic-update-slice"}, got
+    assert got["scores_of_the_table"] == 0, got
+    limit = {"decode": 0.25e9, "prefill_resume": 0.9e9}[program]
+    assert got["temp_bytes"] < limit < out["pool_bytes"], got

@@ -502,6 +502,60 @@ def test_the_existing_train_steps_did_not_move(devices, case):
     assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
+# sha256 of the lowered StableHLO of the serve programs of the tiny window
+# configuration above (over its pool and rings) and of a tiny dense GQA
+# decoder (over one pool), taken on the tree before the state became one
+# array a kind of layer (PR 37's; jax 0.9.0).
+_SERVE_PROGRAMS = {
+    "two_caches/prefill":
+        "34a2ab9483578a5c4831799975f027de09230c7bbeb96a294b00d8aa9e7be35c",
+    "two_caches/prefill_resume":
+        "8dd7e0c23433cdcd04160f713efb59f22c328ca1785170fd7cc3ea38eba3d2b9",
+    "two_caches/decode":
+        "1d076e15d9673a0efc63f12cf38a8518ba3fca124ef4eb2465ac3375c9ea7f72",
+    "dense/prefill":
+        "2c4fa4dde8b1a2f85ab888aee66330331942b29c379d423f7cd4dcb1b5f1ed5c",
+    "dense/prefill_resume":
+        "a9b4a0ea4dc41f1cea940728fd467f2bdae972f4210be2637c314191779141ef",
+    "dense/decode":
+        "7713d90e726dada26c6d7340f579247367a9b30023b763778041935a1b8d83d0",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SERVE_PROGRAMS))
+def test_the_existing_serve_programs_did_not_move(case):
+    """The table of kinds, the recurrent states and the latent pool
+    (ISSUE 38) changed no operation of the programs that serve window
+    and full layers over two caches, nor of the dense ones: the arrays
+    keep their places among the arguments and every operation its
+    order."""
+    import hashlib
+
+    name, program = case.split("/")
+    cfg, ring = ((tiny(), RING) if name == "two_caches" else
+                 (TransformerConfig.tiny(dtype=jnp.float32, remat=False,
+                                         n_kv_heads=2), 0))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    width = 6
+    params = jax.eval_shape(
+        lambda: init_transformer(cfg, jax.random.PRNGKey(0)))
+    kc, vc = jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+        cfg, 13, BS, n_slots=2, ring=ring)))
+    fns = dict(zip(("prefill", "prefill_resume", "decode"),
+                   decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                             table_width=width, ring=ring)))
+    one = (i32(width), i32()) if cfg.mixed else i32(width)
+    many = (i32(2, width), i32(2)) if cfg.mixed else i32(2, width)
+    args = {"prefill": (i32(16), i32(), one),
+            "prefill_resume": (i32(16), i32(), i32(), one),
+            "decode": (i32(2), i32(2), many)}[program]
+    text = fns[program].lower(params, kc, vc, *args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == _SERVE_PROGRAMS[case]
+
+
 def test_the_two_copies_of_the_reference_are_one_text():
     import os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
