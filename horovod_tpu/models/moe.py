@@ -437,6 +437,92 @@ def held_pairs(experts, cfg: MoEConfig):
     return jnp.where(held, local, cfg.n_held), held
 
 
+#: The compacted dispatch engages where it leaves out at least this many
+#: of a call's ``N·K`` rows (a static decision from shapes): below it,
+#: a served chunk or a decode step, a second compiled branch costs more
+#: than the rows it saves.
+_COMPACT_MIN_ROWS_SAVED = 16384
+#: Rows of the grouped matmul's row block (the v5e's MXU), in whole
+#: multiples of which the bound is taken.
+_ROW_TILE = 128
+
+
+def held_row_bound(pairs: int, cfg: MoEConfig) -> Optional[int]:
+    """The rows ``C`` that :func:`_held_experts` compacts a call of
+    ``pairs`` (token, choice) pairs to: one and a half times the pairs
+    an even router puts on the held experts (what the load-balancing
+    term drives towards), in whole row tiles. ``None`` where the rows
+    it would leave out are fewer than ``_COMPACT_MIN_ROWS_SAVED``: the
+    call then runs all ``pairs`` rows, as it does when its held pairs
+    exceed ``C``. The dispatch and the routing report both ask here."""
+    tiles = -(-3 * pairs * cfg.n_held // (2 * cfg.n_experts * _ROW_TILE))
+    bound = _ROW_TILE * tiles
+    return bound if pairs - bound >= _COMPACT_MIN_ROWS_SAVED else None
+
+
+def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None):
+    """The routed sum ``y`` [N, D] of the held experts over the first
+    ``rows`` sorted places (``None``: all ``N·K`` of them). ``order``
+    puts the held pairs first, by expert, so with ``sizes.sum() <=
+    rows`` the places left out hold no pair that counts: no row is
+    gathered for them, no matmul, no SwiGLU, and the combine's ``N·K``
+    slots read a source of ``rows`` rows."""
+    K = gates.shape[1]
+    w_gate, w_up, w_down = w
+    with jax.named_scope("moe_dispatch"):
+        if rows is not None:
+            order = order[:rows]
+            inverse = jnp.minimum(inverse, rows - 1)
+        taken = _take_sorted(xf, order, inverse, K, held)      # [rows, D]
+    with jax.named_scope("moe_experts"):
+        g = jax.nn.silu(lax.ragged_dot(taken, w_gate, sizes)
+                        .astype(jnp.float32))
+        u = lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)
+        out = lax.ragged_dot((g * u).astype(xf.dtype), w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        # what lies behind the last group is not a result: masked, not
+        # multiplied by a zero gate
+        out = jnp.where(held.reshape(-1, 1),
+                        _take_unsorted(out, order, inverse), 0)
+        return jnp.einsum("nkd,nk->nd", out.reshape(-1, K, xf.shape[-1]),
+                          gates.astype(xf.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_rows_bounded(bound: int, xf, w, gates, held, order, inverse,
+                       sizes):
+    """:func:`_held_rows` over ``bound`` rows where the held pairs fit
+    them and over all ``N·K`` where they do not: the same sum either
+    way, every held pair run. The branch is taken outside
+    differentiation, once forward and once backward, and the residuals
+    are the block's own inputs: ``jax.grad`` through a ``lax.cond``
+    would make both branches' residuals results of the forward and
+    fill the untaken one's ``[N·K, ·]`` with zeros every layer."""
+    return lax.cond(sizes.sum() <= bound,
+                    functools.partial(_held_rows, rows=bound), _held_rows,
+                    xf, w, gates, held, order, inverse, sizes)
+
+
+def _held_rows_bounded_fwd(bound, *args):
+    return _held_rows_bounded(bound, *args), args
+
+
+def _held_rows_bounded_bwd(bound, res, g):
+    xf, w, gates, *how = res
+
+    def pull(rows):
+        def branch(xf, w, gates, g):
+            return jax.vjp(lambda *a: _held_rows(*a, *how, rows=rows),
+                           xf, w, gates)[1](g)
+        return branch
+
+    return (*lax.cond(how[-1].sum() <= bound, pull(bound), pull(None),
+                      xf, w, gates, g), None, None, None, None)
+
+
+_held_rows_bounded.defvjp(_held_rows_bounded_fwd, _held_rows_bounded_bwd)
+
+
 def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     """One chip's part of a MoE block whose experts are spread over
     chips (``experts_held``): of the ``N·K`` (token, choice) pairs the
@@ -444,7 +530,10 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     the front and run as grouped matmuls, every one of them (no
     capacity); the others fall behind the last group, their rows are
     never read and they add nothing, as the chip that holds their
-    expert would add it in the deployment's combine. The shared expert
+    expert would add it in the deployment's combine. Where
+    :func:`held_row_bound` gives a bound, only that many sorted places
+    are gathered, multiplied and combined, unless a call's held pairs
+    exceed it, which then runs all ``N·K``. The shared expert
     is added here once: summed over chips, the deployment adds it on
     one of them.
 
@@ -454,24 +543,18 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     B, T, D = x.shape
     K = cfg.top_k
     xf = x.reshape(B * T, D)
+    bound = held_row_bound(B * T * K, cfg)
     with jax.named_scope("moe_dispatch"):
         local, held = held_pairs(experts, cfg)
         order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
         sizes = sizes[:cfg.n_held]
         inverse = jnp.argsort(order)
-        rows = _take_sorted(xf, order, inverse, K, held)       # [N·K, D]
-    with jax.named_scope("moe_experts"):
-        g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
-                        .astype(jnp.float32))
-        u = lax.ragged_dot(rows, lp["w_up"], sizes).astype(jnp.float32)
-        out = lax.ragged_dot((g * u).astype(x.dtype), lp["w_down"], sizes)
-    with jax.named_scope("moe_combine"):
-        # what lies behind the last group is not a result: masked, not
-        # multiplied by a zero gate
-        out = jnp.where(held.reshape(-1, 1),
-                        _take_unsorted(out, order, inverse), 0)
-        y = jnp.einsum("nkd,nk->nd", out.reshape(B * T, K, D),
-                       gates.astype(x.dtype))
+    w = (lp["w_gate"], lp["w_up"], lp["w_down"])
+    if bound is None:
+        y = _held_rows(xf, w, gates, held, order, inverse, sizes)
+    else:
+        y = _held_rows_bounded(bound, xf, w, gates, held, order, inverse,
+                               sizes)
     if cfg.shared_expert:
         y = y + _shared_expert(xf, lp)
     return y.reshape(B, T, D).astype(x.dtype)
@@ -665,6 +748,8 @@ MOE_METRIC_KEYS = (
     "moe_dispatch_dropped_token_frac",
     "moe_dispatch_bytes_saved_pct",
     "moe_expert_load_max_over_mean",
+    "moe_compact_calls_share",
+    "moe_held_pairs_over_bound_max",
 )
 
 _moe_metrics: Dict[str, float] = {}
@@ -700,17 +785,41 @@ def held_pairs_not_run(experts, cfg: MoEConfig):
     :func:`_held_experts` would not run through that expert: its
     grouped matmuls run sorted place i with the matrices of the group
     that the running sum of ``sizes`` puts i in, so a pair is run iff
-    its place lies in a group and the expert sorted there is that
-    group's. Read off the dispatch's own ``order`` and ``sizes``; the
+    its place lies in a group, the expert sorted there is that
+    group's, and the place is among the rows the call runs (all of
+    them, or :func:`held_row_bound`'s where the held pairs fit it).
+    Read off the dispatch's own ``order``, ``sizes`` and bound; the
     held pairs are counted from the router's choices, without the
-    sort. 0 unless the sort, the sizes or a capacity lose a pair."""
+    sort. 0 unless the sort, the sizes, the bound or a capacity lose a
+    pair."""
     local, held = held_pairs(experts, cfg)
     order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
-    ends = jnp.cumsum(sizes[:cfg.n_held])
-    group = jnp.searchsorted(ends, jnp.arange(order.size, dtype=ends.dtype),
-                             side="right")
+    sizes = sizes[:cfg.n_held]
+    ends = jnp.cumsum(sizes)
+    place = jnp.arange(order.size, dtype=ends.dtype)
+    group = jnp.searchsorted(ends, place, side="right")
     run = (group < cfg.n_held) & (local.reshape(-1)[order] == group)
+    bound = held_row_bound(order.size, cfg)
+    if bound is not None:
+        run &= (place < bound) | (sizes.sum() > bound)
     return (held.sum() - run.sum()).astype(jnp.float32)
+
+
+def compaction_summary(counts, pairs: int, cfg: MoEConfig
+                       ) -> Dict[str, float]:
+    """How often :func:`_held_experts` compacts, from the held experts'
+    claims ``counts`` [layers, held] of calls of ``pairs`` (token,
+    choice) pairs each: ``moe_compact_calls_share``, the layers whose
+    held pairs fit :func:`held_row_bound` (the others run every row),
+    and ``moe_held_pairs_over_bound_max``, the largest layer's held
+    pairs over that bound. Empty where a call of that size has no
+    bound: nothing there could compact."""
+    bound = held_row_bound(pairs, cfg)
+    if bound is None:
+        return {}
+    held = counts.sum(-1)
+    return {"moe_compact_calls_share": float((held <= bound).mean()),
+            "moe_held_pairs_over_bound_max": float(held.max() / bound)}
 
 
 def routing_summary(counts, overflow) -> Dict[str, float]:

@@ -1105,8 +1105,12 @@ def moe_routing_report(params, tokens, cfg: TransformerConfig
     configuration that holds a share also reports
     ``moe_local_pair_share``: the pairs on held experts over all
     ``B·T·K`` pairs of a layer, the share of the experts held if the
-    router is even. Host-callable telemetry, outside the train step: it
-    runs a program of its own."""
+    router is even; and, where a call of ``B·T·K`` pairs is large
+    enough for the held dispatch to compact
+    (``moe_lib.held_row_bound``), ``moe_compact_calls_share`` and
+    ``moe_held_pairs_over_bound_max``
+    (``moe_lib.compaction_summary``). Host-callable telemetry, outside
+    the train step: it runs a program of its own."""
     moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
 
     def counting(h, lp):
@@ -1135,8 +1139,9 @@ def moe_routing_report(params, tokens, cfg: TransformerConfig
     counts, overflow = run(params, tokens)
     report = moe_lib.routing_summary(counts, overflow)
     if cfg.moe_experts_held is not None:
-        report["moe_local_pair_share"] = float(counts.sum(-1).mean()) / (
-            tokens.shape[0] * tokens.shape[1] * cfg.moe_top_k)
+        pairs = tokens.shape[0] * tokens.shape[1] * cfg.moe_top_k
+        report["moe_local_pair_share"] = float(counts.sum(-1).mean()) / pairs
+        report.update(moe_lib.compaction_summary(counts, pairs, cfg.moe))
     return report
 
 

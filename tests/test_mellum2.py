@@ -306,6 +306,150 @@ def test_two_data_parallel_chips_train_a_mixed_stack(devices):
 
 
 # (f) --------------------------------------------------------------------
+# The compacted dispatch (ISSUE 40): a call large enough compacts its
+# held pairs to ``moe.held_row_bound`` rows, and runs every row where
+# they exceed it. Here 256 tokens x 2 choices, 2 of 8 experts held: the
+# bound is 256 of the 512 rows, and the size at which it engages (a
+# module constant, 16 384 rows left out) is brought down to these shapes.
+
+PAIRS, BOUND = 512, 256
+
+
+def _a_share():
+    share = moe_lib.MoEConfig(n_experts=8, top_k=2, capacity_factor=None,
+                              norm_topk_prob=True, aux_loss_coef=0.05,
+                              experts_held=2, expert_offset=2)
+    lp = jax.tree.map(lambda a: a[0], moe_lib.init_moe_params(
+        jax.random.PRNGKey(3), 1, 32, 16, share, jnp.float32))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, PAIRS // 4, 32))
+    return share, lp, x
+
+
+def _engage(monkeypatch, share):
+    assert moe_lib.held_row_bound(PAIRS, share) is None
+    monkeypatch.setattr(moe_lib, "_COMPACT_MIN_ROWS_SAVED", PAIRS - BOUND)
+    assert moe_lib.held_row_bound(PAIRS, share) == BOUND
+    assert moe_lib.held_row_bound(PAIRS - 1, share) is None
+
+
+def _close(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("router", ["even", "skewed"])
+def test_the_compact_form_is_the_whole_row_form(monkeypatch, router):
+    """``y``, ``aux`` and the gradients of ``x``, the router and the
+    three expert stacks, with the bound engaged against without: under
+    an even router the held pairs fit the bound, under one that sends
+    every token to a held expert they do not and every row is run."""
+    share, lp, x = _a_share()
+    if router == "skewed":
+        x = x + 1.0
+        lp = {**lp, "router": lp["router"].at[:, 3].add(0.5)
+              .at[:, 2].add(0.05)}
+    cot = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+
+    def block(x, lp):
+        y, aux = moe_lib.moe_ffn_dropless(x, lp, share)
+        return jnp.sum(y * cot) + aux, (y, aux)
+
+    want = jax.value_and_grad(block, (0, 1), has_aux=True)(x, lp)
+    counts, _ = moe_lib.routing_counts(x, lp["router"], share)
+    assert moe_lib.compaction_summary(counts[None], PAIRS, share) == {}
+    _engage(monkeypatch, share)
+    got = jax.value_and_grad(block, (0, 1), has_aux=True)(x, lp)
+    _close(got, want)
+    assert float(jnp.abs(want[1][1]["router"]).max()) > 0
+    counts, not_run = moe_lib.routing_counts(x, lp["router"], share)
+    assert float(not_run) == 0
+    fits = float(counts.sum()) <= BOUND
+    assert fits == (router == "even")
+    assert moe_lib.compaction_summary(counts[None], PAIRS, share) == {
+        "moe_compact_calls_share": float(fits),
+        "moe_held_pairs_over_bound_max": float(counts.sum()) / BOUND}
+
+
+@pytest.mark.parametrize("held_pairs", [BOUND - 1, BOUND, BOUND + 1])
+def test_a_share_at_the_bound_and_one_row_over(monkeypatch, held_pairs):
+    """Routing made by hand so that exactly ``held_pairs`` of the 512
+    pairs lie on a held expert: at the bound the compact form runs them
+    all; one over, the first ``BOUND`` rows alone would lose a pair, and
+    the call takes the whole-row form: no pair is dropped either way."""
+    share, lp, x = _a_share()
+    rng = np.random.default_rng(held_pairs)
+    experts = rng.choice([0, 1, 4, 5, 6, 7], PAIRS)
+    experts[rng.permutation(PAIRS)[:held_pairs]] = rng.choice(
+        [2, 3], held_pairs)
+    experts = jnp.asarray(experts.reshape(-1, 2), jnp.int32)
+    gates = jax.random.uniform(jax.random.PRNGKey(6), experts.shape)
+    cot = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    w = (lp["w_gate"], lp["w_up"], lp["w_down"])
+
+    def block(x, w, gates):
+        y = moe_lib._held_experts(
+            x, dict(zip(("w_gate", "w_up", "w_down"), w)), share, gates,
+            experts)
+        return jnp.sum(y * cot), y
+
+    want = jax.value_and_grad(block, (0, 1, 2), has_aux=True)(x, w, gates)
+    _engage(monkeypatch, share)
+    got = jax.value_and_grad(block, (0, 1, 2), has_aux=True)(x, w, gates)
+    _close(got, want)
+    assert float(moe_lib.held_pairs_not_run(experts, share)) == 0
+
+    local, held = moe_lib.held_pairs(experts, share)
+    order, sizes = moe_lib._sorted_by_expert(local, 3)
+    assert int(sizes[:2].sum()) == held_pairs
+    first_rows = moe_lib._held_rows(
+        x.reshape(-1, 32), w, gates, held, order, jnp.argsort(order),
+        sizes[:2], rows=BOUND).reshape(x.shape)
+    lost = float(jnp.abs(first_rows - want[0][1]).max())
+    assert (lost > 1e-3) == (held_pairs > BOUND), lost
+    counts = jnp.asarray([[held_pairs - 7.0, 7.0]])
+    assert moe_lib.compaction_summary(counts, PAIRS, share) == {
+        "moe_compact_calls_share": float(held_pairs <= BOUND),
+        "moe_held_pairs_over_bound_max": held_pairs / BOUND}
+
+
+@pytest.mark.parametrize("router", ["even", "skewed"])
+def test_a_trained_stack_compacts_and_drops_nothing(monkeypatch, router):
+    """The whole model with the bound engaged (192 pairs a layer, 3 of
+    8 experts held: 128 rows), under remat: the loss and every gradient
+    leaf are the reference's, which has no sort and no bound; the report
+    says how many layers compacted and how full the fullest was, and
+    says neither where no call is large enough."""
+    cfg = tiny()
+    params, rows = seeded(cfg), rows_of(cfg)
+    if router == "skewed":          # every token to one held expert or two
+        for lp in params["layers"]:
+            r = lp["moe"]["router"]
+            lp["moe"]["router"] = r.at[:, 2:5].set(
+                6.0 * r[:, 3:4] * jnp.asarray([-1.0, 1.0, 1.0]))
+    tokens = jnp.asarray(rows[:, :-1])
+    before = moe_routing_report(params, tokens, cfg)
+    assert "moe_compact_calls_share" not in before
+    assert "moe_held_pairs_over_bound_max" not in before
+    monkeypatch.setattr(moe_lib, "_COMPACT_MIN_ROWS_SAVED", 64)
+    assert moe_lib.held_row_bound(2 * SEQ * 2, cfg.moe) == 128
+    report = moe_routing_report(params, tokens, cfg)
+    assert report["moe_dispatch_dropped_token_frac"] == 0
+    assert report["moe_local_pair_share"] == before["moe_local_pair_share"]
+    fullest = report["moe_held_pairs_over_bound_max"]
+    assert fullest >= report["moe_local_pair_share"] * 192 / 128
+    assert report["moe_compact_calls_share"] in (0.0, 0.25, 0.5, 0.75, 1.0)
+    assert (report["moe_compact_calls_share"] == 1.0) == (fullest <= 1.0)
+    if router == "skewed":
+        assert fullest > 1.0        # some layer takes the whole-row form
+    want, want_g = jax.value_and_grad(ref.loss)(params, rows, sizes_of(cfg))
+    got, got_g = jax.jit(jax.value_and_grad(
+        lambda p: lm_loss(p, {"tokens": jnp.asarray(rows)}, cfg)))(params)
+    assert abs(float(got) - float(want)) < 2e-6 * float(want)
+    for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        assert rel(g, w) < 5e-5
+
+
+# (g) --------------------------------------------------------------------
 
 def test_the_two_copies_of_the_reference_are_one_text():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

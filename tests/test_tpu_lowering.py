@@ -372,6 +372,151 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# The trained share of the experts (ISSUE 40): the step of the cell
+# `train-mellum2-ep4-seq8192` at its own shapes (one row of 8192, 8
+# layers, remat `full`; `mellum2-12b-ep4-8l`'s sizes), where
+# `moe._held_experts` compacts 65 536 pairs to 24 576 rows behind a
+# `cond`; and a chunk's call of the two served shares, which it leaves
+# alone. The optimised HLO is walked by computation: what a fall-back
+# branch (the `cond`'s branch 0) calls, and what lies outside them.
+_MELLUM_DRIVER = r"""
+import collections, json, math, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, make_train_step
+from horovod_tpu.models import moe as moe_lib
+from horovod_tpu.parallel import build_mesh
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = SingleDeviceSharding(topo.devices[0])
+yarn = dict(theta=5e5, factor=16.0, original_max_seq=8192, beta_fast=32.0,
+            beta_slow=1.0, attention_factor=1.2772588722239782)
+cfg = TransformerConfig(
+    vocab_size=24576, d_model=2304, n_layers=8, n_heads=32, n_kv_heads=4,
+    d_head=128, d_ff=896, max_seq=8192, rope_theta=5e5, norm_eps=1e-6,
+    layer_types=("sliding", "sliding", "sliding", "full") * 2,
+    attn_window=1024, layer_rotary={{"sliding": {{"theta": 5e5}},
+                                    "full": yarn}},
+    n_experts=64, moe_top_k=8, moe_capacity_factor=None,
+    moe_norm_topk_prob=True, moe_aux_loss_coef=0.001, moe_experts_held=16,
+    moe_expert_offset=16, dtype=jnp.bfloat16, sp_attention="flash",
+    remat=True, remat_policy="full")
+PAIRS, BOUND = 65536, moe_lib.held_row_bound(65536, cfg.moe)
+out = {{"device_kind": topo.devices[0].device_kind, "bound": BOUND}}
+init_state, step, _ = make_train_step(
+    cfg, build_mesh(dp=-1, devices=topo.devices[:1]))
+state = jax.eval_shape(init_state, jax.ShapeDtypeStruct((2,), jnp.uint32))
+compiled = step.lower(state, {{"tokens": jax.ShapeDtypeStruct(
+    (1, 8193), jnp.int32)}}).compile()
+
+comps, name = {{}}, None
+for line in compiled.as_text().splitlines():
+    head = re.match(r"(?:ENTRY )?%?([\w.\-]+) (?:\(.*\) -> .*)?\{{\s*$", line)
+    if head and not line.startswith(" "):
+        name = head.group(1)
+        comps[name] = []
+    elif line.startswith("}}"):
+        name = None
+    elif name is not None:
+        comps[name].append(line)
+
+
+def called(lines):
+    for line in lines:
+        for group in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)"
+                r"|(?:branch|called)_computations=\{{([^}}]*)\}}", line):
+            for names in group:
+                yield from (n.strip().lstrip("%") for n in names.split(",")
+                            if n.strip())
+
+
+def reached(roots):
+    seen, todo = set(), list(roots)
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in seen:
+            seen.add(c)
+            todo.extend(called(comps[c]))
+    return seen
+
+
+conds = [re.search(r"branch_computations=\{{%?([\w.\-]+), %?([\w.\-]+)\}}",
+                   line).groups()
+         for lines in comps.values() for line in lines
+         if " conditional(" in line]
+fall_back = reached(c[0] for c in conds)
+fused = set()                   # fusions' bodies: nothing there is in HBM
+for lines in comps.values():
+    for line in lines:
+        if " fusion(" in line:
+            fused.update(re.findall(r"calls=%?([\w.\-]+)", line))
+rows_of = {{"inside": collections.Counter(), "outside": collections.Counter()}}
+wide = collections.Counter()     # [65536, D] / [65536, F] outside, in HBM
+fills = []
+for comp, lines in comps.items():
+    where = "inside" if comp in fall_back else "outside"
+    for line in lines:
+        inst = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\((.*)",
+                        line)
+        if not inst:
+            continue
+        result, opcode, rest = inst.groups()
+        if "ragged-dot-none" in rest and opcode == "custom-call":
+            shapes = re.findall(r"\[(\d+),(?:2304|896)\]",
+                                result + rest.split("custom_call_target")[0])
+            rows_of[where].update(set(shapes))
+        if where == "outside" and comp not in fused:
+            shape = re.match(r"\w+\[([\d,]*)\]", result)
+            dims = [int(d) for d in shape.group(1).split(",") if d] \
+                if shape else []
+            if dims[:1] == [PAIRS] and dims[1:] in ([2304], [896]):
+                wide[opcode + " " + ",".join(map(str, dims[1:]))] += 1
+            if opcode == "broadcast" and math.prod(dims) in (
+                    PAIRS * 2304, PAIRS * 896):     # in whatever layout
+                fills.append(result)
+out["step"] = {{"conditionals": len(conds),
+               "ragged_rows": {{k: sorted(v) for k, v in rows_of.items()}},
+               "ragged_calls_outside": sum(
+                   " custom-call(" in line and "ragged-dot-none" in line
+                   for comp, lines in comps.items() if comp not in fall_back
+                   for line in lines),
+               "wide_outside": wide, "fills_outside": fills,
+               "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+
+
+# a chunk of 1024 tokens through the two served shares' expert layers
+def chunk_call(d, f, **moe):
+    share = moe_lib.MoEConfig(capacity_factor=None, **moe)
+    lp = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=one),
+        jax.eval_shape(lambda: moe_lib.init_moe_params(
+            jax.random.PRNGKey(0), 1, d, f, share, jnp.bfloat16)))
+    text = jax.jit(lambda x, lp: moe_lib.moe_ffn_dropless(x, lp, share)
+                   ).lower(jax.ShapeDtypeStruct((1, 1024, d), jnp.bfloat16,
+                                                sharding=one),
+                           lp).compile().as_text()
+    return {{"bound": moe_lib.held_row_bound(1024 * share.top_k, share),
+            "conditionals": text.count(" conditional("),
+            "kernels": text.count("ragged-dot-none")}}
+
+
+out["trinity_chunk"] = chunk_call(
+    3072, 3072, n_experts=256, top_k=4, scoring="sigmoid",
+    route_scale=2.448, shared_expert=True, experts_held=32)
+out["ling_chunk"] = chunk_call(
+    2560, 768, n_experts=512, top_k=8, scoring="sigmoid", route_scale=2.5,
+    shared_expert=True, experts_held=128, expert_offset=128, n_group=8,
+    topk_group=4)
+print("LOWERED " + json.dumps(out))
+"""
+
+
 @functools.lru_cache(maxsize=None)
 def _compile_for_v5e(driver):
     proc = subprocess.run(
@@ -572,3 +717,43 @@ def test_state_and_latent_pool_are_updated_where_they_lie_on_v5e(program):
     assert got["scores_of_the_table"] == 0, got
     limit = {"decode": 0.25e9, "prefill_resume": 0.9e9}[program]
     assert got["temp_bytes"] < limit < out["pool_bytes"], got
+
+
+def test_the_trained_share_runs_its_bound_s_rows_outside_the_fall_back():
+    """ISSUE 40: the cell's step compiled for the v5e holds two
+    ``cond``s a layer (forward and backward; the recomputed forward's is
+    dropped with its unused ``y``); every grouped matmul outside the
+    fall-back branches takes ``[24576, ·]`` rows, twelve a layer, and
+    only inside them ``[65536, ·]``; outside them nothing ``[65536,
+    896]`` exists at all (the SwiGLU and its casts run the bound's
+    rows), what is ``[65536, 2304]`` there is the reads of the ``N·K``
+    slots (a gather forward, two and the masked cotangent backward: the
+    token side, ROADMAP A11's remainder), and nothing that large is a
+    broadcast: no residual of the untaken branch is filled with
+    zeros."""
+    out = _compile_for_v5e(_MELLUM_DRIVER)
+    got = out["step"]
+    assert out["bound"] == 24576, out
+    assert got["conditionals"] == 16, got
+    assert got["ragged_rows"] == {"inside": ["65536"],
+                                  "outside": ["24576"]}, got
+    assert got["ragged_calls_outside"] == 8 * 12, got
+    assert not any(k.endswith(" 896") for k in got["wide_outside"]), got
+    moved = {k: v for k, v in got["wide_outside"].items()
+             if k.split()[0] not in ("parameter", "get-tuple-element",
+                                     "bitcast", "copy-start", "copy-done")}
+    assert set(moved) == {"fusion 2304"} and moved["fusion 2304"] <= 8 * 4, \
+        got
+    assert got["fills_outside"] == [], got
+    assert got["temp_bytes"] < 3.2e9, got
+
+
+@pytest.mark.parametrize("share", ["trinity_chunk", "ling_chunk"])
+def test_a_served_chunk_keeps_the_whole_row_form_with_no_cond(share):
+    """... and a served chunk's call (4 096 pairs over 32 of 256
+    experts, 8 192 over 128 of 512) is below the size at which the
+    bound engages: no ``cond``, three grouped matmuls, the parent's
+    program."""
+    got = _compile_for_v5e(_MELLUM_DRIVER)[share]
+    assert got["bound"] is None and got["conditionals"] == 0, got
+    assert got["kernels"] >= 3, got
