@@ -8,7 +8,6 @@ there is nothing to read, and the harness then leaves the metric out.
 
 from __future__ import annotations
 
-import re
 from typing import Any, Dict, List
 
 
@@ -28,9 +27,3 @@ def lookup(meas: Dict[str, Any], dotted: str):
         node = node[part]
     return node
 
-
-def matching_ops(meas: Dict[str, Any], pattern: str):
-    """``[short name, seconds, count, instruction]`` rows of the trace's
-    op table whose whole instruction matches ``pattern``."""
-    rx = re.compile(pattern)
-    return [row for row in meas["trace"]["ops"] if rx.search(row[3])]

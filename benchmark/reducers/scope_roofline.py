@@ -1,7 +1,8 @@
-"""A named kernel's share of its roofline: ``op_roofline`` with the
-calls found by the kernel's name in their scope path (``match`` over
-the ``tf_op`` of the operation's metadata, see ``_scopes.py``), not by
-the shape of the call. ``category`` (over ``hlo_category``) keeps the
+"""A named kernel's share of its roofline: the device time of its calls
+against the least time of ``flops.<cost>`` for as many calls, the calls
+found by the kernel's name in their scope path (``match`` over the
+``tf_op`` of the operation's metadata, see ``_scopes.py``), not by the
+shape of the call. ``category`` (over ``hlo_category``) keeps the
 calls themselves apart from the small copies XLA makes of a kernel's
 results, which inherit its path."""
 from benchmark import flops, harness

@@ -3,7 +3,7 @@ end on the CPU at a tiny size (chunked prefill, a recurrent state a
 slot, the latent pool, the check of tokens and states against
 ``benchmark/reference_ling3.py``), the block dealing, ``flops_ling3.py``
 against hand counts, the roofline reducer on made-up rows, and the
-``.ling`` metrics' files. Times and rates printed here mean nothing."""
+metrics the cell reports. Times and rates printed here mean nothing."""
 import json
 import os
 import shutil
@@ -69,13 +69,13 @@ def test_ling3_cell_runs_on_cpu(trace, monkeypatch, capsys):
             win["window"]["tokens"] / (win["window_s"] - 0.2))
     else:
         m = result["metrics"]
-        assert m["compiles_in_window.ling"]["value"] == 0
+        assert m["compiles_in_window.batch"]["value"] == 0
         # the longest prompt (100) and some of its outputs
         assert 100 <= m["kv_latent_positions_max.ling"]["value"] <= 112
         assert m["state_slots_in_use.ling"]["value"] == 4
-        assert 1 <= m["moe_held_experts_touched_mean.ling"]["value"] <= 8
-        assert m["moe_expert_load_max_over_mean.ling"]["value"] >= 1.0
-        assert m["decode_step_p50_ms.ling"]["value"] > 0
+        assert 1 <= m["moe_held_experts_touched_mean.trinity"]["value"] <= 8
+        assert m["moe_expert_load_max_over_mean.trinity"]["value"] >= 1.0
+        assert m["decode_step_p50_ms.batch"]["value"] > 0
         # no TPU plane in a CPU trace: the device metrics are left out
         assert not [n for n in m if "roofline" in n or n.startswith("scope")]
     json.dumps(result)
@@ -306,11 +306,10 @@ def meas(tmp_path, monkeypatch):
 def test_every_ling_metric_reads_the_recorded_trace(meas):
     bench = harness.load_benchmark()
     values = {}
-    for m in bench["per_layer"]:
-        if m["name"].endswith(".ling"):
-            spec = harness.load_json("metrics", m["name"] + ".json")
-            values[m["name"]] = harness.reducer(spec["reducer"]).reduce(
-                meas, **spec.get("args", {}))
+    for m in harness.cell_metrics(bench, CELL, "per_layer"):
+        spec = harness.load_json("metrics", m["name"] + ".json")
+        values[m["name"]] = harness.reducer(spec["reducer"]).reduce(
+            meas, **spec.get("args", {}))
     assert all(v is not None for v in values.values()), values
     shares = [n for n in values if n.startswith("scope_")
               or "roofline" in n]
@@ -324,15 +323,20 @@ def test_every_ling_metric_reads_the_recorded_trace(meas):
     assert work["prefill_tokens"] == sum(record_ling3_trace.PROMPTS)
 
 
-def test_ling_metrics_name_this_cell_only():
+def test_the_cell_reports_its_own_readers_and_the_backlog_cells():
     bench = harness.load_benchmark()
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".ling")]
-    assert len(mine) == 20 and len(bench["per_layer"]) <= 128
+    mine = harness.cell_metrics(bench, CELL, "per_layer")
+    # the readers written for this cell (a suffix names the first cell
+    # of a reader), beside those it joined by its name on their lists
+    assert {m["name"] for m in mine} >= {
+        "scope_attn_kda_pct.ling", "scope_kda_recurrence_pct.ling",
+        "scope_attn_mla_pct.ling", "kda_step_roofline.ling",
+        "kda_scan_roofline.ling", "mla_decode_roofline.ling",
+        "kv_latent_positions_max.ling", "state_slots_in_use.ling",
+        "scope_moe_pct.trinity", "moe_experts_prefill_roofline.trinity",
+        "decode_step_p50_ms.batch", "ttft_p50_ms.batch"}
+    assert len(bench["per_layer"]) <= 128
     for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-        spec = harness.load_json("metrics", m["name"] + ".json")
-        assert spec["workloads"] == [CELL]
-        harness.reducer(spec["reducer"])      # the module is there
         if "roofline" in m["name"]:
             assert m["unit"] == "%" and m["better"] == "higher"
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)

@@ -1,7 +1,7 @@
 """The windowed sparse decoder's cell: the train-moe-window kind end to
 end on the CPU at a tiny size (the check against
 ``benchmark/reference_mellum2.py``), ``flops_mellum2.py`` against counts
-made another way, every ``.mellum`` metric's reader on a small trace
+made another way, every reader the cell reports on a small trace
 recorded on the v5e (``tools/record_mellum2_trace.py``), and the
 tolerance tool's verdicts at the tiny size. Times and rates printed here
 mean nothing."""
@@ -23,10 +23,6 @@ TRACE = os.path.join(HERE, "data", "tiny-mellum2.xplane.pb")
 def _load(name):
     with open(os.path.join(HERE, "data", name)) as f:
         return json.load(f)
-
-
-def _mellum_metrics(bench):
-    return [m for m in bench["per_layer"] if m["name"].endswith(".mellum")]
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -53,10 +49,10 @@ def test_mellum2_cell_runs_on_cpu(trace):
         assert set(result["metrics"]) == {"train_tok_s_chip", "setup_s"}
     else:
         m = result["metrics"]
-        assert m["compiles_in_window.mellum"]["value"] == 0
+        assert m["compiles_in_window.train"]["value"] == 0
         assert 0 < m["moe_local_pair_share.mellum"]["value"] < 1
-        assert m["moe_expert_load_max_over_mean.mellum"]["value"] >= 1.0
-        assert m["step_p50_ms.mellum"]["value"] > 0
+        assert m["moe_expert_load_max_over_mean.moe"]["value"] >= 1.0
+        assert m["step_p50_ms.train"]["value"] > 0
         # no TPU plane in a CPU trace: the device metrics are left out
         assert "flash_fwd_window_roofline.mellum" not in m
     json.dumps(result)
@@ -155,26 +151,23 @@ def test_flops_mellum2_against_a_count_from_shapes():
     assert ffn["bytes"] == 2 * (16 * 2304 * 896 + 16384 * (2304 + 896))
 
 
-def test_mellum_metrics_name_this_cell_only():
+def test_the_cell_reports_its_own_readers_and_the_training_cells():
     bench = harness.load_benchmark()
-    ours = _mellum_metrics(bench)
-    # what ISSUE 34 names, and the router's scope beside OLMoE's cell's
-    assert {m["name"].removesuffix(".mellum") for m in ours} >= {
-        "step_p50_ms", "mfu_pct", "peak_hbm_gb", "compiles_in_window",
-        "device_idle_pct", "scope_attn_window_pct", "scope_attn_full_pct",
-        "scope_moe_pct", "scope_moe_dispatch_pct", "scope_moe_router_pct",
-        "scope_head_loss_pct", "scope_optimizer_pct", "scope_unnamed_pct",
-        "flash_fwd_window_roofline", "flash_bwd_window_roofline",
-        "flash_fwd_full_roofline", "flash_bwd_full_roofline",
-        "moe_experts_roofline", "moe_local_pair_share",
-        "moe_expert_load_max_over_mean"}
-    for m in ours:
-        assert m["workloads"] == [CELL] and m["moves"] == "train_tok_s_chip"
-        spec = harness.load_json("metrics", m["name"] + ".json")
-        assert spec["workloads"] == [CELL]
-        assert {k: spec[k] for k in ("unit", "better", "source", "layer")} \
-            == {k: m[k] for k in ("unit", "better", "source", "layer")}
-        harness.reducer(spec["reducer"])      # the module is there
+    ours = harness.cell_metrics(bench, CELL, "per_layer")
+    # what ISSUE 34 names, and the router's scope beside OLMoE's cell's:
+    # a suffix names the cell a reader was first written for
+    assert {m["name"] for m in ours} >= {
+        "step_p50_ms.train", "mfu_pct.mellum", "peak_hbm_gb.train",
+        "compiles_in_window.train", "device_idle_pct.train",
+        "scope_attn_window_pct.mellum", "scope_attn_full_pct.mellum",
+        "scope_moe_pct.moe", "scope_moe_dispatch_pct.moe",
+        "scope_moe_router_pct.moe", "scope_head_loss_pct.train",
+        "scope_optimizer_pct.train", "scope_unnamed_pct.moe",
+        "flash_fwd_window_roofline.mellum",
+        "flash_bwd_window_roofline.mellum",
+        "flash_fwd_full_roofline.mellum", "flash_bwd_full_roofline.mellum",
+        "moe_experts_roofline.mellum", "moe_local_pair_share.mellum",
+        "moe_expert_load_max_over_mean.moe"}
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1
     assert CELL in next(m for m in bench["end_to_end"]
@@ -209,7 +202,7 @@ def meas(tmp_path, monkeypatch):
 def test_every_mellum_metric_reads_the_recorded_trace(meas):
     bench = harness.load_benchmark()
     values = {}
-    for m in _mellum_metrics(bench):
+    for m in harness.cell_metrics(bench, CELL, "per_layer"):
         spec = harness.load_json("metrics", m["name"] + ".json")
         values[m["name"]] = harness.reducer(spec["reducer"]).reduce(
             meas, **spec.get("args", {}))
@@ -245,7 +238,7 @@ def test_a_trace_without_the_scopes_leaves_the_metrics_out(
     cpu = {"trace": None, "spans": [], "counters": {}, "peak": None,
            "model": record_mellum2_trace.MODEL}
     bench = harness.load_benchmark()
-    for m in _mellum_metrics(bench):
+    for m in harness.cell_metrics(bench, CELL, "per_layer"):
         spec = harness.load_json("metrics", m["name"] + ".json")
         if spec["reducer"] in ("flash_roofline_mellum2",
                                "held_experts_roofline"):

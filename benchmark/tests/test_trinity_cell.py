@@ -2,7 +2,7 @@
 end on the CPU at a tiny size (chunked prefill, two kinds of cache, the
 check against ``benchmark/reference_trinity.py``), ``flops_trinity.py``
 against a hand count, the roofline reducer on made-up rows, and the
-``.trinity`` metrics' files. Times and rates printed here mean nothing."""
+metrics the cell reports. Times and rates printed here mean nothing."""
 import json
 import os
 
@@ -45,12 +45,12 @@ def test_trinity_cell_runs_on_cpu(trace):
         assert set(result["metrics"]) == {"serve_tok_s", "setup_s"}
     else:
         m = result["metrics"]
-        assert m["compiles_in_window.trinity"]["value"] == 0
+        assert m["compiles_in_window.batch"]["value"] == 0
         # window 16 + chunk 16 + block 8, and sequences of over 40
         assert m["kv_window_positions_max.trinity"]["value"] == 40
         assert 1 <= m["moe_held_experts_touched_mean.trinity"]["value"] <= 4
         assert m["moe_expert_load_max_over_mean.trinity"]["value"] >= 1.0
-        assert m["decode_step_p50_ms.trinity"]["value"] > 0
+        assert m["decode_step_p50_ms.batch"]["value"] > 0
         # no TPU plane in a CPU trace: the device metrics are left out
         assert "moe_experts_prefill_roofline.trinity" not in m
     json.dumps(result)
@@ -222,19 +222,23 @@ def test_the_roofline_counts_chunk_sized_kernels_only(monkeypatch):
                                           **spec["args"]) is None
 
 
-def test_trinity_metrics_name_this_cell_only():
+def test_the_cell_reports_its_own_readers_and_the_backlog_cells():
     bench = harness.load_benchmark()
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".trinity")]
-    assert len(mine) == 19
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-        spec = harness.load_json("metrics", m["name"] + ".json")
-        assert spec["workloads"] == [CELL]
-        harness.reducer(spec["reducer"])      # the module is there
+    mine = harness.cell_metrics(bench, CELL, "per_layer")
+    # the readers written for this cell (a suffix names the first cell
+    # of a reader), beside those it joined by its name on their lists
+    assert {m["name"] for m in mine} >= {
+        "scope_attn_window_pct.trinity", "scope_attn_full_pct.trinity",
+        "scope_moe_pct.trinity", "scope_moe_shared_pct.trinity",
+        "scope_moe_router_pct.trinity", "scope_unnamed_pct.trinity",
+        "moe_experts_prefill_roofline.trinity",
+        "moe_held_experts_touched_mean.trinity",
+        "kv_window_positions_max.trinity", "peak_hbm_gb.trinity",
+        "decode_step_p50_ms.batch", "ttft_p50_ms.batch"}
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert bench["workloads"][-1] is cell and cell["chips"] == 1
+    assert cell["chips"] == 1
     serve = next(m for m in bench["end_to_end"] if m["name"] == "serve_tok_s")
-    assert serve["workloads"][-1] == CELL
+    assert CELL in serve["workloads"]
 
 
 def test_the_configuration_keeps_every_published_width():

@@ -140,19 +140,39 @@ def test_flops_against_a_hand_count():
 
 
 def test_every_metric_has_its_file_and_agrees_with_benchmark_json():
+    """One entry a reader: a metric file is the reader and nothing else,
+    and ``BENCHMARK.json`` alone lists the cells that report it, so a
+    new cell joins a reader by its name on that list."""
     bench = harness.load_benchmark()
     e2e = {m["name"] for m in bench["end_to_end"]}
     cells = {w["name"] for w in bench["workloads"]}
+    files = {f[:-len(".json")]
+             for f in os.listdir(os.path.join(harness.HERE, "metrics"))}
+    assert files == {m["name"] for m in bench["per_layer"]}
+    readers, modules = {}, set()
     for m in bench["per_layer"]:
         spec = harness.load_json("metrics", m["name"] + ".json")
+        assert set(spec) <= {"name", "layer", "unit", "better", "source",
+                             "moves", "reducer", "args"}, m["name"]
         for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert spec[key] == m[key], (m["name"], key)
-        assert spec.get("workloads") == m.get("workloads")
         assert m["moves"] in e2e
         harness.reducer(spec["reducer"])
+        modules.add(spec["reducer"])
+        reader = json.dumps([spec["reducer"], spec.get("args", {}),
+                             spec["moves"]], sort_keys=True)
+        assert readers.setdefault(reader, m["name"]) == m["name"], \
+            "one reader, two entries: list the cells under the first"
         moved = next(x for x in bench["end_to_end"] if x["name"] == m["moves"])
         assert set(m.get("workloads", cells)) <= set(
             moved.get("workloads", cells)), m["name"]
+    # a reducer that no metric file names goes with its last metric
+    # (modules with a leading underscore are what reducers share)
+    assert modules == {
+        f[:-len(".py")]
+        for f in os.listdir(os.path.join(harness.HERE, "reducers"))
+        if f.endswith(".py") and not f.startswith("_")
+        and not f.endswith("_pb2.py")}
     for w in bench["workloads"]:
         cell, config, traffic = harness.find_cell(w["name"], bench)
         harness.generator(traffic["kind"])
