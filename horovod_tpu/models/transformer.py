@@ -49,13 +49,23 @@ class Rotary:
     that frequency, those that turn fewer than ``beta_slow`` times take
     it divided by ``factor``, a linear ramp between the two; cos and sin
     times ``attention_factor``, so on q and on k alike. The frequencies
-    are fixed, whatever the row's length."""
+    are fixed, whatever the row's length. ``mscale_all_dim`` is
+    DeepSeek-V3's: the softmax scale of a layer rotated so is times
+    ``(0.1 mscale_all_dim ln(factor) + 1)^2`` (``softmax_mscale``; the
+    mla layers read it, 0 leaves the scale alone)."""
     theta: float
     factor: Optional[float] = None
     original_max_seq: int = 0
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        if not self.mscale_all_dim or not self.factor or self.factor <= 1:
+            return 1.0
+        return (0.1 * self.mscale_all_dim * math.log(self.factor) + 1.0) ** 2
 
     def frequencies(self, d: int) -> np.ndarray:
         """The ``d / 2`` pairs' angles a position, float32."""
@@ -195,15 +205,21 @@ class TransformerConfig:
     # delta-rule recurrence over a state [n_heads, head_dim, head_dim] a
     # sequence whose decay a channel is exp(kda_decay_floor *
     # sigmoid(.)), a gated per-head RMSNorm on its output;
-    # "mla", DeepSeek-V2's latent attention without a q rank: keys and
-    # values are expanded from a cached latent of mla_kv_rank values a
-    # position beside mla_rope_dim rotated ones that every head shares
-    # (q and k are head_dim + mla_rope_dim wide, v head_dim), rotated at
-    # rope_theta in interleaved pairs, with one sigmoid gate a head.
+    # "mla", DeepSeek-V2's latent attention: keys and values are
+    # expanded from a cached latent of mla_kv_rank values a position
+    # beside mla_rope_dim rotated ones that every head shares (q and k
+    # are head_dim + mla_rope_dim wide, v head_dim), rotated in
+    # interleaved pairs as layer_rotary says of "mla" (plainly at
+    # rope_theta where it says nothing). mla_q_rank: q comes up from a
+    # normed latent of that many values (DeepSeek-V3's q_lora_rank; 0:
+    # straight out of one matrix). mla_head_gate: one sigmoid gate a
+    # head on the attention's output (Ling's; DeepSeek-V3 has none).
     kda_conv: int = 4
     kda_decay_floor: float = -5.0
     mla_kv_rank: int = 0
     mla_rope_dim: int = 0
+    mla_q_rank: int = 0
+    mla_head_gate: bool = True
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -230,10 +246,10 @@ class TransformerConfig:
                     "and no attn_gate, sandwich_norm or qk_norm")
         if self.layer_rotary is not None:
             by_kind = dict(self.layer_rotary)
-            if set(by_kind) - {"sliding", "full"}:
+            if set(by_kind) - {"sliding", "full", "mla"}:
                 raise ValueError(
-                    "layer_rotary is by kind of layer, 'sliding' | 'full', "
-                    f"got {sorted(by_kind)}")
+                    "layer_rotary is by kind of layer, 'sliding' | 'full' "
+                    f"| 'mla', got {sorted(by_kind)}")
             object.__setattr__(self, "layer_rotary", tuple(sorted(
                 (kind, Rotary(**how) if isinstance(how, dict) else how)
                 for kind, how in by_kind.items())))
@@ -290,8 +306,12 @@ class TransformerConfig:
         """How layer ``layer`` rotates q and k, None for not at all:
         what ``layer_rotary`` says of its kind, else plainly at
         ``rope_theta``, but for the full layers of a stack with
-        ``layer_types``, which then take no rotary embedding."""
-        kind = "sliding" if self.sliding(layer) else "full"
+        ``layer_types``, which then take no rotary embedding. (An mla
+        layer rotates its ``mla_rope_dim`` values so; a kda layer reads
+        no position.)"""
+        kind = self.kind_of(layer)
+        if kind == "kda":
+            kind = "full"
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
             return by_kind[kind]
@@ -359,7 +379,13 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
     if kind == "mla":
         del layers["wk"], layers["wv"]
         layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
-                      w_ukv=P(None, None, "tp"), wg=mat)
+                      w_ukv=P(None, None, "tp"))
+        if cfg.mla_q_rank:
+            del layers["wq"]
+            layers.update(w_dq=P(None, "fsdp", None), dq_norm=vec,
+                          w_uq=P(None, None, "tp"))
+        if cfg.mla_head_gate:
+            layers["wg"] = mat
     if cfg.qk_norm:
         layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
         layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
@@ -458,17 +484,23 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             "mlp_norm": jnp.ones((L, D), dt),
         }
     elif kind == "mla":
-        R, C = cfg.mla_rope_dim, cfg.mla_kv_rank
+        R, C, Q = cfg.mla_rope_dim, cfg.mla_kv_rank, cfg.mla_q_rank
+        # a head's q: Dh without position, then R rotated; with a q
+        # rank, up from a normed latent of Q values
+        q = ({"w_dq": dense(next(k), (L, D, Q), D),
+              "dq_norm": jnp.ones((L, Q), dt),
+              "w_uq": dense(next(k), (L, Q, H * (Dh + R)), Q)} if Q
+             else {"wq": dense(next(k), (L, D, H * (Dh + R)), D)})
         layers = {
             "attn_norm": jnp.ones((L, D), dt),
-            # a head's q: Dh without position, then R rotated
-            "wq": dense(next(k), (L, D, H * (Dh + R)), D),
+            **q,
             # the latent, then the R rotated values every head shares
             "w_dkv": dense(next(k), (L, D, C + R), D),
             "kv_norm": jnp.ones((L, C), dt),
             # a head's key without position, then its value
             "w_ukv": dense(next(k), (L, C, H * 2 * Dh), C),
-            "wg": dense(next(k), (L, D, H), D),
+            **({"wg": dense(next(k), (L, D, H), D)}
+               if cfg.mla_head_gate else {}),
             "wo": dense(next(k), (L, H * Dh, D), H * Dh),
             "mlp_norm": jnp.ones((L, D), dt),
         }
@@ -863,21 +895,43 @@ def kda_residual(cfg: TransformerConfig, lp, x, h, o):
     return x + (o @ lp["wo"]).astype(cfg.dtype)
 
 
+def mla_rotary(cfg: TransformerConfig) -> Rotary:
+    """How the mla layers rotate (``rotary_of`` of the first of them:
+    ``layer_rotary`` is by kind)."""
+    return cfg.rotary_of(cfg.layer_types.index("mla"))
+
+
+def mla_scale(cfg: TransformerConfig) -> float:
+    """The mla layers' softmax scale: ``(Dh + R)^-1/2``, times the
+    rotary's ``softmax_mscale`` (YaRN's ``m^2``, 1 without)."""
+    return ((cfg.head_dim + cfg.mla_rope_dim) ** -0.5
+            * mla_rotary(cfg).softmax_mscale)
+
+
 def mla_inputs(cfg: TransformerConfig, lp, x, pos):
     """An mla layer up to its attention: the normed input ``h``, q
     without position [B, T, H, Dh] and rotated [B, T, H, R], and what
     the cache holds of a position, ``[RMSNorm(c) | rotated r]``
-    [B, T, C + R]. Rotation at ``rope_theta`` over interleaved pairs."""
+    [B, T, C + R]. Rotation as :func:`mla_rotary` says, over
+    interleaved pairs; q straight out of ``wq`` or, with a q rank,
+    ``RMSNorm(h W_dq) W_uq``."""
     B, T = x.shape[:2]
     H, Dh, R, C = cfg.n_heads, cfg.head_dim, cfg.mla_rope_dim, cfg.mla_kv_rank
-    rotary = Rotary(cfg.rope_theta)
+    rotary = mla_rotary(cfg)
     h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, T, H, Dh + R)
-    cr = h @ lp["w_dkv"]
-    c = _rmsnorm(cr[..., :C], lp["kv_norm"], cfg.norm_eps)
-    r = _rope(cr[..., None, C:], pos, rotary)[:, :, 0]
-    return (h, q[..., :Dh], _rope(q[..., Dh:], pos, rotary),
-            jnp.concatenate([c, r], -1))
+    with jax.named_scope("mla_q"):
+        if cfg.mla_q_rank:
+            q = _rmsnorm(h @ lp["w_dq"], lp["dq_norm"],
+                         cfg.norm_eps) @ lp["w_uq"]
+        else:
+            q = h @ lp["wq"]
+        q = q.reshape(B, T, H, Dh + R)
+        qr = _rope(q[..., Dh:], pos, rotary)
+    with jax.named_scope("mla_kv_down"):
+        cr = h @ lp["w_dkv"]
+        c = _rmsnorm(cr[..., :C], lp["kv_norm"], cfg.norm_eps)
+        r = _rope(cr[..., None, C:], pos, rotary)[:, :, 0]
+    return h, q[..., :Dh], qr, jnp.concatenate([c, r], -1)
 
 
 def mla_up(cfg: TransformerConfig, lp):
@@ -888,10 +942,12 @@ def mla_up(cfg: TransformerConfig, lp):
 
 def mla_residual(cfg: TransformerConfig, lp, x, h, o):
     """An mla layer after its attention ``o`` [B, T, H, Dh]: one
-    sigmoid gate a head, the output projection and the residual."""
+    sigmoid gate a head where the configuration has it, the output
+    projection and the residual."""
     B, T = x.shape[:2]
-    gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
-    o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
+    if cfg.mla_head_gate:
+        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
     return x + (o.reshape(B, T, -1) @ lp["wo"]).astype(cfg.dtype)
 
 

@@ -685,7 +685,7 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
     B, C, H, Dh = qn.shape
     rank = cfg.mla_kv_rank
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
-    scale = (Dh + cfg.mla_rope_dim) ** -0.5
+    scale = tf_lib.mla_scale(cfg)
     if absorbed:
         qn = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
     width = rank if absorbed else Dh

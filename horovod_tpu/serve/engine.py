@@ -239,6 +239,8 @@ class _Seq:
     slot: int = 0                # its rings and recurrent states (a
     #                              configuration with a state by kind
     #                              of layer; kv_cache.KVCache)
+    mapped: int = 0              # positions mapped from the prefix
+    #                              cache that no prefill span has said
 
     @property
     def last_token(self) -> int:
@@ -443,11 +445,20 @@ class ServeEngine:
         # name.
         self._slot_states = model_cfg.mixed
         if self._slot_states:
+            # A prefix is shared as pages mapped into another block
+            # table: what every layer keeps of a position then has to be
+            # a page. A window layer's ring and a kda layer's recurrent
+            # state lie by batch slot, so those kinds refuse it; full
+            # and mla layers alone (K/V and latent pages) share.
+            by_slot = sorted({"sliding", "kda"}
+                             & set(model_cfg.layer_types or ()))
             refused = [what for what, there in (
-                ("prefix_caching (a page behind a window, and a kda "
-                 "layer's state after a prefix, cannot be mapped into "
-                 "another sequence: engine._admit, kv_cache.BlockAllocator)",
-                 cfg.prefix_caching),
+                (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
+                 "a ring or a recurrent state a batch slot: a page behind "
+                 "a window, and a kda layer's state after a prefix, cannot "
+                 "be mapped into another sequence: engine._admit, "
+                 "kv_cache.BlockAllocator)",
+                 cfg.prefix_caching and by_slot),
                 ("speculative decoding (draft/spec_k: speculative.py "
                  "rolls back pages, not a ring or a recurrent state)",
                  cfg.draft is not None)) if there]
@@ -856,7 +867,7 @@ class ServeEngine:
             self.metrics.record_prefix_lookup(n_hit, plen - n_hit)
             self._prefilling.append(_Seq(
                 rid=req.rid, prompt=req.prompt, max_new=req.max_new,
-                blocks=blocks, table=table, n_cached=n_hit,
+                blocks=blocks, table=table, n_cached=n_hit, mapped=n_hit,
                 generated=[], submitted_at=req.submitted_at,
                 chain=req.chain, registered=len(matched),
                 deadline_class=req.deadline_class,
@@ -929,6 +940,7 @@ class ServeEngine:
             seq.registered += 1
             extended += self.cfg.block_size
         if extended:
+            seq.mapped += extended
             self.metrics.record_prefix_extend(extended)
 
     def _run_prefill_chunk(self, seq: _Seq, chunk: int) -> float:
@@ -940,6 +952,9 @@ class ServeEngine:
         toks[:chunk] = seq.prompt[offset:offset + chunk]
         m = self.metrics
         extra = {"trace": seq.trace} if seq.trace else {}
+        if self.cfg.prefix_caching:
+            # positions this call attends that it did not compute
+            extra["mapped"], seq.mapped = seq.mapped, 0
         with m.phase("serve:prefill", device=True, n_tokens=chunk,
                      offset=offset, **extra) as ph:
             with ph.dispatch():

@@ -117,6 +117,10 @@ class BlockAllocator:
         self._hash_of_block: Dict[int, bytes] = {}
         self._block_of_hash: Dict[bytes, int] = {}
         self._high_water = 0
+        # Blocks with more than one reference (a prefix mapped into
+        # several sequences at once), now and at most.
+        self._shared = 0
+        self._shared_high_water = 0
         # Prefix-cache observability (block granularity; the engine
         # layers token-granularity hit rate on top in ServeMetrics).
         self.prefix_hits = 0
@@ -142,6 +146,15 @@ class BlockAllocator:
     def high_water(self) -> int:
         """Peak concurrent blocks in use (capacity-planning stat)."""
         return self._high_water
+
+    @property
+    def n_shared(self) -> int:
+        """Blocks that more than one sequence holds at once."""
+        return self._shared
+
+    @property
+    def shared_high_water(self) -> int:
+        return self._shared_high_water
 
     def refcount(self, block: int) -> int:
         return self._refs.get(block, 0)
@@ -199,6 +212,10 @@ class BlockAllocator:
             self._high_water = max(self._high_water, len(self._refs))
         else:
             self._refs[b] += 1
+            if self._refs[b] == 2:
+                self._shared += 1
+                self._shared_high_water = max(self._shared_high_water,
+                                              self._shared)
         self.prefix_hits += 1
         return b
 
@@ -236,6 +253,8 @@ class BlockAllocator:
             "live/cached/free do not partition the pool"
         assert all(r > 0 for r in self._refs.values()), \
             "zero/negative refcount held as live"
+        assert self._shared == sum(r > 1 for r in self._refs.values()), \
+            "shared-block count out of sync"
         assert len(self._block_of_hash) == len(self._hash_of_block), \
             "content index out of sync"
         for b, h in self._hash_of_block.items():
@@ -259,6 +278,7 @@ class BlockAllocator:
         for b in blocks:
             self._refs[b] -= 1
             if self._refs[b]:
+                self._shared -= self._refs[b] == 1
                 continue
             del self._refs[b]
             h = self._hash_of_block.get(b)

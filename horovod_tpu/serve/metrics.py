@@ -792,6 +792,11 @@ class ServeMetrics:
                 "kv_blocks_in_use": a.n_used,
                 "kv_blocks_cached": a.n_cached,
                 "kv_blocks_high_water": a.high_water,
+                # pages more than one sequence holds at once (a prefix
+                # mapped, not copied), now and at most: engine-lifetime
+                # as the high water above
+                "kv_pages_shared": a.n_shared,
+                "kv_pages_shared_max": a.shared_high_water,
                 "prefix_block_hits": a.prefix_hits - hits0,
                 "prefix_block_misses": a.prefix_misses - misses0,
                 "prefix_block_evictions": a.evictions - evict0,
