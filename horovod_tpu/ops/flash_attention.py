@@ -220,6 +220,198 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     return out[:, :t], lse[:, 0, :t]
 
 
+def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, q_ref, k_ref,
+                     v_ref, qp_ref, kp_ref, *rest, scale: float,
+                     window: Optional[int], heads: int, carried: bool):
+    """One (batch*head, q-block, kv-block) grid step of the forward over
+    keys that carry their positions (:func:`flash_attention_keys`): the
+    online softmax of :func:`_fwd_kernel` with the mask read from the
+    two position tiles (``qp`` [bq, 1], ``kp`` [1, bk]) instead of the
+    grid's indices, the operands in their own dtype and the value as
+    wide as it is. The four prefetched arrays hold the lowest and the
+    highest position of every q and kv tile, a position group after
+    another: a tile pair with no visible (query, key) computes
+    nothing. ``carried``: two more inputs, the ``(out, lse)`` of the
+    same queries over earlier keys, from which the softmax runs on
+    (``acc = out``, ``m = lse``, ``l = 1`` is that state)."""
+    if carried:
+        o_in_ref, lse_in_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
+    else:
+        o_ref, lse_ref, acc, m_scr, l_scr = rest
+    b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    g = b // heads
+    q_tile = g * pl.num_programs(1) + qi
+    k_tile = g * pl.num_programs(2) + ki
+
+    @pl.when(ki == 0)
+    def _init():
+        if carried:
+            acc[:] = o_in_ref[0]
+            m_scr[:] = lse_in_ref[0, 0][:, None]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            acc[:] = jnp.zeros_like(acc)
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+
+    visible = (k_lo_ref[k_tile] <= q_hi_ref[q_tile]) & (k_hi_ref[k_tile] >= 0)
+    if window is not None:
+        visible &= k_hi_ref[k_tile] > q_lo_ref[q_tile] - window
+
+    @pl.when(visible)
+    def _body():
+        v = v_ref[0]
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        qp, kp = qp_ref[0], kp_ref[0]                # [bq, 1], [1, bk]
+        seen = (kp >= 0) & (kp <= qp)
+        if window is not None:
+            seen &= kp > qp - window
+        s = jnp.where(seen, s, NEG_INF)
+
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = alpha * l_scr[:] + p.sum(axis=1, keepdims=True)
+        acc[:] = acc[:] * alpha + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[:] = m_new
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        # A row that saw no key: l is 0 where every tile of it was
+        # skipped and the count of the masked keys where one was not
+        # (exp(NEG_INF - NEG_INF)); zeros either way, and NEG_INF + log l
+        # is NEG_INF in float32.
+        l, m = l_scr[:], m_scr[:]
+        safe_l = jnp.where(l > 0, l, 1.0)
+        o_ref[0] = jnp.where(m > NEG_INF, acc[:] / safe_l,
+                             0.0).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m + jnp.log(safe_l))[:, 0]
+
+
+def _keys_blocks(c: int, k: int):
+    """(q tile, kv tile) of :func:`flash_attention_keys` from the
+    lengths: a chunk's queries as one tile up to 1024, kv tiles of 1024.
+    On the v5e (2026-09-30, ``tools/prefill_attn_sweep.py --latent``:
+    ``[64, C, 192]`` bf16 queries of a chunk that ends at key 8192,
+    values 128 wide, key blocks of 1024 a call, ms a layer with the
+    expansion of the latents): C = 1024 at 1024x1024 **5.02**, 512x1024
+    5.15, 256x1024 5.53, 1024x512 6.60, 512x512 6.74; C = 256 at
+    256x1024 2.03, at x512 2.42-2.45. Fewer and larger steps win, as
+    in :func:`_default_blocks`; a 1024x1024 float32 score tile beside
+    192-wide operands fits the 16 MiB of scoped VMEM. The kernel alone
+    takes 0.55 ms a call at ``[64, 1024, 192]`` over 1024 keys and 0.31
+    at 32 heads: about 75 us a call and 7.5 us a head and tile, 45 %
+    of the matrix unit's peak on a tile, where :func:`_fwd_kernel`
+    stands; leaving the mask out of the tiles that need none moved it
+    by 1 % and was not kept."""
+    return min(1024, _round_up(c, 128)), min(1024, _round_up(k, 128))
+
+
+def flash_attention_keys(q, k, v, q_pos, k_pos, *, scale: float,
+                         window: Optional[int] = None,
+                         block_q: Optional[int] = None,
+                         block_k: Optional[int] = None,
+                         carry=None, interpret: Optional[bool] = None):
+    """The flash forward over keys that carry their positions: ``q``
+    ``[BH, C, Dk]`` at positions ``q_pos`` ``[G, C]`` over ``k``
+    ``[BHkv, K, Dk]`` and ``v`` ``[BHkv, K, Dv]`` at ``k_pos``
+    ``[G, K]`` (int32, traced; ``G`` divides ``BH``, head-major groups
+    as ``[B, H]`` flattens; ``BHkv`` divides ``BH``, GQA by index map).
+    A key is seen by a query where ``0 <= k_pos <= q_pos`` and, with a
+    ``window``, ``k_pos > q_pos - window``: a negative position is a
+    key that is not there. ``C != K``, ``Dv != Dk`` and positions in any
+    order are all fine; nothing is assumed of them but what the mask
+    says. Returns ``(out [BH, C, Dv], lse [BH, C])`` in float32, a row
+    that saw no key as zeros at ``lse = NEG_INF``. ``carry``: the
+    ``(out, lse)`` of the same queries over OTHER keys (an earlier
+    call's): the running softmax starts from it (``acc = out``, ``m =
+    lse``, ``l = 1`` is that state) and the result is the attention
+    over both sets of keys, the carried pair updated where it lies.
+    That is how a caller attends a key block a call with no pass over
+    the result between the calls (merging two results by their
+    logsumexp outside the kernel took 5.23 ms a layer where carrying
+    takes 4.98, ``_keys_blocks``' sweep).
+
+    Scores, softmax statistics and the accumulator are float32 tiles in
+    VMEM; the two dots take the operands in their own dtype and ``p``
+    rounded to the value's. Tile pairs with no visible pair are skipped
+    by the tiles' lowest and highest positions, which the wrapper
+    computes and the kernel reads from SMEM. Forward only (the serve
+    programs' kernel; ``hvd_flash_keys_fwd`` in a device trace)."""
+    bh, c, dk = q.shape
+    bkv, n_keys, dv = v.shape
+    groups = q_pos.shape[0]
+    if bh % groups or bh % bkv or k.shape != (bkv, n_keys, dk) or (
+            q_pos.shape, k_pos.shape) != ((groups, c), (groups, n_keys)):
+        raise ValueError(
+            f"flash_attention_keys: q {q.shape}, k {k.shape}, v {v.shape}, "
+            f"q_pos {q_pos.shape}, k_pos {k_pos.shape}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    tile_q, tile_k = _keys_blocks(c, n_keys)
+    bq = min(tile_q if block_q is None else block_q, _round_up(c, 128))
+    bk = min(tile_k if block_k is None else block_k, _round_up(n_keys, 128))
+    cp, kp = _round_up(c, bq), _round_up(n_keys, bk)
+    q = jnp.pad(q, ((0, 0), (0, cp - c), (0, 0)))
+    k = jnp.pad(k, ((0, 0), (0, kp - n_keys), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, kp - n_keys), (0, 0)))
+    # padded queries see nothing, padded keys are not there
+    q_pos = jnp.pad(q_pos.astype(jnp.int32), ((0, 0), (0, cp - c)),
+                    constant_values=-1)
+    k_pos = jnp.pad(k_pos.astype(jnp.int32), ((0, 0), (0, kp - n_keys)),
+                    constant_values=-1)
+    q_tiles = q_pos.reshape(groups, cp // bq, bq)
+    k_tiles = k_pos.reshape(groups, kp // bk, bk)
+    bounds = (q_tiles.max(-1).reshape(-1), q_tiles.min(-1).reshape(-1),
+              k_tiles.min(-1).reshape(-1), k_tiles.max(-1).reshape(-1))
+    heads, q_per_kv = bh // groups, bh // bkv
+    out_spec = pl.BlockSpec((1, bq, dv), lambda b, i, j, *_: (b, i, 0))
+    lse_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j, *_: (b, 0, i))
+    carried = [] if carry is None else [
+        jnp.pad(carry[0], ((0, 0), (0, cp - c), (0, 0))),
+        jnp.pad(carry[1], ((0, 0), (0, cp - c)),
+                constant_values=NEG_INF)[:, None, :]]
+
+    out, lse = pl.pallas_call(
+        functools.partial(_keys_fwd_kernel, scale=float(scale),
+                          window=window, heads=heads,
+                          carried=carry is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, cp // bq, kp // bk),
+            in_specs=[
+                pl.BlockSpec((1, bq, dk), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, bk, dk),
+                             lambda b, i, j, *_: (b // q_per_kv, j, 0)),
+                pl.BlockSpec((1, bk, dv),
+                             lambda b, i, j, *_: (b // q_per_kv, j, 0)),
+                pl.BlockSpec((1, bq, 1),
+                             lambda b, i, j, *_: (b // heads, i, 0)),
+                pl.BlockSpec((1, 1, bk),
+                             lambda b, i, j, *_: (b // heads, 0, j)),
+            ] + ([out_spec, lse_spec] if carried else []),
+            out_specs=[out_spec, lse_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bq, dv), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, cp, dv), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, cp), jnp.float32),
+        ],
+        # the carried pair is updated where it lies (inputs 9 and 10
+        # behind the four prefetched arrays and the five operands)
+        input_output_aliases={9: 0, 10: 1} if carried else {},
+        interpret=interpret,
+        name="hvd_flash_keys_fwd",
+    )(*bounds, q, k, v, q_pos[:, :, None], k_pos[:, None, :], *carried)
+    return out[:, :c], lse[:, 0, :c]
+
+
 def _bwd_blocks(t: int, d: int, itemsize: int):
     """The backward's (block, sub) from the shapes: square blocks of
     ``block`` query rows by ``block`` kv rows a grid step, the diagonal

@@ -74,6 +74,19 @@ the float32 scores a tile at a time in VMEM, so a long cold prompt
 holds no ``[H, T, T]`` tensor and no repeated K or V in HBM either; up
 to 512, where that tensor is small and XLA's fusions of it are the
 faster, in the dense form.
+
+The programs of a configuration with layers of several kinds
+(:func:`mixed_programs`) attend through :func:`_attend_keys` (window
+and full layers: keys that carry their positions, in XLA),
+:func:`kda_scan` / :func:`kda_step` (a recurrent state) and
+:func:`_mla_attend` (latent attention), which has two forms: a decode
+step's queries attend ABSORBED, in XLA; a chunk's (``prefill`` over
+itself, ``prefill_resume`` over the pages, at every bucket width)
+EXPANDED, a key block at a time through the Pallas flash forward over
+keys that carry their positions
+(``ops/flash_attention.py::flash_attention_keys``, the kernel
+``hvd_flash_keys_fwd``), so that a chunk's scores are tiles in VMEM
+too. Nothing chooses between the two but which program calls.
 """
 
 from __future__ import annotations
@@ -89,7 +102,8 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.flash_attention import (flash_attention,
+                                             flash_attention_keys)
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
 
@@ -549,6 +563,22 @@ def ring_positions(frontier, ring: int):
 _KDA_BLOCK, _KDA_SUB = 64, 16
 #: Key positions :func:`_mla_attend` gathers and attends at a time.
 _MLA_KEY_BLOCK = 1024
+#: ... and how many such blocks a CHUNK's expanded form gathers, expands
+#: and hands the kernel at a time (a call of the kernel costs about
+#: 75 us beside 7.5 us a head and 1024 x 1024 tile). On the v5e
+#: (2026-09-30, ``tools/prefill_attn_sweep.py --latent``: bf16 queries
+#: ``[1, C, H, 128 + 64]`` of a chunk that ends at key 8192 over latents
+#: of 512 + 64, ms a layer, the einsum form that wrote float32
+#: ``[H, C, 1024]`` scores / this form at 1, **2** and 4 blocks a call):
+#: 64 heads C = 1024 17.29 / 4.91, **4.49**, 4.70, C = 256 3.05 / 2.22,
+#: **2.18**, 2.47; 32 heads C = 1024 8.62 / 2.47, **2.20**, 2.21, C = 256
+#: 0.98 / 1.14, **1.06**, 1.12. At one block a call and 64 heads: C = 512
+#: 8.86 / 3.32, 768 13.07 / 4.31, and over 17 408 keys C = 1024 37.09 /
+#: 10.26, C = 256 6.25 / 4.51; at 32 heads C = 512 2.58 / 1.57, 768 3.69 /
+#: 2.04. The one shape at which the einsum form was ahead, 256 queries
+#: at 32 heads (33 MB of scores a block), is 0.08 ms a layer of a stack
+#: with one such layer in seven: no second form is kept for it.
+_MLA_CHUNK_BLOCKS = 2
 _EXACT = lax.Precision.HIGHEST
 
 
@@ -669,51 +699,79 @@ def kda_step(q, k, v, g, beta, state):
 def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
     """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
     and ``qr`` [B, C, H, R] (rotated) at positions ``pos`` [B, C] over
-    ``n_blocks`` (traced) blocks of cached latents, a block of keys at a
-    time with a running softmax, so that the scores of one block
-    ``[B, H, C, block]`` are all that exists of them. ``keys_of(j) ->
-    (latent [B, K, C + R], key_pos [K])`` gives block j. Float32
-    scores, softmax and accumulators. Returns [B, C, H, Dh].
+    ``n_blocks`` (traced, at least 1) blocks of cached latents, a block
+    of keys at a time, so that no more than one block of them is ever
+    gathered or expanded. ``keys_of(j) -> (latent [B, K, C + R],
+    key_pos [K])`` gives block j; a key is seen where ``key_pos <=
+    pos``. Float32 scores, softmax and accumulators over operands in the
+    latents' dtype, ``p`` rounded to it for the value sum. Returns
+    [B, C, H, Dh]. One function in two forms, chosen by the caller:
 
     **Expanded** (a chunk's queries: many a sequence): a block's
-    latents are expanded to every head's key and value, ``c W_uk`` and
-    ``c W_uv``, and attended as keys and values are. **Absorbed** (a
-    decode step's: one a sequence): ``q W_uk^T`` is scored against the
-    latent itself and the latent is summed, then expanded once
-    (``(sum p c) W_uv``): the same function, with no ``[K, H, Dh]``
-    key or value a position."""
+    latents are expanded to every head's key ``[c W_uk | r]`` and value
+    ``c W_uv`` and attended by the Pallas flash forward over keys that
+    carry their positions (``ops/flash_attention.py::
+    flash_attention_keys``, ``hvd_flash_keys_fwd`` in a device trace):
+    one contraction of ``Dh + R`` a score, scores, mask and running
+    softmax a tile at a time in VMEM, so that no ``[H, C, K]`` tensor
+    reaches HBM at any chunk width, and the running
+    softmax carried from block to block through the kernel. Every
+    chunk width goes this way (``_MLA_CHUNK_BLOCKS`` has the chip's
+    times beside the einsum form's that it replaced).
+    **Absorbed** (a decode step's: one a sequence): ``q W_uk^T`` is
+    scored against the latent itself and the latent is summed, then
+    expanded once (``(sum p c) W_uv``), in XLA with a running softmax
+    over the blocks: the same function, with no ``[K, H, Dh]`` key or
+    value a position, and the tests' reference for the expanded form.
+
+    A query that sees no key (a position below every key's: none a
+    program sends) reads zeros expanded and a mean of the keys
+    absorbed."""
     B, C, H, Dh = qn.shape
-    rank = cfg.mla_kv_rank
+    rank, R = cfg.mla_kv_rank, cfg.mla_rope_dim
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
     scale = tf_lib.mla_scale(cfg)
-    if absorbed:
-        qn = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
-    width = rank if absorbed else Dh
+    if not absorbed:
+        q = jnp.moveaxis(jnp.concatenate([qn, qr], -1), 2, 1).reshape(
+            B * H, C, Dh + R)
+
+        def attend(j, seen):
+            latent, key_pos = keys_of(j)
+            K = latent.shape[1]
+            with jax.named_scope("mla_expand"):
+                c, r = latent[..., :rank], latent[..., rank:rank + R]
+                keys = jnp.concatenate(
+                    [jnp.einsum("bkc,chd->bhkd", c, w_uk),
+                     jnp.broadcast_to(r[:, None], (B, H, K, R))], -1)
+                vals = jnp.einsum("bkc,chd->bhkd", c, w_uv)
+            return flash_attention_keys(
+                q, keys.reshape(B * H, K, Dh + R), vals.reshape(B * H, K, Dh),
+                pos, jnp.broadcast_to(key_pos[None], (B, K)), scale=scale,
+                carry=seen)
+
+        o, _ = lax.fori_loop(
+            0, n_blocks, attend,
+            (jnp.zeros((B * H, C, Dh), jnp.float32),
+             jnp.full((B * H, C), _NEG_BIG, jnp.float32)))
+        return jnp.moveaxis(o.reshape(B, H, C, Dh), 1, 2).astype(qn.dtype)
+
+    qn = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
 
     def block(j, carry):
         m, l, acc = carry
         latent, key_pos = keys_of(j)
-        c = latent[..., :rank]
-        r = latent[..., rank:rank + cfg.mla_rope_dim]
-        if absorbed:
-            vals, summed = c, "bhqk,bkd->bhqd"           # every head's
-            s = jnp.einsum("bqhc,bkc->bhqk", qn, c,
-                           preferred_element_type=jnp.float32)
-        else:
-            vals, summed = (jnp.einsum("bkc,chd->bkhd", c, w_uv),
-                            "bhqk,bkhd->bhqd")
-            s = jnp.einsum("bqhd,bkhd->bhqk", qn,
-                           jnp.einsum("bkc,chd->bkhd", c, w_uk),
-                           preferred_element_type=jnp.float32)
-        s = (s + jnp.einsum("bqhr,bkr->bhqk", qr, r,
-                            preferred_element_type=jnp.float32)) * scale
+        c, r = latent[..., :rank], latent[..., rank:rank + R]
+        s = (jnp.einsum("bqhc,bkc->bhqk", qn, c,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhr,bkr->bhqk", qr, r,
+                          preferred_element_type=jnp.float32)) * scale
         seen = key_pos[None, None, :] <= pos[:, :, None]     # [B, C, K]
         s = jnp.where(seen[:, None], s, _NEG_BIG)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[..., None])
         fade = jnp.exp(m - m_new)
         acc = acc * fade[..., None] + jnp.einsum(
-            summed, p.astype(vals.dtype), vals,
+            "bhqk,bkd->bhqd", p.astype(c.dtype), c,       # every head's
             preferred_element_type=jnp.float32)
         return m_new, l * fade + p.sum(-1), acc
 
@@ -721,11 +779,9 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
         0, n_blocks, block,
         (jnp.full((B, H, C), _NEG_BIG, jnp.float32),
          jnp.zeros((B, H, C), jnp.float32),
-         jnp.zeros((B, H, C, width), jnp.float32)))
+         jnp.zeros((B, H, C, rank), jnp.float32)))
     o = jnp.moveaxis(acc / l[..., None], 1, 2).astype(qn.dtype)
-    if absorbed:
-        o = jnp.einsum("bqhc,chd->bqhd", o, w_uv)
-    return o
+    return jnp.einsum("bqhc,chd->bqhd", o, w_uv)
 
 
 def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
@@ -767,7 +823,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     latent = latent_row(cfg) if "mla" in place else 0
     # the mla layers' key blocks: whole pages, the table padded to them
     key_block = min(_MLA_KEY_BLOCK, S) // block_size * block_size
-    key_blocks = -(-S // key_block)
+    chunk_key_block = min(_MLA_CHUNK_BLOCKS * _MLA_KEY_BLOCK,
+                          S) // block_size * block_size
 
     def embed(params, tokens):
         with jax.named_scope("embed"):
@@ -906,17 +963,20 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                         cfg, lp, qn, qr, lambda j: (new, call.pos[0]), 1,
                         call.pos, absorbed=False)
                 else:
-                    o = _mla_attend(
-                        cfg, lp, qn, qr, mla_pages(kc[n], c, call.table[None]),
-                        jnp.minimum(call.pos[0, -1] // key_block + 1,
-                                    key_blocks), call.pos, absorbed=False)
+                    keys_of, blocks_to = mla_pages(
+                        kc[n], c, call.table[None], chunk_key_block)
+                    o = _mla_attend(cfg, lp, qn, qr, keys_of,
+                                    blocks_to(call.pos[0, -1]), call.pos,
+                                    absorbed=False)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
-    def mla_pages(pool, c, tables):
+    def mla_pages(pool, c, tables, key_block):
         """``keys_of`` of :func:`_mla_attend` over the pages behind
         ``tables`` [B, W]: block j is the ``key_block`` positions from
-        ``j * key_block``, gathered when it is attended."""
-        per = key_block // block_size
+        ``j * key_block``, gathered when it is attended; and
+        ``blocks_to(last)``, how many blocks hold the positions up to
+        ``last``."""
+        per, key_blocks = key_block // block_size, -(-S // key_block)
         tables = jnp.pad(tables, ((0, 0), (0, key_blocks * per
                                            - tables.shape[1])))
 
@@ -927,7 +987,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                              latent),
                         j * key_block + jnp.arange(key_block,
                                                    dtype=jnp.int32))
-        return keys_of
+        return keys_of, lambda last: jnp.minimum(last // key_block + 1,
+                                                 key_blocks)
 
     # -- a decode step of the batch (one position a row) -------------
 
@@ -1014,11 +1075,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                          (c, call.blk, call.positions % block_size),
                          new[:, 0])
             with jax.named_scope("mla_attend"):
-                o = _mla_attend(
-                    cfg, lp, qn, qr,
-                    mla_pages(kc[place["mla"]], c, call.tables),
-                    jnp.minimum(call.positions.max() // key_block + 1,
-                                key_blocks), call.pos, absorbed=True)
+                keys_of, blocks_to = mla_pages(kc[place["mla"]], c,
+                                               call.tables, key_block)
+                o = _mla_attend(cfg, lp, qn, qr, keys_of,
+                                blocks_to(call.positions.max()), call.pos,
+                                absorbed=True)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
     #: kind of layer -> how a chunk and how a decode step run it

@@ -376,3 +376,197 @@ def test_causal_block_skip_multiblock_grid(bq, bk):
     ref = local_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+# -- the forward over keys that carry their positions (ISSUE 44) ----------
+
+def _keys_reference(q, k, v, q_pos, k_pos, scale, window):
+    """Plain attention in numpy's float64 with the mask read from the
+    positions: key j of a query's group is seen where ``0 <= k_pos <=
+    q_pos`` and, with a window, ``k_pos > q_pos - window``; a query
+    that sees no key reads zeros at ``lse = NEG_INF``."""
+    from horovod_tpu.ops.flash_attention import NEG_INF
+    heads = q.shape[0] // q_pos.shape[0]
+    rep = q.shape[0] // k.shape[0]
+    q, k, v = (np.asarray(a.astype(jnp.float32), np.float64)
+               for a in (q, k, v))
+    k, v = np.repeat(k, rep, 0), np.repeat(v, rep, 0)
+    qp = np.repeat(np.asarray(q_pos), heads, 0)[:, :, None]
+    kp = np.repeat(np.asarray(k_pos), heads, 0)[:, None, :]
+    seen = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        seen &= kp > qp - window
+    s = np.where(seen, np.einsum("bqd,bkd->bqk", q, k) * scale, -np.inf)
+    any_seen = seen.any(-1)
+    top = np.where(any_seen, s.max(-1), 0.0)
+    e = np.exp(s - top[..., None])
+    lse = np.where(any_seen, top + np.log(np.maximum(e.sum(-1), 1e-300)),
+                   NEG_INF)
+    p = e / np.maximum(e.sum(-1, keepdims=True), 1e-300)
+    return np.einsum("bqk,bkd->bqd", p, v), lse
+
+
+def _keys_case(heads, kv_heads, c, keys, dk, dv, q_pos, k_pos,
+               dtype=jnp.float32):
+    rng = np.random.default_rng(0)
+    groups = np.asarray(q_pos).shape[0]
+    q, k, v = (jnp.asarray(0.5 * rng.standard_normal(shape, np.float32),
+                           dtype)
+               for shape in ((groups * heads, c, dk),
+                             (groups * kv_heads, keys, dk),
+                             (groups * kv_heads, keys, dv)))
+    return (q, k, v, jnp.asarray(q_pos, jnp.int32),
+            jnp.asarray(k_pos, jnp.int32))
+
+
+def _at(offset, n):
+    return offset + np.arange(n)
+
+
+_KEYS_CASES = {
+    # a chunk of 48 resumed at 112 (a multiple of 16, not of the tile)
+    # over 160 keys, the latent layers' widths, Kimi's 64 heads; the
+    # chunk is padded to the tile: a padded query tail
+    "an_offset_that_is_no_tile_192_128_64_heads": dict(
+        heads=64, kv_heads=64, c=48, keys=160, dk=192, dv=128,
+        q_pos=[_at(112, 48)], k_pos=[_at(0, 160)]),
+    # Ling's 32 heads, two q tiles and three kv tiles
+    "two_q_tiles_three_kv_tiles_32_heads": dict(
+        heads=32, kv_heads=32, c=144, keys=300, dk=192, dv=128,
+        q_pos=[_at(144, 144)], k_pos=[_at(0, 300)],
+        block_q=128, block_k=128),
+    # positions that are not indices: a gap, keys that are not there,
+    # and a tail of keys past every query (a key block's end)
+    "a_gap_a_hole_and_a_tail_past_every_query": dict(
+        heads=4, kv_heads=4, c=40, keys=272, dk=48, dv=32,
+        q_pos=[_at(1000, 40)],
+        k_pos=[np.concatenate([_at(0, 90), np.full(10, -1), _at(900, 120),
+                               _at(1040, 52)])],
+        block_q=128, block_k=128),
+    # a whole kv tile past every query, and one of holes: both skipped
+    "tiles_with_no_visible_pair_are_skipped": dict(
+        heads=2, kv_heads=2, c=64, keys=512, dk=32, dv=32,
+        q_pos=[_at(64, 64)],
+        k_pos=[np.concatenate([_at(0, 128), np.full(128, -1),
+                               _at(128, 256)])],
+        block_q=128, block_k=128),
+    # keys in no order: a ring's places
+    "a_ring_s_positions_under_a_window": dict(
+        heads=4, kv_heads=2, c=24, keys=128, dk=32, dv=32, window=50,
+        q_pos=[_at(200, 24)],
+        k_pos=[(224 - 1) - ((224 - 1) - np.arange(128)) % 128]),
+    # two sequences at their own positions, GQA through the index map
+    "two_groups_gqa_and_a_window": dict(
+        heads=4, kv_heads=1, c=130, keys=260, dk=32, dv=16, window=70,
+        q_pos=[_at(130, 130), _at(16, 130)],
+        k_pos=[_at(0, 260), np.where(np.arange(260) < 146,
+                                     np.arange(260), -1)],
+        block_q=128, block_k=128),
+    # a query below every key sees none: zeros at NEG_INF
+    "a_query_that_sees_no_key": dict(
+        heads=2, kv_heads=2, c=16, keys=128, dk=32, dv=32,
+        q_pos=[_at(-4, 16)], k_pos=[_at(0, 128)]),
+    "bf16_operands_float32_statistics": dict(
+        heads=4, kv_heads=4, c=64, keys=256, dk=192, dv=128,
+        q_pos=[_at(192, 64)], k_pos=[_at(0, 256)], dtype=jnp.bfloat16,
+        block_q=128, block_k=128, tol=2e-2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_KEYS_CASES))
+def test_keys_forward_matches_the_plain_reference(case):
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    kw = dict(_KEYS_CASES[case])
+    window, tol = kw.pop("window", None), kw.pop("tol", 2e-5)
+    tiles = {n: kw.pop(n) for n in ("block_q", "block_k") if n in kw}
+    q, k, v, q_pos, k_pos = _keys_case(**kw)
+    out, lse = flash_attention_keys(q, k, v, q_pos, k_pos, scale=0.11,
+                                    window=window, **tiles)
+    assert out.shape == (q.shape[0], q.shape[1], v.shape[2])
+    assert out.dtype == lse.dtype == jnp.float32
+    want, want_lse = _keys_reference(q, k, v, q_pos, k_pos, 0.11, window)
+    np.testing.assert_allclose(out, want, rtol=tol, atol=tol)
+    np.testing.assert_allclose(lse, want_lse, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("block_q", [128, 256], ids=["two_q_tiles",
+                                                     "one_q_tile"])
+def test_key_blocks_carried_from_call_to_call_are_one_attention(block_q):
+    """A chunk over three key blocks, a block a call, the last one
+    seen by some of its queries only (and by none of the first q tile):
+    carried through the kernel from call to call, the result is the
+    call's over all keys."""
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    q, k, v, q_pos, k_pos = _keys_case(
+        heads=4, kv_heads=2, c=160, keys=384, dk=48, dv=32,
+        q_pos=[_at(136, 160)], k_pos=[_at(0, 384)])
+    seen = None
+    for j in range(3):
+        at = slice(128 * j, 128 * (j + 1))
+        seen = flash_attention_keys(q, k[:, at], v[:, at], q_pos,
+                                    k_pos[:, at], scale=0.2, carry=seen,
+                                    block_q=block_q, block_k=128)
+    want, want_lse = _keys_reference(q, k, v, q_pos, k_pos, 0.2, None)
+    np.testing.assert_allclose(seen[0], want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(seen[1], want_lse, rtol=2e-5, atol=2e-5)
+
+
+def test_keys_forward_refuses_shapes_that_do_not_belong_together():
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    q, k, v, q_pos, k_pos = _keys_case(
+        heads=2, kv_heads=2, c=16, keys=32, dk=16, dv=16,
+        q_pos=[_at(16, 16)], k_pos=[_at(0, 32)])
+    with pytest.raises(ValueError, match="k_pos"):
+        flash_attention_keys(q, k, v, q_pos, k_pos[:, :31], scale=1.0)
+
+
+def _old_entry_points():
+    from horovod_tpu.ops.flash_attention import (flash_attention,
+                                                 flash_attention_with_lse)
+    q, kv, bh = (2, 256, 4, 64), (2, 256, 2, 64), (8, 192, 64)
+    return {
+        "causal_gqa": (lambda q, k, v: flash_attention(q, k, v), (q, kv, kv)),
+        "not_causal": (lambda q, k, v: flash_attention(q, k, v, causal=False),
+                       (q, q, q)),
+        "window": (lambda q, k, v: flash_attention(q, k, v, window=64),
+                   ((1, 200, 4, 32), (1, 200, 2, 32), (1, 200, 2, 32))),
+        "backward": (jax.grad(lambda q, k, v: flash_attention(q, k, v).sum(),
+                              (0, 1, 2)), (q, kv, kv)),
+        "with_lse": (lambda q, k, v: flash_attention_with_lse(
+            q, k, v, causal=True, out_dtype=jnp.float32), (bh, bh, bh)),
+    }
+
+
+def _lowered_digest(f, shapes):
+    import hashlib
+    text = jax.jit(f).lower(*(jax.ShapeDtypeStruct(s, jnp.float32)
+                              for s in shapes)).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_the_old_entry_points_lower_to_the_programs_before_the_keys_forward():
+    """ISSUE 44 gave the forward over keys with positions a kernel body
+    of its own: ``flash_attention`` and ``flash_attention_with_lse``,
+    forward and backward, with and without a window, lower (interpret
+    mode, no locations) to the text they lowered to at the commit
+    before it, so their outputs are that commit's bit for bit on the
+    shapes this file covers (``tests/test_tpu_lowering.py`` holds the
+    v5e's compiled kernels the same way). The digests were written from
+    a checkout of 68601a6; a PR that changes those kernels on purpose
+    writes them anew."""
+    assert {name: _lowered_digest(*case)
+            for name, case in _old_entry_points().items()} == _BEFORE_PR44
+
+
+_BEFORE_PR44 = {
+    "causal_gqa":
+        "8ea96c23f0830defe1265696d88ad832f239e52c0be7e30707536b7f85efd7d1",
+    "not_causal":
+        "a5df8aea76404c21b8c0d420e025652c65fad3ef0be6131981f33a211a08fbe3",
+    "window":
+        "76132d3305290db8cf87c0fe3b646e2977ec5acc5092c48ef27ff9c4c8ff8955",
+    "backward":
+        "6b880fd4a018f7c8d6b7dc914053a030271b7d9ba47d8a74a6b2b10753cd4b6c",
+    "with_lse":
+        "d1dc2e04f2e1ddc831fb3883a7d253080cd921abec03e393cc829b2877f48320",
+}

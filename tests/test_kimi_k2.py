@@ -393,6 +393,8 @@ def test_yarn_as_published_is_the_reference_s():
 
 @pytest.mark.parametrize("queries", [1, 5])
 def test_absorbed_and_expanded_latent_attention_agree(queries):
+    """Every query at a position that some key holds: one that sees no
+    key reads zeros expanded and a mean of the keys absorbed."""
     cfg = tiny()
     lp = seeded(cfg)["layers"][1]
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -400,7 +402,7 @@ def test_absorbed_and_expanded_latent_attention_agree(queries):
     qn = jax.random.normal(ks[0], (B, queries, 4, 16))
     qr = jax.random.normal(ks[1], (B, queries, 4, 16))
     latents = jax.random.normal(ks[2], (B, K, 48))
-    pos = jnp.asarray([[40], [95], [3]]) + jnp.arange(queries)[None] - queries
+    pos = jnp.asarray([[40], [95], [5]]) + jnp.arange(queries)[None] - queries
 
     def keys_of(j):
         return (jax.lax.dynamic_slice_in_dim(latents, j * 32, 32, 1),
@@ -409,6 +411,67 @@ def test_absorbed_and_expanded_latent_attention_agree(queries):
     got = [decode_lib._mla_attend(cfg, lp, qn, qr, keys_of, 3, pos,
                                   absorbed=how) for how in (True, False)]
     assert gap(np.asarray(got[0]), np.asarray(got[1])) < 1e-5
+
+
+def absorbed_everywhere(patch):
+    """The programs traced under ``patch`` attend their chunks in the
+    absorbed form too: the expanded form's independent reference."""
+    attend = decode_lib._mla_attend
+    patch.setattr(
+        decode_lib, "_mla_attend",
+        lambda *a, absorbed: attend(*a, absorbed=True))
+
+
+@pytest.fixture(scope="module")
+def a_document_s_pages():
+    """A document of 72 tokens (9 pages: its end is no key block's)
+    written into pages 1..9 by the programs as they are."""
+    with pytest.MonkeyPatch.context() as patch:    # as small_key_blocks
+        patch.setattr(decode_lib, "_MLA_KEY_BLOCK", 32)
+        cfg = tiny()
+        params = seeded(cfg)
+        doc, = prompts_of(cfg, (72,), seed=3)
+        cache = init_kv_cache(cfg, 40, BS, n_slots=1)
+        table = np.zeros(16, np.int32)
+        table[:9] = range(1, 10)
+        kc, vc, _, _ = chunks(programs(cfg, 16), params, cache.k, cache.v,
+                              doc, (jnp.asarray(table), jnp.int32(1)))
+        return cfg, params, kc, vc
+
+
+@pytest.mark.parametrize("width", [8, 16, 32])     # the engine's buckets
+@pytest.mark.parametrize("how", ["local", "resumed_over_mapped_pages"])
+def test_a_chunk_through_the_kernel_is_the_absorbed_form(a_document_s_pages,
+                                                         how, width):
+    """A chunk of each bucket width through ``mla_chunk``, its last 3
+    places padding: as the whole prompt over itself, and resumed at the
+    document's end (72: a multiple of the page, not of the key block)
+    over the document's pages mapped into another sequence's table.
+    The logits through the Pallas forward are those of the same program
+    attending in the absorbed form."""
+    cfg, params, kc, vc = a_document_s_pages
+    n = width - 3
+    tokens = np.zeros(width, np.int32)
+    tokens[:n] = prompts_of(cfg, (n,), seed=width)[0]
+    table = np.zeros(16, np.int32)
+    if how == "local":
+        table[:4] = range(20, 24)
+    else:
+        table[:9], table[9:13] = range(1, 10), range(20, 24)
+    addr = (jnp.asarray(table), jnp.int32(1))
+
+    def logits():
+        prefill, resume, _ = programs(cfg, 16)
+        if how == "local":
+            return prefill(params, kc, vc, tokens, jnp.int32(n), addr)[2]
+        return resume(params, kc, vc, tokens, jnp.int32(72), jnp.int32(n),
+                      addr)[2]
+
+    got = np.asarray(logits())
+    with pytest.MonkeyPatch.context() as patch:
+        absorbed_everywhere(patch)
+        want = np.asarray(logits())
+    assert got.shape == want.shape and gap(got, want) < 1e-5
 
 
 # (e) the share of the experts --------------------------------------------

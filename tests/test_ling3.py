@@ -280,6 +280,8 @@ def test_a_padded_chunk_leaves_the_state_at_its_length():
 
 @pytest.mark.parametrize("queries", [1, 5])
 def test_absorbed_and_expanded_latent_attention_agree(queries):
+    """Every query at a position that some key holds: one that sees no
+    key reads zeros expanded and a mean of the keys absorbed."""
     cfg = tiny()
     lp = seeded(cfg)["layers"][1]
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -287,7 +289,7 @@ def test_absorbed_and_expanded_latent_attention_agree(queries):
     qn = jax.random.normal(ks[0], (B, queries, 4, 16))
     qr = jax.random.normal(ks[1], (B, queries, 4, 8))
     latents = jax.random.normal(ks[2], (B, K, 40))
-    pos = jnp.asarray([[40], [95], [3]]) + jnp.arange(queries)[None] - queries
+    pos = jnp.asarray([[40], [95], [5]]) + jnp.arange(queries)[None] - queries
 
     def keys_of(j):
         return (jax.lax.dynamic_slice_in_dim(latents, j * 32, 32, 1),
@@ -296,6 +298,59 @@ def test_absorbed_and_expanded_latent_attention_agree(queries):
     got = [decode_lib._mla_attend(cfg, lp, qn, qr, keys_of, 3, pos,
                                   absorbed=how) for how in (True, False)]
     assert gap(np.asarray(got[0]), np.asarray(got[1])) < 1e-5
+
+
+@pytest.fixture(scope="module")
+def a_prompt_s_first_chunks():
+    """72 tokens of a prompt (9 pages: their end is no key block's)
+    written into slot 1 by the programs as they are."""
+    with pytest.MonkeyPatch.context() as patch:    # as small_key_blocks
+        patch.setattr(decode_lib, "_MLA_KEY_BLOCK", 32)
+        cfg = tiny()
+        params = seeded(cfg)
+        start, = prompts_of(cfg, (72,), seed=3)
+        cache = init_kv_cache(cfg, 40, BS, n_slots=1)
+        kc, vc = cache.k, cache.v
+        addr = (jnp.arange(1, 17, dtype=jnp.int32), jnp.int32(1))
+        resume = jax.jit(decode_lib.mixed_programs(
+            cfg, BS, 16, 0, head=lambda lg: lg)[1])
+        for off in range(0, 72, CHUNK):
+            n = min(CHUNK, 72 - off)
+            kc, vc, _ = resume(params, kc, vc,
+                               np.asarray(start[off:off + n], np.int32),
+                               jnp.int32(off), jnp.int32(n), addr)
+        return cfg, params, kc, vc, addr
+
+
+@pytest.mark.parametrize("width", [8, 16, 32])     # the engine's buckets
+@pytest.mark.parametrize("how", ["local", "resumed"])
+def test_a_chunk_through_the_kernel_is_the_absorbed_form(
+        a_prompt_s_first_chunks, how, width):
+    """A chunk of each bucket width through ``mla_chunk``, its last 3
+    places padding: as the whole prompt over itself, and resumed at 72
+    (a multiple of the page, not of the key block) over the pages the
+    earlier chunks wrote. The logits through the Pallas forward are
+    those of the same program attending in the absorbed form."""
+    cfg, params, kc, vc, addr = a_prompt_s_first_chunks
+    n = width - 3
+    tokens = np.zeros(width, np.int32)
+    tokens[:n] = prompts_of(cfg, (n,), seed=width)[0]
+
+    def logits():
+        prefill, resume = map(jax.jit, decode_lib.mixed_programs(
+            cfg, BS, 16, 0, head=lambda lg: lg)[:2])
+        if how == "local":
+            return prefill(params, kc, vc, tokens, jnp.int32(n), addr)[2]
+        return resume(params, kc, vc, tokens, jnp.int32(72), jnp.int32(n),
+                      addr)[2]
+
+    got = np.asarray(logits())
+    with pytest.MonkeyPatch.context() as patch:
+        attend = decode_lib._mla_attend
+        patch.setattr(decode_lib, "_mla_attend",
+                      lambda *a, absorbed: attend(*a, absorbed=True))
+        want = np.asarray(logits())
+    assert got.shape == want.shape and gap(got, want) < 1e-5
 
 
 # (e) --------------------------------------------------------------------

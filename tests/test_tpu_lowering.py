@@ -367,6 +367,11 @@ for name, fn, args in (
         # a float32 tensor with a chunk's queries against a whole table
         "scores_of_the_table": len(re.findall(
             r"f32\[[\d,]*(1024,17408|17408,1024)[\d,]*\]", text)),
+        # ... or against one key block, every head's
+        "scores_of_a_key_block": len(re.findall(
+            r"f32\[[\d,]*32,1024,1024\]", text)),
+        "keys_kernel_paths": sorted(set(re.findall(
+            r'op_name="([^"]*hvd_flash_keys_fwd)[^"]*"', text))),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
 """
@@ -717,6 +722,23 @@ def test_state_and_latent_pool_are_updated_where_they_lie_on_v5e(program):
     assert got["scores_of_the_table"] == 0, got
     limit = {"decode": 0.25e9, "prefill_resume": 0.9e9}[program]
     assert got["temp_bytes"] < limit < out["pool_bytes"], got
+
+
+def test_a_chunk_s_latent_attention_is_the_keys_kernel_on_v5e():
+    """ISSUE 44: a chunk of 1024 at Ling's 32 heads compiles for the v5e
+    with the flash forward over keys that carry their positions under
+    ``attn/attn_mla/mla_attend``, in the loop over key blocks, and
+    holds no float32 ``[32, 1024, 1024]`` tensor (a key
+    block's scores, 134 MB, and the softmax's passes over them: what the
+    einsum form wrote); decode's absorbed form has no such kernel and is
+    left as it was."""
+    out = _compile_for_v5e(_LING_DRIVER)
+    chunk, step = out["prefill_resume"], out["decode"]
+    assert chunk["keys_kernel_paths"] == [
+        "jit(prefill_resume)/attn/attn_mla/mla_attend/while/body/"
+        "hvd_flash_keys_fwd"], chunk
+    assert chunk["scores_of_a_key_block"] == 0, chunk
+    assert step["keys_kernel_paths"] == [], step
 
 
 def test_the_trained_share_runs_its_bound_s_rows_outside_the_fall_back():

@@ -12,9 +12,21 @@ each candidate tile; and the largest difference of ``_attend_prompt``
 from the dense form over float32 inputs at ``HIGHEST``, relative to its
 largest magnitude. How ``_attend_prompt`` came by ``_DENSE_PROMPT`` and
 ``_prompt_block``.
+
+``--latent`` sweeps the mla layers' chunk instead (ISSUE 44): ``[1, C,
+H, 128 + 64]`` bf16 queries of a chunk that ends at key ``keys`` over
+latents ``[keys, 512 + 64]`` gathered and expanded a key block at a time
+(``serve/decode.py::_mla_attend``, ``absorbed=False``), at Kimi's 64 and
+Ling's 32 heads: ms a layer of the einsum form (float32 ``[H, C, block]``
+scores in HBM: all ``_mla_attend`` had before ISSUE 44), of
+``_mla_attend`` as it is (the Pallas forward over keys that carry their
+positions) at each ``--tiles`` pair and ``--key-block``, and of the
+kernel alone over one expanded key block; and the largest difference of
+the two forms relative to the largest magnitude.
 """
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -27,11 +39,16 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
+from horovod_tpu.models import TransformerConfig  # noqa: E402
+from horovod_tpu.models import transformer as tf_lib  # noqa: E402
+from horovod_tpu.ops import flash_attention as flash_lib  # noqa: E402
 from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from horovod_tpu.parallel.ring_attention import local_attention  # noqa: E402
+from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 from horovod_tpu.serve.decode import _attend_prompt  # noqa: E402
 
 LAYERS = 16
+LATENT_LAYERS = 6      # the Kimi cell's depth
 
 
 def dense(q, k, v):
@@ -41,6 +58,18 @@ def dense(q, k, v):
                            causal=True).reshape(*q.shape[:2], -1)
 
 
+def median_ms(fn, xs, reps):
+    """ms a call of the jitted ``fn(*xs)``: the median of ``reps`` runs
+    after the one that compiles."""
+    jax.block_until_ready(fn(*xs))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*xs))
+        times.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(times)
+
+
 def ms_a_layer(attend, q, k, v, reps):
     """``attend`` [1, T, H, Dh] -> [1, T, H * Dh], a layer's output the
     next one's queries, so that the calls run one after another."""
@@ -48,17 +77,173 @@ def ms_a_layer(attend, q, k, v, reps):
     def chain(q, k, v):
         return lax.scan(lambda q, _: (attend(q, k, v).reshape(q.shape),
                                       None), q, None, length=LAYERS)[0]
-    jax.block_until_ready(chain(q, k, v))
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(chain(q, k, v))
-        times.append(time.perf_counter() - t0)
-    return round(1e3 * statistics.median(times) / LAYERS, 4)
+    return round(median_ms(chain, (q, k, v), reps) / LAYERS, 4)
+
+
+def mla_einsum(cfg, lp, qn, qr, keys_of, n_blocks, pos):
+    """The expanded form as ``_mla_attend`` had it before ISSUE 44: a
+    key block's scores from two einsums, masked, maxed, exponentiated
+    and summed as float32 ``[B, H, C, K]`` tensors in HBM."""
+    B, C, H, Dh = qn.shape
+    rank = cfg.mla_kv_rank
+    w_uk, w_uv = tf_lib.mla_up(cfg, lp)
+    scale = tf_lib.mla_scale(cfg)
+
+    def block(j, carry):
+        m, l, acc = carry
+        latent, key_pos = keys_of(j)
+        c = latent[..., :rank]
+        r = latent[..., rank:rank + cfg.mla_rope_dim]
+        vals = jnp.einsum("bkc,chd->bkhd", c, w_uv)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qn,
+                       jnp.einsum("bkc,chd->bkhd", c, w_uk),
+                       preferred_element_type=jnp.float32)
+        s = (s + jnp.einsum("bqhr,bkr->bhqk", qr, r,
+                            preferred_element_type=jnp.float32)) * scale
+        seen = key_pos[None, None, :] <= pos[:, :, None]
+        s = jnp.where(seen[:, None], s, -1e30)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        acc = acc * fade[..., None] + jnp.einsum(
+            "bhqk,bkhd->bhqd", p.astype(vals.dtype), vals,
+            preferred_element_type=jnp.float32)
+        return m_new, l * fade + p.sum(-1), acc
+
+    m, l, acc = lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((B, H, C), -1e30, jnp.float32),
+         jnp.zeros((B, H, C), jnp.float32),
+         jnp.zeros((B, H, C, Dh), jnp.float32)))
+    return jnp.moveaxis(acc / l[..., None], 1, 2).astype(qn.dtype)
+
+
+def latent_sweep(args) -> None:
+    LATENT, RANK, ROPE, DH = 640, 512, 64, 128
+    key_blocks = args.key_blocks or [decode_lib._MLA_CHUNK_BLOCKS
+                                     * decode_lib._MLA_KEY_BLOCK]
+
+    for heads in args.heads:
+        cfg = TransformerConfig(
+            vocab_size=128, d_model=128, n_layers=1, n_heads=heads,
+            n_kv_heads=heads, d_head=DH, d_ff=128, layer_types=("mla",),
+            mla_kv_rank=RANK, mla_rope_dim=ROPE, dtype=jnp.bfloat16)
+        ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+        lp = {"w_ukv": (jax.random.normal(ks[0], (RANK, heads * 2 * DH))
+                        * RANK ** -0.5).astype(jnp.bfloat16)}
+        pool = jax.random.normal(
+            ks[1], (max(args.keys) + max(1024, *key_blocks), LATENT)
+        ).astype(jnp.bfloat16)
+        for c in args.chunks:
+            qn = jax.random.normal(ks[2], (1, c, heads, DH)
+                                   ).astype(jnp.bfloat16)
+            qr = jax.random.normal(ks[3], (1, c, heads, ROPE)
+                                   ).astype(jnp.bfloat16)
+            for keys in args.keys:
+                if keys < c:
+                    continue
+                pos = (keys - c + jnp.arange(c, dtype=jnp.int32))[None]
+                row = {"heads": heads, "C": c, "keys": keys}
+
+                def chained(form, key_block):
+                    """(jitted chain of LATENT_LAYERS calls of ``form``, its
+                    arguments): the arrays are arguments, not constants
+                    of the program."""
+                    @jax.jit
+                    def chain(qn, qr, lp, pool, n_blocks):
+                        def keys_of(j):
+                            return (lax.dynamic_slice_in_dim(
+                                pool, j * key_block, key_block)[None],
+                                j * key_block + jnp.arange(
+                                    key_block, dtype=jnp.int32))
+                        return lax.scan(
+                            lambda q, _: (form(cfg, lp, q, qr, keys_of,
+                                               n_blocks, pos), None),
+                            qn, None, length=LATENT_LAYERS)[0]
+                    return chain, (qn, qr, lp, pool,
+                                   jnp.int32(-(-keys // key_block)))
+
+                chain, xs = chained(mla_einsum, 1024)
+                row["einsum"] = round(
+                    median_ms(chain, xs, args.reps) / LATENT_LAYERS, 4)
+                want = chain(*xs).astype(jnp.float32)
+                attend = functools.partial(decode_lib._mla_attend,
+                                           absorbed=False)
+                kernel = flash_lib.flash_attention_keys
+
+                def merged_outside(*a, carry=None, **kw):
+                    """A key block's call merged with the blocks before
+                    it by XLA, outside the kernel (``--merge-outside``)."""
+                    o, lse = kernel(*a, **kw)
+                    if carry is None:
+                        return o, lse
+                    both = jnp.logaddexp(carry[1], lse)
+                    return (carry[0] * jnp.exp(carry[1] - both)[..., None]
+                            + o * jnp.exp(lse - both)[..., None]), both
+
+                if args.merge_outside:
+                    decode_lib.flash_attention_keys = merged_outside
+                default_tiles = flash_lib._keys_blocks
+                for kb in key_blocks:
+                    for bq, bk in [(None, None)] + list(map(tuple,
+                                                            args.tiles)):
+                        flash_lib._keys_blocks = (
+                            default_tiles if bq is None
+                            else lambda c_, k_: (bq, bk))
+                        chain, xs = chained(attend, kb)
+                        name = f"kernel_kb{kb}" + (
+                            "" if bq is None else f"_{bq}x{bk}")
+                        try:
+                            row[name] = round(median_ms(
+                                chain, xs, args.reps) / LATENT_LAYERS, 4)
+                        except Exception as e:  # a tile Mosaic refuses
+                            row[name] = f"refused: {str(e)[:80]}"
+                            continue
+                        if bq is None and kb == key_blocks[0]:
+                            got = chain(*xs).astype(jnp.float32)
+                            row["rel_err"] = float(
+                                jnp.max(jnp.abs(got - want))
+                                / jnp.max(jnp.abs(want)))
+                flash_lib._keys_blocks = default_tiles
+                decode_lib.flash_attention_keys = kernel
+                print(json.dumps(row), flush=True)
+            # the kernel alone over one expanded key block, all of it
+            # seen, 16 calls chained through the carried pair
+            k = jax.random.normal(ks[2], (heads, 1024, DH + ROPE)
+                                  ).astype(jnp.bfloat16)
+            q = jnp.concatenate([qn, qr], -1)[0].swapaxes(0, 1)
+
+            @jax.jit
+            def alone(q, k):
+                return lax.fori_loop(
+                    0, 16, lambda _, seen: flash_lib.flash_attention_keys(
+                        q, k, k[..., :DH],
+                        4096 + jnp.arange(c, dtype=jnp.int32)[None],
+                        jnp.arange(1024, dtype=jnp.int32)[None], scale=0.07,
+                        carry=seen),
+                    (jnp.zeros((heads, c, DH), jnp.float32),
+                     jnp.full((heads, c), flash_lib.NEG_INF, jnp.float32)))
+            print(json.dumps({
+                "heads": heads, "C": c, "kernel_alone_1024_keys": round(
+                    median_ms(alone, (q, k), args.reps) / 16, 4)}),
+                flush=True)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--latent", action="store_true",
+                    help="the mla layers' chunk instead of the prompt")
+    ap.add_argument("--heads", type=int, nargs="+", default=[64, 32])
+    ap.add_argument("--chunks", type=int, nargs="+",
+                    default=[256, 512, 768, 1024])
+    ap.add_argument("--keys", type=int, nargs="+",
+                    default=[1024, 4096, 8192, 17408])
+    ap.add_argument("--key-blocks", type=int, nargs="+", default=None,
+                    help="keys a call (default: the programs')")
+    ap.add_argument("--merge-outside", action="store_true",
+                    help="merge the key blocks by XLA, not in the kernel")
+    ap.add_argument("--tiles", type=lambda s: [int(x) for x in s.split("x")],
+                    nargs="*", default=[], help="q x kv tiles, as 512x1024")
     ap.add_argument("--buckets", type=int, nargs="+",
                     default=[128, 256, 512, 1024, 1280, 1536, 1792, 2048])
     ap.add_argument("--blocks", type=int, nargs="+",
@@ -66,6 +251,8 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     args = ap.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.latent:
+        return latent_sweep(args)
     for t in args.buckets:
         keys = jax.random.split(jax.random.PRNGKey(t), 3)
         q, k, v = (
