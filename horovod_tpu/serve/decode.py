@@ -78,15 +78,19 @@ faster, in the dense form.
 The programs of a configuration with layers of several kinds
 (:func:`mixed_programs`) attend through :func:`_attend_keys` (window
 and full layers: keys that carry their positions, in XLA),
-:func:`kda_scan` / :func:`kda_step` (a recurrent state) and
-:func:`_mla_attend` (latent attention), which has two forms: a decode
-step's queries attend ABSORBED, in XLA; a chunk's (``prefill`` over
-itself, ``prefill_resume`` over the pages, at every bucket width)
-EXPANDED, a key block at a time through the Pallas flash forward over
-keys that carry their positions
+:func:`kda_scan` / :func:`kda_step` (a recurrent state) and latent
+attention, in two forms. A chunk's queries (``prefill`` over itself,
+``prefill_resume`` over the pages, at every bucket width) attend
+EXPANDED (:func:`_mla_attend`), a key block at a time through the
+Pallas flash forward over keys that carry their positions
 (``ops/flash_attention.py::flash_attention_keys``, the kernel
 ``hvd_flash_keys_fwd``), so that a chunk's scores are tiles in VMEM
-too. Nothing chooses between the two but which program calls.
+too. A decode step's attend ABSORBED (:func:`_mla_decode`), each row
+over its own pages where they lie in the pool, through a Pallas kernel
+of its own (``ops/latent_decode.py``, ``hvd_latent_decode``): no key
+block is gathered and no score reaches HBM. The absorbed form in XLA
+(:func:`_mla_attend`, ``absorbed=True``) is what the tests hold both
+to. Nothing chooses between the two but which program calls.
 """
 
 from __future__ import annotations
@@ -104,6 +108,7 @@ from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_keys)
+from horovod_tpu.ops.latent_decode import latent_decode
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
 
@@ -718,11 +723,14 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
     softmax carried from block to block through the kernel. Every
     chunk width goes this way (``_MLA_CHUNK_BLOCKS`` has the chip's
     times beside the einsum form's that it replaced).
-    **Absorbed** (a decode step's: one a sequence): ``q W_uk^T`` is
-    scored against the latent itself and the latent is summed, then
-    expanded once (``(sum p c) W_uv``), in XLA with a running softmax
-    over the blocks: the same function, with no ``[K, H, Dh]`` key or
-    value a position, and the tests' reference for the expanded form.
+    **Absorbed** (one query a sequence): ``q W_uk^T`` is scored
+    against the latent itself and the latent is summed, then expanded
+    once (``(sum p c) W_uv``), in XLA with a running softmax over the
+    blocks: the same function, with no ``[K, H, Dh]`` key or value a
+    position. No serve program runs it: a decode step's attention is
+    :func:`_mla_decode`, the same arithmetic in a kernel that reads the
+    pool where it lies. It is the tests' reference for both, the
+    expanded form and that kernel.
 
     A query that sees no key (a position below every key's: none a
     program sends) reads zeros expanded and a mean of the keys
@@ -784,6 +792,51 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
     return jnp.einsum("bqhc,chd->bqhd", o, w_uv)
 
 
+def mla_pages(pool, c, tables, key_block: int):
+    """``keys_of`` of :func:`_mla_attend` over the pages of layer ``c``
+    of the latent ``pool`` ``[n_mla, n_blocks, block_size, latent_row]``
+    behind ``tables`` [B, W]: block j is the ``key_block`` positions
+    from ``j * key_block``, gathered when it is attended (a copy
+    ``[B, key_block, latent_row]``: what a chunk's expanded form reads,
+    and what a decode step's read before :func:`_mla_decode`); and
+    ``blocks_to(last)``, how many blocks hold the positions up to
+    ``last``."""
+    block_size, latent = pool.shape[2:]
+    per = key_block // block_size
+    key_blocks = -(-tables.shape[1] * block_size // key_block)
+    tables = jnp.pad(tables, ((0, 0), (0, key_blocks * per
+                                       - tables.shape[1])))
+
+    def keys_of(j):
+        with jax.named_scope("kv_gather"):
+            ids = lax.dynamic_slice_in_dim(tables, j * per, per, 1)
+            return (pool[c, ids].reshape(tables.shape[0], key_block,
+                                         latent),
+                    j * key_block + jnp.arange(key_block,
+                                               dtype=jnp.int32))
+    return keys_of, lambda last: jnp.minimum(last // key_block + 1,
+                                             key_blocks)
+
+
+def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions):
+    """A decode step's latent attention: the absorbed form of
+    :func:`_mla_attend` for one query a row (``qn`` [B, 1, H, Dh],
+    ``qr`` [B, 1, H, R]) at ``positions`` [B], over the pages of layer
+    ``c`` of the latent ``pool`` behind ``tables`` [B, W], read where
+    they lie by ``ops/latent_decode.py`` (``hvd_latent_decode`` in a
+    device trace): each row's own pages, once, no further than its
+    position, with no gathered copy of a key block and no score tensor
+    in HBM. The two small products stay in XLA around the call:
+    ``q W_uk^T`` before it and ``(sum p c) W_uv`` after it. Returns
+    [B, 1, H, Dh]."""
+    w_uk, w_uv = tf_lib.mla_up(cfg, lp)
+    q = jnp.concatenate([jnp.einsum("bqhd,chd->bqhc", qn, w_uk), qr], -1)
+    q = jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+    o = latent_decode(q, pool, c, tables, positions + 1,
+                      rank=cfg.mla_kv_rank, scale=tf_lib.mla_scale(cfg))
+    return jnp.einsum("bqhc,chd->bqhd", o[:, None], w_uv)
+
+
 def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                    compression=None, head=None):
     """(prefill, prefill_resume, decode, held_experts_counts), not
@@ -821,8 +874,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     n_win = seen.get("sliding", 0)
     S = table_width * block_size
     latent = latent_row(cfg) if "mla" in place else 0
-    # the mla layers' key blocks: whole pages, the table padded to them
-    key_block = min(_MLA_KEY_BLOCK, S) // block_size * block_size
+    # an mla chunk's key blocks: whole pages, the table padded to them
     chunk_key_block = min(_MLA_CHUNK_BLOCKS * _MLA_KEY_BLOCK,
                           S) // block_size * block_size
 
@@ -970,26 +1022,6 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                     absorbed=False)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
-    def mla_pages(pool, c, tables, key_block):
-        """``keys_of`` of :func:`_mla_attend` over the pages behind
-        ``tables`` [B, W]: block j is the ``key_block`` positions from
-        ``j * key_block``, gathered when it is attended; and
-        ``blocks_to(last)``, how many blocks hold the positions up to
-        ``last``."""
-        per, key_blocks = key_block // block_size, -(-S // key_block)
-        tables = jnp.pad(tables, ((0, 0), (0, key_blocks * per
-                                           - tables.shape[1])))
-
-        def keys_of(j):
-            with jax.named_scope("kv_gather"):
-                ids = lax.dynamic_slice_in_dim(tables, j * per, per, 1)
-                return (pool[c, ids].reshape(tables.shape[0], key_block,
-                                             latent),
-                        j * key_block + jnp.arange(key_block,
-                                                   dtype=jnp.int32))
-        return keys_of, lambda last: jnp.minimum(last // key_block + 1,
-                                                 key_blocks)
-
     # -- a decode step of the batch (one position a row) -------------
 
     def by_slot(call, rows, n_slots):
@@ -1066,7 +1098,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o)
 
     def mla_step(call, lp, kc, vc, c, x, i):
-        """Absorbed attention over the pages behind the tables."""
+        """Absorbed attention over each row's own pages, where they lie
+        in the pool (:func:`_mla_decode`)."""
         with jax.named_scope("attn_mla"):
             h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, x, call.pos)
             new = jnp.pad(new, ((0, 0), (0, 0), (0, latent - new.shape[-1])))
@@ -1075,11 +1108,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                          (c, call.blk, call.positions % block_size),
                          new[:, 0])
             with jax.named_scope("mla_attend"):
-                keys_of, blocks_to = mla_pages(kc[place["mla"]], c,
-                                               call.tables, key_block)
-                o = _mla_attend(cfg, lp, qn, qr, keys_of,
-                                blocks_to(call.positions.max()), call.pos,
-                                absorbed=True)
+                o = _mla_decode(cfg, lp, qn, qr, kc[place["mla"]], c,
+                                call.tables, call.positions)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
     #: kind of layer -> how a chunk and how a decode step run it

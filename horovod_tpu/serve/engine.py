@@ -94,6 +94,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from horovod_tpu.ops.latent_decode import key_block as latent_key_block
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
     NULL_SLOT, BlockAllocator, hash_chain, init_kv_cache, pick_bucket,
@@ -469,6 +470,11 @@ class ServeEngine:
                     + " and without ".join(refused)
                     + " (ROADMAP B9, B14); set prefix_caching=False, "
                     "draft=None")
+
+        # What a decode call's latent attention reads is counted from
+        # the positions it is given (metrics.record_latent_decode).
+        self._latent_layers = model_cfg.n_layers_of("mla")
+        self._latent_key_block = latent_key_block(bs, self._table_width)
 
         # Inject pad-width menu, in BLOCK units: the prefill buckets
         # (prompt-only handoffs keep their existing programs) plus the
@@ -1371,6 +1377,9 @@ class ServeEngine:
             self.cache.k, self.cache.v, out = self._decode_fn(
                 self._params, self.cache.k, self.cache.v, tokens,
                 positions, address)
+        if self._latent_layers:
+            m.record_latent_decode(positions + 1, self._latent_key_block,
+                                   self._latent_layers)
         if prev is not None:
             m.record_decode_ahead()
         elif out.committed:

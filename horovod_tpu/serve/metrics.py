@@ -340,6 +340,11 @@ class ServeMetrics:
         # successor could be launched, by what stood in the way.
         self.decode_ahead_total = 0
         self.decode_drains: Dict[str, int] = dict.fromkeys(DRAIN_CAUSES, 0)
+        # Key blocks of the latent pool that the decode calls' kernel
+        # read (every row to its own length), and what a loop to the
+        # batch's longest row would have gathered for every row.
+        self.latent_decode_key_blocks_total = 0
+        self.latent_decode_key_blocks_longest_total = 0
         self.queue_depth = 0
         self.max_queue_depth = 0
         self.stalls_total = 0
@@ -578,6 +583,17 @@ class ServeMetrics:
         self.kv_latent_positions_max = max(self.kv_latent_positions_max,
                                            int(held.max(initial=0)))
 
+    def record_latent_decode(self, lengths, key_block: int,
+                             layers: int) -> None:
+        """A decode call was launched whose rows hold ``lengths``
+        positions (an array: every row of the call, a padded row at 1)
+        in the latent pool of ``layers`` mla layers, attended a
+        ``key_block`` of positions at a time."""
+        blocks = -(-lengths // key_block)
+        self.latent_decode_key_blocks_total += layers * int(blocks.sum())
+        self.latent_decode_key_blocks_longest_total += (
+            layers * len(blocks) * int(blocks.max()))
+
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
         nobody's host gap."""
@@ -739,6 +755,13 @@ class ServeMetrics:
             "decode_drains_total": sum(self.decode_drains.values()),
             **{f"decode_drains_{cause}_total": n
                for cause, n in self.decode_drains.items()},
+            # key blocks of the latent pool the decode calls read, a
+            # row to its own length, and what reading every row to the
+            # call's longest would have taken (zeros without mla layers)
+            "latent_decode_key_blocks_total":
+                self.latent_decode_key_blocks_total,
+            "latent_decode_key_blocks_longest_total":
+                self.latent_decode_key_blocks_longest_total,
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
             # device calls and host gaps many times their kind's

@@ -298,6 +298,11 @@ def test_the_engine_shares_a_document_s_pages_and_serves_the_reference():
     assert snap["prefix_cache_hit_rate"] == round(144 / sum(map(len, asks)), 4)
     assert snap["kv_pages_shared_max"] == 9 and snap["kv_pages_shared"] == 0
     assert snap["kv_latent_positions_max"] == 72 + 30 + 12 - 1
+    # a table of 9 pages is one key block: every row of every decode
+    # call (a bucket of 4) reads one a layer, the longest row's too
+    assert (snap["latent_decode_key_blocks_total"]
+            == snap["latent_decode_key_blocks_longest_total"]
+            == cfg.n_layers * 4 * snap["decode_steps"])
     spans = [e for e in eng.metrics._events if e["name"] == "serve:prefill"]
     assert sum(s["args"]["mapped"] for s in spans) == 144
     mapped = [s["args"] for s in spans if s["args"]["mapped"]]

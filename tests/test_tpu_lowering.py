@@ -292,6 +292,25 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# What a decode program's text says of its latent attention (ISSUE 45):
+# the Mosaic calls under `hvd_latent_decode` by scope, a key block of
+# 1024 positions gathered for every row (`bf16[rows * 64, 16, 640]`,
+# what `mla_pages` made for the XLA form) and that form's float32 scores
+# of a key block. Shared by the two drivers that compile a decode.
+_LATENT_DECODE_REPORT = r"""
+def latent_decode_report(text, rows, heads):
+    calls = re.findall(
+        r'custom-call\([^\n]*op_name="([^"]*hvd_latent_decode)[^"]*"', text)
+    return {{
+        "latent_decode_calls": len(calls),
+        "latent_decode_paths": sorted(set(calls)),
+        "gathered_key_blocks": len(re.findall(
+            r"bf16\[(%d,16|%d,1024),640\]" % (rows * 64, rows), text)),
+        "scores_of_a_decode_key_block": len(re.findall(
+            r"f32\[%d,%d,1,1024\]" % (rows, heads), text))}}
+"""
+
+
 # The programs of a configuration whose state is not cached keys (ISSUE
 # 38), at the widths, the 64 slots and the table of the cell that brought
 # them (a dense kda layer, a sparse kda layer and a sparse mla layer;
@@ -338,6 +357,7 @@ kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
     cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
 _, resume, decode, _, _ = decode_lib.make_serve_fns(
     cfg, None, block_size=BS, table_width=WIDTH)
+LATENT_DECODE_REPORT
 
 
 def shape_of(s):
@@ -372,9 +392,78 @@ for name, fn, args in (
             r"f32\[[\d,]*32,1024,1024\]", text)),
         "keys_kernel_paths": sorted(set(re.findall(
             r'op_name="([^"]*hvd_flash_keys_fwd)[^"]*"', text))),
+        **latent_decode_report(text, SLOTS, cfg.n_heads),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT)
+
+
+# The decode step of a stack whose every layer is `mla` (ISSUE 43), at
+# the widths, the 32 slots and the table of `kimi-k2.7-code-ep32-6l`:
+# the dense layer and one sparse layer (two experts held, so that it
+# compiles in a minute), both over one latent pool.
+_KIMI_DRIVER = r"""
+import json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=20480, d_model=7168, n_layers=2, n_heads=64, n_kv_heads=64,
+    d_head=128, d_ff=2048, d_ff_dense=18432, n_dense_layers=1,
+    max_seq=17152, norm_eps=1e-5, layer_types=("mla", "mla"),
+    mla_kv_rank=512, mla_rope_dim=64, mla_q_rank=1536, mla_head_gate=False,
+    layer_rotary={{"mla": dict(theta=5e4, factor=64.0, original_max_seq=4096,
+                              mscale_all_dim=1.0)}},
+    n_experts=384, moe_top_k=8, moe_capacity_factor=None,
+    moe_scoring="sigmoid", moe_route_scale=2.827, moe_shared_expert=True,
+    moe_experts_held=2, moe_expert_offset=12, dtype=jnp.bfloat16,
+    remat=False)
+BS, WIDTH, SLOTS = 16, 1072, 32
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+decode = decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                   table_width=WIDTH)[2]
+LATENT_DECODE_REPORT
+compiled = decode.lower(params, kc, vc, i32(SLOTS), i32(SLOTS),
+                        (i32(SLOTS, WIDTH), i32(SLOTS))).compile()
+text = compiled.as_text()
+pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
+aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+print("LOWERED " + json.dumps({{
+    "device_kind": topo.devices[0].device_kind,
+    "pool_bytes": kc[0].size * 2,
+    "decode": {{
+        "pool_ops": sorted(set(re.findall(
+            r"= %s\{{\S* ([\w\-]+)\(" % re.escape(pool), text))),
+        "aliased": len(re.findall(r"may-alias|must-alias",
+                                  aliased.group(1))),
+        **latent_decode_report(text, SLOTS, cfg.n_heads),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}}}))
+""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT)
 
 
 # The trained share of the experts (ISSUE 40): the step of the cell
@@ -730,15 +819,48 @@ def test_a_chunk_s_latent_attention_is_the_keys_kernel_on_v5e():
     ``attn/attn_mla/mla_attend``, in the loop over key blocks, and
     holds no float32 ``[32, 1024, 1024]`` tensor (a key
     block's scores, 134 MB, and the softmax's passes over them: what the
-    einsum form wrote); decode's absorbed form has no such kernel and is
-    left as it was."""
+    einsum form wrote); a decode step has a kernel of its own (ISSUE
+    45: ``hvd_latent_decode``, below), under the same scope."""
     out = _compile_for_v5e(_LING_DRIVER)
     chunk, step = out["prefill_resume"], out["decode"]
     assert chunk["keys_kernel_paths"] == [
         "jit(prefill_resume)/attn/attn_mla/mla_attend/while/body/"
         "hvd_flash_keys_fwd"], chunk
     assert chunk["scores_of_a_key_block"] == 0, chunk
+    assert chunk["latent_decode_calls"] == 0, chunk
     assert step["keys_kernel_paths"] == [], step
+    assert step["latent_decode_paths"] == [
+        "jit(decode)/attn/attn_mla/mla_attend/hvd_latent_decode"], step
+
+
+@pytest.mark.parametrize("shapes", ["ling", "kimi"])
+def test_a_decode_step_s_latent_attention_reads_the_pool_where_it_lies(
+        shapes):
+    """ISSUE 45: ``jit(decode)`` compiled for the v5e at Ling's shapes
+    (64 rows, 32 heads, one mla layer of three) and at Kimi's (32 rows,
+    64 heads, two mla layers over one pool) holds the Pallas call
+    ``hvd_latent_decode`` once a mla layer, under the scope the
+    benchmark's reader matches; no key block of 1024 positions gathered
+    for every row (``bf16[rows * 64, 16, 640]``: 84 MB a turn of the
+    loop it replaced) and no float32 ``[rows, heads, 1, 1024]`` scores;
+    the pool aliased in and out and never copied (the kernel takes the
+    whole array where it lies, the layer an index), and what a call
+    allocates far under the pool's size."""
+    driver, layers, large = {"ling": (_LING_DRIVER, 1, 3),
+                             "kimi": (_KIMI_DRIVER, 2, 1)}[shapes]
+    out = _compile_for_v5e(driver)
+    got = out["decode"]
+    assert got["latent_decode_calls"] == layers, got
+    assert got["latent_decode_paths"] == [
+        "jit(decode)/attn/attn_mla/mla_attend/hvd_latent_decode"], got
+    assert got["gathered_key_blocks"] == 0, got
+    assert got["scores_of_a_decode_key_block"] == 0, got
+    assert got["aliased"] == large, got
+    if shapes == "kimi":
+        assert set(got["pool_ops"]) <= {
+            "parameter", "get-tuple-element", "bitcast", "fusion",
+            "scatter", "dynamic-update-slice"}, got
+    assert got["temp_bytes"] < 0.25e9 < out["pool_bytes"], got
 
 
 def test_the_trained_share_runs_its_bound_s_rows_outside_the_fall_back():

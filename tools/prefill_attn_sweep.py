@@ -23,6 +23,19 @@ scores in HBM: all ``_mla_attend`` had before ISSUE 44), of
 positions) at each ``--tiles`` pair and ``--key-block``, and of the
 kernel alone over one expanded key block; and the largest difference of
 the two forms relative to the largest magnitude.
+
+``--latent-decode`` sweeps the mla layers' decode step (ISSUE 45): one
+query a row at ``--heads`` heads and ``--rows`` rows over a latent pool
+``[1, rows * 1088 + 1, 16, 640]`` behind tables of 1088 pages in
+shuffled order, the rows' lengths ``even`` (8192 each), ``spread``
+(4096 to 16 384: the Kimi cell's snapshots) or ``tail`` (one of 16 384
+among rows of 256 to 3072: the Ling cell's reasoning requests). ms a
+layer of the XLA form (``_mla_attend``, ``absorbed=True``, over
+``mla_pages`` to the longest row: all a decode step had before ISSUE
+45), of ``_mla_decode`` as it is (the two absorbed products around
+``ops/latent_decode.py``'s kernel) at each ``--key-blocks``, and of the
+kernel alone with the GB/s of the pages it reads; and the largest
+difference of the two forms relative to the largest magnitude.
 """
 
 import argparse
@@ -36,12 +49,14 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
+import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
 from horovod_tpu.models import TransformerConfig  # noqa: E402
 from horovod_tpu.models import transformer as tf_lib  # noqa: E402
 from horovod_tpu.ops import flash_attention as flash_lib  # noqa: E402
+from horovod_tpu.ops import latent_decode as latent_lib  # noqa: E402
 from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from horovod_tpu.parallel.ring_attention import local_attention  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
@@ -229,10 +244,107 @@ def latent_sweep(args) -> None:
                 flush=True)
 
 
+def latent_decode_sweep(args) -> None:
+    LATENT, RANK, ROPE, DH, PAGE, WIDTH = 640, 512, 64, 128, 16, 1088
+    rng = np.random.default_rng(0)
+    default_wave = latent_lib._wave_pages
+    for heads in args.heads:
+        cfg = TransformerConfig(
+            vocab_size=128, d_model=128, n_layers=1, n_heads=heads,
+            n_kv_heads=heads, d_head=DH, d_ff=128, layer_types=("mla",),
+            mla_kv_rank=RANK, mla_rope_dim=ROPE, dtype=jnp.bfloat16)
+        ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+        lp = {"w_ukv": (jax.random.normal(ks[0], (RANK, heads * 2 * DH))
+                        * RANK ** -0.5).astype(jnp.bfloat16)}
+        for rows in args.rows:
+            n_pages = rows * WIDTH + 1
+            pool = jax.jit(lambda k: jnp.pad(jax.random.normal(
+                k, (1, n_pages, PAGE, RANK + ROPE), jnp.bfloat16),
+                ((0, 0),) * 3 + ((0, LATENT - RANK - ROPE),)))(ks[1])
+            tables = jnp.asarray(1 + rng.permutation(rows * WIDTH).reshape(
+                rows, WIDTH), jnp.int32)
+            qn = jax.random.normal(ks[2], (rows, 1, heads, DH), jnp.bfloat16)
+            qr = jax.random.normal(ks[3], (rows, 1, heads, ROPE),
+                                   jnp.bfloat16)
+            for lengths in args.lengths:
+                n = {"even": np.full(rows, 8192),
+                     "spread": rng.integers(4096, 16385, rows),
+                     "tail": np.concatenate([[16384], rng.integers(
+                         256, 3073, rows - 1)])}[lengths]
+                positions = jnp.asarray(n - 1, jnp.int32)
+                pages_read = int(np.sum(-(-n // PAGE)))
+                row = {"heads": heads, "rows": rows, "lengths": lengths,
+                       "positions": int(n.sum()), "longest": int(n.max())}
+
+                def chained(form):
+                    @jax.jit
+                    def chain(qn, qr, lp, pool, tables, positions):
+                        return lax.scan(
+                            lambda q, _: (form(q, qr, lp, pool, tables,
+                                               positions), None),
+                            qn, None, length=LATENT_LAYERS)[0]
+                    return chain, (qn, qr, lp, pool, tables, positions)
+
+                def xla(qn, qr, lp, pool, tables, positions):
+                    keys_of, blocks_to = decode_lib.mla_pages(
+                        pool, 0, tables, decode_lib._MLA_KEY_BLOCK)
+                    return decode_lib._mla_attend(
+                        cfg, lp, qn, qr, keys_of, blocks_to(positions.max()),
+                        positions[:, None], absorbed=True)
+
+                def kernel(qn, qr, lp, pool, tables, positions):
+                    return decode_lib._mla_decode(cfg, lp, qn, qr, pool, 0,
+                                                  tables, positions)
+
+                chain, xs = chained(xla)
+                row["xla"] = round(median_ms(chain, xs, args.reps)
+                                   / LATENT_LAYERS, 4)
+                want = chain(*xs).astype(jnp.float32)
+                for kb in args.key_blocks or [None]:
+                    name = "" if kb is None else f"_kb{kb}"
+                    latent_lib._wave_pages = (
+                        default_wave if kb is None
+                        else lambda page, kb=kb: kb // page)
+                    chain, xs = chained(kernel)
+                    try:
+                        row["decode" + name] = round(median_ms(
+                            chain, xs, args.reps) / LATENT_LAYERS, 4)
+                    except Exception as e:   # a buffer Mosaic refuses
+                        row["decode" + name] = f"refused: {str(e)[:80]}"
+                        continue
+                    got = chain(*xs).astype(jnp.float32)
+                    row["rel_err" + name] = round(float(
+                        jnp.max(jnp.abs(got - want))
+                        / jnp.max(jnp.abs(want))), 5)
+                    q = jnp.zeros((rows, heads, LATENT), jnp.bfloat16)
+
+                    @jax.jit
+                    def alone(q, pool, tables, positions):
+                        return lax.scan(
+                            lambda q, _: (jnp.pad(latent_lib.latent_decode(
+                                q, pool, 0, tables, positions + 1, rank=RANK,
+                                scale=0.07), ((0, 0), (0, 0),
+                                              (0, LATENT - RANK))), None),
+                            q, None, length=LATENT_LAYERS)[0]
+                    ms = median_ms(alone, (q, pool, tables, positions),
+                                   args.reps) / LATENT_LAYERS
+                    row["alone" + name] = round(ms, 4)
+                    row["alone_gb_s" + name] = round(
+                        pages_read * PAGE * LATENT * 2 / ms / 1e6, 1)
+                latent_lib._wave_pages = default_wave
+                print(json.dumps(row), flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--latent", action="store_true",
                     help="the mla layers' chunk instead of the prompt")
+    ap.add_argument("--latent-decode", action="store_true",
+                    help="the mla layers' decode step instead")
+    ap.add_argument("--rows", type=int, nargs="+", default=[32, 64])
+    ap.add_argument("--lengths", nargs="+",
+                    default=["even", "spread", "tail"],
+                    choices=["even", "spread", "tail"])
     ap.add_argument("--heads", type=int, nargs="+", default=[64, 32])
     ap.add_argument("--chunks", type=int, nargs="+",
                     default=[256, 512, 768, 1024])
@@ -253,6 +365,8 @@ def main() -> None:
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
     if args.latent:
         return latent_sweep(args)
+    if args.latent_decode:
+        return latent_decode_sweep(args)
     for t in args.buckets:
         keys = jax.random.split(jax.random.PRNGKey(t), 3)
         q, k, v = (
