@@ -4,10 +4,10 @@
 One process, over whatever ``jax.devices()`` offers, through the entry
 points a user calls (``build_mesh``, ``make_train_step``,
 ``ServeEngine``, ``hvd.init``), at the full width of ``transformer_std``
-(the decoder ``bench.py`` measures; d=2048, 8 layers, 16/8 heads,
-d_ff 8192, vocab 8192, seq 1024, bf16, flash attention) with random
-weights from a seed. Four phases, none optional; an exception in any
-of them ends the run non-zero and prints no result:
+(d=2048, 8 layers, 16/8 heads, d_ff 8192, vocab 8192, seq 1024, bf16,
+flash attention) with random weights from a seed. Four phases, none
+optional; an exception in any of them ends the run non-zero and prints
+no result:
 
 * trainer — ``make_train_step`` on ``build_mesh(dp=-1)`` (one chip) or
   ``build_mesh(dp=2, fsdp=2)`` (four), 8 rows per chip: loss finite and
@@ -108,6 +108,20 @@ def check_state_sharded(state, cfg, mesh) -> int:
     return checked
 
 
+def transformer_std_config():
+    """``transformer_std``: a standard-proportioned 8-layer d=2048 GQA
+    decoder, flash attention with sequence-spanning tiles, remat off,
+    layer scan unrolled."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=8192, d_model=2048, n_layers=8, n_heads=16,
+        n_kv_heads=8, d_ff=8192, max_seq=1024, dtype=jnp.bfloat16,
+        sp_attention="flash", remat=False, scan_unroll=8)
+
+
 def phase_trainer(cfg, mesh, *, rows_per_chip: int = 8, seq: int = 1024,
                   steps: int = 6, on_chip: bool = True) -> dict:
     import jax
@@ -118,9 +132,9 @@ def phase_trainer(cfg, mesh, *, rows_per_chip: int = 8, seq: int = 1024,
 
     n = mesh.devices.size
     init_state, step, _ = make_train_step(cfg, mesh)
-    # bench.py's and examples/lm_pretrain.py's spelling: an outer jit
-    # used to drop the init's in-trace device_put and replicate the
-    # state; the factory now pins the layout whatever the spelling.
+    # examples/lm_pretrain.py's spelling: an outer jit used to drop the
+    # init's in-trace device_put and replicate the state; the factory
+    # now pins the layout whatever the spelling.
     state = jax.jit(init_state)(jax.random.PRNGKey(0))
     n_sharded = check_state_sharded(state, cfg, mesh)
 
@@ -359,7 +373,6 @@ def main() -> int:
          cache_entries_at_start=(len(os.listdir(cache_dir))
                                  if os.path.isdir(cache_dir) else 0))
 
-    from bench import transformer_std_config
     from horovod_tpu.parallel import build_mesh
 
     if len(devices) == 1:

@@ -323,8 +323,7 @@ def _declare_abi(lib: ctypes.CDLL, path: str) -> ctypes.CDLL:
     # Vectored-transport surface (ABI v8, docs/perf_tuning.md
     # zero-copy transport): real SendV/RecvV/frame paths over
     # caller-owned fds — the socketpair unit-test surface
-    # (tests/test_transport.py) plus the resolved-mode probes bench.py
-    # reports alongside the busbw arms.
+    # (tests/test_transport.py) plus the resolved-mode probes.
     lib.hvd_tcp_sendv.restype = ctypes.c_int
     lib.hvd_tcp_sendv.argtypes = [ctypes.c_int,
                                   ctypes.POINTER(ctypes.c_void_p),
@@ -390,8 +389,8 @@ def _declare_abi(lib: ctypes.CDLL, path: str) -> ctypes.CDLL:
                                         ctypes.c_int64, ctypes.c_void_p]
     # Schedule-interpreter surface (docs/perf_tuning.md "Collective
     # algorithm selection"): chunk-op table builder + the default
-    # selection table, both pure functions — the simulator tests and
-    # bench.py's table dump drive them without spawning ranks.
+    # selection table, both pure functions — the simulator tests
+    # drive them without spawning ranks.
     lib.hvd_build_schedule.restype = ctypes.c_int
     lib.hvd_build_schedule.argtypes = [
         ctypes.c_int, ctypes.c_int, ctypes.c_int,

@@ -6,8 +6,7 @@ the native registry's versioned packed snapshot (``hvd_metrics_snapshot``,
 ``native/include/hvd/metrics.h``) and renders it three ways —
 
 * :func:`metrics` — flat dict of counters, gauges, and per-histogram
-  count/sum/p50/p99 (what ``bench.py`` derives its efficiency keys
-  from);
+  count/sum/p50/p99;
 * :func:`metrics_prometheus` — Prometheus text exposition, including
   any registered secondary exporter (the serving engine registers its
   :class:`~horovod_tpu.serve.metrics.ServeMetrics` here, so training
@@ -130,7 +129,7 @@ def metrics() -> Dict[str, float]:
 
 def metrics_reset() -> None:
     """Zero every counter and histogram (e.g. to scope a measurement
-    window, the way ``bench.py`` baselines its telemetry keys)."""
+    window)."""
     _lib().hvd_metrics_reset()
 
 

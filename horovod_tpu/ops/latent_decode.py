@@ -6,8 +6,8 @@ latents of that row's own pages, read once out of the pool in HBM, a
 key block at a time into VMEM, and no further than the row's length.
 The keys are the values (one latent a position: all of it scored, its
 first ``rank`` summed), so a page crosses the memory once for both
-dots. The XLA form it replaces (``serve/decode.py::_mla_attend``,
-``absorbed=True``) is the tests' reference.
+dots. The XLA form it replaces (``tests/reference_mla.py``) is the
+tests' reference.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ def _wave_pages(block_size: int) -> int:
     pages ``[16, 640]`` behind shuffled tables of 1088; ms a layer of
     the kernel alone and the GB/s of the pages it reads, at key blocks
     of 512 / **1024** / 2048 positions; ``xla`` is the form it replaced
-    with the two absorbed products, ``_mla_attend(absorbed=True)`` over
+    with the two absorbed products, ``tests/reference_mla.py`` over
     ``mla_pages`` to the longest row):
 
     == ==== ================== ==== ====================== ===============

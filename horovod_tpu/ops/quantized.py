@@ -55,7 +55,6 @@ telescoping identity pinned in tests/test_quantized.py).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
@@ -427,20 +426,3 @@ def quantized_alltoall(x, axis_name: str = "ep", *, codec: str,
             f"cannot quantize dtype {x.dtype}; compression applies to "
             "float activations")
     return _qa2a(x, axis_name, codec, bwd)
-
-
-def alltoall_wire_bytes(shape, codec: str, *, elem_bytes: int = 4) -> int:
-    """Bytes one :func:`quantized_alltoall` of a ``shape``-shaped f32
-    payload puts on the wire (all P slabs, scales included) — the
-    static accounting behind bench.py's ``moe_dispatch_bytes_saved_pct``
-    (int8 ships ~1/3.94 of the f32 bytes once a slab spans a few
-    blocks; tiny slabs amortize worse because the last block pads)."""
-    _check_codec(codec)
-    n = math.prod(shape)
-    if codec == "none":
-        return n * elem_bytes
-    if codec in _CAST_WIRE:
-        return n * 2
-    per_slab = math.prod(shape[1:])
-    nb = int8_blocks(per_slab)
-    return shape[0] * nb * (INT8_BLOCK_ELEMS + 4)

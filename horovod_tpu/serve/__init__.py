@@ -74,12 +74,8 @@ from horovod_tpu.serve.rpc import (  # noqa: F401
     connect_worker,
     spawn_worker,
 )
-from horovod_tpu.serve.bench import (  # noqa: F401
+from horovod_tpu.serve.traces import (  # noqa: F401
     make_multi_tenant_trace,
     make_shared_prefix_trace,
     make_trace,
-    run_prefix_benchmark,
-    run_router_benchmark,
-    run_serving_benchmark,
-    run_spec_benchmark,
 )

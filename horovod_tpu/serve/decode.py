@@ -89,8 +89,8 @@ too. A decode step's attend ABSORBED (:func:`_mla_decode`), each row
 over its own pages where they lie in the pool, through a Pallas kernel
 of its own (``ops/latent_decode.py``, ``hvd_latent_decode``): no key
 block is gathered and no score reaches HBM. The absorbed form in XLA
-(:func:`_mla_attend`, ``absorbed=True``) is what the tests hold both
-to. Nothing chooses between the two but which program calls.
+(``tests/reference_mla.py``) is what the tests hold both to. Nothing
+chooses between the two but which program calls.
 """
 
 from __future__ import annotations
@@ -251,9 +251,8 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
     ``inject`` and no ``verify``.
 
     Memoized: engines sharing (cfg, mesh, block geometry, compression)
-    — e.g. the benchmark's continuous and static schedulers, or a
-    fleet of per-tenant engines — reuse one pair of jit closures and
-    therefore one compiled program per shape bucket."""
+    — e.g. a fleet of per-tenant engines — reuse one pair of jit
+    closures and therefore one compiled program per shape bucket."""
     sigmoid_share = cfg.moe is not None and cfg.moe.scoring == "sigmoid"
     unserved = [what for what, there in (
         ("qk_norm", cfg.qk_norm),
@@ -701,7 +700,7 @@ def kda_step(q, k, v, g, beta, state):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
-def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
+def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
     """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
     and ``qr`` [B, C, H, R] (rotated) at positions ``pos`` [B, C] over
     ``n_blocks`` (traced, at least 1) blocks of cached latents, a block
@@ -710,86 +709,51 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, absorbed: bool):
     key_pos [K])`` gives block j; a key is seen where ``key_pos <=
     pos``. Float32 scores, softmax and accumulators over operands in the
     latents' dtype, ``p`` rounded to it for the value sum. Returns
-    [B, C, H, Dh]. One function in two forms, chosen by the caller:
+    [B, C, H, Dh].
 
-    **Expanded** (a chunk's queries: many a sequence): a block's
-    latents are expanded to every head's key ``[c W_uk | r]`` and value
-    ``c W_uv`` and attended by the Pallas flash forward over keys that
-    carry their positions (``ops/flash_attention.py::
-    flash_attention_keys``, ``hvd_flash_keys_fwd`` in a device trace):
-    one contraction of ``Dh + R`` a score, scores, mask and running
-    softmax a tile at a time in VMEM, so that no ``[H, C, K]`` tensor
-    reaches HBM at any chunk width, and the running
-    softmax carried from block to block through the kernel. Every
-    chunk width goes this way (``_MLA_CHUNK_BLOCKS`` has the chip's
-    times beside the einsum form's that it replaced).
-    **Absorbed** (one query a sequence): ``q W_uk^T`` is scored
-    against the latent itself and the latent is summed, then expanded
-    once (``(sum p c) W_uv``), in XLA with a running softmax over the
-    blocks: the same function, with no ``[K, H, Dh]`` key or value a
-    position. No serve program runs it: a decode step's attention is
-    :func:`_mla_decode`, the same arithmetic in a kernel that reads the
-    pool where it lies. It is the tests' reference for both, the
-    expanded form and that kernel.
+    The form is the **expanded** one (a chunk's queries: many a
+    sequence): a block's latents are expanded to every head's key
+    ``[c W_uk | r]`` and value ``c W_uv`` and attended by the Pallas
+    flash forward over keys that carry their positions
+    (``ops/flash_attention.py::flash_attention_keys``,
+    ``hvd_flash_keys_fwd`` in a device trace): one contraction of
+    ``Dh + R`` a score, scores, mask and running softmax a tile at a
+    time in VMEM, so that no ``[H, C, K]`` tensor reaches HBM at any
+    chunk width, and the running softmax carried from block to block
+    through the kernel. Every chunk width goes this way
+    (``_MLA_CHUNK_BLOCKS`` has the chip's times beside the einsum
+    form's that it replaced). A decode step's attention is
+    :func:`_mla_decode`; the tests hold both to the absorbed form in
+    XLA (``tests/reference_mla.py``).
 
     A query that sees no key (a position below every key's: none a
-    program sends) reads zeros expanded and a mean of the keys
-    absorbed."""
+    program sends) reads zeros."""
     B, C, H, Dh = qn.shape
     rank, R = cfg.mla_kv_rank, cfg.mla_rope_dim
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
     scale = tf_lib.mla_scale(cfg)
-    if not absorbed:
-        q = jnp.moveaxis(jnp.concatenate([qn, qr], -1), 2, 1).reshape(
-            B * H, C, Dh + R)
+    q = jnp.moveaxis(jnp.concatenate([qn, qr], -1), 2, 1).reshape(
+        B * H, C, Dh + R)
 
-        def attend(j, seen):
-            latent, key_pos = keys_of(j)
-            K = latent.shape[1]
-            with jax.named_scope("mla_expand"):
-                c, r = latent[..., :rank], latent[..., rank:rank + R]
-                keys = jnp.concatenate(
-                    [jnp.einsum("bkc,chd->bhkd", c, w_uk),
-                     jnp.broadcast_to(r[:, None], (B, H, K, R))], -1)
-                vals = jnp.einsum("bkc,chd->bhkd", c, w_uv)
-            return flash_attention_keys(
-                q, keys.reshape(B * H, K, Dh + R), vals.reshape(B * H, K, Dh),
-                pos, jnp.broadcast_to(key_pos[None], (B, K)), scale=scale,
-                carry=seen)
-
-        o, _ = lax.fori_loop(
-            0, n_blocks, attend,
-            (jnp.zeros((B * H, C, Dh), jnp.float32),
-             jnp.full((B * H, C), _NEG_BIG, jnp.float32)))
-        return jnp.moveaxis(o.reshape(B, H, C, Dh), 1, 2).astype(qn.dtype)
-
-    qn = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
-
-    def block(j, carry):
-        m, l, acc = carry
+    def attend(j, seen):
         latent, key_pos = keys_of(j)
-        c, r = latent[..., :rank], latent[..., rank:rank + R]
-        s = (jnp.einsum("bqhc,bkc->bhqk", qn, c,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhr,bkr->bhqk", qr, r,
-                          preferred_element_type=jnp.float32)) * scale
-        seen = key_pos[None, None, :] <= pos[:, :, None]     # [B, C, K]
-        s = jnp.where(seen[:, None], s, _NEG_BIG)
-        m_new = jnp.maximum(m, s.max(-1))
-        p = jnp.exp(s - m_new[..., None])
-        fade = jnp.exp(m - m_new)
-        acc = acc * fade[..., None] + jnp.einsum(
-            "bhqk,bkd->bhqd", p.astype(c.dtype), c,       # every head's
-            preferred_element_type=jnp.float32)
-        return m_new, l * fade + p.sum(-1), acc
+        K = latent.shape[1]
+        with jax.named_scope("mla_expand"):
+            c, r = latent[..., :rank], latent[..., rank:rank + R]
+            keys = jnp.concatenate(
+                [jnp.einsum("bkc,chd->bhkd", c, w_uk),
+                 jnp.broadcast_to(r[:, None], (B, H, K, R))], -1)
+            vals = jnp.einsum("bkc,chd->bhkd", c, w_uv)
+        return flash_attention_keys(
+            q, keys.reshape(B * H, K, Dh + R), vals.reshape(B * H, K, Dh),
+            pos, jnp.broadcast_to(key_pos[None], (B, K)), scale=scale,
+            carry=seen)
 
-    m, l, acc = lax.fori_loop(
-        0, n_blocks, block,
-        (jnp.full((B, H, C), _NEG_BIG, jnp.float32),
-         jnp.zeros((B, H, C), jnp.float32),
-         jnp.zeros((B, H, C, rank), jnp.float32)))
-    o = jnp.moveaxis(acc / l[..., None], 1, 2).astype(qn.dtype)
-    return jnp.einsum("bqhc,chd->bqhd", o, w_uv)
+    o, _ = lax.fori_loop(
+        0, n_blocks, attend,
+        (jnp.zeros((B * H, C, Dh), jnp.float32),
+         jnp.full((B * H, C), _NEG_BIG, jnp.float32)))
+    return jnp.moveaxis(o.reshape(B, H, C, Dh), 1, 2).astype(qn.dtype)
 
 
 def mla_pages(pool, c, tables, key_block: int):
@@ -819,16 +783,15 @@ def mla_pages(pool, c, tables, key_block: int):
 
 
 def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions):
-    """A decode step's latent attention: the absorbed form of
-    :func:`_mla_attend` for one query a row (``qn`` [B, 1, H, Dh],
-    ``qr`` [B, 1, H, R]) at ``positions`` [B], over the pages of layer
-    ``c`` of the latent ``pool`` behind ``tables`` [B, W], read where
-    they lie by ``ops/latent_decode.py`` (``hvd_latent_decode`` in a
-    device trace): each row's own pages, once, no further than its
-    position, with no gathered copy of a key block and no score tensor
-    in HBM. The two small products stay in XLA around the call:
-    ``q W_uk^T`` before it and ``(sum p c) W_uv`` after it. Returns
-    [B, 1, H, Dh]."""
+    """A decode step's latent attention, absorbed, for one query a row
+    (``qn`` [B, 1, H, Dh], ``qr`` [B, 1, H, R]) at ``positions`` [B],
+    over the pages of layer ``c`` of the latent ``pool`` behind
+    ``tables`` [B, W], read where they lie by ``ops/latent_decode.py``
+    (``hvd_latent_decode`` in a device trace): each row's own pages,
+    once, no further than its position, with no gathered copy of a key
+    block and no score tensor in HBM. The two small products stay in
+    XLA around the call: ``q W_uk^T`` before it and ``(sum p c) W_uv``
+    after it. Returns [B, 1, H, Dh]."""
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
     q = jnp.concatenate([jnp.einsum("bqhd,chd->bqhc", qn, w_uk), qr], -1)
     q = jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
@@ -1013,13 +976,12 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                     # every row's keys are its own prompt's, one block
                     o = _mla_attend(
                         cfg, lp, qn, qr, lambda j: (new, call.pos[0]), 1,
-                        call.pos, absorbed=False)
+                        call.pos)
                 else:
                     keys_of, blocks_to = mla_pages(
                         kc[n], c, call.table[None], chunk_key_block)
                     o = _mla_attend(cfg, lp, qn, qr, keys_of,
-                                    blocks_to(call.pos[0, -1]), call.pos,
-                                    absorbed=False)
+                                    blocks_to(call.pos[0, -1]), call.pos)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
     # -- a decode step of the batch (one position a row) -------------

@@ -133,10 +133,6 @@ class ServeConfig:
     # compiles; more buckets = less padding waste.
     batch_buckets: Optional[Tuple[int, ...]] = None
     prefill_buckets: Optional[Tuple[int, ...]] = None
-    # "continuous": iteration-level admission (the point of this
-    # engine). "static": admit only into an empty batch — the
-    # classical serve loop, kept as the benchmark baseline.
-    scheduling: str = "continuous"
     cache_dtype: Any = None      # default: model dtype
     # Map whole-block prompt prefixes out of the content-addressed
     # block cache instead of recomputing them (hit rate shows up in
@@ -393,8 +389,6 @@ class ServeEngine:
                  clock=time.perf_counter,
                  instance: Optional[str] = None):
         cfg = serve_cfg or ServeConfig()
-        if cfg.scheduling not in ("continuous", "static"):
-            raise ValueError(f"unknown scheduling {cfg.scheduling!r}")
         if (cfg.draft is None) != (cfg.spec_k == 0) or cfg.spec_k < 0:
             raise ValueError(
                 f"draft= and spec_k= go together (draft="
@@ -811,15 +805,10 @@ class ServeEngine:
         return n_expired
 
     def _admit(self, now: float) -> int:
-        batch_was_empty = not self._active and not self._prefilling
         n_admitted = 0
         while (self._queue and
                len(self._active) + len(self._prefilling)
                < self.cfg.max_batch):
-            if self.cfg.scheduling == "static" and not batch_was_empty:
-                # Baseline scheduler: wait for the whole batch to
-                # drain before admitting again.
-                break
             req = self._queue[0]
             plen = len(req.prompt)
             # A prefill-only sequence never decodes here — it writes
@@ -1313,8 +1302,7 @@ class ServeEngine:
         leaving = sum(seq is not None for seq in fl.rows) - n
         if n == 0:
             self._drain("idle")
-        elif (leaving and self._queue
-              and self.cfg.scheduling == "continuous"):
+        elif leaving and self._queue:
             # A sequence ends at the call in flight and a request waits
             # for a slot: read now and launch nothing, so that the next
             # step retires, admits and prefills, and the newcomer is in

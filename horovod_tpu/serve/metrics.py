@@ -9,7 +9,7 @@ neither number alone shows it.
 Surfaces:
 
 * :meth:`ServeMetrics.snapshot` — counters + percentiles as a flat
-  dict (what ``bench.py`` folds into the round payload).
+  dict.
 * :meth:`ServeMetrics.export_chrome_trace` — per-step spans in the
   chrome-tracing JSON format, viewable in the same ``chrome://tracing``
   / Perfetto UI as the host timeline (``hvd.start_timeline`` /

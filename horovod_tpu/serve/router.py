@@ -23,8 +23,8 @@ backpressure — holds unchanged underneath):
   last routed, and scores candidates by the max of the live index
   walk and that routing hint, so the first request of a tenant
   CREATES the affinity its burst siblings follow. Random and
-  round-robin placements exist as benchmark baselines — `bench.py`'s
-  routed-vs-random comparison is the tentpole claim.
+  round-robin placements ignore the cache: what affinity is compared
+  with (``examples/serve_fleet.py``).
 * **Prefill/decode pools with KV handoff.** With
   ``RouterConfig.n_prefill > 0`` the fleet splits: prefill replicas
   run admission + (chunked) prefill only, then the router streams each
@@ -167,7 +167,7 @@ class RouterConfig:
     max_queue: int = 256
     # "affinity" (cache-aware, the point of this module) with
     # least-occupancy fallback; "least" = occupancy only;
-    # "random" / "round_robin" = benchmark baselines.
+    # "random" / "round_robin" = cache-blind, to compare with.
     placement: str = "affinity"
     seed: int = 0                # drives the random-placement baseline
     # -- cross-process fleet knobs (docs/serving.md) -----------------
@@ -1584,7 +1584,7 @@ class ServeRouter:
         """Release remote replicas without drain semantics: best-
         effort shutdown RPC to every worker, connections closed.
         In-process replicas need no teardown. Idempotent; the
-        cross-process bench/tests call it between cold fleets."""
+        cross-process tests call it between cold fleets."""
         for rep in self._replicas:
             if rep.remote:
                 rep.engine.shutdown()

@@ -381,7 +381,7 @@ class RpcConn:
         self.fd = sock.fileno()
         self.codec = span_codec_id(codec)
         self.alive = True
-        # Byte accounting (the bench's RPC-tax / bytes-saved keys).
+        # Byte accounting.
         self.msgs_sent = 0
         self.msgs_received = 0
         self.bytes_sent = 0

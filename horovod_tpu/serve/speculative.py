@@ -321,7 +321,7 @@ class SpecDecoder:
 
 
 # ---------------------------------------------------------------------------
-# Bench/test rig: a target that agrees with its draft by construction
+# Example/test rig: a target that agrees with its draft by construction
 # ---------------------------------------------------------------------------
 
 def make_draft_target_params(draft_cfg, n_layers: int, seed: int = 0,

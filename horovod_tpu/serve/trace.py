@@ -18,9 +18,7 @@ router-side half of the timeline:
   in [0, 1], default 1 = trace everything) decides per request,
   deterministically by rid hash — the same request traces or doesn't
   across reruns. An unsampled request carries trace id 0 and pays
-  nothing beyond the sampling test itself; the <2% overhead guard in
-  ``serve/bench.py`` (``serve_trace_overhead_pct``) pins the sampled
-  cost.
+  nothing beyond the sampling test itself.
 * **One timebase.** Every export carries a ``(clock_now, wall_now)``
   anchor pair in its metadata; remote workers additionally get the
   router's RTT-estimated ``clock_offset`` (heartbeat midpoints, the

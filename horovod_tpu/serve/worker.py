@@ -140,9 +140,9 @@ class ReplicaWorker:
                   kv_codec=0):
         """(Re)build the engine. A second configure replaces the
         engine with a fresh one (same process, same jit cache via the
-        ``make_serve_fns`` memo) — the bench's cold-fleet-per-pass
-        protocol without a respawn. ``kv_codec`` sets the span codec
-        for THIS side's replies (the export path's K/V pages)."""
+        ``make_serve_fns`` memo): a cold fleet without a respawn.
+        ``kv_codec`` sets the span codec for THIS side's replies (the
+        export path's K/V pages)."""
         self.engine = _build_engine(model_cfg, serve_cfg, int(seed),
                                     str(instance))
         self.conn.codec = int(kv_codec)

@@ -52,7 +52,7 @@ inline int64_t Int8Blocks(int64_t elems) {
 
 // Encoded byte count for `elems` float32 elements. NONE reports the
 // raw size (callers never ship NONE through the codec, but the ratio
-// math in bench/tests reads this).
+// math in tests reads this).
 int64_t WireEncodedBytes(WireCodec codec, int64_t elems);
 
 // Encode `elems` floats from src into dst (WireEncodedBytes bytes).

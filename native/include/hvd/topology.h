@@ -6,10 +6,9 @@
 // calibration sweep; the bench notes show this box swinging ±30% draw
 // to draw, so those bands are wrong on any other machine. This module
 // closes the loop: at startup (and on demand) every pair of ranks
-// ping-pongs over the EXISTING vectored TCP data connections —
-// bench.py's interleaved-rounds protocol internalized: small and large
-// payload iterations interleave so a scheduler phase shift lands on
-// both estimates, and each keeps its best round — producing a
+// ping-pongs over the EXISTING vectored TCP data connections: small
+// and large payload iterations interleave so a scheduler phase shift
+// lands on both estimates, and each keeps its best round — producing a
 // per-(src, dst) alpha (latency, us) + beta (us per byte) model. Rank
 // 0 gathers every rank's measured out-links and broadcasts the full
 // matrix, so every rank holds IDENTICAL numbers (the same lockstep

@@ -243,9 +243,9 @@ int64_t WireEncodedBytes(WireCodec codec, int64_t elems) {
 namespace {
 
 // Pre/post wire byte accounting for every encode site (plain and
-// relay-fused): wire_bytes_saved_pct in bench.py derives straight from
-// these two counters, so the reported savings are the bytes that
-// actually skipped the wire, not a ratio recomputed from assumptions.
+// relay-fused): a saving derived from these two counters is the bytes
+// that actually skipped the wire, not a ratio recomputed from
+// assumptions.
 inline void RecordEncodeMetrics(WireCodec codec, int64_t elems) {
   if (codec == WireCodec::NONE) return;
   MetricAdd(kCtrWireEncodes);

@@ -684,7 +684,7 @@ void BackgroundThreadLoop(GlobalState& st) {
     // HOROVOD_STEADY_LOCK=off reverts the WHOLE feature to the PR 14
     // loop — fixed sleep-to-budget, every cycle counted in cycle_us —
     // so `off` is behaviorally byte-identical to the pre-lock runtime
-    // (and the bench's off arm measures the real baseline).
+    // (and an off arm measures the real baseline).
     const bool event_driven =
         st.controller->steady_lock() != hvd::kSteadyLockOff;
     const bool empty_cycle =
@@ -1541,8 +1541,7 @@ int hvd_reduce_threads() { return hvd::HostReduceThreads(); }
 // Schedule-interpreter surface (hvd/schedule.h): the chunk-op tables
 // and the default selection table are pure functions, exposed so the
 // Python simulator tests can verify every generated schedule
-// (complete, deadlock-free, chunk-conserving) without spawning ranks,
-// and so bench.py can dump the live selection table.
+// (complete, deadlock-free, chunk-conserving) without spawning ranks.
 
 // Fills out[] with int32 quintets (step, peer, chunk, action, flags)
 // for rank position `pos` of `nranks`. Returns the op count (callers
@@ -1591,7 +1590,7 @@ int hvd_build_coll_schedule(int kind, int algo, int nranks, int pos,
 }
 
 // Default selection-table query (no controller state: callers pass the
-// synced inputs, so bench/tests can probe any (bytes, np, topology)
+// synced inputs, so tests can probe any (bytes, np, topology)
 // cell).
 int hvd_algo_select(int64_t bytes, int np, int hier_ok,
                     int64_t ring_threshold) {
@@ -1599,9 +1598,8 @@ int hvd_algo_select(int64_t bytes, int np, int hier_ok,
 }
 
 // Measured-model verdict for one (bytes, np) cell using THIS process's
-// broadcast topology model (bench.py's synthesized-table dump and the
-// audit comparison). Returns -1 when no model covers np — callers fall
-// back to hvd_algo_select's hand bands.
+// broadcast topology model (the audit comparison). Returns -1 when no
+// model covers np — callers fall back to hvd_algo_select's hand bands.
 int hvd_algo_select_measured(int64_t bytes, int np, int hier_ok,
                              int64_t ring_threshold) {
   auto& st = hvd::State();
@@ -1765,8 +1763,8 @@ int hvd_alltoall_algo() {
 }
 
 // Alpha-beta cost (us) of one alltoall family's P tables at TOTAL
-// exchanged bytes under the live model; <0 when no model. bench.py and
-// the selection tests use this to cross-check the measured verdict
+// exchanged bytes under the live model; <0 when no model. The
+// selection tests use this to cross-check the measured verdict
 // against the priced tables.
 double hvd_alltoall_cost_us(int algo, int64_t bytes) {
   auto& st = hvd::State();

@@ -670,7 +670,7 @@ bool TcpConn::SendWindow(struct iovec* win, int cnt, uint64_t bytes) {
   // ~40 ms on a completion-less kernel, and most processes (tier-1
   // spawns hundreds) never send a zerocopy-eligible span — they must
   // never pay it. Only large sends, or an explicit mode query
-  // (metrics gauge / bench), resolve the mode.
+  // (metrics gauge), resolve the mode.
   if (bytes >= kZcMinBytes && zc_ >= 0 &&
       ResolvedTransportMode() == kTransportZerocopy) {
     if (zc_ == 0) {

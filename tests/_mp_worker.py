@@ -28,6 +28,27 @@ import horovod_tpu as hvd  # noqa: E402
 from horovod_tpu.common.exceptions import HorovodInternalError  # noqa: E402
 
 
+def until_all_locked(name, width, fill, want=None, cap=200):
+    """Allreduce ``np.full(width, fill(i))`` under ``name`` until every
+    rank had the steady lock engaged BEFORE one and the same op: each
+    rank's reading rides the op's last element (their Sum is the size),
+    so all ranks leave at the same op and none reads the flag after a
+    peer may have moved on (its shutdown or next pattern unlocks). One
+    tensor of one shape throughout: the pattern that locks. Under load
+    the lock engages when it engages, not "by op 6". ``want(i)`` is
+    what the other elements must sum to."""
+    size = hvd.size()
+    for i in range(cap):
+        x = np.full(width, fill(i), np.float32)
+        x[-1] = float(hvd.steady_lock_engaged())
+        out = np.asarray(hvd.allreduce(x, op=hvd.Sum, name=name))
+        if want is not None:
+            np.testing.assert_allclose(out[:-1], want(i), rtol=1e-6)
+        if out[-1] == size:
+            return
+    raise AssertionError(f"lock never engaged on every rank in {cap} ops")
+
+
 def main():
     scenario = sys.argv[1]
     if scenario == "xla_rank_order":
@@ -1530,15 +1551,41 @@ def main():
         # negotiated cycle that carries the global shutdown bit, and
         # the job exits cleanly — without the unlock path the final
         # handshake would never run and shutdown would hang.
-        for i in range(8):  # fixed count: engaged by op 6 (see lock_steady)
-            hvd.allreduce(np.full(4, 1.0, np.float32), op=hvd.Sum,
-                          name="lkd")
-        assert hvd.steady_lock_engaged(), "lock never engaged"
+        until_all_locked("lkd", 4, lambda i: 1.0)
         hvd.shutdown()
+        # A peer's shutdown unlocks this rank too, before or after its
+        # own: with the peer's cause where its token said so, as
+        # "peer" where only its closed link did.
         m = hvd.metrics()
-        assert m["ctrl_unlocks_shutdown_total"] >= 1, m
+        assert (m["ctrl_unlocks_shutdown_total"]
+                + m["ctrl_unlocks_peer_total"]) >= 1, m
         print(f"OK rank={r}")
         return  # already shut down
+
+    elif scenario == "duplicate_name":
+        # A name still in flight is refused at the enqueue (reference
+        # common.h:169-172). Rank 0's first "dup" is held in flight by
+        # rank 1, which contributes to it only after "go", and "go"
+        # needs rank 0, which joins it after its second "dup" was
+        # refused: the two enqueues overlap however the ranks are
+        # scheduled.
+        if r == 0:
+            h1 = hvd.allreduce_async(np.ones(8, np.float32), name="dup",
+                                     op=hvd.Sum)
+            try:
+                hvd.allreduce_async(np.ones(8, np.float32), name="dup",
+                                    op=hvd.Sum)
+                raise SystemExit("the duplicate enqueue was accepted")
+            except HorovodInternalError as e:
+                assert "uplicate" in str(e), e
+            hvd.allreduce(np.ones(1, np.float32), name="go", op=hvd.Sum)
+            out = hvd.synchronize(h1)
+        else:
+            hvd.allreduce(np.ones(1, np.float32), name="go", op=hvd.Sum)
+            out = hvd.allreduce(np.ones(8, np.float32), name="dup",
+                                op=hvd.Sum)
+        np.testing.assert_allclose(out, float(s))
+        print(f"OK rank={r}")
 
     elif scenario == "lock_autotune":
         # Staged-tunables trigger: with the autotuner live (tiny
@@ -1638,12 +1685,8 @@ def main():
         # metrics, same values.
         tcp_plane = os.environ.get("HOROVOD_SHM_DISABLE") == "1"
         knob_off = os.environ.get("HOROVOD_STEADY_PERSISTENT") == "off"
-        for i in range(7):  # fixed count: engaged by op 6 (lock_steady)
-            out = hvd.allreduce(np.full(8, float(r + i), np.float32),
-                                op=hvd.Sum, name="lp")
-            np.testing.assert_allclose(
-                out, float(s * i) + s * (s - 1) / 2.0, rtol=1e-6)
-        assert hvd.steady_lock_engaged(), "lock never engaged"
+        until_all_locked("lp", 8, lambda i: float(r + i),
+                         lambda i: float(s * i) + s * (s - 1) / 2.0)
         for i in range(10):
             if i == 9:
                 # The gauges, read BEFORE the last op: a faster peer is
@@ -1681,15 +1724,11 @@ def main():
         assert not hvd.steady_lock_engaged()
         assert hvd.metrics()["tcp_prepost_buffers"] == 0
         p0 = hvd.metrics()["ctrl_persistent_fires_total"]
-        for i in range(11):
-            out = hvd.allreduce(np.full(3, float(r), np.float32),
-                                op=hvd.Sum, name="lp")
-            np.testing.assert_allclose(out, s * (s - 1) / 2.0, rtol=1e-6)
-        # Asserted BEFORE the last op: a faster peer's exit-time
-        # shutdown unlock races a post-loop flag read (near-instantly
-        # over the cells), but no peer can exit before this rank fires
-        # the final slot.
-        assert hvd.steady_lock_engaged(), "no re-lock"
+        until_all_locked("lp", 3, lambda i: float(r),
+                         lambda i: s * (s - 1) / 2.0)
+        # No flag is read from here on (a faster peer's exit-time
+        # shutdown unlocks, near-instantly over the cells); the
+        # counter only grows. The op the ranks agreed at fired locked.
         if not knob_off:
             assert hvd.metrics()["ctrl_persistent_fires_total"] > p0
         out = hvd.allreduce(np.full(3, float(r), np.float32),

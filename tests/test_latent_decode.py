@@ -1,6 +1,6 @@
 """A decode step's latent attention through ``hvd_latent_decode``
 (``ops/latent_decode.py``, interpret mode here) against the XLA form it
-replaced in ``mla_step``: ``_mla_attend(absorbed=True)`` over
+replaced in ``mla_step``: ``reference_mla.mla_attend_absorbed`` over
 ``mla_pages``, the same pool and the same tables (ISSUE 45)."""
 
 import jax
@@ -13,6 +13,7 @@ from horovod_tpu.ops import latent_decode as latent_lib
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import latent_row
 from horovod_tpu.serve.metrics import ServeMetrics
+from reference_mla import mla_attend_absorbed
 
 PAGE, WIDTH, WAVE = 16, 8, 2     # a key block of 32: up to four a row
 N_PAGES = 64
@@ -90,9 +91,9 @@ def test_the_kernel_is_the_absorbed_form_over_the_same_pages(
 
     keys_of, blocks_to = decode_lib.mla_pages(pool, layer, tables,
                                               WAVE * PAGE)
-    want = decode_lib._mla_attend(
+    want = mla_attend_absorbed(
         cfg, lp, qn, qr, keys_of, blocks_to(positions.max()),
-        positions[:, None], absorbed=True)
+        positions[:, None])
     monkeypatch.setattr(latent_lib, "_wave_pages", lambda page: WAVE)
     got = jax.jit(lambda *a: decode_lib._mla_decode(cfg, lp, *a))(
         qn, qr, pool, jnp.int32(layer), tables, positions)

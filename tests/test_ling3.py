@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import reference_ling3 as ref
+from reference_mla import mla_attend_absorbed
 from horovod_tpu.models import (TransformerConfig, init_transformer,
                                 make_train_step)
 from horovod_tpu.models import moe as moe_lib
@@ -295,8 +296,8 @@ def test_absorbed_and_expanded_latent_attention_agree(queries):
         return (jax.lax.dynamic_slice_in_dim(latents, j * 32, 32, 1),
                 j * 32 + jnp.arange(32))
 
-    got = [decode_lib._mla_attend(cfg, lp, qn, qr, keys_of, 3, pos,
-                                  absorbed=how) for how in (True, False)]
+    got = [attend(cfg, lp, qn, qr, keys_of, 3, pos)
+           for attend in (mla_attend_absorbed, decode_lib._mla_attend)]
     assert gap(np.asarray(got[0]), np.asarray(got[1])) < 1e-5
 
 
@@ -346,9 +347,7 @@ def test_a_chunk_through_the_kernel_is_the_absorbed_form(
 
     got = np.asarray(logits())
     with pytest.MonkeyPatch.context() as patch:
-        attend = decode_lib._mla_attend
-        patch.setattr(decode_lib, "_mla_attend",
-                      lambda *a, absorbed: attend(*a, absorbed=True))
+        patch.setattr(decode_lib, "_mla_attend", mla_attend_absorbed)
         want = np.asarray(logits())
     assert got.shape == want.shape and gap(got, want) < 1e-5
 

@@ -909,7 +909,7 @@ def test_cross_process_fleet_parity_drain_and_kill(served_model):
     surviving worker; then, on a fresh pass over the surviving
     workers, a SIGKILLed worker's queued requests complete via requeue
     with no request resolved twice."""
-    from horovod_tpu.serve.bench import make_multi_tenant_trace
+    from horovod_tpu.serve.traces import make_multi_tenant_trace
     from horovod_tpu.serve.rpc import spawn_worker
 
     cfg, params = served_model

@@ -16,7 +16,7 @@ largest magnitude. How ``_attend_prompt`` came by ``_DENSE_PROMPT`` and
 ``--latent`` sweeps the mla layers' chunk instead (ISSUE 44): ``[1, C,
 H, 128 + 64]`` bf16 queries of a chunk that ends at key ``keys`` over
 latents ``[keys, 512 + 64]`` gathered and expanded a key block at a time
-(``serve/decode.py::_mla_attend``, ``absorbed=False``), at Kimi's 64 and
+(``serve/decode.py::_mla_attend``), at Kimi's 64 and
 Ling's 32 heads: ms a layer of the einsum form (float32 ``[H, C, block]``
 scores in HBM: all ``_mla_attend`` had before ISSUE 44), of
 ``_mla_attend`` as it is (the Pallas forward over keys that carry their
@@ -30,7 +30,7 @@ query a row at ``--heads`` heads and ``--rows`` rows over a latent pool
 shuffled order, the rows' lengths ``even`` (8192 each), ``spread``
 (4096 to 16 384: the Kimi cell's snapshots) or ``tail`` (one of 16 384
 among rows of 256 to 3072: the Ling cell's reasoning requests). ms a
-layer of the XLA form (``_mla_attend``, ``absorbed=True``, over
+layer of the XLA form (``tests/reference_mla.py`` over
 ``mla_pages`` to the longest row: all a decode step had before ISSUE
 45), of ``_mla_decode`` as it is (the two absorbed products around
 ``ops/latent_decode.py``'s kernel) at each ``--key-blocks``, and of the
@@ -39,14 +39,14 @@ difference of the two forms relative to the largest magnitude.
 """
 
 import argparse
-import functools
 import json
 import os
 import statistics
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -61,6 +61,7 @@ from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from horovod_tpu.parallel.ring_attention import local_attention  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 from horovod_tpu.serve.decode import _attend_prompt  # noqa: E402
+from reference_mla import mla_attend_absorbed  # noqa: E402
 
 LAYERS = 16
 LATENT_LAYERS = 6      # the Kimi cell's depth
@@ -182,8 +183,6 @@ def latent_sweep(args) -> None:
                 row["einsum"] = round(
                     median_ms(chain, xs, args.reps) / LATENT_LAYERS, 4)
                 want = chain(*xs).astype(jnp.float32)
-                attend = functools.partial(decode_lib._mla_attend,
-                                           absorbed=False)
                 kernel = flash_lib.flash_attention_keys
 
                 def merged_outside(*a, carry=None, **kw):
@@ -205,7 +204,7 @@ def latent_sweep(args) -> None:
                         flash_lib._keys_blocks = (
                             default_tiles if bq is None
                             else lambda c_, k_: (bq, bk))
-                        chain, xs = chained(attend, kb)
+                        chain, xs = chained(decode_lib._mla_attend, kb)
                         name = f"kernel_kb{kb}" + (
                             "" if bq is None else f"_{bq}x{bk}")
                         try:
@@ -288,9 +287,9 @@ def latent_decode_sweep(args) -> None:
                 def xla(qn, qr, lp, pool, tables, positions):
                     keys_of, blocks_to = decode_lib.mla_pages(
                         pool, 0, tables, decode_lib._MLA_KEY_BLOCK)
-                    return decode_lib._mla_attend(
+                    return mla_attend_absorbed(
                         cfg, lp, qn, qr, keys_of, blocks_to(positions.max()),
-                        positions[:, None], absorbed=True)
+                        positions[:, None])
 
                 def kernel(qn, qr, lp, pool, tables, positions):
                     return decode_lib._mla_decode(cfg, lp, qn, qr, pool, 0,
