@@ -87,7 +87,7 @@ class Rotary:
 
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
-LAYER_KINDS = ("sliding", "full", "kda", "mla")
+LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,7 +163,8 @@ class TransformerConfig:
     # and params["layers"] (see `mixed`).
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
-    # One of "sliding" | "full" a layer. A sliding layer sees the keys
+    # One of the five LAYER_KINDS a layer ("sliding" | "full" here;
+    # "kda", "mla" and "mamba" below). A sliding layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
     # layer_rotary says otherwise. None: every layer is causal over
@@ -220,6 +221,28 @@ class TransformerConfig:
     mla_rope_dim: int = 0
     mla_q_rank: int = 0
     mla_head_gate: bool = True
+    # A fifth kind of layer_types (ISSUE 47; served, not trained):
+    # "mamba", Mamba-1's selective state-space layer: the normed input
+    # up to 2 * mamba_expand * d_model (u | z), u through a causal
+    # depthwise convolution of mamba_d_conv taps with a bias and SiLU,
+    # then (delta | B | C) of mamba_dt_rank | mamba_d_state |
+    # mamba_d_state values, each RMS-normed with a gain of its own,
+    # Delta = softplus(delta W_dt + b_dt), and the recurrence
+    # s_t = exp(Delta_t A) s_{t-1} + (Delta_t u_t) B_t over a float32
+    # state [mamba_expand * d_model, mamba_d_state] a sequence,
+    # y_t = s_t C_t + D u_t, gated by SiLU(z). It reads no position.
+    # The full layers beside it may be multi-query (n_kv_heads <
+    # n_heads).
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # The head is the embedding's transpose: logits = x E^T, and the
+    # parameters hold no "lm_head" (a configuration with layer_types:
+    # forward_with_aux and decode.py's mixed_programs read
+    # head_weights; the programs of one stack of one block take a
+    # separate lm_head).
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -237,13 +260,27 @@ class TransformerConfig:
                                                   and self.mla_rope_dim):
                 raise ValueError("mla layers need mla_kv_rank and "
                                  "mla_rope_dim")
-            if self.stateful and (self.n_kv_heads != self.n_heads
+            if "mamba" in self.layer_types and not self.mamba_dt_rank:
+                raise ValueError("mamba layers need mamba_dt_rank")
+            # kda and mla layers project for n_heads heads; the full
+            # layers beside mamba layers alone keep their own n_kv_heads
+            own_heads = {"kda", "mla"} & set(self.layer_types)
+            if self.stateful and (own_heads
+                                  and self.n_kv_heads != self.n_heads
                                   or self.attn_gate or self.sandwich_norm
                                   or self.qk_norm or self.qk_norm_per_head):
                 raise ValueError(
                     "kda and mla layers have n_heads heads of their own "
                     "projections, norms and gates: n_kv_heads = n_heads, "
-                    "and no attn_gate, sandwich_norm or qk_norm")
+                    "and no attn_gate, sandwich_norm or qk_norm (in a "
+                    "stack with mamba layers either)")
+        if self.tie_embeddings and self.layer_types is None:
+            raise ValueError(
+                "tie_embeddings is read where a configuration has "
+                "layer_types (forward_with_aux's loop over layers, "
+                "decode.py's mixed_programs): the pipeline, the quantized "
+                "steps and the serve programs of one stack of one block "
+                "take a separate lm_head")
         if self.layer_rotary is not None:
             by_kind = dict(self.layer_rotary)
             if set(by_kind) - {"sliding", "full", "mla"}:
@@ -298,19 +335,20 @@ class TransformerConfig:
     @property
     def stateful(self) -> bool:
         """Some layer keeps a state that is not cached keys and values
-        (a kda layer's recurrent state, an mla layer's latent)."""
+        (a kda or mamba layer's recurrent state, an mla layer's
+        latent)."""
         return bool(self.layer_types) and bool(
-            {"kda", "mla"} & set(self.layer_types))
+            {"kda", "mla", "mamba"} & set(self.layer_types))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
         """How layer ``layer`` rotates q and k, None for not at all:
         what ``layer_rotary`` says of its kind, else plainly at
         ``rope_theta``, but for the full layers of a stack with
         ``layer_types``, which then take no rotary embedding. (An mla
-        layer rotates its ``mla_rope_dim`` values so; a kda layer reads
-        no position.)"""
+        layer rotates its ``mla_rope_dim`` values so; a kda or mamba
+        layer reads no position.)"""
         kind = self.kind_of(layer)
-        if kind == "kda":
+        if kind in ("kda", "mamba"):
             kind = "full"
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
@@ -376,6 +414,14 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
         layers.update(conv_q=P(None, None, "tp"), conv_k=P(None, None, "tp"),
                       conv_v=P(None, None, "tp"), wa=mat, a_log=vec,
                       a_bias=P(None, "tp"), wbeta=mat, wz=mat, o_norm=vec)
+    if kind == "mamba":
+        layers = {"attn_norm": vec, "mlp_norm": vec,
+                  "w_in": mat, "conv_w": P(None, None, "tp"),
+                  "conv_b": P(None, "tp"), "w_x": P(None, "tp", None),
+                  "dt_norm": vec, "b_norm": vec, "c_norm": vec,
+                  "w_dt": P(None, None, "tp"), "b_dt": P(None, "tp"),
+                  "a_log": P(None, None, "tp"), "d_skip": P(None, "tp"),
+                  "w_out": P(None, "tp", "fsdp")}
     if kind == "mla":
         del layers["wk"], layers["wv"]
         layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
@@ -429,6 +475,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "final_norm": P(None),
         "lm_head": P("fsdp", "tp"),        # [D, V]
     }
+    if cfg.tie_embeddings:
+        del specs["lm_head"]
     if cfg.n_dense_layers:
         specs["dense_layers"] = _block_specs(cfg, False)
     if cfg.mixed:
@@ -481,6 +529,37 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             "wz": dense(next(k), (L, D, H * Dh), D),
             "o_norm": jnp.ones((L, Dh), dt),
             "wo": dense(next(k), (L, H * Dh, D), H * Dh),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
+    elif kind == "mamba":
+        Di, N, R = cfg.mamba_expand * D, cfg.mamba_d_state, cfg.mamba_dt_rank
+        # Mamba's own initialisation of the step: softplus(b_dt) is
+        # log-uniform in [1e-3, 1e-1], so that with A = -(1..N) a
+        # channel's memories spread from a few positions to a thousand
+        step = jnp.exp(uniform(next(k), (L, Di), jnp.log(1e-3),
+                               jnp.log(1e-1)))
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            "w_in": dense(next(k), (L, D, 2 * Di), D),        # u | z
+            # a tap a channel: [taps, Di], the last tap the newest row
+            "conv_w": dense(next(k), (L, cfg.mamba_d_conv, Di),
+                            cfg.mamba_d_conv),
+            "conv_b": dense(next(k), (L, Di), cfg.mamba_d_conv),
+            "w_x": dense(next(k), (L, Di, R + 2 * N), Di),   # delta | B | C
+            "dt_norm": jnp.ones((L, R), dt),
+            "b_norm": jnp.ones((L, N), dt),
+            "c_norm": jnp.ones((L, N), dt),
+            "w_dt": dense(next(k), (L, R, Di), R),
+            # softplus^-1(step); float32, as a_log and d_skip are
+            "b_dt": step + jnp.log(-jnp.expm1(-step)),
+            # A = -exp(a_log), row n of [N, Di] the decay rate n + 1 of
+            # every channel (the published [Di, N], turned so that a
+            # state's channels lie along the lanes: kv_cache.KVCache)
+            "a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None],
+                (L, N, Di)),
+            "d_skip": jnp.ones((L, Di), jnp.float32),
+            "w_out": dense(next(k), (L, Di, D), Di),
             "mlp_norm": jnp.ones((L, D), dt),
         }
     elif kind == "mla":
@@ -564,6 +643,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
             "final_norm": jnp.ones((D,), cfg.dtype),
             "lm_head": dense(next(k), (D, V), D),
         }
+        if cfg.tie_embeddings:
+            del params["lm_head"]
         if cfg.n_dense_layers:
             params["dense_layers"] = [one(i)
                                       for i in range(cfg.n_dense_layers)]
@@ -575,6 +656,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
         "final_norm": jnp.ones((D,), cfg.dtype),
         "lm_head": dense(next(k), (D, V), D),
     }
+    if cfg.tie_embeddings:
+        del params["lm_head"]
     if cfg.n_dense_layers:
         params["dense_layers"] = _init_blocks(
             cfg, iter(jax.random.split(jax.random.fold_in(key, 1), 8)),
@@ -895,6 +978,62 @@ def kda_residual(cfg: TransformerConfig, lp, x, h, o):
     return x + (o @ lp["wo"]).astype(cfg.dtype)
 
 
+def head_weights(cfg: TransformerConfig, params):
+    """``[D, V]``: the head, or with ``tie_embeddings`` the embedding's
+    transpose."""
+    return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+
+
+# -- the state-space kind (ISSUE 47): projections, convolution, gates --
+
+def mamba_rows(cfg: TransformerConfig, lp, x):
+    """A mamba layer up to its convolution: ``[u | z] = RMSNorm(x)
+    W_in``, each [B, T, Di] (``Di = mamba_expand * d_model``). ``u`` is
+    what the convolution runs over (the newest ``mamba_d_conv - 1`` rows
+    of it are what a sequence carries from call to call); ``z`` gates
+    the output."""
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    return jnp.split(h @ lp["w_in"], 2, axis=-1)
+
+
+def mamba_conv(cfg: TransformerConfig, lp, u, before):
+    """``SiLU(b + sum_j w_j u_{t - (taps - 1) + j})``: the causal
+    depthwise convolution over ``u`` [B, T, Di] preceded by the
+    sequence's ``before`` [B, taps - 1, Di] (zeros at a sequence's
+    start), float32 sums, in ``u``'s dtype."""
+    T = u.shape[1]
+    full = jnp.concatenate([before.astype(u.dtype), u], 1)
+    y = lp["conv_b"].astype(jnp.float32) + sum(
+        full[:, j:j + T].astype(jnp.float32)
+        * lp["conv_w"][j].astype(jnp.float32)
+        for j in range(cfg.mamba_d_conv))
+    return jax.nn.silu(y).astype(u.dtype)
+
+
+def mamba_gates(cfg: TransformerConfig, lp, u):
+    """What the recurrence reads of the convolved ``u`` [B, T, Di]:
+    ``[delta | B | C] = u W_x``, each RMS-normed with its own gain; the
+    step ``Delta = softplus(delta W_dt + b_dt)`` [B, T, Di], ``B`` and
+    ``C`` [B, T, N], all float32 (a position's ``Delta A`` adds up over
+    a channel's whole memory, as a kda layer's log-decay does)."""
+    R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
+    dbc = u @ lp["w_x"]
+    delta = _rmsnorm(dbc[..., :R], lp["dt_norm"], cfg.norm_eps)
+    b = _rmsnorm(dbc[..., R:R + N], lp["b_norm"], cfg.norm_eps)
+    c = _rmsnorm(dbc[..., R + N:], lp["c_norm"], cfg.norm_eps)
+    step = jax.nn.softplus(
+        jnp.einsum("btr,rd->btd", delta, lp["w_dt"],
+                   preferred_element_type=jnp.float32) + lp["b_dt"])
+    return step, b.astype(jnp.float32), c.astype(jnp.float32)
+
+
+def mamba_residual(cfg: TransformerConfig, lp, x, y, z):
+    """A mamba layer after its recurrence ``y`` [B, T, Di] float32: the
+    gate ``SiLU(z)``, the output projection and the residual."""
+    o = (y * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+    return x + (o @ lp["w_out"]).astype(cfg.dtype)
+
+
 def mla_rotary(cfg: TransformerConfig) -> Rotary:
     """How the mla layers rotate (``rotary_of`` of the first of them:
     ``layer_rotary`` is by kind)."""
@@ -1051,15 +1190,15 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
-    """The trainer's entry points refuse kda and mla layers by name:
-    their forward exists in the serve programs alone."""
+    """The trainer's entry points refuse kda, mla and mamba layers by
+    name: their forward exists in the serve programs alone."""
     if cfg.stateful:
         raise NotImplementedError(
-            f"{what} does not run kda or mla layers: models/transformer.py"
-            "'s decoder_layer has no backward through the chunked "
-            "delta-rule scan of serve/decode.py and no latent attention "
-            "(ROADMAP B14, B8). The configuration is served through "
-            "ServeEngine.")
+            f"{what} does not run kda, mla or mamba layers: "
+            "models/transformer.py's decoder_layer has no backward through "
+            "the chunked delta-rule scan or the selective scan of "
+            "serve/decode.py and no latent attention (ROADMAP B14, B8). "
+            "The configuration is served through ServeEngine.")
 
 
 def _refuse_mixed_off_dp(cfg: TransformerConfig, what: str, mesh) -> None:
@@ -1139,7 +1278,7 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
                             unroll=cfg.scan_unroll)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = x @ params["lm_head"]
+        logits = x @ head_weights(cfg, params)
         logits = constrain(logits, ("dp", "fsdp"), "sp", "tp")
     return logits, aux if cfg.mixed else auxes.sum()
 

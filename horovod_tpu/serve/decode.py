@@ -78,8 +78,10 @@ faster, in the dense form.
 The programs of a configuration with layers of several kinds
 (:func:`mixed_programs`) attend through :func:`_attend_keys` (window
 and full layers: keys that carry their positions, in XLA),
-:func:`kda_scan` / :func:`kda_step` (a recurrent state) and latent
-attention, in two forms. A chunk's queries (``prefill`` over itself,
+:func:`kda_scan` / :func:`kda_step` and :func:`mamba_scan` /
+:func:`mamba_step` (a recurrent state a batch slot: a delta rule, and
+Mamba-1's selective scan, both in XLA) and latent attention, in two
+forms. A chunk's queries (``prefill`` over itself,
 ``prefill_resume`` over the pages, at every bucket width) attend
 EXPANDED (:func:`_mla_attend`), a key block at a time through the
 Pallas flash forward over keys that carry their positions
@@ -243,8 +245,8 @@ def make_serve_fns(cfg, mesh: Optional[Any] = None, *, block_size: int,
     parallel island can't run.
 
     A configuration whose layers are of more than one kind
-    (``cfg.mixed``: a leading dense stack; window, full, kda and mla
-    layers) or that holds a chip's share of the experts gets the
+    (``cfg.mixed``: a leading dense stack; window, full, kda, mla and
+    mamba layers) or that holds a chip's share of the experts gets the
     programs of :func:`_mixed_serve_fns`, over a state a kind of layer
     (``kv_cache.KVCache``); ``ring`` is the positions a window layer
     keeps for a sequence (``kv_cache.ring_width``). It has no
@@ -700,6 +702,58 @@ def kda_step(q, k, v, g, beta, state):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
+#: Positions :func:`mamba_scan`'s loop body holds. On the v5e at the
+#: published 5120 channels and 16 state rows, ms a layer of a 512-token
+#: chunk (``tools/mamba_scan_sweep.py``, 2026-10-01): a position an
+#: iteration 0.92, **eight 0.43 or less** (the call's own 0.4 ms hides
+#: the rest); ``lax.associative_scan`` inside blocks of 8 / 16 / 32 / 64
+#: positions 0.92 / 1.11 / 1.11 / 1.04 (a block's ``[block, 16, 5120]``
+#: float32 decays and drives go through memory at every level of the
+#: tree) and 7.9 / 9.2 at blocks of 128 / 256.
+_MAMBA_UNROLL = 8
+
+
+def mamba_scan(u, step, a, b, c, state, unroll: int = _MAMBA_UNROLL):
+    """The selective scan of a mamba layer over a chunk: the recurrence
+    a position at a time, ``unroll`` positions a loop iteration, so that
+    nothing larger than a state is ever built (a chunk's decays alone
+    would be ``[T, N, Di]`` float32, 168 MB a layer at 512 positions
+    and the published 5120 x 16).
+
+    ``u`` (the convolved input) and ``step`` (``Delta``, >= 0)
+    [B, T, Di], ``a`` [N, Di] (``A`` turned, < 0), ``b`` and ``c``
+    [B, T, N], ``state`` [B, N, Di], all float32. A channel d and a
+    state row n:
+
+        s_t = exp(step_t a) s_{t-1} + (step_t u_t) b_t
+        y_t = sum_n s_t c_t
+
+    Returns ``(y [B, T, Di], the state after the last position)``; the
+    caller adds ``D u``. A position with ``step = 0`` leaves the state
+    as it was (decay 1, drive 0): that is how a bucket's padding is
+    written. Each position is :func:`mamba_step`, the decode step's
+    own."""
+    def position(s, row):
+        y, s = mamba_step(row[0], row[1], a, row[2], row[3], s)
+        return s, y
+
+    state, y = lax.scan(
+        position, state,
+        tuple(jnp.moveaxis(x, 1, 0) for x in (u, step, b, c)),
+        unroll=min(unroll, u.shape[1]))
+    return jnp.moveaxis(y, 0, 1), state
+
+
+def mamba_step(u, step, a, b, c, state):
+    """One position of :func:`mamba_scan`'s recurrence a row: ``u``,
+    ``step`` [S, Di], ``b``, ``c`` [S, N], ``state`` [S, N, Di].
+    Returns ``(y [S, Di], the new state)``: the state read once and
+    written once."""
+    state = (jnp.exp(step[:, None] * a) * state
+             + (step * u)[:, None] * b[..., None])
+    return jnp.sum(state * c[..., None], axis=1), state
+
+
 def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
     """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
     and ``qr`` [B, C, H, R] (rotated) at positions ``pos`` [B, C] over
@@ -808,9 +862,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     tuples with one array a kind of layer, in
     ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
     is what): pages behind the block tables for ``full`` and ``mla``
-    layers, and rings and recurrent states for ``sliding`` and ``kda``
-    layers, one a batch slot (slot 0 is the null slot, as block 0 is the
-    null block). An address is a pair too: ``(block_table, slot)``.
+    layers, and rings and recurrent states for ``sliding``, ``kda`` and
+    ``mamba`` layers, one a batch slot (slot 0 is the null slot, as
+    block 0 is the null block). An address is a pair too:
+    ``(block_table, slot)``.
 
     The layers are a Python loop: each knows its kind, its stack and
     its place in its kind's arrays when the program is traced. **A
@@ -874,6 +929,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     def emit(params, x, rows):
         with jax.named_scope("head"):
             x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
+            if cfg.tie_embeddings:
+                # x E^T, the table read where it lies and not turned
+                return head(jnp.einsum("...d,vd->...v", x, params["embed"]
+                                       ).astype(jnp.float32))
             return head((x @ params["lm_head"]).astype(jnp.float32))
 
     def softmax_layer(call, lp, kc, vc, x, i, scope, write, attend):
@@ -984,6 +1043,46 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                     blocks_to(call.pos[0, -1]), call.pos)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
+    def mamba_chunk(call, lp, kc, vc, c, x, i):
+        """The chunk's selective scan from the state and the
+        convolution's rows the slot holds (zeros for a sequence's first
+        chunk, whatever the slot held), and both back as they are AT
+        ``length``: a padded position's step is 0, which decays nothing
+        and drives nothing, and the rows kept are the last real ones."""
+        B, T = x.shape[:2]
+        n = place["mamba"]
+        N, Di = cfg.mamba_d_state, cfg.mamba_expand * cfg.d_model
+        with jax.named_scope("attn_mamba"):
+            with jax.named_scope("mamba_proj"):
+                u, z = tf_lib.mamba_rows(cfg, lp, x)
+            state = jnp.zeros((B, N, Di), jnp.float32)
+            before = jnp.zeros((B, cfg.mamba_d_conv - 1, Di), u.dtype)
+            if not call.local:
+                resumed = call.offset > 0
+                state = jnp.where(resumed, kc[n][c, call.slot][None], state)
+                before = jnp.where(
+                    resumed, vc[n][c, call.slot].reshape(before.shape),
+                    before)
+            with jax.named_scope("mamba_conv"):
+                uc = tf_lib.mamba_conv(cfg, lp, u, before)
+            with jax.named_scope("mamba_proj"):
+                step, b, cc = tf_lib.mamba_gates(cfg, lp, uc)
+                real = jnp.arange(T)[None, :, None] < call.length
+                step = jnp.where(real, step, 0.0)
+            with jax.named_scope("mamba_scan"):
+                uc = uc.astype(jnp.float32)
+                y, state = mamba_scan(uc, step, -jnp.exp(lp["a_log"]), b, cc,
+                                      state)
+                y = y + lp["d_skip"] * uc
+            if kc is not None:
+                with jax.named_scope("state_write"):
+                    newest = lax.dynamic_slice_in_dim(
+                        jnp.concatenate([before, u], 1)[0], call.length,
+                        cfg.mamba_d_conv - 1)
+                    kc = put(kc, "mamba", (c, call.slot), state[0])
+                    vc = put(vc, "mamba", (c, call.slot), newest.reshape(-1))
+        return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
+
     # -- a decode step of the batch (one position a row) -------------
 
     def by_slot(call, rows, n_slots):
@@ -1074,12 +1173,43 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                 call.tables, call.positions)
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
+    def mamba_step_layer(call, lp, kc, vc, c, x, i):
+        """One step of the selective scan on every slot's state where
+        it lies, the batch's rows carried to their slots and the
+        results back (as :func:`kda_step_layer` does): a slot that is
+        not in the batch steps by 0, so its state is what it was."""
+        n = place["mamba"]
+        with jax.named_scope("attn_mamba"):
+            with jax.named_scope("mamba_proj"):
+                u, z = tf_lib.mamba_rows(cfg, lp, x)
+            before = vc[n][c, call.slots].reshape(
+                u.shape[0], cfg.mamba_d_conv - 1, -1)
+            with jax.named_scope("mamba_conv"):
+                uc = tf_lib.mamba_conv(cfg, lp, u, before)
+            with jax.named_scope("mamba_proj"):
+                step, b, cc = tf_lib.mamba_gates(cfg, lp, uc)
+            with jax.named_scope("mamba_step"):
+                n_slots = kc[n].shape[1]
+                uc = uc.astype(jnp.float32)
+                us, steps, bs, cs = (by_slot(call, a[:, 0], n_slots)
+                                     for a in (uc, step, b, cc))
+                y, state = mamba_step(us, steps, -jnp.exp(lp["a_log"]), bs,
+                                      cs, kc[n][c])
+                y = y[call.slots][:, None] + lp["d_skip"] * uc
+            with jax.named_scope("state_write"):
+                kc = put(kc, "mamba", (c,), state)
+                vc = put(vc, "mamba", (c, call.slots),
+                         jnp.concatenate([before, u], 1)[:, 1:].reshape(
+                             u.shape[0], -1))
+        return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
+
     #: kind of layer -> how a chunk and how a decode step run it
     kinds = {
         "sliding": {"chunk": window_chunk, "step": window_step},
         "full": {"chunk": full_chunk, "step": full_step},
         "kda": {"chunk": kda_chunk, "step": kda_step_layer},
         "mla": {"chunk": mla_chunk, "step": mla_step},
+        "mamba": {"chunk": mamba_chunk, "step": mamba_step_layer},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -1175,9 +1305,9 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
             raise NotImplementedError(
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
-                "layer's ring and a kda layer's recurrent state are not "
-                "pages another engine or a draft could be handed, and "
-                "decode.py's inject and verify know K and V pages alone "
+                "layer's ring and a kda or mamba layer's recurrent state "
+                "are not pages another engine or a draft could be handed, "
+                "and decode.py's inject and verify know K and V pages alone "
                 "(ROADMAP B9, B14)")
         return refuse
 

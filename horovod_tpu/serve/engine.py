@@ -97,8 +97,8 @@ import numpy as np
 from horovod_tpu.ops.latent_decode import key_block as latent_key_block
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
-    NULL_SLOT, BlockAllocator, hash_chain, init_kv_cache, pick_bucket,
-    ring_width,
+    NULL_SLOT, RECURRENT_KINDS, SLOT_KINDS, BlockAllocator, hash_chain,
+    init_kv_cache, pick_bucket, ring_width,
 )
 from horovod_tpu.serve.metrics import ServeMetrics
 
@@ -442,17 +442,17 @@ class ServeEngine:
         if self._slot_states:
             # A prefix is shared as pages mapped into another block
             # table: what every layer keeps of a position then has to be
-            # a page. A window layer's ring and a kda layer's recurrent
-            # state lie by batch slot, so those kinds refuse it; full
-            # and mla layers alone (K/V and latent pages) share.
-            by_slot = sorted({"sliding", "kda"}
-                             & set(model_cfg.layer_types or ()))
+            # a page. A window layer's ring and a kda or mamba layer's
+            # recurrent state lie by batch slot (kv_cache.SLOT_KINDS),
+            # so those kinds refuse it; full and mla layers alone (K/V
+            # and latent pages) share.
+            by_slot = self._kinds_by_slot()
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, and a kda layer's state after a prefix, cannot "
-                 "be mapped into another sequence: engine._admit, "
-                 "kv_cache.BlockAllocator)",
+                 "a window, and a kda or mamba layer's state after a "
+                 "prefix, cannot be mapped into another sequence: "
+                 "engine._admit, kv_cache.BlockAllocator)",
                  cfg.prefix_caching and by_slot),
                 ("speculative decoding (draft/spec_k: speculative.py "
                  "rolls back pages, not a ring or a recurrent state)",
@@ -707,7 +707,7 @@ class ServeEngine:
             m.kv_window_blocks_in_use = (
                 (self.cfg.max_batch - len(self._free_slots))
                 * self.cache.ring // self.cfg.block_size)
-        if "kda" in self.cache.kinds:
+        if set(RECURRENT_KINDS) & set(self.cache.kinds):
             m.state_slots_in_use = self.cfg.max_batch - len(self._free_slots)
             m.state_bytes = m.state_slots_in_use * self.cache.slot_bytes
         if self._prefilling:
@@ -950,6 +950,9 @@ class ServeEngine:
         if self.cfg.prefix_caching:
             # positions this call attends that it did not compute
             extra["mapped"], seq.mapped = seq.mapped, 0
+        if "mamba" in self.cache.kinds:
+            # positions the selective scan runs: the bucket, pads too
+            extra["scanned"] = len(toks)
         with m.phase("serve:prefill", device=True, n_tokens=chunk,
                      offset=offset, **extra) as ph:
             with ph.dispatch():
@@ -996,13 +999,20 @@ class ServeEngine:
             self.metrics.record_window_positions(
                 min(written, self.cache.ring))
 
+    def _kinds_by_slot(self) -> List[str]:
+        """The configuration's kinds of layer whose state lies by batch
+        slot (``kv_cache.SLOT_KINDS``), by name."""
+        return sorted(set(SLOT_KINDS)
+                      & set(self.model_cfg.layer_types or ()))
+
     def _refuse_slot_states(self, what: str) -> None:
         if self._slot_states:
+            held = " and ".join(self._kinds_by_slot()) or "several kinds of"
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
-                "configuration with layers of several kinds keeps its "
-                "window layers' keys in per-slot rings and its kda layers' "
-                "recurrent states by slot, which are not pages and which "
+                f"configuration with {held} layers keeps a window layer's "
+                "keys in per-slot rings and a kda or mamba layer's "
+                "recurrent state by slot, which are not pages and which "
                 "migrate.py and engine.inject_* do not move yet (ROADMAP "
                 "B9, B14)")
 
@@ -1359,6 +1369,11 @@ class ServeEngine:
         # trace ids of every sampled sequence in it (plural key).
         traces = [s.trace for s in rows if s is not None and s.trace]
         extra = {"traces": traces} if traces else {}
+        if "mamba" in self.cache.kinds:
+            # slots whose state the step reads and writes where it lies,
+            # and the positions its rows attend in the full layers
+            extra["slots_stepped"] = self.cfg.max_batch + 1
+            extra["attended"] = int(positions.sum()) + n
         call = m.launch("serve:decode", n_active=n, ahead=prev is not None,
                         **extra)
         with call.dispatch():

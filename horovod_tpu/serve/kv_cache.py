@@ -292,7 +292,14 @@ class BlockAllocator:
 #: their arrays take in :class:`KVCache` (``full`` and ``sliding`` first
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
-STATE_KINDS = ("full", "sliding", "kda", "mla")
+STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba")
+#: Those of them whose arrays lie by batch slot and not behind the block
+#: tables: nothing of theirs is a page that another sequence, another
+#: engine or a draft could be handed (what ``engine.py`` refuses over
+#: them, and what ``KVCache.slot_bytes`` counts); and those of these
+#: that are a recurrent state, which ``state_slots_in_use`` counts.
+RECURRENT_KINDS = ("kda", "mamba")
+SLOT_KINDS = ("sliding",) + RECURRENT_KINDS
 
 
 def state_kinds(cfg) -> Tuple[str, ...]:
@@ -329,7 +336,16 @@ class KVCache:
       convolution ``[n, n_slots + 1, kda_conv - 1, 3 * H * Dh]``;
     * ``mla``: the latent pages ``[n, n_blocks, block_size,
       latent_row(cfg)]`` behind the block tables, and no second array
-      (``None``).
+      (``None``);
+    * ``mamba``: the selective scan's state ``[n, n_slots + 1,
+      mamba_d_state, Di]`` float32 (``Di = mamba_expand * d_model``; a
+      state's ``[Di, N]`` turned so that its channels lie along the
+      lanes: with the 16 values of a channel innermost the chip's
+      (8, 128) tiles would hold 16 of 128 lanes), and the newest
+      ``mamba_d_conv - 1`` rows before the convolution, end to end,
+      ``[n, n_slots + 1, (mamba_d_conv - 1) * Di]`` (as ``[.., 3, Di]``
+      the 3 rows were a tile's 16 and every program copied the array on
+      its way in and out, a tenth of the device's time: chip, PR 47).
 
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
@@ -354,7 +370,7 @@ class KVCache:
         """Bytes a batch slot holds whatever its sequence's length:
         its rings and its recurrent state."""
         return sum(a[:, 0].size * a.dtype.itemsize
-                   for kind in self.kinds if kind in ("sliding", "kda")
+                   for kind in self.kinds if kind in SLOT_KINDS
                    for a in self.of(kind))
 
     @property
@@ -408,6 +424,7 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
     if cfg.mixed:
         H, Dh = cfg.n_heads, cfg.head_dim
         tail = (cfg.n_kv_heads, Dh)
+        d_inner = cfg.mamba_expand * cfg.d_model
         n = {kind: cfg.n_layers_of(kind) for kind in STATE_KINDS}
         shapes = {   # kind -> (shape, dtype) of its first and second array
             "full": 2 * (((n["full"], n_blocks, block_size) + tail, dtype),),
@@ -418,6 +435,10 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                      dtype)),
             "mla": (((n["mla"], n_blocks, block_size, latent_row(cfg)),
                      dtype), None),
+            "mamba": (((n["mamba"], n_slots + 1, cfg.mamba_d_state, d_inner),
+                       jnp.float32),
+                      ((n["mamba"], n_slots + 1,
+                        (cfg.mamba_d_conv - 1) * d_inner), dtype)),
         }
         kinds = state_kinds(cfg)
 

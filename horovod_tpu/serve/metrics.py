@@ -360,8 +360,8 @@ class ServeMetrics:
         # held for one sequence, and the rings in use, in blocks.
         self.kv_window_positions_max = 0
         self.kv_window_blocks_in_use = 0
-        # The kinds whose state is not keys (kda, mla): the batch slots
-        # whose recurrent state a sequence holds and their bytes; the
+        # The kinds whose state is not keys (kda, mamba, mla): the batch
+        # slots whose recurrent state a sequence holds and their bytes; the
         # positions the latent pool held for the rows of the last
         # decode call (their sum: what absorbed attention has to read),
         # and the most it held for one sequence.

@@ -507,15 +507,15 @@ def test_what_is_not_built_is_refused_by_name(devices):
             decode_lib.make_serve_fns(
                 cfg, build_mesh(devices=devices[:2], **axes), block_size=BS,
                 table_width=4)
-    with pytest.raises(NotImplementedError, match="kda or mla.*B14"):
+    with pytest.raises(NotImplementedError, match="kda, mla or mamba.*B14"):
         make_train_step(cfg, build_mesh(devices=devices[:1], dp=1))
-    with pytest.raises(NotImplementedError, match="kda or mla"):
+    with pytest.raises(NotImplementedError, match="kda, mla or mamba"):
         tf_lib.forward(params, jnp.zeros((1, 8), jnp.int32), cfg)
 
 
 def test_a_configuration_names_its_kinds():
     with pytest.raises(ValueError, match="'kda' | 'mla'"):
-        tiny(layer_types=("kda", "kda", "mamba"))
+        tiny(layer_types=("kda", "kda", "retention"))
     with pytest.raises(ValueError, match="mla_kv_rank"):
         tiny(mla_kv_rank=0)
     with pytest.raises(ValueError, match="n_kv_heads = n_heads"):

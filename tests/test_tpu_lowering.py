@@ -611,6 +611,76 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# The two programs of the state-space kind (ISSUE 47) at the widths, the
+# 256 slots and the table of `jamba2-3b`: four of its 28 layers, three
+# mamba and one multi-query attention layer, a tied head.
+_JAMBA_DRIVER = r"""
+import collections, json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=65536, d_model=2560, n_layers=4, n_heads=20, n_kv_heads=1,
+    d_head=128, d_ff=8192, max_seq=1536, norm_eps=1e-6,
+    layer_types=("mamba", "mamba", "full", "mamba"), mamba_d_state=16,
+    mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160, tie_embeddings=True,
+    dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS, CHUNK = 16, 96, 256, 512
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+_, resume, decode, _, _ = decode_lib.make_serve_fns(
+    cfg, None, block_size=BS, table_width=WIDTH)
+state = "f32[%s]" % ",".join(map(str, kc[1].shape))
+out = {{"device_kind": topo.devices[0].device_kind,
+       "state_bytes": kc[1].size * 4}}
+for name, fn, args in (
+        ("decode", decode,
+         (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
+        ("prefill_resume", resume,
+         (i32(CHUNK), i32(), i32(), (i32(WIDTH), i32())))):
+    compiled = fn.lower(params, kc, vc, *args).compile()
+    text = compiled.as_text()
+    ops = collections.Counter(
+        opcode for result, opcode in
+        re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text) if result == state)
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    out[name] = {{
+        "ops": ops, "aliased": len(re.findall(r"may-alias|must-alias",
+                                              aliased.group(1))),
+        # a whole chunk's decays or states, and not a block's
+        "whole_chunk_states": len(re.findall(
+            r"f32\[[\d,]*512,16,5120\]", text)),
+        "scopes": sorted(set(re.findall(
+            r"attn_mamba/(mamba_\w+|state_write)", text))),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
 @functools.lru_cache(maxsize=None)
 def _compile_for_v5e(driver):
     proc = subprocess.run(
@@ -901,3 +971,27 @@ def test_a_served_chunk_keeps_the_whole_row_form_with_no_cond(share):
     got = _compile_for_v5e(_MELLUM_DRIVER)[share]
     assert got["bound"] is None and got["conditionals"] == 0, got
     assert got["kernels"] >= 3, got
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_the_state_space_programs_lower_for_the_v5e(program):
+    """ISSUE 47: a decode step at 256 slots and a chunk of 512 of a
+    stack of mamba layers beside a multi-query attention layer compile
+    for the v5e with K, V, the selective scan's state and the
+    convolution's rows aliased in and out, no copy of the state array
+    (253 MB here, 2.19 GB at the cell's 26 layers), a chunk's decays
+    and states never whole (``mamba_scan`` goes a position at a time:
+    a float32 ``[512, 16, 5120]`` would be 168 MB a layer), every scope
+    the benchmark reads by name in the program, and what a call
+    allocates under the state's size."""
+    out = _compile_for_v5e(_JAMBA_DRIVER)
+    got = out[program]
+    assert got["aliased"] == 4, got
+    assert set(got["ops"]) <= {"parameter", "get-tuple-element", "bitcast",
+                               "fusion", "dynamic-update-slice",
+                               "scatter"}, got
+    assert got["whole_chunk_states"] == 0, got
+    step = "mamba_step" if program == "decode" else "mamba_scan"
+    assert set(got["scopes"]) == {"mamba_proj", "mamba_conv", step,
+                                  "state_write"}, got
+    assert got["temp_bytes"] < out["state_bytes"], got
