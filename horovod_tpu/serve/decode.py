@@ -110,6 +110,7 @@ from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_keys)
+from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
@@ -923,8 +924,14 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         if cache is None:
             return None
         n = place[kind]
-        return cache[:n] + (cache[n].at[at].set(
-            new.astype(cache[n].dtype)),) + cache[n + 1:]
+        return swap(cache, kind, cache[n].at[at].set(
+            new.astype(cache[n].dtype)))
+
+    def swap(cache, kind, array):
+        """``cache`` with ``array`` as ``kind``'s (a kernel that took
+        the old one aliased hands back the whole of it)."""
+        n = place[kind]
+        return cache[:n] + (array,) + cache[n + 1:]
 
     def emit(params, x, rows):
         with jax.named_scope("head"):
@@ -1174,11 +1181,18 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
 
     def mamba_step_layer(call, lp, kc, vc, c, x, i):
-        """One step of the selective scan on every slot's state where
-        it lies, the batch's rows carried to their slots and the
-        results back (as :func:`kda_step_layer` does): a slot that is
-        not in the batch steps by 0, so its state is what it was."""
+        """One step of the selective scan on each row's own state where
+        it lies in the pool (``ops/mamba_step.py``, the Pallas call
+        ``hvd_mamba_step``: the state read once and written once, a slot
+        that is not in the batch not touched), and the convolution's
+        rows of the layer shifted where they lie (``hvd_mamba_rows``,
+        the layer's slots in blocks). A state that is not whole tiles
+        keeps, on a TPU, the XLA form: every slot's state where it lies,
+        the batch's rows carried to their slots and the results back (as
+        :func:`kda_step_layer` does), a slot that is not in the batch
+        stepped by 0."""
         n = place["mamba"]
+        n_slots, N, Di = kc[n].shape[1:]
         with jax.named_scope("attn_mamba"):
             with jax.named_scope("mamba_proj"):
                 u, z = tf_lib.mamba_rows(cfg, lp, x)
@@ -1188,19 +1202,29 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 uc = tf_lib.mamba_conv(cfg, lp, u, before)
             with jax.named_scope("mamba_proj"):
                 step, b, cc = tf_lib.mamba_gates(cfg, lp, uc)
-            with jax.named_scope("mamba_step"):
-                n_slots = kc[n].shape[1]
-                uc = uc.astype(jnp.float32)
-                us, steps, bs, cs = (by_slot(call, a[:, 0], n_slots)
-                                     for a in (uc, step, b, cc))
-                y, state = mamba_step(us, steps, -jnp.exp(lp["a_log"]), bs,
-                                      cs, kc[n][c])
-                y = y[call.slots][:, None] + lp["d_skip"] * uc
-            with jax.named_scope("state_write"):
-                kc = put(kc, "mamba", (c,), state)
-                vc = put(vc, "mamba", (c, call.slots),
-                         jnp.concatenate([before, u], 1)[:, 1:].reshape(
-                             u.shape[0], -1))
+            a = -jnp.exp(lp["a_log"])
+            uc = uc.astype(jnp.float32)
+            if mamba_step_kernel.taken(N, Di):
+                with jax.named_scope("mamba_step"):
+                    y, state = mamba_step_kernel.mamba_step(
+                        uc[:, 0], step[:, 0], a, b[:, 0], cc[:, 0], kc[n], c,
+                        call.slots)
+                    y = y[:, None] + lp["d_skip"] * uc
+                with jax.named_scope("state_write"):
+                    kc = swap(kc, "mamba", state)
+                    vc = swap(vc, "mamba", mamba_step_kernel.shift_rows(
+                        vc[n], c, call.slots, u[:, 0]))
+            else:
+                with jax.named_scope("mamba_step"):
+                    us, steps, bs, cs = (by_slot(call, r[:, 0], n_slots)
+                                         for r in (uc, step, b, cc))
+                    y, state = mamba_step(us, steps, a, bs, cs, kc[n][c])
+                    y = y[call.slots][:, None] + lp["d_skip"] * uc
+                with jax.named_scope("state_write"):
+                    kc = put(kc, "mamba", (c,), state)
+                    vc = put(vc, "mamba", (c, call.slots),
+                             jnp.concatenate([before, u], 1)[:, 1:].reshape(
+                                 u.shape[0], -1))
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
 
     #: kind of layer -> how a chunk and how a decode step run it

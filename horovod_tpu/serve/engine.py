@@ -1370,8 +1370,11 @@ class ServeEngine:
         traces = [s.trace for s in rows if s is not None and s.trace]
         extra = {"traces": traces} if traces else {}
         if "mamba" in self.cache.kinds:
-            # slots whose state the step reads and writes where it lies,
-            # and the positions its rows attend in the full layers
+            # slots whose state the XLA form of the step read and wrote
+            # where it lies (since PR 48 the kernels touch the batch's
+            # rows alone; the count stays what its reader in the
+            # benchmark holds it to until a `benchmark` issue corrects
+            # both), and the positions its rows attend in the full layers
             extra["slots_stepped"] = self.cfg.max_batch + 1
             extra["attended"] = int(positions.sum()) + n
         call = m.launch("serve:decode", n_active=n, ahead=prev is not None,

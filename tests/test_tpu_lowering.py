@@ -655,8 +655,30 @@ kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
 _, resume, decode, _, _ = decode_lib.make_serve_fns(
     cfg, None, block_size=BS, table_width=WIDTH)
 state = "f32[%s]" % ",".join(map(str, kc[1].shape))
+rows = "bf16[%s]" % ",".join(map(str, vc[1].shape))
 out = {{"device_kind": topo.devices[0].device_kind,
        "state_bytes": kc[1].size * 4}}
+
+
+def readers(text, shape):
+    # the entry computation's instructions that take a value of `shape`
+    # (alone or in a tuple) and do more than hand it on, by opcode
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip().removeprefix("ROOT ").split(" = ", 1)
+             for ln in entry.splitlines() if " = " in ln]
+    held = {{name for name, rest in lines if shape in rest.split(" ")[0]
+            or (rest.startswith("(") and shape in rest[:rest.index(") ")])}}
+    found = collections.Counter()
+    for name, rest in lines:
+        call = re.search(r" ([\w\-]+)\((%[^)]*)\)", rest)
+        if call and call.group(1) not in ("get-tuple-element", "tuple",
+                                          "bitcast"):
+            if held & set(re.findall(r"%[\w.\-]+", call.group(2))):
+                found[call.group(1)] += 1
+    return found
+
+
+
 for name, fn, args in (
         ("decode", decode,
          (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
@@ -669,7 +691,16 @@ for name, fn, args in (
         re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text) if result == state)
     aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
     out[name] = {{
-        "ops": ops, "aliased": len(re.findall(r"may-alias|must-alias",
+        "ops": ops,
+        "rows_ops": collections.Counter(
+            opcode for result, opcode in
+            re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text)
+            if result == rows),
+        "state_readers": readers(text, state),
+        "kernels": {{k: len(re.findall(
+            r"custom-call\(.*/%s/pallas_call" % k, text))
+            for k in ("hvd_mamba_step", "hvd_mamba_rows")}},
+        "aliased": len(re.findall(r"may-alias|must-alias",
                                               aliased.group(1))),
         # a whole chunk's decays or states, and not a block's
         "whole_chunk_states": len(re.findall(
@@ -994,4 +1025,20 @@ def test_the_state_space_programs_lower_for_the_v5e(program):
     step = "mamba_step" if program == "decode" else "mamba_scan"
     assert set(got["scopes"]) == {"mamba_proj", "mamba_conv", step,
                                   "state_write"}, got
+    if program == "decode":
+        # ISSUE 48: a mamba layer's step is the two Pallas calls, one
+        # reader of the state array a layer (the XLA form had a fusion
+        # that reduced to y and a second that wrote the state: six), and
+        # nothing but the rows' kernel makes a whole array of the
+        # convolution's rows (the scatter by slot was a fusion over all
+        # of it a layer; the copy-done is this small model's array
+        # moved whole into fast memory, which 26 layers' is not)
+        assert got["kernels"] == {"hvd_mamba_step": 3,
+                                  "hvd_mamba_rows": 3}, got
+        assert got["state_readers"] == {"custom-call": 3}, got
+        assert set(got["rows_ops"]) <= {"parameter", "bitcast", "copy-done",
+                                        "custom-call"}, got
+    else:
+        assert got["kernels"] == {"hvd_mamba_step": 0,
+                                  "hvd_mamba_rows": 0}, got
     assert got["temp_bytes"] < out["state_bytes"], got

@@ -6,7 +6,20 @@ built), and of ``mamba_step`` over 257 slots:
 
     chiprun -- python tools/mamba_scan_sweep.py
 
-What ``decode._MAMBA_UNROLL`` was chosen from (PERF.md, PR 47)."""
+What ``decode._MAMBA_UNROLL`` was chosen from (PERF.md, PR 47). With
+``--step``, the decode step alone at the cell's shapes (256 rows at
+shuffled slots of 26 layers' pool of 257): the XLA form of a layer
+(every slot's state where it lies, the rows carried to their slots: the
+fall-back of ``decode.mamba_step_layer``) beside the Pallas call
+``ops/mamba_step.py`` by the channels its loop and a grid step hold, ms
+a layer and the GB/s of the states read and written; and the
+convolution rows' update, the scatter by slot beside the layer's rows
+rewritten where they lie:
+
+    chiprun -- python tools/mamba_scan_sweep.py --step
+
+What ``ops/mamba_step.py::_channels`` was chosen from (PERF.md, PR 48)."""
+import argparse
 import json
 import sys
 import time
@@ -15,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, ".")
+from horovod_tpu.ops import mamba_step as step_lib  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 
 DI, N = 5120, 16
@@ -55,7 +69,140 @@ def blocked(u, step, a, b, c, state, block):
     return jnp.moveaxis(y, 0, 1).reshape(B, T, Di), state
 
 
+def carried(layer_fn, pool, at, lead, *rest, n=10):
+    """ms a layer of ``layer_fn(pool, layer, at, lead, *rest) -> (pool,
+    result)`` run on every layer of ``pool`` in turn in one program (a
+    call of one layer alone is over before the host has launched the
+    next: 0.3 ms), a layer's ``lead`` nudged by the result of the layer
+    before it as a decode program's is, the pool donated and handed on
+    from call to call."""
+    layers = pool.shape[0]
+
+    def every_layer(pool, at, lead, *rest):
+        nudge = jnp.zeros_like(lead)
+        for layer in range(layers):
+            pool, result = layer_fn(pool, layer, at, lead + nudge, *rest)
+            nudge = (1e-6 * result[:, :lead.shape[1]]).astype(lead.dtype)
+        return pool, nudge
+
+    fn = jax.jit(every_layer, donate_argnums=(0,))
+    out = fn(pool, at, lead, *rest)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = fn(out[0], at, lead, *rest)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / n / layers
+
+
+def step_sweep(layers=26, slots=256, conv=4):
+    """The decode step of one layer of ``layers``' pool, by form, at a
+    batch of every slot and of a quarter of them."""
+    rows = (slots, slots // 4)
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 8)
+    a = -jnp.exp(jax.random.normal(ks[0], (N, DI)))
+    for B in rows:
+        at = jax.random.permutation(ks[1], slots)[:B].astype(jnp.int32) + 1
+        u = jax.random.normal(ks[2], (B, DI))
+        step = jax.random.uniform(ks[3], (B, DI), minval=1e-3, maxval=0.1)
+        b = jax.random.normal(ks[4], (B, N))
+        c = jax.random.normal(ks[5], (B, N))
+        moved = 2 * B * N * DI * 4
+
+        def xla(pool, layer, at, u, step, b, c):
+            def by_slot(x):
+                return jnp.zeros((slots + 1,) + x.shape[1:],
+                                 x.dtype).at[at].set(x)
+            y, state = decode_lib.mamba_step(
+                by_slot(u), by_slot(step), a, by_slot(b), by_slot(c),
+                pool[layer])
+            return pool.at[layer].set(state), y[at]
+
+        def report(form, fn, **how):
+            pool = jax.random.normal(ks[6], (layers, slots + 1, N, DI))
+            ms = carried(fn, pool, at, u, step, b, c)
+            print(json.dumps({"rows": B, "form": form, **how,
+                              "ms_a_layer": round(ms, 4),
+                              "GBps_of_the_rows_states":
+                                  round(moved / ms / 1e6, 1)}), flush=True)
+
+        # the two forms on one pool, before either is timed
+        pool = jax.random.normal(ks[6], (2, slots + 1, N, DI))
+        want, y_want = jax.jit(xla)(pool, 1, at, u, step, b, c)
+        y_got, got = jax.jit(step_lib.mamba_step)(u, step, a, b, c, pool, 1,
+                                                  at)
+        print(json.dumps({
+            "rows": B, "kernel_against_xla": {
+                "y_max_gap": float(jnp.abs(y_got - y_want).max()),
+                "state_max_gap": float(jnp.abs(got - want).max())}}),
+            flush=True)
+        del pool, want, got
+        report("xla, every slot", xla)
+        for block, channels in ((DI, 256), (DI, 512), (DI, DI),
+                                (2560, 512), (1280, 256)):
+            def kernel(pool, layer, at, u, step, b, c, block=block,
+                       channels=channels):
+                y, pool = step_lib.mamba_step(
+                    u, step, a, b, c, pool, layer, at, block=block,
+                    channels=channels)
+                return pool, y
+            report("hvd_mamba_step", kernel, channels_a_grid_step=block,
+                   channels_a_loop=channels)
+
+        # the convolution's rows: bf16 [layers, slots + 1, (conv - 1) Di]
+        new = jax.random.normal(ks[7], (B, DI)).astype(jnp.bfloat16)
+
+        def scatter(rows, mid, at, new):
+            before = rows[mid, at]
+            return rows.at[mid, at].set(
+                jnp.concatenate([before[:, DI:], new], 1)), before
+
+        def select(rows, mid, at, new):
+            # XLA's form of the kernel: the layer's rows rewritten, a
+            # select a slot (in a decode program of 26 layers the
+            # compiler copied the whole array for two of them)
+            stepped = jnp.zeros((slots + 1, 1), bool).at[at].set(True)
+            last = jnp.zeros((slots + 1, DI), rows.dtype).at[at].set(new)
+            return rows.at[mid].set(jnp.where(stepped, jnp.concatenate(
+                [rows[mid][:, DI:], last], 1), rows[mid])), rows[mid, at]
+
+        def kernel(rows, mid, at, new):
+            before = rows[mid, at]
+            return step_lib.shift_rows(rows, mid, at, new), before
+
+        def gather(rows, mid, at, new):
+            return rows, rows[mid, at]
+
+        pool = jax.random.normal(ks[6], (layers, slots + 1, (conv - 1) * DI)
+                                 ).astype(jnp.bfloat16)
+        print(json.dumps({"rows": B, "hvd_mamba_rows_is_the_scatter": bool(
+            (jax.jit(kernel, static_argnums=1)(pool, 1, at, new)[0]
+             == jax.jit(scatter, static_argnums=1)(pool, 1, at, new)[0]
+             ).all())}), flush=True)
+        del pool
+        for form, fn in (("the gather of the rows before, alone", gather),
+                         ("gather and scatter by slot", scatter),
+                         ("gather and the layer's rows selected in XLA",
+                          select),
+                         ("gather and hvd_mamba_rows", kernel)):
+            pool = jnp.zeros((layers, slots + 1, (conv - 1) * DI),
+                             jnp.bfloat16)
+            ms = carried(fn, pool, at, new)
+            print(json.dumps({"rows": B, "conv_rows": form,
+                              "ms_a_layer": round(ms, 4)}), flush=True)
+
+
 def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--step", action="store_true",
+                        help="the decode step's forms, not the scan's")
+    parser.add_argument("--layers", type=int, default=26)
+    parser.add_argument("--slots", type=int, default=256,
+                        help="with --layers: a rehearsal's size, off the chip")
+    args = parser.parse_args()
+    if args.step:
+        return step_sweep(args.layers, args.slots)
     key = jax.random.PRNGKey(0)
     a = -jnp.exp(jax.random.normal(key, (N, DI)))
     for T in (512, 4096):
