@@ -110,6 +110,7 @@ from horovod_tpu.models import moe as moe_lib
 from horovod_tpu.models import transformer as tf_lib
 from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_keys)
+from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
 from horovod_tpu.parallel.ring_attention import local_attention
@@ -703,14 +704,20 @@ def kda_step(q, k, v, g, beta, state):
     return o, decayed + k[..., None] * u[..., None, :]
 
 
-#: Positions :func:`mamba_scan`'s loop body holds. On the v5e at the
-#: published 5120 channels and 16 state rows, ms a layer of a 512-token
-#: chunk (``tools/mamba_scan_sweep.py``, 2026-10-01): a position an
-#: iteration 0.92, **eight 0.43 or less** (the call's own 0.4 ms hides
-#: the rest); ``lax.associative_scan`` inside blocks of 8 / 16 / 32 / 64
-#: positions 0.92 / 1.11 / 1.11 / 1.04 (a block's ``[block, 16, 5120]``
-#: float32 decays and drives go through memory at every level of the
-#: tree) and 7.9 / 9.2 at blocks of 128 / 256.
+#: Positions :func:`mamba_scan`'s loop body holds. Since PR 49 the loop
+#: is the form of a chunk that ``ops/mamba_scan.py`` does not take (a
+#: state that is not whole tiles on a TPU), the kernel's reference in
+#: the tests and the sweep's baseline: a chunk of the published sizes
+#: runs the Pallas call ``hvd_mamba_scan`` (0.115 ms a layer of 512
+#: positions where this loop takes 0.299 inside a program of 26
+#: layers). What the 8 was chosen from, on the v5e at the published
+#: 5120 channels and 16 state rows, ms a layer of a 512-token chunk
+#: alone (``tools/mamba_scan_sweep.py --xla-forms``, 2026-10-01): a
+#: position an iteration 0.92, **eight 0.43 or less** (the call's own
+#: 0.4 ms hides the rest); ``lax.associative_scan`` inside blocks of
+#: 8 / 16 / 32 / 64 positions 0.92 / 1.11 / 1.11 / 1.04 (a block's
+#: ``[block, 16, 5120]`` float32 decays and drives go through memory at
+#: every level of the tree) and 7.9 / 9.2 at blocks of 128 / 256.
 _MAMBA_UNROLL = 8
 
 
@@ -733,7 +740,8 @@ def mamba_scan(u, step, a, b, c, state, unroll: int = _MAMBA_UNROLL):
     caller adds ``D u``. A position with ``step = 0`` leaves the state
     as it was (decay 1, drive 0): that is how a bucket's padding is
     written. Each position is :func:`mamba_step`, the decode step's
-    own."""
+    own. ``mamba_chunk`` calls this where ``ops/mamba_scan.py::taken``
+    says no; the kernel is held to it."""
     def position(s, row):
         y, s = mamba_step(row[0], row[1], a, row[2], row[3], s)
         return s, y
@@ -1078,8 +1086,12 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 step = jnp.where(real, step, 0.0)
             with jax.named_scope("mamba_scan"):
                 uc = uc.astype(jnp.float32)
-                y, state = mamba_scan(uc, step, -jnp.exp(lp["a_log"]), b, cc,
-                                      state)
+                a = -jnp.exp(lp["a_log"])
+                if mamba_scan_kernel.taken(N, Di, T):
+                    y, state = mamba_scan_kernel.mamba_scan(
+                        uc, step, a, b, cc, state, call.length)
+                else:
+                    y, state = mamba_scan(uc, step, a, b, cc, state)
                 y = y + lp["d_skip"] * uc
             if kc is not None:
                 with jax.named_scope("state_write"):

@@ -699,7 +699,11 @@ for name, fn, args in (
         "state_readers": readers(text, state),
         "kernels": {{k: len(re.findall(
             r"custom-call\(.*/%s/pallas_call" % k, text))
-            for k in ("hvd_mamba_step", "hvd_mamba_rows")}},
+            for k in ("hvd_mamba_step", "hvd_mamba_rows",
+                      "hvd_mamba_scan")}},
+        # a loop in XLA under the scan's scope: the form it replaced
+        "scan_whiles": len(re.findall(
+            r" while\(.*attn_mamba/mamba_scan", text)),
         "aliased": len(re.findall(r"may-alias|must-alias",
                                               aliased.group(1))),
         # a whole chunk's decays or states, and not a block's
@@ -1033,12 +1037,15 @@ def test_the_state_space_programs_lower_for_the_v5e(program):
         # convolution's rows (the scatter by slot was a fusion over all
         # of it a layer; the copy-done is this small model's array
         # moved whole into fast memory, which 26 layers' is not)
-        assert got["kernels"] == {"hvd_mamba_step": 3,
-                                  "hvd_mamba_rows": 3}, got
+        assert got["kernels"] == {"hvd_mamba_step": 3, "hvd_mamba_rows": 3,
+                                  "hvd_mamba_scan": 0}, got
         assert got["state_readers"] == {"custom-call": 3}, got
         assert set(got["rows_ops"]) <= {"parameter", "bitcast", "copy-done",
                                         "custom-call"}, got
     else:
-        assert got["kernels"] == {"hvd_mamba_step": 0,
-                                  "hvd_mamba_rows": 0}, got
+        # ISSUE 49: a mamba layer's chunk is one Pallas call, and no
+        # loop a position at a time is left under its scope
+        assert got["kernels"] == {"hvd_mamba_step": 0, "hvd_mamba_rows": 0,
+                                  "hvd_mamba_scan": 3}, got
+        assert got["scan_whiles"] == 0, got
     assert got["temp_bytes"] < out["state_bytes"], got

@@ -1,12 +1,20 @@
-"""On the chip: ms a layer of ``serve/decode.py::mamba_scan`` over one
-chunk at the published Jamba2-3B sizes (5120 channels, 16 state rows),
-a position at a time (``lax.scan``, by positions a loop iteration)
-beside ``lax.associative_scan`` inside blocks (the form PR 47 first
-built), and of ``mamba_step`` over 257 slots:
+"""On the chip: ms a layer of a chunk's selective scan at the published
+Jamba2-3B sizes (5120 channels, 16 state rows), every layer of 26 in
+turn in one program: the XLA form ``serve/decode.py::mamba_scan`` (a
+position at a time, the padding scanned with a step of 0) beside the
+Pallas call ``ops/mamba_scan.py`` by the channels and positions a grid
+step holds and the channels and positions its loop holds, at chunks of
+512, 256 and 128 positions full and at a ``length`` of three quarters,
+and the two forms' states and ``y`` compared on one input:
 
     chiprun -- python tools/mamba_scan_sweep.py
 
-What ``decode._MAMBA_UNROLL`` was chosen from (PERF.md, PR 47). With
+What ``ops/mamba_scan.py::_channels``, ``_POSITIONS`` and ``_WIDTHS``
+were chosen from (PERF.md, PR 49). With ``--xla-forms``, the XLA forms
+of the scan alone, one call at a time (``lax.scan`` by positions a loop
+iteration beside ``lax.associative_scan`` inside blocks, the form PR 47
+first built), and ``mamba_step`` over 257 slots: what
+``decode._MAMBA_UNROLL`` was chosen from (PERF.md, PR 47). With
 ``--step``, the decode step alone at the cell's shapes (256 rows at
 shuffled slots of 26 layers' pool of 257): the XLA form of a layer
 (every slot's state where it lies, the rows carried to their slots: the
@@ -18,7 +26,10 @@ rewritten where they lie:
 
     chiprun -- python tools/mamba_scan_sweep.py --step
 
-What ``ops/mamba_step.py::_channels`` was chosen from (PERF.md, PR 48)."""
+What ``ops/mamba_step.py::_channels`` was chosen from (PERF.md, PR 48).
+Off the chip the interpreter takes an hour at these sizes: rehearse with
+``--layers 1 --channels 1024`` (or ``--layers 2 --slots 16`` with
+``--step``)."""
 import argparse
 import json
 import sys
@@ -28,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 sys.path.insert(0, ".")
+from horovod_tpu.ops import mamba_scan as scan_lib  # noqa: E402
 from horovod_tpu.ops import mamba_step as step_lib  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 
@@ -193,16 +205,113 @@ def step_sweep(layers=26, slots=256, conv=4):
                               "ms_a_layer": round(ms, 4)}), flush=True)
 
 
-def main():
-    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--step", action="store_true",
-                        help="the decode step's forms, not the scan's")
-    parser.add_argument("--layers", type=int, default=26)
-    parser.add_argument("--slots", type=int, default=256,
-                        help="with --layers: a rehearsal's size, off the chip")
-    args = parser.parse_args()
-    if args.step:
-        return step_sweep(args.layers, args.slots)
+#: The kernel's forms the sweep times: channels a grid step, positions a
+#: grid step, channels a loop carries in registers, positions a loop
+#: iteration.
+KERNEL_FORMS = (
+    (5120, 64, 512, 8), (5120, 128, 512, 8), (5120, 32, 512, 8),
+    (5120, 64, 256, 8), (5120, 64, 1024, 8), (5120, 64, 512, 1),
+    (5120, 64, 512, 4), (5120, 64, 512, 16), (2560, 64, 512, 8),
+    (1024, 64, 512, 8), (512, 64, 512, 8))
+
+
+def scan_sweep(layers=26, d_inner=DI):
+    """A chunk's scan of every layer in turn in one program, a layer's
+    input nudged by the result of the layer before it (one layer's call
+    alone is over before the host has launched the next): the XLA form
+    ``decode.mamba_scan`` with the padding's step zeroed, as
+    ``mamba_chunk`` wrote it, beside the Pallas call
+    ``ops/mamba_scan.py`` by its block sizes, at a full bucket and at a
+    ``length`` of three quarters of it. Every form at the largest
+    bucket; the other buckets the XLA form, the program's own sizes and
+    the two fastest."""
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 6)
+    a = -jnp.exp(jax.random.normal(ks[0], (N, d_inner)))
+
+    def every_layer(scan):
+        def run(states, u, step, b, c, length):
+            nudge, out = jnp.zeros_like(u), []
+            for layer in range(layers):
+                y, state = scan(u + nudge, step, b, c, states[layer], length)
+                out.append(state)
+                nudge = 1e-6 * y
+            return jnp.stack(out), nudge
+        return jax.jit(run, donate_argnums=(0,))
+
+    def xla(u, step, b, c, state, length):
+        real = jnp.arange(u.shape[1])[None, :, None] < length
+        return decode_lib.mamba_scan(u, jnp.where(real, step, 0.0), a, b, c,
+                                     state)
+
+    def kernel(channels=None, block=None, width=None, unroll=8):
+        def scan(u, step, b, c, state, length):
+            return scan_lib.mamba_scan(
+                u, step, a, b, c, state, length, channels=channels,
+                block=block, width=width, unroll=unroll)
+        return scan
+
+    def ms_a_layer(fn, inputs, length, n=10):
+        states = jax.random.normal(ks[5], (layers, 1, N, d_inner))
+        out = fn(states, *inputs, length)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(out[0], *inputs, length)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / n / layers
+
+    fastest = []
+    for T in (512, 256, 128):
+        inputs = (jax.random.normal(ks[1], (1, T, d_inner)),
+                  jax.random.uniform(ks[2], (1, T, d_inner), minval=1e-3,
+                                     maxval=0.1),
+                  jax.random.normal(ks[3], (1, T, N)),
+                  jax.random.normal(ks[4], (1, T, N)))
+        lengths = (jnp.int32(T), jnp.int32(3 * T // 4))
+        # the two forms on one input, before either is timed
+        state = jax.random.normal(ks[5], (1, N, d_inner))
+        for length in lengths:
+            y_want, want = jax.jit(xla)(*inputs, state, length)
+            y_got, got = jax.jit(kernel())(*inputs, state + 0.0, length)
+            real = jnp.arange(T)[None, :, None] < length
+            print(json.dumps({"T": T, "length": int(length),
+                              "kernel_against_xla": {
+                "state_max_gap": float(jnp.abs(got - want).max()),
+                "y_max_gap_at_real_positions": float(
+                    jnp.abs(jnp.where(real, y_got - y_want, 0.0)).max()),
+                "y_max_past_length": float(
+                    jnp.abs(jnp.where(real, 0.0, y_got)).max())}}),
+                flush=True)
+        row = {"T": T, "form": "xla, decode.mamba_scan"}
+        for length in lengths:
+            row[f"ms_a_layer_at_{int(length)}"] = round(
+                ms_a_layer(every_layer(xla), inputs, length), 4)
+        print(json.dumps(row), flush=True)
+        rows = []
+        for form in [None] + fastest if fastest else KERNEL_FORMS:
+            if form is not None and (form[0] > d_inner or form[1] > T):
+                continue
+            scan = kernel(*(form or ()))
+            row = {"T": T, "form": "hvd_mamba_scan"}
+            if form is None:
+                row["sizes"] = "the program's own"
+            else:
+                row.update(zip(("channels_a_grid_step", "positions_a_grid_"
+                                "step", "channels_a_loop", "unroll"), form))
+            for length in lengths:
+                row[f"ms_a_layer_at_{int(length)}"] = round(
+                    ms_a_layer(every_layer(scan), inputs, length), 4)
+            print(json.dumps(row), flush=True)
+            rows.append((row[f"ms_a_layer_at_{T}"], form))
+        if not fastest:
+            fastest = [form for _, form in sorted(rows)[:2]]
+
+
+def xla_forms_sweep():
+    """One call at a time: the scan in XLA by positions a loop iteration
+    beside ``lax.associative_scan`` inside blocks, and ``mamba_step``
+    over 257 slots."""
     key = jax.random.PRNGKey(0)
     a = -jnp.exp(jax.random.normal(key, (N, DI)))
     for T in (512, 4096):
@@ -241,6 +350,28 @@ def main():
     ms = 1e3 * (time.perf_counter() - t0) / 50
     print(json.dumps({"mamba_step_257_slots_ms": round(ms, 3),
                       "GBps": round(2 * S * N * DI * 4 / ms / 1e6, 1)}))
+
+
+
+def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--step", action="store_true",
+                        help="the decode step's forms, not the scan's")
+    parser.add_argument("--xla-forms", action="store_true",
+                        help="the chunk's scan in XLA alone, by form: what "
+                             "PR 47 chose among")
+    parser.add_argument("--layers", type=int, default=26)
+    parser.add_argument("--slots", type=int, default=256,
+                        help="with --layers: a rehearsal's size, off the chip")
+    parser.add_argument("--channels", type=int, default=DI,
+                        help="the scan's channels: a rehearsal's, off the "
+                             "chip")
+    args = parser.parse_args()
+    if args.step:
+        return step_sweep(args.layers, args.slots)
+    if args.xla_forms:
+        return xla_forms_sweep()
+    return scan_sweep(args.layers, args.channels)
 
 
 if __name__ == "__main__":

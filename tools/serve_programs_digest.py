@@ -29,7 +29,9 @@ from horovod_tpu.serve.kv_cache import init_kv_cache, ring_width  # noqa: E402
 CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          ("trinity-large-ep8-5l", "mixed-backlog-decode"),
          ("ling-3.0-flash-ep4-7l", "reasoning-backlog-longtail"),
-         ("kimi-k2.7-code-ep32-6l", "repo-questions-backlog")]
+         ("kimi-k2.7-code-ep32-6l", "repo-questions-backlog"),
+         # its chunk programs changed with PR 49, its decode did not
+         ("jamba2-3b", "chat-backlog")]
 
 
 def i32(*shape):
