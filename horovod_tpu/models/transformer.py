@@ -87,7 +87,8 @@ class Rotary:
 
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
-LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba")
+LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba", "sparse",
+               "lightning")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,8 +164,9 @@ class TransformerConfig:
     # and params["layers"] (see `mixed`).
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
-    # One of the five LAYER_KINDS a layer ("sliding" | "full" here;
-    # "kda", "mla" and "mamba" below). A sliding layer sees the keys
+    # One of the LAYER_KINDS a layer ("sliding" | "full" here; "kda",
+    # "mla", "mamba", "sparse" and "lightning" below). A sliding layer
+    # sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
     # layer_rotary says otherwise. None: every layer is causal over
@@ -243,6 +245,41 @@ class TransformerConfig:
     # head_weights; the programs of one stack of one block take a
     # separate lm_head).
     tie_embeddings: bool = False
+    # A sixth and a seventh kind of layer_types (ISSUE 50; served, not
+    # trained). "sparse", InfLLM-v2's attention (MiniCPM4): a full
+    # layer's q, k, v, norms and gate, and beside the keys their means
+    # over kernels of sparse_kernel positions every sparse_stride (the
+    # COMPRESSED keys). A query at a position >= sparse_dense_len scores
+    # the kernels that are complete at its position (a softmax over
+    # them a head), a block of sparse_block positions by the largest
+    # score of a kernel that meets it, summed over the heads of the
+    # query's GQA group; the group attends the keys of its sparse_topk
+    # best blocks alone, the first sparse_init_blocks and the blocks
+    # that hold the sparse_window positions before the query always
+    # among them. A query below sparse_dense_len attends every key
+    # before it. "lightning", Lightning Attention's linear recurrence:
+    # q, k, v of n_heads x head_dim each (as many heads for k and v as
+    # for q, whatever n_kv_heads the sparse layers beside it have), q
+    # and k normed a head where qk_norm_per_head and rotated (in
+    # halves, at rope_theta), a float32 state S [heads, Dh, Dh] a
+    # sequence, S_t = lambda_h S_{t-1} + k_t^T v_t with the head's
+    # fixed decay lambda_h = exp(-2^(-8 (h + 1) / heads)), o_t =
+    # (q_t / sqrt(Dh)) S_t, an RMSNorm over all heads' outputs together
+    # and the gate of attn_gate.
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # MiniCPM's three multipliers (None: none): the embeddings times
+    # embed_multiplier, every residual branch (mixer and feed-forward)
+    # times residual_multiplier, the head's input divided by
+    # logit_divisor.
+    embed_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logit_divisor: Optional[float] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -265,10 +302,24 @@ class TransformerConfig:
             # kda and mla layers project for n_heads heads; the full
             # layers beside mamba layers alone keep their own n_kv_heads
             own_heads = {"kda", "mla"} & set(self.layer_types)
-            if self.stateful and (own_heads
-                                  and self.n_kv_heads != self.n_heads
-                                  or self.attn_gate or self.sandwich_norm
-                                  or self.qk_norm or self.qk_norm_per_head):
+            if "sparse" in self.layer_types and (
+                    self.sparse_kernel % self.sparse_stride
+                    or self.sparse_block % self.sparse_stride
+                    or self.sparse_window % self.sparse_block
+                    or self.sparse_dense_len % self.sparse_block
+                    or self.sparse_init_blocks
+                    + self.sparse_window // self.sparse_block
+                    > self.sparse_topk):
+                raise ValueError(
+                    "sparse layers need sparse_kernel and sparse_block in "
+                    "whole sparse_stride, sparse_window and sparse_dense_len "
+                    "in whole sparse_block, and the forced blocks "
+                    "(sparse_init_blocks and the window's) within "
+                    "sparse_topk")
+            if (own_heads or "mamba" in self.layer_types) and (
+                    own_heads and self.n_kv_heads != self.n_heads
+                    or self.attn_gate or self.sandwich_norm
+                    or self.qk_norm or self.qk_norm_per_head):
                 raise ValueError(
                     "kda and mla layers have n_heads heads of their own "
                     "projections, norms and gates: n_kv_heads = n_heads, "
@@ -335,10 +386,11 @@ class TransformerConfig:
     @property
     def stateful(self) -> bool:
         """Some layer keeps a state that is not cached keys and values
-        (a kda or mamba layer's recurrent state, an mla layer's
-        latent)."""
+        alone (a kda, mamba or lightning layer's recurrent state, an mla
+        layer's latent, a sparse layer's compressed keys)."""
         return bool(self.layer_types) and bool(
-            {"kda", "mla", "mamba"} & set(self.layer_types))
+            {"kda", "mla", "mamba", "sparse", "lightning"}
+            & set(self.layer_types))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
         """How layer ``layer`` rotates q and k, None for not at all:
@@ -346,9 +398,12 @@ class TransformerConfig:
         ``rope_theta``, but for the full layers of a stack with
         ``layer_types``, which then take no rotary embedding. (An mla
         layer rotates its ``mla_rope_dim`` values so; a kda or mamba
-        layer reads no position.)"""
+        layer reads no position; a sparse layer is a full layer here; a
+        lightning layer rotates, in halves, plainly.)"""
         kind = self.kind_of(layer)
-        if kind in ("kda", "mamba"):
+        if kind == "lightning":
+            return Rotary(self.rope_theta)
+        if kind in ("kda", "mamba", "sparse"):
             kind = "full"
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
@@ -410,6 +465,8 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
         "wo": P(None, "tp", "fsdp"),   # [L, H*Dh, D]
         "mlp_norm": P(None, None),
     }
+    if kind == "lightning":
+        layers["o_norm"] = P(None, "tp")   # [L, H*Dh], as wo's rows
     if kind == "kda":
         layers.update(conv_q=P(None, None, "tp"), conv_k=P(None, None, "tp"),
                       conv_v=P(None, None, "tp"), wa=mat, a_log=vec,
@@ -500,6 +557,8 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
     attention of ``kind``, drawing its keys from the iterator ``k`` in
     one fixed order."""
     D, H, Hkv, Dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if kind == "lightning":
+        Hkv = H                  # as many heads for k and v as for q
     dt = cfg.dtype
 
     def dense(kk, shape, fan_in):
@@ -592,6 +651,8 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             "wo": dense(next(k), (L, H * Dh, D), H * Dh),
             "mlp_norm": jnp.ones((L, D), dt),
         }
+        if kind == "lightning":
+            layers["o_norm"] = jnp.ones((L, H * Dh), dt)
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
         layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
@@ -908,7 +969,15 @@ def attention_residual(cfg: TransformerConfig, lp, x, o):
     y = (o @ lp["wo"]).astype(cfg.dtype)
     if cfg.sandwich_norm:
         y = _rmsnorm(y, lp["post_attn_norm"], cfg.norm_eps)
-    return x + y
+    return x + _branch(cfg, y)
+
+
+def _branch(cfg: TransformerConfig, y):
+    """A residual branch's output as the stream takes it: times
+    ``residual_multiplier`` where the configuration has one."""
+    if cfg.residual_multiplier is None:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
 # -- the two kinds of layer whose state is not cached keys (ISSUE 38) --
@@ -1034,6 +1103,68 @@ def mamba_residual(cfg: TransformerConfig, lp, x, y, z):
     return x + (o @ lp["w_out"]).astype(cfg.dtype)
 
 
+# -- the linear-attention kind (ISSUE 50): projections, decays, gate --
+
+def _rope_halves(x, pos, rotary: Rotary):
+    """Rotary embedding over the pairs ``(i, i + d / 2)`` (the rotated
+    halves of the lightning layers' family; :func:`_rope` pairs
+    ``(2i, 2i + 1)``). x [B, T, H, d], pos [T] or [B, T]; float32."""
+    d = x.shape[-1]
+    ang = (pos[..., None].astype(jnp.float32)
+           * jnp.asarray(rotary.frequencies(d)))[..., None, :]
+    if ang.ndim < x.ndim:
+        ang = ang[None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def lightning_decay(cfg: TransformerConfig):
+    """The heads' log-decays ``ln lambda_h = -2^(-8 (h + 1) / heads)``
+    [heads], float32: Lightning Attention's fixed slopes, the same in
+    every layer."""
+    heads = cfg.n_heads
+    return -(2.0 ** (-8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32)
+                     / heads))
+
+
+def lightning_inputs(cfg: TransformerConfig, lp, x, pos, layer: int):
+    """A lightning layer up to its recurrence: the normed input ``h``
+    and q, k, v [B, T, heads, Dh] float32: q and k normed a head where
+    ``qk_norm_per_head`` and rotated at ``pos`` as ``rotary_of(layer)``
+    says, q times ``Dh ** -0.5``."""
+    B, T = x.shape[:2]
+    heads, Dh = cfg.n_heads, cfg.head_dim
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    rotary = cfg.rotary_of(layer)
+
+    def project(w, norm=None):
+        y = (h @ lp[w]).reshape(B, T, heads, Dh)
+        if norm is None:                       # v: neither normed nor rotated
+            return y.astype(jnp.float32)
+        if cfg.qk_norm_per_head:
+            with jax.named_scope("qk_norm"):
+                y = _rmsnorm(y, lp[norm], cfg.norm_eps)
+        return _rope_halves(y, pos, rotary)
+
+    return (h, project("wq", "q_norm") * Dh ** -0.5, project("wk", "k_norm"),
+            project("wv"))
+
+
+def lightning_residual(cfg: TransformerConfig, lp, x, h, o):
+    """A lightning layer after its recurrence ``o`` [B, T, heads, Dh]
+    float32: RMSNorm over all heads' outputs together, the gate
+    ``sigmoid(h Wg)`` where ``attn_gate``, the output projection and the
+    residual."""
+    B, T = x.shape[:2]
+    o = _rmsnorm(o.reshape(B, T, -1), lp["o_norm"], cfg.norm_eps)
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+    return x + _branch(cfg, (o.astype(cfg.dtype) @ lp["wo"]
+                             ).astype(cfg.dtype))
+
+
 def mla_rotary(cfg: TransformerConfig) -> Rotary:
     """How the mla layers rotate (``rotary_of`` of the first of them:
     ``layer_rotary`` is by kind)."""
@@ -1111,7 +1242,7 @@ def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
         aux = jnp.zeros((), jnp.float32)
     if cfg.sandwich_norm:
         y = _rmsnorm(y, lp["post_mlp_norm"], cfg.norm_eps)
-    return x + y, aux
+    return x + _branch(cfg, y), aux
 
 
 def _scoped(name: str, attend):
@@ -1190,14 +1321,17 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
-    """The trainer's entry points refuse kda, mla and mamba layers by
-    name: their forward exists in the serve programs alone."""
+    """The trainer's entry points refuse kda, mla, mamba, sparse and
+    lightning layers by name: their forward exists in the serve programs
+    alone."""
     if cfg.stateful:
         raise NotImplementedError(
-            f"{what} does not run kda, mla or mamba layers: "
-            "models/transformer.py's decoder_layer has no backward through "
-            "the chunked delta-rule scan or the selective scan of "
-            "serve/decode.py and no latent attention (ROADMAP B14, B8). "
+            f"{what} does not run kda, mla or mamba layers, nor sparse or "
+            "lightning layers: models/transformer.py's decoder_layer has no "
+            "backward through the chunked delta-rule scan, the selective "
+            "scan or the decayed linear scan of serve/decode.py, no latent "
+            "attention and no selection of key blocks (ROADMAP B14, B8, "
+            "B18). "
             "The configuration is served through ServeEngine.")
 
 
@@ -1252,6 +1386,8 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
         x = embed_lookup(params["embed"], tokens, cfg.dtype, mesh)
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+        if cfg.embed_multiplier is not None:
+            x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
         x = constrain(x, ("dp", "fsdp"), "sp", None)
 
     def layer(x, lp, i=0):
@@ -1278,6 +1414,8 @@ def forward_with_aux(params, tokens, cfg: TransformerConfig,
                             unroll=cfg.scan_unroll)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.logit_divisor is not None:
+            x = x / jnp.asarray(cfg.logit_divisor, x.dtype)
         logits = x @ head_weights(cfg, params)
         logits = constrain(logits, ("dp", "fsdp"), "sp", "tp")
     return logits, aux if cfg.mixed else auxes.sum()

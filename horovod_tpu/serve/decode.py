@@ -762,6 +762,138 @@ def mamba_step(u, step, a, b, c, state):
              + (step * u)[:, None] * b[..., None])
     return jnp.sum(state * c[..., None], axis=1), state
 
+#: Positions a block of :func:`lightning_scan` holds: the inside of a
+#: block is two masked ``[block, block]`` products a head, the state
+#: two ``[block, Dh] x [Dh, Dh]`` products; at 128 = Dh the two cost
+#: the same.
+_LIGHTNING_BLOCK = 128
+#: Key positions a chunk of a sparse layer gathers and attends at a
+#: time (whole pages), under the mask of what each query chose.
+_SPARSE_KEY_BLOCK = 1024
+
+
+def lightning_scan(q, k, v, g, state, block: int = _LIGHTNING_BLOCK):
+    """The decayed linear recurrence of a lightning layer over a chunk,
+    a block of ``block`` positions at a time: the inside of a block as
+    two masked matrix products, the state carried between blocks.
+
+    ``q`` (scaled), ``k``, ``v`` [B, T, H, Dh] float32, ``g`` [B, T, H]
+    the log-decay a position (a head's ``ln lambda_h``, <= 0), ``state``
+    [B, H, Dh, Dh] float32. A head:
+
+        S_t = exp(g_t) S_{t-1} + k_t^T v_t
+        o_t = q_t S_t
+
+    Returns ``(o [B, T, H, Dh], the state after the last position)``. A
+    position with ``g = 0`` and ``k = 0`` leaves the state as it was:
+    that is how a bucket's padding is written. Inside a block, with
+    ``G`` the running sum of ``g`` from the block's start: ``O = (Q
+    exp G) S_0 + ((Q K^T) * D) V`` with ``D_ts = exp(G_t - G_s)`` for
+    s <= t (each exponent <= 0: nothing overflows at any block size),
+    and ``S_C = exp(G_C) S_0 + (K exp(G_C - G))^T V``. Everything in
+    float32 at the highest matmul precision."""
+    B, T, H, D = q.shape
+    C = min(block, T)
+    pad = -T % C
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+        g = jnp.pad(g, ((0, 0), (0, pad), (0, 0)))
+    n = (T + pad) // C
+
+    def blocks(a):                                   # -> [n, B, H, C, .]
+        return jnp.moveaxis(a.reshape(B, n, C, H, -1), (1, 3), (0, 2))
+
+    q, k, v = map(blocks, (q, k, v))
+    G = jnp.cumsum(blocks(g[..., None])[..., 0], axis=-1)   # [n, B, H, C]
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    decay = jnp.exp(jnp.where(i >= j, G[..., :, None] - G[..., None, :],
+                              -jnp.inf))
+    A = jnp.einsum("...td,...sd->...ts", q, k, precision=_EXACT) * decay
+    qg = q * jnp.exp(G)[..., None]
+    k_end = k * jnp.exp(G[..., -1:] - G)[..., None]
+    at_end = jnp.exp(G[..., -1])                            # [n, B, H]
+
+    def one_block(S, xs):
+        A, qg, k_end, v, at_end = xs
+        o = (jnp.einsum("...tk,...kv->...tv", qg, S, precision=_EXACT)
+             + jnp.einsum("...ts,...sv->...tv", A, v, precision=_EXACT))
+        S = at_end[..., None, None] * S + jnp.einsum(
+            "...tk,...tv->...kv", k_end, v, precision=_EXACT)
+        return S, o
+
+    state, o = lax.scan(one_block, state, (A, qg, k_end, v, at_end))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, T + pad, H, D)
+    return o[:, :T], state
+
+
+def lightning_step(q, k, v, g, state):
+    """One position of :func:`lightning_scan`'s recurrence a row:
+    ``q``, ``k``, ``v`` [N, H, Dh], ``g`` [N, H], ``state``
+    [N, H, Dh, Dh]. Returns ``(o [N, H, Dh], the new state)``: the
+    state read once and written once."""
+    state = (jnp.exp(g)[..., None, None] * state
+             + k[..., :, None] * v[..., None, :])
+    return jnp.einsum("nhk,nhkv->nhv", q, state, precision=_EXACT), state
+
+
+def kernel_means(rows, stride: int, strides_a_kernel: int):
+    """The compressed keys of the kernels that lie whole in ``rows``
+    [N, Hkv, Dh] (N in whole strides): kernel i is the mean of rows
+    ``[stride * i, stride * (i + strides_a_kernel))``, float32 sums, in
+    ``rows``' dtype. ``N / stride - strides_a_kernel + 1`` of them."""
+    groups = rows.astype(jnp.float32).reshape(
+        -1, stride, *rows.shape[1:]).mean(1)
+    n = groups.shape[0] - strides_a_kernel + 1
+    return (sum(groups[u:u + n] for u in range(strides_a_kernel))
+            / strides_a_kernel).astype(rows.dtype)
+
+
+def sparse_block_scores(q, ck, exist, per: int, strides_a_kernel: int):
+    """What a sparse layer's queries make of each block of keys: ``q``
+    [B, C, H, Dh] over the compressed keys ``ck`` [B, J, Hkv, Dh] (J =
+    W * ``per``: ``per`` kernels start in a block) of which query c of
+    row b sees ``exist`` [B, C, J]. A head's scores are a softmax over
+    the kernels it sees of ``q . c / sqrt(Dh)``; a block's is the
+    largest over the kernels that meet it (those that start in it and
+    the ``strides_a_kernel - 1`` before them), summed over the heads of
+    the GQA group. Returns [B, C, Hkv, W] float32."""
+    B, C, H, Dh = q.shape
+    J, Hkv = ck.shape[1:3]
+    W, r = J // per, strides_a_kernel
+    seen = exist[:, None, None]                            # [B, 1, 1, C, J]
+    s = jnp.einsum("bqgrd,bjgd->bgrqj", q.reshape(B, C, Hkv, H // Hkv, Dh),
+                   ck, preferred_element_type=jnp.float32) * Dh ** -0.5
+    p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, _NEG_BIG), -1), 0.0)
+    # kernel j at j + r - 1: block b's kernels are then [per b, per b +
+    # per + r - 1), a whole group of `per` and the next one's first r - 1
+    p = jnp.pad(p, ((0, 0),) * 4 + ((r - 1, per - r + 1),))
+    best = p[..., :J].reshape(*p.shape[:-1], W, per).max(-1)
+    if r > 1:
+        best = jnp.maximum(best, p[..., per:].reshape(
+            *p.shape[:-1], W, per)[..., :r - 1].max(-1))
+    return jnp.moveaxis(best.sum(2), 1, 2)                  # [B, C, Hkv, W]
+
+
+def sparse_choose(scores, at_block, cfg):
+    """The ``sparse_topk`` best blocks of ``scores`` [B, C, G, W] for
+    queries in block ``at_block`` [B, C] (position // sparse_block):
+    among the blocks up to the query's own, the first
+    ``sparse_init_blocks`` and those that hold the ``sparse_window``
+    positions before it first, then by score (a tie: the lower block).
+    Returns ``(blocks [B, C, G, k] int32, chosen [B, C, G, k] bool)``,
+    ``k = min(sparse_topk, W)``; ``chosen`` is False where fewer than
+    ``k`` blocks lie at or before the query."""
+    W = scores.shape[-1]
+    b = jnp.arange(W, dtype=jnp.int32)
+    at = at_block[..., None, None]
+    valid = b <= at
+    forced = valid & ((b < cfg.sparse_init_blocks)
+                      | (b > at - cfg.sparse_window // cfg.sparse_block))
+    ranked = jnp.where(forced, jnp.inf, jnp.where(valid, scores, -jnp.inf))
+    best, blocks = lax.top_k(ranked, min(cfg.sparse_topk, W))
+    return blocks.astype(jnp.int32), best > -jnp.inf
+
 
 def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
     """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
@@ -864,7 +996,7 @@ def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions):
 
 
 def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
-                   compression=None, head=None):
+                   compression=None, head=None, chosen: bool = False):
     """(prefill, prefill_resume, decode, held_experts_counts), not
     jitted, of a configuration with layers of several kinds (the last
     is :func:`moe_share_report`'s). The caches ``kc`` and ``vc`` are
@@ -883,7 +1015,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     that kind's state around its attention. A program gives every
     layer the same ``call``: its positions and addresses. ``head`` maps
     float32 logits to what a program returns (None: their argmax, the
-    next token; the tests read the logits themselves)."""
+    next token; the tests read the logits themselves). ``chosen``: the
+    programs return a fourth value, the pages the queries of every
+    sparse layer chose ([n_sparse, C or B, Hkv, table_width] bool, all
+    False for a query below ``sparse_dense_len``): the same programs
+    with one more output, for the tests and the tolerance tool."""
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window = cfg.attn_window
     if head is None:
@@ -911,6 +1047,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                     None, compression)
             if cfg.embed_scale:
                 x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
+            if cfg.embed_multiplier is not None:
+                x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
             return x
 
     def layers(params, kc, vc, x, call, moe_fn=None):
@@ -944,6 +1082,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     def emit(params, x, rows):
         with jax.named_scope("head"):
             x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
+            if cfg.logit_divisor is not None:
+                x = x / jnp.asarray(cfg.logit_divisor, x.dtype)
             if cfg.tie_embeddings:
                 # x E^T, the table read where it lies and not turned
                 return head(jnp.einsum("...d,vd->...v", x, params["embed"]
@@ -1102,6 +1242,180 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                     vc = put(vc, "mamba", (c, call.slot), newest.reshape(-1))
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
 
+
+    # A sparse layer's sizes: `per` kernels start in a page, a kernel
+    # spans `strides` strides; a row past sparse_dense_len reads its
+    # sparse_topk chosen pages and one below it all of its own, at most
+    # `step_pages` either way.
+    stride = cfg.sparse_stride
+    per, strides = block_size // stride, cfg.sparse_kernel // stride
+    dense_pages = cfg.sparse_dense_len // block_size
+    step_pages = min(max(cfg.sparse_topk, dense_pages), table_width)
+    key_pages = min(_SPARSE_KEY_BLOCK // block_size or 1, table_width)
+
+    def sparse_kernels_seen(pos):
+        """[.., J]: the kernels of a table's pages that are complete at
+        ``pos`` [..]."""
+        last = stride * jnp.arange(table_width * per, dtype=jnp.int32) \
+            + cfg.sparse_kernel - 1
+        return last <= pos[..., None]
+
+    def kernels_behind(ck, c, tables):
+        """The compressed keys of layer ``c`` behind ``tables`` [B, W],
+        kernel by kernel: [B, W * per, Hkv, Dh]."""
+        return ck[c, tables].swapaxes(2, 3).reshape(
+            tables.shape[0], table_width * per, Hkv, Dh)
+
+    def sparse_chosen_pages(blocks, ok):
+        """[.., Hkv, W] bool out of the chosen list [.., Hkv, k]."""
+        lead = blocks.shape[:-1]
+        at = tuple(jnp.arange(n).reshape((1,) * i + (n,) + (1,) * (
+            len(lead) - i)) for i, n in enumerate(lead))
+        return jnp.zeros(lead + (table_width,), bool).at[
+            at + (blocks,)].set(ok)
+
+    def sparse_chunk(call, lp, kc, vc, c, x, i):
+        """A full layer's chunk with the selection inside it: the new
+        keys into their pages, the compressed keys of every kernel the
+        call completes AT ``length`` (a kernel spans two pages: the
+        first of them reaches back into the page before the chunk),
+        then each query past ``sparse_dense_len`` scores, pools and
+        chooses, and the chunk attends every key block up to its end
+        under the mask of what each query chose, with a running
+        softmax. A whole prompt no longer than ``sparse_dense_len``
+        attends over itself as a full layer's does."""
+        n = place["sparse"]
+        Tc = x.shape[1]
+        heads = jnp.arange(Hkv)
+        q, k, v = tf_lib.attention_inputs(cfg, lp, x, call.pos, i)
+        with jax.named_scope("attn_sparse"):
+            # a whole prompt under sparse_dense_len: a full layer's
+            attend_local = call.local and Tc <= cfg.sparse_dense_len
+            assert attend_local or kc is not None
+            if kc is not None:
+                with jax.named_scope("kv_write"):
+                    def pages_of(new):      # a page: [Hkv, block, Dh]
+                        return new[0].reshape(-1, block_size, Hkv, Dh
+                                              ).swapaxes(1, 2)
+                    kp, ck = kc[n]
+                    kp = kp.at[c, call.blks].set(pages_of(k).astype(kp.dtype))
+                    vc = put(vc, "sparse", (c, call.blks), pages_of(v))
+                with jax.named_scope("sparse_compress"):
+                    back = cfg.sparse_kernel - stride
+                    before = jnp.take(call.table, jnp.maximum(
+                        call.offset // block_size - 1, 0))
+                    rows = jnp.concatenate(
+                        [kp[c, before, :, block_size - back:].swapaxes(0, 1),
+                         k[0].astype(kp.dtype)])
+                    j = (call.offset - back) // stride + jnp.arange(
+                        Tc // stride, dtype=jnp.int32)
+                    whole = (j >= 0) & (stride * j + cfg.sparse_kernel
+                                        <= call.offset + call.length)
+                    slot = j // per
+                    blk = jnp.where(
+                        whole & (slot < table_width),
+                        jnp.take(call.table,
+                                 jnp.clip(slot, 0, table_width - 1)),
+                        NULL_BLOCK)
+                    ck = ck.at[c, blk[:, None], heads, (j % per)[:, None]
+                               ].set(kernel_means(rows, stride, strides))
+                kc = swap(kc, "sparse", (kp, ck))
+            if attend_local:
+                o = _attend_keys(q, k, v, call.pos, call.pos, None)
+            else:
+                past = call.pos[0] >= cfg.sparse_dense_len           # [Tc]
+                with jax.named_scope("sparse_select"):
+                    def select():
+                        scores = sparse_block_scores(
+                            q, kernels_behind(ck, c, call.table[None]),
+                            sparse_kernels_seen(call.pos), per, strides)
+                        return sparse_chosen_pages(*sparse_choose(
+                            scores, call.pos // block_size, cfg))[0]
+
+                    picked = lax.cond(
+                        past[-1], select, lambda: jnp.zeros(
+                            (Tc, Hkv, table_width), bool))
+                    picked &= past[:, None, None]
+                with jax.named_scope("sparse_attend"):
+                    o = sparse_attend_chunk(
+                        q, kp, vc[n], c, call.table, call.pos[0],
+                        picked | ~past[:, None, None])
+            if chosen:
+                call.chose.append(jnp.zeros((Tc, Hkv, table_width), bool)
+                                  if attend_local else picked)
+        return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
+
+    def sparse_attend_chunk(q, kp, vp, c, table, pos, allowed):
+        """``q`` [1, C, H, Dh] at ``pos`` [C] over the pages of layer
+        ``c`` behind ``table``, ``key_pages`` pages at a time up to the
+        chunk's end, each query over the keys at or before it in the
+        pages ``allowed`` [C, Hkv, W] it: float32 scores and a running
+        softmax, so that a chunk's scores are one key block's."""
+        C = q.shape[1]
+        KB = key_pages * block_size
+        n_blocks = -(-table_width // key_pages)
+        padded = n_blocks * key_pages
+        table = jnp.pad(table, (0, padded - table_width))
+        allowed = jnp.pad(allowed, ((0, 0), (0, 0),
+                                    (0, padded - table_width)))
+        qg = q[0].reshape(C, Hkv, H // Hkv, Dh)
+
+        def attend(j, carry):
+            acc, m, l = carry
+            with jax.named_scope("kv_gather"):
+                ids = lax.dynamic_slice_in_dim(table, j * key_pages,
+                                               key_pages)
+                keys = kp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
+                vals = vp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
+            key_pos = j * KB + jnp.arange(KB, dtype=jnp.int32)
+            seen = jnp.repeat(lax.dynamic_slice_in_dim(
+                allowed, j * key_pages, key_pages, 2), block_size, 2)
+            seen = seen & (key_pos[None, None] <= pos[:, None, None])
+            seen = jnp.moveaxis(seen, 0, 1)[:, None]        # [G, 1, C, KB]
+            s = jnp.einsum("qgrd,gkd->grqk", qg, keys,
+                           preferred_element_type=jnp.float32) * Dh ** -0.5
+            s = jnp.where(seen, s, _NEG_BIG)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+            scale = jnp.exp(m - m_new)
+            acc = acc * scale[..., None] + jnp.einsum(
+                "grqk,gkd->grqd", p.astype(vals.dtype), vals,
+                preferred_element_type=jnp.float32)
+            return acc, m_new, l * scale + p.sum(-1)
+
+        shape = (Hkv, H // Hkv, C)
+        acc, _, l = lax.fori_loop(
+            0, jnp.minimum(pos[-1] // KB + 1, n_blocks), attend,
+            (jnp.zeros(shape + (Dh,), jnp.float32),
+             jnp.full(shape, _NEG_BIG, jnp.float32),
+             jnp.zeros(shape, jnp.float32)))
+        o = acc / jnp.maximum(l, 1e-30)[..., None]
+        return jnp.moveaxis(o, 2, 0).reshape(1, C, H * Dh).astype(q.dtype)
+
+    def lightning_chunk(call, lp, kc, vc, c, x, i):
+        """The chunk's recurrence from the state the slot holds (zeros
+        for a sequence's first chunk, whatever the slot held), and the
+        state back as it is AT ``length``: a padded position decays
+        nothing and writes nothing."""
+        B, T = x.shape[:2]
+        n = place["lightning"]
+        with jax.named_scope("attn_lightning"):
+            h, q, k, v = tf_lib.lightning_inputs(cfg, lp, x, call.pos, i)
+            state = jnp.zeros((B, q.shape[2], q.shape[3], q.shape[3]),
+                              jnp.float32)
+            if not call.local:
+                state = jnp.where(call.offset > 0,
+                                  kc[n][c, call.slot][None], state)
+            real = jnp.arange(T)[None, :, None] < call.length
+            g = jnp.where(real, tf_lib.lightning_decay(cfg), 0.0)
+            with jax.named_scope("lightning_scan"):
+                o, state = lightning_scan(
+                    q, jnp.where(real[..., None], k, 0.0), v, g, state)
+            if kc is not None:
+                with jax.named_scope("state_write"):
+                    kc = put(kc, "lightning", (c, call.slot), state[0])
+        return kc, vc, tf_lib.lightning_residual(cfg, lp, x, h, o)
+
     # -- a decode step of the batch (one position a row) -------------
 
     def by_slot(call, rows, n_slots):
@@ -1239,6 +1553,112 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                  u.shape[0], -1))
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
 
+
+    def sparse_step(call, lp, kc, vc, c, x, i):
+        """A row's new key into its page and, where it completes a
+        kernel, that kernel's mean into the compressed keys; then ONE
+        gather of at most ``step_pages`` pages a row and KV head: a row
+        past ``sparse_dense_len`` scores the compressed keys behind its
+        table, pools, chooses, and reads its chosen pages; a row below
+        it reads its own first pages, all that hold a key of its. Never
+        the table's width of pages."""
+        n = place["sparse"]
+        B = x.shape[0]
+        t = call.positions
+        heads = jnp.arange(Hkv)
+        q, k, v = tf_lib.attention_inputs(cfg, lp, x, call.pos, i)
+        with jax.named_scope("attn_sparse"):
+            kp, ck = kc[n]
+            with jax.named_scope("kv_write"):
+                # a row of Dh values a KV head, as the pages lie
+                at = (c, call.blk[:, None], heads, (t % block_size)[:, None])
+                kp = kp.at[at].set(k.reshape(-1, Hkv, Dh).astype(kp.dtype))
+                vc = put(vc, "sparse", at, v.reshape(-1, Hkv, Dh))
+            with jax.named_scope("sparse_compress"):
+                first = t - cfg.sparse_kernel + 1
+                whole = (first >= 0) & (first % stride == 0)
+                at_pos = jnp.maximum(first, 0)[:, None] + jnp.arange(
+                    cfg.sparse_kernel, dtype=jnp.int32)        # [B, kernel]
+                blks = jnp.take_along_axis(
+                    call.tables,
+                    jnp.minimum(at_pos // block_size, table_width - 1), 1)
+                mean = kp[c, blks[..., None], heads,
+                          (at_pos % block_size)[..., None]].astype(
+                              jnp.float32).mean(1)
+                j = jnp.maximum(first, 0) // stride
+                slot = j // per
+                blk = jnp.where(
+                    whole & (slot < table_width),
+                    jnp.take_along_axis(
+                        call.tables,
+                        jnp.minimum(slot, table_width - 1)[:, None], 1)[:, 0],
+                    NULL_BLOCK)
+                ck = ck.at[c, blk[:, None], heads, (j % per)[:, None]].set(
+                    mean.astype(ck.dtype))
+            kc = swap(kc, "sparse", (kp, ck))
+            past = t >= cfg.sparse_dense_len                          # [B]
+            with jax.named_scope("sparse_select"):
+                scores = sparse_block_scores(
+                    q, kernels_behind(ck, c, call.tables),
+                    sparse_kernels_seen(call.pos), per, strides)
+                blocks, ok = sparse_choose(scores, call.pos // block_size,
+                                           cfg)
+                blocks, ok = blocks[:, 0], ok[:, 0]              # [B, G, k]
+                if chosen:
+                    call.chose.append(sparse_chosen_pages(blocks, ok)
+                                      & past[:, None, None])
+                more = step_pages - blocks.shape[-1]
+                own = jnp.arange(step_pages, dtype=jnp.int32)
+                pages = jnp.where(
+                    past[:, None, None],
+                    jnp.pad(blocks, ((0, 0), (0, 0), (0, more))), own)
+                read = jnp.where(
+                    past[:, None, None],
+                    jnp.pad(ok, ((0, 0), (0, 0), (0, more))),
+                    own <= (t // block_size)[:, None, None])
+            with jax.named_scope("sparse_attend"):
+                with jax.named_scope("kv_gather"):
+                    ids = jnp.take_along_axis(
+                        call.tables, pages.reshape(B, -1), 1).reshape(
+                            pages.shape)                     # [B, G, pages]
+                    keys = kp[c, ids, heads[:, None]]  # [B, G, pages, bs, Dh]
+                    vals = vc[n][c, ids, heads[:, None]]
+                S = step_pages * block_size
+                key_pos = (pages[..., None] * block_size + jnp.arange(
+                    block_size, dtype=jnp.int32)).reshape(B, Hkv, S)
+                seen = (jnp.repeat(read, block_size, -1)
+                        & (key_pos <= t[:, None, None]))[:, :, None]
+                sc = jnp.einsum(
+                    "bgrd,bgkd->bgrk", q.reshape(B, Hkv, H // Hkv, Dh),
+                    keys.reshape(B, Hkv, S, Dh),
+                    preferred_element_type=jnp.float32) * Dh ** -0.5
+                p = jax.nn.softmax(jnp.where(seen, sc, _NEG_BIG), axis=-1)
+                o = jnp.einsum("bgrk,bgkd->bgrd", p.astype(vals.dtype),
+                               vals.reshape(B, Hkv, S, Dh),
+                               preferred_element_type=jnp.float32
+                               ).astype(q.dtype).reshape(B, 1, H * Dh)
+        return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
+
+    def lightning_step_layer(call, lp, kc, vc, c, x, i):
+        """One step of the recurrence on every slot's state where it
+        lies, the batch's rows carried to their slots and the results
+        back (as :func:`kda_step_layer`): a slot that is not in the
+        batch decays by 1 and is written by 0."""
+        n = place["lightning"]
+        with jax.named_scope("attn_lightning"):
+            h, q, k, v = tf_lib.lightning_inputs(cfg, lp, x, call.pos, i)
+            with jax.named_scope("lightning_step"):
+                n_slots = kc[n].shape[1]
+                g = jnp.broadcast_to(tf_lib.lightning_decay(cfg),
+                                     q.shape[:1] + q.shape[2:3])
+                o, state = lightning_step(
+                    *(by_slot(call, a[:, 0], n_slots) for a in (q, k, v)),
+                    by_slot(call, g, n_slots), kc[n][c])
+                o = o[call.slots][:, None]
+            with jax.named_scope("state_write"):
+                kc = put(kc, "lightning", (c,), state)
+        return kc, vc, tf_lib.lightning_residual(cfg, lp, x, h, o)
+
     #: kind of layer -> how a chunk and how a decode step run it
     kinds = {
         "sliding": {"chunk": window_chunk, "step": window_step},
@@ -1246,6 +1666,9 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         "kda": {"chunk": kda_chunk, "step": kda_step_layer},
         "mla": {"chunk": mla_chunk, "step": mla_step},
         "mamba": {"chunk": mamba_chunk, "step": mamba_step_layer},
+        "sparse": {"chunk": sparse_chunk, "step": sparse_step},
+        "lightning": {"chunk": lightning_chunk,
+                      "step": lightning_step_layer},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -1268,10 +1691,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         held = ring_positions(offset[None] + Tc, ring) if n_win else None
         call = types.SimpleNamespace(
             step="chunk", local=local, pos=pos, offset=offset, length=length,
-            table=table, slot=slot, blks=blks, held=held)
+            table=table, slot=slot, blks=blks, held=held, chose=[])
         kc, vc, x = layers(params, kc, vc, x, call)
-        return kc, vc, emit(params, x,
-                            lambda x: jnp.take(x[0], length - 1, axis=0))
+        out = emit(params, x, lambda x: jnp.take(x[0], length - 1, axis=0))
+        return (kc, vc, out, jnp.stack(call.chose)) if chosen else (
+            kc, vc, out)
 
     def prefill(params, kc, vc, tokens, length, address):
         """A whole prompt, tokens [Tp] bucket-padded. Returns (kc, vc,
@@ -1300,9 +1724,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         blk = jnp.where(blk_i < table_width, blk, NULL_BLOCK)
         call = types.SimpleNamespace(
             step="step", pos=pos, positions=positions, tables=tables,
-            slots=slots, blk=blk)
+            slots=slots, blk=blk, chose=[])
         kc, vc, x = layers(params, kc, vc, x, call)
-        return kc, vc, emit(params, x, lambda x: x[:, 0])
+        out = emit(params, x, lambda x: x[:, 0])
+        return (kc, vc, out, jnp.stack(call.chose)) if chosen else (
+            kc, vc, out)
 
     def held_experts_counts(params, tokens):
         """The claims on each held expert of every MoE layer [n_moe,
@@ -1342,7 +1768,9 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
                 "layer's ring and a kda or mamba layer's recurrent state "
-                "are not pages another engine or a draft could be handed, "
+                "(a lightning layer's too) are not pages another engine or "
+                "a draft could be handed, nor are a sparse layer's "
+                "compressed keys, "
                 "and decode.py's inject and verify know K and V pages alone "
                 "(ROADMAP B9, B14)")
         return refuse

@@ -442,16 +442,17 @@ class ServeEngine:
         if self._slot_states:
             # A prefix is shared as pages mapped into another block
             # table: what every layer keeps of a position then has to be
-            # a page. A window layer's ring and a kda or mamba layer's
-            # recurrent state lie by batch slot (kv_cache.SLOT_KINDS),
-            # so those kinds refuse it; full and mla layers alone (K/V
-            # and latent pages) share.
+            # a page. A window layer's ring and a kda, mamba or lightning
+            # layer's recurrent state lie by batch slot
+            # (kv_cache.SLOT_KINDS), so those kinds refuse it; full, mla
+            # and sparse layers alone (K/V and latent pages, compressed
+            # keys behind the same tables) share.
             by_slot = self._kinds_by_slot()
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, and a kda or mamba layer's state after a "
-                 "prefix, cannot be mapped into another sequence: "
+                 "a window, and a kda, mamba or lightning layer's state "
+                 "after a prefix, cannot be mapped into another sequence: "
                  "engine._admit, kv_cache.BlockAllocator)",
                  cfg.prefix_caching and by_slot),
                 ("speculative decoding (draft/spec_k: speculative.py "
@@ -953,6 +954,12 @@ class ServeEngine:
         if "mamba" in self.cache.kinds:
             # positions the selective scan runs: the bucket, pads too
             extra["scanned"] = len(toks)
+        if "sparse" in self.cache.kinds:
+            # the call's queries that choose their blocks
+            extra["selected"] = max(0, offset + chunk - max(
+                offset, self.model_cfg.sparse_dense_len))
+            m.record_sparse(self.model_cfg, offset + chunk,
+                            prefill=extra["selected"])
         with m.phase("serve:prefill", device=True, n_tokens=chunk,
                      offset=offset, **extra) as ph:
             with ph.dispatch():
@@ -1011,8 +1018,9 @@ class ServeEngine:
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
                 f"configuration with {held} layers keeps a window layer's "
-                "keys in per-slot rings and a kda or mamba layer's "
-                "recurrent state by slot, which are not pages and which "
+                "keys in per-slot rings and a kda, mamba or lightning "
+                "layer's recurrent state by slot, which are not pages (nor "
+                "are a sparse layer's compressed keys K or V pages) and which "
                 "migrate.py and engine.inject_* do not move yet (ROADMAP "
                 "B9, B14)")
 
@@ -1377,6 +1385,23 @@ class ServeEngine:
             # both), and the positions its rows attend in the full layers
             extra["slots_stepped"] = self.cfg.max_batch + 1
             extra["attended"] = int(positions.sum()) + n
+        if "sparse" in self.cache.kinds:
+            # rows that choose their blocks (a padded row is at 0)
+            # and the keys a KV group of its rows attends: every one at
+            # or before a row below sparse_dense_len, those of its
+            # chosen blocks (the last of them its own, part filled) past it
+            c = self.model_cfg
+            chose = positions >= c.sparse_dense_len
+            extra["rows_selected"] = int(chose.sum())
+            extra["attended"] = int(np.where(
+                chose, (c.sparse_topk - 1) * c.sparse_block
+                + positions % c.sparse_block, positions).sum()) + n
+            # the compressed keys those rows score: the kernels complete
+            extra["scored"] = int(
+                ((positions[chose] - c.sparse_kernel) // c.sparse_stride
+                 + 1).sum())
+            m.record_sparse(c, int(positions.max()) + 1,
+                            decode=extra["rows_selected"])
         call = m.launch("serve:decode", n_active=n, ahead=prev is not None,
                         **extra)
         with call.dispatch():

@@ -292,13 +292,14 @@ class BlockAllocator:
 #: their arrays take in :class:`KVCache` (``full`` and ``sliding`` first
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
-STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba")
+STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
+               "lightning")
 #: Those of them whose arrays lie by batch slot and not behind the block
 #: tables: nothing of theirs is a page that another sequence, another
 #: engine or a draft could be handed (what ``engine.py`` refuses over
 #: them, and what ``KVCache.slot_bytes`` counts); and those of these
 #: that are a recurrent state, which ``state_slots_in_use`` counts.
-RECURRENT_KINDS = ("kda", "mamba")
+RECURRENT_KINDS = ("kda", "mamba", "lightning")
 SLOT_KINDS = ("sliding",) + RECURRENT_KINDS
 
 
@@ -322,9 +323,13 @@ class KVCache:
     layout.
 
     A configuration with layers of several kinds (``cfg.mixed``) keeps
-    **a state by kind of layer**: ``kinds`` names them, and ``k`` and
-    ``v`` hold, place for place, each kind's first and second array
-    (``of(kind)`` gives the pair), stacked over that kind's layers:
+    **a state by kind of layer**: ``kinds`` names them, and
+    ``of(kind)`` gives a kind's arrays, a tuple of the kind's own
+    length (one, two or three), each stacked over that kind's layers.
+    The serve programs take and return them as ``k`` and ``v``, place
+    for place by kind: ``k`` a kind's first array and ``v`` its second
+    (``None`` where it has one), and where it has three its first two
+    as a pair under ``k``:
 
     * ``full``: K and V pages ``[n, n_blocks, block_size, Hkv, Dh]``
       behind the block tables, as above;
@@ -335,8 +340,7 @@ class KVCache:
       float32, and the newest ``kda_conv - 1`` rows before the
       convolution ``[n, n_slots + 1, kda_conv - 1, 3 * H * Dh]``;
     * ``mla``: the latent pages ``[n, n_blocks, block_size,
-      latent_row(cfg)]`` behind the block tables, and no second array
-      (``None``);
+      latent_row(cfg)]`` behind the block tables, and no second array;
     * ``mamba``: the selective scan's state ``[n, n_slots + 1,
       mamba_d_state, Di]`` float32 (``Di = mamba_expand * d_model``; a
       state's ``[Di, N]`` turned so that its channels lie along the
@@ -345,7 +349,20 @@ class KVCache:
       ``mamba_d_conv - 1`` rows before the convolution, end to end,
       ``[n, n_slots + 1, (mamba_d_conv - 1) * Di]`` (as ``[.., 3, Di]``
       the 3 rows were a tile's 16 and every program copied the array on
-      its way in and out, a tenth of the device's time: chip, PR 47).
+      its way in and out, a tenth of the device's time: chip, PR 47);
+    * ``sparse``: K pages ``[n, n_blocks, Hkv, block_size, Dh]``, the
+      COMPRESSED keys ``[n, n_blocks, Hkv, block_size // sparse_stride,
+      Dh]`` (the means over the kernels that START in a page, behind
+      the same block tables: kernel j lies in page ``j // per`` at
+      ``j % per``), and V pages as K's. A page is a selected block
+      (``block_size`` is ``sparse_block``) and holds a KV head's
+      positions together: a decode step gathers the chosen pages of ONE
+      head a GQA group, and with the heads innermost, as a ``full``
+      layer's pages have them, every program turned the whole pool over
+      on its way in and back out (four copies of 545 MB a decode step:
+      compiled for the v5e, PR 50);
+    * ``lightning``: the decayed linear state ``[n, n_slots + 1, heads,
+      Dh, Dh]`` float32, and no second array.
 
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
@@ -360,10 +377,11 @@ class KVCache:
     ring: int = 0   # positions a window layer keeps a sequence
     kinds: Tuple[str, ...] = ()
 
-    def of(self, kind: str):
-        """``(first, second)`` array of ``kind``."""
+    def of(self, kind: str) -> Tuple[Any, ...]:
+        """``kind``'s arrays, in the order the class lists them."""
         i = self.kinds.index(kind)
-        return self.k[i], self.v[i]
+        first = self.k[i] if isinstance(self.k[i], tuple) else (self.k[i],)
+        return first + (() if self.v[i] is None else (self.v[i],))
 
     @property
     def slot_bytes(self) -> int:
@@ -426,7 +444,13 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
         tail = (cfg.n_kv_heads, Dh)
         d_inner = cfg.mamba_expand * cfg.d_model
         n = {kind: cfg.n_layers_of(kind) for kind in STATE_KINDS}
-        shapes = {   # kind -> (shape, dtype) of its first and second array
+        if n["sparse"] and block_size != cfg.sparse_block:
+            raise ValueError(
+                f"a sparse layer's selected block is a page: block_size "
+                f"{block_size} is not sparse_block {cfg.sparse_block}")
+        pages = ((n["sparse"], n_blocks, cfg.n_kv_heads, block_size, Dh),
+                 dtype)
+        shapes = {   # kind -> (shape, dtype) of what k and v hold of it
             "full": 2 * (((n["full"], n_blocks, block_size) + tail, dtype),),
             "sliding": 2 * (((n["sliding"], n_slots + 1, ring) + tail,
                              dtype),),
@@ -439,13 +463,24 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                        jnp.float32),
                       ((n["mamba"], n_slots + 1,
                         (cfg.mamba_d_conv - 1) * d_inner), dtype)),
+            "sparse": ((pages, ((n["sparse"], n_blocks, cfg.n_kv_heads,
+                                 block_size // cfg.sparse_stride, Dh),
+                                dtype)), pages),
+            "lightning": (((n["lightning"], n_slots + 1, cfg.n_heads, Dh, Dh),
+                           jnp.float32), None),
         }
         kinds = state_kinds(cfg)
 
+        def zeros(of):
+            """``of``: a (shape, dtype), a pair of such, or None."""
+            if of is None:
+                return None
+            if isinstance(of[1], tuple):
+                return tuple(map(zeros, of))
+            return jnp.zeros(*of)
+
         def arrays(place):
-            return tuple(shapes[kind][place]
-                         and jnp.zeros(*shapes[kind][place])
-                         for kind in kinds)
+            return tuple(zeros(shapes[kind][place]) for kind in kinds)
         return KVCache(k=arrays(0), v=arrays(1), block_size=block_size,
                        n_blocks=n_blocks, ring=ring, kinds=kinds)
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,

@@ -360,8 +360,9 @@ class ServeMetrics:
         # held for one sequence, and the rings in use, in blocks.
         self.kv_window_positions_max = 0
         self.kv_window_blocks_in_use = 0
-        # The kinds whose state is not keys (kda, mamba, mla): the batch
-        # slots whose recurrent state a sequence holds and their bytes; the
+        # The kinds whose state is not keys (kda, mamba, lightning,
+        # mla): the batch slots whose recurrent state a sequence holds
+        # and their bytes; the
         # positions the latent pool held for the rows of the last
         # decode call (their sum: what absorbed attention has to read),
         # and the most it held for one sequence.
@@ -369,6 +370,13 @@ class ServeMetrics:
         self.state_bytes = 0
         self.kv_latent_positions_live = 0
         self.kv_latent_positions_max = 0
+        # Sparse layers (a selection inside paged attention): the
+        # queries of the prefill calls and the rows of the decode calls
+        # that chose their blocks (at or past sparse_dense_len), and
+        # the most compressed keys (kernels) held for one sequence.
+        self.sparse_selected_queries_total = 0
+        self.sparse_selected_rows_total = 0
+        self.kv_compressed_max = 0
         # Speculative decoding (serve/speculative.py): proposal /
         # acceptance tallies (their ratio is the token-weighted accept
         # rate) and the per-round draft / verify wall-time series.
@@ -583,6 +591,17 @@ class ServeMetrics:
         self.kv_latent_positions_max = max(self.kv_latent_positions_max,
                                            int(held.max(initial=0)))
 
+    def record_sparse(self, cfg, held: int, prefill: int = 0,
+                      decode: int = 0) -> None:
+        """A call was launched after which the sparse layers hold
+        ``held`` positions of its longest sequence; ``prefill`` of its
+        queries, or ``decode`` of its rows, choose their blocks."""
+        self.sparse_selected_queries_total += prefill
+        self.sparse_selected_rows_total += decode
+        self.kv_compressed_max = max(
+            self.kv_compressed_max,
+            (held - cfg.sparse_kernel) // cfg.sparse_stride + 1)
+
     def record_latent_decode(self, lengths, key_block: int,
                              layers: int) -> None:
         """A decode call was launched whose rows hold ``lengths``
@@ -783,6 +802,11 @@ class ServeMetrics:
             "state_bytes": self.state_bytes,
             "kv_latent_positions_live": self.kv_latent_positions_live,
             "kv_latent_positions_max": self.kv_latent_positions_max,
+            # sparse layers (zeros without such layers)
+            "sparse_selected_queries_total":
+                self.sparse_selected_queries_total,
+            "sparse_selected_rows_total": self.sparse_selected_rows_total,
+            "kv_compressed_max": self.kv_compressed_max,
             "p50_first_token_ms": ms(percentile(self.first_token_s, 50)),
             "p99_first_token_ms": ms(percentile(self.first_token_s, 99)),
             "p50_per_token_ms": ms(percentile(self.per_token_s, 50)),

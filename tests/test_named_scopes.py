@@ -95,6 +95,38 @@ def test_serve_programs_carry_every_scope_name(program):
     assert f"jit({program})" in lowered.as_text(debug_info=True)
 
 
+@pytest.mark.parametrize("program", ["prefill_resume", "decode"])
+def test_sparse_and_lightning_programs_carry_every_scope_name(program):
+    """ISSUE 50: the names the sparse and lightning kinds' per-layer
+    metrics read, under ``attn``, in a chunk and in a decode step."""
+    from horovod_tpu.models import init_transformer
+    from horovod_tpu.serve.kv_cache import init_kv_cache
+
+    cfg = TransformerConfig.tiny(
+        dtype=jnp.float32, remat=False, n_layers=2, d_head=16,
+        layer_types=("sparse", "lightning"), qk_norm_per_head=True,
+        attn_gate=True, sparse_kernel=4, sparse_stride=2, sparse_block=BS,
+        sparse_topk=4, sparse_window=16, sparse_dense_len=16)
+    fns = dict(zip(("prefill", "prefill_resume", "decode"),
+                   decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                             table_width=6)))
+    params = jax.eval_shape(lambda: init_transformer(
+        cfg, jax.random.PRNGKey(0)))
+    kc, vc = jax.eval_shape(lambda: (lambda c: (c.k, c.v))(
+        init_kv_cache(cfg, 13, BS, n_slots=2)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    args = {"prefill_resume": (i32(8), i32(), i32(), (i32(6), i32())),
+            "decode": (i32(2), i32(2), (i32(2, 6), i32(2)))}[program]
+    lowered = fns[program].lower(params, kc, vc, *args)
+    words = _scope_words(lowered)
+    want = {"embed", "attn", "mlp", "head", "attn_sparse", "kv_write",
+            "sparse_compress", "sparse_select", "sparse_attend", "kv_gather",
+            "attn_lightning", "state_write",
+            "lightning_step" if program == "decode" else "lightning_scan"}
+    assert want <= words, want - words
+    assert f"jit({program})" in lowered.as_text(debug_info=True)
+
+
 def _instructions(lowered):
     """The optimised HLO with everything that only names things taken
     out: metadata, and the tables of files and stack frames."""
