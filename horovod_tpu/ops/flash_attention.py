@@ -220,9 +220,10 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,
     return out[:, :t], lse[:, 0, :t]
 
 
-def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, q_ref, k_ref,
-                     v_ref, qp_ref, kp_ref, *rest, scale: float,
-                     window: Optional[int], heads: int, carried: bool):
+def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, *rest,
+                     scale: float, window: Optional[int], heads: int,
+                     carried: bool, page: Optional[int] = None,
+                     q_per_kv: int = 1):
     """One (batch*head, q-block, kv-block) grid step of the forward over
     keys that carry their positions (:func:`flash_attention_keys`): the
     online softmax of :func:`_fwd_kernel` with the mask read from the
@@ -233,7 +234,18 @@ def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, q_ref, k_ref,
     another: a tile pair with no visible (query, key) computes
     nothing. ``carried``: two more inputs, the ``(out, lse)`` of the
     same queries over earlier keys, from which the softmax runs on
-    (``acc = out``, ``m = lse``, ``l = 1`` is that state)."""
+    (``acc = out``, ``m = lse``, ``l = 1`` is that state). ``page``: a
+    mask by page and query besides. Two more prefetched tables a (KV
+    row, q tile), the last kv tile in which the q tile computes
+    anything (the index maps' clamp, not read here) and, a kv tile
+    each, whether some query of the q tile was given some page of it;
+    and one more input, ``bits`` [bq, 1]: bit ``i`` of a query's int32
+    is page ``i`` of this kv tile."""
+    if page is not None:
+        _, given_ref, *rest = rest
+    q_ref, k_ref, v_ref, qp_ref, kp_ref, *rest = rest
+    if page is not None:
+        bits_ref, *rest = rest
     if carried:
         o_in_ref, lse_in_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     else:
@@ -257,6 +269,9 @@ def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, q_ref, k_ref,
     visible = (k_lo_ref[k_tile] <= q_hi_ref[q_tile]) & (k_hi_ref[k_tile] >= 0)
     if window is not None:
         visible &= k_hi_ref[k_tile] > q_lo_ref[q_tile] - window
+    if page is not None:
+        visible &= given_ref[((b // q_per_kv) * pl.num_programs(1) + qi)
+                             * pl.num_programs(2) + ki] != 0
 
     @pl.when(visible)
     def _body():
@@ -267,6 +282,11 @@ def _keys_fwd_kernel(q_hi_ref, q_lo_ref, k_lo_ref, k_hi_ref, q_ref, k_ref,
         seen = (kp >= 0) & (kp <= qp)
         if window is not None:
             seen &= kp > qp - window
+        if page is not None:
+            of_page = jax.lax.broadcasted_iota(
+                jnp.int32, kp.shape, 1) // page
+            seen &= (jax.lax.shift_right_logical(bits_ref[0], of_page)
+                     & 1) != 0
         s = jnp.where(seen, s, NEG_INF)
 
         m_prev = m_scr[:]
@@ -306,15 +326,65 @@ def _keys_blocks(c: int, k: int):
     at 32 heads: about 75 us a call and 7.5 us a head and tile, 45 %
     of the matrix unit's peak on a tile, where :func:`_fwd_kernel`
     stands; leaving the mask out of the tiles that need none moved it
-    by 1 % and was not kept."""
+    by 1 % and was not kept.
+
+    Under a page mask (2026-10-02, ``tools/prefill_attn_sweep.py
+    --sparse``: ``[32, 1024, 128]`` bf16 queries over 2 KV heads' keys
+    in pages of 64, a chunk that ends at key 8192 / 16 384 / 32 768,
+    every query 64 pages of its own choice, ms a layer with both
+    gathers): 1024x1024 **1.42 / 2.50 / 4.55**, 512x1024 1.61 / 2.72 /
+    4.89, 1024x512 1.90 / 3.22 / 5.83, 512x512 2.03 / 3.24 / 5.58: the
+    same order. A KV head a grid row with its 16 heads' queries
+    interleaved (q tiles of 64 queries) took 3.34 / 4.38 / 6.46: the
+    tiles are the same, the mask repeated 16 times in XLA is not. With
+    the index maps' clamp off 1.42 / 2.50 / 4.55 and with the skip by
+    choice off 1.42 / 2.51 / 4.56: neither costs or gives anything
+    where every tile holds some query's page; where a chunk's queries
+    agree on their 64 pages, 89 % of the causal tile pairs at 32 768
+    keys are computed in 4.11."""
     return min(1024, _round_up(c, 128)), min(1024, _round_up(k, 128))
+
+
+def _page_bits(page_mask, cp: int, n_k: int, per: int):
+    """``page_mask`` [R, C, P] bool as one int32 a (row, kv tile,
+    query), [R, n_k, cp]: bit ``i`` is page ``i`` of the kv tile's
+    ``per``. A padded query and a page past ``P`` have no bit."""
+    r, c, p = page_mask.shape
+    mask = jnp.pad(page_mask, ((0, 0), (0, cp - c), (0, n_k * per - p)))
+    bits = jnp.left_shift(mask.reshape(r, cp, n_k, per).astype(jnp.int32),
+                          jnp.arange(per, dtype=jnp.int32)).sum(-1)
+    return jnp.moveaxis(bits, 2, 1)
+
+
+def _page_tables(bits, bounds, bq: int, window: Optional[int]):
+    """The two prefetched tables of a masked call from ``bits``
+    [R, n_k, cp] and the tiles' position ``bounds`` (a group after
+    another; ``R`` whole groups): ``last`` [R * n_q], the last kv tile
+    in which a q tile computes anything (0 where it computes nothing),
+    and ``given`` [R * n_q * n_k], whether the tile pair holds a query
+    that may see a key by position and some query that was given some
+    page of the kv tile."""
+    r, n_k, cp = bits.shape
+    n_q = cp // bq
+    q_hi, q_lo, k_lo, k_hi = (
+        jnp.repeat(a.reshape(-1, n), r // (a.size // n), 0)
+        for a, n in zip(bounds, (n_q, n_q, n_k, n_k)))
+    given = (k_lo[:, None] <= q_hi[..., None]) & (k_hi[:, None] >= 0)
+    if window is not None:
+        given &= k_hi[:, None] > q_lo[..., None] - window
+    given &= jnp.moveaxis(
+        (bits.reshape(r, n_k, n_q, bq) != 0).any(-1), 1, 2)
+    last = jnp.where(given, jnp.arange(n_k, dtype=jnp.int32), 0).max(-1)
+    return last.reshape(-1), given.reshape(-1).astype(jnp.int32)
 
 
 def flash_attention_keys(q, k, v, q_pos, k_pos, *, scale: float,
                          window: Optional[int] = None,
                          block_q: Optional[int] = None,
                          block_k: Optional[int] = None,
-                         carry=None, interpret: Optional[bool] = None):
+                         carry=None, page_mask=None,
+                         page: Optional[int] = None,
+                         interpret: Optional[bool] = None):
     """The flash forward over keys that carry their positions: ``q``
     ``[BH, C, Dk]`` at positions ``q_pos`` ``[G, C]`` over ``k``
     ``[BHkv, K, Dk]`` and ``v`` ``[BHkv, K, Dv]`` at ``k_pos``
@@ -334,6 +404,21 @@ def flash_attention_keys(q, k, v, q_pos, k_pos, *, scale: float,
     the result between the calls (merging two results by their
     logsumexp outside the kernel took 5.23 ms a layer where carrying
     takes 4.98, ``_keys_blocks``' sweep).
+
+    ``page_mask`` ``[BHkv, C, P]`` bool with ``page``: the keys lie in
+    pages of ``page`` (key ``n`` in page ``n // page``; a page past
+    the ``P`` given is seen by nobody), and query ``c`` of every head of KV row ``r`` sees a key only where
+    ``page_mask[r, c]`` has its page besides (a sparse layer's chunk:
+    ``serve/decode.py::sparse_attend_pages``). The kv tile must be whole
+    pages, 32 at most: a (KV row, kv tile, query) is one int32 with a
+    bit a page, a ``[bq, 1]`` tile beside the queries' positions, and
+    the mask inside the tile is a shift, an and and a compare. A tile
+    pair in which no query was given a page is skipped like one with no
+    visible pair, and the K/V index maps stop at the last kv tile a q
+    tile computes (a repeated block is not fetched again), so that
+    keys past what the call sees cost nothing (:func:`_page_tables`).
+    ``BHkv`` must be whole position groups. With ``page_mask=None`` the
+    call is the one it was, operand for operand.
 
     Scores, softmax statistics and the accumulator are float32 tiles in
     VMEM; the two dots take the operands in their own dtype and ``p``
@@ -374,25 +459,53 @@ def flash_attention_keys(q, k, v, q_pos, k_pos, *, scale: float,
         jnp.pad(carry[0], ((0, 0), (0, cp - c), (0, 0))),
         jnp.pad(carry[1], ((0, 0), (0, cp - c)),
                 constant_values=NEG_INF)[:, None, :]]
+    n_q, n_k = cp // bq, kp // bk
 
+    def kv_tile(b, i, j, *_):
+        return j
+
+    tables, masked, bits_spec = (), [], []
+    if page_mask is not None:
+        if (page is None or bk % page or bk // page > 32 or bkv % groups
+                or page_mask.shape[:2] != (bkv, c)
+                or page_mask.shape[2] * page > kp):
+            raise ValueError(
+                f"flash_attention_keys: page_mask {page_mask.shape} with "
+                f"pages of {page} for q {q.shape}, k {k.shape} in "
+                f"{groups} position groups and kv tiles of {bk}")
+        bits = _page_bits(page_mask, cp, n_k, bk // page)
+        tables = _page_tables(bits, bounds, bq, window)
+        masked = [bits.reshape(bkv * n_k, cp, 1)]
+
+        def kv_tile(b, i, j, *pre):
+            # pre[4]: the last kv tile a (KV row, q tile) computes
+            return jnp.minimum(j, pre[4][(b // q_per_kv) * n_q + i])
+
+        bits_spec = [pl.BlockSpec(
+            (1, bq, 1), lambda b, i, j, *pre: (
+                (b // q_per_kv) * n_k + kv_tile(b, i, j, *pre), i, 0))]
+
+    inputs = (*bounds, *tables, q, k, v, q_pos[:, :, None],
+              k_pos[:, None, :], *masked, *carried)
     out, lse = pl.pallas_call(
         functools.partial(_keys_fwd_kernel, scale=float(scale),
                           window=window, heads=heads,
-                          carried=carry is not None),
+                          carried=carry is not None,
+                          page=page if masked else None, q_per_kv=q_per_kv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(bh, cp // bq, kp // bk),
+            num_scalar_prefetch=4 + len(tables),
+            grid=(bh, n_q, n_k),
             in_specs=[
                 pl.BlockSpec((1, bq, dk), lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, bk, dk),
-                             lambda b, i, j, *_: (b // q_per_kv, j, 0)),
-                pl.BlockSpec((1, bk, dv),
-                             lambda b, i, j, *_: (b // q_per_kv, j, 0)),
+                pl.BlockSpec((1, bk, dk), lambda b, i, j, *pre: (
+                    b // q_per_kv, kv_tile(b, i, j, *pre), 0)),
+                pl.BlockSpec((1, bk, dv), lambda b, i, j, *pre: (
+                    b // q_per_kv, kv_tile(b, i, j, *pre), 0)),
                 pl.BlockSpec((1, bq, 1),
                              lambda b, i, j, *_: (b // heads, i, 0)),
-                pl.BlockSpec((1, 1, bk),
-                             lambda b, i, j, *_: (b // heads, 0, j)),
-            ] + ([out_spec, lse_spec] if carried else []),
+                pl.BlockSpec((1, 1, bk), lambda b, i, j, *pre: (
+                    b // heads, 0, kv_tile(b, i, j, *pre))),
+            ] + bits_spec + ([out_spec, lse_spec] if carried else []),
             out_specs=[out_spec, lse_spec],
             scratch_shapes=[
                 pltpu.VMEM((bq, dv), jnp.float32),
@@ -403,12 +516,13 @@ def flash_attention_keys(q, k, v, q_pos, k_pos, *, scale: float,
             jax.ShapeDtypeStruct((bh, cp, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, cp), jnp.float32),
         ],
-        # the carried pair is updated where it lies (inputs 9 and 10
-        # behind the four prefetched arrays and the five operands)
-        input_output_aliases={9: 0, 10: 1} if carried else {},
+        # the carried pair is updated where it lies (the last two inputs,
+        # behind the prefetched arrays and the operands)
+        input_output_aliases=(
+            {len(inputs) - 2: 0, len(inputs) - 1: 1} if carried else {}),
         interpret=interpret,
         name="hvd_flash_keys_fwd",
-    )(*bounds, q, k, v, q_pos[:, :, None], k_pos[:, None, :], *carried)
+    )(*inputs)
     return out[:, :c], lse[:, 0, :c]
 
 

@@ -770,6 +770,24 @@ _LIGHTNING_BLOCK = 128
 #: Key positions a chunk of a sparse layer gathers and attends at a
 #: time (whole pages), under the mask of what each query chose.
 _SPARSE_KEY_BLOCK = 1024
+#: The q tile of a sparse layer's chunk through the kernel
+#: (:func:`sparse_attend_pages`); a narrower chunk stays in XLA
+#: (:func:`sparse_attend_chunk`). On the v5e (2026-10-02,
+#: ``tools/prefill_attn_sweep.py --sparse``: 32 heads over 2 KV heads
+#: of 128, pages of 64 behind a table of 520, each query 64 chosen
+#: pages), ms a layer of the XLA form -> the kernel, a chunk that ends
+#: at key 8192 / 16 384 / 32 768: C = 1024 5.11 -> **1.42**, 10.12 ->
+#: **2.50**, 20.05 -> **4.55** (the XLA form writes a float32
+#: ``[2, 16, 1024, 1024]`` a key block and reads it three times; the
+#: kernel takes 0.130 ms a key tile of 32 heads, 67 % of the matrix
+#: unit's peak, and 0.38 ms a call around its tiles: both gathers, the
+#: queries' and the result's transposes, the bits); C = 512 0.73 ->
+#: 0.90, 1.33 -> 1.44, 2.53 -> 2.54 and C = 256 0.42 -> 0.60, 0.75 ->
+#: 0.92, 1.40 -> 1.52: there XLA keeps a key block's scores on the
+#: chip of itself (0.075 ms a block of 32 heads by 512 queries is 58 %
+#: of the peak) and gathers no page past the chunk's end, so the call's
+#: fixed part decides.
+_SPARSE_KERNEL_CHUNK = 1024
 
 
 def lightning_scan(q, k, v, g, state, block: int = _LIGHTNING_BLOCK):
@@ -893,6 +911,108 @@ def sparse_choose(scores, at_block, cfg):
     ranked = jnp.where(forced, jnp.inf, jnp.where(valid, scores, -jnp.inf))
     best, blocks = lax.top_k(ranked, min(cfg.sparse_topk, W))
     return blocks.astype(jnp.int32), best > -jnp.inf
+
+
+def sparse_attend_chunk(q, kp, vp, c, table, pos, allowed):
+    """``q`` [1, C, H, Dh] at ``pos`` [C] over the pages of layer
+    ``c`` behind ``table``, ``key_pages`` pages at a time up to the
+    chunk's end, each query over the keys at or before it in the
+    pages ``allowed`` [C, Hkv, W] it: float32 scores and a running
+    softmax, so that a chunk's scores are one key block's. The form
+    in XLA: the chunk of a shape :func:`sparse_attend_taken` refuses,
+    and what the tests hold :func:`sparse_attend_pages` to."""
+    C, H, Dh = q.shape[1:]
+    Hkv, block_size = kp.shape[2:4]
+    table_width = table.shape[0]
+    key_pages = min(_SPARSE_KEY_BLOCK // block_size or 1, table_width)
+    KB = key_pages * block_size
+    n_blocks = -(-table_width // key_pages)
+    padded = n_blocks * key_pages
+    table = jnp.pad(table, (0, padded - table_width))
+    allowed = jnp.pad(allowed, ((0, 0), (0, 0),
+                                (0, padded - table_width)))
+    qg = q[0].reshape(C, Hkv, H // Hkv, Dh)
+
+    def attend(j, carry):
+        acc, m, l = carry
+        with jax.named_scope("kv_gather"):
+            ids = lax.dynamic_slice_in_dim(table, j * key_pages,
+                                           key_pages)
+            keys = kp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
+            vals = vp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
+        key_pos = j * KB + jnp.arange(KB, dtype=jnp.int32)
+        seen = jnp.repeat(lax.dynamic_slice_in_dim(
+            allowed, j * key_pages, key_pages, 2), block_size, 2)
+        seen = seen & (key_pos[None, None] <= pos[:, None, None])
+        seen = jnp.moveaxis(seen, 0, 1)[:, None]        # [G, 1, C, KB]
+        s = jnp.einsum("qgrd,gkd->grqk", qg, keys,
+                       preferred_element_type=jnp.float32) * Dh ** -0.5
+        s = jnp.where(seen, s, _NEG_BIG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        scale = jnp.exp(m - m_new)
+        acc = acc * scale[..., None] + jnp.einsum(
+            "grqk,gkd->grqd", p.astype(vals.dtype), vals,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l * scale + p.sum(-1)
+
+    shape = (Hkv, H // Hkv, C)
+    acc, _, l = lax.fori_loop(
+        0, jnp.minimum(pos[-1] // KB + 1, n_blocks), attend,
+        (jnp.zeros(shape + (Dh,), jnp.float32),
+         jnp.full(shape, _NEG_BIG, jnp.float32),
+         jnp.zeros(shape, jnp.float32)))
+    o = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.moveaxis(o, 2, 0).reshape(1, C, H * Dh).astype(q.dtype)
+
+
+def _sparse_key_tile(block_size: int, table_width: int) -> int:
+    """The kv tile of :func:`sparse_attend_pages`: ``_SPARSE_KEY_BLOCK``
+    positions, or a shorter table's rounded up to whole lanes."""
+    return min(_SPARSE_KEY_BLOCK, -(-table_width * block_size // 128) * 128)
+
+
+def sparse_attend_taken(chunk: int, block_size: int,
+                        table_width: int) -> bool:
+    """Whether a sparse layer's chunk of ``chunk`` positions over a
+    table of ``table_width`` pages of ``block_size`` attends through the
+    kernel (:func:`sparse_attend_pages`): the queries have to be whole
+    tiles of ``_SPARSE_KERNEL_CHUNK`` and the kv tile whole pages, 32 at
+    most (a bit each of an int32). By the shapes alone, here and on a
+    TPU."""
+    tile = _sparse_key_tile(block_size, table_width)
+    return (chunk % _SPARSE_KERNEL_CHUNK == 0 and tile % block_size == 0
+            and tile // block_size <= 32)
+
+
+@jax.jit
+def sparse_attend_pages(q, kp, vp, c, table, pos, allowed):
+    """:func:`sparse_attend_chunk` as ONE call of the Pallas flash
+    forward over keys that carry their positions
+    (``ops/flash_attention.py::flash_attention_keys`` with a page mask;
+    ``hvd_flash_keys_fwd`` in a device trace): the table's K and V pages
+    gathered once (``[Hkv, W * block, Dh]`` each; the kernel's index
+    maps stop at the chunk's last kv tile, so what lies past it is
+    copied here and read by nobody), a head a grid row with GQA by
+    index map, ``allowed`` as a bit a page inside the tile. Float32
+    scores, statistics and accumulator in VMEM, ``p`` rounded to the
+    values' dtype: no ``[.., C, K]`` array reaches HBM. Jitted of
+    itself, so that a program's call sites (a sparse layer each) trace
+    and lower it once."""
+    C, H, Dh = q.shape[1:]
+    Hkv, block_size = kp.shape[2:4]
+    tile = _sparse_key_tile(block_size, table.shape[0])
+    table = jnp.pad(table, (0, -table.shape[0] % (tile // block_size)))
+    with jax.named_scope("kv_gather"):
+        keys, vals = (pages[c, table].swapaxes(0, 1).reshape(Hkv, -1, Dh)
+                      for pages in (kp, vp))
+    n_keys = keys.shape[1]
+    o, _ = flash_attention_keys(
+        jnp.moveaxis(q[0], 1, 0), keys, vals, pos[None],
+        jnp.arange(n_keys, dtype=jnp.int32)[None], scale=Dh ** -0.5,
+        page_mask=jnp.moveaxis(allowed, 1, 0), page=block_size,
+        block_k=tile)
+    return jnp.moveaxis(o, 0, 1).reshape(1, C, H * Dh).astype(q.dtype)
 
 
 def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
@@ -1251,7 +1371,6 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     per, strides = block_size // stride, cfg.sparse_kernel // stride
     dense_pages = cfg.sparse_dense_len // block_size
     step_pages = min(max(cfg.sparse_topk, dense_pages), table_width)
-    key_pages = min(_SPARSE_KEY_BLOCK // block_size or 1, table_width)
 
     def sparse_kernels_seen(pos):
         """[.., J]: the kernels of a table's pages that are complete at
@@ -1337,60 +1456,14 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                             (Tc, Hkv, table_width), bool))
                     picked &= past[:, None, None]
                 with jax.named_scope("sparse_attend"):
-                    o = sparse_attend_chunk(
-                        q, kp, vc[n], c, call.table, call.pos[0],
-                        picked | ~past[:, None, None])
+                    attend = (sparse_attend_pages if sparse_attend_taken(
+                        Tc, block_size, table_width) else sparse_attend_chunk)
+                    o = attend(q, kp, vc[n], c, call.table, call.pos[0],
+                               picked | ~past[:, None, None])
             if chosen:
                 call.chose.append(jnp.zeros((Tc, Hkv, table_width), bool)
                                   if attend_local else picked)
         return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
-
-    def sparse_attend_chunk(q, kp, vp, c, table, pos, allowed):
-        """``q`` [1, C, H, Dh] at ``pos`` [C] over the pages of layer
-        ``c`` behind ``table``, ``key_pages`` pages at a time up to the
-        chunk's end, each query over the keys at or before it in the
-        pages ``allowed`` [C, Hkv, W] it: float32 scores and a running
-        softmax, so that a chunk's scores are one key block's."""
-        C = q.shape[1]
-        KB = key_pages * block_size
-        n_blocks = -(-table_width // key_pages)
-        padded = n_blocks * key_pages
-        table = jnp.pad(table, (0, padded - table_width))
-        allowed = jnp.pad(allowed, ((0, 0), (0, 0),
-                                    (0, padded - table_width)))
-        qg = q[0].reshape(C, Hkv, H // Hkv, Dh)
-
-        def attend(j, carry):
-            acc, m, l = carry
-            with jax.named_scope("kv_gather"):
-                ids = lax.dynamic_slice_in_dim(table, j * key_pages,
-                                               key_pages)
-                keys = kp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
-                vals = vp[c, ids].swapaxes(0, 1).reshape(Hkv, KB, Dh)
-            key_pos = j * KB + jnp.arange(KB, dtype=jnp.int32)
-            seen = jnp.repeat(lax.dynamic_slice_in_dim(
-                allowed, j * key_pages, key_pages, 2), block_size, 2)
-            seen = seen & (key_pos[None, None] <= pos[:, None, None])
-            seen = jnp.moveaxis(seen, 0, 1)[:, None]        # [G, 1, C, KB]
-            s = jnp.einsum("qgrd,gkd->grqk", qg, keys,
-                           preferred_element_type=jnp.float32) * Dh ** -0.5
-            s = jnp.where(seen, s, _NEG_BIG)
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
-            scale = jnp.exp(m - m_new)
-            acc = acc * scale[..., None] + jnp.einsum(
-                "grqk,gkd->grqd", p.astype(vals.dtype), vals,
-                preferred_element_type=jnp.float32)
-            return acc, m_new, l * scale + p.sum(-1)
-
-        shape = (Hkv, H // Hkv, C)
-        acc, _, l = lax.fori_loop(
-            0, jnp.minimum(pos[-1] // KB + 1, n_blocks), attend,
-            (jnp.zeros(shape + (Dh,), jnp.float32),
-             jnp.full(shape, _NEG_BIG, jnp.float32),
-             jnp.zeros(shape, jnp.float32)))
-        o = acc / jnp.maximum(l, 1e-30)[..., None]
-        return jnp.moveaxis(o, 2, 0).reshape(1, C, H * Dh).astype(q.dtype)
 
     def lightning_chunk(call, lp, kc, vc, c, x, i):
         """The chunk's recurrence from the state the slot holds (zeros

@@ -380,11 +380,14 @@ def test_causal_block_skip_multiblock_grid(bq, bk):
 
 # -- the forward over keys that carry their positions (ISSUE 44) ----------
 
-def _keys_reference(q, k, v, q_pos, k_pos, scale, window):
+def _keys_reference(q, k, v, q_pos, k_pos, scale, window, page_mask=None,
+                    page=None):
     """Plain attention in numpy's float64 with the mask read from the
     positions: key j of a query's group is seen where ``0 <= k_pos <=
     q_pos`` and, with a window, ``k_pos > q_pos - window``; a query
-    that sees no key reads zeros at ``lse = NEG_INF``."""
+    that sees no key reads zeros at ``lse = NEG_INF``. ``page_mask``
+    [BHkv, C, P]: key j besides only where its page ``j // page`` is
+    the query's KV row's."""
     from horovod_tpu.ops.flash_attention import NEG_INF
     heads = q.shape[0] // q_pos.shape[0]
     rep = q.shape[0] // k.shape[0]
@@ -396,6 +399,9 @@ def _keys_reference(q, k, v, q_pos, k_pos, scale, window):
     seen = (kp >= 0) & (kp <= qp)
     if window is not None:
         seen &= kp > qp - window
+    if page_mask is not None:
+        by_key = np.repeat(np.asarray(page_mask), page, 2)[..., :k.shape[1]]
+        seen = seen & np.repeat(by_key, rep, 0)
     s = np.where(seen, np.einsum("bqd,bkd->bqk", q, k) * scale, -np.inf)
     any_seen = seen.any(-1)
     top = np.where(any_seen, s.max(-1), 0.0)
@@ -518,6 +524,129 @@ def test_keys_forward_refuses_shapes_that_do_not_belong_together():
         q_pos=[_at(16, 16)], k_pos=[_at(0, 32)])
     with pytest.raises(ValueError, match="k_pos"):
         flash_attention_keys(q, k, v, q_pos, k_pos[:, :31], scale=1.0)
+
+
+# -- ... under a mask by page and query (ISSUE 51) -------------------------
+
+def _page_mask_case(name):
+    """(arguments, keywords, page mask) of one masked call: 128 queries
+    at 128.. over 256 keys (512 where the tail matters) in pages of 32,
+    tiles of 128 (4 pages a kv tile), every query of a KV row allowed
+    each page with probability 0.4 and then what the case is about."""
+    group = {"a_gqa_group_of_16": 16}.get(name, 2)
+    groups = 2 if name == "a_gqa_group_of_1_in_two_position_groups" else 1
+    heads = 2 if groups == 2 else group
+    c = 100 if name == "a_padded_query" else 128
+    keys = 512 if name == "keys_past_the_last_tile_the_call_sees" else 256
+    q, k, v, q_pos, k_pos = _keys_case(
+        heads=heads, kv_heads=heads // (1 if groups == 2 else group), c=c,
+        keys=keys, dk=32, dv=32,
+        q_pos=[_at(128 + 28 * g, c) for g in range(groups)],
+        k_pos=[_at(0, keys)] * groups)
+    rng = np.random.default_rng(1)
+    mask = rng.random((k.shape[0], c, keys // 32)) < 0.4
+    if name == "a_query_that_allows_every_page":
+        mask[:, 3] = True
+    if name == "a_first_tile_that_holds_none_of_a_query_s_pages":
+        mask[:, 7, :4], mask[:, 7, 4] = False, True
+        mask[:, 9] = False                  # and a query with no page
+    if name == "keys_past_the_last_tile_the_call_sees":
+        # a KV row none of whose queries was given a page of the second
+        # tile, and NaN wherever no tile pair is computed
+        mask[0, :, 4:8] = False
+        k = k.at[:, 256:].set(np.nan).at[0, 128:].set(np.nan)
+        v = v.at[:, 256:].set(np.nan).at[0, 128:].set(np.nan)
+    return (q, k, v, q_pos, k_pos), dict(
+        scale=0.17, block_q=128, block_k=128, page=32), mask
+
+
+@pytest.mark.parametrize("case", [
+    "a_gqa_group_of_1_in_two_position_groups", "a_gqa_group_of_16",
+    "a_query_that_allows_every_page",
+    "a_first_tile_that_holds_none_of_a_query_s_pages", "a_padded_query",
+    "keys_past_the_last_tile_the_call_sees", "carried_from_call_to_call"])
+def test_keys_forward_under_a_page_mask_matches_the_plain_reference(case):
+    """``flash_attention_keys(page_mask=)`` against the float64 softmax
+    over the keys a query sees by position AND by its KV row's pages."""
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    (q, k, v, q_pos, k_pos), kw, mask = _page_mask_case(case)
+    if case == "carried_from_call_to_call":
+        seen = None
+        for at, pages in ((slice(0, 128), slice(0, 4)),
+                          (slice(128, 256), slice(4, 8))):
+            seen = flash_attention_keys(
+                q, k[:, at], v[:, at], q_pos, k_pos[:, at], carry=seen,
+                page_mask=jnp.asarray(mask[..., pages]), **kw)
+        out, lse = seen
+    else:
+        out, lse = flash_attention_keys(q, k, v, q_pos, k_pos,
+                                        page_mask=jnp.asarray(mask), **kw)
+    finite = np.nan_to_num(np.asarray(k)), np.nan_to_num(np.asarray(v))
+    want, want_lse = _keys_reference(q, *map(jnp.asarray, finite), q_pos,
+                                     k_pos, 0.17, None, mask, 32)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+    if case == "a_first_tile_that_holds_none_of_a_query_s_pages":
+        assert not np.asarray(out[:, 9]).any()
+
+
+def test_a_page_mask_is_refused_where_a_kv_tile_is_not_whole_pages():
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    (q, k, v, q_pos, k_pos), kw, mask = _page_mask_case("a_padded_query")
+    for page, pages in ((48, 6), (2, 128), (32, 9)):   # 128 / 2 > 32 bits
+        with pytest.raises(ValueError, match="page_mask"):
+            flash_attention_keys(
+                q, k, v, q_pos, k_pos, **dict(kw, page=page),
+                page_mask=jnp.ones((k.shape[0], 100, pages), bool))
+
+
+def _unmasked_keys_calls():
+    from horovod_tpu.ops.flash_attention import flash_attention_keys
+    f32, i32 = jnp.float32, jnp.int32
+
+    def carried(q, k, v, qp, kp, o, lse):
+        return flash_attention_keys(q, k, v, qp, kp, scale=0.2,
+                                    carry=(o, lse), block_k=128)
+    return {
+        "plain": (lambda *a: flash_attention_keys(*a, scale=0.11), (
+            ((8, 160, 48), f32), ((8, 384, 48), f32), ((8, 384, 32), f32),
+            ((1, 160), i32), ((1, 384), i32))),
+        "two_groups_gqa_and_a_window": (
+            lambda *a: flash_attention_keys(
+                *a, scale=0.11, window=70, block_q=128, block_k=128), (
+            ((8, 130, 32), jnp.bfloat16), ((2, 260, 32), jnp.bfloat16),
+            ((2, 260, 16), jnp.bfloat16), ((2, 130), i32), ((2, 260), i32))),
+        "carried": (carried, (
+            ((4, 160, 48), f32), ((2, 384, 48), f32), ((2, 384, 32), f32),
+            ((1, 160), i32), ((1, 384), i32), ((4, 160, 32), f32),
+            ((4, 160), f32))),
+    }
+
+
+def test_the_keys_forward_without_a_mask_lowers_to_the_program_before_it():
+    """ISSUE 51 gave ``flash_attention_keys`` a page mask as one more
+    optional operand: without one (the latent chunks of the Kimi and
+    Ling cells) it lowers, interpret mode and no locations, to the text
+    it lowered to at the commit before, so its results are that
+    commit's bit for bit. The digests were written from a checkout of
+    c1c86e2."""
+    import hashlib
+    got = {}
+    for name, (f, args) in _unmasked_keys_calls().items():
+        text = jax.jit(f).lower(*(jax.ShapeDtypeStruct(s, d)
+                                  for s, d in args)).as_text()
+        got[name] = hashlib.sha256(text.encode()).hexdigest()
+    assert got == _BEFORE_PR51
+
+
+_BEFORE_PR51 = {
+    "plain":
+        "41b8d71e8ede76c589125ca092596720ac43c5a258120a9d57da00a065f77e99",
+    "two_groups_gqa_and_a_window":
+        "5b019334342a34be14a88e6f6f78027f886bc0490f345c83c723b82177627b53",
+    "carried":
+        "0b67238247e2239c908f557c0c03addbb048e207379906899f322417b6293788",
+}
 
 
 def _old_entry_points():

@@ -548,3 +548,90 @@ def test_the_two_references_are_one():
     assert body("tests/reference_minicpm_sala.py") == body(
         "benchmark/reference_minicpm_sala.py")
     assert "horovod_tpu" not in body("tests/reference_minicpm_sala.py")
+
+
+# (g) a chunk's attention through the kernel (ISSUE 51) -------------------
+
+def pages_case(dtype, chunk=128, page=32, width=14, offset=256):
+    """A chunk's queries at ``offset``.. over a layer's K and V pages
+    behind a shuffled table, each query of a KV head allowed a third of
+    the pages, one of them every page and one none."""
+    rng = np.random.default_rng(0)
+    H, Hkv, Dh = 8, 2, 16
+    kp, vp = (jnp.asarray(rng.standard_normal((2, width + 3, Hkv, page, Dh)),
+                          dtype) for _ in range(2))
+    q = jnp.asarray(2 * rng.standard_normal((1, chunk, H, Dh)), dtype)
+    table = jnp.asarray(1 + rng.permutation(width + 2)[:width], jnp.int32)
+    allowed = rng.random((chunk, Hkv, width)) < 0.3
+    allowed[3], allowed[5] = True, False
+    return (q, kp, vp, 1, table,
+            offset + jnp.arange(chunk, dtype=jnp.int32), jnp.asarray(allowed))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 0.08)])
+def test_a_chunk_s_pages_through_the_kernel_are_the_running_softmax_s(
+        dtype, tol):
+    """``sparse_attend_pages`` (one call of the flash forward under the
+    page mask) against ``sparse_attend_chunk`` (a key block at a time in
+    XLA): a chunk that ends in the table's second key tile, a query
+    that sees no key as zeros."""
+    args = pages_case(dtype, page=32, width=40, offset=900)
+    got = decode_lib.sparse_attend_pages(*args)
+    want = decode_lib.sparse_attend_chunk(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype
+    assert gap(got, want) < tol
+    assert not np.asarray(got[0, 5], np.float32).any()
+
+
+@pytest.mark.parametrize("chunk,page,width,taken", [
+    (1024, 64, 520, True), (2048, 32, 14, True), (1024, 64, 3, True),
+    (512, 64, 520, False),    # no whole tile of 1024 queries
+    (1024, 8, 160, False),    # a key tile of 128 pages: more than 32 bits
+    (1024, 48, 30, False)])   # a key tile that is no whole pages
+def test_the_kernel_is_taken_by_the_shapes_alone(chunk, page, width, taken):
+    assert decode_lib.sparse_attend_taken(chunk, page, width) is taken
+
+
+@pytest.mark.parametrize("chunk,kernel", [(1024, True), (96, False)])
+def test_a_sparse_chunk_attends_through_the_kernel_where_it_is_whole_tiles(
+        monkeypatch, chunk, kernel):
+    """``prefill_resume`` over pages of 64: a chunk of 1024 holds the
+    Pallas call once a sparse layer and its logits, states and pages
+    are the fall-back's within bfloat16's tolerance, past the dense
+    length with a choice of 4 of up to 32 pages; a chunk of 96 holds no
+    call and IS the fall-back."""
+    page = 64 if kernel else 32
+    cfg = tiny(dtype=jnp.bfloat16, sparse_block=page, sparse_window=page,
+               sparse_dense_len=2 * page, max_seq=2 * chunk + page)
+    params = seeded(cfg)
+    prompt = np.asarray(prompts_of(cfg, (2 * chunk,))[0], np.int32)
+    width = 2 * chunk // page + 1
+    assert decode_lib.sparse_attend_taken(chunk, page, width) is kernel
+
+    def served():
+        _, resume, _, _ = decode_lib.mixed_programs(
+            cfg, page, width, 0, head=lambda lg: lg)
+        cache = init_kv_cache(cfg, width + 1, page, n_slots=1)
+        kc, vc, rows = cache.k, cache.v, []
+        addr = (jnp.arange(1, width + 1, dtype=jnp.int32), jnp.int32(1))
+        text = str(jax.make_jaxpr(resume)(
+            params, kc, vc, prompt[:chunk], jnp.int32(0), jnp.int32(chunk),
+            addr))
+        resume = jax.jit(resume)
+        for off in range(0, len(prompt), chunk):
+            kc, vc, lg = resume(params, kc, vc, prompt[off:off + chunk],
+                                jnp.int32(off), jnp.int32(chunk), addr)
+            rows.append(np.asarray(lg, np.float32))
+        return text.count("hvd_flash_keys_fwd"), np.stack(rows), kc, vc
+
+    calls, rows, kc, vc = served()
+    assert calls == (TYPES.count("sparse") if kernel else 0)
+    monkeypatch.setattr(decode_lib, "sparse_attend_taken",
+                        lambda *shape: False)
+    calls, rows0, kc0, vc0 = served()
+    assert calls == 0
+    tol = 0.08 if kernel else 0.0
+    assert gap(rows, rows0) <= tol
+    for a, b in zip(jax.tree.leaves((kc, vc)), jax.tree.leaves((kc0, vc0))):
+        assert gap(a, b) <= tol

@@ -792,6 +792,19 @@ for name, fn, args in (
         "gathered_pages": max(
             [d[2] for d in made if len(d) == 5 and d[0] == SLOTS
              and d[3:] == (BS, 128)], default=0),
+        # the Pallas calls under the chunk's attention, and the float32
+        # arrays there that hold a row of 1024 scores or more a query
+        # over a megabyte of them (a KV head's [.., 1024, 1024])
+        "attend_kernels": sum(
+            "tpu_custom_call" in ln and "sparse_attend" in ln
+            for ln in sparse),
+        "attend_scores": sorted(set(
+            tuple(int(d) for d in dims.split(","))
+            for ln in sparse if "sparse_attend" in ln
+            for dims in re.findall(r"= \(?f32\[([\d,]+)\]", ln)
+            if int(dims.split(",")[-1]) >= 1024
+            and int(np.prod([int(d) for d in dims.split(",")]))
+            >= 1024 * 1024)),
         "scopes": sorted(set(re.findall(
             r"attn_sparse/(kv_write|sparse_\w+)"
             r"|attn_lightning/(lightning_\w+|state_write)", text))),
@@ -1147,8 +1160,9 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
     of keys (``[16, 520 * 64, 2, 128]``: what a full layer's step
     gathers) and gathers at most 128 pages a row and KV head (a row
     below the dense length all of its own, a row past it its 64
-    chosen); a chunk holds one key block's scores at a time. Every scope
-    the benchmark reads by name is in the program."""
+    chosen); a chunk holds no key block's scores at all (ISSUE 51: they
+    are tiles in VMEM). Every scope the benchmark reads by name is in
+    the program."""
     out = _compile_for_v5e(_SALA_DRIVER)
     got = out[program]
     assert got["aliased"] == 4, got
@@ -1166,4 +1180,11 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
         "lightning_step" if step else "lightning_scan", "state_write"}, got
     if step:
         assert 0 < got["gathered_pages"] <= 128, got
+    # ISSUE 51: a chunk attends through the Pallas flash forward under
+    # its page mask, one call a sparse layer, and holds no float32
+    # score array at all; a decode step is what it was (its rows'
+    # scores over the 128 pages they gather)
+    assert got["attend_kernels"] == (0 if step else 2), got
+    assert got["attend_scores"] == (
+        [[16, 2, 16, 128 * 64]] if step else []), got
     assert got["temp_bytes"] < 2 * out["pages_bytes"], got

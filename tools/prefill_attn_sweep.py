@@ -36,6 +36,23 @@ layer of the XLA form (``tests/reference_mla.py`` over
 ``ops/latent_decode.py``'s kernel) at each ``--key-blocks``, and of the
 kernel alone with the GB/s of the pages it reads; and the largest
 difference of the two forms relative to the largest magnitude.
+
+``--sparse`` sweeps a sparse layer's chunk (ISSUE 51) at
+``minicpm-sala-8l``'s shapes: ``[1, C, 32, 128]`` bf16 queries of a
+chunk that ends at key ``keys`` over pages ``[2, 64, 128]`` behind a
+table of 520 in shuffled order, each query of a KV head allowed 64
+pages at or before its own (every page below 8192). Two selections:
+``independent`` (every query draws its own: what seeded weights give)
+and ``agreeing`` (a chunk's queries share one draw). ms a layer of
+``serve/decode.py::sparse_attend_chunk`` (float32 ``[2, 16, C, 1024]``
+scores in HBM a key block: all a chunk had before ISSUE 51), of
+``sparse_attend_pages`` as it is, and of the kernel at each ``--tiles``
+pair in both query layouts (``heads``: a head a grid row; ``group``: a
+KV head a grid row, its heads' queries interleaved), without the index
+maps' clamp and without the skip by choice; beside each, the share of
+the causally visible tile pairs that it computes, and the largest
+difference from ``sparse_attend_chunk`` relative to the largest
+magnitude.
 """
 
 import argparse
@@ -334,8 +351,150 @@ def latent_decode_sweep(args) -> None:
                 print(json.dumps(row), flush=True)
 
 
+def sparse_form(layout: str, bq: int, bk: int):
+    """``sparse_attend_pages`` with the query layout and the tiles to
+    choose: ``heads`` is the programs' own."""
+    def form(q, kp, vp, table, pos, allowed):
+        C, H, Dh = q.shape[1:]
+        Hkv, page = kp.shape[2:4]
+        table = jnp.pad(table, (0, -table.shape[0] % (bk // page)))
+        keys, vals = (pages[0, table].swapaxes(0, 1).reshape(Hkv, -1, Dh)
+                      for pages in (kp, vp))
+        mask, r = jnp.moveaxis(allowed, 1, 0), H // Hkv
+        if layout == "heads":
+            qs, q_pos = jnp.moveaxis(q[0], 1, 0), pos
+        else:
+            qs = jnp.moveaxis(q[0].reshape(C, Hkv, r, Dh), 1, 0).reshape(
+                Hkv, C * r, Dh)
+            q_pos, mask = jnp.repeat(pos, r), jnp.repeat(mask, r, 1)
+        o, _ = flash_lib.flash_attention_keys(
+            qs, keys, vals, q_pos[None],
+            jnp.arange(keys.shape[1], dtype=jnp.int32)[None],
+            scale=Dh ** -0.5, page_mask=mask, page=page, block_q=bq,
+            block_k=bk)
+        if layout == "heads":
+            o = jnp.moveaxis(o, 0, 1)
+        else:
+            o = jnp.moveaxis(o.reshape(Hkv, C, r, Dh), 0, 1)
+        return o.reshape(1, C, H * Dh).astype(q.dtype)
+    return form
+
+
+def sparse_sweep(args) -> None:
+    H, HKV, DH, PAGE, TOPK, DENSE = 32, 2, 128, 64, 64, 8192
+    width, layers = args.table_pages, args.sparse_layers
+    rng = np.random.default_rng(0)
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    kp, vp = (jax.random.normal(k, (1, width + 1, HKV, PAGE, DH),
+                                jnp.bfloat16) for k in ks[:2])
+    table = jnp.asarray(1 + rng.permutation(width), jnp.int32)
+    tables_of = flash_lib._page_tables
+
+    def no_clamp(bits, bounds, bq, window):
+        last, given = tables_of(bits, bounds, bq, window)
+        return jnp.full_like(last, bits.shape[1] - 1), given
+
+    def no_skip(bits, bounds, bq, window):
+        return tables_of(jnp.ones_like(bits), bounds, bq, window)
+
+    def chained(form):
+        @jax.jit
+        def chain(q, kp, vp, table, pos, allowed):
+            return lax.scan(
+                lambda q, _: (form(q, kp, vp, table, pos, allowed).reshape(
+                    q.shape), None), q, None, length=layers)[0]
+        return chain
+
+    def xla(q, kp, vp, table, pos, allowed):
+        return decode_lib.sparse_attend_chunk(q, kp, vp, 0, table, pos,
+                                              allowed)
+
+    def pages(q, kp, vp, table, pos, allowed):
+        return decode_lib.sparse_attend_pages(q, kp, vp, 0, table, pos,
+                                              allowed)
+
+    for c in args.chunks:
+        q = jax.random.normal(ks[2], (1, c, H, DH), jnp.bfloat16)
+        for end in args.keys:
+            pos = np.arange(end - c, end)
+            at = pos // PAGE                                   # [C]
+            for selection in ("independent", "agreeing"):
+                draws = rng.random((1 if selection == "agreeing" else c,
+                                    HKV, width))
+                page = np.arange(width)
+                draws = np.where(page <= at[:, None, None], draws, 2.0)
+                draws[..., 0] = -1.0                   # the first page
+                draws[(page == at[:, None])[:, None].repeat(HKV, 1)] = -1.0
+                nth = np.sort(draws, -1)[..., TOPK - 1:TOPK]      # width >= TOPK
+                allowed = jnp.asarray(
+                    ((draws <= nth) & (draws < 2.0))
+                    | (pos < DENSE)[:, None, None]
+                    & (page <= at[:, None, None]))
+                xs = (q, kp, vp, table, jnp.asarray(pos, jnp.int32), allowed)
+                want = jax.jit(xla)(*xs).astype(jnp.float32)
+                row = {"C": c, "keys": end, "selection": selection,
+                       "xla": round(median_ms(chained(xla), xs, args.reps)
+                                    / layers, 4)}
+
+                def measure(name, form, tables=tables_of, tiles=None):
+                    flash_lib._page_tables = tables
+                    decode_lib.sparse_attend_pages.clear_cache()
+                    try:
+                        row[name] = round(median_ms(
+                            chained(form), xs, args.reps) / layers, 4)
+                        got = jax.jit(form)(*xs).astype(jnp.float32)
+                        row[name + "_rel_err"] = round(float(
+                            jnp.max(jnp.abs(got - want))
+                            / jnp.max(jnp.abs(want))), 5)
+                    except Exception as e:     # a tile Mosaic refuses
+                        row[name] = f"refused: {str(e)[:80]}"
+                    finally:
+                        flash_lib._page_tables = tables_of
+                    if tiles:
+                        row[name + "_tiles_pct"] = tiles_share(*tiles)
+
+                def tiles_share(layout, bq, bk):
+                    """% of the causally visible tile pairs computed."""
+                    r = H // HKV if layout == "group" else 1
+                    mask = jnp.repeat(jnp.moveaxis(allowed, 1, 0), r, 1)
+                    q_pos = jnp.repeat(xs[4], r)
+                    k_pos = jnp.arange(-(-width * PAGE // bk) * bk)
+                    bits = flash_lib._page_bits(
+                        mask, c * r, k_pos.size // bk, bk // PAGE)
+                    bounds = (q_pos.reshape(-1, bq).max(-1),
+                              q_pos.reshape(-1, bq).min(-1),
+                              k_pos.reshape(-1, bk).min(-1),
+                              k_pos.reshape(-1, bk).max(-1))
+                    given = tables_of(bits, bounds, bq, None)[1]
+                    causal = tables_of(jnp.ones_like(bits), bounds, bq,
+                                       None)[1]
+                    return round(100 * float(given.sum() / causal.sum()), 2)
+
+                measure("pages", pages, tiles=("heads", min(1024, c), 1024))
+                if selection == "independent" and c == max(args.chunks):
+                    measure("pages_no_clamp", pages, no_clamp)
+                    measure("pages_no_skip", pages, no_skip)
+                for layout in ("heads", "group"):
+                    for bq, bk in args.tiles or [[1024, 1024], [512, 1024],
+                                                 [1024, 512], [512, 512]]:
+                        if layout == "heads" and (
+                                bq > c or (bq, bk) == (min(1024, c), 1024)):
+                            continue          # no such tile, or `pages`
+                        if selection == "agreeing" and layout == "heads":
+                            continue
+                        measure(f"{layout}_{bq}x{bk}",
+                                sparse_form(layout, bq, bk),
+                                tiles=(layout, bq, bk))
+                print(json.dumps(row), flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--sparse", action="store_true",
+                    help="a sparse layer's chunk over its pages instead")
+    ap.add_argument("--table-pages", type=int, default=520)
+    ap.add_argument("--sparse-layers", type=int, default=8,
+                    help="calls chained in one program")
     ap.add_argument("--latent", action="store_true",
                     help="the mla layers' chunk instead of the prompt")
     ap.add_argument("--latent-decode", action="store_true",
@@ -345,10 +504,8 @@ def main() -> None:
                     default=["even", "spread", "tail"],
                     choices=["even", "spread", "tail"])
     ap.add_argument("--heads", type=int, nargs="+", default=[64, 32])
-    ap.add_argument("--chunks", type=int, nargs="+",
-                    default=[256, 512, 768, 1024])
-    ap.add_argument("--keys", type=int, nargs="+",
-                    default=[1024, 4096, 8192, 17408])
+    ap.add_argument("--chunks", type=int, nargs="+", default=None)
+    ap.add_argument("--keys", type=int, nargs="+", default=None)
     ap.add_argument("--key-blocks", type=int, nargs="+", default=None,
                     help="keys a call (default: the programs')")
     ap.add_argument("--merge-outside", action="store_true",
@@ -362,6 +519,12 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     args = ap.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.sparse:
+        args.chunks = args.chunks or [1024, 512, 256]
+        args.keys = args.keys or [8192, 16384, 32768]
+        return sparse_sweep(args)
+    args.chunks = args.chunks or [256, 512, 768, 1024]
+    args.keys = args.keys or [1024, 4096, 8192, 17408]
     if args.latent:
         return latent_sweep(args)
     if args.latent_decode:

@@ -31,7 +31,9 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          ("ling-3.0-flash-ep4-7l", "reasoning-backlog-longtail"),
          ("kimi-k2.7-code-ep32-6l", "repo-questions-backlog"),
          # its chunk programs changed with PR 49, its decode did not
-         ("jamba2-3b", "chat-backlog")]
+         ("jamba2-3b", "chat-backlog"),
+         # its chunk program changed with PR 51, its decode did not
+         ("minicpm-sala-8l", "longdoc-backlog")]
 
 
 def i32(*shape):
