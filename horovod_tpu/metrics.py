@@ -293,8 +293,9 @@ def render_gauges(prefix: str, values: Dict[str, object],
                   labels: Optional[Dict[str, str]] = None) -> str:
     """Shared exposition helper: render a flat dict as gauge families
     under ``prefix`` (None values are skipped — an empty latency series
-    has no sample, not a 0). The serving engine's snapshot renders
-    through here, so serving and training speak one text format.
+    has no sample, not a 0; a dict of numbers, a count by cause, is a
+    family a key: ``<key>_<its key>``). The serving engine's snapshot
+    renders through here, so serving and training speak one text format.
     ``labels`` (e.g. ``{"instance": "3"}``) ride every sample so
     several exporters of the same family — N engine replicas in one
     process — emit distinguishable series instead of colliding on the
@@ -306,8 +307,12 @@ def render_gauges(prefix: str, values: Dict[str, object],
             f'{_sanitize(k)}="{_escape_label(v)}"'
             for k, v in sorted(labels.items())) + "}"
     lines = []
-    for key in sorted(values):
-        v = values[key]
+    flat = {f"{key}_{sub}" if sub else key: v
+            for key, val in values.items()
+            for sub, v in (val.items() if isinstance(val, dict)
+                           else (("", val),))}
+    for key in sorted(flat):
+        v = flat[key]
         if v is None or isinstance(v, bool) or not isinstance(v, (int, float)):
             continue
         name = f"{_sanitize(prefix)}_{_sanitize(key)}"

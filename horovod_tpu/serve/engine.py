@@ -704,13 +704,16 @@ class ServeEngine:
             ph.args["queue"] = len(self._queue)
         if not (self._prefilling or self._active):
             m.record_idle()
-        if self.cache.ring:
-            m.kv_window_blocks_in_use = (
-                (self.cfg.max_batch - len(self._free_slots))
-                * self.cache.ring // self.cfg.block_size)
-        if set(RECURRENT_KINDS) & set(self.cache.kinds):
-            m.state_slots_in_use = self.cfg.max_batch - len(self._free_slots)
-            m.state_bytes = m.state_slots_in_use * self.cache.slot_bytes
+        recurrent = set(RECURRENT_KINDS) & set(self.cache.kinds)
+        if self.cache.ring or recurrent:
+            with m.phase("serve:gauges"):
+                in_use = self.cfg.max_batch - len(self._free_slots)
+                if self.cache.ring:
+                    m.kv_window_blocks_in_use = (
+                        in_use * self.cache.ring // self.cfg.block_size)
+                if recurrent:
+                    m.state_slots_in_use = in_use
+                    m.state_bytes = in_use * self.cache.slot_bytes
         if self._prefilling:
             self._drain("prefill")
         self._advance_prefills()
@@ -882,27 +885,36 @@ class ServeEngine:
         step — the monolithic behavior."""
         budget = self.cfg.prefill_chunk
         spent = 0
+        m = self.metrics
         while self._prefilling and (budget is None or spent < budget):
             seq = self._prefilling[0]
-            self._extend_prefix_match(seq)
-            remaining = len(seq.prompt) - seq.n_cached
-            if budget is None:
-                chunk = remaining
-            else:
-                # Cap by the UNSPENT budget, not the full chunk size:
-                # several queued suffixes could otherwise spend up to
-                # 2N-1 tokens in one step. Non-final chunks must end
-                # block-aligned (the next chunk's pages start there).
-                chunk = min(remaining, budget - spent)
-                if chunk < remaining:
-                    chunk -= chunk % self.cfg.block_size
-                    if chunk == 0:
-                        break
-            done_at = self._run_prefill_chunk(seq, chunk)
-            spent += chunk
-            if seq.n_cached >= len(seq.prompt):
-                self._prefilling.pop(0)
-                self._complete_prefill(seq, done_at)
+            # The host's own work around a chunk runs with nothing in
+            # flight: under a name each side of the call, so that
+            # `serve:unfed` can say where its host time went.
+            with m.phase("serve:prefill_prep"):
+                self._extend_prefix_match(seq)
+                remaining = len(seq.prompt) - seq.n_cached
+                if budget is None:
+                    chunk = remaining
+                else:
+                    # Cap by the UNSPENT budget, not the full chunk
+                    # size: several queued suffixes could otherwise
+                    # spend up to 2N-1 tokens in one step. Non-final
+                    # chunks must end block-aligned (the next chunk's
+                    # pages start there).
+                    chunk = min(remaining, budget - spent)
+                    if chunk < remaining:
+                        chunk -= chunk % self.cfg.block_size
+                        if chunk == 0:
+                            break
+                toks, extra = self._prefill_call(seq, chunk)
+            done_at = self._run_prefill_chunk(seq, chunk, toks, extra)
+            with m.phase("serve:prefill_post"):
+                self._record_prefill_chunk(seq)
+                spent += chunk
+                if seq.n_cached >= len(seq.prompt):
+                    self._prefilling.pop(0)
+                    self._complete_prefill(seq, done_at)
 
     def _extend_prefix_match(self, seq: _Seq) -> None:
         """Retry the cache walk just before prefilling. Admission in a
@@ -939,10 +951,9 @@ class ServeEngine:
             seq.mapped += extended
             self.metrics.record_prefix_extend(extended)
 
-    def _run_prefill_chunk(self, seq: _Seq, chunk: int) -> float:
-        """Run one chunk; returns when its host sync ended (engine
-        clock: the end of its ``serve:prefill`` span)."""
-        plen = len(seq.prompt)
+    def _prefill_call(self, seq: _Seq, chunk: int):
+        """The next chunk's padded tokens and what its span says of it
+        beside ``n_tokens`` and ``offset``."""
         offset = seq.n_cached
         toks = np.zeros(pick_bucket(chunk, self._prefill_buckets), np.int32)
         toks[:chunk] = seq.prompt[offset:offset + chunk]
@@ -960,8 +971,16 @@ class ServeEngine:
                 offset, self.model_cfg.sparse_dense_len))
             m.record_sparse(self.model_cfg, offset + chunk,
                             prefill=extra["selected"])
-        with m.phase("serve:prefill", device=True, n_tokens=chunk,
-                     offset=offset, **extra) as ph:
+        return toks, extra
+
+    def _run_prefill_chunk(self, seq: _Seq, chunk: int, toks: np.ndarray,
+                           extra: Dict[str, Any]) -> float:
+        """Run one chunk; returns when its host sync ended (engine
+        clock: the end of its ``serve:prefill`` span)."""
+        plen = len(seq.prompt)
+        offset = seq.n_cached
+        with self.metrics.phase("serve:prefill", device=True, n_tokens=chunk,
+                                offset=offset, **extra) as ph:
             with ph.dispatch():
                 if offset == 0 and chunk == plen:
                     # Whole cold prompt: the monolithic program (exactly
@@ -980,7 +999,12 @@ class ServeEngine:
         seq.n_cached = offset + chunk
         seq.last_prefill_tok = tok
         self._record_window_positions(offset + len(toks))
-        m.record_prefill()
+        return ph.end
+
+    def _record_prefill_chunk(self, seq: _Seq) -> None:
+        """Count the chunk that just ran and publish the blocks it
+        filled."""
+        self.metrics.record_prefill()
         if self.cfg.prefix_caching:
             # Publish the prompt blocks this chunk filled. A losing
             # race (hash already published by a concurrent twin) keeps
@@ -989,7 +1013,6 @@ class ServeEngine:
             for i in range(seq.registered, n_full):
                 self.allocator.register(seq.blocks[i], seq.chain[i])
             seq.registered = max(seq.registered, n_full)
-        return ph.end
 
     def _address(self, seq: _Seq):
         """Where a sequence's state lies, as the serve programs take
@@ -1313,11 +1336,13 @@ class ServeEngine:
         if fl is None:
             self._launch(None)
             return
-        stay = np.array([seq is not None
-                         and len(seq.generated) + 1 < seq.max_new
-                         for seq in fl.rows])
-        n = int(stay.sum())
-        leaving = sum(seq is not None for seq in fl.rows) - n
+        with self.metrics.phase("serve:decode_plan"):
+            stay = np.array([seq is not None
+                             and len(seq.generated) + 1 < seq.max_new
+                             for seq in fl.rows])
+            n = int(stay.sum())
+            leaving = sum(seq is not None for seq in fl.rows) - n
+            bucket = pick_bucket(n, self._batch_buckets)
         if n == 0:
             self._drain("idle")
         elif leaving and self._queue:
@@ -1327,7 +1352,7 @@ class ServeEngine:
             # the next call. Launched ahead, that call would run a row
             # short and the newcomer would join a step late.
             self._drain("admit")
-        elif pick_bucket(n, self._batch_buckets) != len(fl.rows):
+        elif bucket != len(fl.rows):
             self._drain("bucket")
             self._launch(None)
         else:
@@ -1344,8 +1369,9 @@ class ServeEngine:
         with the tokens the host holds."""
         m = self.metrics
         if prev is None:
-            seqs = [s for s in self._active
-                    if not s.finished(self.cfg.eos_id)]
+            with m.phase("serve:decode_plan"):
+                seqs = [s for s in self._active
+                        if not s.finished(self.cfg.eos_id)]
             if not seqs:
                 return
         with m.phase("serve:decode_prep"):
@@ -1372,36 +1398,36 @@ class ServeEngine:
                 tables = np.where(stay[:, None], prev.tables, np.int32(0))
                 slots = np.where(stay, prev.slots, np.int32(NULL_SLOT))
             address = (tables, slots) if self._slot_states else tables
-        n = sum(seq is not None for seq in rows)
-        # A decode step serves the whole batch, so it carries the
-        # trace ids of every sampled sequence in it (plural key).
-        traces = [s.trace for s in rows if s is not None and s.trace]
-        extra = {"traces": traces} if traces else {}
-        if "mamba" in self.cache.kinds:
-            # slots whose state the XLA form of the step read and wrote
-            # where it lies (since PR 48 the kernels touch the batch's
-            # rows alone; the count stays what its reader in the
-            # benchmark holds it to until a `benchmark` issue corrects
-            # both), and the positions its rows attend in the full layers
-            extra["slots_stepped"] = self.cfg.max_batch + 1
-            extra["attended"] = int(positions.sum()) + n
-        if "sparse" in self.cache.kinds:
-            # rows that choose their blocks (a padded row is at 0)
-            # and the keys a KV group of its rows attends: every one at
-            # or before a row below sparse_dense_len, those of its
-            # chosen blocks (the last of them its own, part filled) past it
-            c = self.model_cfg
-            chose = positions >= c.sparse_dense_len
-            extra["rows_selected"] = int(chose.sum())
-            extra["attended"] = int(np.where(
-                chose, (c.sparse_topk - 1) * c.sparse_block
-                + positions % c.sparse_block, positions).sum()) + n
-            # the compressed keys those rows score: the kernels complete
-            extra["scored"] = int(
-                ((positions[chose] - c.sparse_kernel) // c.sparse_stride
-                 + 1).sum())
-            m.record_sparse(c, int(positions.max()) + 1,
-                            decode=extra["rows_selected"])
+            n = sum(seq is not None for seq in rows)
+            # A decode step serves the whole batch, so it carries the
+            # trace ids of every sampled sequence in it (plural key).
+            traces = [s.trace for s in rows if s is not None and s.trace]
+            extra = {"traces": traces} if traces else {}
+            if "mamba" in self.cache.kinds:
+                # slots whose state the XLA form of the step read and wrote
+                # where it lies (since PR 48 the kernels touch the batch's
+                # rows alone; the count stays what its reader in the
+                # benchmark holds it to until a `benchmark` issue corrects
+                # both), and the positions its rows attend in the full layers
+                extra["slots_stepped"] = self.cfg.max_batch + 1
+                extra["attended"] = int(positions.sum()) + n
+            if "sparse" in self.cache.kinds:
+                # rows that choose their blocks (a padded row is at 0)
+                # and the keys a KV group of its rows attends: every one at
+                # or before a row below sparse_dense_len, those of its
+                # chosen blocks (the last of them its own, part filled) past it
+                c = self.model_cfg
+                chose = positions >= c.sparse_dense_len
+                extra["rows_selected"] = int(chose.sum())
+                extra["attended"] = int(np.where(
+                    chose, (c.sparse_topk - 1) * c.sparse_block
+                    + positions % c.sparse_block, positions).sum()) + n
+                # the compressed keys those rows score: the kernels complete
+                extra["scored"] = int(
+                    ((positions[chose] - c.sparse_kernel) // c.sparse_stride
+                     + 1).sum())
+                m.record_sparse(c, int(positions.max()) + 1,
+                                decode=extra["rows_selected"])
         call = m.launch("serve:decode", n_active=n, ahead=prev is not None,
                         **extra)
         with call.dispatch():

@@ -33,6 +33,17 @@ Surfaces:
   with ``ahead`` saying whether a call was in flight when it was
   launched. ``serve:host_gap`` is written only for time in which no
   call was in flight.
+* ``serve:unfed`` — every interval in which the engine had work and
+  the device had nothing enqueued: from the moment a call's result was
+  ready with no other call in flight to the moment the next call's
+  jitted call returned, in three parts (the readback, the host between
+  the read and the next launch, the dispatch), the host's part by the
+  phases that ran in it, and with why no successor was in flight
+  (:data:`UNFED_WHYS`). Where the engine ran out of work in between
+  (:meth:`ServeMetrics.record_idle`) the interval is the traffic's and
+  is written as ``serve:no_work``. ``device_unfed_s_total``,
+  ``device_unfed_s_by_why`` and ``device_no_work_s_total`` sum them for
+  an operator who has no profiler.
 * ``serve:stall`` — a device call or a host gap many times longer
   than its kind usually is, written with what the host was doing in it
   (its own CPU time, the process's, the collector's pauses, what
@@ -77,6 +88,14 @@ STALL_MIN_SAMPLES = 8
 #: queued request waits for the slot of a sequence that ends at the
 #: call in flight; pages are about to move in or out (export, inject).
 DRAIN_CAUSES = ("prefill", "bucket", "idle", "admit", "migrate")
+#: Why no successor was in flight when a call's result was ready
+#: (``why`` of ``serve:unfed``): the call was a decode call drained for
+#: one of :data:`DRAIN_CAUSES`; it was a prefill chunk, whose token the
+#: host reads before it launches anything; it was part of a speculative
+#: round, whose acceptance the host reads.
+UNFED_WHYS = DRAIN_CAUSES + ("prefill_read", "spec")
+_WHY_AFTER = {"serve:prefill": "prefill_read", "serve:spec_draft": "spec",
+              "serve:spec_verify": "spec"}
 #: The median is taken again every this many samples, so that a call
 #: pays one comparison and not a sort.
 _REMEDIAN_EVERY = 8
@@ -135,16 +154,19 @@ class DeviceCall(Phase):
     predecessor until then)."""
 
     __slots__ = ("name", "call", "dispatch_s", "wait_s", "_clock", "_mark",
-                 "_gc0", "_twin", "_before_s", "_gap")
+                 "_gc0", "_twin", "_before_s", "_gap", "_metrics")
 
-    def __init__(self, name: str, call: int, clock, gc_seq: int, args: dict):
+    def __init__(self, name: str, call: int, metrics: "ServeMetrics",
+                 args: dict):
         self._twin = TraceAnnotation(name, call=call)
         self._twin.__enter__()
+        clock = metrics._clock
         super().__init__(clock(), args)
         self.name, self.call, self._clock = name, call, clock
+        self._metrics = metrics
         self.dispatch_s = self.wait_s = self._before_s = 0.0
         self._mark = self.t0
-        self._gc0 = gc_seq
+        self._gc0 = metrics._gc.seq
         self._gap = None
 
     def _lap(self) -> float:
@@ -166,21 +188,23 @@ class DeviceCall(Phase):
 
     def read(self, out, to_host):
         """``to_host(out)`` once the jitted call's result ``out`` is
-        ready, as ``:wait`` and ``:readback`` inside ``:sync``: the wait
-        for the program, then the rest of the wait for the copy
+        ready, as ``:wait`` and then ``:readback``: the wait for the
+        program, then the rest of the wait for the copy
         (``np.asarray``, ``int``). The copy is asked for before the
         wait, so that it follows the program on the device's queue as
         it does when ``np.asarray`` meets a result that is not ready;
         asked for after the wait it costs the host one more round trip
-        (0.1 ms a call on the v5e)."""
+        (0.1 ms a call on the v5e). Where no other call is in flight
+        the device has nothing to run from the wait's end on
+        (``serve:unfed``)."""
         name, call = self.name, self.call
-        with TraceAnnotation(name + ":sync", call=call):
-            with TraceAnnotation(name + ":wait", call=call):
-                out.copy_to_host_async()
-                out.block_until_ready()
-            self.wait_s += self._lap()
-            with TraceAnnotation(name + ":readback", call=call):
-                return to_host(out)
+        with TraceAnnotation(name + ":wait", call=call):
+            out.copy_to_host_async()
+            out.block_until_ready()
+        self.wait_s += self._lap()
+        self._metrics._result_ready(self)
+        with TraceAnnotation(name + ":readback", call=call):
+            return to_host(out)
 
     @property
     def ready_s(self) -> float:
@@ -215,6 +239,25 @@ class _Dispatch:
     def __exit__(self, *exc) -> None:
         self._annotation.__exit__(*exc)
         self._call.dispatch_s += self._call._lap()
+        if self._call._metrics._unfed is not None:
+            self._call._metrics._fed_again(self._call)
+
+
+class _Unfed:
+    """The device has had nothing enqueued since ``ready``, when the
+    result of call ``after`` was ready with no other call in flight.
+    ``read_end`` is where that call's read ended (``finish``); the host
+    phases that end after it add their time to ``phases``, and a
+    ``step()`` that begins after it the time outside any
+    (``outside_step``)."""
+
+    __slots__ = ("ready", "read_end", "after", "why", "phases", "no_work")
+
+    def __init__(self, ready: float, after: int, why: str):
+        self.ready, self.after, self.why = ready, after, why
+        self.read_end: Optional[float] = None
+        self.phases: Dict[str, float] = {}
+        self.no_work = False
 
 
 class _Typical:
@@ -340,6 +383,15 @@ class ServeMetrics:
         # successor could be launched, by what stood in the way.
         self.decode_ahead_total = 0
         self.decode_drains: Dict[str, int] = dict.fromkeys(DRAIN_CAUSES, 0)
+        # Seconds the device had nothing enqueued while the engine had
+        # work (the `serve:unfed` spans' sum, and by their `why`), and
+        # while it had none (`serve:no_work`); the interval that is
+        # open now, if one is.
+        self.device_unfed_s = 0.0
+        self.device_unfed_s_by_why: Dict[str, float] = dict.fromkeys(
+            UNFED_WHYS, 0.0)
+        self.device_no_work_s = 0.0
+        self._unfed: Optional[_Unfed] = None
         # Key blocks of the latent pool that the decode calls' kernel
         # read (every row to its own length), and what a loop to the
         # batch's longest row would have gathered for every row.
@@ -461,12 +513,16 @@ class ServeMetrics:
                 yield span
                 span.dur = self._clock() - span.t0
             self._span(name, span.t0, span.dur, span.args)
+            unfed = self._unfed
+            if unfed is not None and unfed.read_end is not None:
+                unfed.phases[name] = unfed.phases.get(name, 0.0) + span.dur
             return
         span = self.launch(name, **args)
         try:
             yield span
         except BaseException:
             self._flying.remove(span)
+            self._unfed = None
             span._twin.__exit__(None, None, None)
             raise
         self.finish(span)
@@ -480,7 +536,7 @@ class ServeMetrics:
         time since the last one's read ended is this call's
         ``serve:host_gap`` (written by :meth:`finish`)."""
         call = args["call"] = next(self._calls)
-        span = DeviceCall(name, call, self._clock, self._gc.seq, args)
+        span = DeviceCall(name, call, self, args)
         since = self._device_idle_since
         if since is not None and not self._flying:
             span._gap = (since, span.t0 - since, self._stepped_since,
@@ -503,6 +559,8 @@ class ServeMetrics:
         self._flying.remove(span)
         for other in self._flying:
             other._starts_at(end)
+        if self._unfed is not None:
+            self._unfed.read_end = end
         stalls = []
         if span._gap is not None:
             since, gap, stepped, gc_pauses = span._gap
@@ -524,6 +582,47 @@ class ServeMetrics:
             for stall in stalls:
                 self._stall(*stall, call, self._cpu_mark, mark)
             self._cpu_mark = mark
+
+    def _result_ready(self, span: DeviceCall) -> None:
+        """``span``'s wait ended (at its ``_mark``): with no other call
+        in flight the device is unfed from here on."""
+        if len(self._flying) == 1:
+            self._unfed = _Unfed(span._mark, span.call,
+                                 _WHY_AFTER.get(span.name, span.name))
+
+    def _fed_again(self, span: DeviceCall) -> None:
+        """``span``'s jitted call returned (at its ``_mark``) while the
+        device was unfed: write the interval as ``serve:unfed``, or as
+        ``serve:no_work`` where the engine ran out of work in it. Its
+        parts: the readback (until call ``after``'s read ended), the
+        host (until ``span``'s launch, by the phases that ran in it)
+        and the dispatch. Between two launches of one call (a
+        speculative round's draft steps) no read's end is stamped, and
+        the whole interval reads as dispatch, as ``dispatch_ms`` of
+        the call's own span does."""
+        unfed, self._unfed = self._unfed, None
+        end = span._mark
+        dur = end - unfed.ready
+        if unfed.no_work:
+            self.device_no_work_s += dur
+            self._span("serve:no_work", unfed.ready, dur,
+                       {"after": unfed.after, "before": span.call})
+            return
+        read_end = launch = unfed.ready
+        if unfed.read_end is not None:
+            read_end, launch = unfed.read_end, span.t0
+        host_ms = (launch - read_end) * 1e3
+        phases = {name: s * 1e3 for name, s in unfed.phases.items()}
+        self.device_unfed_s += dur
+        self.device_unfed_s_by_why[unfed.why] = (
+            self.device_unfed_s_by_why.get(unfed.why, 0.0) + dur)
+        self._span("serve:unfed", unfed.ready, dur, {
+            "readback_ms": (read_end - unfed.ready) * 1e3,
+            "host_ms": host_ms, "dispatch_ms": (end - launch) * 1e3,
+            "phases": phases,
+            "unnamed_ms": host_ms - sum(phases.values()),
+            "why": unfed.why, "after": unfed.after, "before": span.call,
+            "across_steps": self._stepped_since})
 
     def _stall(self, of: str, t0: float, dur: float, part: str,
                gc_pauses: list, call: int, mark0: tuple, mark1: tuple
@@ -569,8 +668,17 @@ class ServeMetrics:
     def record_step(self, now: float) -> None:
         """A scheduler iteration with work to do began at ``now``:
         pool occupancy goes on a counter track next to the spans (live
-        blocks vs warm refcount-0 cached blocks), once a step."""
+        blocks vs warm refcount-0 cached blocks), once a step. Where
+        the device is unfed, what no phase has held since the last
+        read ended is the caller's time between two ``step()``s (and
+        the few lines of ``step()`` after its last phase):
+        ``outside_step`` among the interval's ``phases``."""
         self._stepped_since = True
+        unfed = self._unfed
+        if unfed is not None and unfed.read_end is not None:
+            unfed.phases["outside_step"] = (
+                unfed.phases.get("outside_step", 0.0) + now - unfed.read_end
+                - sum(unfed.phases.values()))
         if self._allocator is not None:
             self._events.append({
                 "name": "kv_blocks", "ph": "C", "pid": 0, "tid": 0,
@@ -615,8 +723,11 @@ class ServeMetrics:
 
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
-        nobody's host gap."""
+        nobody's host gap, and the time the device goes unfed is the
+        traffic's (``serve:no_work``)."""
         self._device_idle_since = None
+        if self._unfed is not None:
+            self._unfed.no_work = True
 
     def record_queue_depth(self, depth: int) -> None:
         self.queue_depth = depth
@@ -663,8 +774,11 @@ class ServeMetrics:
 
     def record_decode_drain(self, cause: str) -> None:
         """A decode call in flight was read with no successor launched
-        behind it, for ``cause`` (one of :data:`DRAIN_CAUSES`)."""
+        behind it, for ``cause`` (one of :data:`DRAIN_CAUSES`): that is
+        why the device is unfed since."""
         self.decode_drains[cause] += 1
+        if self._unfed is not None:
+            self._unfed.why = cause
 
     def record_decode(self, dur_s: float, n_active: int,
                       max_batch: int) -> None:
@@ -774,6 +888,15 @@ class ServeMetrics:
             "decode_drains_total": sum(self.decode_drains.values()),
             **{f"decode_drains_{cause}_total": n
                for cause, n in self.decode_drains.items()},
+            # seconds the device had nothing enqueued while the engine
+            # had work (the `serve:unfed` spans' sum), by why no
+            # successor was in flight (UNFED_WHYS: a key a cause), and
+            # while the engine had none (`serve:no_work`)
+            "device_unfed_s_total": round(self.device_unfed_s, 6),
+            "device_unfed_s_by_why": {
+                why: round(s, 6)
+                for why, s in self.device_unfed_s_by_why.items()},
+            "device_no_work_s_total": round(self.device_no_work_s, 6),
             # key blocks of the latent pool the decode calls read, a
             # row to its own length, and what reading every row to the
             # call's longest would have taken (zeros without mla layers)

@@ -24,7 +24,7 @@ from horovod_tpu.serve import metrics as metrics_mod
 
 TICK = 1e-3
 AFTER_THE_FACT = {"serve:host_gap", "serve:queue", "serve:request",
-                  "serve:stall"}
+                  "serve:stall", "serve:unfed", "serve:no_work"}
 DEVICE = ("serve:prefill", "serve:decode")
 
 
@@ -298,14 +298,15 @@ def test_every_span_has_a_twin_annotation_of_its_name(recorded):
     assert [n for n, _ in opened if n.count(":") == 1] == written
     # the second decode call is launched before the first is read
     assert [(n, st["call"]) for n, st in opened if n.count(":") == 2] == [
-        ("serve:prefill:dispatch", 1), ("serve:prefill:sync", 1),
+        ("serve:prefill:dispatch", 1),
         ("serve:prefill:wait", 1), ("serve:prefill:readback", 1),
         ("serve:decode:dispatch", 2), ("serve:decode:dispatch", 3)] + [
         ("serve:decode" + part, call) for call in (2, 3)
-        for part in (":sync", ":wait", ":readback")]
-    assert set(written) == {"serve:schedule", "serve:prefill",
-                            "serve:decode_prep", "serve:decode",
-                            "serve:decode_post"}
+        for part in (":wait", ":readback")]
+    assert set(written) == {"serve:schedule", "serve:prefill_prep",
+                            "serve:prefill", "serve:prefill_post",
+                            "serve:decode_prep", "serve:decode_plan",
+                            "serve:decode", "serve:decode_post"}
 
 
 def test_a_device_call_has_one_number_on_its_span_twin_and_nested(recorded):
@@ -319,7 +320,7 @@ def test_a_device_call_has_one_number_on_its_span_twin_and_nested(recorded):
     for (name, stats) in twins:
         nested = [st for n, st in opened if n.startswith(name + ":")
                   and st == stats]
-        assert len(nested) == 4      # :dispatch, :sync, :wait, :readback
+        assert len(nested) == 3      # :dispatch, :wait, :readback
     assert all(st == {} for n, st in opened if n.count(":") == 1
                and n not in DEVICE)
 
@@ -624,3 +625,274 @@ def test_the_engine_s_own_run_has_no_stall(served):
     eng, _, _, spans, _ = served
     assert not _named(spans, "serve:stall")
     assert eng.metrics.snapshot()["stalls_total"] == 0
+
+
+# -- every second the device is unfed has a cause (PR 52) --------------
+
+
+def _reaches(served_model, cause):
+    """A tiny engine run to the end on a course that reads a decode
+    call for ``cause`` (or a prefill's token) and launches again."""
+    if cause == "idle":
+        # one slot: the first request's last call leaves no row, and
+        # the second waits in the queue for its slot
+        eng = _engine(served_model, max_batch=1)
+        eng.submit([5, 6, 7], 3)
+        eng.submit([1, 2, 3], 3)
+    elif cause == "admit":
+        # two slots, three requests: one ends while one goes on
+        eng = _engine(served_model, max_batch=2)
+        eng.submit([5, 6, 7, 8, 9], 6)
+        eng.submit([1, 2, 3], 2)
+        eng.submit([4, 5, 6], 2)
+    elif cause == "prefill":
+        # a request arrives while a decode call is in flight and a
+        # slot is free
+        eng = _engine(served_model)
+        eng.submit([5, 6, 7, 8, 9], 6)
+        for _ in range(3):
+            eng.step()
+        eng.submit([1, 2, 3], 2)
+    else:
+        # "bucket": one of two ends and nobody waits; "prefill_read":
+        # any request at all
+        eng = _engine(served_model)
+        eng.submit([5, 6, 7, 8, 9], 6)
+        eng.submit([1, 2, 3], 4)
+    eng.run_until_idle()
+    return eng
+
+
+CAUSES = ("prefill", "admit", "bucket", "idle", "prefill_read")
+
+
+@pytest.fixture(scope="module", params=CAUSES)
+def reached(request, served_model, tmp_path_factory):
+    eng = _reaches(served_model, request.param)
+    spans, _ = _spans(eng, tmp_path_factory.mktemp("unfed"))
+    return request.param, eng, spans
+
+
+def test_unfed_says_why_no_successor_was_in_flight(reached):
+    cause, eng, spans = reached
+    unfed = _named(spans, "serve:unfed")
+    assert {u["args"]["why"] for u in unfed} <= set(metrics_mod.UNFED_WHYS)
+    mine = [u for u in unfed if u["args"]["why"] == cause]
+    assert mine
+    by_call = {s["args"]["call"]: s["name"] for s in spans
+               if s["name"] in DEVICE}
+    after = "serve:prefill" if cause == "prefill_read" else "serve:decode"
+    for u in mine:
+        assert by_call[u["args"]["after"]] == after
+        assert u["args"]["before"] > u["args"]["after"]
+    if cause != "prefill_read":
+        # every drain was followed by a launch but the run's last,
+        # for `idle`, which nothing follows
+        drains = eng.metrics.snapshot()[f"decode_drains_{cause}_total"]
+        assert len(mine) == drains - (cause == "idle") > 0
+
+
+def test_unfed_is_readback_host_and_dispatch(reached):
+    _, _, spans = reached
+    gaps = {round(g["t0"], 6): g for g in _named(spans, "serve:host_gap")}
+    unfed = _named(spans, "serve:unfed")
+    assert len(unfed) == len(gaps)
+    for u in unfed:
+        a = u["args"]
+        assert a["readback_ms"] + a["host_ms"] + a["dispatch_ms"] == \
+            pytest.approx(1e3 * (u["end"] - u["t0"]), abs=1e-3)
+        # the read's copy and the dispatch are one tick each on this
+        # clock; the host's part is the host gap inside
+        assert a["readback_ms"] == pytest.approx(1e3 * TICK)
+        assert a["dispatch_ms"] == pytest.approx(1e3 * TICK)
+        gap = gaps[round(u["t0"] + a["readback_ms"] * 1e-3, 6)]
+        assert a["host_ms"] == pytest.approx(
+            1e3 * (gap["end"] - gap["t0"]), abs=1e-3)
+        assert a["across_steps"] == gap["args"]["across_steps"]
+
+
+def test_unfed_host_time_is_its_phases_and_the_unnamed_rest(reached):
+    _, _, spans = reached
+    named = [s for s in spans if s["name"] not in AFTER_THE_FACT
+             and s["name"] not in DEVICE]
+    for u in _named(spans, "serve:unfed"):
+        a = u["args"]
+        assert sum(a["phases"].values()) + a["unnamed_ms"] == \
+            pytest.approx(a["host_ms"])
+        assert a["unnamed_ms"] > 0          # the clock ticks between phases
+        lo = u["t0"] + a["readback_ms"] * 1e-3
+        hi = lo + a["host_ms"] * 1e-3
+        inside: dict = {}
+        for s in named:
+            if lo - 1e-7 <= s["t0"] and s["end"] <= hi + 1e-7:
+                inside[s["name"]] = inside.get(s["name"], 0.0) + 1e3 * (
+                    s["end"] - s["t0"])
+        # a step() that began in between names the time outside any
+        outside = a["phases"].pop("outside_step", None)
+        assert (outside is not None) == a["across_steps"]
+        assert a["phases"] == pytest.approx(inside, abs=1e-3)
+        if a["why"] == "prefill_read":
+            assert "serve:prefill_post" in a["phases"]
+        else:
+            assert "serve:decode_post" in a["phases"]
+
+
+def test_no_unfed_lies_over_a_call_in_flight(reached):
+    _, _, spans = reached
+    unfed = _named(spans, "serve:unfed") + _named(spans, "serve:no_work")
+    assert unfed
+    for d in (s for s in spans if s["name"] in DEVICE):
+        # on the device's queue or running: from the jitted call's
+        # return (before its span began, for a call launched ahead)
+        # until its result was ready
+        a = d["args"]
+        lo = d["t0"] + (0.0 if a.get("ahead") else a["dispatch_ms"] * 1e-3)
+        hi = d["t0"] + a["ready_ms"] * 1e-3
+        for u in unfed:
+            assert u["end"] <= lo + 1e-6 or hi <= u["t0"] + 1e-6
+            if u["args"]["before"] == a["call"]:
+                assert u["end"] == pytest.approx(lo, abs=1e-6)
+            if u["args"]["after"] == a["call"]:
+                assert u["t0"] == pytest.approx(hi, abs=1e-6)
+
+
+def test_the_unfed_counters_are_the_spans_sums_and_restart(reached):
+    cause, eng, spans = reached
+    snap = eng.metrics.snapshot()
+    unfed = _named(spans, "serve:unfed")
+    assert snap["device_unfed_s_total"] == pytest.approx(
+        sum(u["end"] - u["t0"] for u in unfed), abs=1e-6)
+    assert set(snap["device_unfed_s_by_why"]) == set(metrics_mod.UNFED_WHYS)
+    for why, s in snap["device_unfed_s_by_why"].items():
+        assert s == pytest.approx(sum(
+            u["end"] - u["t0"] for u in unfed if u["args"]["why"] == why),
+            abs=1e-6)
+    assert snap["device_unfed_s_by_why"][cause] > 0
+    assert snap["device_no_work_s_total"] == pytest.approx(sum(
+        s["end"] - s["t0"] for s in _named(spans, "serve:no_work")),
+        abs=1e-6)
+    text = eng.metrics.prometheus()
+    assert f"serve_device_unfed_s_by_why_{cause}{{" in text
+    assert "serve_device_unfed_s_total{" in text
+    assert "serve_device_no_work_s_total{" in text
+
+
+def test_the_unfed_counters_restart_at_reset(served_model):
+    eng = _reaches(served_model, "bucket")
+    assert eng.metrics.snapshot()["device_unfed_s_total"] > 0
+    eng.metrics.reset()
+    snap = eng.metrics.snapshot()
+    assert snap["device_unfed_s_total"] == snap["device_no_work_s_total"] == 0
+    assert not any(snap["device_unfed_s_by_why"].values())
+    # the interval that was open at reset() is dropped with the spans
+    eng.submit([1, 2, 3], 2)
+    eng.run_until_idle()
+    names = [e["name"] for e in eng.metrics._events]
+    assert "serve:no_work" not in names
+    snap = eng.metrics.snapshot()
+    assert snap["device_unfed_s_total"] == pytest.approx(sum(
+        e["dur"] for e in eng.metrics._events
+        if e["name"] == "serve:unfed") * 1e-6, abs=1e-6)
+
+
+def test_an_engine_out_of_work_writes_no_work_and_no_unfed(
+        served_model, tmp_path):
+    clock = TickClock()
+    eng = _engine(served_model, clock=clock)
+    eng.submit([5, 6, 7], 2)
+    eng.run_until_idle()
+    before = len([e for e in eng.metrics._events
+                  if e["name"] == "serve:unfed"])
+    clock.t += 50.0                  # nobody asks for anything
+    eng.step()
+    eng.submit([1, 2, 3], 2)
+    eng.run_until_idle()
+    spans, _ = _spans(eng, tmp_path)
+    waits = _named(spans, "serve:no_work")
+    assert len(waits) == 1
+    assert waits[0]["end"] - waits[0]["t0"] > 50.0
+    prefills = _named(spans, "serve:prefill")
+    assert waits[0]["args"] == {"after": prefills[1]["args"]["call"] - 1,
+                                "before": prefills[1]["args"]["call"]}
+    # the wait is the traffic's: in no serve:unfed and not in its sum
+    unfed = _named(spans, "serve:unfed")
+    assert max(u["end"] - u["t0"] for u in unfed) < 1.0
+    assert eng.metrics.snapshot()["device_unfed_s_total"] < 1.0
+    assert eng.metrics.snapshot()["device_no_work_s_total"] == \
+        pytest.approx(waits[0]["end"] - waits[0]["t0"], abs=1e-6)
+    # the last call of the first request was drained for `idle`, and
+    # nothing followed it but the wait
+    assert len([u for u in unfed if u["args"]["why"] == "idle"]) == 0
+    assert len(unfed) > before
+
+
+def test_unfed_by_the_clock_and_none_for_a_call_launched_ahead():
+    """The parts by hand on a clock the test moves: a call read with
+    nothing behind it, then b launched while a is in flight."""
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    _device_call(m, clock, name="serve:prefill")     # ready at +23 ms
+    ready = clock.t - 1e-3
+    with m.phase("serve:prefill_post"):
+        clock.t += 0.5e-3
+    clock.t += 1.5e-3
+    a = m.launch("serve:decode", n_active=2, ahead=False)
+    with a.dispatch():
+        clock.t += 1e-3
+    unfed = [e for e in m._events if e["name"] == "serve:unfed"]
+    assert len(unfed) == 1
+    assert unfed[0]["ts"] == pytest.approx((ready - m.started_at) * 1e6)
+    assert unfed[0]["dur"] == pytest.approx(4e3)
+    assert unfed[0]["args"] == {
+        "readback_ms": pytest.approx(1.0), "host_ms": pytest.approx(2.0),
+        "dispatch_ms": pytest.approx(1.0),
+        "phases": {"serve:prefill_post": pytest.approx(0.5)},
+        "unnamed_ms": pytest.approx(1.5), "why": "prefill_read",
+        "after": a.call - 1, "before": a.call, "across_steps": False}
+    b = m.launch("serve:decode", n_active=2, ahead=True)
+    with b.dispatch():
+        clock.t += 1e-3
+    a.read(Result(clock, 8e-3), lambda out: out)
+    m.finish(a)
+    with m.phase("serve:decode_post"):   # b is in flight: nobody's
+        clock.t += 1e-3
+    b.read(Result(clock, 10e-3), lambda out: out)
+    clock.t += 0.5e-3
+    m.finish(b)
+    m.record_decode_drain("admit")
+    clock.t += 2e-3
+    m.record_step(clock.t)
+    _device_call(m, clock, gap=0.0, name="serve:prefill")
+    unfed = [e for e in m._events if e["name"] == "serve:unfed"]
+    assert [(e["args"]["after"], e["args"]["why"]) for e in unfed] == [
+        (a.call - 1, "prefill_read"), (b.call, "admit")]
+    assert unfed[1]["args"]["phases"] == {
+        "outside_step": pytest.approx(2.0)}
+    assert unfed[1]["args"]["unnamed_ms"] == pytest.approx(0.0, abs=1e-9)
+    assert unfed[1]["args"]["across_steps"] is True
+    assert unfed[1]["dur"] == pytest.approx(3.5e3)
+    assert m.snapshot()["device_unfed_s_by_why"]["admit"] == \
+        pytest.approx(3.5e-3)
+
+
+def test_a_round_of_several_launches_is_unfed_between_them():
+    """A speculative round's draft phase launches k times inside one
+    call: between two of them the device has nothing, and no read's
+    end is stamped, so the interval reads as dispatch."""
+    clock = SetClock()
+    m = metrics_mod.ServeMetrics(clock=clock)
+    with m.phase("serve:spec_draft", device=True) as draft:
+        for _ in range(2):
+            with draft.dispatch():
+                clock.t += 1e-3
+            draft.read(Result(clock, 5e-3), lambda out: out)
+            clock.t += 0.5e-3
+    unfed = [e for e in m._events if e["name"] == "serve:unfed"]
+    assert len(unfed) == 1
+    assert unfed[0]["dur"] == pytest.approx(1.5e3)
+    assert unfed[0]["args"]["after"] == unfed[0]["args"]["before"] \
+        == draft.call
+    assert unfed[0]["args"]["why"] == "spec"
+    assert (unfed[0]["args"]["readback_ms"], unfed[0]["args"]["host_ms"],
+            unfed[0]["args"]["dispatch_ms"]) == (0.0, 0.0,
+                                                 pytest.approx(1.5))
