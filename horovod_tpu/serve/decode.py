@@ -112,6 +112,7 @@ from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_keys)
 from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
+from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
@@ -875,7 +876,12 @@ def sparse_block_scores(q, ck, exist, per: int, strides_a_kernel: int):
     the kernels it sees of ``q . c / sqrt(Dh)``; a block's is the
     largest over the kernels that meet it (those that start in it and
     the ``strides_a_kernel - 1`` before them), summed over the heads of
-    the GQA group. Returns [B, C, Hkv, W] float32."""
+    the GQA group. Returns [B, C, Hkv, W] float32.
+
+    The form in XLA: a float32 ``[B, Hkv, H / Hkv, C, J]`` array over
+    every kernel of the table, passed over ten times. A decode step's
+    form (a row a query), that of a chunk :func:`sparse_select_taken`
+    refuses, and what the tests hold ``ops/sparse_scores.py`` to."""
     B, C, H, Dh = q.shape
     J, Hkv = ck.shape[1:3]
     W, r = J // per, strides_a_kernel
@@ -891,6 +897,19 @@ def sparse_block_scores(q, ck, exist, per: int, strides_a_kernel: int):
         best = jnp.maximum(best, p[..., per:].reshape(
             *p.shape[:-1], W, per)[..., :r - 1].max(-1))
     return jnp.moveaxis(best.sum(2), 1, 2)                  # [B, C, Hkv, W]
+
+
+def sparse_select_taken(chunk: int, block_size: int,
+                        table_width: int) -> bool:
+    """Whether a sparse layer's chunk of ``chunk`` positions over a
+    table of ``table_width`` pages scores its blocks through the kernel
+    (``ops/sparse_scores.py``, ``hvd_sparse_scores`` in a device trace):
+    the queries have to be whole tiles of it whose ``[tile, table]``
+    blocks fit its fast memory; pages of any ``block_size`` do. By the
+    shapes alone, here and on a TPU. Any other chunk, and every decode
+    step, keeps :func:`sparse_block_scores`."""
+    del block_size
+    return sparse_scores_kernel.q_tile(chunk, table_width) is not None
 
 
 def sparse_choose(scores, at_block, cfg):
@@ -1445,9 +1464,15 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 past = call.pos[0] >= cfg.sparse_dense_len           # [Tc]
                 with jax.named_scope("sparse_select"):
                     def select():
-                        scores = sparse_block_scores(
-                            q, kernels_behind(ck, c, call.table[None]),
-                            sparse_kernels_seen(call.pos), per, strides)
+                        if sparse_select_taken(Tc, block_size, table_width):
+                            scores = sparse_scores_kernel.sparse_scores(
+                                q[0], ck[c, call.table], call.offset,
+                                call.length, stride=stride,
+                                kernel=cfg.sparse_kernel)[None]
+                        else:
+                            scores = sparse_block_scores(
+                                q, kernels_behind(ck, c, call.table[None]),
+                                sparse_kernels_seen(call.pos), per, strides)
                         return sparse_chosen_pages(*sparse_choose(
                             scores, call.pos // block_size, cfg))[0]
 

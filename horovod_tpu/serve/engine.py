@@ -969,8 +969,14 @@ class ServeEngine:
             # the call's queries that choose their blocks
             extra["selected"] = max(0, offset + chunk - max(
                 offset, self.model_cfg.sparse_dense_len))
+            # those of them whose block scores the kernel makes
+            extra["select_kernel"] = (
+                extra["selected"] if decode_lib.sparse_select_taken(
+                    len(toks), self.cfg.block_size, self._table_width)
+                else 0)
             m.record_sparse(self.model_cfg, offset + chunk,
-                            prefill=extra["selected"])
+                            prefill=extra["selected"],
+                            kernel=extra["select_kernel"])
         return toks, extra
 
     def _run_prefill_chunk(self, seq: _Seq, chunk: int, toks: np.ndarray,

@@ -424,9 +424,12 @@ class ServeMetrics:
         self.kv_latent_positions_max = 0
         # Sparse layers (a selection inside paged attention): the
         # queries of the prefill calls and the rows of the decode calls
-        # that chose their blocks (at or past sparse_dense_len), and
-        # the most compressed keys (kernels) held for one sequence.
+        # that chose their blocks (at or past sparse_dense_len), those
+        # of the queries whose chunk scored through the kernel
+        # (decode.sparse_select_taken), and the most compressed keys
+        # (kernels) held for one sequence.
         self.sparse_selected_queries_total = 0
+        self.sparse_select_kernel_queries_total = 0
         self.sparse_selected_rows_total = 0
         self.kv_compressed_max = 0
         # Speculative decoding (serve/speculative.py): proposal /
@@ -700,11 +703,13 @@ class ServeMetrics:
                                            int(held.max(initial=0)))
 
     def record_sparse(self, cfg, held: int, prefill: int = 0,
-                      decode: int = 0) -> None:
+                      decode: int = 0, kernel: int = 0) -> None:
         """A call was launched after which the sparse layers hold
         ``held`` positions of its longest sequence; ``prefill`` of its
-        queries, or ``decode`` of its rows, choose their blocks."""
+        queries, or ``decode`` of its rows, choose their blocks,
+        ``kernel`` of the queries by scores the kernel made."""
         self.sparse_selected_queries_total += prefill
+        self.sparse_select_kernel_queries_total += kernel
         self.sparse_selected_rows_total += decode
         self.kv_compressed_max = max(
             self.kv_compressed_max,
@@ -928,6 +933,8 @@ class ServeMetrics:
             # sparse layers (zeros without such layers)
             "sparse_selected_queries_total":
                 self.sparse_selected_queries_total,
+            "sparse_select_kernel_queries_total":
+                self.sparse_select_kernel_queries_total,
             "sparse_selected_rows_total": self.sparse_selected_rows_total,
             "kv_compressed_max": self.kv_compressed_max,
             "p50_first_token_ms": ms(percentile(self.first_token_s, 50)),

@@ -204,6 +204,8 @@ def test_the_engine_serves_the_reference_s_tokens_and_leaves_its_states():
     assert snap["kv_compressed_max"] == (161 - 4) // 2 + 1
     # queries at 64.. of the prompts, rows at or past 64 of the steps
     assert snap["sparse_selected_queries_total"] == (150 - 64) + (70 - 64)
+    # buckets of 8 to 32 positions: no whole tile of the scores' kernel
+    assert snap["sparse_select_kernel_queries_total"] == 0
     assert snap["sparse_selected_rows_total"] == 2 * 11
 
 
@@ -219,6 +221,7 @@ def test_the_spans_say_what_chose_its_blocks(tmp_path):
     chunks = [s["args"] for s in spans if s["name"] == "serve:prefill"]
     steps = [s["args"] for s in spans if s["name"] == "serve:decode"]
     assert [c["selected"] for c in chunks] == [0, 0, 6]
+    assert [c["select_kernel"] for c in chunks] == [0, 0, 0]
     assert [s["rows_selected"] for s in steps] == [1, 1, 1]
 
 
@@ -635,3 +638,78 @@ def test_a_sparse_chunk_attends_through_the_kernel_where_it_is_whole_tiles(
     assert gap(rows, rows0) <= tol
     for a, b in zip(jax.tree.leaves((kc, vc)), jax.tree.leaves((kc0, vc0))):
         assert gap(a, b) <= tol
+
+
+# (h) a chunk's block scores through the kernel (ISSUE 53) ----------------
+
+@pytest.mark.parametrize("chunk,page,width,taken", [
+    (1024, 64, 520, True), (512, 64, 520, True), (256, 64, 520, True),
+    (2048, 32, 14, True), (1024, 8, 160, True), (1024, 48, 30, True),
+    (768, 64, 520, True),     # three tiles of 256
+    (128, 64, 520, False), (96, 32, 14, False),   # no whole tile of queries
+    (1024, 64, 4096, False)])  # a tile's [256, table] blocks: 16.8 MB
+def test_the_scores_kernel_is_taken_by_the_shapes_alone(chunk, page, width,
+                                                        taken):
+    assert decode_lib.sparse_select_taken(chunk, page, width) is taken
+
+
+def test_a_sparse_chunk_scores_through_the_kernel_and_chooses_the_same(
+        monkeypatch):
+    """A prompt of 600 in chunks of 256 (the last 88 of its bucket): each
+    chunk program holds ``hvd_sparse_scores`` once a sparse layer, the
+    decode program never, and tokens, logits and every query's chosen
+    pages are those of the same programs scoring in XLA."""
+    cfg = tiny(max_seq=1024)
+    params = seeded(cfg)
+    prompt = prompts_of(cfg, (600,))
+
+    def served():
+        return serve_logits(cfg, params, prompt, 3, chunk=256, pad_to=256,
+                            chosen=True)
+
+    def calls(step):
+        width = 128
+        programs = decode_lib.mixed_programs(cfg, BS, width, 0)
+        cache = init_kv_cache(cfg, width + 1, BS, n_slots=1)
+        table = jnp.arange(1, width + 1, dtype=jnp.int32)
+        if step == "chunk":
+            text = jax.make_jaxpr(programs[1])(
+                params, cache.k, cache.v, jnp.zeros(256, jnp.int32),
+                jnp.int32(256), jnp.int32(256), (table, jnp.int32(1)))
+        else:
+            text = jax.make_jaxpr(programs[2])(
+                params, cache.k, cache.v, jnp.zeros(1, jnp.int32),
+                jnp.full((1,), 300, jnp.int32),
+                (table[None], jnp.ones(1, jnp.int32)))
+        return str(text).count("hvd_sparse_scores")
+
+    assert calls("chunk") == TYPES.count("sparse") and calls("step") == 0
+    ((rows, _, toks),), _, (picks,) = served()
+    monkeypatch.setattr(decode_lib, "sparse_select_taken",
+                        lambda *shape: False)
+    assert calls("chunk") == 0
+    ((rows0, _, toks0),), _, (picks0,) = served()
+    assert toks == toks0
+    assert gap(rows, rows0) < 2e-6
+    assert picks.shape == picks0.shape and picks[:, DENSE:].any()
+    assert (picks == picks0).all()
+
+
+def test_the_spans_say_which_queries_the_kernel_scored_for(tmp_path):
+    """A prompt of 552 in chunks of 256: the two whole chunks score
+    through the kernel, the last 40 in their bucket of 64 do not."""
+    import json
+    cfg = tiny(max_seq=1024)
+    eng = engine_for(cfg, seeded(cfg), max_prompt=640, prefill_chunk=256,
+                     prefill_buckets=(64, 256))
+    eng.submit(prompts_of(cfg, (552,))[0], 2)
+    eng.run_until_idle()
+    path = tmp_path / "trace.json"
+    eng.metrics.export_chrome_trace(str(path))
+    chunks = [s["args"] for s in json.load(open(path))["traceEvents"]
+              if s["name"] == "serve:prefill"]
+    assert [c["selected"] for c in chunks] == [256 - DENSE, 256, 40]
+    assert [c["select_kernel"] for c in chunks] == [256 - DENSE, 256, 0]
+    snap = eng.metrics.snapshot()
+    assert snap["sparse_selected_queries_total"] == 552 - DENSE
+    assert snap["sparse_select_kernel_queries_total"] == 512 - DENSE

@@ -805,6 +805,25 @@ for name, fn, args in (
             if int(dims.split(",")[-1]) >= 1024
             and int(np.prod([int(d) for d in dims.split(",")]))
             >= 1024 * 1024)),
+        # the Pallas calls under the chunk's selection, and the float32
+        # arrays there of a chunk's scores over the table's kernels
+        # ([.., 1024, 520 * 4]) or more
+        "select_kernels": sum(
+            "tpu_custom_call" in ln and "sparse_select" in ln
+            for ln in sparse),
+        "select_scores": sorted(set(
+            tuple(int(d) for d in dims.split(","))
+            for ln in sparse if "sparse_select" in ln
+            for dims in re.findall(r"= \(?f32\[([\d,]+)\]", ln)
+            if int(np.prod([int(d) for d in dims.split(",")]))
+            >= CHUNK * WIDTH * 4)),
+        # the dimension that lies in the lanes of each top-k's sort
+        # under the selection: 1 is the chunk's queries
+        "select_sort_lanes": [
+            int(minor) for ln in sparse
+            if " sort(" in ln and "sparse_select" in ln
+            for minor in re.findall(
+                r"= \(f32\[1,%d,2,%d\]\{{(\d)," % (CHUNK, WIDTH), ln)],
         "scopes": sorted(set(re.findall(
             r"attn_sparse/(kv_write|sparse_\w+)"
             r"|attn_lightning/(lightning_\w+|state_write)", text))),
@@ -1161,7 +1180,8 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
     gathers) and gathers at most 128 pages a row and KV head (a row
     below the dense length all of its own, a row past it its 64
     chosen); a chunk holds no key block's scores at all (ISSUE 51: they
-    are tiles in VMEM). Every scope the benchmark reads by name is in
+    are tiles in VMEM) and no block scores over the table's kernels
+    (ISSUE 53: the same). Every scope the benchmark reads by name is in
     the program."""
     out = _compile_for_v5e(_SALA_DRIVER)
     got = out[program]
@@ -1187,4 +1207,19 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
     assert got["attend_kernels"] == (0 if step else 2), got
     assert got["attend_scores"] == (
         [[16, 2, 16, 128 * 64]] if step else []), got
-    assert got["temp_bytes"] < 2 * out["pages_bytes"], got
+    # ISSUE 53: a chunk's block scores are made by a kernel of their
+    # own, one call a sparse layer, and no float32 array of a chunk's
+    # scores over the table's kernels (f32[1, 2, 16, 1024, 2080] and six
+    # more of its shapes before) is left under the selection; a decode
+    # step holds no such call
+    assert got["select_kernels"] == (0 if step else 2), got
+    assert got["select_scores"] == [], got
+    # the kernel hands its scores over with the queries in the lanes,
+    # so the top-k behind it sorts 1024 queries at a time, as it did
+    # behind the XLA form: laid [1024, 2, 520] as a first form of the
+    # kernel laid them, a layer's sort took 5 ms a call for 0.4
+    assert got["select_sort_lanes"] == ([] if step else [1, 1]), got
+    # the chunk program's temporaries: 854.9 MB before ISSUE 53 (three
+    # live copies of a layer's 272 MB of scores), 62.6 MB since; the
+    # decode program's 90.2 MB are what they were
+    assert got["temp_bytes"] < ((96 if step else 80) << 20), got

@@ -53,6 +53,17 @@ maps' clamp and without the skip by choice; beside each, the share of
 the causally visible tile pairs that it computes, and the largest
 difference from ``sparse_attend_chunk`` relative to the largest
 magnitude.
+
+``--select`` sweeps the selection before it (ISSUE 53), same shapes:
+the chunk's queries over the compressed keys ``[2, 4, 128]`` a page
+behind the same table. ms a layer of
+``serve/decode.py::sparse_block_scores`` alone (float32 ``[2, 16, C,
+2080]`` scores, their softmax and its pooling in HBM: all a chunk had
+before ISSUE 53), of ``sparse_choose``, of the scatter of what it chose
+and of the two together, of ``ops/sparse_scores.py``'s kernel alone at
+each ``--tiles`` pair (queries x pages a grid step) and with the choice
+behind it as the programs run it; and the largest difference of the
+kernel's scores from the XLA form's relative to the largest score.
 """
 
 import argparse
@@ -61,6 +72,7 @@ import os
 import statistics
 import sys
 import time
+import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
@@ -74,6 +86,7 @@ from horovod_tpu.models import TransformerConfig  # noqa: E402
 from horovod_tpu.models import transformer as tf_lib  # noqa: E402
 from horovod_tpu.ops import flash_attention as flash_lib  # noqa: E402
 from horovod_tpu.ops import latent_decode as latent_lib  # noqa: E402
+from horovod_tpu.ops import sparse_scores as scores_lib  # noqa: E402
 from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from horovod_tpu.parallel.ring_attention import local_attention  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
@@ -488,8 +501,111 @@ def sparse_sweep(args) -> None:
                 print(json.dumps(row), flush=True)
 
 
+def select_sweep(args) -> None:
+    H, HKV, DH, PAGE, STRIDE, KERNEL = 32, 2, 128, 64, 16, 32
+    per, strides = PAGE // STRIDE, KERNEL // STRIDE
+    cfg = types.SimpleNamespace(sparse_init_blocks=1, sparse_window=2048,
+                                sparse_block=PAGE, sparse_topk=64)
+    width, layers = args.table_pages, args.sparse_layers
+    ks = jax.random.split(jax.random.PRNGKey(0), 2)
+    pool = jax.random.normal(ks[0], (1, width + 1, HKV, per, DH), jnp.bfloat16)
+    table = jnp.asarray(
+        1 + np.random.default_rng(0).permutation(width), jnp.int32)
+
+    def chained(form):
+        """``form(x, offset) -> an array``: a layer's result feeds the
+        next one's ``x`` (times 0), so that the calls run in turn."""
+        @jax.jit
+        def chain(x, offset):
+            return lax.scan(
+                lambda x, _: (x + (0 * form(x, offset).sum(
+                    dtype=jnp.float32)).astype(x.dtype),
+                              None), x, None, length=layers)[0]
+        return chain
+
+    def xla_scores(q, offset):
+        pos = offset + jnp.arange(q.shape[0], dtype=jnp.int32)
+        seen = (STRIDE * jnp.arange(width * per, dtype=jnp.int32)
+                + KERNEL - 1 <= pos[:, None])
+        kernels = pool[0, table].swapaxes(1, 2).reshape(
+            1, width * per, HKV, DH)
+        return decode_lib.sparse_block_scores(q[None], kernels, seen[None],
+                                              per, strides)[0]
+
+    def best_blocks(scores, offset):
+        pos = offset + jnp.arange(scores.shape[0], dtype=jnp.int32)
+        return decode_lib.sparse_choose(scores, pos // PAGE, cfg)
+
+    def as_pages(blocks, ok):
+        """The scatter of ``mixed_programs``' ``sparse_chosen_pages``."""
+        c = blocks.shape[0]
+        return jnp.zeros((c, HKV, width), bool).at[
+            jnp.arange(c)[:, None, None], jnp.arange(HKV)[None, :, None],
+            blocks].set(ok)
+
+    def choice(scores, offset):
+        return as_pages(*best_blocks(scores, offset))
+
+    def kernel_scores(bq=None):
+        def form(q, offset):
+            return scores_lib.sparse_scores(
+                q, pool[0, table], offset, jnp.int32(q.shape[0]),
+                stride=STRIDE, kernel=KERNEL, block_q=bq)
+        return form
+
+    for c in args.chunks:
+        q = jax.random.normal(ks[1], (c, H, DH), jnp.bfloat16)
+        for end in args.keys:
+            xs = (q, jnp.int32(end - c))
+            want = jax.jit(xla_scores)(*xs)
+            row = {"C": c, "keys": end}
+
+            def measure(name, form, xs=xs):
+                try:
+                    row[name] = round(median_ms(
+                        chained(form), xs, args.reps) / layers, 4)
+                except Exception as e:     # a tile Mosaic refuses
+                    row[name] = f"refused: {str(e)[:80]}"
+
+            measure("xla_scores", xla_scores)
+            # the choice over scores that are the program's own argument:
+            # XLA then lays them with the queries in the lanes and sorts
+            # 1024 of them at a time, as the chunk programs' top-k does
+            # (under a producer of its own choosing the same call read
+            # 5.97 ms at C = 1024 where this reads 1.20)
+            blocks, ok = jax.jit(best_blocks)(want, xs[1])
+            measure("top_k", lambda s, offset: best_blocks(s, offset)[0],
+                    (want, xs[1]))
+            measure("scatter", lambda s, offset: as_pages(
+                blocks + (0 * s[..., :1]).astype(jnp.int32), ok),
+                (want, xs[1]))
+            measure("choice", choice, (want, xs[1]))
+            default = scores_lib._PAGES
+            # the programs' own tile first: min(C, 1024) x 128
+            for bq, pages in args.tiles or [[1024, 128], [512, 128],
+                                            [256, 128], [1024, 256]]:
+                if bq > c:
+                    continue
+                scores_lib._PAGES = pages
+                scores_lib._scores.clear_cache()
+                measure(f"kernel_{bq}x{pages}", kernel_scores(bq))
+            scores_lib._PAGES = default
+            scores_lib._scores.clear_cache()
+            measure("select", lambda q, offset: choice(
+                kernel_scores()(q, offset), offset))
+            got = jax.jit(kernel_scores())(*xs)
+            row["rel_err"] = float(jnp.max(jnp.abs(got - want))
+                                   / jnp.max(jnp.abs(want)))
+            row["same_choice_pct"] = round(100 * float(jnp.mean(
+                jax.jit(choice)(got, xs[1]) == jax.jit(choice)(
+                    want, xs[1]))), 4)
+            print(json.dumps(row), flush=True)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--select", action="store_true",
+                    help="a sparse layer's selection for a chunk instead")
     ap.add_argument("--sparse", action="store_true",
                     help="a sparse layer's chunk over its pages instead")
     ap.add_argument("--table-pages", type=int, default=520)
@@ -519,10 +635,10 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     args = ap.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
-    if args.sparse:
+    if args.sparse or args.select:
         args.chunks = args.chunks or [1024, 512, 256]
         args.keys = args.keys or [8192, 16384, 32768]
-        return sparse_sweep(args)
+        return (sparse_sweep if args.sparse else select_sweep)(args)
     args.chunks = args.chunks or [256, 512, 768, 1024]
     args.keys = args.keys or [1024, 4096, 8192, 17408]
     if args.latent:

@@ -32,7 +32,8 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          ("kimi-k2.7-code-ep32-6l", "repo-questions-backlog"),
          # its chunk programs changed with PR 49, its decode did not
          ("jamba2-3b", "chat-backlog"),
-         # its chunk program changed with PR 51, its decode did not
+         # its chunk program changed with PR 51 and again with PR 53
+         # (hvd_sparse_scores: a chunk's block scores), its decode did not
          ("minicpm-sala-8l", "longdoc-backlog")]
 
 
