@@ -88,7 +88,7 @@ class Rotary:
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
 LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba", "sparse",
-               "lightning")
+               "lightning", "conv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,8 +165,8 @@ class TransformerConfig:
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
     # One of the LAYER_KINDS a layer ("sliding" | "full" here; "kda",
-    # "mla", "mamba", "sparse" and "lightning" below). A sliding layer
-    # sees the keys
+    # "mla", "mamba", "sparse", "lightning" and "conv" below). A sliding
+    # layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
     # layer_rotary says otherwise. None: every layer is causal over
@@ -280,6 +280,16 @@ class TransformerConfig:
     embed_multiplier: Optional[float] = None
     residual_multiplier: Optional[float] = None
     logit_divisor: Optional[float] = None
+    # An eighth kind of layer_types (ISSUE 54; served, not trained):
+    # "conv", LFM2's gated short convolution: the normed input up to
+    # 3 * d_model (B | C | u), z = B * u through a causal depthwise
+    # convolution of conv_taps taps a channel with no bias and no
+    # activation, times C, and down through one more matrix. Between
+    # calls a sequence keeps the newest conv_taps - 1 rows of z and
+    # nothing else: no recurrence. It reads no position. The full layers
+    # beside it keep their own n_kv_heads, qk_norm_per_head and
+    # layer_rotary.
+    conv_taps: int = 3
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -299,9 +309,12 @@ class TransformerConfig:
                                  "mla_rope_dim")
             if "mamba" in self.layer_types and not self.mamba_dt_rank:
                 raise ValueError("mamba layers need mamba_dt_rank")
-            # kda and mla layers project for n_heads heads; the full
-            # layers beside mamba layers alone keep their own n_kv_heads
+            # kda and mla layers project for n_heads heads with norms
+            # and gates of their own; mamba and conv layers have no q or
+            # k, and the full layers beside them keep the configuration's
+            # n_kv_heads and qk_norm_per_head
             own_heads = {"kda", "mla"} & set(self.layer_types)
+            no_heads = {"mamba", "conv"} & set(self.layer_types)
             if "sparse" in self.layer_types and (
                     self.sparse_kernel % self.sparse_stride
                     or self.sparse_block % self.sparse_stride
@@ -316,15 +329,25 @@ class TransformerConfig:
                     "in whole sparse_block, and the forced blocks "
                     "(sparse_init_blocks and the window's) within "
                     "sparse_topk")
-            if (own_heads or "mamba" in self.layer_types) and (
-                    own_heads and self.n_kv_heads != self.n_heads
+            if own_heads and (
+                    self.n_kv_heads != self.n_heads
                     or self.attn_gate or self.sandwich_norm
                     or self.qk_norm or self.qk_norm_per_head):
                 raise ValueError(
-                    "kda and mla layers have n_heads heads of their own "
-                    "projections, norms and gates: n_kv_heads = n_heads, "
-                    "and no attn_gate, sandwich_norm or qk_norm (in a "
-                    "stack with mamba layers either)")
+                    f"{' and '.join(sorted(own_heads))} layers have n_heads "
+                    "heads of their own projections, norms and gates: "
+                    "n_kv_heads = n_heads, and no attn_gate, sandwich_norm "
+                    "or qk_norm")
+            if no_heads and (self.attn_gate or self.sandwich_norm
+                             or self.qk_norm):
+                # what a mamba or conv layer's own residual does not
+                # read, and what nothing served pairs with them
+                raise ValueError(
+                    f"{' and '.join(sorted(no_heads))} layers have no "
+                    "gate and no norm on their branch's output: no "
+                    "attn_gate, sandwich_norm or qk_norm over the whole "
+                    "vector (qk_norm_per_head and n_kv_heads are the full "
+                    "layers' beside them)")
         if self.tie_embeddings and self.layer_types is None:
             raise ValueError(
                 "tie_embeddings is read where a configuration has "
@@ -387,9 +410,10 @@ class TransformerConfig:
     def stateful(self) -> bool:
         """Some layer keeps a state that is not cached keys and values
         alone (a kda, mamba or lightning layer's recurrent state, an mla
-        layer's latent, a sparse layer's compressed keys)."""
+        layer's latent, a sparse layer's compressed keys, a conv layer's
+        rows)."""
         return bool(self.layer_types) and bool(
-            {"kda", "mla", "mamba", "sparse", "lightning"}
+            {"kda", "mla", "mamba", "sparse", "lightning", "conv"}
             & set(self.layer_types))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
@@ -397,13 +421,13 @@ class TransformerConfig:
         what ``layer_rotary`` says of its kind, else plainly at
         ``rope_theta``, but for the full layers of a stack with
         ``layer_types``, which then take no rotary embedding. (An mla
-        layer rotates its ``mla_rope_dim`` values so; a kda or mamba
-        layer reads no position; a sparse layer is a full layer here; a
-        lightning layer rotates, in halves, plainly.)"""
+        layer rotates its ``mla_rope_dim`` values so; a kda, mamba or
+        conv layer reads no position; a sparse layer is a full layer
+        here; a lightning layer rotates, in halves, plainly.)"""
         kind = self.kind_of(layer)
         if kind == "lightning":
             return Rotary(self.rope_theta)
-        if kind in ("kda", "mamba", "sparse"):
+        if kind in ("kda", "mamba", "sparse", "conv"):
             kind = "full"
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
@@ -452,6 +476,11 @@ class TransformerConfig:
 # Parameter init + sharding specs
 # ---------------------------------------------------------------------------
 
+#: The kinds of layer with no q and no k: the gains ``qk_norm_per_head``
+#: gives the full layers beside them, they do not hold.
+_NO_HEADS = ("mamba", "conv")
+
+
 def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
                  ) -> Dict[str, Any]:
     """The specs of one stack of blocks: MoE blocks or dense ones, with
@@ -479,6 +508,10 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
                   "w_dt": P(None, None, "tp"), "b_dt": P(None, "tp"),
                   "a_log": P(None, None, "tp"), "d_skip": P(None, "tp"),
                   "w_out": P(None, "tp", "fsdp")}
+    if kind == "conv":
+        layers = {"attn_norm": vec, "mlp_norm": vec, "w_in": mat,
+                  "conv_w": P(None, None, "tp"),
+                  "w_out": P(None, "tp", "fsdp")}
     if kind == "mla":
         del layers["wk"], layers["wv"]
         layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
@@ -492,7 +525,7 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
     if cfg.qk_norm:
         layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
         layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
-    if cfg.qk_norm_per_head:
+    if cfg.qk_norm_per_head and kind not in _NO_HEADS:
         layers["q_norm"] = P(None, None)   # [L, Dh], every head's gain
         layers["k_norm"] = P(None, None)
     if cfg.attn_gate:
@@ -621,6 +654,15 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             "w_out": dense(next(k), (L, Di, D), Di),
             "mlp_norm": jnp.ones((L, D), dt),
         }
+    elif kind == "conv":
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            "w_in": dense(next(k), (L, D, 3 * D), D),         # B | C | u
+            # a tap a channel: [taps, D], the last tap the newest row
+            "conv_w": dense(next(k), (L, cfg.conv_taps, D), cfg.conv_taps),
+            "w_out": dense(next(k), (L, D, D), D),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
     elif kind == "mla":
         R, C, Q = cfg.mla_rope_dim, cfg.mla_kv_rank, cfg.mla_q_rank
         # a head's q: Dh without position, then R rotated; with a q
@@ -656,7 +698,7 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
         layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
-    if cfg.qk_norm_per_head:
+    if cfg.qk_norm_per_head and kind not in _NO_HEADS:
         layers["q_norm"] = jnp.ones((L, Dh), dt)
         layers["k_norm"] = jnp.ones((L, Dh), dt)
     if cfg.attn_gate:
@@ -1065,17 +1107,28 @@ def mamba_rows(cfg: TransformerConfig, lp, x):
     return jnp.split(h @ lp["w_in"], 2, axis=-1)
 
 
+def causal_taps(u, before, taps, bias=None):
+    """``bias + sum_j w_j u_{t - (n - 1) + j}``, float32: the causal
+    depthwise convolution of ``taps`` [n, C] (a tap a channel, the last
+    the newest row) over ``u`` [B, T, C] preceded by the sequence's
+    ``before`` [B, n - 1, C] (zeros at a sequence's start). A mamba
+    layer's (with its bias) and a conv layer's (without) alike."""
+    T = u.shape[1]
+    full = jnp.concatenate([before.astype(u.dtype), u], 1)
+    # (here, so that a mamba layer's program lowers as it did before the
+    # two kinds shared this)
+    b = None if bias is None else bias.astype(jnp.float32)
+    y = sum(full[:, j:j + T].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(taps.shape[0]))
+    return y if b is None else b + y
+
+
 def mamba_conv(cfg: TransformerConfig, lp, u, before):
     """``SiLU(b + sum_j w_j u_{t - (taps - 1) + j})``: the causal
     depthwise convolution over ``u`` [B, T, Di] preceded by the
     sequence's ``before`` [B, taps - 1, Di] (zeros at a sequence's
     start), float32 sums, in ``u``'s dtype."""
-    T = u.shape[1]
-    full = jnp.concatenate([before.astype(u.dtype), u], 1)
-    y = lp["conv_b"].astype(jnp.float32) + sum(
-        full[:, j:j + T].astype(jnp.float32)
-        * lp["conv_w"][j].astype(jnp.float32)
-        for j in range(cfg.mamba_d_conv))
+    y = causal_taps(u, before, lp["conv_w"], lp["conv_b"])
     return jax.nn.silu(y).astype(u.dtype)
 
 
@@ -1101,6 +1154,33 @@ def mamba_residual(cfg: TransformerConfig, lp, x, y, z):
     gate ``SiLU(z)``, the output projection and the residual."""
     o = (y * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
     return x + (o @ lp["w_out"]).astype(cfg.dtype)
+
+
+# -- the gated short convolution (ISSUE 54): projections, gates, taps --
+
+def conv_inputs(cfg: TransformerConfig, lp, x):
+    """A conv layer up to its gates: ``[B | C | u] = RMSNorm(x) W_in``,
+    each [B, T, D]."""
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    return jnp.split(h @ lp["w_in"], 3, axis=-1)
+
+
+def conv_gated(cfg: TransformerConfig, lp, b, c, u, before):
+    """``(z, C * conv(z))``: ``z = B * u`` [B, T, D], what the
+    convolution runs over (the newest ``conv_taps - 1`` rows of it are
+    ALL a sequence carries from call to call), and :func:`causal_taps`
+    of it after the sequence's ``before`` [B, conv_taps - 1, D], with
+    neither bias nor activation, gated by ``C``; both in ``u``'s
+    dtype."""
+    z = b * u
+    y = causal_taps(z, before, lp["conv_w"]).astype(u.dtype)
+    return z, c * y
+
+
+def conv_residual(cfg: TransformerConfig, lp, x, g):
+    """A conv layer after its gated convolution ``g`` [B, T, D]: the
+    output projection and the residual."""
+    return x + (g @ lp["w_out"]).astype(cfg.dtype)
 
 
 # -- the linear-attention kind (ISSUE 50): projections, decays, gate --
@@ -1321,13 +1401,14 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
-    """The trainer's entry points refuse kda, mla, mamba, sparse and
-    lightning layers by name: their forward exists in the serve programs
-    alone."""
+    """The trainer's entry points refuse kda, mla, mamba, sparse,
+    lightning and conv layers by name: their forward exists in the serve
+    programs alone."""
     if cfg.stateful:
         raise NotImplementedError(
             f"{what} does not run kda, mla or mamba layers, nor sparse or "
-            "lightning layers: models/transformer.py's decoder_layer has no "
+            "lightning layers, nor conv layers: "
+            "models/transformer.py's decoder_layer has no "
             "backward through the chunked delta-rule scan, the selective "
             "scan or the decayed linear scan of serve/decode.py, no latent "
             "attention and no selection of key blocks (ROADMAP B14, B8, "

@@ -115,7 +115,8 @@ from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
 from horovod_tpu.parallel.ring_attention import local_attention
-from horovod_tpu.serve.kv_cache import NULL_BLOCK, latent_row, state_kinds
+from horovod_tpu.serve.kv_cache import (NULL_BLOCK, latent_row, page_tail,
+                                        state_kinds)
 
 _NEG_BIG = -1e30  # matches ring_attention's finite "-inf"
 
@@ -1142,8 +1143,9 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     tuples with one array a kind of layer, in
     ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
     is what): pages behind the block tables for ``full`` and ``mla``
-    layers, and rings and recurrent states for ``sliding``, ``kda`` and
-    ``mamba`` layers, one a batch slot (slot 0 is the null slot, as
+    layers, and rings, recurrent states and convolution rows for
+    ``sliding``, ``kda``, ``mamba`` and ``conv`` layers, one a batch
+    slot (slot 0 is the null slot, as
     block 0 is the null block). An address is a pair too:
     ``(block_table, slot)``.
 
@@ -1243,7 +1245,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
 
     def pages(cache, c, tables, rows: int):
         """A ``full`` layer's K or V behind the tables of ``rows``
-        sequences."""
+        sequences (a page's positions hold ``kv_cache.page_tail``)."""
         with jax.named_scope("kv_gather"):
             return cache[place["full"]][c, tables].reshape(rows, S, Hkv, Dh)
 
@@ -1267,7 +1269,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     def full_chunk(call, lp, kc, vc, c, x, i):
         def write(cache, new):
             return put(cache, "full", (c, call.blks),
-                       new[0].reshape(-1, block_size, Hkv, Dh))
+                       new[0].reshape(-1, block_size, *page_tail(cfg)))
 
         def attend(q, k, v, kc, vc):
             if call.local:
@@ -1514,6 +1516,35 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                     kc = put(kc, "lightning", (c, call.slot), state[0])
         return kc, vc, tf_lib.lightning_residual(cfg, lp, x, h, o)
 
+    def conv_chunk(call, lp, kc, vc, c, x, i):
+        """The chunk's gated convolution from the rows the slot holds
+        (zeros for a sequence's first chunk, whatever the slot held),
+        and the rows back as they are AT ``length``: the last real
+        ones, not the bucket's last (a padded position writes nothing a
+        later call reads; a real position sees none after it)."""
+        B = x.shape[0]
+        n = place["conv"]
+        kept = cfg.conv_taps - 1
+        with jax.named_scope("attn_conv"):
+            with jax.named_scope("conv_proj"):
+                b, cc, u = tf_lib.conv_inputs(cfg, lp, x)
+            before = jnp.zeros((B, kept, cfg.d_model), u.dtype)
+            if not call.local:
+                before = jnp.where(
+                    call.offset > 0,
+                    kc[n][c, call.slot].reshape(before.shape), before)
+            with jax.named_scope("conv_taps"):
+                z, g = tf_lib.conv_gated(cfg, lp, b, cc, u, before)
+            if kc is not None:
+                with jax.named_scope("state_write"):
+                    newest = lax.dynamic_slice_in_dim(
+                        jnp.concatenate([before, z], 1)[0], call.length,
+                        kept)
+                    kc = put(kc, "conv", (c, call.slot), newest.reshape(-1))
+            with jax.named_scope("conv_proj"):
+                x = tf_lib.conv_residual(cfg, lp, x, g)
+        return kc, vc, x
+
     # -- a decode step of the batch (one position a row) -------------
 
     def by_slot(call, rows, n_slots):
@@ -1552,7 +1583,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         def write(cache, new):
             return put(cache, "full",
                        (c, call.blk, call.positions % block_size),
-                       new.reshape(-1, Hkv, Dh))
+                       new.reshape(-1, *page_tail(cfg)))
 
         def attend(q, k, v, kc, vc):
             rows = call.tables.shape[0]
@@ -1757,6 +1788,27 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 kc = put(kc, "lightning", (c,), state)
         return kc, vc, tf_lib.lightning_residual(cfg, lp, x, h, o)
 
+    def conv_step_layer(call, lp, kc, vc, c, x, i):
+        """One position of the gated convolution a row of the batch
+        over its own slot's rows, which are shifted where they lie: the
+        oldest out, the row's ``z`` in (a padded row shifts the null
+        slot's)."""
+        n = place["conv"]
+        with jax.named_scope("attn_conv"):
+            with jax.named_scope("conv_proj"):
+                b, cc, u = tf_lib.conv_inputs(cfg, lp, x)
+            before = kc[n][c, call.slots].reshape(
+                u.shape[0], cfg.conv_taps - 1, -1)
+            with jax.named_scope("conv_taps"):
+                z, g = tf_lib.conv_gated(cfg, lp, b, cc, u, before)
+            with jax.named_scope("state_write"):
+                kc = put(kc, "conv", (c, call.slots),
+                         jnp.concatenate([before, z], 1)[:, 1:].reshape(
+                             u.shape[0], -1))
+            with jax.named_scope("conv_proj"):
+                x = tf_lib.conv_residual(cfg, lp, x, g)
+        return kc, vc, x
+
     #: kind of layer -> how a chunk and how a decode step run it
     kinds = {
         "sliding": {"chunk": window_chunk, "step": window_step},
@@ -1767,6 +1819,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         "sparse": {"chunk": sparse_chunk, "step": sparse_step},
         "lightning": {"chunk": lightning_chunk,
                       "step": lightning_step_layer},
+        "conv": {"chunk": conv_chunk, "step": conv_step_layer},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -1866,7 +1919,8 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
                 "layer's ring and a kda or mamba layer's recurrent state "
-                "(a lightning layer's too) are not pages another engine or "
+                "(a lightning layer's too, and a conv layer's rows) are not "
+                "pages another engine or "
                 "a draft could be handed, nor are a sparse layer's "
                 "compressed keys, "
                 "and decode.py's inject and verify know K and V pages alone "

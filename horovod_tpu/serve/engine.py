@@ -97,7 +97,7 @@ import numpy as np
 from horovod_tpu.ops.latent_decode import key_block as latent_key_block
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
-    NULL_SLOT, RECURRENT_KINDS, SLOT_KINDS, BlockAllocator, hash_chain,
+    NULL_SLOT, SLOT_KINDS, BlockAllocator, hash_chain,
     init_kv_cache, pick_bucket, ring_width,
 )
 from horovod_tpu.serve.metrics import ServeMetrics
@@ -442,16 +442,19 @@ class ServeEngine:
         if self._slot_states:
             # A prefix is shared as pages mapped into another block
             # table: what every layer keeps of a position then has to be
-            # a page. A window layer's ring and a kda, mamba or lightning
-            # layer's recurrent state lie by batch slot
-            # (kv_cache.SLOT_KINDS), so those kinds refuse it; full, mla
+            # a page. A window layer's ring, a kda, mamba or lightning
+            # layer's recurrent state and a conv layer's rows lie by
+            # batch slot (kv_cache.SLOT_KINDS), so those kinds refuse it
+            # (a conv layer's rows at a block boundary would be the
+            # cheapest of them to snapshot: ROADMAP B14); full, mla
             # and sparse layers alone (K/V and latent pages, compressed
             # keys behind the same tables) share.
             by_slot = self._kinds_by_slot()
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, and a kda, mamba or lightning layer's state "
+                 "a window, a kda, mamba or lightning layer's state and a "
+                 "conv layer's rows "
                  "after a prefix, cannot be mapped into another sequence: "
                  "engine._admit, kv_cache.BlockAllocator)",
                  cfg.prefix_caching and by_slot),
@@ -704,7 +707,8 @@ class ServeEngine:
             ph.args["queue"] = len(self._queue)
         if not (self._prefilling or self._active):
             m.record_idle()
-        recurrent = set(RECURRENT_KINDS) & set(self.cache.kinds)
+        # (the rings have their own gauge)
+        recurrent = (set(SLOT_KINDS) - {"sliding"}) & set(self.cache.kinds)
         if self.cache.ring or recurrent:
             with m.phase("serve:gauges"):
                 in_use = self.cfg.max_batch - len(self._free_slots)
@@ -965,6 +969,10 @@ class ServeEngine:
         if "mamba" in self.cache.kinds:
             # positions the selective scan runs: the bucket, pads too
             extra["scanned"] = len(toks)
+        if "conv" in self.cache.kinds:
+            # positions the short convolutions run and the mixture
+            # dispatches: the bucket, pads too (n_tokens are the real)
+            extra["convolved"] = len(toks)
         if "sparse" in self.cache.kinds:
             # the call's queries that choose their blocks
             extra["selected"] = max(0, offset + chunk - max(
@@ -1048,7 +1056,8 @@ class ServeEngine:
                 f"{what} moves a sequence's pages between engines; a "
                 f"configuration with {held} layers keeps a window layer's "
                 "keys in per-slot rings and a kda, mamba or lightning "
-                "layer's recurrent state by slot, which are not pages (nor "
+                "layer's recurrent state and a conv layer's rows by slot, "
+                "which are not pages (nor "
                 "are a sparse layer's compressed keys K or V pages) and which "
                 "migrate.py and engine.inject_* do not move yet (ROADMAP "
                 "B9, B14)")
@@ -1416,6 +1425,12 @@ class ServeEngine:
                 # benchmark holds it to until a `benchmark` issue corrects
                 # both), and the positions its rows attend in the full layers
                 extra["slots_stepped"] = self.cfg.max_batch + 1
+                extra["attended"] = int(positions.sum()) + n
+            if "conv" in self.cache.kinds:
+                # rows whose slot's convolution rows the step shifted (the
+                # bucket: a padded row shifts the null slot's), and the
+                # positions the batch's rows attend in the full layers
+                extra["slots_stepped"] = len(positions)
                 extra["attended"] = int(positions.sum()) + n
             if "sparse" in self.cache.kinds:
                 # rows that choose their blocks (a padded row is at 0)

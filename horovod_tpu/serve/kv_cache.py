@@ -293,14 +293,22 @@ class BlockAllocator:
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
 STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
-               "lightning")
+               "lightning", "conv")
 #: Those of them whose arrays lie by batch slot and not behind the block
 #: tables: nothing of theirs is a page that another sequence, another
 #: engine or a draft could be handed (what ``engine.py`` refuses over
 #: them, and what ``KVCache.slot_bytes`` counts); and those of these
-#: that are a recurrent state, which ``state_slots_in_use`` counts.
+#: that are a recurrent state. ``conv`` joins ``SLOT_KINDS`` alone: its
+#: rows lie by slot, so everything refused over a slot's state is
+#: refused over them and ``state_slots_in_use`` / ``state_bytes`` count
+#: them (the engine reads ``SLOT_KINDS`` for both, the rings apart,
+#: which have their own gauge), but they are the layer's last
+#: ``conv_taps - 1`` inputs and no recurrence (nothing of a position
+#: further back is in them, and nothing in float32 lies beside them).
+#: ``RECURRENT_KINDS`` only names which slot kinds are recurrences:
+#: nothing in the engine treats them apart.
 RECURRENT_KINDS = ("kda", "mamba", "lightning")
-SLOT_KINDS = ("sliding",) + RECURRENT_KINDS
+SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv",)
 
 
 def state_kinds(cfg) -> Tuple[str, ...]:
@@ -332,7 +340,10 @@ class KVCache:
     as a pair under ``k``:
 
     * ``full``: K and V pages ``[n, n_blocks, block_size, Hkv, Dh]``
-      behind the block tables, as above;
+      behind the block tables, as above; where a head is narrower than
+      the chip's 128 lanes and the heads together fill whole lanes
+      (:func:`page_tail`), ``[n, n_blocks, block_size, Hkv * Dh]``, a
+      position's heads as ONE row;
     * ``sliding``: K and V rings ``[n, n_slots + 1, ring, Hkv, Dh]``,
       one ring of ``ring`` positions a batch slot; position p of a
       sequence lies at ``p % ring``;
@@ -362,7 +373,12 @@ class KVCache:
       on its way in and back out (four copies of 545 MB a decode step:
       compiled for the v5e, PR 50);
     * ``lightning``: the decayed linear state ``[n, n_slots + 1, heads,
-      Dh, Dh]`` float32, and no second array.
+      Dh, Dh]`` float32, and no second array;
+    * ``conv``: the newest ``conv_taps - 1`` rows before the
+      convolution, end to end as a mamba layer's,
+      ``[n, n_slots + 1, (conv_taps - 1) * d_model]`` in the
+      configuration's dtype, and nothing else: no recurrent state and
+      no second array.
 
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
@@ -410,6 +426,24 @@ def latent_row(cfg) -> int:
     return -(-(cfg.mla_kv_rank + cfg.mla_rope_dim) // 128) * 128
 
 
+def page_tail(cfg) -> Tuple[int, ...]:
+    """What a ``full`` layer's page holds of a position: ``(Hkv, Dh)``,
+    or ``(Hkv * Dh,)``, the heads end to end, where ``Dh`` is not whole
+    lanes of 128 and ``Hkv * Dh`` is. With 8 heads of 64 innermost the
+    chip keeps an array ``[.., block, 8, 64]`` with its BLOCKS innermost
+    (the layout without padding, as :func:`latent_row` found of rows of
+    576), and every program that writes or gathers pages turned the
+    whole pool over on its way in and back out: 20 copies of 1 GB a
+    decode step and 7.1 GB of temporaries beside 11.4 GB of arguments
+    (compiled for the v5e, PR 54). As rows of 512 the layout the scatter
+    and the gather read is the array's own. A head of whole lanes keeps
+    its own dimension: those programs lower as before."""
+    heads, width = cfg.n_kv_heads, cfg.head_dim
+    if width % 128 and (heads * width) % 128 == 0:
+        return (heads * width,)
+    return (heads, width)
+
+
 def ring_width(window: int, chunk: int, block_size: int) -> int:
     """Positions a window layer's ring keeps: a chunk's last query sees
     ``window`` keys back from itself and its first query ``window``
@@ -451,7 +485,8 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
         pages = ((n["sparse"], n_blocks, cfg.n_kv_heads, block_size, Dh),
                  dtype)
         shapes = {   # kind -> (shape, dtype) of what k and v hold of it
-            "full": 2 * (((n["full"], n_blocks, block_size) + tail, dtype),),
+            "full": 2 * (((n["full"], n_blocks, block_size) + page_tail(cfg),
+                          dtype),),
             "sliding": 2 * (((n["sliding"], n_slots + 1, ring) + tail,
                              dtype),),
             "kda": (((n["kda"], n_slots + 1, H, Dh, Dh), jnp.float32),
@@ -468,6 +503,8 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                                 dtype)), pages),
             "lightning": (((n["lightning"], n_slots + 1, cfg.n_heads, Dh, Dh),
                            jnp.float32), None),
+            "conv": (((n["conv"], n_slots + 1,
+                       (cfg.conv_taps - 1) * cfg.d_model), dtype), None),
         }
         kinds = state_kinds(cfg)
 

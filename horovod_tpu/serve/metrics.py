@@ -413,8 +413,8 @@ class ServeMetrics:
         self.kv_window_positions_max = 0
         self.kv_window_blocks_in_use = 0
         # The kinds whose state is not keys (kda, mamba, lightning,
-        # mla): the batch slots whose recurrent state a sequence holds
-        # and their bytes; the
+        # conv, mla): the batch slots whose recurrent state, or whose
+        # convolution rows alone, a sequence holds and their bytes; the
         # positions the latent pool held for the rows of the last
         # decode call (their sum: what absorbed attention has to read),
         # and the most it held for one sequence.

@@ -1223,3 +1223,96 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
     # live copies of a layer's 272 MB of scores), 62.6 MB since; the
     # decode program's 90.2 MB are what they were
     assert got["temp_bytes"] < ((96 if step else 80) << 20), got
+
+
+# Heads of 64 behind the block tables (ISSUE 54): a conv layer, an
+# attention layer of 32 / 8 heads of 64 and two experts of a mixture
+# held whole, at LFM2-8B-A1B's widths, 128 slots and tables of 160
+# pages.
+_LFM2_DRIVER = r"""
+import json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=8192, d_model=2048, n_layers=3, n_heads=32, n_kv_heads=8,
+    d_head=64, d_ff=1792, d_ff_dense=7168, n_dense_layers=1, max_seq=2560,
+    norm_eps=1e-5, layer_types=("conv", "full", "conv"), conv_taps=3,
+    layer_rotary={{"full": dict(theta=1e6)}}, qk_norm_per_head=True,
+    tie_embeddings=True, n_experts=2, moe_top_k=1, moe_capacity_factor=None,
+    moe_scoring="sigmoid", dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS = 16, 160, 128
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+fns = dict(zip(("prefill", "prefill_resume", "decode"),
+               decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                         table_width=WIDTH)))
+args = {{"prefill_resume": (i32(1024), i32(), i32(), (i32(WIDTH), i32())),
+        "decode": (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))}}
+pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
+out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
+       "pool_bytes": kc[0].size * 2}}
+for name, a in args.items():
+    compiled = fns[name].lower(params, kc, vc, *a).compile()
+    text = compiled.as_text()
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    out[name] = {{
+        # copies of anything as large as a pool, whatever its shape
+        "pool_copies": sum(
+            int(np.prod([int(d) for d in dims.split(",")])) >= kc[0].size
+            for dims in re.findall(r"= bf16\[([\d,]+)\]\{{\S* copy\(",
+                                   text)),
+        "aliased": len(re.findall(r"may-alias|must-alias",
+                                  aliased.group(1))),
+        "ragged_dots": len(re.findall(r"%ragged-dot-(?!metadata)\S+ = ",
+                                      text)),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_pages_of_narrow_heads_are_never_copied_whole(program):
+    """ISSUE 54: with 8 KV heads of 64 a position's heads lie in a page
+    as ONE row of 512 (``kv_cache.page_tail``). As ``[.., 16, 8, 64]``
+    the v5e keeps the pool with its blocks innermost and a decode step
+    of the cell's programs held 20 copies of the 1 GB pool and 7.1 GB of
+    temporaries. Here: K and V pages and the convolution's rows aliased
+    in and out, no copy of anything as large as a pool, a decode
+    step's temporaries what its gathered tables take (1.06 GB) and a
+    chunk's 20 MB, and the whole mixture's three grouped products a
+    sparse layer in the program."""
+    out = _compile_for_v5e(_LFM2_DRIVER)
+    got = out[program]
+    assert out["pool"] == "bf16[1,20481,16,512]", out
+    assert got["aliased"] == 3, got
+    assert got["pool_copies"] == 0, got
+    assert got["ragged_dots"] == 2 * 3, got
+    # a decode step gathers every row's whole table (ROADMAP A5): 128 x
+    # 2560 positions x 1 KB, K and V, and once more as the products
+    # read them
+    assert got["temp_bytes"] < (1.2e9 if program == "decode" else 0.1e9), got
