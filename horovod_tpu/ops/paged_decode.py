@@ -1,0 +1,322 @@
+"""A decode step's attention of a ``full`` layer over the K and V pages
+where they lie.
+
+One Pallas call a layer (``hvd_paged_decode`` in a device trace): for
+each row of the batch, its one query a head against the keys of that
+row's own pages and the values of the same pages, read once out of the
+two pools in HBM, a key block at a time into VMEM, and no further than
+the row's length. Built as ``ops/latent_decode.py`` is (tables and
+lengths as prefetched scalars, a key block's pages by asynchronous
+copies into one half of a double buffer while the other half is
+attended), with two pools where that kernel has one. The XLA form it
+replaced (``serve/decode.py``: ``_attend_keys`` over every row's whole
+table, gathered) is the tests' reference.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import NEG_INF
+
+
+def _wave_pages(block_size: int) -> int:
+    """Pages a wave of copies brings of each pool (a key block): 1024
+    positions. On the v5e (2026-10-02, ``tools/prefill_attn_sweep.py
+    --paged-decode``: bf16 queries ``[rows, H, Dh]`` over two pools
+    behind shuffled tables, the rows' lengths log-uniform; ms a layer of
+    the kernel alone and the GB/s of the K and V pages it reads, at key
+    blocks of 512 / **1024** / 2048 positions; ``xla`` is the form it
+    replaced, ``_attend_keys`` over every row's whole table, gathered):
+
+    ======= ==== ========== ====== ==== ===== ========================= ===============
+    cell    rows lengths    tables H    xla   kernel alone, ms          GB/s
+    ======= ==== ========== ====== ==== ===== ========================= ===============
+    lfm2    128  256..2560  160    32/8 7.81  0.575 / **0.554** / 0.594 445 / 462 / 431
+    trinity 32   1024..8576 536    48/8 5.39  0.744 / **0.758** / 0.761 632 / 620 / 617
+    jamba   256  64..1536   96     20/1 0.85  0.548 / **0.541** / 0.543 117 / 118 / 118
+    ======= ==== ========== ====== ==== ===== ========================= ===============
+
+    (lfm2: pages ``[16, 512]``, 8 heads of 64 end to end; trinity: ``[16,
+    8, 128]``; jamba: ``[16, 1, 128]``. 7.8 k pages of each pool a call
+    in all three.) A page of each pool costs 69-71 ns at lfm2's 16 KB
+    and at jamba's 4 KB alike: nearly every key block there is a row's
+    last one, whose copies are issued in a loop, and what a copy costs
+    is its issue (34 ns: ``ops/latent_decode.py``), not its bytes; at
+    trinity's 32 KB, where most blocks are whole, it is the memory (105
+    ns a page pair where 819 GB/s would take 80). A first form that
+    started a page a turn of the loop and waited for every copy by
+    itself took 0.664, 0.747 and 0.625 ms: the eight pages a turn and
+    the one wait a power of two are 17 % of lfm2's time and 14 % of
+    jamba's. The masked form of trinity's pages (every query head
+    scored against every KV head's keys, 8 x the exponentials) is not
+    what bounds it: 620-632 GB/s is the latent kernel's speed behind
+    shuffled tables with one pool. 2048 is no faster anywhere; 512 is 2
+    % ahead at trinity's shapes and 4 % behind at lfm2's. (With a
+    block's offset into the tables taken once and not once a page, as
+    it stands: 0.546, 0.749 and 0.526 ms at 1024.)"""
+    return max(1, 1024 // block_size)
+
+
+def key_block(block_size: int, table_width: int) -> int:
+    """Positions a key block of :func:`paged_decode` holds over pages of
+    ``block_size`` behind tables ``table_width`` wide."""
+    return min(_wave_pages(block_size), table_width) * block_size
+
+
+def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
+            o_ref, buf, sem, acc, m_scr, l_scr, *, scale: float, width: int,
+            page: int, group: int):
+    """Row ``b`` of the batch (one grid step): its key blocks in a
+    loop, block ``j`` waited for in one half of ``buf`` (K's pages at
+    ``[half, 0]``, V's at ``[half, 1]``) while the pages of the next
+    (the row's, or the first of row ``b + 1``) are on their way into the
+    other. ``first_ref[b]`` counts the key blocks of the rows before
+    ``b``: its parity says which half block 0 arrives in. Where this
+    block and the next are both whole, the next one's copies are started
+    as straight-line code in the block that holds the dots and this
+    one's are waited for at once; a block at a row's end takes a loop
+    over the pages it has (``ops/latent_decode.py::_kernel``).
+
+    A page is ``page * group`` rows of the buffer: one a position
+    (``group`` 1: every KV head in the row, the queries laid
+    block-diagonal over it), or one a position and KV head (``group``
+    ``Hkv``: column ``c`` of the scores is position ``c // group`` under
+    KV head ``c % group``, and a query head sees its own KV head's
+    columns alone)."""
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    pages = buf.shape[2]
+    kb = pages * page
+    layer, length = layer_ref[0], len_ref[b]
+    n_blocks = pl.cdiv(length, kb)
+
+    def copies(r, j, half):
+        """``of(i)``: the two copies, K's and V's, of page i of row r's
+        key block j into ``half``."""
+        first = r * width + j * pages
+
+        def of(i):
+            at = tab_ref[first + i]
+            return [pltpu.make_async_copy(pool.at[layer, at],
+                                          buf.at[half, n, i], sem.at[half])
+                    for n, pool in enumerate((k_ref, v_ref))]
+        return of
+
+    def pages_of(r, j):
+        """The pages of row r's key block j that hold positions below
+        the row's length."""
+        return jnp.minimum(pages, pl.cdiv(len_ref[r] - j * kb, page))
+
+    def start(r, j, half):
+        """Start the copies of row r's key block j: the pages below the
+        row's length, no other; eight pages' in a turn of the loop, so
+        that the scalar unit issues them without a branch between."""
+        n, of = pages_of(r, j), copies(r, j, half)
+
+        def some(count):
+            def turn(i, _):
+                for u in range(count):
+                    for c in of(i * count + u):
+                        c.start()
+                return _
+            return turn
+        lax.fori_loop(0, n // 8, some(8), 0)
+        lax.fori_loop(n // 8 * 8, n, some(1), 0)
+
+    def wait(r, j, half):
+        """Wait for the bytes of the ``n`` pages :func:`start` asked of
+        each pool: one wait for each power of two in ``n``, not one a
+        copy."""
+        n = pages_of(r, j)
+        for bit in reversed(range(pages.bit_length())):
+            @pl.when(n & (1 << bit) != 0)
+            def _wait():
+                pltpu.make_async_copy(
+                    buf.at[1 - half, :, pl.ds(0, 1 << bit)],
+                    buf.at[half, :, pl.ds(0, 1 << bit)], sem.at[half]).wait()
+
+    @pl.when(b == 0)
+    def _first():
+        # what a wave does not fill is what an earlier one left, and is
+        # masked: it has to be a number
+        buf[...] = jnp.zeros_like(buf)
+        start(0, 0, 0)
+
+    acc[...] = jnp.zeros_like(acc)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    q = q_ref[...]
+
+    def attend(j, half):
+        k = buf[half, 0].reshape(kb * group, buf.shape[-1])
+        v = buf[half, 1].reshape(kb * group, buf.shape[-1])
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = j * kb + col // group < length
+        if group > 1:
+            head = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            seen &= col % group == head // (s.shape[0] // group)
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fade = jnp.exp(m_prev - m_new)
+        l_scr[...] = fade * l_scr[...] + p.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * fade + lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    def block(j, _):
+        half = (first_ref[b] + j) % 2
+        last = j == n_blocks - 1
+
+        def ragged():
+            @pl.when(jnp.logical_not(last))
+            def _next():
+                start(b, j + 1, 1 - half)
+
+            @pl.when(last & (b + 1 < rows))
+            def _next_row():
+                start(b + 1, 0, 1 - half)
+
+            wait(b, j, half)
+            attend(j, half)
+
+        def whole():
+            of = copies(b, j + 1, 1 - half)
+            for i in range(pages):
+                for c in of(i):
+                    c.start()
+            # one wait for the bytes of all of this half's copies
+            pltpu.make_async_copy(buf.at[1 - half], buf.at[half],
+                                  sem.at[half]).wait()
+            attend(j, half)
+
+        # j + 2 blocks lie below the length: block j + 1 is whole too
+        lax.cond((j + 2) * kb <= length, whole, ragged)
+        return _
+
+    lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[...] = (acc[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def paged_decode(q, k_pool, v_pool, layer, tables, lengths, *,
+                 interpret: Optional[bool] = None):
+    """Attention of one query a row and head over the row's pages:
+    ``q`` ``[B, H, Dh]`` against ``k_pool`` and ``v_pool`` ``[layers,
+    n_blocks, block_size, *tail]`` at ``layer`` (traced: the layers of a
+    stack share one compiled kernel), row b's positions ``0 ..
+    lengths[b] - 1`` (at least one: a length under 1 is read as 1) in
+    the pages ``tables[b]`` ``[B, W]`` names in order. ``tail`` is what
+    ``kv_cache.page_tail`` gives a position: ``(Hkv, Dh)``, or ``(Hkv *
+    Dh,)``, the heads end to end. Returns ``[B, H, Dh]`` in ``q``'s
+    dtype: the softmax of ``Dh ** -0.5 * q . k`` over the row's
+    positions under each query head's KV head, times the same positions'
+    values.
+
+    The pools stay in HBM and are never sliced or gathered outside the
+    kernel: a key block's pages (:func:`key_block` positions of each
+    pool) are copied into VMEM by as many asynchronous copies, into one
+    half of a double buffer while the other half's block is attended,
+    the next row's first block under the last of this one's. A page past
+    a row's length is not copied and a key block past it is not visited,
+    so a call reads ``sum_b ceil(lengths[b] / block_size)`` pages of
+    each pool whatever the tables' width is. Float32 scores, softmax
+    and accumulator over operands in the pools' dtype, ``p`` rounded to
+    it for the value dot: the numerics of ``_attend_keys``. ``tables``
+    and ``lengths`` are read from SMEM (scalar prefetch).
+
+    The two page shapes are one kernel. Rows of ``Hkv * Dh``: the
+    queries are laid block-diagonal ``[H, Hkv * Dh]`` (head h in the
+    columns of its KV head, zeros elsewhere), one dot against the whole
+    row scores every head and one of ``p`` against the whole V row sums
+    it, each head's own ``Dh`` columns picked afterwards (``Hkv`` times
+    the dots' operations, which the copies hide). Heads of their own
+    dimension: the pools are read as ``[.., block_size * Hkv, Dh]`` (the
+    same bytes), the one dot scores every query head against every KV
+    head's keys and the mask keeps a head's own."""
+    B, H, Dh = q.shape
+    page, tail = k_pool.shape[2], k_pool.shape[3:]
+    n_kv = tail[0] if len(tail) == 2 else tail[0] // Dh
+    if (v_pool.shape != k_pool.shape or len(tail) not in (1, 2)
+            or tail[-1] != (Dh if len(tail) == 2 else n_kv * Dh)
+            or H % n_kv or tables.shape[0] != B or lengths.shape != (B,)):
+        raise ValueError(
+            f"paged_decode: q {q.shape}, pools {k_pool.shape} and "
+            f"{v_pool.shape}, tables {tables.shape}, lengths {lengths.shape}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _decode(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables,
+                   lengths, pages=key_block(page, tables.shape[1]) // page,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
+def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
+            interpret: bool):
+    """The Pallas call, jitted of itself: a program of several full
+    layers traces and lowers the kernel once, not once a layer
+    (``ops/mamba_scan.py::_scan``)."""
+    B, H, Dh = q.shape
+    n_layers, n_pages, page = k_pool.shape[:3]
+    tail, width = k_pool.shape[3:], tables.shape[1]
+    if len(tail) == 2:
+        group, row = tail
+        k_pool, v_pool = (pool.reshape(n_layers, n_pages, page * group, row)
+                          for pool in (k_pool, v_pool))
+    else:
+        group, row = 1, tail[0]
+    n_kv = row // Dh            # KV heads end to end in a row: 1 or Hkv
+    if n_kv > 1:
+        own = jnp.eye(n_kv, dtype=q.dtype)
+        q = jnp.einsum("bgrd,gk->bgrkd", q.reshape(B, n_kv, H // n_kv, Dh),
+                       own).reshape(B, H, row)
+    # a row with no block would start no copy for the row after it,
+    # which would wait for one for ever: every row reads one position
+    lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
+    n_blocks = -(-lengths // (pages * page))
+    first = jnp.cumsum(n_blocks) - n_blocks
+    wave = 2 * pages * page * group * row * k_pool.dtype.itemsize
+    scores = H * pages * page * group * 4
+    o = pl.pallas_call(
+        functools.partial(_kernel, scale=Dh ** -0.5, width=width, page=page,
+                          group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, 2, pages, page * group, row), k_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, row), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, row), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # both halves of the buffer and the float32 tiles of a key
+            # block's scores, with room for what the compiler keeps
+            vmem_limit_bytes=2 * wave + 8 * scores + (16 << 20)),
+        interpret=interpret,
+        name="hvd_paged_decode",
+    )(layer.reshape(1), lengths, first.astype(jnp.int32),
+      tables.astype(jnp.int32).reshape(-1), q, k_pool, v_pool)
+    if n_kv > 1:
+        o = jnp.einsum("bgrkd,gk->bgrd",
+                       o.reshape(B, n_kv, H // n_kv, n_kv, Dh), own
+                       ).reshape(B, H, Dh)
+    return o

@@ -77,7 +77,10 @@ faster, in the dense form.
 
 The programs of a configuration with layers of several kinds
 (:func:`mixed_programs`) attend through :func:`_attend_keys` (window
-and full layers: keys that carry their positions, in XLA),
+layers and a full layer's chunks: keys that carry their positions, in
+XLA; a full layer's decode STEP reads each row's own K and V pages
+where they lie in the pools, to the row's length, through the Pallas
+kernel of ``ops/paged_decode.py``, ``hvd_paged_decode``),
 :func:`kda_scan` / :func:`kda_step` and :func:`mamba_scan` /
 :func:`mamba_step` (a recurrent state a batch slot: a delta rule, and
 Mamba-1's selective scan, both in XLA) and latent attention, in two
@@ -114,6 +117,7 @@ from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
+from horovod_tpu.ops.paged_decode import paged_decode
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import (NULL_BLOCK, latent_row, page_tail,
                                         state_kinds)
@@ -1243,11 +1247,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
             o = attend(q, k, v, kc, vc)
         return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
 
-    def pages(cache, c, tables, rows: int):
-        """A ``full`` layer's K or V behind the tables of ``rows``
-        sequences (a page's positions hold ``kv_cache.page_tail``)."""
+    def pages(cache, c, table):
+        """A ``full`` layer's K or V behind the table of a chunk's one
+        sequence (a page's positions hold ``kv_cache.page_tail``)."""
         with jax.named_scope("kv_gather"):
-            return cache[place["full"]][c, tables].reshape(rows, S, Hkv, Dh)
+            return cache[place["full"]][c, table].reshape(1, S, Hkv, Dh)
 
     # -- a chunk of one sequence (B = 1) -----------------------------
 
@@ -1275,7 +1279,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
             if call.local:
                 return _attend_keys(q, k, v, call.pos, call.pos, None)
             return _attend_keys(
-                q, pages(kc, c, call.table, 1), pages(vc, c, call.table, 1),
+                q, pages(kc, c, call.table), pages(vc, c, call.table),
                 jnp.arange(S, dtype=jnp.int32)[None], call.pos, None)
         return softmax_layer(call, lp, kc, vc, x, i, "attn_full", write,
                              attend)
@@ -1580,17 +1584,20 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                              attend)
 
     def full_step(call, lp, kc, vc, c, x, i):
+        """Each row over its own pages, where they lie in the two pools
+        and no further than its position (``ops/paged_decode.py``, the
+        Pallas call ``hvd_paged_decode``): no table is gathered and no
+        score reaches HBM."""
         def write(cache, new):
             return put(cache, "full",
                        (c, call.blk, call.positions % block_size),
                        new.reshape(-1, *page_tail(cfg)))
 
         def attend(q, k, v, kc, vc):
-            rows = call.tables.shape[0]
-            return _attend_keys(
-                q, pages(kc, c, call.tables, rows),
-                pages(vc, c, call.tables, rows),
-                jnp.arange(S, dtype=jnp.int32)[None], call.pos, None)
+            n = place["full"]
+            o = paged_decode(q[:, 0], kc[n], vc[n], c, call.tables,
+                             call.positions + 1)
+            return o.reshape(o.shape[0], 1, H * Dh)
         return softmax_layer(call, lp, kc, vc, x, i, "attn_full", write,
                              attend)
 

@@ -473,6 +473,10 @@ class ServeEngine:
         # the positions it is given (metrics.record_latent_decode).
         self._latent_layers = model_cfg.n_layers_of("mla")
         self._latent_key_block = latent_key_block(bs, self._table_width)
+        # ... and the pages its full layers read, where they are one
+        # kind among several (metrics.record_paged_decode).
+        self._paged_layers = (model_cfg.n_layers_of("full")
+                              if model_cfg.mixed else 0)
 
         # Inject pad-width menu, in BLOCK units: the prefill buckets
         # (prompt-only handoffs keep their existing programs) plus the
@@ -1458,6 +1462,9 @@ class ServeEngine:
         if self._latent_layers:
             m.record_latent_decode(positions + 1, self._latent_key_block,
                                    self._latent_layers)
+        if self._paged_layers:
+            m.record_paged_decode(positions + 1, self.cfg.block_size,
+                                  self._table_width, self._paged_layers)
         if prev is not None:
             m.record_decode_ahead()
         elif out.committed:

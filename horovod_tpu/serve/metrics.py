@@ -397,6 +397,11 @@ class ServeMetrics:
         # batch's longest row would have gathered for every row.
         self.latent_decode_key_blocks_total = 0
         self.latent_decode_key_blocks_longest_total = 0
+        # Pages of the full layers' K pool (V's are as many) that the
+        # decode calls' kernel read, every row to its own length, and
+        # what every row's whole table would have been.
+        self.paged_decode_pages_total = 0
+        self.paged_decode_pages_table_total = 0
         self.queue_depth = 0
         self.max_queue_depth = 0
         self.stalls_total = 0
@@ -726,6 +731,17 @@ class ServeMetrics:
         self.latent_decode_key_blocks_longest_total += (
             layers * len(blocks) * int(blocks.max()))
 
+    def record_paged_decode(self, lengths, block_size: int,
+                            table_width: int, layers: int) -> None:
+        """A decode call was launched whose rows hold ``lengths``
+        positions (an array: every row of the call, a padded row at 1)
+        in pages of ``block_size`` behind tables ``table_width`` wide,
+        in each of ``layers`` full layers."""
+        self.paged_decode_pages_total += layers * int(
+            (-(-lengths // block_size)).sum())
+        self.paged_decode_pages_table_total += (
+            layers * len(lengths) * table_width)
+
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
         nobody's host gap, and the time the device goes unfed is the
@@ -909,6 +925,12 @@ class ServeMetrics:
                 self.latent_decode_key_blocks_total,
             "latent_decode_key_blocks_longest_total":
                 self.latent_decode_key_blocks_longest_total,
+            # pages of the K pool the decode calls' full layers read, a
+            # row to its own length, and what the rows' whole tables
+            # hold (zeros without full layers of several kinds)
+            "paged_decode_pages_total": self.paged_decode_pages_total,
+            "paged_decode_pages_table_total":
+                self.paged_decode_pages_table_total,
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
             # device calls and host gaps many times their kind's

@@ -214,6 +214,23 @@ print("LOWERED " + json.dumps(out))
 """
 
 
+# What a decode program's text says of its full layers' attention (ISSUE
+# 55): the Mosaic calls under `hvd_paged_decode` by scope, and what is
+# left under `attn_full/kv_gather` (every row's whole table, gathered:
+# `bf16[rows * width, 16, ...]` a pool and full layer before). Shared by
+# the three drivers whose configurations have a full kind.
+_PAGED_DECODE_REPORT = r"""
+def paged_decode_report(text):
+    calls = re.findall(
+        r'custom-call\([^\n]*op_name="([^"]*hvd_paged_decode)[^"]*"', text)
+    return {{
+        "paged_decode_calls": len(calls),
+        "paged_decode_paths": sorted(set(calls)),
+        "table_gathers": len(re.findall(
+            r'op_name="[^"]*attn_full/kv_gather', text))}}
+"""
+
+
 # The two-cache serve programs of a configuration with layers of several
 # kinds, small but with caches and experts too large for the compiler to
 # stage whole in fast memory (it does with small ones, and the copies it
@@ -269,6 +286,7 @@ def shape_of(s):
     return "bf16[%s]" % ",".join(map(str, s.shape))
 
 
+PAGED_DECODE_REPORT
 large = {{shape_of(kv[0]): "pool", shape_of(kv[1]): "rings",
          shape_of(params["layers"][0]["moe"]["w_gate"]): "experts"}}
 out = {{"device_kind": topo.devices[0].device_kind,
@@ -287,9 +305,10 @@ for name, fn, args in (
             ops[large[result] + " " + opcode] += 1
     out[name] = {{"ops": ops,
                  "kernels": compiled.as_text().count("tpu_custom_call"),
-                 "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+                 "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+                 **paged_decode_report(compiled.as_text())}}
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
 
 
 # What a decode program's text says of its latent attention (ISSUE 45):
@@ -678,7 +697,7 @@ def readers(text, shape):
     return found
 
 
-
+PAGED_DECODE_REPORT
 for name, fn, args in (
         ("decode", decode,
          (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
@@ -711,9 +730,10 @@ for name, fn, args in (
             r"f32\[[\d,]*512,16,5120\]", text)),
         "scopes": sorted(set(re.findall(
             r"attn_mamba/(mamba_\w+|state_write)", text))),
-        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+        **paged_decode_report(text)}}
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
 
 # The two programs of the sparse and lightning kinds (ISSUE 50) at the
 # widths, the 16 slots and the table of 520 pages of 64 of
@@ -1274,6 +1294,7 @@ fns = dict(zip(("prefill", "prefill_resume", "decode"),
 args = {{"prefill_resume": (i32(1024), i32(), i32(), (i32(WIDTH), i32())),
         "decode": (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))}}
 pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
+PAGED_DECODE_REPORT
 out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
        "pool_bytes": kc[0].size * 2}}
 for name, a in args.items():
@@ -1290,9 +1311,10 @@ for name, a in args.items():
                                   aliased.group(1))),
         "ragged_dots": len(re.findall(r"%ragged-dot-(?!metadata)\S+ = ",
                                       text)),
-        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+        **paged_decode_report(text)}}
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_resume"])
@@ -1302,17 +1324,36 @@ def test_pages_of_narrow_heads_are_never_copied_whole(program):
     the v5e keeps the pool with its blocks innermost and a decode step
     of the cell's programs held 20 copies of the 1 GB pool and 7.1 GB of
     temporaries. Here: K and V pages and the convolution's rows aliased
-    in and out, no copy of anything as large as a pool, a decode
-    step's temporaries what its gathered tables take (1.06 GB) and a
-    chunk's 20 MB, and the whole mixture's three grouped products a
-    sparse layer in the program."""
+    in and out, no copy of anything as large as a pool, a program's
+    temporaries under 0.1 GB (a decode step's gathered tables took 1.06
+    GB before ISSUE 55; a chunk's 20 MB), and the whole mixture's three
+    grouped products a sparse layer in the program."""
     out = _compile_for_v5e(_LFM2_DRIVER)
     got = out[program]
     assert out["pool"] == "bf16[1,20481,16,512]", out
     assert got["aliased"] == 3, got
     assert got["pool_copies"] == 0, got
     assert got["ragged_dots"] == 2 * 3, got
-    # a decode step gathers every row's whole table (ROADMAP A5): 128 x
-    # 2560 positions x 1 KB, K and V, and once more as the products
-    # read them
-    assert got["temp_bytes"] < (1.2e9 if program == "decode" else 0.1e9), got
+    # a decode step gathers no table (ISSUE 55; 1.06 GB of them before)
+    assert got["temp_bytes"] < 0.1e9, got
+
+
+@pytest.mark.parametrize("shapes", ["lfm2", "trinity", "jamba"])
+def test_a_decode_step_s_full_layers_read_the_pools_where_they_lie(shapes):
+    """ISSUE 55: ``jit(decode)`` compiled for the v5e at the three page
+    shapes the cells have (LFM2's rows of 512 behind tables of 160 at
+    128 slots; trinity's ``[16, 8, 128]`` behind 512; jamba's ``[16, 1,
+    128]`` behind 96 at 256 slots) holds the Pallas call
+    ``hvd_paged_decode`` once a full layer, under ``attn_full``, and
+    nothing under ``attn_full/kv_gather`` (every row's whole table,
+    ``bf16[rows * width, 16, ...]`` a pool and layer before); a chunk
+    program holds no such call and keeps its one row's gather."""
+    out = _compile_for_v5e({"lfm2": _LFM2_DRIVER, "trinity": _MIXED_DRIVER,
+                            "jamba": _JAMBA_DRIVER}[shapes])
+    step, chunk = out["decode"], out["prefill_resume"]
+    assert step["paged_decode_calls"] == 1, step
+    assert step["paged_decode_paths"] == [
+        "jit(decode)/attn/attn_full/jit(_decode)/hvd_paged_decode"], step
+    assert step["table_gathers"] == 0, step
+    assert chunk["paged_decode_calls"] == 0, chunk
+    assert chunk["table_gathers"] > 0, chunk

@@ -505,14 +505,16 @@ def test_the_existing_train_steps_did_not_move(devices, case):
 # sha256 of the lowered StableHLO of the serve programs of the tiny window
 # configuration above (over its pool and rings) and of a tiny dense GQA
 # decoder (over one pool), taken on the tree before the state became one
-# array a kind of layer (PR 37's; jax 0.9.0).
+# array a kind of layer (PR 37's; jax 0.9.0). The decode program over
+# two caches was taken again at PR 55: its full layer's step is the
+# Pallas call of ``ops/paged_decode.py`` (the interpreter's form here).
 _SERVE_PROGRAMS = {
     "two_caches/prefill":
         "34a2ab9483578a5c4831799975f027de09230c7bbeb96a294b00d8aa9e7be35c",
     "two_caches/prefill_resume":
         "8dd7e0c23433cdcd04160f713efb59f22c328ca1785170fd7cc3ea38eba3d2b9",
     "two_caches/decode":
-        "1d076e15d9673a0efc63f12cf38a8518ba3fca124ef4eb2465ac3375c9ea7f72",
+        "8f3fcb1bb5af5ed37db48cd21f27032cd829ba87a562b21eb16ad82d697d6ac2",
     "dense/prefill":
         "2c4fa4dde8b1a2f85ab888aee66330331942b29c379d423f7cd4dcb1b5f1ed5c",
     "dense/prefill_resume":

@@ -37,6 +37,19 @@ layer of the XLA form (``tests/reference_mla.py`` over
 kernel alone with the GB/s of the pages it reads; and the largest
 difference of the two forms relative to the largest magnitude.
 
+``--paged-decode`` sweeps a full layer's decode step (ISSUE 55) at the
+three page shapes the cells have: ``lfm2`` (128 rows of 256-2560
+positions behind tables of 160 pages of rows of 512, 32 / 8 heads of
+64), ``trinity`` (32 rows of 1024-8576 behind 536 pages ``[16, 8,
+128]``, 48 / 8 heads) and ``jamba`` (256 rows of 64-1536 behind 96
+pages ``[16, 1, 128]``, 20 heads over one), the lengths log-uniform,
+the tables in shuffled order. ms a layer of the XLA form
+(``serve/decode.py::_attend_keys`` over every row's whole table,
+gathered: all a decode step had before ISSUE 55) and of
+``ops/paged_decode.py``'s kernel at each ``--key-blocks``, with the GB/s
+of the K and V pages it reads; and the largest difference of the two
+relative to the largest magnitude.
+
 ``--sparse`` sweeps a sparse layer's chunk (ISSUE 51) at
 ``minicpm-sala-8l``'s shapes: ``[1, C, 32, 128]`` bf16 queries of a
 chunk that ends at key ``keys`` over pages ``[2, 64, 128]`` behind a
@@ -86,6 +99,7 @@ from horovod_tpu.models import TransformerConfig  # noqa: E402
 from horovod_tpu.models import transformer as tf_lib  # noqa: E402
 from horovod_tpu.ops import flash_attention as flash_lib  # noqa: E402
 from horovod_tpu.ops import latent_decode as latent_lib  # noqa: E402
+from horovod_tpu.ops import paged_decode as paged_lib  # noqa: E402
 from horovod_tpu.ops import sparse_scores as scores_lib  # noqa: E402
 from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from horovod_tpu.parallel.ring_attention import local_attention  # noqa: E402
@@ -95,6 +109,7 @@ from reference_mla import mla_attend_absorbed  # noqa: E402
 
 LAYERS = 16
 LATENT_LAYERS = 6      # the Kimi cell's depth
+PAGED_LAYERS = 8       # calls chained in one program
 
 
 def dense(q, k, v):
@@ -364,6 +379,81 @@ def latent_decode_sweep(args) -> None:
                 print(json.dumps(row), flush=True)
 
 
+#: cell -> rows, H, Hkv, Dh, heads end to end in a row, table width,
+#: shortest and longest row
+PAGED_SHAPES = {"lfm2": (128, 32, 8, 64, True, 160, 256, 2560),
+                "trinity": (32, 48, 8, 128, False, 536, 1024, 8576),
+                "jamba": (256, 20, 1, 128, False, 96, 64, 1536)}
+
+
+def paged_decode_sweep(args) -> None:
+    PAGE = 16
+    rng = np.random.default_rng(0)
+    default_wave = paged_lib._wave_pages
+    for cell in args.cells:
+        rows, H, Hkv, Dh, end_to_end, width, lo, hi = PAGED_SHAPES[cell]
+        tail = (Hkv * Dh,) if end_to_end else (Hkv, Dh)
+        ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+        kp, vp = (jax.jit(lambda k: jax.random.normal(
+            k, (1, rows * width + 1, PAGE) + tail, jnp.bfloat16))(k)
+            for k in ks[:2])
+        q = jax.random.normal(ks[2], (rows, H, Dh), jnp.bfloat16)
+        tables = jnp.asarray(1 + rng.permutation(rows * width).reshape(
+            rows, width), jnp.int32)
+        n = np.exp(rng.uniform(np.log(lo), np.log(hi), rows)).astype(int)
+        positions = jnp.asarray(n - 1, jnp.int32)
+        pages_read = int(np.sum(-(-n // PAGE)))
+        row = {"cell": cell, "rows": rows, "positions": int(n.sum()),
+               "longest": int(n.max()), "pages_read": pages_read,
+               "pages_table": rows * width}
+
+        def chained(form):
+            @jax.jit
+            def chain(q, kp, vp, tables, positions):
+                return lax.scan(
+                    lambda q, _: (form(q, kp, vp, tables, positions), None),
+                    q, None, length=PAGED_LAYERS)[0]
+            return chain, (q, kp, vp, tables, positions)
+
+        def xla(q, kp, vp, tables, positions):
+            S = width * PAGE
+            # the tables made to follow from the queries (by nothing), or
+            # the compiler gathers once for all the chained calls
+            tables = tables + (q[0, 0, 0] * 0).astype(jnp.int32)
+            keys, vals = (pool[0, tables].reshape(rows, S, Hkv, Dh)
+                          for pool in (kp, vp))
+            return decode_lib._attend_keys(
+                q[:, None], keys, vals, jnp.arange(S, dtype=jnp.int32)[None],
+                positions[:, None], None).reshape(q.shape)
+
+        def kernel(q, kp, vp, tables, positions):
+            return paged_lib.paged_decode(q, kp, vp, 0, tables,
+                                          positions + 1)
+
+        chain, xs = chained(xla)
+        row["xla"] = round(median_ms(chain, xs, args.reps) / PAGED_LAYERS, 4)
+        want = jax.jit(xla)(*xs).astype(jnp.float32)
+        for kb in args.key_blocks or [None]:
+            name = "" if kb is None else f"_kb{kb}"
+            paged_lib._wave_pages = (
+                default_wave if kb is None
+                else lambda page, kb=kb: kb // page)
+            chain, xs = chained(kernel)
+            try:
+                ms = median_ms(chain, xs, args.reps) / PAGED_LAYERS
+            except Exception as e:       # a buffer Mosaic refuses
+                row["kernel" + name] = f"refused: {str(e)[:80]}"
+                continue
+            row["kernel" + name] = round(ms, 4)
+            row["gb_s" + name] = round(
+                pages_read * PAGE * Hkv * Dh * 2 * 2 / ms / 1e6, 1)
+            got = jax.jit(kernel)(*xs).astype(jnp.float32)
+            row["rel_err" + name] = round(float(
+                jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))), 5)
+        paged_lib._wave_pages = default_wave
+        print(json.dumps(row), flush=True)
+
+
 def sparse_form(layout: str, bq: int, bk: int):
     """``sparse_attend_pages`` with the query layout and the tiles to
     choose: ``heads`` is the programs' own."""
@@ -615,6 +705,10 @@ def main() -> None:
                     help="the mla layers' chunk instead of the prompt")
     ap.add_argument("--latent-decode", action="store_true",
                     help="the mla layers' decode step instead")
+    ap.add_argument("--paged-decode", action="store_true",
+                    help="a full layer's decode step instead")
+    ap.add_argument("--cells", nargs="+", default=sorted(PAGED_SHAPES),
+                    choices=sorted(PAGED_SHAPES))
     ap.add_argument("--rows", type=int, nargs="+", default=[32, 64])
     ap.add_argument("--lengths", nargs="+",
                     default=["even", "spread", "tail"],
@@ -635,6 +729,8 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     args = ap.parse_args()
     print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.paged_decode:
+        return paged_decode_sweep(args)
     if args.sparse or args.select:
         args.chunks = args.chunks or [1024, 512, 256]
         args.keys = args.keys or [8192, 16384, 32768]
