@@ -88,7 +88,7 @@ class Rotary:
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
 LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv")
+               "lightning", "conv", "eva")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,8 +165,8 @@ class TransformerConfig:
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
     # One of the LAYER_KINDS a layer ("sliding" | "full" here; "kda",
-    # "mla", "mamba", "sparse", "lightning" and "conv" below). A sliding
-    # layer sees the keys
+    # "mla", "mamba", "sparse", "lightning", "conv" and "eva" below). A
+    # sliding layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
     # layer_rotary says otherwise. None: every layer is causal over
@@ -290,6 +290,33 @@ class TransformerConfig:
     # beside it keep their own n_kv_heads, qk_norm_per_head and
     # layer_rotary.
     conv_taps: int = 3
+    # A ninth kind of layer_types (ISSUE 56; served, not trained):
+    # "eva", EvaByte's attention (EVA, Zheng et al. 2023, in the form of
+    # its modelling code): a full layer's q, k and v (rotated in HALVES
+    # at rope_theta), and two learned vectors a KV head, eva_mu and
+    # eva_phi [Hkv, Dh]. Positions fall into chunks of eva_chunk and ALIGNED
+    # windows of eva_window (whole chunks); a chunk's summary, once its
+    # positions exist, is (sum_j softmax_j(s mu.k_j) k_j, sum_j
+    # softmax_j(s phi.k_j) v_j), s = Dh^-1/2. The query at t attends, in
+    # ONE float32 softmax, the keys of its own window up to itself
+    # exactly and one summary a chunk of every window that has closed.
+    # It is the one kind with both halves between calls: the open
+    # window's K and V rows by batch slot, and the summaries in pages
+    # behind the block tables (kv_cache.KVCache).
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    # EvaByte's three further switches, each read in one place. The
+    # norms multiply by 1 + g, g the stored gain (norm_unit_offset: the
+    # stored g in bf16 plus one is not a stored 1 + g in bf16, so it is
+    # never folded into the weights). The stream between the layers,
+    # both residual additions and the head's logits are float32
+    # (stream_fp32: the norms read float32 and give `dtype`). The head
+    # predicts head_rows positions a position: lm_head is [d_model,
+    # head_rows * vocab_size], logits [.., head_rows, vocab_size], row j
+    # the token j + 1 ahead; the serve programs emit from row 0.
+    norm_unit_offset: bool = False
+    stream_fp32: bool = False
+    head_rows: int = 1
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -299,7 +326,9 @@ class TransformerConfig:
                     or set(self.layer_types) - set(LAYER_KINDS)):
                 raise ValueError(
                     f"layer_types needs n_layers={self.n_layers} entries "
-                    f"of {' | '.join(map(repr, LAYER_KINDS))}, got "
+                    f"of the {len(LAYER_KINDS)} kinds "
+                    f"{' | '.join(map(repr, LAYER_KINDS))} (eva alone keeps "
+                    "both rows by slot and pages behind the tables), got "
                     f"{self.layer_types}")
             if "sliding" in self.layer_types and not self.attn_window:
                 raise ValueError("sliding layers need attn_window")
@@ -309,6 +338,15 @@ class TransformerConfig:
                                  "mla_rope_dim")
             if "mamba" in self.layer_types and not self.mamba_dt_rank:
                 raise ValueError("mamba layers need mamba_dt_rank")
+            if "eva" in self.layer_types and (
+                    self.eva_chunk < 1 or self.eva_window % self.eva_chunk
+                    or self.attn_gate or self.qk_norm):
+                raise ValueError(
+                    "eva layers need eva_window in whole eva_chunk "
+                    f"(got {self.eva_window} and {self.eva_chunk}) and no "
+                    "attn_gate, nor a qk_norm over the whole vector (the "
+                    "one kind that keeps both rows by slot and summary "
+                    "pages behind the tables)")
             # kda and mla layers project for n_heads heads with norms
             # and gates of their own; mamba and conv layers have no q or
             # k, and the full layers beside them keep the configuration's
@@ -348,6 +386,17 @@ class TransformerConfig:
                     "attn_gate, sandwich_norm or qk_norm over the whole "
                     "vector (qk_norm_per_head and n_kv_heads are the full "
                     "layers' beside them)")
+        if ((self.norm_unit_offset or self.stream_fp32
+             or self.head_rows != 1) and not self.stateful):
+            raise ValueError(
+                "norm_unit_offset, stream_fp32 and head_rows are read by "
+                "the serve programs of a configuration whose layers keep a "
+                "state by kind (decode.py's mixed_programs: stream_norm, "
+                "embed, emit); the trainer's forward and the programs of "
+                "one stack of one block do not read them")
+        if self.head_rows != 1 and self.tie_embeddings:
+            raise ValueError("head_rows rows a position need a head of "
+                             "their own: no tie_embeddings")
         if self.tie_embeddings and self.layer_types is None:
             raise ValueError(
                 "tie_embeddings is read where a configuration has "
@@ -411,9 +460,9 @@ class TransformerConfig:
         """Some layer keeps a state that is not cached keys and values
         alone (a kda, mamba or lightning layer's recurrent state, an mla
         layer's latent, a sparse layer's compressed keys, a conv layer's
-        rows)."""
+        rows, an eva layer's window rows and summary pages)."""
         return bool(self.layer_types) and bool(
-            {"kda", "mla", "mamba", "sparse", "lightning", "conv"}
+            {"kda", "mla", "mamba", "sparse", "lightning", "conv", "eva"}
             & set(self.layer_types))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
@@ -423,9 +472,9 @@ class TransformerConfig:
         ``layer_types``, which then take no rotary embedding. (An mla
         layer rotates its ``mla_rope_dim`` values so; a kda, mamba or
         conv layer reads no position; a sparse layer is a full layer
-        here; a lightning layer rotates, in halves, plainly.)"""
+        here; a lightning or eva layer rotates, in halves, plainly.)"""
         kind = self.kind_of(layer)
-        if kind == "lightning":
+        if kind in ("lightning", "eva"):
             return Rotary(self.rope_theta)
         if kind in ("kda", "mamba", "sparse", "conv"):
             kind = "full"
@@ -496,6 +545,9 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
     }
     if kind == "lightning":
         layers["o_norm"] = P(None, "tp")   # [L, H*Dh], as wo's rows
+    if kind == "eva":
+        layers.update(eva_mu=P(None, "tp", None),   # [L, Hkv, Dh]
+                      eva_phi=P(None, "tp", None))
     if kind == "kda":
         layers.update(conv_q=P(None, None, "tp"), conv_k=P(None, None, "tp"),
                       conv_v=P(None, None, "tp"), wa=mat, a_log=vec,
@@ -563,7 +615,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "embed": P("tp", "fsdp"),
         "layers": _block_specs(cfg, cfg.moe is not None),
         "final_norm": P(None),
-        "lm_head": P("fsdp", "tp"),        # [D, V]
+        "lm_head": P("fsdp", "tp"),        # [D, head_rows * V]
     }
     if cfg.tie_embeddings:
         del specs["lm_head"]
@@ -695,6 +747,16 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
         }
         if kind == "lightning":
             layers["o_norm"] = jnp.ones((L, H * Dh), dt)
+        if kind == "eva":
+            # the pooling vectors, of the keys' own size (entries
+            # N(0, 1)), so that a chunk's pooling weights are a softmax
+            # of unit-variance logits and not a mean
+            layers["eva_mu"] = dense(next(k), (L, Hkv, Dh), 1)
+            layers["eva_phi"] = dense(next(k), (L, Hkv, Dh), 1)
+    if cfg.norm_unit_offset:
+        # the stored gain is g of 1 + g
+        for name in ("attn_norm", "mlp_norm"):
+            layers[name] = jnp.zeros_like(layers[name])
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
         layers["k_norm"] = jnp.ones((L, Hkv * Dh), dt)
@@ -743,8 +805,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
             "layers": [one(i) for i in range(cfg.n_dense_layers,
                                              cfg.n_layers)],
             "embed": dense(next(k), (V, D), D),
-            "final_norm": jnp.ones((D,), cfg.dtype),
-            "lm_head": dense(next(k), (D, V), D),
+            "final_norm": (jnp.zeros if cfg.norm_unit_offset
+                           else jnp.ones)((D,), cfg.dtype),
+            "lm_head": dense(next(k), (D, cfg.head_rows * V), D),
         }
         if cfg.tie_embeddings:
             del params["lm_head"]
@@ -787,10 +850,27 @@ def _on_mesh(cfg: TransformerConfig, params, mesh: Optional[Mesh]):
 # Forward
 # ---------------------------------------------------------------------------
 
-def _rmsnorm(x, w, eps):
+def _rmsnorm(x, w, eps, unit_offset: bool = False, dtype=None):
+    """``x / rms(x) * w`` in float32, in ``x``'s dtype (``dtype``:
+    another); ``unit_offset``: times ``1 + w``, the sum in float32."""
     h = x.astype(jnp.float32)
     h = h * lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
-    return (h * w.astype(jnp.float32)).astype(x.dtype)
+    w = w.astype(jnp.float32)
+    if unit_offset:
+        w = 1.0 + w
+    return (h * w).astype(dtype or x.dtype)
+
+
+def stream_norm(cfg: "TransformerConfig", x, w):
+    """The norm of the stream ``x`` before a branch or the head, as the
+    configuration has it: the ONE place that reads ``norm_unit_offset``
+    and ``stream_fp32`` (a float32 stream's norm gives ``cfg.dtype``).
+    With neither it is ``_rmsnorm(x, w, cfg.norm_eps)``, operation for
+    operation."""
+    if not (cfg.norm_unit_offset or cfg.stream_fp32):
+        return _rmsnorm(x, w, cfg.norm_eps)
+    return _rmsnorm(x, w, cfg.norm_eps, cfg.norm_unit_offset,
+                    cfg.dtype if cfg.stream_fp32 else None)
 
 
 def _rope(x, pos, rotary: Rotary):
@@ -975,25 +1055,39 @@ def attention_inputs(cfg: TransformerConfig, lp, x, pos, layer: int = 0):
     [B, T, Hkv, Dh] (no GQA repeat: what the server's cache stores)."""
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     B, T = x.shape[0], x.shape[1]
-    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    eva = cfg.kind_of(layer) == "eva"
+    h = stream_norm(cfg, x, lp["attn_norm"])
 
     def project(w, norm, heads):
-        y = h @ lp[w]
-        if cfg.qk_norm:
-            with jax.named_scope("qk_norm"):
-                y = _rmsnorm(y, lp[norm], cfg.norm_eps)
-        y = y.reshape(B, T, heads, Dh)
-        if cfg.qk_norm_per_head:
+        if eva:
+            # one product over the heads' own dimensions: as `h @ w`
+            # reshaped afterwards the v5e's compiler TRANSPOSED wq, wk
+            # and wv (32 MB each at EvaByte's widths) on every call, a
+            # chunk's and a decode step's alike (compiled for the v5e,
+            # PR 56: 3 copies a layer, none in this form)
+            y = jnp.einsum("btd,dhk->bthk", h, lp[w].reshape(-1, heads, Dh))
+        else:
+            y = h @ lp[w]
+            if cfg.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    y = _rmsnorm(y, lp[norm], cfg.norm_eps)
+            y = y.reshape(B, T, heads, Dh)
+        if cfg.qk_norm_per_head and norm:
             with jax.named_scope("qk_norm"):
                 y = _rmsnorm(y, lp[norm], cfg.norm_eps)
         return y
 
     q = project("wq", "q_norm", H)
     k = project("wk", "k_norm", Hkv)
-    v = (h @ lp["wv"]).reshape(B, T, Hkv, Dh)
+    v = (project("wv", None, Hkv) if eva
+         else (h @ lp["wv"]).reshape(B, T, Hkv, Dh))
     rotary = cfg.rotary_of(layer)
     if rotary is None:
         return q, k, v
+    if eva:
+        # in halves (float32 there), back in the projections' dtype
+        return (_rope_halves(q, pos, rotary).astype(q.dtype),
+                _rope_halves(k, pos, rotary).astype(k.dtype), v)
     return _rope(q, pos, rotary), _rope(k, pos, rotary), v
 
 
@@ -1090,8 +1184,9 @@ def kda_residual(cfg: TransformerConfig, lp, x, h, o):
 
 
 def head_weights(cfg: TransformerConfig, params):
-    """``[D, V]``: the head, or with ``tie_embeddings`` the embedding's
-    transpose."""
+    """``[D, V]``: the head (``[D, head_rows * V]``, a row of
+    predictions after another, where the configuration has several), or
+    with ``tie_embeddings`` the embedding's transpose."""
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
 
@@ -1309,7 +1404,7 @@ def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
     ``moe_fn=None`` is the meshless :func:`moe_lib.make_moe_ffn`: the
     plain GSPMD :func:`moe_lib.moe_ffn`, or the dropless dispatch on
     the caller's own rows for a configuration without a capacity."""
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    h = stream_norm(cfg, x, lp["mlp_norm"])
     if "moe" in lp:
         if moe_fn is None:
             moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
@@ -1402,17 +1497,17 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
     """The trainer's entry points refuse kda, mla, mamba, sparse,
-    lightning and conv layers by name: their forward exists in the serve
-    programs alone."""
+    lightning, conv and eva layers by name: their forward exists in the
+    serve programs alone."""
     if cfg.stateful:
         raise NotImplementedError(
             f"{what} does not run kda, mla or mamba layers, nor sparse or "
-            "lightning layers, nor conv layers: "
+            "lightning layers, nor conv layers, nor eva layers: "
             "models/transformer.py's decoder_layer has no "
             "backward through the chunked delta-rule scan, the selective "
             "scan or the decayed linear scan of serve/decode.py, no latent "
-            "attention and no selection of key blocks (ROADMAP B14, B8, "
-            "B18). "
+            "attention, no selection of key blocks and no attention over "
+            "a window beside chunk summaries (ROADMAP B14, B8, B18). "
             "The configuration is served through ServeEngine.")
 
 

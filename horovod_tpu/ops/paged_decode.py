@@ -72,8 +72,8 @@ def key_block(block_size: int, table_width: int) -> int:
 
 
 def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
-            o_ref, buf, sem, acc, m_scr, l_scr, *, scale: float, width: int,
-            page: int, group: int):
+            o_ref, *rest, scale: float, width: int, page: int, group: int,
+            stats: bool = False):
     """Row ``b`` of the batch (one grid step): its key blocks in a
     loop, block ``j`` waited for in one half of ``buf`` (K's pages at
     ``[half, 0]``, V's at ``[half, 1]``) while the pages of the next
@@ -90,7 +90,14 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
     block-diagonal over it), or one a position and KV head (``group``
     ``Hkv``: column ``c`` of the scores is position ``c // group`` under
     KV head ``c % group``, and a query head sees its own KV head's
-    columns alone)."""
+    columns alone).
+
+    ``stats``: one more output before the scratch, the softmax's
+    logsumexp a head (along the lanes of a ``[H, 128]`` tile), for a
+    caller that merges this call's keys with others' in one softmax
+    (:func:`paged_decode_stats`)."""
+    lse_ref, (buf, sem, acc, m_scr, l_scr) = (
+        (rest[0], rest[1:]) if stats else (None, rest))
     b, rows = pl.program_id(0), pl.num_programs(0)
     pages = buf.shape[2]
     kb = pages * page
@@ -206,6 +213,9 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
 
     lax.fori_loop(0, n_blocks, block, 0)
     o_ref[...] = (acc[...] / l_scr[...]).astype(o_ref.dtype)
+    if stats:
+        lse_ref[...] = jnp.broadcast_to(m_scr[...] + jnp.log(l_scr[...]),
+                                        lse_ref.shape)
 
 
 def paged_decode(q, k_pool, v_pool, layer, tables, lengths, *,
@@ -259,12 +269,45 @@ def paged_decode(q, k_pool, v_pool, layer, tables, lengths, *,
                    interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("pages", "interpret"))
+def paged_decode_stats(q, k_pool, v_pool, layer, tables, lengths, *,
+                       key_positions: int,
+                       interpret: Optional[bool] = None):
+    """:func:`paged_decode` (the same kernel) for a caller whose rows
+    attend OTHER keys besides, in one softmax: returns ``(out [B, H,
+    Dh], lse [B, H])``, both float32, the call's own softmax and its
+    logsumexp, which two calls merge exactly (``exp(lse_a - lse)`` and
+    ``exp(lse_b - lse)`` weigh the two outs; an eva layer's step: the
+    open window's rows and the summaries' pages,
+    ``serve/decode.py::mixed_programs``). A row whose length is under 1
+    still reads one position, as there: the caller gives that row's
+    ``lse`` no weight. ``key_positions``: the key block, in whole pages
+    (the caller's: with as many KV heads as query heads a position is
+    ``H`` rows of the buffer, and 1024 of them would be 33 MB of VMEM
+    and 4 MB a float32 tile of scores)."""
+    B, H, Dh = q.shape
+    page, tail = k_pool.shape[2], k_pool.shape[3:]
+    if (v_pool.shape != k_pool.shape or len(tail) != 2 or tail[-1] != Dh
+            or H % tail[0] or tables.shape[0] != B or lengths.shape != (B,)
+            or key_positions % page):
+        raise ValueError(
+            f"paged_decode_stats: q {q.shape}, pools {k_pool.shape} and "
+            f"{v_pool.shape}, tables {tables.shape}, lengths "
+            f"{lengths.shape}, key blocks of {key_positions}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _decode(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables,
+                   lengths,
+                   pages=min(key_positions // page, tables.shape[1]),
+                   interpret=interpret, stats=True)
+
+
+@functools.partial(jax.jit, static_argnames=("pages", "interpret", "stats"))
 def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
-            interpret: bool):
+            interpret: bool, stats: bool = False):
     """The Pallas call, jitted of itself: a program of several full
     layers traces and lowers the kernel once, not once a layer
-    (``ops/mamba_scan.py::_scan``)."""
+    (``ops/mamba_scan.py::_scan``). ``stats``: float32 out and the
+    logsumexp beside it (:func:`paged_decode_stats`)."""
     B, H, Dh = q.shape
     n_layers, n_pages, page = k_pool.shape[:3]
     tail, width = k_pool.shape[3:], tables.shape[1]
@@ -286,9 +329,10 @@ def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
     first = jnp.cumsum(n_blocks) - n_blocks
     wave = 2 * pages * page * group * row * k_pool.dtype.itemsize
     scores = H * pages * page * group * 4
+    row_spec = pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0))
     o = pl.pallas_call(
         functools.partial(_kernel, scale=Dh ** -0.5, width=width, page=page,
-                          group=group),
+                          group=group, stats=stats),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B,),
@@ -297,7 +341,9 @@ def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0)),
+            out_specs=([row_spec, pl.BlockSpec((None, H, 128),
+                                               lambda b, *_: (b, 0, 0))]
+                       if stats else row_spec),
             scratch_shapes=[
                 pltpu.VMEM((2, 2, pages, page * group, row), k_pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -305,7 +351,9 @@ def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, H, row), q.dtype),
+        out_shape=([jax.ShapeDtypeStruct((B, H, row), jnp.float32),
+                    jax.ShapeDtypeStruct((B, H, 128), jnp.float32)]
+                   if stats else jax.ShapeDtypeStruct((B, H, row), q.dtype)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # both halves of the buffer and the float32 tiles of a key
@@ -315,6 +363,8 @@ def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
         name="hvd_paged_decode",
     )(layer.reshape(1), lengths, first.astype(jnp.int32),
       tables.astype(jnp.int32).reshape(-1), q, k_pool, v_pool)
+    if stats:
+        return o[0], o[1][:, :, 0]
     if n_kv > 1:
         o = jnp.einsum("bgrkd,gk->bgrd",
                        o.reshape(B, n_kv, H // n_kv, n_kv, Dh), own

@@ -95,12 +95,20 @@ over its own pages where they lie in the pool, through a Pallas kernel
 of its own (``ops/latent_decode.py``, ``hvd_latent_decode``): no key
 block is gathered and no score reaches HBM. The absorbed form in XLA
 (``tests/reference_mla.py``) is what the tests hold both to. Nothing
-chooses between the two but which program calls.
+chooses between the two but which program calls. An ``eva`` layer
+(an exact aligned window beside one attended summary a chunk of every
+window that has closed, :func:`eva_summaries`) attends both in ONE
+softmax: a chunk through two calls of ``flash_attention_keys``, the
+second carrying the first's ``(out, lse)``; a decode step through
+``ops/paged_decode.py``'s kernel twice (``paged_decode_stats``: the
+slot's rows to the row's count, the summaries' pages), merged by their
+logsumexp.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import types
 from typing import Any, Optional
 
@@ -117,7 +125,7 @@ from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops.latent_decode import latent_decode
-from horovod_tpu.ops.paged_decode import paged_decode
+from horovod_tpu.ops.paged_decode import paged_decode, paged_decode_stats
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import (NULL_BLOCK, latent_row, page_tail,
                                         state_kinds)
@@ -873,6 +881,45 @@ def kernel_means(rows, stride: int, strides_a_kernel: int):
             / strides_a_kernel).astype(rows.dtype)
 
 
+def eva_summaries(k, v, mu, phi):
+    """The summaries of whole chunks of an eva layer: ``k`` and ``v``
+    [N, chunk, H, Dh] (the rotated keys as the cache holds them, and
+    their values), ``mu`` and ``phi`` [H, Dh] the layer's pooling
+    vectors. ``k~ = sum_j softmax_j(s mu.k_j) k_j`` and ``v~ = sum_j
+    softmax_j(s phi.k_j) v_j`` over a chunk's positions, ``s = Dh **
+    -0.5``; float32 throughout, each [N, H, Dh] in its input's dtype.
+    (Beside :func:`kernel_means`: a sparse layer's compressed keys are
+    plain means that only choose pages; these are attended, key and
+    value.)"""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    s = k.shape[-1] ** -0.5
+
+    def pooled(by, rows):
+        w = jax.nn.softmax(
+            s * jnp.einsum("hd,nchd->nch", by.astype(jnp.float32), k32,
+                           precision=_EXACT), axis=1)
+        return jnp.einsum("nch,nchd->nhd", w, rows, precision=_EXACT)
+
+    return pooled(mu, k32).astype(k.dtype), pooled(phi, v32).astype(v.dtype)
+
+
+#: Key positions (rows of the open window, or summaries) a key block of
+#: an eva layer's decode step holds: with as many KV heads as query
+#: heads a position is H rows of ``ops/paged_decode.py``'s buffer, so a
+#: block of 256 is 4.2 MB of K and V a half at 32 heads of 128 in bf16,
+#: and its float32 scores 1 MB.
+_EVA_KEY_BLOCK = 256
+#: ... and the "page" the window's rows are read as (the same bytes):
+#: positions a copy brings, 128 KB of each of K and V at those sizes.
+_EVA_ROW_PAGE = 16
+
+
+def _eva_key_block(page: int) -> int:
+    """:data:`_EVA_KEY_BLOCK` in whole pages of ``page`` (one at
+    least)."""
+    return max(page, _EVA_KEY_BLOCK // page * page)
+
+
 def sparse_block_scores(q, ck, exist, per: int, strides_a_kernel: int):
     """What a sparse layer's queries make of each block of keys: ``q``
     [B, C, H, Dh] over the compressed keys ``ck`` [B, J, Hkv, Dh] (J =
@@ -1146,11 +1193,13 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     is :func:`moe_share_report`'s). The caches ``kc`` and ``vc`` are
     tuples with one array a kind of layer, in
     ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
-    is what): pages behind the block tables for ``full`` and ``mla``
-    layers, and rings, recurrent states and convolution rows for
-    ``sliding``, ``kda``, ``mamba`` and ``conv`` layers, one a batch
-    slot (slot 0 is the null slot, as
-    block 0 is the null block). An address is a pair too:
+    is what) for the nine kinds of layer: pages behind the block tables
+    for ``full``, ``mla`` and ``sparse`` layers, and rings, recurrent
+    states and convolution rows for ``sliding``, ``kda``, ``mamba``,
+    ``lightning`` and ``conv`` layers, one a batch slot (slot 0 is the
+    null slot, as block 0 is the null block); an ``eva`` layer alone has
+    BOTH halves, its open window's K and V rows by slot and its chunk
+    summaries in pages behind the tables. An address is a pair too:
     ``(block_table, slot)``.
 
     The layers are a Python loop: each knows its kind, its stack and
@@ -1160,7 +1209,9 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     that kind's state around its attention. A program gives every
     layer the same ``call``: its positions and addresses. ``head`` maps
     float32 logits to what a program returns (None: their argmax, the
-    next token; the tests read the logits themselves). ``chosen``: the
+    next token, of row 0 where the head has ``head_rows`` rows a
+    position, logits ``[.., head_rows, vocab]``; the tests read the
+    logits themselves). ``chosen``: the
     programs return a fourth value, the pages the queries of every
     sparse layer chose ([n_sparse, C or B, Hkv, table_width] bool, all
     False for a query below ``sparse_dense_len``): the same programs
@@ -1169,6 +1220,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     window = cfg.attn_window
     if head is None:
         def head(logits):
+            if cfg.head_rows > 1:
+                logits = logits[..., 0, :]      # row 0: the next token
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     place = {kind: n for n, kind in enumerate(state_kinds(cfg))}
     # layer -> (its list, its index there, its kind, its index in its cache)
@@ -1182,6 +1235,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     n_win = seen.get("sliding", 0)
     S = table_width * block_size
     latent = latent_row(cfg) if "mla" in place else 0
+    # summaries a page of an eva layer holds
+    per_eva = block_size // cfg.eva_chunk if "eva" in place else 0
     # an mla chunk's key blocks: whole pages, the table padded to them
     chunk_key_block = min(_MLA_CHUNK_BLOCKS * _MLA_KEY_BLOCK,
                           S) // block_size * block_size
@@ -1194,7 +1249,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
             if cfg.embed_multiplier is not None:
                 x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
-            return x
+            # a float32 stream starts here (the norms give cfg.dtype back)
+            return x.astype(jnp.float32) if cfg.stream_fp32 else x
 
     def layers(params, kc, vc, x, call, moe_fn=None):
         """Every layer over ``x`` [B, T, D]: ``kinds[kind][call.step](
@@ -1226,9 +1282,19 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
 
     def emit(params, x, rows):
         with jax.named_scope("head"):
-            x = rows(tf_lib._rmsnorm(x, params["final_norm"], cfg.norm_eps))
+            x = rows(tf_lib.stream_norm(cfg, x, params["final_norm"]))
             if cfg.logit_divisor is not None:
                 x = x / jnp.asarray(cfg.logit_divisor, x.dtype)
+            if cfg.stream_fp32 or cfg.head_rows > 1:
+                # the product kept in float32, and read as head_rows
+                # rows of the vocabulary a position
+                logits = jnp.einsum(
+                    "...d,dv->...v", x, tf_lib.head_weights(cfg, params),
+                    preferred_element_type=jnp.float32)
+                if cfg.head_rows > 1:
+                    logits = logits.reshape(*logits.shape[:-1],
+                                            cfg.head_rows, cfg.vocab_size)
+                return head(logits)
             if cfg.tie_embeddings:
                 # x E^T, the table read where it lies and not turned
                 return head(jnp.einsum("...d,vd->...v", x, params["embed"]
@@ -1549,6 +1615,82 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 x = tf_lib.conv_residual(cfg, lp, x, g)
         return kc, vc, x
 
+    def eva_chunk(call, lp, kc, vc, c, x, i):
+        """A chunk that lies in ONE window (the engine cuts a prompt's
+        chunks at the windows' ends; a whole prompt no longer than a
+        window attends over itself): its queries attend, in one softmax
+        carried from the first call into the second
+        (``flash_attention_keys``' ``carry``), one summary a chunk of
+        every CLOSED window out of the pages, then the open window's
+        rows from before the chunk and the chunk's own keys up to each
+        query. Then the chunk's real rows go into the slot's rows at
+        ``offset % eva_window`` (a padded position writes none: rows
+        past ``length`` are not live) and the summaries of its whole
+        groups of ``eva_chunk`` into its pages (zeros where a group is
+        not whole at ``length``: a decode step closes it later, from
+        its rows)."""
+        n = place["eva"]
+        Tc = x.shape[1]
+        W, ch = cfg.eva_window, cfg.eva_chunk
+        assert Tc <= W and Tc % ch == 0, (
+            f"an eva layer's chunk of {Tc} lies in one window of {W}, in "
+            f"whole chunks of {ch}")
+        q, k, v = tf_lib.attention_inputs(cfg, lp, x, call.pos, i)
+        with jax.named_scope("attn_eva"):
+            qh = q[0].swapaxes(0, 1)                          # [H, Tc, Dh]
+            own = call.pos[0]
+            keys, vals, key_pos = k[0], v[0], own
+            carry = None
+            if not call.local:
+                (kr, ks), (vr, vs) = kc[n], vc[n]
+                start = call.offset % W
+                with jax.named_scope("eva_summaries"):
+                    seen = (call.offset // W) * (W // ch)
+                    at = jnp.where(jnp.arange(table_width * per_eva,
+                                              dtype=jnp.int32) < seen, 0, -1)
+                    carry = flash_attention_keys(
+                        qh, *(pool[c, call.table].reshape(-1, Hkv, Dh)
+                              .swapaxes(0, 1) for pool in (ks, vs)),
+                        call.pos, at[None], scale=Dh ** -0.5)
+                if Tc < W:
+                    # the window's rows before the chunk: start <= W - Tc
+                    r = jnp.arange(W - Tc, dtype=jnp.int32)
+                    keys = jnp.concatenate(
+                        [kr[c, call.slot, :W - Tc].astype(k.dtype), keys])
+                    vals = jnp.concatenate(
+                        [vr[c, call.slot, :W - Tc].astype(v.dtype), vals])
+                    key_pos = jnp.concatenate(
+                        [jnp.where(r < start, call.offset - start + r, -1),
+                         own])
+            with jax.named_scope("eva_window"):
+                o, _ = flash_attention_keys(
+                    qh, keys.swapaxes(0, 1), vals.swapaxes(0, 1), call.pos,
+                    key_pos[None], scale=Dh ** -0.5, carry=carry)
+            o = o.swapaxes(0, 1).reshape(1, Tc, H * Dh).astype(q.dtype)
+            if kc is not None:
+                (kr, ks), (vr, vs) = kc[n], vc[n]
+                with jax.named_scope("kv_write"):
+                    j = jnp.arange(Tc, dtype=jnp.int32)
+                    rows = jnp.where(j < call.length, call.offset % W + j, W)
+                    kr = kr.at[c, call.slot, rows].set(
+                        k[0].astype(kr.dtype), mode="drop")
+                    vr = vr.at[c, call.slot, rows].set(
+                        v[0].astype(vr.dtype), mode="drop")
+                with jax.named_scope("eva_summarise"):
+                    sk, sv = eva_summaries(
+                        k[0].astype(kr.dtype).reshape(-1, ch, Hkv, Dh),
+                        v[0].astype(vr.dtype).reshape(-1, ch, Hkv, Dh),
+                        lp["eva_mu"], lp["eva_phi"])
+                    whole = (ch * (1 + jnp.arange(Tc // ch, dtype=jnp.int32))
+                             <= call.length)[:, None, None]
+                    ks = ks.at[c, call.blks].set(jnp.where(
+                        whole, sk, 0).reshape(-1, per_eva, Hkv, Dh))
+                    vs = vs.at[c, call.blks].set(jnp.where(
+                        whole, sv, 0).reshape(-1, per_eva, Hkv, Dh))
+                kc = swap(kc, "eva", (kr, ks))
+                vc = swap(vc, "eva", (vr, vs))
+        return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
+
     # -- a decode step of the batch (one position a row) -------------
 
     def by_slot(call, rows, n_slots):
@@ -1816,6 +1958,61 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 x = tf_lib.conv_residual(cfg, lp, x, g)
         return kc, vc, x
 
+    def eva_step(call, lp, kc, vc, c, x, i):
+        """A row's new key and value into its slot's rows at ``t %
+        eva_window``; where ``t`` ends a chunk, that chunk's summary,
+        from its ``eva_chunk`` rows alone, into its page; then each row
+        over its own window's rows TO ITS OWN COUNT and over the
+        summaries of its own closed windows where they lie, both
+        through ``ops/paged_decode.py``'s kernel (the rows of a slot
+        read as pages of ``_EVA_ROW_PAGE`` positions, the same bytes)
+        and merged by their logsumexp: one softmax over both. No closed
+        window's rows are read again and no table is gathered."""
+        n = place["eva"]
+        W, ch = cfg.eva_window, cfg.eva_chunk
+        t = call.positions
+        q, k, v = tf_lib.attention_inputs(cfg, lp, x, call.pos, i)
+        with jax.named_scope("attn_eva"):
+            (kr, ks), (vr, vs) = kc[n], vc[n]
+            r = t % W
+            with jax.named_scope("kv_write"):
+                kr = kr.at[c, call.slots, r].set(k[:, 0].astype(kr.dtype))
+                vr = vr.at[c, call.slots, r].set(v[:, 0].astype(vr.dtype))
+            with jax.named_scope("eva_summarise"):
+                at = (jnp.maximum(r - (ch - 1), 0)[:, None]
+                      + jnp.arange(ch, dtype=jnp.int32))            # [B, ch]
+                sk, sv = eva_summaries(kr[c, call.slots[:, None], at],
+                                       vr[c, call.slots[:, None], at],
+                                       lp["eva_mu"], lp["eva_phi"])
+                blk = jnp.where(t % ch == ch - 1, call.blk, NULL_BLOCK)
+                ks = ks.at[c, blk, t % block_size // ch].set(sk)
+                vs = vs.at[c, blk, t % block_size // ch].set(sv)
+            with jax.named_scope("eva_window"):
+                page = math.gcd(W, _EVA_ROW_PAGE)
+                o, lse = paged_decode_stats(
+                    q[:, 0], *(rows.reshape(rows.shape[0], -1, page, Hkv, Dh)
+                               for rows in (kr, vr)), c,
+                    call.slots[:, None] * (W // page)
+                    + jnp.arange(W // page, dtype=jnp.int32), r + 1,
+                    key_positions=_eva_key_block(page))
+            with jax.named_scope("eva_summaries"):
+                seen = (t // W) * (W // ch)
+                o_s, lse_s = paged_decode_stats(
+                    q[:, 0], ks, vs, c, call.tables, seen,
+                    key_positions=_eva_key_block(per_eva))
+                # a row with no closed window read one summary all the
+                # same (the kernel's least): it weighs nothing
+                some = (seen > 0)[:, None]
+                lse_s = jnp.where(some, lse_s, _NEG_BIG)
+                top = jnp.maximum(lse, lse_s)
+                a, b = jnp.exp(lse - top), jnp.exp(lse_s - top)
+                o = (o * a[..., None] + jnp.where(some[..., None], o_s, 0.0)
+                     * b[..., None]) / (a + b)[..., None]
+            o = o.astype(q.dtype).reshape(o.shape[0], 1, H * Dh)
+            kc = swap(kc, "eva", (kr, ks))
+            vc = swap(vc, "eva", (vr, vs))
+        return kc, vc, tf_lib.attention_residual(cfg, lp, x, o)
+
     #: kind of layer -> how a chunk and how a decode step run it
     kinds = {
         "sliding": {"chunk": window_chunk, "step": window_step},
@@ -1827,6 +2024,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         "lightning": {"chunk": lightning_chunk,
                       "step": lightning_step_layer},
         "conv": {"chunk": conv_chunk, "step": conv_step_layer},
+        "eva": {"chunk": eva_chunk, "step": eva_step},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -1926,7 +2124,8 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
                 "layer's ring and a kda or mamba layer's recurrent state "
-                "(a lightning layer's too, and a conv layer's rows) are not "
+                "(a lightning layer's too, a conv layer's rows and an eva "
+                "layer's open window) are not "
                 "pages another engine or "
                 "a draft could be handed, nor are a sparse layer's "
                 "compressed keys, "

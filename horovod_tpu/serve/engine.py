@@ -188,6 +188,12 @@ class RequestResult:
     # on this engine (0: none; kv_cache.KVCache): where a test or a
     # check finds what the sequence left on the device.
     slot: int = 0
+    # ... and the blocks its table named, in order, where the
+    # configuration keeps a state by kind of layer (empty otherwise):
+    # where a check finds the pages it left (an eva layer's summaries).
+    # They are free again when the result exists: read before another
+    # request is admitted.
+    blocks: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def first_token_latency_s(self) -> Optional[float]:
@@ -446,15 +452,18 @@ class ServeEngine:
             # layer's recurrent state and a conv layer's rows lie by
             # batch slot (kv_cache.SLOT_KINDS), so those kinds refuse it
             # (a conv layer's rows at a block boundary would be the
-            # cheapest of them to snapshot: ROADMAP B14); full, mla
+            # cheapest of them to snapshot: ROADMAP B14), and so does
+            # an eva layer, whose open window's rows lie by slot beside
+            # its summary pages (a prefix of whole windows would need
+            # the pages alone: B14 too); full, mla
             # and sparse layers alone (K/V and latent pages, compressed
             # keys behind the same tables) share.
             by_slot = self._kinds_by_slot()
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, a kda, mamba or lightning layer's state and a "
-                 "conv layer's rows "
+                 "a window, a kda, mamba or lightning layer's state, a "
+                 "conv layer's rows and an eva layer's open window "
                  "after a prefix, cannot be mapped into another sequence: "
                  "engine._admit, kv_cache.BlockAllocator)",
                  cfg.prefix_caching and by_slot),
@@ -477,6 +486,20 @@ class ServeEngine:
         # kind among several (metrics.record_paged_decode).
         self._paged_layers = (model_cfg.n_layers_of("full")
                               if model_cfg.mixed else 0)
+        # An eva layer's chunk lies in ONE aligned window (its program
+        # attends the window's earlier rows, its own keys and the closed
+        # windows' summaries: decode.mixed_programs), so a prompt's
+        # chunks are cut at the windows' ends (_advance_prefills) and
+        # the bucket a cut chunk takes has to fit a window too.
+        self._eva_window = (model_cfg.eva_window
+                            if model_cfg.n_layers_of("eva") else 0)
+        if self._eva_window and pick_bucket(
+                min(self._eva_window, cfg.prefill_chunk or cfg.max_prompt),
+                self._prefill_buckets) > self._eva_window:
+            raise ValueError(
+                f"prefill_buckets {self._prefill_buckets} hold no bucket "
+                f"within eva_window {self._eva_window} for a chunk cut at "
+                "a window's end")
 
         # Inject pad-width menu, in BLOCK units: the prefill buckets
         # (prompt-only handoffs keep their existing programs) plus the
@@ -757,6 +780,7 @@ class ServeEngine:
             submitted_at=seq.submitted_at,
             first_token_at=seq.first_token_at, finished_at=now,
             slot=seq.slot,
+            blocks=list(seq.blocks) if self._slot_states else [],
             deadline_class=seq.deadline_class,
             token_times=seq.token_times)
         self._retire_ema.observe(now)
@@ -915,6 +939,11 @@ class ServeEngine:
                         chunk -= chunk % self.cfg.block_size
                         if chunk == 0:
                             break
+                if self._eva_window:
+                    # no further than the window's end (whole blocks:
+                    # a window is)
+                    chunk = min(chunk, self._eva_window
+                                - seq.n_cached % self._eva_window)
                 toks, extra = self._prefill_call(seq, chunk)
             done_at = self._run_prefill_chunk(seq, chunk, toks, extra)
             with m.phase("serve:prefill_post"):
@@ -989,6 +1018,11 @@ class ServeEngine:
             m.record_sparse(self.model_cfg, offset + chunk,
                             prefill=extra["selected"],
                             kernel=extra["select_kernel"])
+        if self._eva_window:
+            # the chunk ends a window: from here on its summaries are
+            # attended and its rows are dead
+            m.record_eva(closed=int(
+                (offset + chunk) % self._eva_window == 0))
         return toks, extra
 
     def _run_prefill_chunk(self, seq: _Seq, chunk: int, toks: np.ndarray,
@@ -1059,8 +1093,9 @@ class ServeEngine:
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
                 f"configuration with {held} layers keeps a window layer's "
-                "keys in per-slot rings and a kda, mamba or lightning "
-                "layer's recurrent state and a conv layer's rows by slot, "
+                "keys in per-slot rings, a kda, mamba or lightning "
+                "layer's recurrent state, a conv layer's rows and an eva "
+                "layer's open window's rows by slot, "
                 "which are not pages (nor "
                 "are a sparse layer's compressed keys K or V pages) and which "
                 "migrate.py and engine.inject_* do not move yet (ROADMAP "
@@ -1436,6 +1471,21 @@ class ServeEngine:
                 # positions the batch's rows attend in the full layers
                 extra["slots_stepped"] = len(positions)
                 extra["attended"] = int(positions.sum()) + n
+            if self._eva_window:
+                # what the eva layers' attention of this call has to
+                # read, a layer: the open windows' rows up to each real
+                # row's position and the summaries of its closed windows
+                # (a padded row reads one row of the null slot: not
+                # counted); and the summary pages those are, the windows
+                # this step's rows close
+                c, W = self.model_cfg, self._eva_window
+                at = positions[[seq is not None for seq in rows]]
+                extra["eva_rows"] = int((at % W + 1).sum())
+                extra["eva_summaries"] = int(
+                    (at // W).sum()) * (W // c.eva_chunk)
+                m.record_eva(
+                    pages=int((at // W).sum()) * (W // self.cfg.block_size),
+                    closed=int(((at + 1) % W == 0).sum()))
             if "sparse" in self.cache.kinds:
                 # rows that choose their blocks (a padded row is at 0)
                 # and the keys a KV group of its rows attends: every one at

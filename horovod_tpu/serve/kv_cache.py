@@ -293,7 +293,7 @@ class BlockAllocator:
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
 STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv")
+               "lightning", "conv", "eva")
 #: Those of them whose arrays lie by batch slot and not behind the block
 #: tables: nothing of theirs is a page that another sequence, another
 #: engine or a draft could be handed (what ``engine.py`` refuses over
@@ -306,9 +306,16 @@ STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
 #: ``conv_taps - 1`` inputs and no recurrence (nothing of a position
 #: further back is in them, and nothing in float32 lies beside them).
 #: ``RECURRENT_KINDS`` only names which slot kinds are recurrences:
-#: nothing in the engine treats them apart.
+#: nothing in the engine treats them apart. ``eva`` is the one kind with
+#: BOTH halves: its summaries are pages behind the tables, but the open
+#: window's K and V rows lie by slot, so it is in ``SLOT_KINDS``
+#: (everything refused over a slot's state is refused over the rows: a
+#: prefix mapped as pages would bring no rows, and ``migrate`` /
+#: ``inject`` move pages alone) and not in ``RECURRENT_KINDS``: the rows
+#: are the window's own keys and values in the cache's dtype, nothing
+#: is folded over positions and a closed window's rows are dead.
 RECURRENT_KINDS = ("kda", "mamba", "lightning")
-SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv",)
+SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv", "eva")
 
 
 def state_kinds(cfg) -> Tuple[str, ...]:
@@ -378,7 +385,27 @@ class KVCache:
       convolution, end to end as a mamba layer's,
       ``[n, n_slots + 1, (conv_taps - 1) * d_model]`` in the
       configuration's dtype, and nothing else: no recurrent state and
-      no second array.
+      no second array;
+    * ``eva``: BOTH halves, four arrays (a pair under ``k`` and a pair
+      under ``v``). The open window's K rows and V rows by batch slot,
+      ``[n, n_slots + 1, eva_window, Hkv, Dh]``: position ``t`` lies at
+      row ``t % eva_window`` (aligned: nothing rolls), and rows ``0 ..
+      t % eva_window`` are live, whatever an earlier window or sequence
+      left behind them. And the chunk summaries ``k~`` and ``v~`` in
+      pages behind the block tables, ``[n, n_blocks, block_size //
+      eva_chunk, Hkv, Dh]``: chunk ``m`` (positions ``eva_chunk * m ..``)
+      lies in the page of its positions, table entry ``eva_chunk * m //
+      block_size``, at row ``m % (block_size // eva_chunk)``, written
+      when the chunk closes; attention reads the summaries of CLOSED
+      windows alone, ``m < (t // eva_window) * (eva_window //
+      eva_chunk)``. ``block_size`` is whole chunks and divides the
+      window (:func:`init_kv_cache`), and is otherwise the engine's:
+      the pages are allocated position by position as any layer's, and
+      a chunk of a prompt is whole blocks as everywhere. (A page a
+      WINDOW, ``block_size = eva_window``, is the same layout at its
+      widest; the engine's chunks and prefill buckets are whole blocks,
+      so a stack that is cut into 1024-chunks takes a smaller one, and
+      ``full`` layers beside it share the block size.)
 
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
@@ -396,16 +423,28 @@ class KVCache:
     def of(self, kind: str) -> Tuple[Any, ...]:
         """``kind``'s arrays, in the order the class lists them."""
         i = self.kinds.index(kind)
-        first = self.k[i] if isinstance(self.k[i], tuple) else (self.k[i],)
-        return first + (() if self.v[i] is None else (self.v[i],))
+        def arrays(of):
+            return () if of is None else of if isinstance(of, tuple) else (of,)
+        return arrays(self.k[i]) + arrays(self.v[i])
+
+    def by_slot(self, kind: str) -> Tuple[Any, ...]:
+        """Those of ``kind``'s arrays that lie by batch slot: all of a
+        ``SLOT_KINDS`` kind's, but of ``eva``'s four the two of rows
+        (the other two are pages)."""
+        if kind not in SLOT_KINDS:
+            return ()
+        return self.of(kind)[::2] if kind == "eva" else self.of(kind)
 
     @property
     def slot_bytes(self) -> int:
         """Bytes a batch slot holds whatever its sequence's length:
-        its rings and its recurrent state."""
-        return sum(a[:, 0].size * a.dtype.itemsize
-                   for kind in self.kinds if kind in SLOT_KINDS
-                   for a in self.of(kind))
+        its rings, its recurrent state, its open window's rows."""
+        # from the shapes: `a[:, 0]` is a slice ON THE DEVICE, and the
+        # engine reads this gauge every step (with an eva layer's rows a
+        # slice and a copy of 134 MB each, 8 % of the device's time:
+        # chip, PR 56)
+        return sum(a.size // a.shape[1] * a.dtype.itemsize
+                   for kind in self.kinds for a in self.by_slot(kind))
 
     @property
     def max_blocks_per_seq(self) -> int:
@@ -482,8 +521,18 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
             raise ValueError(
                 f"a sparse layer's selected block is a page: block_size "
                 f"{block_size} is not sparse_block {cfg.sparse_block}")
+        if n["eva"] and (block_size % cfg.eva_chunk
+                         or cfg.eva_window % block_size):
+            raise ValueError(
+                f"an eva layer's page holds whole chunks of a window: "
+                f"block_size {block_size} is not whole eva_chunk "
+                f"{cfg.eva_chunk} or does not divide eva_window "
+                f"{cfg.eva_window}")
         pages = ((n["sparse"], n_blocks, cfg.n_kv_heads, block_size, Dh),
                  dtype)
+        eva = (((n["eva"], n_slots + 1, cfg.eva_window) + tail, dtype),
+               ((n["eva"], n_blocks, block_size // cfg.eva_chunk) + tail,
+                dtype))
         shapes = {   # kind -> (shape, dtype) of what k and v hold of it
             "full": 2 * (((n["full"], n_blocks, block_size) + page_tail(cfg),
                           dtype),),
@@ -505,6 +554,7 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                            jnp.float32), None),
             "conv": (((n["conv"], n_slots + 1,
                        (cfg.conv_taps - 1) * cfg.d_model), dtype), None),
+            "eva": (eva, eva),   # (K rows, k~ pages), (V rows, v~ pages)
         }
         kinds = state_kinds(cfg)
 

@@ -418,8 +418,9 @@ class ServeMetrics:
         self.kv_window_positions_max = 0
         self.kv_window_blocks_in_use = 0
         # The kinds whose state is not keys (kda, mamba, lightning,
-        # conv, mla): the batch slots whose recurrent state, or whose
-        # convolution rows alone, a sequence holds and their bytes; the
+        # conv, eva, mla): the batch slots whose recurrent state, whose
+        # convolution rows alone, or whose open window's rows a
+        # sequence holds and their bytes; the
         # positions the latent pool held for the rows of the last
         # decode call (their sum: what absorbed attention has to read),
         # and the most it held for one sequence.
@@ -437,6 +438,15 @@ class ServeMetrics:
         self.sparse_select_kernel_queries_total = 0
         self.sparse_selected_rows_total = 0
         self.kv_compressed_max = 0
+        # Eva layers (an aligned window's rows by slot beside chunk
+        # summaries in pages): the summary pages the rows of the last
+        # decode call attend (those of their closed windows; the pages
+        # of an open window are allocated and written, and not read
+        # yet), the most a call attended, and the windows closed (by a
+        # chunk or a decode step that wrote a window's last row).
+        self.eva_summary_pages_in_use = 0
+        self.eva_summary_pages_max = 0
+        self.eva_windows_closed_total = 0
         # Speculative decoding (serve/speculative.py): proposal /
         # acceptance tallies (their ratio is the token-weighted accept
         # rate) and the per-round draft / verify wall-time series.
@@ -720,6 +730,17 @@ class ServeMetrics:
             self.kv_compressed_max,
             (held - cfg.sparse_kernel) // cfg.sparse_stride + 1)
 
+    def record_eva(self, pages: Optional[int] = None,
+                   closed: int = 0) -> None:
+        """A call was launched that closes ``closed`` windows of its eva
+        layers' sequences; a decode call's rows attend ``pages`` summary
+        pages (None: a chunk, which moves no gauge)."""
+        self.eva_windows_closed_total += closed
+        if pages is not None:
+            self.eva_summary_pages_in_use = pages
+            self.eva_summary_pages_max = max(self.eva_summary_pages_max,
+                                             pages)
+
     def record_latent_decode(self, lengths, key_block: int,
                              layers: int) -> None:
         """A decode call was launched whose rows hold ``lengths``
@@ -959,6 +980,10 @@ class ServeMetrics:
                 self.sparse_select_kernel_queries_total,
             "sparse_selected_rows_total": self.sparse_selected_rows_total,
             "kv_compressed_max": self.kv_compressed_max,
+            # eva layers (zeros without such layers)
+            "eva_summary_pages_in_use": self.eva_summary_pages_in_use,
+            "eva_summary_pages_max": self.eva_summary_pages_max,
+            "eva_windows_closed_total": self.eva_windows_closed_total,
             "p50_first_token_ms": ms(percentile(self.first_token_s, 50)),
             "p99_first_token_ms": ms(percentile(self.first_token_s, 99)),
             "p50_per_token_ms": ms(percentile(self.per_token_s, 50)),

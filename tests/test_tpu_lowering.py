@@ -1357,3 +1357,119 @@ def test_a_decode_step_s_full_layers_read_the_pools_where_they_lie(shapes):
     assert step["table_gathers"] == 0, step
     assert chunk["paged_decode_calls"] == 0, chunk
     assert chunk["table_gathers"] > 0, chunk
+
+
+# EVA attention at EvaByte's widths (ISSUE 56): two eva layers of 32
+# heads of 128 over 16 slots of window rows and summary pages of 256
+# positions behind tables of 128, a float32 stream and an 8 x 320 head.
+_EVA_DRIVER = r"""
+import json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=320, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32,
+    d_ff=11008, max_seq=32768, norm_eps=1e-5, rope_theta=1e5,
+    layer_types=("eva", "eva"), eva_window=2048, eva_chunk=16,
+    norm_unit_offset=True, stream_fp32=True, head_rows=8,
+    dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS = 256, 128, 16
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+fns = dict(zip(("prefill", "prefill_resume", "decode"),
+               decode_lib.make_serve_fns(cfg, None, block_size=BS,
+                                         table_width=WIDTH)))
+args = {{"prefill_resume": (i32(1024), i32(), i32(), (i32(WIDTH), i32())),
+        "decode": (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))}}
+rows, pages = kc[0]
+out = {{"device_kind": topo.devices[0].device_kind,
+       "rows": list(rows.shape), "pages": list(pages.shape)}}
+for name, a in args.items():
+    compiled = fns[name].lower(params, kc, vc, *a).compile()
+    text = compiled.as_text()
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    calls = re.findall(
+        r'custom-call\([^\n]*op_name="([^"]*hvd_[a-z_]+)[^"]*"', text)
+    out[name] = {{
+        # copies of rows or pages (.., 32, 128) larger than ONE slot's
+        # rows of one layer (a chunk gathers its sequence's summary
+        # pages, 1/16 of its closed windows, and half a window of rows)
+        "big_copies": sum(
+            int(np.prod([int(d) for d in dims.split(",")]))
+            > 2048 * 32 * 128
+            for dims in re.findall(
+                r"= bf16\[([\d,]+,32,128)\]\{{\S* copy\(", text)),
+        "aliased": len(re.findall(r"may-alias|must-alias",
+                                  aliased.group(1))),
+        "kernels": sorted(set(calls)), "n_kernels": len(calls),
+        # a projection matrix turned over on its way into its product
+        "weight_transposes": len(re.findall(
+            r"= bf16\[4096,4096\]\{{\S* copy\(%bitcast", text)),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_the_eva_programs_lower_for_the_v5e_at_the_cell_s_shapes(program):
+    """ISSUE 56: ``jit(decode)`` and ``jit(prefill_resume)`` of a stack
+    of eva layers compiled for the v5e at EvaByte's widths (32 heads of
+    128, a window of 2048 in chunks of 16, 16 slots, summary pages of
+    256 positions behind tables of 128). A decode step holds the
+    extended ``hvd_paged_decode`` twice a layer (``paged_decode_stats``:
+    the slot's rows read as pages to each row's count under
+    ``eva_window``, the summaries' pages under ``eva_summaries``), a
+    chunk ``hvd_flash_keys_fwd`` twice a layer (the summaries, then the
+    window carried on them); the four arrays of the kind (K and V rows,
+    k~ and v~ pages) are aliased in and out, no rows or pages beyond
+    one slot's rows of a layer are copied (no array of rows turned over,
+    no closed window re-read, no batch's tables gathered), and a
+    program's temporaries stay under 0.25 GB."""
+    out = _compile_for_v5e(_EVA_DRIVER)
+    got = out[program]
+    assert out["rows"] == [2, 17, 2048, 32, 128], out
+    assert out["pages"] == [2, 16 * 128 + 1, 16, 32, 128], out
+    assert got["aliased"] == 4, got
+    assert got["big_copies"] == 0, got
+    assert got["n_kernels"] == 4, got
+    if program == "decode":
+        assert got["kernels"] == [
+            "jit(decode)/attn/attn_eva/eva_summaries/jit(_decode)/"
+            "hvd_paged_decode",
+            "jit(decode)/attn/attn_eva/eva_window/jit(_decode)/"
+            "hvd_paged_decode"], got
+    else:
+        assert got["kernels"] == [
+            "jit(prefill_resume)/attn/attn_eva/eva_summaries/"
+            "hvd_flash_keys_fwd",
+            "jit(prefill_resume)/attn/attn_eva/eva_window/"
+            "hvd_flash_keys_fwd"], got
+    assert got["temp_bytes"] < 0.25e9, got
+    # q, k and v come out of one product over the heads' own dimensions
+    # (`attention_inputs`): as `h @ w` reshaped afterwards the compiler
+    # transposed wq, wk and wv, 32 MB each, in every call (3 a layer)
+    assert got["weight_transposes"] == 0, got
