@@ -18,8 +18,10 @@ A configuration without a capacity (``capacity_factor=None``: OLMoE and
 the other fine-grained sparse decoders) takes :func:`moe_ffn_dropless`
 instead: the ``N·K`` (token, choice) pairs are sorted by expert, the
 experts run as one grouped matmul over the sorted rows
-(``lax.ragged_dot``, which the TPU compiler lowers to a Mosaic grouped
-matmul), and nothing is dropped. Its largest value is ``[N·K, D]``;
+(:func:`_grouped_product`: ``ops/grouped_matmul.py``'s kernel where the
+matrices' bytes bound the product, ``lax.ragged_dot``, which the TPU
+compiler lowers to a Mosaic grouped matmul, where the matrix unit
+does), and nothing is dropped. Its largest value is ``[N·K, D]``;
 the one-hot tensors above, whose size grows with ``E · C``, do not
 exist there. :func:`make_moe_ffn` picks between the two from the
 configuration alone.
@@ -38,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.ops import grouped_matmul as grouped_matmul_lib
 
 #: Dispatch-plane values for the HOROVOD_MOE_DISPATCH knob /
 #: ``TransformerConfig.moe_dispatch`` (docs/perf_tuning.md).
@@ -352,6 +356,35 @@ def _sorted_by_expert(experts, n_experts: int):
     return order, sizes
 
 
+#: Grouped products of the whole mixture traced in this process, and of
+#: them those that took the kernel (``moe_grouped_kernel_products_share``).
+_grouped_traced = [0, 0]
+
+
+def _grouped_product(rows, w, sizes, sharded: bool = False):
+    """One of the whole mixture's three products, ``rows`` [N·K, ·]
+    sorted by expert times ``w`` [E, ·, ·]: through
+    ``ops/grouped_matmul.py``'s kernel (``hvd_grouped_matmul``: each
+    expert's matrix read once, whole) where ``grouped_matmul.taken``
+    says the matrices' bytes bound it, from the shapes alone: the mean
+    rows an expert under the chip's operations a byte (the LFM2 cell's
+    16 in a decode step and 32 to 128 in a chunk); ``lax.ragged_dot``
+    otherwise (OLMoE's trainer's 1024), and for a shard of a mesh's
+    tokens (``sharded``: a Pallas result says nothing of the axes it
+    varies over, which :func:`_dropless_over_mesh`'s ``shard_map``
+    checks). The choice is made as the program is traced and counted
+    there."""
+    kernel = not sharded and grouped_matmul_lib.taken(rows, w)
+    with _moe_metrics_lock:
+        _grouped_traced[0] += 1
+        _grouped_traced[1] += kernel
+        share = _grouped_traced[1] / _grouped_traced[0]
+    record_moe_stats({"moe_grouped_kernel_products_share": share})
+    if kernel:
+        return grouped_matmul_lib.grouped_matmul(rows, w, sizes)
+    return lax.ragged_dot(rows, w, sizes)
+
+
 def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
     """One MoE FFN block with no capacity and no dropped token. Same
     signature and return as :func:`moe_ffn`.
@@ -405,10 +438,13 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
         rows = _take_sorted(xf, order, inverse, K)            # [N·K, D]
     aux = router_losses(sizes)
     with jax.named_scope("moe_experts"):
-        g = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)
+        sharded = bool(token_axes)
+        g = jax.nn.silu(_grouped_product(rows, lp["w_gate"], sizes, sharded)
                         .astype(jnp.float32))
-        u = lax.ragged_dot(rows, lp["w_up"], sizes).astype(jnp.float32)
-        out = lax.ragged_dot((g * u).astype(x.dtype), lp["w_down"], sizes)
+        u = _grouped_product(rows, lp["w_up"], sizes, sharded
+                             ).astype(jnp.float32)
+        out = _grouped_product((g * u).astype(x.dtype), lp["w_down"], sizes,
+                               sharded)
     with jax.named_scope("moe_combine"):
         y = jnp.einsum(
             "nkd,nk->nd",
@@ -750,6 +786,7 @@ MOE_METRIC_KEYS = (
     "moe_expert_load_max_over_mean",
     "moe_compact_calls_share",
     "moe_held_pairs_over_bound_max",
+    "moe_grouped_kernel_products_share",
 )
 
 _moe_metrics: Dict[str, float] = {}

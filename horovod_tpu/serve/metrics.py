@@ -67,6 +67,7 @@ from typing import Dict, List, Optional
 from jax.profiler import TraceAnnotation
 
 from horovod_tpu.common.compile_cache import compile_stats
+from horovod_tpu.models.moe import moe_metrics
 
 #: Keep at most this many latency samples per series (drop-oldest);
 #: long-running engines must not grow without bound.
@@ -952,6 +953,11 @@ class ServeMetrics:
             "paged_decode_pages_total": self.paged_decode_pages_total,
             "paged_decode_pages_table_total":
                 self.paged_decode_pages_table_total,
+            # of the whole mixture's grouped products this process
+            # traced, the share that took ops/grouped_matmul.py's kernel
+            # (models/moe.py counts it as it traces; 0.0 without any)
+            "moe_grouped_kernel_products_share": moe_metrics().get(
+                "moe_grouped_kernel_products_share", 0.0),
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
             # device calls and host gaps many times their kind's

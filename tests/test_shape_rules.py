@@ -3,17 +3,21 @@ ring holds, read by value.
 
 Every tile below was chosen by a sweep on the chip that its rule's
 docstring records (``flash_attention._default_blocks``, ``_keys_blocks``,
-``_bwd_blocks``, ``decode._prompt_block``), at the lengths the benchmark's
-cells run. A changed tile fails here, on the CPU: run the sweep that
+``_bwd_blocks``, ``decode._prompt_block``, ``grouped_matmul.taken``), at the lengths the
+benchmark's cells run. A changed tile fails here, on the CPU: run the sweep that
 justified the old one (``tools/prefill_attn_sweep.py``,
-``benchmark/tools/flash_window_sweep.py``) before writing the new value
+``benchmark/tools/flash_window_sweep.py``,
+``tools/grouped_matmul_sweep.py``) before writing the new value
 in. ``decode.ring_positions`` is what every window layer's mask stands
 on; it is held to a plain loop that writes position p at ``p % ring``."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from horovod_tpu.ops import flash_attention as flash_lib
+from horovod_tpu.ops import grouped_matmul as grouped_lib
 from horovod_tpu.serve import decode as decode_lib
 
 
@@ -166,3 +170,40 @@ def test_a_batch_s_rows_hold_their_own_rings(ring):
         np.asarray(frontiers, np.int32), ring))
     for row, n in zip(got, frontiers):
         holds(row, n, ring)
+
+
+# -- who runs a whole mixture's grouped product: (M, G, K, N) -> kernel? --
+
+GROUPED_PRODUCTS = {
+    # the LFM2 cell: a decode step's 512 pairs and the three chunk
+    # buckets' over 32 experts, gate or up and down
+    "lfm2_step": ((512, 32, 2048, 1792), True),
+    "lfm2_step_down": ((512, 32, 1792, 2048), True),
+    "lfm2_chunk_256": ((1024, 32, 2048, 1792), True),
+    "lfm2_chunk_512": ((2048, 32, 2048, 1792), True),
+    "lfm2_chunk_1024": ((4096, 32, 2048, 1792), True),
+    # one row under the threshold of 256 a group, and on it
+    "under_the_threshold": ((256 * 32 - 1, 32, 2048, 1792), True),
+    "on_the_threshold": ((256 * 32, 32, 2048, 1792), False),
+    # OLMoE's trainer: 65 536 pairs over 64 experts, the matrix unit's
+    "olmoe_step": ((65536, 64, 2048, 1024), False),
+    "olmoe_step_down": ((65536, 64, 1024, 2048), False),
+    # widths that are no whole lane tiles (the tests' tiny models)
+    "narrow_k": ((64, 4, 64, 128), False),
+    "narrow_n": ((64, 4, 128, 192), False),
+    # two whole matrices over the buffer's 48 MB
+    "a_matrix_of_32_mb": ((512, 8, 4096, 4096), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_PRODUCTS))
+def test_who_runs_a_grouped_product(case):
+    """``grouped_matmul.taken`` at the cells' shapes: a rule from
+    shapes alone, so it is read from shapes alone."""
+    (m, g, k, n), kernel = GROUPED_PRODUCTS[case]
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16)
+    rhs = jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16)
+    assert grouped_lib.taken(lhs, rhs) is kernel
+    # operands of two dtypes never take it
+    assert not grouped_lib.taken(
+        jax.ShapeDtypeStruct((m, k), jnp.float32), rhs)

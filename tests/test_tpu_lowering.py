@@ -1246,9 +1246,10 @@ def test_the_sparse_and_lightning_programs_lower_for_the_v5e(program):
 
 
 # Heads of 64 behind the block tables (ISSUE 54): a conv layer, an
-# attention layer of 32 / 8 heads of 64 and two experts of a mixture
-# held whole, at LFM2-8B-A1B's widths, 128 slots and tables of 160
-# pages.
+# attention layer of 32 / 8 heads of 64 and the 32 experts of a mixture
+# held whole, four a token (ISSUE 57: the pairs an expert decide who
+# runs the grouped products), at LFM2-8B-A1B's widths, 128 slots and
+# tables of 160 pages.
 _LFM2_DRIVER = r"""
 import json, re, sys
 sys.path.insert(0, {root!r})
@@ -1270,9 +1271,10 @@ cfg = TransformerConfig(
     d_head=64, d_ff=1792, d_ff_dense=7168, n_dense_layers=1, max_seq=2560,
     norm_eps=1e-5, layer_types=("conv", "full", "conv"), conv_taps=3,
     layer_rotary={{"full": dict(theta=1e6)}}, qk_norm_per_head=True,
-    tie_embeddings=True, n_experts=2, moe_top_k=1, moe_capacity_factor=None,
+    tie_embeddings=True, n_experts=32, moe_top_k=4, moe_capacity_factor=None,
     moe_scoring="sigmoid", dtype=jnp.bfloat16, remat=False)
 BS, WIDTH, SLOTS = 16, 160, 128
+EXPERTS = 32 * 2048 * 1792          # a layer's stack of one matrix
 
 
 def on_chip(tree):
@@ -1311,10 +1313,52 @@ for name, a in args.items():
                                   aliased.group(1))),
         "ragged_dots": len(re.findall(r"%ragged-dot-(?!metadata)\S+ = ",
                                       text)),
+        "grouped_kernels": len(re.findall(
+            r'custom-call\([^\n]*op_name="[^"]*moe_experts/[^"]*'
+            r'hvd_grouped_matmul', text)),
+        # copies or slices of anything as large as a layer's experts
+        "expert_moves": sum(
+            int(np.prod([int(d) for d in dims.split(",")])) >= EXPERTS
+            for dims in re.findall(
+                r"= bf16\[([\d,]+)\]\{{\S* (?:copy|slice|dynamic-slice)\(",
+                text)),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
         **paged_decode_report(text)}}
+
+
+# the rule's other side (ISSUE 57): one layer of OLMoE's trainer, 65 536
+# pairs over 64 experts of [2048, 1024], forward and backward
+from horovod_tpu.models import moe as moe_lib
+olmoe = moe_lib.MoEConfig(n_experts=64, top_k=8, capacity_factor=None,
+                          norm_topk_prob=False)
+lp = on_chip(jax.tree.map(
+    lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+    jax.eval_shape(lambda: moe_lib.init_moe_params(
+        jax.random.PRNGKey(0), 1, 2048, 1024, olmoe, jnp.bfloat16))))
+text = jax.jit(jax.grad(
+    lambda x, lp: moe_lib.moe_ffn_dropless(x, lp, olmoe)[0].astype(
+        jnp.float32).sum(), (0, 1))).lower(
+    jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16, sharding=one), lp
+    ).compile().as_text()
+out["olmoe_grad"] = {{
+    "ragged_dots": len(re.findall(
+        r"%ragged-dot-(?!metadata)\S+ = [^\n]* custom-call\(", text)),
+    "grouped_kernels": text.count("hvd_grouped_matmul")}}
 print("LOWERED " + json.dumps(out))
 """.replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
+
+
+def test_a_trainer_s_whole_mixture_keeps_the_compiler_s_grouped_products():
+    """ISSUE 57, the rule's other side: ``jax.grad`` of
+    ``moe_ffn_dropless`` at OLMoE's trainer's shapes (``[2, 4096,
+    2048]``, 64 experts of ``[2048, 1024]``, 8 a token: 1024 pairs an
+    expert, which the matrix unit bounds) holds the compiler's
+    ``ragged-dot`` kernels, three forward and the two cotangents of
+    each backward, and no ``hvd_grouped_matmul``: the trainer's program
+    is what it was."""
+    got = _compile_for_v5e(_LFM2_DRIVER)["olmoe_grad"]
+    assert got["grouped_kernels"] == 0, got
+    assert got["ragged_dots"] >= 3 + 6, got
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_resume"])
@@ -1327,13 +1371,20 @@ def test_pages_of_narrow_heads_are_never_copied_whole(program):
     in and out, no copy of anything as large as a pool, a program's
     temporaries under 0.1 GB (a decode step's gathered tables took 1.06
     GB before ISSUE 55; a chunk's 20 MB), and the whole mixture's three
-    grouped products a sparse layer in the program."""
+    grouped products a sparse layer in the program: since ISSUE 57 each
+    is ``hvd_grouped_matmul`` under ``moe_experts`` (16 pairs an expert
+    in a step, 128 in the chunk: the matrices' bytes bound both), none
+    the compiler's ``ragged-dot``, and the kernel reads the matrices
+    where the parameters lie (no copy or slice as large as a layer's
+    ``bf16[32,2048,1792]``)."""
     out = _compile_for_v5e(_LFM2_DRIVER)
     got = out[program]
     assert out["pool"] == "bf16[1,20481,16,512]", out
     assert got["aliased"] == 3, got
     assert got["pool_copies"] == 0, got
-    assert got["ragged_dots"] == 2 * 3, got
+    assert got["grouped_kernels"] == 2 * 3, got
+    assert got["ragged_dots"] == 0, got
+    assert got["expert_moves"] == 0, got
     # a decode step gathers no table (ISSUE 55; 1.06 GB of them before)
     assert got["temp_bytes"] < 0.1e9, got
 

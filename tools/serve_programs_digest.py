@@ -37,7 +37,9 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          ("minicpm-sala-8l", "longdoc-backlog"),
          # the decode programs of the three configurations with a full
          # kind (this, trinity, jamba) changed with PR 55
-         # (hvd_paged_decode: a step's full layers), their chunks did not
+         # (hvd_paged_decode: a step's full layers), their chunks did not;
+         # this one's three programs changed with PR 57 (hvd_grouped_matmul:
+         # the whole mixture's grouped products), no other cell's did
          ("lfm2-8b-a1b-14l", "assistant-backlog")]
 
 
