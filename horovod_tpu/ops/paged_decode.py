@@ -1,16 +1,21 @@
-"""A decode step's attention of a ``full`` layer over the K and V pages
-where they lie.
+"""A decode step's attention over a pool's pages where they lie.
 
-One Pallas call a layer (``hvd_paged_decode`` in a device trace): for
-each row of the batch, its one query a head against the keys of that
-row's own pages and the values of the same pages, read once out of the
-two pools in HBM, a key block at a time into VMEM, and no further than
-the row's length. Built as ``ops/latent_decode.py`` is (tables and
-lengths as prefetched scalars, a key block's pages by asynchronous
+One Pallas call a layer: for each row of the batch, its one query a head
+against that row's own pages, read once out of the pools in HBM, a key
+block at a time into VMEM, and no further than the row's length (tables
+and lengths as prefetched scalars, a key block's pages by asynchronous
 copies into one half of a double buffer while the other half is
-attended), with two pools where that kernel has one. The XLA form it
-replaced (``serve/decode.py``: ``_attend_keys`` over every row's whole
-table, gathered) is the tests' reference.
+attended, one running softmax). ONE kernel body, two Pallas calls of it:
+
+``hvd_paged_decode`` (:func:`paged_decode`, :func:`paged_decode_stats`)
+    a ``full`` layer's K and V pages, two pools. The XLA form it
+    replaced (``serve/decode.py``: ``_attend_keys`` over every row's
+    whole table, gathered) is the tests' reference.
+``hvd_latent_decode`` (:func:`latent_decode`)
+    an ``mla`` layer's latents, one pool: the keys are the values (one
+    latent a position: all of it scored, its first ``rank`` summed), so
+    a page crosses the memory once for both dots. The XLA form it
+    replaced (``tests/reference_mla.py``) is the tests' reference.
 """
 
 from __future__ import annotations
@@ -29,7 +34,9 @@ from .flash_attention import NEG_INF
 
 def _wave_pages(block_size: int) -> int:
     """Pages a wave of copies brings of each pool (a key block): 1024
-    positions. On the v5e (2026-10-02, ``tools/prefill_attn_sweep.py
+    positions, for K and V pages and for latents alike.
+
+    **K and V pages.** On the v5e (2026-10-02, ``tools/prefill_attn_sweep.py
     --paged-decode``: bf16 queries ``[rows, H, Dh]`` over two pools
     behind shuffled tables, the rows' lengths log-uniform; ms a layer of
     the kernel alone and the GB/s of the K and V pages it reads, at key
@@ -49,55 +56,109 @@ def _wave_pages(block_size: int) -> int:
     in all three.) A page of each pool costs 69-71 ns at lfm2's 16 KB
     and at jamba's 4 KB alike: nearly every key block there is a row's
     last one, whose copies are issued in a loop, and what a copy costs
-    is its issue (34 ns: ``ops/latent_decode.py``), not its bytes; at
-    trinity's 32 KB, where most blocks are whole, it is the memory (105
+    is its issue (34 ns, below), not its bytes; at trinity's 32 KB,
+    where most blocks are whole, it is the memory (105
     ns a page pair where 819 GB/s would take 80). A first form that
     started a page a turn of the loop and waited for every copy by
     itself took 0.664, 0.747 and 0.625 ms: the eight pages a turn and
     the one wait a power of two are 17 % of lfm2's time and 14 % of
     jamba's. The masked form of trinity's pages (every query head
     scored against every KV head's keys, 8 x the exponentials) is not
-    what bounds it: 620-632 GB/s is the latent kernel's speed behind
+    what bounds it: 620-632 GB/s is the latent call's speed behind
     shuffled tables with one pool. 2048 is no faster anywhere; 512 is 2
     % ahead at trinity's shapes and 4 % behind at lfm2's. (With a
     block's offset into the tables taken once and not once a page, as
-    it stands: 0.546, 0.749 and 0.526 ms at 1024.)"""
+    it stands: 0.546, 0.749 and 0.526 ms at 1024.)
+
+    **Latents.** On the v5e (``tools/prefill_attn_sweep.py
+    --latent-decode``: bf16 queries ``[rows, H, 640]`` over a pool of
+    pages ``[16, 640]`` behind shuffled tables of 1088; ms a layer of
+    the kernel alone and the GB/s of the pages it reads, at key blocks
+    of 512 / **1024** / 2048 positions; ``xla``, 2026-10-01, is the
+    form it replaced with the two absorbed products,
+    ``tests/reference_mla.py`` over ``mla_pages`` to the longest row;
+    the kernel's columns 2026-10-03, builder, PR 58, as it stands: this
+    module's one body):
+
+    == ==== ================== ==== ====================== ===============
+    H  rows lengths            xla  kernel alone, ms       GB/s
+    == ==== ================== ==== ====================== ===============
+    64 32   8192 each          1.40 0.76 / **0.66** / 0.62 440 / 509 / 542
+    64 32   4096 .. 16 384     2.61 0.96 / **0.84** / 0.82 449 / 513 / 527
+    64 32   16 384, 256 .. 3 k 2.59 0.33 / **0.30** / 0.31 289 / 317 / 307
+    32 64   8192 each          2.49 1.34 / **1.13** / 1.02 501 / 594 / 658
+    32 64   4096 .. 16 384     4.78 1.74 / **1.46** / 1.36 500 / 594 / 639
+    32 64   16 384, 256 .. 3 k 4.82 0.44 / **0.40** / 0.41 349 / 379 / 371
+    == ==== ================== ==== ====================== ===============
+
+    (The third lengths: one row of 16 384 among rows of 256 to 3072. The
+    sweep shares a program's time out over its six chained calls, so
+    every level carries about 0.1 ms of the program's own cost:
+    ``PERF.md`` §7.) 64 heads at 64 rows and 32 at 32 lie between
+    (0.62-1.54 ms, 541-552 GB/s on whole blocks). To PR 57 this call had
+    a kernel of its own, whose blocks at a row's end started and awaited
+    a page a turn of the loop; beside this one in one call (builder, PR
+    58) its 1024 column read 0.70 / 0.89 / 0.34 and 1.17 / 1.54 / 0.48
+    ms: the eight pages a turn and the one wait a power of two are 14-18
+    % of the time of short rows and 4-7 % of rows of 4-16 k, whose last
+    TWO blocks take that form (``whole`` needs the next block whole
+    too). A whole key block of 1024 takes 2.3-2.8 us where the memory's
+    819 GB/s would take 1.6, and four fifths of that is the copies, not
+    the dots: with both dots taken out the kernel took 0.71 of its 0.90
+    ms at 64 heads and 32 rows of 4-16 k (598 GB/s: 64 copies of 20 KB
+    from scattered pages, 34 ns each), the score dot adds 0.08, the
+    value dot 0.04, the softmax 0.09, and 32 heads take nine tenths of
+    64's time (builder, PR 45, as what follows). Behind the engine's
+    tables, where a sequence's pages mostly follow one another, the
+    kernel read 629 GB/s (the Kimi cell's trace, 0.695 ms a layer). With
+    every block's copies in a loop a page a turn it read 341-362 GB/s on
+    whole blocks (0.99 and 1.24 ms where 0.70 and 0.90 stood): the
+    straight-line copies beside the dots are a third of its speed.
+    Scoring with the latents as the streamed operand (``latent . q^T``,
+    turned back) was slower, 1.23 ms. 512 starts twice the blocks. 2048
+    was level with 1024 on long rows and is 3-10 % ahead of it since PR
+    58 (a row's ragged blocks, its last two, cost less than they did),
+    level or 3 % behind on short rows; not taken: one rule for both
+    calls, and at the K and V cells' shapes 2048 is no faster (above)."""
     return max(1, 1024 // block_size)
 
 
 def key_block(block_size: int, table_width: int) -> int:
-    """Positions a key block of :func:`paged_decode` holds over pages of
+    """Positions a key block of this module's calls holds over pages of
     ``block_size`` behind tables ``table_width`` wide."""
     return min(_wave_pages(block_size), table_width) * block_size
 
 
-def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
-            o_ref, *rest, scale: float, width: int, page: int, group: int,
+def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, *refs,
+            scale: float, width: int, page: int, group: int,
             stats: bool = False):
     """Row ``b`` of the batch (one grid step): its key blocks in a
-    loop, block ``j`` waited for in one half of ``buf`` (K's pages at
-    ``[half, 0]``, V's at ``[half, 1]``) while the pages of the next
-    (the row's, or the first of row ``b + 1``) are on their way into the
-    other. ``first_ref[b]`` counts the key blocks of the rows before
-    ``b``: its parity says which half block 0 arrives in. Where this
-    block and the next are both whole, the next one's copies are started
-    as straight-line code in the block that holds the dots and this
-    one's are waited for at once; a block at a row's end takes a loop
-    over the pages it has (``ops/latent_decode.py::_kernel``).
+    loop, block ``j`` waited for in one half of ``buf`` (pool ``n``'s
+    pages at ``[half, n]``) while the pages of the next (the row's, or
+    the first of row ``b + 1``) are on their way into the other.
+    ``first_ref[b]`` counts the key blocks of the rows before ``b``: its
+    parity says which half block 0 arrives in. Where this block and the
+    next are both whole, the next one's copies are started as
+    straight-line code in the block that holds the dots (the scalar unit
+    issues them while the matrix unit works) and this one's are waited
+    for at once; a block at a row's end takes a loop over the pages it
+    has.
+
+    ``refs``: the pools (as many as ``buf`` has at ``[half]``: K and V,
+    or the one of latents, whose keys are the values, their first
+    ``acc``-wide columns), the output, with ``stats`` one more (the
+    softmax's logsumexp a head, along the lanes of a ``[H, 128]`` tile,
+    for a caller that merges this call's keys with others' in one
+    softmax: :func:`paged_decode_stats`), and the scratch.
 
     A page is ``page * group`` rows of the buffer: one a position
     (``group`` 1: every KV head in the row, the queries laid
     block-diagonal over it), or one a position and KV head (``group``
     ``Hkv``: column ``c`` of the scores is position ``c // group`` under
     KV head ``c % group``, and a query head sees its own KV head's
-    columns alone).
-
-    ``stats``: one more output before the scratch, the softmax's
-    logsumexp a head (along the lanes of a ``[H, 128]`` tile), for a
-    caller that merges this call's keys with others' in one softmax
-    (:func:`paged_decode_stats`)."""
-    lse_ref, (buf, sem, acc, m_scr, l_scr) = (
-        (rest[0], rest[1:]) if stats else (None, rest))
+    columns alone)."""
+    buf, sem, acc, m_scr, l_scr = refs[-5:]
+    pools, o_ref = refs[:buf.shape[1]], refs[buf.shape[1]]
     b, rows = pl.program_id(0), pl.num_programs(0)
     pages = buf.shape[2]
     kb = pages * page
@@ -105,15 +166,15 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
     n_blocks = pl.cdiv(length, kb)
 
     def copies(r, j, half):
-        """``of(i)``: the two copies, K's and V's, of page i of row r's
-        key block j into ``half``."""
+        """``of(i)``: the copies, one a pool, of page i of row r's key
+        block j into ``half``."""
         first = r * width + j * pages
 
         def of(i):
             at = tab_ref[first + i]
             return [pltpu.make_async_copy(pool.at[layer, at],
                                           buf.at[half, n, i], sem.at[half])
-                    for n, pool in enumerate((k_ref, v_ref))]
+                    for n, pool in enumerate(pools)]
         return of
 
     def pages_of(r, j):
@@ -163,7 +224,8 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
 
     def attend(j, half):
         k = buf[half, 0].reshape(kb * group, buf.shape[-1])
-        v = buf[half, 1].reshape(kb * group, buf.shape[-1])
+        v = (buf[half, 1].reshape(kb * group, buf.shape[-1])
+             if len(pools) > 1 else k[:, :acc.shape[1]])
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -214,6 +276,7 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, k_ref, v_ref,
     lax.fori_loop(0, n_blocks, block, 0)
     o_ref[...] = (acc[...] / l_scr[...]).astype(o_ref.dtype)
     if stats:
+        lse_ref = refs[len(pools) + 1]
         lse_ref[...] = jnp.broadcast_to(m_scr[...] + jnp.log(l_scr[...]),
                                         lse_ref.shape)
 
@@ -264,8 +327,10 @@ def paged_decode(q, k_pool, v_pool, layer, tables, lengths, *,
             f"{v_pool.shape}, tables {tables.shape}, lengths {lengths.shape}")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    return _decode(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables,
-                   lengths, pages=key_block(page, tables.shape[1]) // page,
+    return _decode(q, (k_pool, v_pool), jnp.asarray(layer, jnp.int32),
+                   tables, lengths, name="hvd_paged_decode",
+                   scale=Dh ** -0.5, rank=tail[-1],
+                   pages=key_block(page, tables.shape[1]) // page,
                    interpret=interpret)
 
 
@@ -295,26 +360,58 @@ def paged_decode_stats(q, k_pool, v_pool, layer, tables, lengths, *,
             f"{lengths.shape}, key blocks of {key_positions}")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    return _decode(q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables,
-                   lengths,
+    return _decode(q, (k_pool, v_pool), jnp.asarray(layer, jnp.int32),
+                   tables, lengths, name="hvd_paged_decode",
+                   scale=Dh ** -0.5, rank=tail[-1],
                    pages=min(key_positions // page, tables.shape[1]),
                    interpret=interpret, stats=True)
 
 
-@functools.partial(jax.jit, static_argnames=("pages", "interpret", "stats"))
-def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
-            interpret: bool, stats: bool = False):
-    """The Pallas call, jitted of itself: a program of several full
-    layers traces and lowers the kernel once, not once a layer
-    (``ops/mamba_scan.py::_scan``). ``stats``: float32 out and the
-    logsumexp beside it (:func:`paged_decode_stats`)."""
+def latent_decode(q, pool, layer, tables, lengths, *, rank: int,
+                  scale: float, interpret: Optional[bool] = None):
+    """Absorbed latent attention of one query a row over the row's
+    pages: ``q`` ``[B, H, row]`` (``[q W_uk^T | q_rope]``, zeros from
+    ``rank + R`` on) against ``pool`` ``[layers, n_blocks, block_size,
+    row]`` at ``layer`` (traced, as :func:`paged_decode`'s), row b's
+    positions ``0 .. lengths[b] - 1`` (at least one: a length under 1 is
+    read as 1) in the pages ``tables[b]`` ``[B, W]`` names in order.
+    Returns ``[B, H, rank]`` in ``q``'s dtype: the softmax of ``scale *
+    q . latent`` over the row's positions, times the latents' first
+    ``rank`` values. :func:`paged_decode`'s kernel, copies and numerics
+    (a key block: 64 pages of 20 KB at a block of 16 rows of 640 bf16)
+    over the one pool, every score column below the length seen."""
+    B, H, row = q.shape
+    n_layers, n_pages, page, pool_row = pool.shape
+    if (pool_row != row or tables.shape[0] != B or lengths.shape != (B,)
+            or rank > row):
+        raise ValueError(
+            f"latent_decode: q {q.shape}, pool {pool.shape}, tables "
+            f"{tables.shape}, lengths {lengths.shape}, rank {rank}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    return _decode(q, (pool,), jnp.asarray(layer, jnp.int32), tables,
+                   lengths, name="hvd_latent_decode", scale=float(scale),
+                   rank=rank, pages=key_block(page, tables.shape[1]) // page,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "name", "scale", "rank", "pages", "interpret", "stats"))
+def _decode(q, pools, layer, tables, lengths, *, name: str, scale: float,
+            rank: int, pages: int, interpret: bool, stats: bool = False):
+    """The Pallas call ``name`` over ``pools`` (a tuple), jitted of
+    itself: a program of several such layers traces and lowers the
+    kernel once, not once a layer (``ops/mamba_scan.py::_scan``).
+    ``rank``: the columns of a value that are summed (K and V pages:
+    all). ``stats``: float32 out and the logsumexp beside it
+    (:func:`paged_decode_stats`)."""
     B, H, Dh = q.shape
-    n_layers, n_pages, page = k_pool.shape[:3]
-    tail, width = k_pool.shape[3:], tables.shape[1]
+    n_layers, n_pages, page = pools[0].shape[:3]
+    tail, width = pools[0].shape[3:], tables.shape[1]
     if len(tail) == 2:
         group, row = tail
-        k_pool, v_pool = (pool.reshape(n_layers, n_pages, page * group, row)
-                          for pool in (k_pool, v_pool))
+        pools = tuple(pool.reshape(n_layers, n_pages, page * group, row)
+                      for pool in pools)
     else:
         group, row = 1, tail[0]
     n_kv = row // Dh            # KV heads end to end in a row: 1 or Hkv
@@ -327,42 +424,46 @@ def _decode(q, k_pool, v_pool, layer, tables, lengths, *, pages: int,
     lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
     n_blocks = -(-lengths // (pages * page))
     first = jnp.cumsum(n_blocks) - n_blocks
-    wave = 2 * pages * page * group * row * k_pool.dtype.itemsize
+    wave = len(pools) * pages * page * group * row * pools[0].dtype.itemsize
     scores = H * pages * page * group * 4
-    row_spec = pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0))
+    out_spec = pl.BlockSpec((None, H, rank), lambda b, *_: (b, 0, 0))
     o = pl.pallas_call(
-        functools.partial(_kernel, scale=Dh ** -0.5, width=width, page=page,
+        functools.partial(_kernel, scale=scale, width=width, page=page,
                           group=group, stats=stats),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B,),
-            in_specs=[
-                pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=([row_spec, pl.BlockSpec((None, H, 128),
+            in_specs=[pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=([out_spec, pl.BlockSpec((None, H, 128),
                                                lambda b, *_: (b, 0, 0))]
-                       if stats else row_spec),
+                       if stats else out_spec),
             scratch_shapes=[
-                pltpu.VMEM((2, 2, pages, page * group, row), k_pool.dtype),
+                pltpu.VMEM((2, len(pools), pages, page * group, row),
+                           pools[0].dtype),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((H, row), jnp.float32),
+                pltpu.VMEM((H, rank), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
             ]),
-        out_shape=([jax.ShapeDtypeStruct((B, H, row), jnp.float32),
+        out_shape=([jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
                     jax.ShapeDtypeStruct((B, H, 128), jnp.float32)]
-                   if stats else jax.ShapeDtypeStruct((B, H, row), q.dtype)),
+                   if stats else jax.ShapeDtypeStruct((B, H, rank), q.dtype)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            # both halves of the buffer and the float32 tiles of a key
-            # block's scores, with room for what the compiler keeps
-            vmem_limit_bytes=2 * wave + 8 * scores + (16 << 20)),
+            # K and V pages: both halves of the buffer and the float32
+            # tiles of a key block's scores, with room for what the
+            # compiler keeps. The one pool of latents fits the default
+            # and asks for nothing: what a call asks for XLA cannot
+            # prefetch into around it, and asking for these 20.7 MB
+            # moved the schedule of Kimi's whole decode program and the
+            # last bits of its logits (builder, PR 58)
+            vmem_limit_bytes=(2 * wave + 8 * scores + (16 << 20)
+                              if len(pools) > 1 else None)),
         interpret=interpret,
-        name="hvd_paged_decode",
+        name=name,
     )(layer.reshape(1), lengths, first.astype(jnp.int32),
-      tables.astype(jnp.int32).reshape(-1), q, k_pool, v_pool)
+      tables.astype(jnp.int32).reshape(-1), q, *pools)
     if stats:
         return o[0], o[1][:, :, 0]
     if n_kv > 1:

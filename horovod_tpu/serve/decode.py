@@ -91,9 +91,10 @@ Pallas flash forward over keys that carry their positions
 (``ops/flash_attention.py::flash_attention_keys``, the kernel
 ``hvd_flash_keys_fwd``), so that a chunk's scores are tiles in VMEM
 too. A decode step's attend ABSORBED (:func:`_mla_decode`), each row
-over its own pages where they lie in the pool, through a Pallas kernel
-of its own (``ops/latent_decode.py``, ``hvd_latent_decode``): no key
-block is gathered and no score reaches HBM. The absorbed form in XLA
+over its own pages where they lie in the pool, through the same kernel
+body over the one pool (``ops/paged_decode.py::latent_decode``, the
+Pallas call ``hvd_latent_decode``): no key block is gathered and no
+score reaches HBM. The absorbed form in XLA
 (``tests/reference_mla.py``) is what the tests hold both to. Nothing
 chooses between the two but which program calls. An ``eva`` layer
 (an exact aligned window beside one attended summary a chunk of every
@@ -124,8 +125,8 @@ from horovod_tpu.ops.flash_attention import (flash_attention,
 from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
-from horovod_tpu.ops.latent_decode import latent_decode
-from horovod_tpu.ops.paged_decode import paged_decode, paged_decode_stats
+from horovod_tpu.ops.paged_decode import (latent_decode, paged_decode,
+                                          paged_decode_stats)
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import (NULL_BLOCK, latent_row, page_tail,
                                         state_kinds)
@@ -1172,10 +1173,11 @@ def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions):
     """A decode step's latent attention, absorbed, for one query a row
     (``qn`` [B, 1, H, Dh], ``qr`` [B, 1, H, R]) at ``positions`` [B],
     over the pages of layer ``c`` of the latent ``pool`` behind
-    ``tables`` [B, W], read where they lie by ``ops/latent_decode.py``
-    (``hvd_latent_decode`` in a device trace): each row's own pages,
-    once, no further than its position, with no gathered copy of a key
-    block and no score tensor in HBM. The two small products stay in
+    ``tables`` [B, W], read where they lie by
+    ``ops/paged_decode.py::latent_decode`` (``hvd_latent_decode`` in a
+    device trace): each row's own pages, once, no further than its
+    position, with no gathered copy of a key block and no score tensor
+    in HBM. The two small products stay in
     XLA around the call: ``q W_uk^T`` before it and ``(sum p c) W_uv``
     after it. Returns [B, 1, H, Dh]."""
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)

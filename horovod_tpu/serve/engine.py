@@ -94,7 +94,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from horovod_tpu.ops.latent_decode import key_block as latent_key_block
+from horovod_tpu.ops.paged_decode import key_block
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
     NULL_SLOT, SLOT_KINDS, BlockAllocator, hash_chain,
@@ -481,7 +481,7 @@ class ServeEngine:
         # What a decode call's latent attention reads is counted from
         # the positions it is given (metrics.record_latent_decode).
         self._latent_layers = model_cfg.n_layers_of("mla")
-        self._latent_key_block = latent_key_block(bs, self._table_width)
+        self._latent_key_block = key_block(bs, self._table_width)
         # ... and the pages its full layers read, where they are one
         # kind among several (metrics.record_paged_decode).
         self._paged_layers = (model_cfg.n_layers_of("full")

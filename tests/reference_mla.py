@@ -1,8 +1,8 @@
 """The absorbed form of latent attention in XLA: the tests' reference
 for ``serve/decode.py::_mla_attend`` (a chunk, expanded, through the
 Pallas forward over keys that carry their positions) and for
-``_mla_decode`` (a decode step, through ``ops/latent_decode.py``'s
-kernel). No serve program runs it."""
+``_mla_decode`` (a decode step, through
+``ops/paged_decode.py::latent_decode``). No serve program runs it."""
 
 import jax.numpy as jnp
 from jax import lax

@@ -1061,8 +1061,8 @@ def test_a_chunk_s_latent_attention_is_the_keys_kernel_on_v5e():
     ``attn/attn_mla/mla_attend``, in the loop over key blocks, and
     holds no float32 ``[32, 1024, 1024]`` tensor (a key
     block's scores, 134 MB, and the softmax's passes over them: what the
-    einsum form wrote); a decode step has a kernel of its own (ISSUE
-    45: ``hvd_latent_decode``, below), under the same scope."""
+    einsum form wrote); a decode step has a Pallas call of its own
+    (ISSUE 45: ``hvd_latent_decode``, below), under the same scope."""
     out = _compile_for_v5e(_LING_DRIVER)
     chunk, step = out["prefill_resume"], out["decode"]
     assert chunk["keys_kernel_paths"] == [
@@ -1072,7 +1072,8 @@ def test_a_chunk_s_latent_attention_is_the_keys_kernel_on_v5e():
     assert chunk["latent_decode_calls"] == 0, chunk
     assert step["keys_kernel_paths"] == [], step
     assert step["latent_decode_paths"] == [
-        "jit(decode)/attn/attn_mla/mla_attend/hvd_latent_decode"], step
+        "jit(decode)/attn/attn_mla/mla_attend/jit(_decode)/"
+        "hvd_latent_decode"], step
 
 
 @pytest.mark.parametrize("shapes", ["ling", "kimi"])
@@ -1094,7 +1095,8 @@ def test_a_decode_step_s_latent_attention_reads_the_pool_where_it_lies(
     got = out["decode"]
     assert got["latent_decode_calls"] == layers, got
     assert got["latent_decode_paths"] == [
-        "jit(decode)/attn/attn_mla/mla_attend/hvd_latent_decode"], got
+        "jit(decode)/attn/attn_mla/mla_attend/jit(_decode)/"
+        "hvd_latent_decode"], got
     assert got["gathered_key_blocks"] == 0, got
     assert got["scores_of_a_decode_key_block"] == 0, got
     assert got["aliased"] == large, got

@@ -33,9 +33,9 @@ among rows of 256 to 3072: the Ling cell's reasoning requests). ms a
 layer of the XLA form (``tests/reference_mla.py`` over
 ``mla_pages`` to the longest row: all a decode step had before ISSUE
 45), of ``_mla_decode`` as it is (the two absorbed products around
-``ops/latent_decode.py``'s kernel) at each ``--key-blocks``, and of the
-kernel alone with the GB/s of the pages it reads; and the largest
-difference of the two forms relative to the largest magnitude.
+``ops/paged_decode.py::latent_decode``) at each ``--key-blocks``, and
+of the kernel alone with the GB/s of the pages it reads; and the
+largest difference of the two forms relative to the largest magnitude.
 
 ``--paged-decode`` sweeps a full layer's decode step (ISSUE 55) at the
 three page shapes the cells have: ``lfm2`` (128 rows of 256-2560
@@ -46,7 +46,8 @@ pages ``[16, 1, 128]``, 20 heads over one), the lengths log-uniform,
 the tables in shuffled order. ms a layer of the XLA form
 (``serve/decode.py::_attend_keys`` over every row's whole table,
 gathered: all a decode step had before ISSUE 55) and of
-``ops/paged_decode.py``'s kernel at each ``--key-blocks``, with the GB/s
+``ops/paged_decode.py::paged_decode`` (the same kernel body: ISSUE 58)
+at each ``--key-blocks``, with the GB/s
 of the K and V pages it reads; and the largest difference of the two
 relative to the largest magnitude.
 
@@ -98,7 +99,6 @@ from jax import lax  # noqa: E402
 from horovod_tpu.models import TransformerConfig  # noqa: E402
 from horovod_tpu.models import transformer as tf_lib  # noqa: E402
 from horovod_tpu.ops import flash_attention as flash_lib  # noqa: E402
-from horovod_tpu.ops import latent_decode as latent_lib  # noqa: E402
 from horovod_tpu.ops import paged_decode as paged_lib  # noqa: E402
 from horovod_tpu.ops import sparse_scores as scores_lib  # noqa: E402
 from horovod_tpu.ops.flash_attention import flash_attention  # noqa: E402
@@ -291,7 +291,7 @@ def latent_sweep(args) -> None:
 def latent_decode_sweep(args) -> None:
     LATENT, RANK, ROPE, DH, PAGE, WIDTH = 640, 512, 64, 128, 16, 1088
     rng = np.random.default_rng(0)
-    default_wave = latent_lib._wave_pages
+    default_wave = paged_lib._wave_pages
     for heads in args.heads:
         cfg = TransformerConfig(
             vocab_size=128, d_model=128, n_layers=1, n_heads=heads,
@@ -346,7 +346,7 @@ def latent_decode_sweep(args) -> None:
                 want = chain(*xs).astype(jnp.float32)
                 for kb in args.key_blocks or [None]:
                     name = "" if kb is None else f"_kb{kb}"
-                    latent_lib._wave_pages = (
+                    paged_lib._wave_pages = (
                         default_wave if kb is None
                         else lambda page, kb=kb: kb // page)
                     chain, xs = chained(kernel)
@@ -365,7 +365,7 @@ def latent_decode_sweep(args) -> None:
                     @jax.jit
                     def alone(q, pool, tables, positions):
                         return lax.scan(
-                            lambda q, _: (jnp.pad(latent_lib.latent_decode(
+                            lambda q, _: (jnp.pad(paged_lib.latent_decode(
                                 q, pool, 0, tables, positions + 1, rank=RANK,
                                 scale=0.07), ((0, 0), (0, 0),
                                               (0, LATENT - RANK))), None),
@@ -375,7 +375,7 @@ def latent_decode_sweep(args) -> None:
                     row["alone" + name] = round(ms, 4)
                     row["alone_gb_s" + name] = round(
                         pages_read * PAGE * LATENT * 2 / ms / 1e6, 1)
-                latent_lib._wave_pages = default_wave
+                paged_lib._wave_pages = default_wave
                 print(json.dumps(row), flush=True)
 
 

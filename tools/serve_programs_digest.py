@@ -40,7 +40,10 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          # (hvd_paged_decode: a step's full layers), their chunks did not;
          # this one's three programs changed with PR 57 (hvd_grouped_matmul:
          # the whole mixture's grouped products), no other cell's did
-         ("lfm2-8b-a1b-14l", "assistant-backlog")]
+         ("lfm2-8b-a1b-14l", "assistant-backlog"),
+         # the one caller of paged_decode_stats (an eva layer's step: the
+         # kernel twice, over the window's rows and the summaries' pages)
+         ("evabyte-6.5b-8l", "bytedoc-backlog")]
 
 
 def i32(*shape):
