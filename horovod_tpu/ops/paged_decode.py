@@ -7,10 +7,14 @@ and lengths as prefetched scalars, a key block's pages by asynchronous
 copies into one half of a double buffer while the other half is
 attended, one running softmax). ONE kernel body, two Pallas calls of it:
 
-``hvd_paged_decode`` (:func:`paged_decode`, :func:`paged_decode_stats`)
+``hvd_paged_decode`` (:func:`paged_decode`, :func:`paged_decode_stats`,
+:func:`ring_decode`)
     a ``full`` layer's K and V pages, two pools. The XLA form it
     replaced (``serve/decode.py``: ``_attend_keys`` over every row's
-    whole table, gathered) is the tests' reference.
+    whole table, gathered) is the tests' reference. A window layer's
+    rings are such pools too (:func:`ring_decode`: a slot's ring as
+    pages, the table arithmetic, a row's window from the middle of its
+    first page on), against ``_attend_keys`` over whole rings.
 ``hvd_latent_decode`` (:func:`latent_decode`)
     an ``mla`` layer's latents, one pool: the keys are the values (one
     latent a position: all of it scored, its first ``rank`` summed), so
@@ -69,6 +73,32 @@ def _wave_pages(block_size: int) -> int:
     % ahead at trinity's shapes and 4 % behind at lfm2's. (With a
     block's offset into the tables taken once and not once a page, as
     it stands: 0.546, 0.749 and 0.526 ms at 1024.)
+
+    **Rings.** A window layer's step (:func:`ring_decode`; on the v5e,
+    2026-10-03, builder, PR 59, ``--paged-decode --cells ring trinity``
+    in one call: 32 rows of 48 / 8 heads at positions log-uniform
+    1024..8576 in 33 slots' rings of 5136 places under a window of 4096,
+    11 rows past the window and 8 past the ring, 98 399 visible
+    positions; ``xla`` is ``_attend_keys`` over every slot's whole ring,
+    694 MB a layer, WITHOUT the slices of the stacked cache in front of
+    it, which were 1.6 ms each and eight a step in the cell), at a ring
+    read as pages of **16** / 48 positions (the two multiples of the
+    block that divide 5136 = 16 * 3 * 107):
+
+    ==== ==== ======== ====== ==== ===== ===================== =========
+    cell rows visible  tables H    xla   kernel alone, ms      GB/s
+    ==== ==== ======== ====== ==== ===== ===================== =========
+    ring 32   1 .. 4 k 257/87 48/8 1.304 **0.6718** / 0.6734   602 / 605
+    ==== ==== ======== ====== ==== ===== ===================== =========
+
+    (6 171 pages of 16 or 2 072 of 48 of each ring: 404 and 407 MB; the
+    ``trinity`` row above read 0.8919 ms and 653.6 GB/s in the same
+    call.) A page of 48 is a third of the copies at three times the
+    bytes and is level to a quarter of a percent: at 32 KB a page pair
+    the memory bounds the copy, not its issue (the table above), so
+    :func:`ring_page` is the block and a ring's call is the very
+    instance of the kernel the ``full`` layers run. The masked first
+    page (``skip``) costs one compare a score tile.
 
     **Latents.** On the v5e (``tools/prefill_attn_sweep.py
     --latent-decode``: bf16 queries ``[rows, H, 640]`` over a pool of
@@ -129,9 +159,9 @@ def key_block(block_size: int, table_width: int) -> int:
     return min(_wave_pages(block_size), table_width) * block_size
 
 
-def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, *refs,
+def _kernel(layer_ref, len_ref, first_ref, tab_ref, *refs,
             scale: float, width: int, page: int, group: int,
-            stats: bool = False):
+            stats: bool = False, skips: bool = False):
     """Row ``b`` of the batch (one grid step): its key blocks in a
     loop, block ``j`` waited for in one half of ``buf`` (pool ``n``'s
     pages at ``[half, n]``) while the pages of the next (the row's, or
@@ -144,7 +174,11 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, *refs,
     for at once; a block at a row's end takes a loop over the pages it
     has.
 
-    ``refs``: the pools (as many as ``buf`` has at ``[half]``: K and V,
+    ``refs``: with ``skips`` one more prefetched scalar a row first (the
+    positions at the head of the row's first page that are copied with
+    it and NOT seen: a ring's window begins in the middle of a page,
+    :func:`ring_decode`; ``len_ref[b]`` counts them), then the queries,
+    the pools (as many as ``buf`` has at ``[half]``: K and V,
     or the one of latents, whose keys are the values, their first
     ``acc``-wide columns), the output, with ``stats`` one more (the
     softmax's logsumexp a head, along the lanes of a ``[H, 128]`` tile,
@@ -157,6 +191,9 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, *refs,
     ``Hkv``: column ``c`` of the scores is position ``c // group`` under
     KV head ``c % group``, and a query head sees its own KV head's
     columns alone)."""
+    if skips:
+        skip_ref, *refs = refs
+    q_ref, *refs = refs
     buf, sem, acc, m_scr, l_scr = refs[-5:]
     pools, o_ref = refs[:buf.shape[1]], refs[buf.shape[1]]
     b, rows = pl.program_id(0), pl.num_programs(0)
@@ -229,7 +266,10 @@ def _kernel(layer_ref, len_ref, first_ref, tab_ref, q_ref, *refs,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        seen = j * kb + col // group < length
+        at = j * kb + col // group
+        seen = at < length
+        if skips:
+            seen &= at >= skip_ref[b]
         if group > 1:
             head = lax.broadcasted_iota(jnp.int32, s.shape, 0)
             seen &= col % group == head // (s.shape[0] // group)
@@ -367,6 +407,78 @@ def paged_decode_stats(q, k_pool, v_pool, layer, tables, lengths, *,
                    interpret=interpret, stats=True)
 
 
+def ring_page(ring: int, block_size: int) -> int:
+    """Positions :func:`ring_decode` reads a ring's places as one page:
+    ``block_size``, the page of the ``full`` layers' pools (``ring`` is
+    a whole number of them, ``kv_cache.ring_width``), so that the call
+    is the instance of the kernel those layers run. A larger divisor of
+    the ring (48 of 5136) was level on the chip: :func:`_wave_pages`."""
+    if ring % block_size:
+        raise ValueError(f"a ring of {ring} is not whole blocks of "
+                         f"{block_size}")
+    return block_size
+
+
+def ring_reach(positions, window: int, ring: int):
+    """``(at, n_vis)`` of rows at ``positions`` (an int32 array, jax's
+    or numpy's) in rings of ``ring`` places under a ``window``: the
+    place of the first key a row sees and how many it sees, up to its
+    own. In pages of ``page`` the row reads ``ceil((at % page + n_vis)
+    / page)`` of them (:func:`ring_decode`; the engine's counters)."""
+    n_vis = (positions + 1).clip(max=min(window, ring))
+    return (positions + 1 - n_vis) % ring, n_vis
+
+
+def ring_decode(q, k_rings, v_rings, layer, slots, positions, *,
+                window: int, page: int, interpret: Optional[bool] = None):
+    """A window layer's decode step, each row over its own slot's ring
+    where it lies: ``q`` ``[B, H, Dh]`` against ``k_rings`` and
+    ``v_rings`` ``[layers, n_slots, ring, Hkv, Dh]`` at ``layer``, row b
+    at position ``positions[b]`` of the sequence in slot ``slots[b]``
+    (position p lies at place ``p % ring``, already written), over the
+    ``min(p + 1, window)`` positions up to its own (a ring narrower
+    than the window holds ``ring`` of them, and is the window). Returns
+    ``[B, H, Dh]`` in ``q``'s dtype, :func:`paged_decode`'s kernel and
+    numerics.
+
+    The rings are read as a pool of ``n_slots * ring // page`` pages of
+    ``page`` positions (the same bytes: ``page`` divides ``ring``), and
+    a row's table is arithmetic: from the page that holds its first
+    visible position ``j0 = p + 1 - min(p + 1, window)``, at place ``a =
+    j0 % ring``, the ring's pages in order, round its end: ``slot * P +
+    (a // page + t) % P``. The visible keys are then the table's
+    positions ``skip .. skip + n_vis - 1`` in order, ``skip = a %
+    page``, whether or not the window wraps; the first ``skip`` are
+    copied with their page and masked (the kernel's one more scalar a
+    row). A table is ``ceil((window + page - 1) / page)`` pages wide,
+    not the ring's: places outside the window are never addressed, and
+    a row reads ``ceil((skip + n_vis) / page)`` pages of each ring."""
+    B, H, Dh = q.shape
+    n_layers, n_slots, ring = k_rings.shape[:3]
+    if (v_rings.shape != k_rings.shape or k_rings.ndim != 5 or ring % page
+            or k_rings.shape[4] != Dh or H % k_rings.shape[3]
+            or slots.shape != (B,)
+            or positions.shape != (B,)):
+        raise ValueError(
+            f"ring_decode: q {q.shape}, rings {k_rings.shape} and "
+            f"{v_rings.shape}, slots {slots.shape}, positions "
+            f"{positions.shape}, a window of {window} in pages of {page}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    per = ring // page
+    width = -(-(min(window, ring) + page - 1) // page)
+    at, n_vis = ring_reach(positions.astype(jnp.int32), window, ring)
+    skip = at % page
+    tables = slots[:, None] * per + (
+        at[:, None] // page + jnp.arange(width, dtype=jnp.int32)) % per
+    return _decode(
+        q, tuple(r.reshape(n_layers, n_slots * per, page, *r.shape[3:])
+                 for r in (k_rings, v_rings)),
+        jnp.asarray(layer, jnp.int32), tables, skip + n_vis, skip,
+        name="hvd_paged_decode", scale=Dh ** -0.5, rank=Dh,
+        pages=key_block(page, width) // page, interpret=interpret)
+
+
 def latent_decode(q, pool, layer, tables, lengths, *, rank: int,
                   scale: float, interpret: Optional[bool] = None):
     """Absorbed latent attention of one query a row over the row's
@@ -397,14 +509,19 @@ def latent_decode(q, pool, layer, tables, lengths, *, rank: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "name", "scale", "rank", "pages", "interpret", "stats"))
-def _decode(q, pools, layer, tables, lengths, *, name: str, scale: float,
-            rank: int, pages: int, interpret: bool, stats: bool = False):
+def _decode(q, pools, layer, tables, lengths, skip=None, *, name: str,
+            scale: float, rank: int, pages: int, interpret: bool,
+            stats: bool = False):
     """The Pallas call ``name`` over ``pools`` (a tuple), jitted of
     itself: a program of several such layers traces and lowers the
     kernel once, not once a layer (``ops/mamba_scan.py::_scan``).
     ``rank``: the columns of a value that are summed (K and V pages:
     all). ``stats``: float32 out and the logsumexp beside it
-    (:func:`paged_decode_stats`)."""
+    (:func:`paged_decode_stats`). ``skip`` [B]: the positions at the
+    head of each row's first page that ``lengths`` counts and the row
+    does not see (:func:`ring_decode`); None, and no fifth scalar, for
+    a caller whose rows begin with their pages: its kernel is then text
+    for text what it was without the notion."""
     B, H, Dh = q.shape
     n_layers, n_pages, page = pools[0].shape[:3]
     tail, width = pools[0].shape[3:], tables.shape[1]
@@ -429,9 +546,9 @@ def _decode(q, pools, layer, tables, lengths, *, name: str, scale: float,
     out_spec = pl.BlockSpec((None, H, rank), lambda b, *_: (b, 0, 0))
     o = pl.pallas_call(
         functools.partial(_kernel, scale=scale, width=width, page=page,
-                          group=group, stats=stats),
+                          group=group, stats=stats, skips=skip is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=4 + (skip is not None),
             grid=(B,),
             in_specs=[pl.BlockSpec((None, H, row), lambda b, *_: (b, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -463,7 +580,8 @@ def _decode(q, pools, layer, tables, lengths, *, name: str, scale: float,
         interpret=interpret,
         name=name,
     )(layer.reshape(1), lengths, first.astype(jnp.int32),
-      tables.astype(jnp.int32).reshape(-1), q, *pools)
+      tables.astype(jnp.int32).reshape(-1),
+      *(() if skip is None else (skip.astype(jnp.int32),)), q, *pools)
     if stats:
         return o[0], o[1][:, :, 0]
     if n_kv > 1:

@@ -76,11 +76,13 @@ to 512, where that tensor is small and XLA's fusions of it are the
 faster, in the dense form.
 
 The programs of a configuration with layers of several kinds
-(:func:`mixed_programs`) attend through :func:`_attend_keys` (window
-layers and a full layer's chunks: keys that carry their positions, in
-XLA; a full layer's decode STEP reads each row's own K and V pages
-where they lie in the pools, to the row's length, through the Pallas
-kernel of ``ops/paged_decode.py``, ``hvd_paged_decode``),
+(:func:`mixed_programs`) attend through :func:`_attend_keys` (a window
+layer's and a full layer's CHUNKS: keys that carry their positions, in
+XLA; a decode STEP of either reads its keys where they lie through the
+Pallas kernel of ``ops/paged_decode.py``, ``hvd_paged_decode``: a full
+layer each row's own K and V pages in the pools, to the row's length,
+a window layer each row's own slot's ring in the stacked rings, from
+the first key its window admits to its own position),
 :func:`kda_scan` / :func:`kda_step` and :func:`mamba_scan` /
 :func:`mamba_step` (a recurrent state a batch slot: a delta rule, and
 Mamba-1's selective scan, both in XLA) and latent attention, in two
@@ -126,7 +128,8 @@ from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops.paged_decode import (latent_decode, paged_decode,
-                                          paged_decode_stats)
+                                          paged_decode_stats, ring_decode,
+                                          ring_page)
 from horovod_tpu.parallel.ring_attention import local_attention
 from horovod_tpu.serve.kv_cache import (NULL_BLOCK, latent_row, page_tail,
                                         state_kinds)
@@ -532,8 +535,9 @@ def _cached_serve_fns(cfg, mesh, block_size: int, table_width: int,
 def _attend_keys(q, keys, vals, key_pos, pos, window):
     """Attention of a query chunk per sequence over keys that each
     carry the position they hold: the one attention of the mixed
-    programs, for a prompt over itself, a block table's pages and a
-    window layer's ring.
+    programs' chunks, for a prompt over itself, a block table's pages
+    and a window layer's ring (no decode step's since ISSUE 59: the
+    tests hold ``ops/paged_decode.py``'s calls to it with C = 1).
 
     ``q`` [B, C, H, Dh]; ``keys``/``vals`` [B, S, Hkv, Dh] in the
     cache's dtype; ``key_pos`` [B, S] the position each key holds
@@ -1702,28 +1706,22 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                          rows.dtype).at[call.slots].set(rows)
 
     def window_step(call, lp, kc, vc, c, x, i):
+        """Each row over its own slot's ring where it lies in the
+        stacked cache, from the first key its window admits to its own
+        position (``ops/paged_decode.py::ring_decode``: the rings read
+        as pages, ``hvd_paged_decode`` under ``attn_window``): no ring
+        is sliced out of the cache and no score reaches HBM."""
         def write(cache, new):
             return put(cache, "sliding",
                        (c, call.slots, call.positions % ring),
                        new.reshape(-1, Hkv, Dh))
 
         def attend(q, k, v, kc, vc):
-            # Every ring of the layer where it lies, the queries
-            # carried to their slots and the results back: the rings
-            # are then read once, by the two dots, and not gathered
-            # first into a copy the size of the batch's share of them
-            # (a gather through (layer, slots) that the compiler made
-            # of all layers' rings in slabs, at a twelfth of the memory
-            # bandwidth). A slot that is not in the batch has written
-            # nothing (frontier 0): every key of its ring is refused
-            # and its row is not read back.
             n = place["sliding"]
-            n_slots = kc[n].shape[1]
-            at = by_slot(call, call.pos + 1, n_slots)               # [S, 1]
-            o = _attend_keys(by_slot(call, q, n_slots), kc[n][c], vc[n][c],
-                             ring_positions(at[:, 0], ring), at - 1,
-                             window)
-            return o[call.slots]
+            o = ring_decode(q[:, 0], kc[n], vc[n], c, call.slots,
+                            call.positions, window=window,
+                            page=ring_page(ring, block_size))
+            return o.reshape(o.shape[0], 1, H * Dh)
         return softmax_layer(call, lp, kc, vc, x, i, "attn_window", write,
                              attend)
 
@@ -1748,9 +1746,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     def kda_step_layer(call, lp, kc, vc, c, x, i):
         """One step of the recurrence on every slot's state where it
         lies, the batch's rows carried to their slots and the results
-        back (as the window layers' rings are read): a slot that is not
-        in the batch decays by 1 and is written by 0, so its state is
-        what it was."""
+        back: a slot that is not in the batch decays by 1 and is
+        written by 0, so its state is what it was."""
         n = place["kda"]
         with jax.named_scope("attn_kda"):
             h, rows = tf_lib.kda_rows(cfg, lp, x)

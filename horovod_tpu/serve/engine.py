@@ -94,7 +94,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from horovod_tpu.ops.paged_decode import key_block
+from horovod_tpu.ops.paged_decode import key_block, ring_page
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
     NULL_SLOT, SLOT_KINDS, BlockAllocator, hash_chain,
@@ -524,6 +524,10 @@ class ServeEngine:
                            cfg.prefill_chunk or max(self._prefill_buckets),
                            bs)
                 if model_cfg.n_window_layers else 0)
+        # ... of which a decode call reads pages, counted as the full
+        # layers' are (metrics.record_window_decode).
+        self._window_layers = model_cfg.n_window_layers
+        self._ring_page = ring_page(ring, bs) if ring else 0
         self._free_slots = list(range(cfg.max_batch, 0, -1))
         self.cache = init_kv_cache(model_cfg, n_blocks, bs, mesh=mesh,
                                    dtype=cfg.cache_dtype,
@@ -1515,6 +1519,11 @@ class ServeEngine:
         if self._paged_layers:
             m.record_paged_decode(positions + 1, self.cfg.block_size,
                                   self._table_width, self._paged_layers)
+        if self._window_layers:
+            m.record_window_decode(
+                positions + 1, self.model_cfg.attn_window, self.cache.ring,
+                self._ring_page, self.cfg.max_batch + 1,
+                self._window_layers)
         if prev is not None:
             m.record_decode_ahead()
         elif out.committed:

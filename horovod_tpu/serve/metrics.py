@@ -68,6 +68,7 @@ from jax.profiler import TraceAnnotation
 
 from horovod_tpu.common.compile_cache import compile_stats
 from horovod_tpu.models.moe import moe_metrics
+from horovod_tpu.ops.paged_decode import ring_reach
 
 #: Keep at most this many latency samples per series (drop-oldest);
 #: long-running engines must not grow without bound.
@@ -403,6 +404,12 @@ class ServeMetrics:
         # what every row's whole table would have been.
         self.paged_decode_pages_total = 0
         self.paged_decode_pages_table_total = 0
+        # Pages of the window layers' K rings (V's are as many) that the
+        # decode calls' kernel read, every row from the page of its
+        # window's first key to its own position, and what every
+        # slot's whole ring holds.
+        self.window_decode_pages_total = 0
+        self.window_decode_pages_ring_total = 0
         self.queue_depth = 0
         self.max_queue_depth = 0
         self.stalls_total = 0
@@ -764,6 +771,21 @@ class ServeMetrics:
         self.paged_decode_pages_table_total += (
             layers * len(lengths) * table_width)
 
+    def record_window_decode(self, lengths, window: int, ring: int,
+                             page: int, n_slots: int, layers: int) -> None:
+        """A decode call was launched whose rows hold ``lengths``
+        positions (an array: every row of the call, a padded row at 1)
+        of which each sees its newest ``window``, in rings of ``ring``
+        places read as pages of ``page`` (``ops/paged_decode.py::
+        ring_decode``: the first page a row reads is the one that holds
+        its window's first key), ``n_slots`` rings in each of ``layers``
+        window layers."""
+        at, seen = ring_reach(lengths - 1, window, ring)
+        self.window_decode_pages_total += layers * int(
+            (-(-(at % page + seen) // page)).sum())
+        self.window_decode_pages_ring_total += (
+            layers * n_slots * (ring // page))
+
     def record_idle(self) -> None:
         """The engine ran out of work: the wait for the next request is
         nobody's host gap, and the time the device goes unfed is the
@@ -953,6 +975,12 @@ class ServeMetrics:
             "paged_decode_pages_total": self.paged_decode_pages_total,
             "paged_decode_pages_table_total":
                 self.paged_decode_pages_table_total,
+            # pages of the K rings the decode calls' window layers
+            # read, a row no further back than its window, and what
+            # the slots' whole rings hold (zeros without window layers)
+            "window_decode_pages_total": self.window_decode_pages_total,
+            "window_decode_pages_ring_total":
+                self.window_decode_pages_ring_total,
             # of the whole mixture's grouped products this process
             # traced, the share that took ops/grouped_matmul.py's kernel
             # (models/moe.py counts it as it traces; 0.0 without any)

@@ -2,7 +2,10 @@
 ``hvd_paged_decode`` (``ops/paged_decode.py``, interpret mode here)
 against the XLA form it replaced in ``full_step``: ``_attend_keys`` over
 every row's whole table, gathered out of the same pools through the
-same tables (ISSUE 55)."""
+same tables (ISSUE 55); and a window layer's through the same kernel
+over each slot's ring where it lies (``ring_decode``, ISSUE 59) against
+what ``window_step`` ran before it: ``_attend_keys`` over whole rings
+under ``ring_positions`` and the window."""
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +106,86 @@ def test_the_counters_say_what_whole_tables_would_have_held(lengths, read):
     assert snap["paged_decode_pages_table_total"] == 2 * 3 * 4 * 160
     assert paged_lib.key_block(16, 160) == 1024
     assert paged_lib.key_block(16, 9) == 144         # a table under a wave
+
+
+#: a ring of ten pages of 4 under a window of 24 (a chunk of 12 beside
+#: it, ``kv_cache.ring_width``): tables of 7 pages, key blocks of 2
+RING_PAGE, WINDOW, RING = 4, 24, 40
+
+#: the positions ``p + 1`` written of each row's sequence; the row's slot
+#: is its place in this list counted from the END (slots out of order),
+#: and every batch gains a padded row in front (position 0, slot 0)
+FRONTIERS = {"one": [1],
+             "under_a_page": [RING_PAGE - 1],
+             "exactly_the_window": [WINDOW],
+             "between_window_and_ring": [WINDOW + 6, WINDOW + 5, RING],
+             "just_past_the_ring": [RING + 1, RING + 3, RING + WINDOW - 1],
+             "a_multiple_of_the_ring": [2 * RING, 3 * RING],
+             "slots_out_of_order": [7, 2 * RING + 9, WINDOW + 1, 31, 1]}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(FRONTIERS))
+def test_a_ring_is_read_where_it_lies_no_further_back_than_the_window(
+        case, dtype, monkeypatch):
+    """Every row's result out of rings of two layers, read at layer 1,
+    against ``_attend_keys`` over each row's whole ring under
+    ``ring_positions`` and the window (``window_step`` before ISSUE 59).
+    Every page of a slot's ring that holds no position its row sees is
+    NaN in the rings the kernel reads (the XLA form reads them clean),
+    so a page read outside the window, or in another slot, shows."""
+    H, n_kv, Dh = 8, 2, 128
+    frontiers = np.asarray([1] + FRONTIERS[case])
+    B = len(frontiers)
+    slots = np.asarray([0] + list(range(B - 1, 0, -1)))
+    n_slots, per = B + 1, RING // RING_PAGE       # a slot nobody holds
+    ks = jax.random.split(jax.random.PRNGKey(B), 3)
+    rings = [jax.random.normal(k, (2, n_slots, RING, n_kv, Dh)).astype(dtype)
+             for k in ks[:2]]
+    q = jax.random.normal(ks[2], (B, H, Dh)).astype(dtype)
+    live = np.zeros((n_slots, per), bool)
+    for slot, n in zip(slots, frontiers):
+        seen = np.arange(max(0, n - WINDOW), n) % RING
+        live[slot, np.unique(seen // RING_PAGE)] = True
+    poisoned = [jnp.where(np.repeat(live, RING_PAGE, 1)[None, :, :, None,
+                                                         None], r, jnp.nan)
+                for r in rings]
+    slots, positions = (jnp.asarray(a, jnp.int32)
+                        for a in (slots, frontiers - 1))
+
+    want = decode_lib._attend_keys(
+        q[:, None], *(r[1, slots] for r in rings),
+        decode_lib.ring_positions(positions + 1, RING), positions[:, None],
+        WINDOW).reshape(B, H, Dh)
+    monkeypatch.setattr(paged_lib, "_wave_pages", lambda page: WAVE)
+    got = paged_lib.ring_decode(q, *poisoned, jnp.int32(1), slots, positions,
+                                window=WINDOW, page=RING_PAGE)
+    assert got.shape == want.shape == (B, H, Dh) and got.dtype == q.dtype
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()
+    limit = 1e-5 if dtype == "float32" else 2e-2
+    assert gap(got, want) < limit
+
+
+@pytest.mark.parametrize("frontiers, read", [
+    ([1, 16, 17, 4096], 1 + 1 + 2 + 256),           # all inside the window
+    ([4097, 4111, 4112], 257 + 257 + 256),          # a window from mid-page
+    ([5137, 5136 + 4096], 257 + 256),               # round the ring's end
+    ([1, 1, 1, 1], 4)])                             # padded rows alone
+def test_the_counters_say_what_whole_rings_would_have_held(frontiers, read):
+    """``window_decode_pages_total`` is every row from the page of its
+    window's first key to its own position, ``..._ring_total`` every
+    slot's whole ring, both times the window layers and summed over the
+    calls."""
+    m = ServeMetrics()
+    for _ in range(2):
+        m.record_window_decode(np.asarray(frontiers), 4096, 5136, 16, 33, 4)
+    snap = m.snapshot()
+    assert snap["window_decode_pages_total"] == 2 * 4 * read
+    assert snap["window_decode_pages_ring_total"] == 2 * 4 * 33 * 321
+    assert paged_lib.ring_page(5136, 16) == 16
+    with pytest.raises(ValueError, match="whole blocks"):
+        paged_lib.ring_page(5136, 32)
 
 
 def test_a_length_under_one_is_read_as_one():

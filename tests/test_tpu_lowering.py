@@ -20,6 +20,23 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# A Mosaic kernel as a compiled program's text holds it (the base64 of
+# its serialized MLIR under "body"), parsed and printed WITHOUT its debug
+# locations, which move with the source's lines. Shared by the drivers
+# that hold a kernel to the one of an earlier tree.
+_KERNEL_DIGEST = r"""
+def kernel_digest(body):
+    import base64, hashlib
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    with ir.Context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    return hashlib.sha256(asm.encode()).hexdigest()
+"""
+
 _DRIVER = r"""
 import json, sys
 sys.path.insert(0, {root!r})
@@ -43,24 +60,18 @@ out = {{"device_kind": topo.devices[0].device_kind}}
 one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
 
 
+KERNEL_DIGEST
+
+
 def program_digest(text):
     # The compiled program without what moves when a line of the source
     # moves: op metadata, the tables of files, functions and frames, and
     # the debug locations inside each Mosaic kernel's serialized MLIR
-    # (the kernel is parsed and printed without them).
-    import base64, hashlib, re
-    from jax._src.lib import tpu
-    from jax._src.lib.mlir import ir
-
-    def kernel(match):
-        with ir.Context() as ctx:
-            tpu.register_dialect(ctx)
-            ctx.allow_unregistered_dialects = True
-            asm = ir.Module.parse(base64.b64decode(match.group(1))
-                                  ).operation.get_asm(enable_debug_info=False)
-        return '"body":"' + hashlib.sha256(asm.encode()).hexdigest() + '"'
-
-    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', kernel, text)
+    # (`kernel_digest`).
+    import hashlib, re
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"',
+                  lambda match: '"body":"' + kernel_digest(match.group(1))
+                  + '"', text)
     text = re.sub(r", metadata=\{{[^}}]*\}}", "", text)
     text = re.sub(r"\nFileNames\n.*?\n\n\n", "\n", text, flags=re.S)
     return hashlib.sha256(text.encode()).hexdigest()
@@ -116,7 +127,7 @@ for name, mesh in (
     wq = compiled.input_shardings[0][0]["params"]["layers"]["wq"]
     out[name + "_wq_shard"] = list(wq.shard_shape((2, 256, 256)))
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("KERNEL_DIGEST", _KERNEL_DIGEST)
 
 
 # The serve programs at the sizes of the benchmark's chat cell:
@@ -215,7 +226,8 @@ print("LOWERED " + json.dumps(out))
 
 
 # What a decode program's text says of its full layers' attention (ISSUE
-# 55): the Mosaic calls under `hvd_paged_decode` by scope, and what is
+# 55; a window layer's too since ISSUE 59): the Mosaic calls under
+# `hvd_paged_decode` by scope, and what is
 # left under `attn_full/kv_gather` (every row's whole table, gathered:
 # `bf16[rows * width, 16, ...]` a pool and full layer before). Shared by
 # the three drivers whose configurations have a full kind.
@@ -225,7 +237,8 @@ def paged_decode_report(text):
         r'custom-call\([^\n]*op_name="([^"]*hvd_paged_decode)[^"]*"', text)
     return {{
         "paged_decode_calls": len(calls),
-        "paged_decode_paths": sorted(set(calls)),
+        "paged_decode_by_path": {{path: calls.count(path)
+                                 for path in set(calls)}},
         "table_gathers": len(re.findall(
             r'op_name="[^"]*attn_full/kv_gather', text))}}
 """
@@ -238,7 +251,7 @@ def paged_decode_report(text):
 # the full layers' pool, the window layers' rings, or one layer's
 # experts, by opcode.
 _MIXED_DRIVER = r"""
-import collections, json, re, sys
+import collections, dataclasses, json, re, sys
 sys.path.insert(0, {root!r})
 import jax, jax.numpy as jnp
 from jax.experimental import topologies
@@ -307,6 +320,33 @@ for name, fn, args in (
                  "kernels": compiled.as_text().count("tpu_custom_call"),
                  "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
                  **paged_decode_report(compiled.as_text())}}
+
+# A decode step over caches of the trinity cell's shapes (ISSUE 59): the
+# published 48 / 8 heads of 128, four window layers and a full one, 32
+# rows in 33 slots' rings of 4096 + 1024 + 16 places behind tables of
+# 536; narrow otherwise, so that it compiles in seconds.
+cell = dataclasses.replace(
+    cfg, n_layers=5, n_heads=48,
+    layer_types=("sliding", "sliding", "sliding", "sliding", "full"))
+ring = ring_width(cell.attn_window, 1024, BS)
+kv = on_chip(jax.eval_shape(lambda: init_kv_cache(
+    cell, 32 * 536 + 1, BS, n_slots=32, ring=ring).k))
+compiled = decode_lib.make_serve_fns(
+    cell, None, block_size=BS, table_width=536, ring=ring)[2].lower(
+    on_chip(jax.eval_shape(
+        lambda: init_transformer(cell, jax.random.PRNGKey(0)))),
+    kv, kv, i32(32), i32(32), (i32(32, 536), i32(32))).compile()
+text = compiled.as_text()
+out["decode_cell"] = {{
+    "rings": shape_of(kv[1]),
+    # whatever else has a ring's places among its dimensions: a slot's
+    # or a layer's rings sliced, copied, gathered or scored
+    "ring_wide": sorted(set(
+        result + " " + opcode for result, opcode in re.findall(
+            r"= (\w+\[[\d,]*\b%d\b[\d,]*\])\{{\S* ([\w\-]+)\(" % ring, text)
+        if result != shape_of(kv[1]))),
+    "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+    **paged_decode_report(text)}}
 print("LOWERED " + json.dumps(out))
 """.replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
 
@@ -1013,13 +1053,18 @@ def test_two_cache_serve_programs_copy_neither_cache_nor_experts_on_v5e():
         got = out[program]
         # (a "custom-call" of the experts' shape is the compiler
         # staging one layer's matrix in fast memory ahead of its
-        # kernel, which it does at this size and not at 604 MB)
+        # kernel, which it does at this size and not at 604 MB; a
+        # "copy-done" of it is the same staging as an asynchronous
+        # copy into that memory, `S(1)` in its layout, which the
+        # compiler chose for one layer once the window layers' steps
+        # were Pallas calls too: ISSUE 59)
         assert set(got["ops"]) <= {
             "pool parameter", "pool get-tuple-element", "pool fusion",
             "pool scatter", "pool bitcast", "rings parameter",
             "rings get-tuple-element", "rings fusion", "rings scatter",
             "rings bitcast", "experts parameter",
-            "experts get-tuple-element", "experts custom-call"}, (
+            "experts get-tuple-element", "experts custom-call",
+            "experts copy-done"}, (
                 program, got)
         assert got["kernels"] >= 9, (program, got)     # 3 a sparse layer
         # (the writes are scatters, or at some shapes an update of a
@@ -1404,12 +1449,134 @@ def test_a_decode_step_s_full_layers_read_the_pools_where_they_lie(shapes):
     out = _compile_for_v5e({"lfm2": _LFM2_DRIVER, "trinity": _MIXED_DRIVER,
                             "jamba": _JAMBA_DRIVER}[shapes])
     step, chunk = out["decode"], out["prefill_resume"]
-    assert step["paged_decode_calls"] == 1, step
-    assert step["paged_decode_paths"] == [
-        "jit(decode)/attn/attn_full/jit(_decode)/hvd_paged_decode"], step
+    # (trinity's three window layers here read their rings through the
+    # same call since ISSUE 59, under their own scope: below)
+    assert step["paged_decode_by_path"] == {
+        "jit(decode)/attn/attn_full/jit(_decode)/hvd_paged_decode": 1,
+        **({"jit(decode)/attn/attn_window/jit(_decode)/hvd_paged_decode": 3}
+           if shapes == "trinity" else {})}, step
     assert step["table_gathers"] == 0, step
     assert chunk["paged_decode_calls"] == 0, chunk
     assert chunk["table_gathers"] > 0, chunk
+
+
+def test_a_decode_step_s_window_layers_read_the_rings_where_they_lie():
+    """ISSUE 59: ``jit(decode)`` compiled for the v5e over caches of the
+    trinity cell's shapes (32 rows of 48 / 8 heads in 33 slots' rings of
+    5136 places, four window layers and a full one) holds
+    ``hvd_paged_decode`` once a window layer under ``attn_window``
+    (``ring_decode``: the rings read as pages where they lie) beside
+    the full layer's one under ``attn_full``; nothing but the stacked
+    rings themselves (the parameter, ``kv_write``'s scatters on the
+    donated array, the bitcast to pages) has a ring's 5136 places among
+    its dimensions (before: K and V of every window layer sliced out
+    and copied, ``bf16[1,33,5136,8,128]`` eight times a step, 347 MB
+    each, and float32 ``[33,8,6,1,5136]`` scores), and the program's
+    temporaries are under 0.1 GB (0.41 GB before at the published
+    widths)."""
+    got = _compile_for_v5e(_MIXED_DRIVER)["decode_cell"]
+    assert got["rings"] == "bf16[4,33,5136,8,128]", got
+    assert got["paged_decode_by_path"] == {
+        "jit(decode)/attn/attn_full/jit(_decode)/hvd_paged_decode": 1,
+        "jit(decode)/attn/attn_window/jit(_decode)/hvd_paged_decode": 4}, got
+    assert got["ring_wide"] == [], got
+    assert got["temp_bytes"] < 0.1e9, got
+
+
+# The calls of ``ops/paged_decode.py`` that pass no ``skip`` (ISSUE 59
+# gave the kernel one more prefetched scalar a row for a ring's window,
+# which begins in the middle of a page), each alone at its cell's
+# shapes: every Mosaic kernel of the compiled call (`kernel_digest`).
+_KERNELS_DRIVER = r"""
+import json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.ops import paged_decode as paged_lib
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = SingleDeviceSharding(topo.devices[0])
+
+
+def sds(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+
+def i32(*shape):
+    return sds(*shape, dtype=jnp.int32)
+
+
+KERNEL_DIGEST
+
+
+def kernels(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [kernel_digest(body)[:16] for body in re.findall(
+        r'"body":"([A-Za-z0-9+/=]+)"', text)]
+
+
+def paged(rows, heads, width, pool):
+    return kernels(
+        lambda q, k, v, t, n: paged_lib.paged_decode(q, k, v, 0, t, n),
+        sds(rows, heads, pool[-1] if len(pool) == 5 else 64), sds(*pool),
+        sds(*pool), i32(rows, width), i32(rows))
+
+
+def stats(pool):
+    return kernels(
+        lambda q, k, v, t, n: paged_lib.paged_decode_stats(
+            q, k, v, 1, t, n, key_positions=256),
+        sds(16, 32, 128), sds(*pool), sds(*pool), i32(16, 128), i32(16))
+
+
+out = {{
+    "device_kind": topo.devices[0].device_kind,
+    # LFM2: 128 rows of 32 / 8 heads of 64 behind tables of 160, rows of 512
+    "lfm2": paged(128, 32, 160, (1, 20481, 16, 512)),
+    # trinity's full layer: 32 rows of 48 / 8 heads behind tables of 536
+    "trinity_full": paged(32, 48, 536, (1, 17153, 16, 8, 128)),
+    # jamba: 256 rows of 20 heads over one behind tables of 96
+    "jamba": paged(256, 20, 96, (3, 24577, 16, 1, 128)),
+    # EvaByte: the window's rows as pages of 16, and the summaries' pages
+    "eva_window": stats((2, 17 * 128, 16, 32, 128)),
+    "eva_summaries": stats((2, 2049, 16, 32, 128)),
+    # Kimi: 32 rows of 64 heads over latents of 640 behind tables of 1088
+    "kimi": kernels(
+        lambda q, p, t, n: paged_lib.latent_decode(
+            q, p, 1, t, n, rank=512, scale=0.1),
+        sds(32, 64, 640), sds(2, 34817, 16, 640), i32(32, 1088), i32(32)),
+}}
+print("LOWERED " + json.dumps(out))
+""".replace("KERNEL_DIGEST", _KERNEL_DIGEST)
+
+# ... as the driver above printed them on the tree before ISSUE 59
+# (4b5f844, unpacked beside this one: the same driver, its root there).
+_BEFORE_THE_SKIP = {
+    "lfm2": ["8ebd5fdae6605efd"],
+    "trinity_full": ["e1a51dd151ca042f"],
+    "jamba": ["89ade33d6fa13a18"],
+    "eva_window": ["29b3badb245ecf6f"],
+    "eva_summaries": ["acb493729cfa3ca2"],
+    "kimi": ["2c0aabf1afcf564d"],
+}
+
+
+@pytest.mark.parametrize("call", sorted(_BEFORE_THE_SKIP))
+def test_the_calls_without_a_skip_are_the_kernels_before_it(call):
+    """ISSUE 59: a row's ``skip`` is ABSENT, not zero, from the calls
+    whose rows begin with their pages (``paged_decode`` under
+    ``attn_full``, both ``paged_decode_stats`` calls of an eva step,
+    ``latent_decode``): compiled for the v5e at LFM2's, trinity's full
+    layer's, jamba's, EvaByte's and Kimi's shapes, each call's Mosaic
+    module is text for text the one of the tree before (PR 58 found
+    what a call that asks the compiler for something it does not need
+    does to the program around it)."""
+    assert _compile_for_v5e(_KERNELS_DRIVER)[call] == \
+        _BEFORE_THE_SKIP[call]
 
 
 # EVA attention at EvaByte's widths (ISSUE 56): two eva layers of 32

@@ -310,6 +310,14 @@ def test_the_window_cache_keeps_its_bound_and_a_retired_slot_is_clean():
     assert engine.cache.k[1].shape[:3] == (4, 2, RING)       # the rings
     assert engine.cache.k[0].shape[0] == 1                   # the full layer
     assert snap["kv_blocks_high_water"] == -(-(60 + 46) // BS)
+    # every decode call read two or three pages of 8 a window layer (a
+    # window of 16, from the middle of a page or not) of its slot's
+    # ring, where the two slots' whole rings are five pages each
+    calls, rest = divmod(snap["window_decode_pages_ring_total"],
+                         4 * 2 * (RING // BS))
+    assert calls >= 45 and rest == 0, snap
+    assert (2 * 4 * calls <= snap["window_decode_pages_total"]
+            <= 3 * 4 * calls), snap
     rid = engine.submit(other, 12)
     engine.step()
     assert engine.metrics.snapshot()["kv_window_blocks_in_use"] == RING // BS
@@ -507,14 +515,15 @@ def test_the_existing_train_steps_did_not_move(devices, case):
 # decoder (over one pool), taken on the tree before the state became one
 # array a kind of layer (PR 37's; jax 0.9.0). The decode program over
 # two caches was taken again at PR 55: its full layer's step is the
-# Pallas call of ``ops/paged_decode.py`` (the interpreter's form here).
+# Pallas call of ``ops/paged_decode.py`` (the interpreter's form here),
+# and at PR 59: so are its four window layers' steps (``ring_decode``).
 _SERVE_PROGRAMS = {
     "two_caches/prefill":
         "34a2ab9483578a5c4831799975f027de09230c7bbeb96a294b00d8aa9e7be35c",
     "two_caches/prefill_resume":
         "8dd7e0c23433cdcd04160f713efb59f22c328ca1785170fd7cc3ea38eba3d2b9",
     "two_caches/decode":
-        "8f3fcb1bb5af5ed37db48cd21f27032cd829ba87a562b21eb16ad82d697d6ac2",
+        "73067530b4a6134bfef7d5bcd9dd8d1f1479db566c171b1eea33b3dbd9e2fb3e",
     "dense/prefill":
         "2c4fa4dde8b1a2f85ab888aee66330331942b29c379d423f7cd4dcb1b5f1ed5c",
     "dense/prefill_resume":

@@ -49,7 +49,15 @@ gathered: all a decode step had before ISSUE 55) and of
 ``ops/paged_decode.py::paged_decode`` (the same kernel body: ISSUE 58)
 at each ``--key-blocks``, with the GB/s
 of the K and V pages it reads; and the largest difference of the two
-relative to the largest magnitude.
+relative to the largest magnitude. ``--cells ring`` is a window layer's
+step (ISSUE 59) at the trinity cell's shapes: 32 rows of 48 / 8 heads at
+positions log-uniform 1024-8576 in 33 slots' rings of 5136 places, a
+window of 4096. ms a layer of the XLA form (``_attend_keys`` over every
+slot's whole ring under ``ring_positions``, the queries carried to
+their slots: all ``window_step`` had, without the slices of the stacked
+cache in front of it) and of ``ops/paged_decode.py::ring_decode`` at
+each ``--ring-pages`` (positions a ring's places are read as one page:
+divisors of the ring).
 
 ``--sparse`` sweeps a sparse layer's chunk (ISSUE 51) at
 ``minicpm-sala-8l``'s shapes: ``[1, C, 32, 128]`` bf16 queries of a
@@ -386,11 +394,76 @@ PAGED_SHAPES = {"lfm2": (128, 32, 8, 64, True, 160, 256, 2560),
                 "jamba": (256, 20, 1, 128, False, 96, 64, 1536)}
 
 
+#: a window layer's step at the trinity cell's shapes: rows, H, Hkv, Dh,
+#: slots, ring, window, first and last position
+RING_SHAPE = (32, 48, 8, 128, 33, 5136, 4096, 1024, 8576)
+
+
+def ring_decode_sweep(args) -> None:
+    rows, H, Hkv, Dh, n_slots, ring, window, lo, hi = RING_SHAPE
+    rng = np.random.default_rng(0)
+    ks = jax.random.split(jax.random.PRNGKey(rows), 3)
+    kr, vr = (jax.jit(lambda k: jax.random.normal(
+        k, (1, n_slots, ring, Hkv, Dh), jnp.bfloat16))(k) for k in ks[:2])
+    q = jax.random.normal(ks[2], (rows, H, Dh), jnp.bfloat16)
+    slots = jnp.asarray(1 + rng.permutation(n_slots - 1)[:rows], jnp.int32)
+    n = np.exp(rng.uniform(np.log(lo), np.log(hi), rows)).astype(int)
+    positions = jnp.asarray(n - 1, jnp.int32)
+    at, seen = paged_lib.ring_reach(n - 1, window, ring)
+    row = {"cell": "ring", "rows": rows, "visible": int(seen.sum()),
+           "past_the_window": int((n > window).sum()),
+           "past_the_ring": int((n > ring).sum())}
+
+    def chained(form):
+        @jax.jit
+        def chain(q, kr, vr, slots, positions):
+            return lax.scan(
+                lambda q, _: (form(q, kr, vr, slots, positions), None),
+                q, None, length=PAGED_LAYERS)[0]
+        return chain, (q, kr, vr, slots, positions)
+
+    def xla(q, kr, vr, slots, positions):
+        def by_slot(a):
+            return jnp.zeros((n_slots,) + a.shape[1:],
+                             a.dtype).at[slots].set(a)
+        frontier = by_slot(positions[:, None] + 1)
+        return decode_lib._attend_keys(
+            by_slot(q[:, None]), kr[0], vr[0],
+            decode_lib.ring_positions(frontier[:, 0], ring), frontier - 1,
+            window)[slots].reshape(q.shape)
+
+    chain, xs = chained(xla)
+    row["xla"] = round(median_ms(chain, xs, args.reps) / PAGED_LAYERS, 4)
+    want = jax.jit(xla)(*xs).astype(jnp.float32)
+    for page in args.ring_pages:
+        def kernel(q, kr, vr, slots, positions, page=page):
+            return paged_lib.ring_decode(q, kr, vr, 0, slots, positions,
+                                         window=window, page=page)
+        name = f"_page{page}"
+        pages_read = int(np.sum(-(-(at % page + seen) // page)))
+        chain, xs = chained(kernel)
+        try:
+            ms = median_ms(chain, xs, args.reps) / PAGED_LAYERS
+        except Exception as e:       # a buffer Mosaic refuses
+            row["kernel" + name] = f"refused: {str(e)[:80]}"
+            continue
+        row["kernel" + name] = round(ms, 4)
+        row["pages_read" + name] = pages_read
+        row["gb_s" + name] = round(
+            pages_read * page * Hkv * Dh * 2 * 2 / ms / 1e6, 1)
+        got = jax.jit(kernel)(*xs).astype(jnp.float32)
+        row["rel_err" + name] = round(float(
+            jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))), 5)
+    print(json.dumps(row), flush=True)
+
+
 def paged_decode_sweep(args) -> None:
     PAGE = 16
     rng = np.random.default_rng(0)
     default_wave = paged_lib._wave_pages
-    for cell in args.cells:
+    if "ring" in args.cells:
+        ring_decode_sweep(args)
+    for cell in (c for c in args.cells if c != "ring"):
         rows, H, Hkv, Dh, end_to_end, width, lo, hi = PAGED_SHAPES[cell]
         tail = (Hkv * Dh,) if end_to_end else (Hkv, Dh)
         ks = jax.random.split(jax.random.PRNGKey(rows), 3)
@@ -708,7 +781,9 @@ def main() -> None:
     ap.add_argument("--paged-decode", action="store_true",
                     help="a full layer's decode step instead")
     ap.add_argument("--cells", nargs="+", default=sorted(PAGED_SHAPES),
-                    choices=sorted(PAGED_SHAPES))
+                    choices=sorted(PAGED_SHAPES) + ["ring"])
+    ap.add_argument("--ring-pages", type=int, nargs="+", default=[16, 48],
+                    help="positions a page of a ring (--cells ring)")
     ap.add_argument("--rows", type=int, nargs="+", default=[32, 64])
     ap.add_argument("--lengths", nargs="+",
                     default=["even", "spread", "tail"],
