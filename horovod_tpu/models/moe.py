@@ -82,10 +82,37 @@ class MoEConfig:
     #: chosen inside the ``topk_group`` best groups (1, 1: no limit).
     n_group: int = 1
     topk_group: int = 1
+    #: An expert's form: ``"swiglu"`` (``silu(x Wg) * (x Wu)`` then
+    #: ``Wd``: three matrices) or ``"relu2"`` (``relu(x Wu)^2`` then
+    #: ``Wd``: UNGATED, two matrices and no ``w_gate``), the shared
+    #: expert's too.
+    activation: str = "swiglu"
+    #: The routed experts read and write a LATENT of this many values
+    #: (0: the model's width): ``latent_down`` [D, latent] before the
+    #: dispatch (a dispatched row is ``latent`` wide), ``latent_up``
+    #: [latent, D] after the routed sum (linear, so once for the sum and
+    #: not once an expert). The router and the shared expert read the
+    #: full-width input.
+    latent: int = 0
+    #: The shared expert's width (``None``: the routed experts').
+    shared_d_ff: Optional[int] = None
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown MoE scoring {self.scoring!r}")
+        if self.activation not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown MoE activation {self.activation!r}")
+        if self.experts_held is None and (
+                self.activation != "swiglu" or self.latent
+                or self.shared_d_ff is not None):
+            raise ValueError(
+                "an ungated activation, a latent around the experts and a "
+                "shared expert of its own width are the held dispatch's "
+                "(experts_held, a chip's share, which may be all of them): "
+                "the whole mixture's dispatch (moe_ffn_dropless) and "
+                "ops/grouped_matmul.py's kernel, whose rule (taken) is "
+                "tuned on its three products, run a SwiGLU at the model's "
+                "width")
         if not (1 <= self.topk_group <= self.n_group
                 and self.n_experts % self.n_group == 0
                 and (self.n_group == 1 or self.top_k
@@ -140,6 +167,12 @@ def moe_param_specs(n_layers_leading: bool = True,
         specs.update(shared_gate=P(*lead, "fsdp", "tp"),    # [L?, D, F]
                      shared_up=P(*lead, "fsdp", "tp"),
                      shared_down=P(*lead, "tp", "fsdp"))    # [L?, F, D]
+    if cfg is not None and cfg.latent:
+        specs.update(latent_down=P(*lead, "fsdp", None),    # [L?, D, latent]
+                     latent_up=P(*lead, None, "fsdp"))      # [L?, latent, D]
+    if cfg is not None and cfg.activation == "relu2":
+        for gate in {"w_gate", "shared_gate"} & set(specs):
+            del specs[gate]
     return specs
 
 
@@ -147,18 +180,20 @@ def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
                     cfg: MoEConfig, dtype) -> Dict[str, Any]:
     kr, kg, ku, kd = jax.random.split(key, 4)
     L, D, F, E, Eh = n_layers, d_model, d_ff, cfg.n_experts, cfg.n_held
+    Dx = cfg.latent or D          # what the routed experts read and write
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
+    gated = cfg.activation == "swiglu"     # relu2: no gate matrix
     params = {
         # Router in f32: small, and routing decisions are precision-
         # sensitive (standard practice).
         "router": (jax.random.normal(kr, (L, D, E), jnp.float32) * D ** -0.5),
-        "w_gate": dense(kg, (L, Eh, D, F), D),
-        "w_up": dense(ku, (L, Eh, D, F), D),
-        "w_down": dense(kd, (L, Eh, F, D), F),
+        **({"w_gate": dense(kg, (L, Eh, Dx, F), Dx)} if gated else {}),
+        "w_up": dense(ku, (L, Eh, Dx, F), Dx),
+        "w_down": dense(kd, (L, Eh, F, Dx), F),
     }
     if cfg.scoring == "sigmoid":
         # The selection bias: what load balancing moves in training,
@@ -166,9 +201,15 @@ def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
         params["router_bias"] = jnp.zeros((L, E), jnp.float32)
     if cfg.shared_expert:
         ks = jax.random.split(jax.random.fold_in(key, 1), 3)
-        params.update(shared_gate=dense(ks[0], (L, D, F), D),
-                      shared_up=dense(ks[1], (L, D, F), D),
-                      shared_down=dense(ks[2], (L, F, D), F))
+        Fs = cfg.shared_d_ff or F
+        if gated:
+            params["shared_gate"] = dense(ks[0], (L, D, Fs), D)
+        params.update(shared_up=dense(ks[1], (L, D, Fs), D),
+                      shared_down=dense(ks[2], (L, Fs, D), Fs))
+    if cfg.latent:
+        kl = jax.random.split(jax.random.fold_in(key, 2), 2)
+        params.update(latent_down=dense(kl[0], (L, D, Dx), D),
+                      latent_up=dense(kl[1], (L, Dx, D), Dx))
     return params
 
 
@@ -456,11 +497,16 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
 
 
 def _shared_expert(xf, lp):
-    """The SwiGLU every token takes, on ``xf`` [N, D]."""
+    """The expert every token takes, on ``xf`` [N, D]: a SwiGLU, or
+    where the parameters hold no gate matrix ``relu(x Wu)^2 Wd``."""
     with jax.named_scope("moe_shared"):
-        g = jax.nn.silu((xf @ lp["shared_gate"]).astype(jnp.float32))
-        u = (xf @ lp["shared_up"]).astype(jnp.float32)
-        return ((g * u).astype(xf.dtype) @ lp["shared_down"]).astype(xf.dtype)
+        if "shared_gate" in lp:
+            g = jax.nn.silu((xf @ lp["shared_gate"]).astype(jnp.float32))
+            h = g * (xf @ lp["shared_up"]).astype(jnp.float32)
+        else:
+            h = jnp.square(jax.nn.relu(
+                (xf @ lp["shared_up"]).astype(jnp.float32)))
+        return (h.astype(xf.dtype) @ lp["shared_down"]).astype(xf.dtype)
 
 
 def held_pairs(experts, cfg: MoEConfig):
@@ -511,10 +557,16 @@ def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None):
             inverse = jnp.minimum(inverse, rows - 1)
         taken = _take_sorted(xf, order, inverse, K, held)      # [rows, D]
     with jax.named_scope("moe_experts"):
-        g = jax.nn.silu(lax.ragged_dot(taken, w_gate, sizes)
-                        .astype(jnp.float32))
-        u = lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)
-        out = lax.ragged_dot((g * u).astype(xf.dtype), w_down, sizes)
+        if w_gate is None:
+            # ungated: relu(x Wu)^2, two products and not three
+            h = jnp.square(jax.nn.relu(
+                lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)))
+        else:
+            g = jax.nn.silu(lax.ragged_dot(taken, w_gate, sizes)
+                            .astype(jnp.float32))
+            u = lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)
+            h = g * u
+        out = lax.ragged_dot(h.astype(xf.dtype), w_down, sizes)
     with jax.named_scope("moe_combine"):
         # what lies behind the last group is not a result: masked, not
         # multiplied by a zero gate
@@ -585,12 +637,23 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
         order, sizes = _sorted_by_expert(local, cfg.n_held + 1)
         sizes = sizes[:cfg.n_held]
         inverse = jnp.argsort(order)
-    w = (lp["w_gate"], lp["w_up"], lp["w_down"])
+    # (an ungated expert has no gate matrix: None, a pytree of nothing)
+    w = (lp.get("w_gate"), lp["w_up"], lp["w_down"])
+    rows = xf
+    if cfg.latent:
+        # what is dispatched is the latent row: a quarter of the bytes
+        # of a row at Nemotron's 1024 of 4096
+        with jax.named_scope("moe_latent_down"):
+            rows = xf @ lp["latent_down"]
     if bound is None:
-        y = _held_rows(xf, w, gates, held, order, inverse, sizes)
+        y = _held_rows(rows, w, gates, held, order, inverse, sizes)
     else:
-        y = _held_rows_bounded(bound, xf, w, gates, held, order, inverse,
+        y = _held_rows_bounded(bound, rows, w, gates, held, order, inverse,
                                sizes)
+    if cfg.latent:
+        # after the sum over a token's experts: linear, so once
+        with jax.named_scope("moe_latent_up"):
+            y = (y @ lp["latent_up"]).astype(x.dtype)
     if cfg.shared_expert:
         y = y + _shared_expert(xf, lp)
     return y.reshape(B, T, D).astype(x.dtype)
@@ -787,6 +850,9 @@ MOE_METRIC_KEYS = (
     "moe_compact_calls_share",
     "moe_held_pairs_over_bound_max",
     "moe_grouped_kernel_products_share",
+    "moe_held_pairs_run",
+    "moe_held_pairs_not_run",
+    "moe_held_experts_touched_mean",
 )
 
 _moe_metrics: Dict[str, float] = {}

@@ -88,7 +88,11 @@ class Rotary:
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
 LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv", "eva")
+               "lightning", "conv", "eva", "mamba2")
+#: What ``layer_types`` names a layer that is a feed-forward ALONE, in a
+#: stack whose layers are one branch each (``one_branch``): no kind of
+#: mixer, and nothing kept between calls.
+FFN = "ffn"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,7 +169,8 @@ class TransformerConfig:
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
     # One of the LAYER_KINDS a layer ("sliding" | "full" here; "kda",
-    # "mla", "mamba", "sparse", "lightning", "conv" and "eva" below). A
+    # "mla", "mamba", "sparse", "lightning", "conv", "eva" and "mamba2"
+    # below; "ffn" with one_branch). A
     # sliding layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
@@ -317,13 +322,51 @@ class TransformerConfig:
     norm_unit_offset: bool = False
     stream_fp32: bool = False
     head_rows: int = 1
+    # A tenth kind of layer_types (ISSUE 60; served, not trained):
+    # "mamba2", Mamba-2's state-space duality (SSD) layer. Of the normed
+    # input ``[z | xBC | dt] = h W_in`` as ``Di | Di + 2 G N | Hm`` (Di =
+    # mamba_expand * d_model channels in Hm = Di / mamba2_head_dim heads,
+    # N = mamba_d_state, G = mamba2_groups), xBC through a causal
+    # depthwise convolution of mamba_d_conv taps with a bias and SiLU,
+    # split x [Hm, P] and B, C [G, N] (head h reads group h // (Hm / G));
+    # Delta = softplus(dt + dt_bias), ONE scalar decay a head exp(Delta
+    # A), a float32 state [Hm, P, N] a sequence, S_t = exp(Delta_t A)
+    # S_{t-1} + Delta_t x_t (x) B_t, y_t = S_t C_t + D x_t; the output is
+    # gated by SiLU(z) and THEN RMS-normed over each of the G groups of
+    # Di / G channels, before W_out. It reads no position. A chunk of a
+    # prompt runs as matrix products over blocks of mamba2_chunk
+    # positions (serve/decode.py::ssd_scan). mamba2_dt_range: the
+    # seeded step's (least, most, floor), Mamba-2's own initialisation.
+    mamba2_head_dim: int = 64
+    mamba2_groups: int = 1
+    mamba2_chunk: int = 128
+    mamba2_dt_range: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
+    # Every layer is ONE branch (Nemotron-H's stack): a layer of a kind
+    # of LAYER_KINDS is its mixer alone (one norm, one residual addition,
+    # no feed-forward), and a layer that layer_types names "ffn" is a
+    # feed-forward alone (the mixture where n_experts > 0), with no
+    # mixer and nothing kept between calls. Served, not trained.
+    one_branch: bool = False
+    # The mixture's experts are UNGATED (moe_activation "relu2": relu(x
+    # W1)^2 W2, no gate matrix; "swiglu": the three matrices every other
+    # configuration has), run in a latent of moe_latent values (0: at
+    # d_model) between one projection down before the dispatch and one up
+    # after the routed sum, and the shared expert, on the full-width
+    # input, has a width of its own (None: d_ff). The router reads the
+    # full-width input whatever the experts read (models/moe.py).
+    moe_activation: str = "swiglu"
+    moe_latent: int = 0
+    moe_shared_d_ff: Optional[int] = None
 
     def __post_init__(self):
+        object.__setattr__(self, "mamba2_dt_range",
+                           tuple(self.mamba2_dt_range))
         if self.layer_types is not None:
             # a configuration file gives a list; the config is a jit key
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if (len(self.layer_types) != self.n_layers
-                    or set(self.layer_types) - set(LAYER_KINDS)):
+                    or set(self.layer_types) - set(LAYER_KINDS)
+                    - ({FFN} if self.one_branch else set())):
                 raise ValueError(
                     f"layer_types needs n_layers={self.n_layers} entries "
                     f"of the {len(LAYER_KINDS)} kinds "
@@ -338,6 +381,15 @@ class TransformerConfig:
                                  "mla_rope_dim")
             if "mamba" in self.layer_types and not self.mamba_dt_rank:
                 raise ValueError("mamba layers need mamba_dt_rank")
+            if "mamba2" in self.layer_types and (
+                    self.mamba_expand * self.d_model % self.mamba2_head_dim
+                    or self.mamba2_heads % self.mamba2_groups):
+                raise ValueError(
+                    "mamba2 layers need mamba_expand * d_model in whole "
+                    "heads of mamba2_head_dim, and the heads in "
+                    f"mamba2_groups equal groups (got {self.mamba_expand} * "
+                    f"{self.d_model}, {self.mamba2_head_dim} and "
+                    f"{self.mamba2_groups})")
             if "eva" in self.layer_types and (
                     self.eva_chunk < 1 or self.eva_window % self.eva_chunk
                     or self.attn_gate or self.qk_norm):
@@ -352,7 +404,7 @@ class TransformerConfig:
             # k, and the full layers beside them keep the configuration's
             # n_kv_heads and qk_norm_per_head
             own_heads = {"kda", "mla"} & set(self.layer_types)
-            no_heads = {"mamba", "conv"} & set(self.layer_types)
+            no_heads = {"mamba", "conv", "mamba2"} & set(self.layer_types)
             if "sparse" in self.layer_types and (
                     self.sparse_kernel % self.sparse_stride
                     or self.sparse_block % self.sparse_stride
@@ -386,6 +438,14 @@ class TransformerConfig:
                     "attn_gate, sandwich_norm or qk_norm over the whole "
                     "vector (qk_norm_per_head and n_kv_heads are the full "
                     "layers' beside them)")
+        if self.one_branch and (self.layer_types is None
+                                or self.n_dense_layers or self.sandwich_norm):
+            raise ValueError(
+                "one_branch says what each entry of layer_types is (a mixer "
+                "alone, or 'ffn', a feed-forward alone): it needs "
+                "layer_types, and no n_dense_layers or sandwich_norm (a "
+                "dense feed-forward of such a stack is an 'ffn' layer of a "
+                "configuration without experts)")
         if ((self.norm_unit_offset or self.stream_fp32
              or self.head_rows != 1) and not self.stateful):
             raise ValueError(
@@ -456,14 +516,26 @@ class TransformerConfig:
         return sum(self.kind_of(i) == kind for i in range(self.n_layers))
 
     @property
+    def mamba2_heads(self) -> int:
+        return self.mamba_expand * self.d_model // self.mamba2_head_dim
+
+    @property
+    def mamba2_conv_width(self) -> int:
+        """Channels a mamba2 layer's convolution runs over: x | B | C."""
+        return (self.mamba_expand * self.d_model
+                + 2 * self.mamba2_groups * self.mamba_d_state)
+
+    @property
     def stateful(self) -> bool:
         """Some layer keeps a state that is not cached keys and values
         alone (a kda, mamba or lightning layer's recurrent state, an mla
         layer's latent, a sparse layer's compressed keys, a conv layer's
-        rows, an eva layer's window rows and summary pages)."""
-        return bool(self.layer_types) and bool(
-            {"kda", "mla", "mamba", "sparse", "lightning", "conv", "eva"}
-            & set(self.layer_types))
+        rows, an eva layer's window rows and summary pages, a mamba2
+        layer's state), or its layers are one branch each: what the
+        serve programs alone run."""
+        return bool(self.layer_types) and (self.one_branch or bool(
+            {"kda", "mla", "mamba", "sparse", "lightning", "conv", "eva",
+             "mamba2"} & set(self.layer_types)))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
         """How layer ``layer`` rotates q and k, None for not at all:
@@ -476,7 +548,7 @@ class TransformerConfig:
         kind = self.kind_of(layer)
         if kind in ("lightning", "eva"):
             return Rotary(self.rope_theta)
-        if kind in ("kda", "mamba", "sparse", "conv"):
+        if kind in ("kda", "mamba", "sparse", "conv", "mamba2", FFN):
             kind = "full"
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
@@ -518,7 +590,10 @@ class TransformerConfig:
                                  experts_held=self.moe_experts_held,
                                  expert_offset=self.moe_expert_offset,
                                  n_group=self.moe_n_group,
-                                 topk_group=self.moe_topk_group)
+                                 topk_group=self.moe_topk_group,
+                                 activation=self.moe_activation,
+                                 latent=self.moe_latent,
+                                 shared_d_ff=self.moe_shared_d_ff)
 
 
 # ---------------------------------------------------------------------------
@@ -527,7 +602,7 @@ class TransformerConfig:
 
 #: The kinds of layer with no q and no k: the gains ``qk_norm_per_head``
 #: gives the full layers beside them, they do not hold.
-_NO_HEADS = ("mamba", "conv")
+_NO_HEADS = ("mamba", "conv", "mamba2", FFN)
 
 
 def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
@@ -564,6 +639,15 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
         layers = {"attn_norm": vec, "mlp_norm": vec, "w_in": mat,
                   "conv_w": P(None, None, "tp"),
                   "w_out": P(None, "tp", "fsdp")}
+    if kind == "mamba2":
+        layers = {"attn_norm": vec, "mlp_norm": vec, "w_in": mat,
+                  "w_dt": mat,
+                  "conv_w": P(None, None, "tp"), "conv_b": P(None, "tp"),
+                  "dt_bias": P(None, "tp"), "a_log": P(None, "tp"),
+                  "d_skip": P(None, "tp"), "o_norm": P(None, "tp"),
+                  "w_out": P(None, "tp", "fsdp")}
+    if kind == FFN:
+        layers = {"mlp_norm": vec}
     if kind == "mla":
         del layers["wk"], layers["wv"]
         layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
@@ -585,6 +669,9 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
     if cfg.sandwich_norm:
         layers["post_attn_norm"] = P(None, None)
         layers["post_mlp_norm"] = P(None, None)
+    if cfg.one_branch and kind != FFN:
+        del layers["mlp_norm"]          # a mixer alone
+        return layers
     if moe:
         layers["moe"] = moe_lib.moe_param_specs(cfg=cfg.moe)
     else:
@@ -715,6 +802,35 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             "w_out": dense(next(k), (L, D, D), D),
             "mlp_norm": jnp.ones((L, D), dt),
         }
+    elif kind == "mamba2":
+        Di, Hm, W = (cfg.mamba_expand * D, cfg.mamba2_heads,
+                     cfg.mamba2_conv_width)
+        # Mamba-2's own initialisation: softplus(dt_bias) log-uniform in
+        # [least, most], floored; A = -U(1, 16), ONE scalar a head; D = 1
+        least, most, floor = cfg.mamba2_dt_range
+        step = jnp.maximum(jnp.exp(uniform(
+            next(k), (L, Hm), jnp.log(least), jnp.log(most))), floor)
+        layers = {
+            "attn_norm": jnp.ones((L, D), dt),
+            # the published in_proj's columns [z | xBC | dt] as two
+            # matrices: dt comes out in float32 (mamba2_rows)
+            "w_in": dense(next(k), (L, D, Di + W), D),        # z | xBC
+            "w_dt": dense(next(k), (L, D, Hm), D),
+            # a tap a channel: [taps, W], the last tap the newest row
+            "conv_w": dense(next(k), (L, cfg.mamba_d_conv, W),
+                            cfg.mamba_d_conv),
+            "conv_b": dense(next(k), (L, W), cfg.mamba_d_conv),
+            # softplus^-1(step); float32, as a_log and d_skip are
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "a_log": jnp.log(uniform(next(k), (L, Hm), 1.0, 16.0)),
+            "d_skip": jnp.ones((L, Hm), jnp.float32),
+            # the gated norm's gain, over all Di channels
+            "o_norm": jnp.ones((L, Di), dt),
+            "w_out": dense(next(k), (L, Di, D), Di),
+            "mlp_norm": jnp.ones((L, D), dt),
+        }
+    elif kind == FFN:
+        layers = {"mlp_norm": jnp.ones((L, D), dt)}
     elif kind == "mla":
         R, C, Q = cfg.mla_rope_dim, cfg.mla_kv_rank, cfg.mla_q_rank
         # a head's q: Dh without position, then R rotated; with a q
@@ -755,7 +871,7 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             layers["eva_phi"] = dense(next(k), (L, Hkv, Dh), 1)
     if cfg.norm_unit_offset:
         # the stored gain is g of 1 + g
-        for name in ("attn_norm", "mlp_norm"):
+        for name in {"attn_norm", "mlp_norm"} & set(layers):
             layers[name] = jnp.zeros_like(layers[name])
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, H * Dh), dt)
@@ -768,6 +884,9 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
     if cfg.sandwich_norm:
         layers["post_attn_norm"] = jnp.ones((L, D), dt)
         layers["post_mlp_norm"] = jnp.ones((L, D), dt)
+    if cfg.one_branch and kind != FFN:
+        del layers["mlp_norm"]          # a mixer alone
+        return layers
     if moe:
         layers["moe"] = moe_lib.init_moe_params(next(k), L, D, F, cfg.moe, dt)
     else:
@@ -1278,6 +1397,55 @@ def conv_residual(cfg: TransformerConfig, lp, x, g):
     return x + (g @ lp["w_out"]).astype(cfg.dtype)
 
 
+# -- Mamba-2's SSD layer (ISSUE 60): projections, convolution, gated norm --
+
+def mamba2_rows(cfg: TransformerConfig, lp, x):
+    """A mamba2 layer up to its convolution: ``[z | xBC] = RMSNorm(x)
+    W_in`` as ``Di | Di + 2 G N`` and ``dt = RMSNorm(x) W_dt`` [B, T, Hm]
+    in float32 out of the matrix unit (a position's ``Delta A`` adds up
+    over a head's whole memory, as a kda layer's log-decay does).
+    ``xBC`` is what the convolution runs over (the newest ``mamba_d_conv
+    - 1`` rows of it are what a sequence carries from call to call);
+    ``z`` gates the output."""
+    Di = cfg.mamba_expand * cfg.d_model
+    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    zx = h @ lp["w_in"]
+    dt = jnp.einsum("btd,dh->bth", h, lp["w_dt"],
+                    preferred_element_type=jnp.float32)
+    return zx[..., :Di], zx[..., Di:], dt
+
+
+def mamba2_inputs(cfg: TransformerConfig, lp, xbc, dt, before):
+    """What the recurrence reads: ``SiLU(conv(xBC))`` (:func:`causal_taps`
+    with the bias, after the sequence's ``before`` [B, taps - 1, W])
+    split ``x`` [B, T, Hm, P], ``B`` and ``C`` [B, T, G, N], and the step
+    ``Delta = softplus(dt + dt_bias)`` [B, T, Hm], all float32."""
+    B, T = xbc.shape[:2]
+    Di, N, G = (cfg.mamba_expand * cfg.d_model, cfg.mamba_d_state,
+                cfg.mamba2_groups)
+    y = jax.nn.silu(causal_taps(xbc, before, lp["conv_w"], lp["conv_b"]))
+    return (y[..., :Di].reshape(B, T, cfg.mamba2_heads, cfg.mamba2_head_dim),
+            y[..., Di:Di + G * N].reshape(B, T, G, N),
+            y[..., Di + G * N:].reshape(B, T, G, N),
+            jax.nn.softplus(dt + lp["dt_bias"]))
+
+
+def mamba2_residual(cfg: TransformerConfig, lp, x, y, z):
+    """A mamba2 layer after its recurrence ``y`` [B, T, Hm, P] float32
+    (``D x`` added): the gate ``SiLU(z)``, THEN the RMSNorm whose
+    statistics are over each of the ``mamba2_groups`` groups of channels,
+    its gain over all of them, the output projection and the residual."""
+    B, T = x.shape[:2]
+    G = cfg.mamba2_groups
+    with jax.named_scope("mamba2_norm"):
+        g = (y.reshape(B, T, G, -1)
+             * jax.nn.silu(z.astype(jnp.float32)).reshape(B, T, G, -1))
+        g = g * lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + cfg.norm_eps)
+        o = (g.reshape(B, T, -1) * lp["o_norm"].astype(jnp.float32)
+             ).astype(cfg.dtype)
+    return x + (o @ lp["w_out"]).astype(cfg.dtype)
+
+
 # -- the linear-attention kind (ISSUE 50): projections, decays, gate --
 
 def _rope_halves(x, pos, rotary: Rotary):
@@ -1497,15 +1665,19 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
     """The trainer's entry points refuse kda, mla, mamba, sparse,
-    lightning, conv and eva layers by name: their forward exists in the
-    serve programs alone."""
+    lightning, conv, eva and mamba2 layers, and a stack of one-branch
+    layers, by name: their forward exists in the serve programs
+    alone."""
     if cfg.stateful:
         raise NotImplementedError(
             f"{what} does not run kda, mla or mamba layers, nor sparse or "
-            "lightning layers, nor conv layers, nor eva layers: "
-            "models/transformer.py's decoder_layer has no "
+            "lightning layers, nor conv layers, nor eva layers, nor mamba2 "
+            "layers or layers of one branch (one_branch): "
+            "models/transformer.py's decoder_layer is a mixer AND a "
+            "feed-forward and has no "
             "backward through the chunked delta-rule scan, the selective "
-            "scan or the decayed linear scan of serve/decode.py, no latent "
+            "scan, the decayed linear scan or the SSD chunks of "
+            "serve/decode.py, no latent "
             "attention, no selection of key blocks and no attention over "
             "a window beside chunk summaries (ROADMAP B14, B8, B18). "
             "The configuration is served through ServeEngine.")

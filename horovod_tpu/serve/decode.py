@@ -781,6 +781,83 @@ def mamba_step(u, step, a, b, c, state):
              + (step * u)[:, None] * b[..., None])
     return jnp.sum(state * c[..., None], axis=1), state
 
+
+def ssd_scan(x, dt, a, b, c, state, block: int):
+    """Mamba-2's recurrence over a chunk as state-space duality (Dao &
+    Gu 2024): matrix products over blocks of ``block`` positions, the
+    state carried between blocks.
+
+    ``x`` [B, T, Hm, P], ``dt`` [B, T, Hm] (``Delta``, >= 0), ``a`` [Hm]
+    (``A``, < 0, ONE scalar a head), ``b`` and ``c`` [B, T, G, N] (head h
+    reads group ``h // (Hm / G)``), ``state`` [B, Hm, P, N], all
+    float32. A head:
+
+        S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) b_t
+        y_t = S_t c_t
+
+    Returns ``(y [B, T, Hm, P], the state after the last position)``;
+    the caller adds ``D x``. A position with ``dt = 0`` leaves the state
+    as it was (decay 1, drive 0): that is how a bucket's padding is
+    written, and how ``T`` is padded to whole blocks here. Inside a
+    block, with ``L`` the running sum of ``dt a`` from the block's
+    start: ``Y = exp(L) (S_0 C) + ((C B^T) * D) (dt X)`` with ``D_ts =
+    exp(L_t - L_s)`` for s <= t, and ``S_end = exp(L_end) S_0 + B^T
+    (exp(L_end - L) dt X)`` (each exponent <= 0: nothing overflows at
+    any block size). The products are by GROUP where the operand is
+    (``C B^T`` is made once a group, not once a head). Held to
+    :func:`ssd_step` a position at a time in the tests."""
+    B, T, Hm, P = x.shape
+    G, N = b.shape[2:]
+    pad = -T % block
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    n = (T + pad) // block
+
+    def blocks(v):                                   # -> [n, B, block, ..]
+        return jnp.moveaxis(v.reshape(B, n, block, *v.shape[2:]), 1, 0)
+
+    causal = jnp.tril(jnp.ones((block, block), bool))
+
+    def one_block(S, xs):
+        xb, dtb, bb, cb = xs
+        run = jnp.cumsum(dtb * a, axis=1)                    # [B, C, Hm] <= 0
+        # D_ts = exp(L_t - L_s), s <= t                      [B, Hm, C, C]
+        gap = run[:, :, None] - run[:, None, :]              # [B, t, s, Hm]
+        decay = jnp.exp(jnp.where(causal[None, :, :, None], gap, -jnp.inf))
+        decay = jnp.moveaxis(decay, -1, 1).reshape(B, G, Hm // G, block,
+                                                   block)
+        scores = jnp.einsum("btgn,bsgn->bgts", cb, bb)       # C B^T a group
+        drive = (dtb[..., None] * xb).reshape(B, block, G, Hm // G, P)
+        inside = jnp.einsum("bghts,bsghp->btghp",
+                            scores[:, :, None] * decay, drive)
+        Sg = S.reshape(B, G, Hm // G, P, N)
+        before = jnp.einsum("bghpn,btgn->btghp", Sg, cb) * jnp.exp(
+            run).reshape(B, block, G, Hm // G, 1)
+        to_end = jnp.exp(run[:, -1:] - run)                  # [B, C, Hm]
+        new = (jnp.exp(run[:, -1]).reshape(B, G, Hm // G, 1, 1) * Sg
+               + jnp.einsum("bsghp,bsgn->bghpn",
+                            drive * to_end.reshape(B, block, G, Hm // G, 1),
+                            bb))
+        return new.reshape(B, Hm, P, N), (inside + before).reshape(
+            B, block, Hm, P)
+
+    state, y = lax.scan(one_block, state, tuple(map(blocks, (x, dt, b, c))))
+    return jnp.moveaxis(y, 0, 1).reshape(B, T + pad, Hm, P)[:, :T], state
+
+
+def ssd_step(x, dt, a, b, c, state):
+    """One position of :func:`ssd_scan`'s recurrence a row: ``x`` [S,
+    Hm, P], ``dt`` [S, Hm], ``b`` and ``c`` [S, G, N], ``state`` [S, Hm,
+    P, N]. Returns ``(y [S, Hm, P], the new state)``: the state read
+    once and written once."""
+    Hm, G = x.shape[1], b.shape[1]
+    bh, ch = (jnp.repeat(v, Hm // G, axis=1)[:, :, None] for v in (b, c))
+    state = (jnp.exp(dt * a)[..., None, None] * state
+             + (dt[..., None] * x)[..., None] * bh)
+    return jnp.sum(state * ch, axis=-1), state
+
+
 #: Positions a block of :func:`lightning_scan` holds: the inside of a
 #: block is two masked ``[block, block]`` products a head, the state
 #: two ``[block, Dh] x [Dh, Dh]`` products; at 128 = Dh the two cost
@@ -1199,10 +1276,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     is :func:`moe_share_report`'s). The caches ``kc`` and ``vc`` are
     tuples with one array a kind of layer, in
     ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
-    is what) for the nine kinds of layer: pages behind the block tables
+    is what) for the ten kinds of layer: pages behind the block tables
     for ``full``, ``mla`` and ``sparse`` layers, and rings, recurrent
     states and convolution rows for ``sliding``, ``kda``, ``mamba``,
-    ``lightning`` and ``conv`` layers, one a batch slot (slot 0 is the
+    ``lightning``, ``conv`` and ``mamba2`` layers, one a batch slot (slot 0 is the
     null slot, as block 0 is the null block); an ``eva`` layer alone has
     BOTH halves, its open window's K and V rows by slot and its chunk
     summaries in pages behind the tables. An address is a pair too:
@@ -1230,8 +1307,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 logits = logits[..., 0, :]      # row 0: the next token
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     place = {kind: n for n, kind in enumerate(state_kinds(cfg))}
-    # layer -> (its list, its index there, its kind, its index in its cache)
-    plan, seen = [], dict.fromkeys(place, 0)
+    # layer -> (its list, its index there, its kind, its index in its
+    # cache); a layer that is a feed-forward alone (tf_lib.FFN) is of no
+    # kind that keeps anything
+    plan, seen = [], dict.fromkeys((*place, tf_lib.FFN), 0)
     for i in range(cfg.n_layers):
         stack, j = (("dense_layers", i) if i < cfg.n_dense_layers
                     else ("layers", i - cfg.n_dense_layers))
@@ -1265,10 +1344,15 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         arrays."""
         for i, (stack, j, kind, c) in enumerate(plan):
             lp = params[stack][j]
-            with jax.named_scope("attn"):
-                kc, vc, x = kinds[kind][call.step](call, lp, kc, vc, c, x, i)
-            with jax.named_scope("mlp"):
-                x, _aux = tf_lib.ffn_block(cfg, lp, x, moe_fn)
+            # a stack of one-branch layers: the mixer alone, or ("ffn")
+            # the feed-forward alone
+            if kind != tf_lib.FFN:
+                with jax.named_scope("attn"):
+                    kc, vc, x = kinds[kind][call.step](call, lp, kc, vc, c,
+                                                       x, i)
+            if kind == tf_lib.FFN or not cfg.one_branch:
+                with jax.named_scope("mlp"):
+                    x, _aux = tf_lib.ffn_block(cfg, lp, x, moe_fn)
         return kc, vc, x
 
     def put(cache, kind, at, new):
@@ -1458,6 +1542,47 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                     kc = put(kc, "mamba", (c, call.slot), state[0])
                     vc = put(vc, "mamba", (c, call.slot), newest.reshape(-1))
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
+
+    def mamba2_chunk(call, lp, kc, vc, c, x, i):
+        """The chunk's SSD from the state and the convolution's rows the
+        slot holds (zeros for a sequence's first chunk, whatever the
+        slot held), and both back as they are AT ``length``: a padded
+        position's step is 0, which decays nothing and drives nothing,
+        and the rows kept are the last real ones."""
+        B, T = x.shape[:2]
+        n = place["mamba2"]
+        taps, W = cfg.mamba_d_conv, cfg.mamba2_conv_width
+        with jax.named_scope("attn_mamba2"):
+            with jax.named_scope("mamba2_proj"):
+                z, xbc, dt = tf_lib.mamba2_rows(cfg, lp, x)
+            state = jnp.zeros((B, cfg.mamba2_heads, cfg.mamba2_head_dim,
+                               cfg.mamba_d_state), jnp.float32)
+            before = jnp.zeros((B, taps - 1, W), xbc.dtype)
+            if not call.local:
+                resumed = call.offset > 0
+                state = jnp.where(resumed, kc[n][c, call.slot][None], state)
+                before = jnp.where(
+                    resumed, vc[n][c, call.slot].reshape(before.shape),
+                    before)
+            with jax.named_scope("conv_taps"):
+                xs, b, cc, step = tf_lib.mamba2_inputs(cfg, lp, xbc, dt,
+                                                       before)
+                real = jnp.arange(T)[None, :, None] < call.length
+                step = jnp.where(real, step, 0.0)
+            with jax.named_scope("mamba2_scan"):
+                y, state = ssd_scan(xs, step, -jnp.exp(lp["a_log"]), b, cc,
+                                    state, cfg.mamba2_chunk)
+                y = y + lp["d_skip"][:, None] * xs
+            if kc is not None:
+                with jax.named_scope("state_write"):
+                    newest = lax.dynamic_slice_in_dim(
+                        jnp.concatenate([before, xbc], 1)[0], call.length,
+                        taps - 1)
+                    kc = put(kc, "mamba2", (c, call.slot), state[0])
+                    vc = put(vc, "mamba2", (c, call.slot),
+                             newest.reshape(-1))
+            x = tf_lib.mamba2_residual(cfg, lp, x, y, z)
+        return kc, vc, x
 
 
     # A sparse layer's sizes: `per` kernels start in a page, a kernel
@@ -1830,6 +1955,36 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                                  u.shape[0], -1))
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
 
+    def mamba2_step_layer(call, lp, kc, vc, c, x, i):
+        """One step of the recurrence on every slot's state where it
+        lies (4 MB a slot: read once, written once, never gathered), the
+        batch's rows carried to their slots and the results back, as
+        :func:`kda_step_layer` does: a slot that is not in the batch is
+        stepped by 0, so its state is what it was."""
+        n = place["mamba2"]
+        n_slots = kc[n].shape[1]
+        with jax.named_scope("attn_mamba2"):
+            with jax.named_scope("mamba2_proj"):
+                z, xbc, dt = tf_lib.mamba2_rows(cfg, lp, x)
+            before = vc[n][c, call.slots].reshape(
+                x.shape[0], cfg.mamba_d_conv - 1, -1)
+            with jax.named_scope("conv_taps"):
+                xs, b, cc, step = tf_lib.mamba2_inputs(cfg, lp, xbc, dt,
+                                                       before)
+            with jax.named_scope("mamba2_step"):
+                xr, dr, br, cr = (by_slot(call, r[:, 0], n_slots)
+                                  for r in (xs, step, b, cc))
+                y, state = ssd_step(xr, dr, -jnp.exp(lp["a_log"]), br, cr,
+                                    kc[n][c])
+                y = y[call.slots][:, None] + lp["d_skip"][:, None] * xs
+            with jax.named_scope("state_write"):
+                kc = put(kc, "mamba2", (c,), state)
+                vc = put(vc, "mamba2", (c, call.slots),
+                         jnp.concatenate([before, xbc], 1)[:, 1:].reshape(
+                             x.shape[0], -1))
+            x = tf_lib.mamba2_residual(cfg, lp, x, y, z)
+        return kc, vc, x
+
 
     def sparse_step(call, lp, kc, vc, c, x, i):
         """A row's new key into its page and, where it completes a
@@ -2024,6 +2179,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                       "step": lightning_step_layer},
         "conv": {"chunk": conv_chunk, "step": conv_step_layer},
         "eva": {"chunk": eva_chunk, "step": eva_step},
+        "mamba2": {"chunk": mamba2_chunk, "step": mamba2_step_layer},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -2123,8 +2279,8 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
                 "layer's ring and a kda or mamba layer's recurrent state "
-                "(a lightning layer's too, a conv layer's rows and an eva "
-                "layer's open window) are not "
+                "(a lightning or mamba2 layer's too, a conv layer's rows "
+                "and an eva layer's open window) are not "
                 "pages another engine or "
                 "a draft could be handed, nor are a sparse layer's "
                 "compressed keys, "
@@ -2149,11 +2305,20 @@ def moe_share_report(params, tokens, cfg, block_size: int = 16):
     held experts (the largest layer's) and
     ``moe_dispatch_dropped_token_frac`` (pairs on held experts, as the
     router chose them, that the dispatch's sort and group sizes do not
-    run through their expert: ``moe.held_pairs_not_run``)."""
+    run through their expert: ``moe.held_pairs_not_run``). The held
+    pairs a layer that ran and did not, and the held experts touched,
+    are also recorded (``moe.record_moe_stats``) and shown by the
+    engine's ``metrics.snapshot()``."""
     count = mixed_programs(cfg, block_size, 1, 0)[3]
     counts, not_run = jax.jit(count)(params, jnp.asarray(tokens, jnp.int32))
     pairs = tokens.shape[0] * tokens.shape[1] * cfg.moe.top_k
     summary = moe_lib.routing_summary(counts, not_run)
+    # the last report's, a layer: what ``metrics.snapshot()`` shows
+    moe_lib.record_moe_stats({
+        "moe_held_pairs_run": float(counts.sum(-1).mean()
+                                    - not_run.mean()),
+        "moe_held_pairs_not_run": float(not_run.mean()),
+        "moe_held_experts_touched_mean": float((counts > 0).sum(-1).mean())})
     return {
         "moe_local_pair_share": float(counts.sum(-1).mean()) / pairs,
         "moe_held_experts_touched_mean": float((counts > 0).sum(-1).mean()),

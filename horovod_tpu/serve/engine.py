@@ -462,7 +462,8 @@ class ServeEngine:
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, a kda, mamba or lightning layer's state, a "
+                 "a window, a kda, mamba, mamba2 or lightning layer's "
+                 "state, a "
                  "conv layer's rows and an eva layer's open window "
                  "after a prefix, cannot be mapped into another sequence: "
                  "engine._admit, kv_cache.BlockAllocator)",
@@ -1003,8 +1004,9 @@ class ServeEngine:
         if self.cfg.prefix_caching:
             # positions this call attends that it did not compute
             extra["mapped"], seq.mapped = seq.mapped, 0
-        if "mamba" in self.cache.kinds:
-            # positions the selective scan runs: the bucket, pads too
+        if "mamba" in self.cache.kinds or "mamba2" in self.cache.kinds:
+            # positions the selective scan (a mamba2 layer's SSD blocks)
+            # runs: the bucket, pads too
             extra["scanned"] = len(toks)
         if "conv" in self.cache.kinds:
             # positions the short convolutions run and the mixture
@@ -1097,7 +1099,7 @@ class ServeEngine:
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
                 f"configuration with {held} layers keeps a window layer's "
-                "keys in per-slot rings, a kda, mamba or lightning "
+                "keys in per-slot rings, a kda, mamba, mamba2 or lightning "
                 "layer's recurrent state, a conv layer's rows and an eva "
                 "layer's open window's rows by slot, "
                 "which are not pages (nor "
@@ -1461,7 +1463,9 @@ class ServeEngine:
             # trace ids of every sampled sequence in it (plural key).
             traces = [s.trace for s in rows if s is not None and s.trace]
             extra = {"traces": traces} if traces else {}
-            if "mamba" in self.cache.kinds:
+            if "mamba" in self.cache.kinds or "mamba2" in self.cache.kinds:
+                # (a mamba2 layer's step does step every slot's SSD state
+                # where it lies, the null slot's too: mamba2_step_layer)
                 # slots whose state the XLA form of the step read and wrote
                 # where it lies (since PR 48 the kernels touch the batch's
                 # rows alone; the count stays what its reader in the

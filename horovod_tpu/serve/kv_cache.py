@@ -293,7 +293,7 @@ class BlockAllocator:
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
 STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv", "eva")
+               "lightning", "conv", "eva", "mamba2")
 #: Those of them whose arrays lie by batch slot and not behind the block
 #: tables: nothing of theirs is a page that another sequence, another
 #: engine or a draft could be handed (what ``engine.py`` refuses over
@@ -314,7 +314,7 @@ STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
 #: ``inject`` move pages alone) and not in ``RECURRENT_KINDS``: the rows
 #: are the window's own keys and values in the cache's dtype, nothing
 #: is folded over positions and a closed window's rows are dead.
-RECURRENT_KINDS = ("kda", "mamba", "lightning")
+RECURRENT_KINDS = ("kda", "mamba", "lightning", "mamba2")
 SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv", "eva")
 
 
@@ -407,6 +407,17 @@ class KVCache:
       so a stack that is cut into 1024-chunks takes a smaller one, and
       ``full`` layers beside it share the block size.)
 
+    * ``mamba2``: the SSD state ``[n, n_slots + 1, Hm, P, N]`` float32
+      (``Hm = mamba_expand * d_model / mamba2_head_dim`` heads of ``P =
+      mamba2_head_dim`` values by ``N = mamba_d_state`` columns: 4 MB a
+      slot a layer at Nemotron's 128 x 64 x 128, thirteen times a mamba
+      layer's; ``N`` innermost fills the chip's 128 lanes as published,
+      so nothing is turned), and the newest ``mamba_d_conv - 1`` rows
+      before the convolution, end to end as a mamba layer's, ``[n,
+      n_slots + 1, (mamba_d_conv - 1) * (Di + 2 G N)]``. A layer that
+      ``layer_types`` names ``"ffn"`` (``one_branch``) keeps nothing and
+      has no array.
+
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
     however long a sequence grows. A slot needs no cleaning: a new
@@ -476,9 +487,15 @@ def page_tail(cfg) -> Tuple[int, ...]:
     decode step and 7.1 GB of temporaries beside 11.4 GB of arguments
     (compiled for the v5e, PR 54). As rows of 512 the layout the scatter
     and the gather read is the array's own. A head of whole lanes keeps
-    its own dimension: those programs lower as before."""
+    its own dimension (those programs lower as before), but for 2 to 7
+    of them: ``[.., block, 2, 128]`` is kept in tiles of (2, 128), and a
+    chunk's scatter of whole blocks and its gather behind the table turn
+    the whole pool to blocks-second-innermost and back, 4 copies of 335
+    MB a chunk at Nemotron's 2 heads (8 M cycles each: compiled for the
+    v5e, PR 60; none as rows of 256). One head (Jamba2's) and eight or
+    more (Trinity's) fill or need no such tile and stay as they were."""
     heads, width = cfg.n_kv_heads, cfg.head_dim
-    if width % 128 and (heads * width) % 128 == 0:
+    if (heads * width) % 128 == 0 and (width % 128 or 1 < heads < 8):
         return (heads * width,)
     return (heads, width)
 
@@ -555,6 +572,12 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
             "conv": (((n["conv"], n_slots + 1,
                        (cfg.conv_taps - 1) * cfg.d_model), dtype), None),
             "eva": (eva, eva),   # (K rows, k~ pages), (V rows, v~ pages)
+            "mamba2": (((n["mamba2"], n_slots + 1, cfg.mamba2_heads,
+                         cfg.mamba2_head_dim, cfg.mamba_d_state),
+                        jnp.float32),
+                       ((n["mamba2"], n_slots + 1,
+                         (cfg.mamba_d_conv - 1) * cfg.mamba2_conv_width),
+                        dtype)),
         }
         kinds = state_kinds(cfg)
 

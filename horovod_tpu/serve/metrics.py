@@ -986,6 +986,14 @@ class ServeMetrics:
             # (models/moe.py counts it as it traces; 0.0 without any)
             "moe_grouped_kernel_products_share": moe_metrics().get(
                 "moe_grouped_kernel_products_share", 0.0),
+            # a chip's share of the experts, a layer, on the tokens of
+            # the last decode.moe_share_report (a set-up program; zeros
+            # before one): held pairs its dispatch ran and did not run
+            # (the second is 0 unless the sort or a bound loses a pair),
+            # and the held experts with at least one pair
+            **{key: moe.get(key, 0.0) for moe in [moe_metrics()]
+               for key in ("moe_held_pairs_run", "moe_held_pairs_not_run",
+                           "moe_held_experts_touched_mean")},
             "queue_depth": self.queue_depth,
             "max_queue_depth": self.max_queue_depth,
             # device calls and host gaps many times their kind's

@@ -325,7 +325,10 @@ def test_record_moe_stats_counters_gauges_and_export():
             "moe_expert_load_max_over_mean": 1.5,
             "moe_compact_calls_share": 0.875,
             "moe_held_pairs_over_bound_max": 1.25,
-            "moe_grouped_kernel_products_share": 0.5})
+            "moe_grouped_kernel_products_share": 0.5,
+            "moe_held_pairs_run": 704.0,
+            "moe_held_pairs_not_run": 0.0,
+            "moe_held_experts_touched_mean": 116.0})
         m = moe_lib.moe_metrics()
         assert m["moe_dispatch_overflow_tokens_total"] == 5.0
         assert m["moe_dispatch_dropped_token_frac"] == 0.125
@@ -333,6 +336,7 @@ def test_record_moe_stats_counters_gauges_and_export():
         assert m["moe_compact_calls_share"] == 0.875
         assert m["moe_held_pairs_over_bound_max"] == 1.25
         assert m["moe_grouped_kernel_products_share"] == 0.5
+        assert m["moe_held_pairs_run"] == 704.0
         text = metrics_prometheus()
         for key in moe_lib.MOE_METRIC_KEYS:
             assert f"{NAMESPACE}_{key}" in text, key

@@ -1693,3 +1693,124 @@ def test_the_eva_programs_lower_for_the_v5e_at_the_cell_s_shapes(program):
     # (`attention_inputs`): as `h @ w` reshaped afterwards the compiler
     # transposed wq, wk and wv, 32 MB each, in every call (3 a layer)
     assert got["weight_transposes"] == 0, got
+
+
+# The serve programs of a stack of ONE-BRANCH layers (ISSUE 60): a
+# mamba2 layer's SSD state of 4 MB a slot, pages of 2 KV heads of 128 as
+# one row of 256, and a latent mixture of ungated experts held in part,
+# at the published widths and few slots.
+_NEMOTRON_DRIVER = r"""
+import collections, json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=32768, d_model=4096, n_layers=4, n_heads=32, n_kv_heads=2,
+    d_head=128, d_ff=2688, max_seq=5120, norm_eps=1e-5,
+    layer_types=("mamba2", "ffn", "full", "mamba2"), one_branch=True,
+    mamba_d_state=128, mamba_d_conv=4, mamba_expand=2, mamba2_head_dim=64,
+    mamba2_groups=8, mamba2_chunk=128, n_experts=512, moe_top_k=22,
+    moe_capacity_factor=None, moe_scoring="sigmoid", moe_route_scale=5.0,
+    moe_shared_expert=True, moe_experts_held=32, moe_expert_offset=128,
+    moe_activation="relu2", moe_latent=1024, moe_shared_d_ff=5376,
+    dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS, CHUNK = 16, 320, 64, 1024
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS))))
+_, resume, decode, _, _ = decode_lib.make_serve_fns(
+    cfg, None, block_size=BS, table_width=WIDTH)
+pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
+state = "f32[%s]" % ",".join(map(str, kc[1].shape))
+out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
+       "state": state, "state_bytes": kc[1].size * 4}}
+for name, fn, args in (
+        ("decode", decode,
+         (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
+        ("prefill_resume", resume,
+         (i32(CHUNK), i32(), i32(), (i32(WIDTH), i32())))):
+    compiled = fn.lower(params, kc, vc, *args).compile()
+    text = compiled.as_text()
+    results = re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text)
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    out[name] = {{
+        "state_ops": collections.Counter(
+            opcode for result, opcode in results if result == state),
+        "pool_copies": sum(result == pool and opcode == "copy"
+                           for result, opcode in results),
+        "aliased": len(re.findall(r"may-alias|must-alias",
+                                  aliased.group(1))),
+        # the widths the compiler's grouped products come out at
+        "ragged_dots": sorted(set(re.findall(
+            r"%ragged-dot-(?!metadata)\S+ = bf16\[\d+,(\d+)\][^\n]* "
+            r"custom-call\(", text))),
+        "grouped_kernels": len(re.findall(
+            r"custom-call\(.*/hvd_grouped_matmul/pallas_call", text)),
+        "paged_decode": len(re.findall(
+            r"custom-call\(.*attn_full/.*hvd_paged_decode/pallas_call",
+            text)),
+        "scopes": sorted(set(re.findall(
+            r"attn_mamba2/(\w+)", text)) | set(re.findall(
+                r"/(moe_\w+)/", text))),
+        "both_branches": len(re.findall(
+            r'op_name="jit\(\w+\)/attn/[^"]*\bmlp\b', text)),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_the_one_branch_programs_lower_for_the_v5e(program):
+    """ISSUE 60: a decode step at 64 slots and a chunk of 1024 of a
+    stack of one-branch layers (mamba2, ffn, full, mamba2) at
+    Nemotron-3-Super's widths compile for the v5e with K and V pages, the
+    SSD state and the convolution's rows aliased in and out; the pages
+    are rows of 256 (``kv_cache.page_tail``: as ``[.., 16, 2, 128]`` a
+    chunk turned the whole pool over four times) and never copied
+    whole; the state array (1.1 GB here) is only ever updated where it
+    lies; the held experts' ungated form is TWO kinds of ``ragged-dot``
+    product a mixture layer (up to 2688, down to the latent's 1024: no
+    gate's) and no kernel of the whole mixture's; every scope the
+    benchmark reads by name is in the program; and a call's temporaries
+    stay under the state's size."""
+    out = _compile_for_v5e(_NEMOTRON_DRIVER)
+    got = out[program]
+    assert out["pool"] == "bf16[1,20481,16,256]", out
+    assert out["state"] == "f32[2,65,128,64,128]", out
+    assert got["aliased"] == 4, got
+    assert got["pool_copies"] == 0, got
+    assert set(got["state_ops"]) <= {"parameter", "get-tuple-element",
+                                     "bitcast", "fusion",
+                                     "dynamic-update-slice"}, got
+    assert got["ragged_dots"] == ["1024", "2688"], got
+    assert got["grouped_kernels"] == 0, got
+    assert got["paged_decode"] == (1 if program == "decode" else 0), got
+    step = "mamba2_step" if program == "decode" else "mamba2_scan"
+    assert set(got["scopes"]) >= {
+        "mamba2_proj", "conv_taps", step, "state_write", "mamba2_norm",
+        "moe_router", "moe_latent_down", "moe_dispatch", "moe_experts",
+        "moe_combine", "moe_latent_up", "moe_shared"}, got
+    assert got["temp_bytes"] < out["state_bytes"], got
