@@ -18,10 +18,11 @@ A configuration without a capacity (``capacity_factor=None``: OLMoE and
 the other fine-grained sparse decoders) takes :func:`moe_ffn_dropless`
 instead: the ``N·K`` (token, choice) pairs are sorted by expert, the
 experts run as one grouped matmul over the sorted rows
-(:func:`_grouped_product`: ``ops/grouped_matmul.py``'s kernel where the
-matrices' bytes bound the product, ``lax.ragged_dot``, which the TPU
-compiler lowers to a Mosaic grouped matmul, where the matrix unit
-does), and nothing is dropped. Its largest value is ``[N·K, D]``;
+(:func:`_grouped_product`, the whole mixture's and a chip's share's
+alike: ``ops/grouped_matmul.py``'s kernel where the matrices' bytes
+bound the product, ``lax.ragged_dot``, which the TPU compiler lowers to
+a Mosaic grouped matmul, where the matrix unit does), and nothing is
+dropped. Its largest value is ``[N·K, D]``;
 the one-hot tensors above, whose size grows with ``E · C``, do not
 exist there. :func:`make_moe_ffn` picks between the two from the
 configuration alone.
@@ -108,11 +109,10 @@ class MoEConfig:
             raise ValueError(
                 "an ungated activation, a latent around the experts and a "
                 "shared expert of its own width are the held dispatch's "
-                "(experts_held, a chip's share, which may be all of them): "
-                "the whole mixture's dispatch (moe_ffn_dropless) and "
-                "ops/grouped_matmul.py's kernel, whose rule (taken) is "
-                "tuned on its three products, run a SwiGLU at the model's "
-                "width")
+                "(experts_held, a chip's share, which may be all of them; "
+                "its products take ops/grouped_matmul.py's kernel by the "
+                "same rule, taken): the whole mixture's dispatch "
+                "(moe_ffn_dropless) runs a SwiGLU at the model's width")
         if not (1 <= self.topk_group <= self.n_group
                 and self.n_experts % self.n_group == 0
                 and (self.n_group == 1 or self.top_k
@@ -397,20 +397,26 @@ def _sorted_by_expert(experts, n_experts: int):
     return order, sizes
 
 
-#: Grouped products of the whole mixture traced in this process, and of
-#: them those that took the kernel (``moe_grouped_kernel_products_share``).
+#: Grouped products traced in this process (the whole mixture's and a
+#: held share's alike), and of them those that took the kernel
+#: (``moe_grouped_kernel_products_share``).
 _grouped_traced = [0, 0]
 
 
 def _grouped_product(rows, w, sizes, sharded: bool = False):
-    """One of the whole mixture's three products, ``rows`` [N·K, ·]
-    sorted by expert times ``w`` [E, ·, ·]: through
-    ``ops/grouped_matmul.py``'s kernel (``hvd_grouped_matmul``: each
-    expert's matrix read once, whole) where ``grouped_matmul.taken``
+    """One grouped product of a mixture, the whole one's
+    (:func:`moe_ffn_dropless`) or a held share's (:func:`_held_rows`):
+    ``rows`` [M, ·] sorted by expert times ``w`` [G, ·, ·], ``sizes``
+    [G] with ``sizes.sum() <= M`` (a held share's other pairs lie
+    behind the last group; what the product leaves there is no result).
+    Through ``ops/grouped_matmul.py``'s kernel (``hvd_grouped_matmul``:
+    each expert's matrix read once, whole) where ``grouped_matmul.taken``
     says the matrices' bytes bound it, from the shapes alone: the mean
     rows an expert under the chip's operations a byte (the LFM2 cell's
-    16 in a decode step and 32 to 128 in a chunk); ``lax.ragged_dot``
-    otherwise (OLMoE's trainer's 1024), and for a shard of a mesh's
+    16 in a decode step and 32 to 128 in a chunk; 22 and up to 176 over
+    the Nemotron cell's 128 held experts); ``lax.ragged_dot`` otherwise
+    (OLMoE's trainer's 1024, mellum's 1536 and more, Kimi's two
+    matrices of 29 MB over the buffer), and for a shard of a mesh's
     tokens (``sharded``: a Pallas result says nothing of the axes it
     varies over, which :func:`_dropless_over_mesh`'s ``shard_map``
     checks). The choice is made as the program is traced and counted
@@ -471,7 +477,7 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
         # so the choices are counted without a sort.
         claims = (experts[..., None] == jnp.arange(E, dtype=experts.dtype)
                   ).sum((0, 1))
-        return (_held_experts(x, lp, cfg, gates, experts),
+        return (_held_experts(x, lp, cfg, gates, experts, token_axes),
                 router_losses(claims))
     with jax.named_scope("moe_dispatch"):
         order, sizes = _sorted_by_expert(experts, E)
@@ -542,13 +548,17 @@ def held_row_bound(pairs: int, cfg: MoEConfig) -> Optional[int]:
     return bound if pairs - bound >= _COMPACT_MIN_ROWS_SAVED else None
 
 
-def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None):
+def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None,
+               sharded: bool = False):
     """The routed sum ``y`` [N, D] of the held experts over the first
     ``rows`` sorted places (``None``: all ``N·K`` of them). ``order``
     puts the held pairs first, by expert, so with ``sizes.sum() <=
     rows`` the places left out hold no pair that counts: no row is
     gathered for them, no matmul, no SwiGLU, and the combine's ``N·K``
-    slots read a source of ``rows`` rows."""
+    slots read a source of ``rows`` rows. The two (ungated) or three
+    products are :func:`_grouped_product`'s, the whole mixture's rule:
+    the kernel where the matrices' bytes bound them, unless the tokens
+    are one shard of a mesh's (``sharded``)."""
     K = gates.shape[1]
     w_gate, w_up, w_down = w
     with jax.named_scope("moe_dispatch"):
@@ -559,26 +569,28 @@ def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None):
     with jax.named_scope("moe_experts"):
         if w_gate is None:
             # ungated: relu(x Wu)^2, two products and not three
-            h = jnp.square(jax.nn.relu(
-                lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)))
+            h = jnp.square(jax.nn.relu(_grouped_product(
+                taken, w_up, sizes, sharded).astype(jnp.float32)))
         else:
-            g = jax.nn.silu(lax.ragged_dot(taken, w_gate, sizes)
+            g = jax.nn.silu(_grouped_product(taken, w_gate, sizes, sharded)
                             .astype(jnp.float32))
-            u = lax.ragged_dot(taken, w_up, sizes).astype(jnp.float32)
+            u = _grouped_product(taken, w_up, sizes, sharded
+                                 ).astype(jnp.float32)
             h = g * u
-        out = lax.ragged_dot(h.astype(xf.dtype), w_down, sizes)
+        out = _grouped_product(h.astype(xf.dtype), w_down, sizes, sharded)
     with jax.named_scope("moe_combine"):
-        # what lies behind the last group is not a result: masked, not
-        # multiplied by a zero gate
+        # what lies behind the last group is not a result (the kernel
+        # leaves those rows unwritten): masked, not multiplied by a zero
+        # gate
         out = jnp.where(held.reshape(-1, 1),
                         _take_unsorted(out, order, inverse), 0)
         return jnp.einsum("nkd,nk->nd", out.reshape(-1, K, xf.shape[-1]),
                           gates.astype(xf.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _held_rows_bounded(bound: int, xf, w, gates, held, order, inverse,
-                       sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_rows_bounded(bound: int, sharded: bool, xf, w, gates, held, order,
+                       inverse, sizes):
     """:func:`_held_rows` over ``bound`` rows where the held pairs fit
     them and over all ``N·K`` where they do not: the same sum either
     way, every held pair run. The branch is taken outside
@@ -586,22 +598,25 @@ def _held_rows_bounded(bound: int, xf, w, gates, held, order, inverse,
     are the block's own inputs: ``jax.grad`` through a ``lax.cond``
     would make both branches' residuals results of the forward and
     fill the untaken one's ``[N·K, ·]`` with zeros every layer."""
-    return lax.cond(sizes.sum() <= bound,
-                    functools.partial(_held_rows, rows=bound), _held_rows,
-                    xf, w, gates, held, order, inverse, sizes)
+    return lax.cond(
+        sizes.sum() <= bound,
+        functools.partial(_held_rows, rows=bound, sharded=sharded),
+        functools.partial(_held_rows, sharded=sharded),
+        xf, w, gates, held, order, inverse, sizes)
 
 
-def _held_rows_bounded_fwd(bound, *args):
-    return _held_rows_bounded(bound, *args), args
+def _held_rows_bounded_fwd(bound, sharded, *args):
+    return _held_rows_bounded(bound, sharded, *args), args
 
 
-def _held_rows_bounded_bwd(bound, res, g):
+def _held_rows_bounded_bwd(bound, sharded, res, g):
     xf, w, gates, *how = res
 
     def pull(rows):
         def branch(xf, w, gates, g):
-            return jax.vjp(lambda *a: _held_rows(*a, *how, rows=rows),
-                           xf, w, gates)[1](g)
+            return jax.vjp(
+                lambda *a: _held_rows(*a, *how, rows=rows, sharded=sharded),
+                xf, w, gates)[1](g)
         return branch
 
     return (*lax.cond(how[-1].sum() <= bound, pull(bound), pull(None),
@@ -611,7 +626,7 @@ def _held_rows_bounded_bwd(bound, res, g):
 _held_rows_bounded.defvjp(_held_rows_bounded_fwd, _held_rows_bounded_bwd)
 
 
-def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
+def _held_experts(x, lp, cfg: MoEConfig, gates, experts, token_axes=()):
     """One chip's part of a MoE block whose experts are spread over
     chips (``experts_held``): of the ``N·K`` (token, choice) pairs the
     router made over ALL experts, those on a held expert are sorted to
@@ -623,7 +638,8 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
     are gathered, multiplied and combined, unless a call's held pairs
     exceed it, which then runs all ``N·K``. The shared expert
     is added here once: summed over chips, the deployment adds it on
-    one of them.
+    one of them. ``token_axes`` is :func:`moe_ffn_dropless`'s: a shard
+    of a mesh's tokens keeps ``lax.ragged_dot`` for its products.
 
     Differentiable: gradients reach the held experts' matrices, the
     router through the gates of held pairs, and ``x``; both row
@@ -645,11 +661,13 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts):
         # of a row at Nemotron's 1024 of 4096
         with jax.named_scope("moe_latent_down"):
             rows = xf @ lp["latent_down"]
+    sharded = bool(token_axes)
     if bound is None:
-        y = _held_rows(rows, w, gates, held, order, inverse, sizes)
+        y = _held_rows(rows, w, gates, held, order, inverse, sizes,
+                       sharded=sharded)
     else:
-        y = _held_rows_bounded(bound, rows, w, gates, held, order, inverse,
-                               sizes)
+        y = _held_rows_bounded(bound, sharded, rows, w, gates, held, order,
+                               inverse, sizes)
     if cfg.latent:
         # after the sum over a token's experts: linear, so once
         with jax.named_scope("moe_latent_up"):

@@ -1,5 +1,6 @@
 """A grouped matmul over rows sorted by group, each group's matrix read
-once.
+once: a whole mixture's products (``moe.moe_ffn_dropless``) and a chip's
+share of the experts' (``moe._held_rows``) alike.
 
 ``grouped_matmul(lhs [M, K], rhs [G, K, N], sizes [G]) -> [M, N]`` has
 the semantics of ``lax.ragged_dot``: rows ``sizes[:g].sum() ..
@@ -104,7 +105,47 @@ def taken(lhs, rhs) -> bool:
     the matrix unit's side too, so ``_MAX_ROWS_A_GROUP`` marks where the
     bytes stop bounding a product (the chip's 240 operations a byte),
     not where the kernel stops winning; the trainers keep
-    ``lax.ragged_dot`` until their backward has kernels too."""
+    ``lax.ragged_dot`` until their backward has kernels too.
+
+    A chip's SHARE of the experts (``moe._held_rows``, since ISSUE 61
+    under the same rule; the v5e, 2026-10-04, the same tool with
+    ``--held-share``: of the ``M = N·K`` rows only the share's own lie
+    in a group, the others behind the last one, which cost the kernel
+    grid steps that do nothing; ``router`` sizes, row tile 128; gate or
+    up / down, ms a call as above, GB/s of the matrices the groups with
+    rows read):
+
+    ======== ================== ===== ======== ============= ================= =========
+    cell     rhs                M     in group ragged_dot    kernel@128        GB/s
+    ======== ================== ===== ======== ============= ================= =========
+    Nemotron [128, 1024, 2688]  2816  704      2.818 / 2.670 **0.991 / 0.992** 700 / 699
+    Nemotron (5.5 MB a matrix)  5632  1408     3.653 / 3.426 **1.021 / 1.025** 691 / 687
+    Nemotron                    11264 2816     3.736 / 3.559 **1.046 / 1.039** 674 / 678
+    Nemotron                    22528 5632     3.905 / 3.910 **1.079 / 1.073** 653 / 657
+    ling     [128, 2560, 768]   512   128      1.184 / 1.142 **0.469 / 0.460** 646 / 659
+    ling     (3.9 MB)           2048  512      1.846 / 1.777 **0.699 / 0.707** 687 / 679
+    ling                        4096  1024     1.925 / 1.873 **0.725 / 0.730** 688 / 684
+    ling                        8192  2048     1.975 / 1.909 **0.757 / 0.755** 665 / 666
+    trinity  [32, 3072, 3072]   128   16       0.488 / 0.486 **0.422 / 0.419** 627 / 630
+    trinity  (18.9 MB)          1024  128      1.917 / 1.915 **0.844 / 0.842** 694 / 695
+    trinity                     2048  256      1.975 / 1.978 **0.872 / 0.875** 693 / 690
+    trinity                     4096  512      1.971 / 1.970 **0.885 / 0.867** 683 / 697
+    ======== ================== ===== ======== ============= ================= =========
+
+    (A step is each cell's first row, its largest chunk the last; at a
+    step 2 of Nemotron's 128 groups, 51 of ling's and 18 of trinity's
+    32 were empty.) The kernel holds 627-700 GB/s at every shape, a
+    matrix of 18.9 MB, two and a half times LFM2's, included: two of
+    them are the most the buffer holds. The compiler's kernels pay
+    their toll a group here too (Nemotron's 21-31 us a group over 128,
+    ling's 9-15, trinity's 60 over 32 at a chunk), and only trinity's
+    step, 14 matrices touched of 32, is near even (0.49 ms for 0.42). The rows behind the last group add
+    a tenth from the step to the largest chunk (176 row tiles' steps
+    for 44 tiles of rows at Nemotron's). No shape loses, so the rule
+    stands as it was. Kimi's share (``[12, 7168, 2048]``: two matrices
+    of 29.4 MB are 58.7 MB, over ``_MATRIX_BUFFER_BYTES``) and
+    mellum's trainer's (``[16, 2304, 896]`` at 24 576 rows and more:
+    1536 a group) fall on the compiler's side by their shapes."""
     groups, k, n = rhs.shape
     return (lhs.shape[0] < _MAX_ROWS_A_GROUP * groups
             and k % _LANES == 0 and n % _LANES == 0

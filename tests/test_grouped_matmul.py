@@ -1,7 +1,8 @@
-"""A whole mixture's grouped products through ``hvd_grouped_matmul``
+"""A mixture's grouped products through ``hvd_grouped_matmul``
 (``ops/grouped_matmul.py``, interpret mode here) against
 ``lax.ragged_dot``, whose semantics it has and which
-``moe.moe_ffn_dropless`` called before ISSUE 57 and still calls where
+``moe.moe_ffn_dropless`` called before ISSUE 57, a chip's share of the
+experts (``moe._held_rows``) before ISSUE 61, and both still call where
 the matrix unit bounds the product."""
 
 import jax
@@ -133,14 +134,141 @@ def test_the_whole_mixture_takes_the_kernel_by_its_shapes(monkeypatch):
         "moe_grouped_kernel_products_share"] == 0.25
 
 
-def test_a_mesh_s_shards_keep_ragged_dot(devices):
+#: A chip's share: experts 4..7 of 16 held, four choices a token. name:
+#: (tokens, how the [tokens, 4] choices are drawn, tokens whose rows are
+#: NaN and whose choices are all moved off the held experts)
+HELD_CASES = {
+    # a uniform router: about a quarter of the 256 pairs on the share
+    "a_quarter_of_the_pairs": (64, "uniform", 0),
+    # held experts 5 and 7 get no pair at all
+    "empty_held_groups": (64, "two_empty", 0),
+    # every token chooses held expert 6: 160 rows, over two row tiles
+    "a_group_over_two_row_tiles": (160, "one_for_all", 0),
+    # the rows behind the last group hold NaN
+    "nan_behind_the_last_group": (64, "uniform", 24),
+    # nothing on the share
+    "no_held_pair": (32, "none", 0),
+}
+HELD = (4, 4)                                   # experts_held, expert_offset
+
+
+def held_share(form, dtype=jnp.bfloat16):
+    """``(cfg, lp)`` of one chip's share, a SwiGLU at the model's width
+    (``gated``: three products) or Nemotron's form (``latent_relu2``:
+    ``relu(x Wu)^2 Wd`` inside a latent of half the width, two)."""
+    more = ({} if form == "gated" else
+            {"activation": "relu2", "latent": 128})
+    cfg = moe_lib.MoEConfig(n_experts=16, top_k=4, capacity_factor=None,
+                            experts_held=HELD[0], expert_offset=HELD[1],
+                            **more)
+    lp = jax.tree.map(lambda p: p[0], moe_lib.init_moe_params(
+        jax.random.PRNGKey(3), 1, 256, 256, cfg, dtype))
+    return cfg, lp
+
+
+def held_inputs(case, dtype=jnp.bfloat16):
+    """``(x [1, tokens, 256], gates, experts [tokens, 4], poisoned)``."""
+    tokens, draw, poisoned = HELD_CASES[case]
+    rng = np.random.default_rng(61)
+    away = [e for e in range(16) if not 4 <= e < 8]
+    if draw == "none":
+        allowed = away
+    elif draw == "two_empty":
+        allowed = [e for e in range(16) if e not in (5, 7)]
+    else:
+        allowed = list(range(16))
+    experts = np.stack([rng.choice(allowed, 4, replace=False)
+                        for _ in range(tokens)])
+    if draw == "one_for_all":
+        experts[:, 1] = 6
+        experts[:, [0, 2, 3]] = np.stack(
+            [rng.choice(away, 3, replace=False) for _ in range(tokens)])
+    x = rng.standard_normal((1, tokens, 256)).astype(np.float32)
+    if poisoned:
+        experts[:poisoned] = np.stack([rng.choice(away, 4, replace=False)
+                                       for _ in range(poisoned)])
+        x[0, :poisoned] = np.nan
+    gates = rng.uniform(0.1, 0.4, (tokens, 4)).astype(np.float32)
+    return (jnp.asarray(x, dtype), jnp.asarray(gates),
+            jnp.asarray(experts, jnp.int32), poisoned)
+
+
+@pytest.mark.parametrize("case", sorted(HELD_CASES))
+@pytest.mark.parametrize("form", ["gated", "latent_relu2"])
+def test_a_held_share_takes_the_kernel_by_its_shapes(form, case,
+                                                     monkeypatch):
+    """``moe._held_experts`` (ISSUE 61): its two or three products run
+    the kernel where ``taken`` says so and give the ``y`` of the same
+    call with ``taken`` forced false, to a rounding; every product is
+    counted. The sizes sum to a part of ``M``: what the kernel leaves
+    behind the last group (here the products of NaN rows) is masked out
+    of ``y``, which is exactly 0 for a token with no held pair whatever
+    its gates."""
+    cfg, lp = held_share(form)
+    x, gates, experts, poisoned = held_inputs(case)
+    products = 3 if form == "gated" else 2
+    monkeypatch.setattr(moe_lib, "_grouped_traced", [0, 0])
+
+    def run(with_kernel):
+        with monkeypatch.context() as patch:
+            if not with_kernel:
+                patch.setattr(gm, "taken", lambda lhs, rhs: False)
+            fn = jax.jit(lambda x: moe_lib._held_experts(
+                x, lp, cfg, gates, experts))
+            text = fn.lower(x).as_text(debug_info=True)
+            return (np.asarray(fn(x), np.float32)[0],
+                    text.count("hvd_grouped_matmul"))
+
+    y, kernels = run(True)
+    assert kernels >= products
+    assert moe_lib._grouped_traced == [products, products]
+    want, none = run(False)
+    assert none == 0
+    assert moe_lib._grouped_traced == [2 * products, products]
+    assert np.isfinite(y).all() and np.isfinite(want).all()
+    assert np.abs(y - want).max() <= 2.0 ** -6 * max(np.abs(want).max(), 1.0)
+    local = np.asarray(experts) - HELD[1]
+    unheld = ~((local >= 0) & (local < HELD[0])).any(1)
+    assert unheld[:poisoned].all()
+    assert (y[unheld] == 0).all() and (np.abs(y[~unheld]).max(initial=1) > 0)
+
+
+@pytest.mark.parametrize("form", ["gated", "latent_relu2"])
+def test_a_held_share_s_gradients_are_ragged_dot_s(form, monkeypatch):
+    """``jax.grad`` through ``_held_experts`` with the kernel forward
+    (float32 operands; the cotangents are ``lax.ragged_dot``'s) equals
+    the gradient with ``taken`` forced false, for ``x`` and every
+    matrix: what the kernel leaves behind the last group reaches
+    neither."""
+    cfg, lp = held_share(form, jnp.float32)
+    x, gates, experts, _ = held_inputs("a_quarter_of_the_pairs", jnp.float32)
+
+    def loss(x, lp):
+        return jnp.tanh(moe_lib._held_experts(x, lp, cfg, gates, experts)
+                        ).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(x, lp).as_text(
+        debug_info=True)
+    assert "hvd_grouped_matmul" in text
+    got = jax.jit(jax.grad(loss, (0, 1)))(x, lp)
+    monkeypatch.setattr(gm, "taken", lambda lhs, rhs: False)
+    want = jax.jit(jax.grad(loss, (0, 1)))(x, lp)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("held", [None, 2])
+def test_a_mesh_s_shards_keep_ragged_dot(devices, held):
     """A shard of a mesh's tokens (``make_moe_ffn``'s ``shard_map``
     over ``dp`` / ``fsdp``) keeps ``lax.ragged_dot`` at shapes whose
     whole batch would take the kernel: a Pallas result carries no
-    varying axes for the ``shard_map`` to check. Forward and under
+    varying axes for the ``shard_map`` to check. The whole mixture and
+    a held share (experts 1 and 2 of 4) alike, forward and under
     ``jax.grad``, against the unsharded block."""
     from horovod_tpu.parallel import build_mesh
-    cfg = moe_lib.MoEConfig(n_experts=4, top_k=2, capacity_factor=None)
+    cfg = moe_lib.MoEConfig(n_experts=4, top_k=2, capacity_factor=None,
+                            experts_held=held, expert_offset=1 if held else 0)
     lp = jax.tree.map(lambda p: p[0], moe_lib.init_moe_params(
         jax.random.PRNGKey(0), 1, 128, 128, cfg, jnp.float32))
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 128))

@@ -172,7 +172,7 @@ def test_a_batch_s_rows_hold_their_own_rings(ring):
         holds(row, n, ring)
 
 
-# -- who runs a whole mixture's grouped product: (M, G, K, N) -> kernel? --
+# -- who runs a mixture's grouped product: (M, G, K, N) -> kernel? --
 
 GROUPED_PRODUCTS = {
     # the LFM2 cell: a decode step's 512 pairs and the three chunk
@@ -193,6 +193,26 @@ GROUPED_PRODUCTS = {
     "narrow_n": ((64, 4, 128, 192), False),
     # two whole matrices over the buffer's 48 MB
     "a_matrix_of_32_mb": ((512, 8, 4096, 4096), False),
+    # a chip's share of the experts (ISSUE 61), M the N·K pairs of a
+    # call of which the share's own lie in a group. Nemotron: 128 held
+    # of 512, a step's 128 slots x 22 and the largest chunk's 1024 x 22
+    "nemotron_step_up": ((2816, 128, 1024, 2688), True),
+    "nemotron_step_down": ((2816, 128, 2688, 1024), True),
+    "nemotron_chunk_1024_up": ((22528, 128, 1024, 2688), True),
+    "nemotron_chunk_1024_down": ((22528, 128, 2688, 1024), True),
+    # ling: 128 held of 512, 64 slots x 8 and 1024 x 8
+    "ling_step": ((512, 128, 2560, 768), True),
+    "ling_chunk_1024_down": ((8192, 128, 768, 2560), True),
+    # trinity: 32 held of 256, 32 slots x 4 and 1024 x 4; two matrices
+    # of 18.9 MB are the largest the buffer holds
+    "trinity_step": ((128, 32, 3072, 3072), True),
+    "trinity_chunk_1024": ((4096, 32, 3072, 3072), True),
+    # Kimi: 12 held of 384, two matrices of 29.4 MB are over the buffer
+    "kimi_step": ((256, 12, 7168, 2048), False),
+    "kimi_chunk_1024_down": ((8192, 12, 2048, 7168), False),
+    # mellum's trainer: 16 held of 64, the bound's 24 576 rows and all
+    "mellum_bounded": ((24576, 16, 2304, 896), False),
+    "mellum_over_the_bound": ((65536, 16, 2304, 896), False),
 }
 
 

@@ -244,6 +244,29 @@ def paged_decode_report(text):
 """
 
 
+# Who runs a program's grouped products (ISSUE 61): the compiler's
+# `ragged-dot` custom calls, the Pallas calls `hvd_grouped_matmul`, and
+# what `moe._grouped_product` counted as the process traced its programs
+# (`moe_grouped_kernel_products_share`, which `ServeMetrics.snapshot()`
+# carries). One program holds one kind or the other, never both: the
+# benchmark's readers price all of a window's products against the
+# kernels they find by name. Shared by the drivers that compile a
+# mixture.
+_GROUPED_PRODUCTS_REPORT = r"""
+def grouped_products(text):
+    from horovod_tpu.models import moe as moe_lib
+    # of the products traced since the last reading: a program's own
+    traced, kernel = moe_lib._grouped_traced
+    moe_lib._grouped_traced[:] = [0, 0]
+    return {{
+        "ragged_dots": len(re.findall(
+            r"%ragged-dot-(?!metadata)\S+ = [^\n]* custom-call\(", text)),
+        "grouped_kernels": len(re.findall(
+            r"custom-call\([^\n]*hvd_grouped_matmul", text)),
+        "kernel_products_share": kernel / traced if traced else None}}
+"""
+
+
 # The two-cache serve programs of a configuration with layers of several
 # kinds, small but with caches and experts too large for the compiler to
 # stage whole in fast memory (it does with small ones, and the copies it
@@ -300,6 +323,7 @@ def shape_of(s):
 
 
 PAGED_DECODE_REPORT
+GROUPED_PRODUCTS_REPORT
 large = {{shape_of(kv[0]): "pool", shape_of(kv[1]): "rings",
          shape_of(params["layers"][0]["moe"]["w_gate"]): "experts"}}
 out = {{"device_kind": topo.devices[0].device_kind,
@@ -319,6 +343,7 @@ for name, fn, args in (
     out[name] = {{"ops": ops,
                  "kernels": compiled.as_text().count("tpu_custom_call"),
                  "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+                 **grouped_products(compiled.as_text()),
                  **paged_decode_report(compiled.as_text())}}
 
 # A decode step over caches of the trinity cell's shapes (ISSUE 59): the
@@ -348,7 +373,8 @@ out["decode_cell"] = {{
     "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
     **paged_decode_report(text)}}
 print("LOWERED " + json.dumps(out))
-""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
+""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT).replace(
+    "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
 # What a decode program's text says of its latent attention (ISSUE 45):
@@ -396,7 +422,7 @@ cfg = TransformerConfig(
     rope_theta=6e6, norm_eps=1e-6, layer_types=("kda", "kda", "mla"),
     mla_kv_rank=512, mla_rope_dim=64, n_experts=512, moe_top_k=8,
     moe_capacity_factor=None, moe_scoring="sigmoid", moe_route_scale=2.5,
-    moe_shared_expert=True, moe_experts_held=16, moe_expert_offset=128,
+    moe_shared_expert=True, moe_experts_held=128, moe_expert_offset=128,
     moe_n_group=8, moe_topk_group=4, dtype=jnp.bfloat16, remat=False)
 BS, WIDTH, SLOTS, CHUNK = 16, 1088, 64, 1024
 
@@ -417,6 +443,7 @@ kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
 _, resume, decode, _, _ = decode_lib.make_serve_fns(
     cfg, None, block_size=BS, table_width=WIDTH)
 LATENT_DECODE_REPORT
+GROUPED_PRODUCTS_REPORT
 
 
 def shape_of(s):
@@ -452,9 +479,11 @@ for name, fn, args in (
         "keys_kernel_paths": sorted(set(re.findall(
             r'op_name="([^"]*hvd_flash_keys_fwd)[^"]*"', text))),
         **latent_decode_report(text, SLOTS, cfg.n_heads),
+        **grouped_products(text),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
-""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT)
+""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT).replace(
+    "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
 # The decode step of a stack whose every layer is `mla` (ISSUE 43), at
@@ -507,6 +536,7 @@ kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
 decode = decode_lib.make_serve_fns(cfg, None, block_size=BS,
                                    table_width=WIDTH)[2]
 LATENT_DECODE_REPORT
+GROUPED_PRODUCTS_REPORT
 compiled = decode.lower(params, kc, vc, i32(SLOTS), i32(SLOTS),
                         (i32(SLOTS, WIDTH), i32(SLOTS))).compile()
 text = compiled.as_text()
@@ -521,8 +551,10 @@ print("LOWERED " + json.dumps({{
         "aliased": len(re.findall(r"may-alias|must-alias",
                                   aliased.group(1))),
         **latent_decode_report(text, SLOTS, cfg.n_heads),
+        **grouped_products(text),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}}}))
-""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT)
+""".replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT).replace(
+    "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
 # The trained share of the experts (ISSUE 40): the step of the cell
@@ -560,6 +592,7 @@ cfg = TransformerConfig(
     moe_expert_offset=16, dtype=jnp.bfloat16, sp_attention="flash",
     remat=True, remat_policy="full")
 PAIRS, BOUND = 65536, moe_lib.held_row_bound(65536, cfg.moe)
+GROUPED_PRODUCTS_REPORT
 out = {{"device_kind": topo.devices[0].device_kind, "bound": BOUND}}
 init_state, step, _ = make_train_step(
     cfg, build_mesh(dp=-1, devices=topo.devices[:1]))
@@ -640,34 +673,43 @@ out["step"] = {{"conditionals": len(conds),
                    for comp, lines in comps.items() if comp not in fall_back
                    for line in lines),
                "wide_outside": wide, "fills_outside": fills,
-               "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+               "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+               **grouped_products(compiled.as_text())}}
 
 
-# a chunk of 1024 tokens through the two served shares' expert layers
-def chunk_call(d, f, **moe):
+# a chunk of 1024 tokens, and a decode step's rows, through the served
+# shares' expert layers at the cells' own shapes
+def share_call(d, f, tokens, **moe):
     share = moe_lib.MoEConfig(capacity_factor=None, **moe)
     lp = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=one),
         jax.eval_shape(lambda: moe_lib.init_moe_params(
             jax.random.PRNGKey(0), 1, d, f, share, jnp.bfloat16)))
     text = jax.jit(lambda x, lp: moe_lib.moe_ffn_dropless(x, lp, share)
-                   ).lower(jax.ShapeDtypeStruct((1, 1024, d), jnp.bfloat16,
+                   ).lower(jax.ShapeDtypeStruct((1, tokens, d), jnp.bfloat16,
                                                 sharding=one),
                            lp).compile().as_text()
-    return {{"bound": moe_lib.held_row_bound(1024 * share.top_k, share),
+    return {{"bound": moe_lib.held_row_bound(tokens * share.top_k, share),
             "conditionals": text.count(" conditional("),
-            "kernels": text.count("ragged-dot-none")}}
+            **grouped_products(text)}}
 
 
-out["trinity_chunk"] = chunk_call(
-    3072, 3072, n_experts=256, top_k=4, scoring="sigmoid",
-    route_scale=2.448, shared_expert=True, experts_held=32)
-out["ling_chunk"] = chunk_call(
-    2560, 768, n_experts=512, top_k=8, scoring="sigmoid", route_scale=2.5,
-    shared_expert=True, experts_held=128, expert_offset=128, n_group=8,
-    topk_group=4)
+SHARES = {{
+    "trinity": (3072, 3072, 32, dict(
+        n_experts=256, top_k=4, scoring="sigmoid", route_scale=2.448,
+        shared_expert=True, experts_held=32)),
+    "ling": (2560, 768, 64, dict(
+        n_experts=512, top_k=8, scoring="sigmoid", route_scale=2.5,
+        shared_expert=True, experts_held=128, expert_offset=128, n_group=8,
+        topk_group=4)),
+    "kimi": (7168, 2048, 32, dict(
+        n_experts=384, top_k=8, scoring="sigmoid", route_scale=2.827,
+        shared_expert=True, experts_held=12, expert_offset=12))}}
+for name, (d, f, slots, moe) in SHARES.items():
+    out[name + "_chunk"] = share_call(d, f, 1024, **moe)
+    out[name + "_step"] = share_call(d, f, slots, **moe)
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
 # The two programs of the state-space kind (ISSUE 47) at the widths, the
@@ -1067,6 +1109,11 @@ def test_two_cache_serve_programs_copy_neither_cache_nor_experts_on_v5e():
             "experts copy-done"}, (
                 program, got)
         assert got["kernels"] >= 9, (program, got)     # 3 a sparse layer
+        # ... and since ISSUE 61 each of them is the Pallas call that
+        # takes the experts where they lie, a matrix at a time
+        assert got["grouped_kernels"] == 9, (program, got)
+        assert got["ragged_dots"] == 0, (program, got)
+        assert got["kernel_products_share"] == 1.0, (program, got)
         # (the writes are scatters, or at some shapes an update of a
         # reshaped view; either way on the donated buffer:) all the
         # program allocates, the pages it gathers to attend over and
@@ -1185,11 +1232,53 @@ def test_the_trained_share_runs_its_bound_s_rows_outside_the_fall_back():
 def test_a_served_chunk_keeps_the_whole_row_form_with_no_cond(share):
     """... and a served chunk's call (4 096 pairs over 32 of 256
     experts, 8 192 over 128 of 512) is below the size at which the
-    bound engages: no ``cond``, three grouped matmuls, the parent's
-    program."""
+    bound engages: no ``cond``, three grouped matmuls (since ISSUE 61
+    the kernel's, below)."""
     got = _compile_for_v5e(_MELLUM_DRIVER)[share]
     assert got["bound"] is None and got["conditionals"] == 0, got
-    assert got["kernels"] >= 3, got
+    assert got["grouped_kernels"] + got["ragged_dots"] == 3, got
+
+
+@pytest.mark.parametrize("call", ["trinity_chunk", "trinity_step",
+                                  "ling_chunk", "ling_step"])
+def test_a_served_share_s_products_are_the_kernel_s(call):
+    """ISSUE 61: ``moe_ffn_dropless`` over a chip's share of the
+    experts at the trinity and ling cells' own shapes (32 of 256
+    experts of ``[3072, 3072]``: two whole matrices of 18.9 MB are the
+    largest the kernel's buffer holds, and the call compiles for the
+    v5e under its ``vmem_limit_bytes``; 128 of 512 of ``[2560, 768]``),
+    a chunk of 1024 tokens and a decode step's rows: all three products
+    are ``hvd_grouped_matmul`` calls, none is left with the compiler,
+    and the engine's counter reads 1.0."""
+    got = _compile_for_v5e(_MELLUM_DRIVER)[call]
+    assert got["grouped_kernels"] == 3 and got["ragged_dots"] == 0, got
+    assert got["kernel_products_share"] == 1.0, got
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_ling_s_programs_hold_the_kernel_alone(program):
+    """... and in ling's two programs at the cell's 128 held experts,
+    64 slots and a chunk of 1024 (two sparse layers here): three
+    kernels a sparse layer, no ``ragged-dot``."""
+    got = _compile_for_v5e(_LING_DRIVER)[program]
+    assert got["grouped_kernels"] == 3 * 2 and got["ragged_dots"] == 0, got
+    assert got["kernel_products_share"] == 1.0, got
+
+
+@pytest.mark.parametrize("call", ["kimi_decode", "kimi_chunk", "kimi_step",
+                                  "mellum_step"])
+def test_the_rule_s_other_side_keeps_the_compiler_s_products(call):
+    """ISSUE 61, the shares that ``grouped_matmul.taken`` leaves with
+    the compiler by their shapes: Kimi's (two matrices of 29.4 MB are
+    over the buffer: its decode program with two held experts, and the
+    cell's 12 in a chunk and a step) and mellum's trainer (1536 rows an
+    expert and more): no kernel, the ``ragged-dot`` calls they had, and
+    a counter of 0.0."""
+    got = (_compile_for_v5e(_KIMI_DRIVER)["decode"] if call == "kimi_decode"
+           else _compile_for_v5e(_MELLUM_DRIVER)[call.replace("mellum_", "")])
+    assert got["grouped_kernels"] == 0, got
+    assert got["ragged_dots"] >= 3, got
+    assert got["kernel_products_share"] == 0.0, got
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_resume"])
@@ -1722,7 +1811,7 @@ cfg = TransformerConfig(
     mamba_d_state=128, mamba_d_conv=4, mamba_expand=2, mamba2_head_dim=64,
     mamba2_groups=8, mamba2_chunk=128, n_experts=512, moe_top_k=22,
     moe_capacity_factor=None, moe_scoring="sigmoid", moe_route_scale=5.0,
-    moe_shared_expert=True, moe_experts_held=32, moe_expert_offset=128,
+    moe_shared_expert=True, moe_experts_held=128, moe_expert_offset=128,
     moe_activation="relu2", moe_latent=1024, moe_shared_d_ff=5376,
     dtype=jnp.bfloat16, remat=False)
 BS, WIDTH, SLOTS, CHUNK = 16, 320, 64, 1024
@@ -1745,6 +1834,7 @@ _, resume, decode, _, _ = decode_lib.make_serve_fns(
     cfg, None, block_size=BS, table_width=WIDTH)
 pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
 state = "f32[%s]" % ",".join(map(str, kc[1].shape))
+GROUPED_PRODUCTS_REPORT
 out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
        "state": state, "state_bytes": kc[1].size * 4}}
 for name, fn, args in (
@@ -1763,12 +1853,11 @@ for name, fn, args in (
                            for result, opcode in results),
         "aliased": len(re.findall(r"may-alias|must-alias",
                                   aliased.group(1))),
-        # the widths the compiler's grouped products come out at
-        "ragged_dots": sorted(set(re.findall(
-            r"%ragged-dot-(?!metadata)\S+ = bf16\[\d+,(\d+)\][^\n]* "
-            r"custom-call\(", text))),
-        "grouped_kernels": len(re.findall(
-            r"custom-call\(.*/hvd_grouped_matmul/pallas_call", text)),
+        # the widths the held experts' products come out at
+        "grouped_widths": sorted(set(re.findall(
+            r"= bf16\[\d+,(\d+)\][^\n]* custom-call\([^\n]*"
+            r"moe_experts/[^\n]*hvd_grouped_matmul", text))),
+        **grouped_products(text),
         "paged_decode": len(re.findall(
             r"custom-call\(.*attn_full/.*hvd_paged_decode/pallas_call",
             text)),
@@ -1779,7 +1868,7 @@ for name, fn, args in (
             r'op_name="jit\(\w+\)/attn/[^"]*\bmlp\b', text)),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
-"""
+""".replace("GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_resume"])
@@ -1791,11 +1880,12 @@ def test_the_one_branch_programs_lower_for_the_v5e(program):
     are rows of 256 (``kv_cache.page_tail``: as ``[.., 16, 2, 128]`` a
     chunk turned the whole pool over four times) and never copied
     whole; the state array (1.1 GB here) is only ever updated where it
-    lies; the held experts' ungated form is TWO kinds of ``ragged-dot``
-    product a mixture layer (up to 2688, down to the latent's 1024: no
-    gate's) and no kernel of the whole mixture's; every scope the
-    benchmark reads by name is in the program; and a call's temporaries
-    stay under the state's size."""
+    lies; the held experts' ungated form is TWO products a mixture layer
+    (up to 2688, down to the latent's 1024: no gate's), since ISSUE 61
+    both ``hvd_grouped_matmul`` calls at the cell's 128 held experts
+    (1408 pairs a step, 22 528 a chunk) with no ``ragged-dot`` left
+    beside them; every scope the benchmark reads by name is in the
+    program; and a call's temporaries stay under the state's size."""
     out = _compile_for_v5e(_NEMOTRON_DRIVER)
     got = out[program]
     assert out["pool"] == "bf16[1,20481,16,256]", out
@@ -1805,8 +1895,10 @@ def test_the_one_branch_programs_lower_for_the_v5e(program):
     assert set(got["state_ops"]) <= {"parameter", "get-tuple-element",
                                      "bitcast", "fusion",
                                      "dynamic-update-slice"}, got
-    assert got["ragged_dots"] == ["1024", "2688"], got
-    assert got["grouped_kernels"] == 0, got
+    assert got["ragged_dots"] == 0, got
+    assert got["grouped_kernels"] == 2 * 1, got
+    assert got["grouped_widths"] == ["1024", "2688"], got
+    assert got["kernel_products_share"] == 1.0, got
     assert got["paged_decode"] == (1 if program == "decode" else 0), got
     step = "mamba2_step" if program == "decode" else "mamba2_scan"
     assert set(got["scopes"]) >= {

@@ -9,7 +9,11 @@ At the LFM2 cell's shapes (``lfm2-8b-a1b-14l``: 32 experts of ``[2048,
 chunk buckets) and three draws of the group sizes (``router``: a
 multinomial over a Dirichlet whose fullest expert holds about 1.8 times
 the mean at 4096 pairs, the cell's seeded router's figure; ``even``;
-``skewed``: one group holds half the rows and eight are empty): ms a
+``skewed``: one group holds half the rows and eight are empty; with
+``--held-share`` the sizes sum to that share of ``M`` and the other rows
+lie behind the last group, as a chip's share of the experts has them:
+``--experts 128 --k 1024 --n 2688 --rows 2816 22528 --held-share 0.25``
+is the Nemotron cell's step and largest chunk): ms a
 call on the device's side of the launch (:func:`ms_a_call`: a program
 of 32 calls less a program of 8, each call with operands of its own
 and every result a result of the program; medians of ``--reps`` runs)
@@ -119,7 +123,8 @@ def product(args, keys, name, rows, k, n, groups, sizes):
     lhs = [normal(next(keys), (rows, k)) for _ in range(args.operands)]
     sz = jnp.asarray(sizes, jnp.int32)
     read = int((sizes > 0).sum()) * k * n * 2
-    line = {"product": name, "M": rows, "K": k, "N": n, "G": groups,
+    line = {"product": name, "M": rows, "in_a_group": int(sizes.sum()),
+            "K": k, "N": n, "G": groups,
             "max_over_mean": round(float(sizes.max() / sizes.mean()), 2),
             "empty": int((sizes == 0).sum()),
             "least_ms": round(1e3 * read / HBM_BYTES_S, 4)}
@@ -148,6 +153,10 @@ def main() -> None:
     ap.add_argument("--experts", type=int, default=32)
     ap.add_argument("--k", type=int, default=2048)
     ap.add_argument("--n", type=int, default=1792)
+    ap.add_argument("--held-share", type=float, default=1.0,
+                    help="the share of --rows that lies in a group: a "
+                    "quarter where a chip holds 128 of 512 experts; the "
+                    "other rows lie behind the last group")
     ap.add_argument("--tiles", type=int, nargs="+", default=[128, 256])
     ap.add_argument("--draws", nargs="+", default=["router", "even", "skewed"])
     ap.add_argument("--megablox", action="store_true")
@@ -171,7 +180,10 @@ def main() -> None:
         say({"device": jax.devices()[0].device_kind, "calls": args.calls,
              "reps": args.reps})
         for rows in args.rows:
-            for draw, sizes in draws(rng, rows, args.experts).items():
+            # a chip's share sorts its own pairs to the front, the
+            # others behind the last group (moe._held_rows)
+            in_a_group = int(round(rows * args.held_share))
+            for draw, sizes in draws(rng, in_a_group, args.experts).items():
                 if draw not in args.draws:
                     continue
                 for name, k, n in (("gate_up", args.k, args.n),

@@ -43,7 +43,11 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          ("lfm2-8b-a1b-14l", "assistant-backlog"),
          # the one caller of paged_decode_stats (an eva layer's step: the
          # kernel twice, over the window's rows and the summaries' pages)
-         ("evabyte-6.5b-8l", "bytedoc-backlog")]
+         ("evabyte-6.5b-8l", "bytedoc-backlog"),
+         # with trinity's and ling's, the programs that changed with
+         # PR 61 (hvd_grouped_matmul under a chip's share of the experts:
+         # moe._held_rows); Kimi's share keeps lax.ragged_dot by its shapes
+         ("nemotron-3-super-120b-ep4-11l", "agent-backlog")]
 
 
 def i32(*shape):
