@@ -127,6 +127,7 @@ from horovod_tpu.ops.flash_attention import (flash_attention,
 from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
+from horovod_tpu.ops import state_step as state_step_kernel
 from horovod_tpu.ops.paged_decode import (latent_decode, paged_decode,
                                           paged_decode_stats, ring_decode,
                                           ring_page)
@@ -1869,10 +1870,15 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                              attend)
 
     def kda_step_layer(call, lp, kc, vc, c, x, i):
-        """One step of the recurrence on every slot's state where it
-        lies, the batch's rows carried to their slots and the results
-        back: a slot that is not in the batch decays by 1 and is
-        written by 0, so its state is what it was."""
+        """One step of the delta rule on each row's own state where it
+        lies in the pool (``ops/state_step.py::kda_step``, the Pallas
+        call ``hvd_state_step``: a head's state read once, ``S'^T k``,
+        ``S'^T q`` and the update on it in VMEM, and written once; a
+        slot that is not in the batch not touched). A state that is not
+        whole tiles keeps, on a TPU, the XLA form: every slot's state
+        where it lies, the batch's rows carried to their slots and the
+        results back, a slot that is not in the batch decayed by 1 and
+        written by 0."""
         n = place["kda"]
         with jax.named_scope("attn_kda"):
             h, rows = tf_lib.kda_rows(cfg, lp, x)
@@ -1881,17 +1887,25 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 q, k, v = tf_lib.kda_conv(cfg, lp, rows, before)
             with jax.named_scope("kda_gates"):
                 g, beta = tf_lib.kda_gates(cfg, lp, h)
-            with jax.named_scope("kda_step"):
-                n_slots = kc[n].shape[1]
-                o, state = kda_step(*(by_slot(call, a[:, 0], n_slots)
-                                      for a in (q, k, v, g, beta)),
-                                    kc[n][c])
-                o = o[call.slots][:, None]
+            inputs = tuple(a[:, 0] for a in (q, k, v, g, beta))
+            if state_step_kernel.taken(*kc[n].shape[3:]):
+                with jax.named_scope("kda_step"):
+                    o, state = state_step_kernel.kda_step(
+                        *inputs, kc[n], c, call.slots)
+                with jax.named_scope("state_write"):
+                    kc = swap(kc, "kda", state)
+            else:
+                with jax.named_scope("kda_step"):
+                    n_slots = kc[n].shape[1]
+                    o, state = kda_step(*(by_slot(call, a, n_slots)
+                                          for a in inputs), kc[n][c])
+                    o = o[call.slots]
+                with jax.named_scope("state_write"):
+                    kc = put(kc, "kda", (c,), state)
             with jax.named_scope("state_write"):
-                kc = put(kc, "kda", (c,), state)
                 vc = put(vc, "kda", (c, call.slots),
                          jnp.concatenate([before, rows], 1)[:, 1:])
-        return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o)
+        return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o[:, None])
 
     def mla_step(call, lp, kc, vc, c, x, i):
         """Absorbed attention over each row's own pages, where they lie
@@ -1956,13 +1970,14 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         return kc, vc, tf_lib.mamba_residual(cfg, lp, x, y, z)
 
     def mamba2_step_layer(call, lp, kc, vc, c, x, i):
-        """One step of the recurrence on every slot's state where it
-        lies (4 MB a slot: read once, written once, never gathered), the
-        batch's rows carried to their slots and the results back, as
-        :func:`kda_step_layer` does: a slot that is not in the batch is
-        stepped by 0, so its state is what it was."""
+        """One step of the recurrence on each row's own state where it
+        lies in the pool (``ops/state_step.py::ssd_step``, the Pallas
+        call ``hvd_state_step``: 4 MB a slot read once and written
+        once, a slot that is not in the batch not touched). A state
+        that is not whole tiles keeps, on a TPU, the XLA form, as
+        :func:`kda_step_layer` does: every slot stepped where it lies,
+        one that is not in the batch by 0."""
         n = place["mamba2"]
-        n_slots = kc[n].shape[1]
         with jax.named_scope("attn_mamba2"):
             with jax.named_scope("mamba2_proj"):
                 z, xbc, dt = tf_lib.mamba2_rows(cfg, lp, x)
@@ -1971,20 +1986,31 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
             with jax.named_scope("conv_taps"):
                 xs, b, cc, step = tf_lib.mamba2_inputs(cfg, lp, xbc, dt,
                                                        before)
+            a = -jnp.exp(lp["a_log"])
+            xr, dr, br, cr = (r[:, 0] for r in (xs, step, b, cc))
+            if state_step_kernel.taken(*kc[n].shape[3:]):
+                with jax.named_scope("mamba2_step"):
+                    y, state = state_step_kernel.ssd_step(
+                        xr, dr, a, br, cr, kc[n], c, call.slots)
+                with jax.named_scope("state_write"):
+                    kc = swap(kc, "mamba2", state)
+            else:
+                with jax.named_scope("mamba2_step"):
+                    n_slots = kc[n].shape[1]
+                    xr, dr, br, cr = (by_slot(call, r, n_slots)
+                                      for r in (xr, dr, br, cr))
+                    y, state = ssd_step(xr, dr, a, br, cr, kc[n][c])
+                    y = y[call.slots]
+                with jax.named_scope("state_write"):
+                    kc = put(kc, "mamba2", (c,), state)
             with jax.named_scope("mamba2_step"):
-                xr, dr, br, cr = (by_slot(call, r[:, 0], n_slots)
-                                  for r in (xs, step, b, cc))
-                y, state = ssd_step(xr, dr, -jnp.exp(lp["a_log"]), br, cr,
-                                    kc[n][c])
-                y = y[call.slots][:, None] + lp["d_skip"][:, None] * xs
+                y = y[:, None] + lp["d_skip"][:, None] * xs
             with jax.named_scope("state_write"):
-                kc = put(kc, "mamba2", (c,), state)
                 vc = put(vc, "mamba2", (c, call.slots),
                          jnp.concatenate([before, xbc], 1)[:, 1:].reshape(
                              x.shape[0], -1))
             x = tf_lib.mamba2_residual(cfg, lp, x, y, z)
         return kc, vc, x
-
 
     def sparse_step(call, lp, kc, vc, c, x, i):
         """A row's new key into its page and, where it completes a

@@ -377,6 +377,37 @@ print("LOWERED " + json.dumps(out))
     "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
 
 
+# Who reads a state array in a compiled program's entry computation, and
+# the Pallas calls of ``ops/state_step.py`` with the scope each stands
+# under (ISSUES 48 and 62): shared by the drivers of the stacks with a
+# recurrent state.
+_STATE_READERS_REPORT = r"""
+def readers(text, shape):
+    # the entry computation's instructions that take a value of `shape`
+    # (alone or in a tuple) and do more than hand it on, by opcode
+    entry = text[text.index("\nENTRY "):]
+    lines = [ln.strip().removeprefix("ROOT ").split(" = ", 1)
+             for ln in entry.splitlines() if " = " in ln]
+    held = {{name for name, rest in lines if shape in rest.split(" ")[0]
+            or (rest.startswith("(") and shape in rest[:rest.index(") ")])}}
+    found = collections.Counter()
+    for name, rest in lines:
+        call = re.search(r" ([\w\-]+)\((%[^)]*)\)", rest)
+        if call and call.group(1) not in ("get-tuple-element", "tuple",
+                                          "bitcast"):
+            if held & set(re.findall(r"%[\w.\-]+", call.group(2))):
+                found[call.group(1)] += 1
+    return found
+
+
+def state_step_calls(text):
+    # the scopes (`attn_mamba2/mamba2_step`, `attn_kda/kda_step`) the
+    # `hvd_state_step` custom calls stand under, one entry a call
+    return sorted(re.findall(
+        r"custom-call\([^\n]*/(attn_\w+/\w+)/hvd_state_step/pallas_call",
+        text))
+"""
+
 # What a decode program's text says of its latent attention (ISSUE 45):
 # the Mosaic calls under `hvd_latent_decode` by scope, a key block of
 # 1024 positions gathered for every row (`bf16[rows * 64, 16, 640]`,
@@ -444,6 +475,7 @@ _, resume, decode, _, _ = decode_lib.make_serve_fns(
     cfg, None, block_size=BS, table_width=WIDTH)
 LATENT_DECODE_REPORT
 GROUPED_PRODUCTS_REPORT
+STATE_READERS_REPORT
 
 
 def shape_of(s):
@@ -480,10 +512,14 @@ for name, fn, args in (
             r'op_name="([^"]*hvd_flash_keys_fwd)[^"]*"', text))),
         **latent_decode_report(text, SLOTS, cfg.n_heads),
         **grouped_products(text),
+        "state_step": state_step_calls(text),
+        "state_readers": readers(text, shape_of(kc[0])),
+        "scopes": sorted(set(re.findall(r"attn_kda/(\w+)", text))),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
 """.replace("LATENT_DECODE_REPORT", _LATENT_DECODE_REPORT).replace(
-    "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
+    "GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT).replace(
+    "STATE_READERS_REPORT", _STATE_READERS_REPORT)
 
 
 # The decode step of a stack whose every layer is `mla` (ISSUE 43), at
@@ -761,22 +797,7 @@ out = {{"device_kind": topo.devices[0].device_kind,
        "state_bytes": kc[1].size * 4}}
 
 
-def readers(text, shape):
-    # the entry computation's instructions that take a value of `shape`
-    # (alone or in a tuple) and do more than hand it on, by opcode
-    entry = text[text.index("\nENTRY "):]
-    lines = [ln.strip().removeprefix("ROOT ").split(" = ", 1)
-             for ln in entry.splitlines() if " = " in ln]
-    held = {{name for name, rest in lines if shape in rest.split(" ")[0]
-            or (rest.startswith("(") and shape in rest[:rest.index(") ")])}}
-    found = collections.Counter()
-    for name, rest in lines:
-        call = re.search(r" ([\w\-]+)\((%[^)]*)\)", rest)
-        if call and call.group(1) not in ("get-tuple-element", "tuple",
-                                          "bitcast"):
-            if held & set(re.findall(r"%[\w.\-]+", call.group(2))):
-                found[call.group(1)] += 1
-    return found
+STATE_READERS_REPORT
 
 
 PAGED_DECODE_REPORT
@@ -815,7 +836,8 @@ for name, fn, args in (
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
         **paged_decode_report(text)}}
 print("LOWERED " + json.dumps(out))
-""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT)
+""".replace("PAGED_DECODE_REPORT", _PAGED_DECODE_REPORT).replace(
+    "STATE_READERS_REPORT", _STATE_READERS_REPORT)
 
 # The two programs of the sparse and lightning kinds (ISSUE 50) at the
 # widths, the 16 slots and the table of 520 pages of 64 of
@@ -1129,7 +1151,8 @@ def test_state_and_latent_pool_are_updated_where_they_lie_on_v5e(program):
     convolution's rows and the latent pool aliased in and out; no copy
     of the state array (570 MB here, 0.85 GB at the cell's six kda
     layers) or of the pool (1.4 GB), only the in-place writes of
-    ``state_write`` and ``kv_write``; no float32 tensor of a chunk's
+    ``state_write`` and ``kv_write`` (a decode step's state through
+    ``hvd_state_step`` since ISSUE 62); no float32 tensor of a chunk's
     queries against the whole table (2.3 GB over 32 heads: the latent
     attention goes a key block of 1024 at a time); and what a call
     allocates is bounded: the scan's blocks and one key block's scores
@@ -1137,11 +1160,26 @@ def test_state_and_latent_pool_are_updated_where_they_lie_on_v5e(program):
     out = _compile_for_v5e(_LING_DRIVER)
     got = out[program]
     assert got["aliased"] == 3, got            # state, conv rows, pool
-    assert set(got["ops"]) <= {
-        "state parameter", "state get-tuple-element", "state bitcast",
-        "state fusion", "state dynamic-update-slice", "state scatter",
-        "pool parameter", "pool get-tuple-element", "pool bitcast",
-        "pool fusion", "pool scatter", "pool dynamic-update-slice"}, got
+    handed_on = {"state parameter", "state get-tuple-element",
+                 "state bitcast", "pool parameter", "pool get-tuple-element",
+                 "pool bitcast", "pool fusion", "pool scatter",
+                 "pool dynamic-update-slice"}
+    if program == "decode":
+        # ISSUE 62: a kda layer's step is ONE Pallas call under
+        # ``attn_kda/kda_step``, the state array's only reader (the XLA
+        # form was a fusion over all 65 slots a layer that decayed,
+        # reduced and wrote, and its dynamic-update-slice)
+        assert set(got["ops"]) <= handed_on | {"state custom-call"}, got
+        assert got["state_step"] == ["attn_kda/kda_step"] * 2, got
+        assert got["state_readers"] == {"custom-call": 2}, got
+    else:
+        assert set(got["ops"]) <= handed_on | {
+            "state fusion", "state dynamic-update-slice",
+            "state scatter"}, got
+        assert got["state_step"] == [], got
+    step = "kda_step" if program == "decode" else "kda_scan"
+    assert set(got["scopes"]) >= {"kda_conv", "kda_gates", step,
+                                  "state_write"}, got
     assert got["scores_of_the_table"] == 0, got
     limit = {"decode": 0.25e9, "prefill_resume": 0.9e9}[program]
     assert got["temp_bytes"] < limit < out["pool_bytes"], got
@@ -1835,6 +1873,7 @@ _, resume, decode, _, _ = decode_lib.make_serve_fns(
 pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
 state = "f32[%s]" % ",".join(map(str, kc[1].shape))
 GROUPED_PRODUCTS_REPORT
+STATE_READERS_REPORT
 out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
        "state": state, "state_bytes": kc[1].size * 4}}
 for name, fn, args in (
@@ -1866,9 +1905,12 @@ for name, fn, args in (
                 r"/(moe_\w+)/", text))),
         "both_branches": len(re.findall(
             r'op_name="jit\(\w+\)/attn/[^"]*\bmlp\b', text)),
+        "state_step": state_step_calls(text),
+        "state_readers": readers(text, state),
         "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
 print("LOWERED " + json.dumps(out))
-""".replace("GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
+""".replace("GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT).replace(
+    "STATE_READERS_REPORT", _STATE_READERS_REPORT)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_resume"])
@@ -1884,17 +1926,29 @@ def test_the_one_branch_programs_lower_for_the_v5e(program):
     (up to 2688, down to the latent's 1024: no gate's), since ISSUE 61
     both ``hvd_grouped_matmul`` calls at the cell's 128 held experts
     (1408 pairs a step, 22 528 a chunk) with no ``ragged-dot`` left
-    beside them; every scope the benchmark reads by name is in the
-    program; and a call's temporaries stay under the state's size."""
+    beside them; since ISSUE 62 a step's recurrence is one
+    ``hvd_state_step`` a mamba2 layer; every scope the benchmark reads
+    by name is in the program; and a call's temporaries stay under the
+    state's size."""
     out = _compile_for_v5e(_NEMOTRON_DRIVER)
     got = out[program]
     assert out["pool"] == "bf16[1,20481,16,256]", out
     assert out["state"] == "f32[2,65,128,64,128]", out
     assert got["aliased"] == 4, got
     assert got["pool_copies"] == 0, got
-    assert set(got["state_ops"]) <= {"parameter", "get-tuple-element",
-                                     "bitcast", "fusion",
-                                     "dynamic-update-slice"}, got
+    handed_on = {"parameter", "get-tuple-element", "bitcast"}
+    if program == "decode":
+        # ISSUE 62: a mamba2 layer's step is ONE Pallas call under
+        # ``attn_mamba2/mamba2_step``, the state array's only reader
+        # (the XLA form was a fusion over all 65 slots a layer that
+        # decayed, drove and wrote, and a second that reduced ``S c``)
+        assert set(got["state_ops"]) <= handed_on | {"custom-call"}, got
+        assert got["state_step"] == ["attn_mamba2/mamba2_step"] * 2, got
+        assert got["state_readers"] == {"custom-call": 2}, got
+    else:
+        assert set(got["state_ops"]) <= handed_on | {
+            "fusion", "dynamic-update-slice"}, got
+        assert got["state_step"] == [], got
     assert got["ragged_dots"] == 0, got
     assert got["grouped_kernels"] == 2 * 1, got
     assert got["grouped_widths"] == ["1024", "2688"], got

@@ -27,9 +27,20 @@ rewritten where they lie:
     chiprun -- python tools/mamba_scan_sweep.py --step
 
 What ``ops/mamba_step.py::_channels`` was chosen from (PERF.md, PR 48).
-Off the chip the interpreter takes an hour at these sizes: rehearse with
-``--layers 1 --channels 1024`` (or ``--layers 2 --slots 16`` with
-``--step``)."""
+With ``--step --rule ssd`` or ``--rule kda``, the decode step of a
+Mamba-2 or a kda layer alone at its cell's shapes (Nemotron's pool
+``[5, 129, 128, 64, 128]`` at 128 and 64 rows, ling's ``[6, 65, 32,
+128, 128]`` at 64 and 16): the XLA form of a layer (``decode.ssd_step``
+/ ``decode.kda_step`` on every slot, the rows carried to their slots)
+beside the Pallas call ``ops/state_step.py`` by the heads a grid step
+holds:
+
+    chiprun -- python tools/mamba_scan_sweep.py --step --rule ssd
+
+What ``ops/state_step.py::_SSD_HEADS`` and ``_KDA_HEADS`` were chosen
+from (PERF.md, PR 62). Off the chip the interpreter takes an hour at
+these sizes: rehearse with ``--layers 1 --channels 1024`` (or ``--layers
+2 --slots 16`` with ``--step``)."""
 import argparse
 import json
 import sys
@@ -41,6 +52,7 @@ import jax.numpy as jnp
 sys.path.insert(0, ".")
 from horovod_tpu.ops import mamba_scan as scan_lib  # noqa: E402
 from horovod_tpu.ops import mamba_step as step_lib  # noqa: E402
+from horovod_tpu.ops import state_step as state_lib  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 
 DI, N = 5120, 16
@@ -205,6 +217,91 @@ def step_sweep(layers=26, slots=256, conv=4):
                               "ms_a_layer": round(ms, 4)}), flush=True)
 
 
+#: The heads a grid step of ``hvd_state_step`` holds, by rule.
+STATE_HEADS = {"ssd": (8, 16, 32, 64), "kda": (8, 16, 32)}
+
+
+def state_step_inputs(rule, key, B, heads, rows, cols, groups=8):
+    """The per-row inputs of ``rule`` for ``B`` rows, in the order
+    ``decode.ssd_step`` / ``decode.kda_step`` and the kernels take them;
+    the first is the one a layer's result nudges."""
+    ks = jax.random.split(key, 5)
+    if rule == "ssd":
+        return (jax.random.normal(ks[0], (B, heads, rows)),
+                jax.random.uniform(ks[1], (B, heads), minval=1e-3,
+                                   maxval=0.1),
+                -jnp.exp(jax.random.normal(ks[2], (heads,))),
+                jax.random.normal(ks[3], (B, groups, cols)),
+                jax.random.normal(ks[4], (B, groups, cols)))
+    unit = jax.random.normal(ks[1], (B, heads, rows))
+    return (jax.random.normal(ks[0], (B, heads, rows)) * rows ** -0.5,
+            unit / jnp.linalg.norm(unit, axis=-1, keepdims=True),
+            jax.random.normal(ks[2], (B, heads, cols)),
+            -jnp.exp(jax.random.normal(ks[3], (B, heads, rows))),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (B, heads))))
+
+
+def state_step_sweep(rule, layers=None, slots=None):
+    """The decode step of one Mamba-2 (``ssd``) or kda layer of
+    ``layers``' pool, by form, at a batch of every slot but the null
+    one and of half or a quarter of them."""
+    xla_step, kernel, shape, fewer = {
+        "ssd": (decode_lib.ssd_step, state_lib.ssd_step, (128, 64, 128), 2),
+        "kda": (decode_lib.kda_step, state_lib.kda_step, (32, 128, 128), 4),
+    }[rule]
+    layers = layers or {"ssd": 5, "kda": 6}[rule]
+    slots = slots or {"ssd": 128, "kda": 64}[rule]
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 3)
+    # the inputs a row has one of (ssd's ``a`` is the layer's)
+    by_row = (0, 1, 3, 4) if rule == "ssd" else (0, 1, 2, 3, 4)
+    for B in (slots, slots // fewer):
+        at = jax.random.permutation(ks[0], slots)[:B].astype(jnp.int32) + 1
+        inputs = state_step_inputs(rule, ks[1], B, *shape)
+        moved = 2 * B * 4 * shape[0] * shape[1] * shape[2]
+
+        def xla(pool, layer, at, lead, *rest):
+            def by_slot(x):
+                return jnp.zeros((slots + 1,) + x.shape[1:],
+                                 x.dtype).at[at].set(x)
+            out, state = xla_step(
+                *(by_slot(x) if n in by_row else x for n, x in enumerate(
+                    (lead.reshape(inputs[0].shape), *rest))), pool[layer])
+            return pool.at[layer].set(state), out[at].reshape(B, -1)
+
+        def through(fn, **how):
+            def layer_fn(pool, layer, at, lead, *rest):
+                out, pool = fn(lead.reshape(inputs[0].shape), *rest, pool,
+                               layer, at, **how)
+                return pool, out.reshape(B, -1)
+            return layer_fn
+
+        def report(form, fn, **how):
+            pool = jax.random.normal(ks[2], (layers, slots + 1) + shape)
+            ms = carried(fn, pool, at, inputs[0].reshape(B, -1),
+                         *inputs[1:])
+            print(json.dumps({"rule": rule, "rows": B, "form": form, **how,
+                              "ms_a_layer": round(ms, 4),
+                              "GBps_of_the_rows_states":
+                                  round(moved / ms / 1e6, 1)}), flush=True)
+
+        # the forms on one pool, before any is timed
+        pool = jax.random.normal(ks[2], (2, slots + 1) + shape)
+        want, out_want = jax.jit(xla)(pool, 1, at, *inputs)
+        out, got = jax.jit(kernel)(*inputs, pool, 1, at)
+        print(json.dumps({
+            "rule": rule, "rows": B, "kernel_against_xla": {
+                "out_max_gap": float(jnp.abs(
+                    out.reshape(B, -1) - out_want).max()),
+                "state_max_gap": float(jnp.abs(got - want).max())}}),
+            flush=True)
+        del pool, want, got
+        report("xla, every slot", xla)
+        for heads in STATE_HEADS[rule]:
+            report("hvd_state_step", through(kernel, heads=heads),
+                   heads_a_grid_step=heads)
+
+
 #: The kernel's forms the sweep times: channels a grid step, positions a
 #: grid step, channels a loop carries in registers, positions a loop
 #: iteration.
@@ -360,18 +457,25 @@ def main():
     parser.add_argument("--xla-forms", action="store_true",
                         help="the chunk's scan in XLA alone, by form: what "
                              "PR 47 chose among")
-    parser.add_argument("--layers", type=int, default=26)
-    parser.add_argument("--slots", type=int, default=256,
+    parser.add_argument("--rule", choices=("mamba", "ssd", "kda"),
+                        default="mamba",
+                        help="with --step: the selective scan's step "
+                             "(ops/mamba_step.py), or Mamba-2's or a kda "
+                             "layer's (ops/state_step.py)")
+    parser.add_argument("--layers", type=int, default=None)
+    parser.add_argument("--slots", type=int, default=None,
                         help="with --layers: a rehearsal's size, off the chip")
     parser.add_argument("--channels", type=int, default=DI,
                         help="the scan's channels: a rehearsal's, off the "
                              "chip")
     args = parser.parse_args()
+    if args.step and args.rule != "mamba":
+        return state_step_sweep(args.rule, args.layers, args.slots)
     if args.step:
-        return step_sweep(args.layers, args.slots)
+        return step_sweep(args.layers or 26, args.slots or 256)
     if args.xla_forms:
         return xla_forms_sweep()
-    return scan_sweep(args.layers, args.channels)
+    return scan_sweep(args.layers or 26, args.channels)
 
 
 if __name__ == "__main__":

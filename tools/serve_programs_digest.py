@@ -47,6 +47,9 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          # with trinity's and ling's, the programs that changed with
          # PR 61 (hvd_grouped_matmul under a chip's share of the experts:
          # moe._held_rows); Kimi's share keeps lax.ragged_dot by its shapes
+         # (its decode and ling's changed again with PR 62:
+         # hvd_state_step, a step's mamba2 and kda layers; no chunk
+         # program and no other cell's decode did)
          ("nemotron-3-super-120b-ep4-11l", "agent-backlog")]
 
 
