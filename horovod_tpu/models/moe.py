@@ -85,9 +85,17 @@ class MoEConfig:
     topk_group: int = 1
     #: An expert's form: ``"swiglu"`` (``silu(x Wg) * (x Wu)`` then
     #: ``Wd``: three matrices) or ``"relu2"`` (``relu(x Wu)^2`` then
-    #: ``Wd``: UNGATED, two matrices and no ``w_gate``), the shared
+    #: ``Wd``: UNGATED, two matrices and no ``w_gate``), or
+    #: ``"polynorm"`` (:func:`polynorm` of ``x Wg`` where SwiGLU has
+    #: SiLU, with three weights and a bias of its own an expert,
+    #: ``poly_w`` [Eh, 3] and ``poly_b`` [Eh], float32), the shared
     #: expert's too.
     activation: str = "swiglu"
+    #: PolyNorm's output scale, the clamp on its bias and the eps of
+    #: its norms (the model's ``norm_eps``).
+    polynorm_scale: float = 1.0
+    polynorm_bias_clamp: float = 0.5
+    norm_eps: float = 1e-5
     #: The routed experts read and write a LATENT of this many values
     #: (0: the model's width): ``latent_down`` [D, latent] before the
     #: dispatch (a dispatched row is ``latent`` wide), ``latent_up``
@@ -101,13 +109,14 @@ class MoEConfig:
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown MoE scoring {self.scoring!r}")
-        if self.activation not in ("swiglu", "relu2"):
+        if self.activation not in ("swiglu", "relu2", "polynorm"):
             raise ValueError(f"unknown MoE activation {self.activation!r}")
         if self.experts_held is None and (
                 self.activation != "swiglu" or self.latent
                 or self.shared_d_ff is not None):
             raise ValueError(
-                "an ungated activation, a latent around the experts and a "
+                "an ungated or PolyNorm activation, a latent around the "
+                "experts and a "
                 "shared expert of its own width are the held dispatch's "
                 "(experts_held, a chip's share, which may be all of them; "
                 "its products take ops/grouped_matmul.py's kernel by the "
@@ -173,7 +182,38 @@ def moe_param_specs(n_layers_leading: bool = True,
     if cfg is not None and cfg.activation == "relu2":
         for gate in {"w_gate", "shared_gate"} & set(specs):
             del specs[gate]
+    if cfg is not None and cfg.activation == "polynorm":
+        specs.update(poly_w=P(*lead, "ep", None), poly_b=P(*lead, "ep"))
+        if cfg.shared_expert:
+            specs.update(shared_poly_w=P(*lead, None), shared_poly_b=P(*lead))
     return specs
+
+
+def polynorm(z, w, b, scale: float, clamp: float, eps: float):
+    """PolyNorm of ``z`` [.., F] in float32: ``scale * (w1 N(z^3) + w2
+    N(z^2) + w3 N(z) + clip(b, +-clamp))``, ``N(t) = t / sqrt(mean_F(t^2)
+    + eps)``. ``w`` [.., 3] and ``b`` [..] are one set for every row (a
+    feed-forward's own) or a set a row (a sorted row's expert's)."""
+    z = z.astype(jnp.float32)
+
+    def normed(t):
+        return t * lax.rsqrt(jnp.mean(t * t, -1, keepdims=True) + eps)
+
+    with jax.named_scope("polynorm"):
+        w = w.astype(jnp.float32)[..., None, :]
+        b = jnp.clip(b.astype(jnp.float32), -clamp, clamp)[..., None]
+        return scale * (w[..., 0] * normed(z * z * z) + w[..., 1]
+                        * normed(z * z) + w[..., 2] * normed(z) + b)
+
+
+def init_polynorm(key, lead) -> Dict[str, Any]:
+    """``poly_w`` [*lead, 3] and ``poly_b`` [*lead], float32: a third
+    each and zero, as published, plus noise so that a seeded model's
+    sets differ and a term left out shows."""
+    kw, kb = jax.random.split(key)
+    return {"poly_w": 1 / 3 + 0.1 * jax.random.normal(kw, (*lead, 3),
+                                                      jnp.float32),
+            "poly_b": 0.2 * jax.random.normal(kb, tuple(lead), jnp.float32)}
 
 
 def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
@@ -186,7 +226,7 @@ def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
         return (jax.random.normal(k, shape, jnp.float32)
                 * (fan_in ** -0.5)).astype(dtype)
 
-    gated = cfg.activation == "swiglu"     # relu2: no gate matrix
+    gated = cfg.activation != "relu2"      # relu2: no gate matrix
     params = {
         # Router in f32: small, and routing decisions are precision-
         # sensitive (standard practice).
@@ -210,6 +250,12 @@ def init_moe_params(key, n_layers: int, d_model: int, d_ff: int,
         kl = jax.random.split(jax.random.fold_in(key, 2), 2)
         params.update(latent_down=dense(kl[0], (L, D, Dx), D),
                       latent_up=dense(kl[1], (L, Dx, D), Dx))
+    if cfg.activation == "polynorm":
+        kp = jax.random.split(jax.random.fold_in(key, 3), 2)
+        params.update(init_polynorm(kp[0], (L, Eh)))
+        if cfg.shared_expert:
+            params.update({"shared_" + name: a for name, a in
+                           init_polynorm(kp[1], (L,)).items()})
     return params
 
 
@@ -502,11 +548,18 @@ def moe_ffn_dropless(x, lp, cfg: MoEConfig, token_axes=()):
     return y.reshape(B, T, D).astype(x.dtype), aux
 
 
-def _shared_expert(xf, lp):
-    """The expert every token takes, on ``xf`` [N, D]: a SwiGLU, or
-    where the parameters hold no gate matrix ``relu(x Wu)^2 Wd``."""
+def _shared_expert(xf, lp, cfg: Optional[MoEConfig] = None):
+    """The expert every token takes, on ``xf`` [N, D]: a SwiGLU (with
+    PolyNorm for its SiLU where the parameters hold
+    ``shared_poly_w``), or where they hold no gate matrix ``relu(x
+    Wu)^2 Wd``."""
     with jax.named_scope("moe_shared"):
-        if "shared_gate" in lp:
+        if "shared_poly_w" in lp:
+            g = polynorm(xf @ lp["shared_gate"], lp["shared_poly_w"],
+                         lp["shared_poly_b"], cfg.polynorm_scale,
+                         cfg.polynorm_bias_clamp, cfg.norm_eps)
+            h = g * (xf @ lp["shared_up"]).astype(jnp.float32)
+        elif "shared_gate" in lp:
             g = jax.nn.silu((xf @ lp["shared_gate"]).astype(jnp.float32))
             h = g * (xf @ lp["shared_up"]).astype(jnp.float32)
         else:
@@ -549,7 +602,7 @@ def held_row_bound(pairs: int, cfg: MoEConfig) -> Optional[int]:
 
 
 def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None,
-               sharded: bool = False):
+               sharded: bool = False, poly=None):
     """The routed sum ``y`` [N, D] of the held experts over the first
     ``rows`` sorted places (``None``: all ``N·K`` of them). ``order``
     puts the held pairs first, by expert, so with ``sizes.sum() <=
@@ -558,7 +611,9 @@ def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None,
     slots read a source of ``rows`` rows. The two (ungated) or three
     products are :func:`_grouped_product`'s, the whole mixture's rule:
     the kernel where the matrices' bytes bound them, unless the tokens
-    are one shard of a mesh's (``sharded``)."""
+    are one shard of a mesh's (``sharded``). ``poly``: ``(poly_w [Eh,
+    3], poly_b [Eh], cfg)``, PolyNorm for the gate's SiLU, each sorted
+    row under its own expert's set."""
     K = gates.shape[1]
     w_gate, w_up, w_down = w
     with jax.named_scope("moe_dispatch"):
@@ -571,6 +626,19 @@ def _held_rows(xf, w, gates, held, order, inverse, sizes, rows=None,
             # ungated: relu(x Wu)^2, two products and not three
             h = jnp.square(jax.nn.relu(_grouped_product(
                 taken, w_up, sizes, sharded).astype(jnp.float32)))
+        elif poly is not None:
+            poly_w, poly_b, cfg = poly
+            # the expert of each sorted place: the groups lie in order
+            # (a place behind the last group reads the last expert's set
+            # and is masked below)
+            of = jnp.minimum(jnp.searchsorted(
+                jnp.cumsum(sizes), jnp.arange(taken.shape[0]), side="right"),
+                sizes.shape[0] - 1)
+            g = polynorm(_grouped_product(taken, w_gate, sizes, sharded),
+                         poly_w[of], poly_b[of], cfg.polynorm_scale,
+                         cfg.polynorm_bias_clamp, cfg.norm_eps)
+            h = g * _grouped_product(taken, w_up, sizes, sharded
+                                     ).astype(jnp.float32)
         else:
             g = jax.nn.silu(_grouped_product(taken, w_gate, sizes, sharded)
                             .astype(jnp.float32))
@@ -662,9 +730,14 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts, token_axes=()):
         with jax.named_scope("moe_latent_down"):
             rows = xf @ lp["latent_down"]
     sharded = bool(token_axes)
-    if bound is None:
+    # PolyNorm's sets, an expert's own: every row of such a mixture is
+    # run (the bounded form's custom_vjp carries the matrices alone, and
+    # a served chunk or step is under its threshold anyway)
+    poly = ((lp["poly_w"], lp["poly_b"], cfg)
+            if cfg.activation == "polynorm" else None)
+    if bound is None or poly is not None:
         y = _held_rows(rows, w, gates, held, order, inverse, sizes,
-                       sharded=sharded)
+                       sharded=sharded, poly=poly)
     else:
         y = _held_rows_bounded(bound, sharded, rows, w, gates, held, order,
                                inverse, sizes)
@@ -673,7 +746,7 @@ def _held_experts(x, lp, cfg: MoEConfig, gates, experts, token_axes=()):
         with jax.named_scope("moe_latent_up"):
             y = (y @ lp["latent_up"]).astype(x.dtype)
     if cfg.shared_expert:
-        y = y + _shared_expert(xf, lp)
+        y = y + _shared_expert(xf, lp, cfg)
     return y.reshape(B, T, D).astype(x.dtype)
 
 

@@ -88,7 +88,9 @@ class Rotary:
 
 #: The kinds of layer a stack with ``layer_types`` may hold.
 LAYER_KINDS = ("sliding", "full", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv", "eva", "mamba2")
+               "lightning", "conv", "eva", "mamba2", "mla_sliding")
+#: The two kinds whose cache is a latent (pages, or a ring by slot).
+MLA_KINDS = ("mla", "mla_sliding")
 #: What ``layer_types`` names a layer that is a feed-forward ALONE, in a
 #: stack whose layers are one branch each (``one_branch``): no kind of
 #: mixer, and nothing kept between calls.
@@ -169,8 +171,8 @@ class TransformerConfig:
     n_dense_layers: int = 0
     d_ff_dense: Optional[int] = None
     # One of the LAYER_KINDS a layer ("sliding" | "full" here; "kda",
-    # "mla", "mamba", "sparse", "lightning", "conv", "eva" and "mamba2"
-    # below; "ffn" with one_branch). A
+    # "mla", "mamba", "sparse", "lightning", "conv", "eva", "mamba2" and
+    # "mla_sliding" below; "ffn" with one_branch). A
     # sliding layer sees the keys
     # j with p - attn_window < j <= p and rotates q and k; a full layer
     # sees every j <= p and applies no rotary embedding, unless
@@ -357,6 +359,43 @@ class TransformerConfig:
     moe_activation: str = "swiglu"
     moe_latent: int = 0
     moe_shared_d_ff: Optional[int] = None
+    # An eleventh kind of layer_types (ISSUE 63; served, not trained):
+    # "mla_sliding", an mla layer that sees the keys j with p -
+    # attn_window < j <= p: what it keeps of a position is the mla
+    # layer's latent row, in a RING by batch slot as a sliding layer's
+    # keys are (kv_cache.KVCache) and not in pages. Both mla kinds may
+    # have GROUPED heads (Motif's GDLA): n_kv_heads < n_heads key and
+    # value heads come up from the latent (w_ukv [C, n_kv_heads * 2 *
+    # Dh]) and KV head g serves the query heads g * n_heads / n_kv_heads
+    # onwards. mla_noise_heads (0 or n_kv_heads): the LAST query head of
+    # each group is a noise head, and each other (signal) head's output
+    # is A_s - sigmoid(h W_lambda)_s * A_noise (Differential Transformer
+    # V2: lambda a token and signal head, no norm after); wo reads the
+    # signal heads alone. mla_elementwise_gate: the output times
+    # sigmoid(h Wg) elementwise, Wg [D, signal heads * Dh] (where
+    # mla_head_gate is one value a head).
+    mla_noise_heads: int = 0
+    mla_elementwise_gate: bool = False
+    # The residual path of manifold-constrained hyper-connections (mHC,
+    # arXiv:2512.24880): the stream is mhc_streams rows a position, [.., n,
+    # D]; around each branch F of a layer, with x~ = RMSNorm(vec X) in
+    # float32, H_pre = sigmoid(a x~ W_pre + b) [n], H_post = 2 sigmoid(.)
+    # [n], H_res = mhc_sinkhorn_iters alternations of row and column
+    # normalisation of exp(a reshape(x~ W_res) + b) [n, n]; X' = H_res X
+    # + H_post^T F(norm(H_pre X)) (stream_in / stream_out, the serve
+    # programs' mla kinds and ffn_block). The embedding is copied to the n
+    # streams and their sum goes to the final norm and the head. 1: the
+    # one stream every other configuration has.
+    mhc_streams: int = 1
+    mhc_sinkhorn_iters: int = 20
+    # The dense feed-forward's activation: "swiglu", or "polynorm"
+    # (models/moe.py::polynorm: s (w1 N(z^3) + w2 N(z^2) + w3 N(z) +
+    # clip(b)) on the gate's product z, N the RMS norm over the width,
+    # float32, three weights and a bias a feed-forward; moe_activation
+    # "polynorm" gives every expert and the shared expert their own).
+    ffn_activation: str = "swiglu"
+    polynorm_scale: float = 1.0
+    polynorm_bias_clamp: float = 0.5
 
     def __post_init__(self):
         object.__setattr__(self, "mamba2_dt_range",
@@ -375,10 +414,12 @@ class TransformerConfig:
                     f"{self.layer_types}")
             if "sliding" in self.layer_types and not self.attn_window:
                 raise ValueError("sliding layers need attn_window")
-            if "mla" in self.layer_types and not (self.mla_kv_rank
-                                                  and self.mla_rope_dim):
+            if set(MLA_KINDS) & set(self.layer_types) and not (
+                    self.mla_kv_rank and self.mla_rope_dim):
                 raise ValueError("mla layers need mla_kv_rank and "
                                  "mla_rope_dim")
+            if "mla_sliding" in self.layer_types and not self.attn_window:
+                raise ValueError("mla_sliding layers need attn_window")
             if "mamba" in self.layer_types and not self.mamba_dt_rank:
                 raise ValueError("mamba layers need mamba_dt_rank")
             if "mamba2" in self.layer_types and (
@@ -399,11 +440,13 @@ class TransformerConfig:
                     "attn_gate, nor a qk_norm over the whole vector (the "
                     "one kind that keeps both rows by slot and summary "
                     "pages behind the tables)")
-            # kda and mla layers project for n_heads heads with norms
-            # and gates of their own; mamba and conv layers have no q or
-            # k, and the full layers beside them keep the configuration's
+            # kda and mla layers project their own heads with norms and
+            # gates of their own (a kda layer n_heads of each; an mla
+            # layer n_heads of q over n_kv_heads of k and v out of the
+            # latent); mamba and conv layers have no q or k, and the
+            # full layers beside them keep the configuration's
             # n_kv_heads and qk_norm_per_head
-            own_heads = {"kda", "mla"} & set(self.layer_types)
+            own_heads = {"kda", *MLA_KINDS} & set(self.layer_types)
             no_heads = {"mamba", "conv", "mamba2"} & set(self.layer_types)
             if "sparse" in self.layer_types and (
                     self.sparse_kernel % self.sparse_stride
@@ -420,13 +463,16 @@ class TransformerConfig:
                     "(sparse_init_blocks and the window's) within "
                     "sparse_topk")
             if own_heads and (
-                    self.n_kv_heads != self.n_heads
+                    (self.n_kv_heads != self.n_heads
+                     if "kda" in own_heads
+                     else self.n_heads % self.n_kv_heads)
                     or self.attn_gate or self.sandwich_norm
                     or self.qk_norm or self.qk_norm_per_head):
                 raise ValueError(
                     f"{' and '.join(sorted(own_heads))} layers have n_heads "
                     "heads of their own projections, norms and gates: "
-                    "n_kv_heads = n_heads, and no attn_gate, sandwich_norm "
+                    "n_kv_heads = n_heads (mla layers alone: n_kv_heads "
+                    "dividing n_heads), and no attn_gate, sandwich_norm "
                     "or qk_norm")
             if no_heads and (self.attn_gate or self.sandwich_norm
                              or self.qk_norm):
@@ -438,6 +484,32 @@ class TransformerConfig:
                     "attn_gate, sandwich_norm or qk_norm over the whole "
                     "vector (qk_norm_per_head and n_kv_heads are the full "
                     "layers' beside them)")
+        mla_alone = bool(self.layer_types) and not (
+            set(self.layer_types) - set(MLA_KINDS))
+        if self.mla_noise_heads and not (
+                mla_alone and self.mla_noise_heads == self.n_kv_heads
+                and self.n_heads >= 2 * self.n_kv_heads):
+            raise ValueError(
+                "mla_noise_heads are the mla kinds' (mla, mla_sliding): one "
+                "noise head a KV head, the last query head of its group, "
+                f"beside at least one signal head (got {self.mla_noise_heads} "
+                f"of {self.n_heads} heads over {self.n_kv_heads} KV heads)")
+        if self.mla_elementwise_gate and not (
+                mla_alone and not self.mla_head_gate):
+            raise ValueError(
+                "mla_elementwise_gate is the mla kinds' output gate, in "
+                "place of mla_head_gate: set one")
+        if self.mhc_streams < 1 or self.mhc_sinkhorn_iters < 1 or (
+                self.mhc_streams > 1 and not mla_alone):
+            raise ValueError(
+                f"mhc_streams {self.mhc_streams} rows a position (mHC) are "
+                "read by the serve programs' mla and mla_sliding layers and "
+                "by ffn_block (stream_in, stream_out): every other kind's "
+                "residual, and the trainer's decoder_layer, add a branch to "
+                "ONE stream")
+        if self.ffn_activation not in ("swiglu", "polynorm"):
+            raise ValueError(
+                f"unknown ffn_activation {self.ffn_activation!r}")
         if self.one_branch and (self.layer_types is None
                                 or self.n_dense_layers or self.sandwich_norm):
             raise ValueError(
@@ -531,11 +603,11 @@ class TransformerConfig:
         alone (a kda, mamba or lightning layer's recurrent state, an mla
         layer's latent, a sparse layer's compressed keys, a conv layer's
         rows, an eva layer's window rows and summary pages, a mamba2
-        layer's state), or its layers are one branch each: what the
-        serve programs alone run."""
+        layer's state, an mla_sliding layer's ring of latents), or its
+        layers are one branch each: what the serve programs alone run."""
         return bool(self.layer_types) and (self.one_branch or bool(
             {"kda", "mla", "mamba", "sparse", "lightning", "conv", "eva",
-             "mamba2"} & set(self.layer_types)))
+             "mamba2", "mla_sliding"} & set(self.layer_types)))
 
     def rotary_of(self, layer: int = 0) -> Optional[Rotary]:
         """How layer ``layer`` rotates q and k, None for not at all:
@@ -550,6 +622,8 @@ class TransformerConfig:
             return Rotary(self.rope_theta)
         if kind in ("kda", "mamba", "sparse", "conv", "mamba2", FFN):
             kind = "full"
+        if kind == "mla_sliding":
+            kind = "mla"        # both latent kinds rotate alike
         by_kind = dict(self.layer_rotary or ())
         if kind in by_kind:
             return by_kind[kind]
@@ -560,6 +634,12 @@ class TransformerConfig:
     @property
     def n_window_layers(self) -> int:
         return sum(self.sliding(i) for i in range(self.n_layers))
+
+    @property
+    def mla_signal_heads(self) -> int:
+        """The mla kinds' heads that reach ``wo``: all but the noise
+        heads."""
+        return self.n_heads - self.mla_noise_heads
 
     @classmethod
     def llama3_8b(cls, **kw):
@@ -593,7 +673,13 @@ class TransformerConfig:
                                  topk_group=self.moe_topk_group,
                                  activation=self.moe_activation,
                                  latent=self.moe_latent,
-                                 shared_d_ff=self.moe_shared_d_ff)
+                                 shared_d_ff=self.moe_shared_d_ff,
+                                 **({"polynorm_scale": self.polynorm_scale,
+                                     "polynorm_bias_clamp":
+                                         self.polynorm_bias_clamp,
+                                     "norm_eps": self.norm_eps}
+                                    if self.moe_activation == "polynorm"
+                                    else {}))
 
 
 # ---------------------------------------------------------------------------
@@ -648,7 +734,7 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
                   "w_out": P(None, "tp", "fsdp")}
     if kind == FFN:
         layers = {"mlp_norm": vec}
-    if kind == "mla":
+    if kind in MLA_KINDS:
         del layers["wk"], layers["wv"]
         layers.update(w_dkv=P(None, "fsdp", None), kv_norm=vec,
                       w_ukv=P(None, None, "tp"))
@@ -656,8 +742,10 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
             del layers["wq"]
             layers.update(w_dq=P(None, "fsdp", None), dq_norm=vec,
                           w_uq=P(None, None, "tp"))
-        if cfg.mla_head_gate:
+        if cfg.mla_head_gate or cfg.mla_elementwise_gate:
             layers["wg"] = mat
+        if cfg.mla_noise_heads:
+            layers["w_lambda"] = mat
     if cfg.qk_norm:
         layers["q_norm"] = P(None, "tp")   # [L, H*Dh], as wq's columns
         layers["k_norm"] = P(None, "tp")   # [L, Hkv*Dh]
@@ -669,12 +757,19 @@ def _block_specs(cfg: TransformerConfig, moe: bool, kind: str = "full"
     if cfg.sandwich_norm:
         layers["post_attn_norm"] = P(None, None)
         layers["post_mlp_norm"] = P(None, None)
+    if cfg.mhc_streams > 1:
+        # float32, a branch: [L, n * D, n * n + 2 n], [L, n * n + 2 n], [L, 3]
+        layers.update({f"mhc_{branch}": {"w": P(None, None, None),
+                                         "b": vec, "alpha": vec}
+                       for branch in ("attn", "mlp")})
     if cfg.one_branch and kind != FFN:
         del layers["mlp_norm"]          # a mixer alone
         return layers
     if moe:
         layers["moe"] = moe_lib.moe_param_specs(cfg=cfg.moe)
     else:
+        if cfg.ffn_activation == "polynorm":
+            layers.update(poly_w=vec, poly_b=P(None))
         layers.update({
             # Separate gate/up/q/k/v matmuls measure FASTER than fused
             # wide projections on v5e at d=2048-4096 (fusion costs the
@@ -831,8 +926,9 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
         }
     elif kind == FFN:
         layers = {"mlp_norm": jnp.ones((L, D), dt)}
-    elif kind == "mla":
+    elif kind in MLA_KINDS:
         R, C, Q = cfg.mla_rope_dim, cfg.mla_kv_rank, cfg.mla_q_rank
+        Hs = cfg.mla_signal_heads
         # a head's q: Dh without position, then R rotated; with a q
         # rank, up from a normed latent of Q values
         q = ({"w_dq": dense(next(k), (L, D, Q), D),
@@ -845,13 +941,17 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
             # the latent, then the R rotated values every head shares
             "w_dkv": dense(next(k), (L, D, C + R), D),
             "kv_norm": jnp.ones((L, C), dt),
-            # a head's key without position, then its value
-            "w_ukv": dense(next(k), (L, C, H * 2 * Dh), C),
+            # a KV head's key without position, then its value
+            "w_ukv": dense(next(k), (L, C, Hkv * 2 * Dh), C),
             **({"wg": dense(next(k), (L, D, H), D)}
                if cfg.mla_head_gate else {}),
-            "wo": dense(next(k), (L, H * Dh, D), H * Dh),
+            "wo": dense(next(k), (L, Hs * Dh, D), Hs * Dh),
             "mlp_norm": jnp.ones((L, D), dt),
         }
+        if cfg.mla_elementwise_gate:
+            layers["wg"] = dense(next(k), (L, D, Hs * Dh), D)
+        if cfg.mla_noise_heads:
+            layers["w_lambda"] = dense(next(k), (L, D, Hs), D)
     else:
         layers = {
             "attn_norm": jnp.ones((L, D), dt),
@@ -884,12 +984,27 @@ def _init_blocks(cfg: TransformerConfig, k, L: int, moe: bool, F: int,
     if cfg.sandwich_norm:
         layers["post_attn_norm"] = jnp.ones((L, D), dt)
         layers["post_mlp_norm"] = jnp.ones((L, D), dt)
+    if cfg.mhc_streams > 1:
+        n = cfg.mhc_streams
+        for branch in ("attn", "mlp"):
+            # the three mappings' matrices side by side, [W_pre | W_post |
+            # W_res]: logits of unit variance on the normed stream, and
+            # biases drawn as wide (mhc_identity_init false), so that the
+            # streams do mix and 20 alternations differ from 1; float32
+            layers[f"mhc_{branch}"] = {
+                "w": (jax.random.normal(next(k), (L, n * D, n * n + 2 * n),
+                                        jnp.float32) * (n * D) ** -0.5),
+                "b": jax.random.normal(next(k), (L, n * n + 2 * n),
+                                       jnp.float32),
+                "alpha": jnp.ones((L, 3), jnp.float32)}
     if cfg.one_branch and kind != FFN:
         del layers["mlp_norm"]          # a mixer alone
         return layers
     if moe:
         layers["moe"] = moe_lib.init_moe_params(next(k), L, D, F, cfg.moe, dt)
     else:
+        if cfg.ffn_activation == "polynorm":
+            layers.update(moe_lib.init_polynorm(next(k), (L,)))
         layers.update({
             "w_gate": dense(next(k), (L, D, F), D),
             "w_up": dense(next(k), (L, D, F), D),
@@ -1511,7 +1626,8 @@ def lightning_residual(cfg: TransformerConfig, lp, x, h, o):
 def mla_rotary(cfg: TransformerConfig) -> Rotary:
     """How the mla layers rotate (``rotary_of`` of the first of them:
     ``layer_rotary`` is by kind)."""
-    return cfg.rotary_of(cfg.layer_types.index("mla"))
+    return cfg.rotary_of(next(i for i, kind in enumerate(cfg.layer_types)
+                              if kind in MLA_KINDS))
 
 
 def mla_scale(cfg: TransformerConfig) -> float:
@@ -1548,44 +1664,137 @@ def mla_inputs(cfg: TransformerConfig, lp, x, pos):
 
 
 def mla_up(cfg: TransformerConfig, lp):
-    """``(W_uk, W_uv)`` [C, H, Dh] each, out of ``w_ukv``."""
-    w = lp["w_ukv"].reshape(cfg.mla_kv_rank, cfg.n_heads, 2, cfg.head_dim)
+    """``(W_uk, W_uv)`` [C, Hkv, Dh] each, out of ``w_ukv``: one pair a
+    KV head (``n_kv_heads``; as many as query heads unless the heads
+    are grouped), which serves the ``n_heads / n_kv_heads`` query heads
+    of its group."""
+    w = lp["w_ukv"].reshape(cfg.mla_kv_rank, cfg.n_kv_heads, 2,
+                            cfg.head_dim)
     return w[:, :, 0], w[:, :, 1]
 
 
-def mla_residual(cfg: TransformerConfig, lp, x, h, o):
-    """An mla layer after its attention ``o`` [B, T, H, Dh]: one
-    sigmoid gate a head where the configuration has it, the output
-    projection and the residual."""
-    B, T = x.shape[:2]
+def gdla_diff(cfg: TransformerConfig, lp, h, o):
+    """Differential heads (``mla_noise_heads``): ``o`` [B, T, H, X], a
+    head's attention result in any basis ``X`` (expanded values, or the
+    latent before ``W_uv``: the subtraction is linear), to the signal
+    heads' ``A_s - sigmoid(h W_lambda)_s A_noise`` [B, T, Hs, X], the
+    noise head the last of the signal head's KV group; float32 inside.
+    Without noise heads ``o`` as it came."""
+    if not cfg.mla_noise_heads:
+        return o
+    with jax.named_scope("gdla_diff"):
+        B, T, H, X = o.shape
+        G = cfg.n_kv_heads
+        lam = jax.nn.sigmoid((h @ lp["w_lambda"]).astype(jnp.float32))
+        o = o.astype(jnp.float32).reshape(B, T, G, H // G, X)
+        o = (o[:, :, :, :-1]
+             - lam.reshape(B, T, G, H // G - 1, 1) * o[:, :, :, -1:])
+        return o.reshape(B, T, H - G, X).astype(cfg.dtype)
+
+
+def mla_residual(cfg: TransformerConfig, lp, x, h, o, mix=None):
+    """An mla layer after its attention ``o`` [B, T, Hs, Dh] (the
+    signal heads: all of them without noise heads): one sigmoid gate a
+    head, or one a value (``mla_elementwise_gate``), where the
+    configuration has it, the output projection and the residual
+    (``mix``: :func:`stream_in`'s, for :func:`stream_out`)."""
+    B, T = o.shape[:2]
     if cfg.mla_head_gate:
         gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
         o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
-    return x + (o.reshape(B, T, -1) @ lp["wo"]).astype(cfg.dtype)
+    o = o.reshape(B, T, -1)
+    if cfg.mla_elementwise_gate:
+        with jax.named_scope("attn_gate"):
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                (h @ lp["wg"]).astype(jnp.float32))).astype(cfg.dtype)
+    return stream_out(cfg, x, (o @ lp["wo"]).astype(cfg.dtype), mix)
+
+
+def sinkhorn_knopp(logits, iters: int):
+    """``iters`` alternations of row and column normalisation of
+    ``exp(logits)`` [.., n, n], float32: towards a doubly stochastic
+    matrix (rows first, so the columns sum to 1 exactly and the rows
+    nearly)."""
+    m = jnp.exp(logits - logits.max((-2, -1), keepdims=True))
+    for _ in range(iters):
+        m = m / m.sum(-1, keepdims=True)
+        m = m / m.sum(-2, keepdims=True)
+    return m
+
+
+def stream_in(cfg: TransformerConfig, lp, branch: str, x):
+    """What a branch (``"attn"`` or ``"mlp"``) reads of the stream
+    ``x``, and what :func:`stream_out` needs to put its result back:
+    ``(u, mix)``. One stream: ``(x, None)``, nothing computed. mHC
+    (``mhc_streams`` n > 1, ``x`` [B, T, n, D]): the three mappings from
+    the float32 RMS-normed ``vec X`` (no gain) through
+    ``lp["mhc_<branch>"]``, ``u = H_pre X`` [B, T, D] and ``mix =
+    (H_post [B, T, n], H_res [B, T, n, n])``."""
+    if cfg.mhc_streams == 1:
+        return x, None
+    with jax.named_scope("mhc_mix"):
+        n, p = cfg.mhc_streams, lp[f"mhc_{branch}"]
+        B, T = x.shape[:2]
+        flat = x.reshape(B, T, -1).astype(jnp.float32)
+        flat = flat * lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True)
+                                + cfg.norm_eps)
+        z = jnp.einsum("btk,km->btm", flat, p["w"],
+                       precision=lax.Precision.HIGHEST)
+        a = p["alpha"]
+        pre = jax.nn.sigmoid(a[0] * z[..., :n] + p["b"][:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * z[..., n:2 * n] + p["b"][n:2 * n])
+        res = sinkhorn_knopp(
+            (a[2] * z[..., 2 * n:] + p["b"][2 * n:]).reshape(B, T, n, n),
+            cfg.mhc_sinkhorn_iters)
+        u = jnp.einsum("btn,btnd->btd", pre, x.astype(jnp.float32))
+        return u.astype(cfg.dtype), (post, res)
+
+
+def stream_out(cfg: TransformerConfig, x, y, mix):
+    """The stream after a branch's result ``y``: ``x + y`` on one
+    stream (``mix`` None), ``H_res X + H_post^T y`` on mHC's, float32
+    inside."""
+    if mix is None:
+        return x + y
+    with jax.named_scope("mhc_mix"):
+        post, res = mix
+        out = (jnp.einsum("btmn,btnd->btmd", res, x.astype(jnp.float32))
+               + post[..., None] * y.astype(jnp.float32)[:, :, None])
+        return out.astype(x.dtype)
 
 
 def ffn_block(cfg: TransformerConfig, lp, x, moe_fn=None):
-    """A decoder block after its attention: pre-norm, the dense SwiGLU
+    """A decoder block after its attention: what the branch reads of
+    the stream (:func:`stream_in`), pre-norm, the dense SwiGLU (or
+    PolyNorm: ``ffn_activation``)
     or ``moe_fn(h, lp['moe']) -> (y, aux)`` (whichever the block's
     parameters hold), the norm on the branch where configured, the
-    residual. Returns (x, aux), aux 0 for the dense FFN.
+    residual (:func:`stream_out`). Returns (x, aux), aux 0 for the dense
+    FFN.
     ``moe_fn=None`` is the meshless :func:`moe_lib.make_moe_ffn`: the
     plain GSPMD :func:`moe_lib.moe_ffn`, or the dropless dispatch on
     the caller's own rows for a configuration without a capacity."""
-    h = stream_norm(cfg, x, lp["mlp_norm"])
+    u, mix = stream_in(cfg, lp, "mlp", x)
+    h = stream_norm(cfg, u, lp["mlp_norm"])
     if "moe" in lp:
         if moe_fn is None:
             moe_fn = moe_lib.make_moe_ffn(cfg.moe, None)
         y, aux = moe_fn(h, lp["moe"])
         y = y.astype(cfg.dtype)
     else:
-        g = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32))
+        g = (h @ lp["w_gate"]).astype(jnp.float32)
+        if cfg.ffn_activation == "polynorm":
+            g = moe_lib.polynorm(g, lp["poly_w"], lp["poly_b"],
+                                 cfg.polynorm_scale, cfg.polynorm_bias_clamp,
+                                 cfg.norm_eps)
+        else:
+            g = jax.nn.silu(g)
         u = (h @ lp["w_up"]).astype(jnp.float32)
         y = ((g * u).astype(cfg.dtype) @ lp["w_down"]).astype(cfg.dtype)
         aux = jnp.zeros((), jnp.float32)
     if cfg.sandwich_norm:
         y = _rmsnorm(y, lp["post_mlp_norm"], cfg.norm_eps)
-    return x + _branch(cfg, y), aux
+    return stream_out(cfg, x, _branch(cfg, y), mix), aux
 
 
 def _scoped(name: str, attend):
@@ -1664,10 +1873,10 @@ def _refuse_mixed(cfg: TransformerConfig, what: str) -> None:
 
 
 def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
-    """The trainer's entry points refuse kda, mla, mamba, sparse,
-    lightning, conv, eva and mamba2 layers, and a stack of one-branch
-    layers, by name: their forward exists in the serve programs
-    alone."""
+    """The trainer's entry points refuse kda, mla, mla_sliding, mamba,
+    sparse, lightning, conv, eva and mamba2 layers, and a stack of
+    one-branch layers, by name: their forward exists in the serve
+    programs alone."""
     if cfg.stateful:
         raise NotImplementedError(
             f"{what} does not run kda, mla or mamba layers, nor sparse or "
@@ -1678,7 +1887,7 @@ def _refuse_stateful(cfg: TransformerConfig, what: str) -> None:
             "backward through the chunked delta-rule scan, the selective "
             "scan, the decayed linear scan or the SSD chunks of "
             "serve/decode.py, no latent "
-            "attention, no selection of key blocks and no attention over "
+            "attention (mla, mla_sliding), no selection of key blocks and no attention over "
             "a window beside chunk summaries (ROADMAP B14, B8, B18). "
             "The configuration is served through ServeEngine.")
 

@@ -15,8 +15,10 @@ attended, one running softmax). ONE kernel body, two Pallas calls of it:
     rings are such pools too (:func:`ring_decode`: a slot's ring as
     pages, the table arithmetic, a row's window from the middle of its
     first page on), against ``_attend_keys`` over whole rings.
-``hvd_latent_decode`` (:func:`latent_decode`)
-    an ``mla`` layer's latents, one pool: the keys are the values (one
+``hvd_latent_decode`` (:func:`latent_decode`, :func:`latent_ring_decode`)
+    an ``mla`` layer's latents, one pool (an ``mla_sliding`` layer's
+    rings of latents are such a pool, read as :func:`ring_decode` reads
+    rings of keys): the keys are the values (one
     latent a position: all of it scored, its first ``rank`` summed), so
     a page crosses the memory once for both dots. The XLA form it
     replaced (``tests/reference_mla.py``) is the tests' reference.
@@ -465,18 +467,14 @@ def ring_decode(q, k_rings, v_rings, layer, slots, positions, *,
             f"{positions.shape}, a window of {window} in pages of {page}")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    per = ring // page
-    width = -(-(min(window, ring) + page - 1) // page)
-    at, n_vis = ring_reach(positions.astype(jnp.int32), window, ring)
-    skip = at % page
-    tables = slots[:, None] * per + (
-        at[:, None] // page + jnp.arange(width, dtype=jnp.int32)) % per
+    tables, skip, n_vis = _ring_tables(slots, positions, window, ring, page)
     return _decode(
-        q, tuple(r.reshape(n_layers, n_slots * per, page, *r.shape[3:])
+        q, tuple(r.reshape(n_layers, n_slots * (ring // page), page,
+                           *r.shape[3:])
                  for r in (k_rings, v_rings)),
         jnp.asarray(layer, jnp.int32), tables, skip + n_vis, skip,
         name="hvd_paged_decode", scale=Dh ** -0.5, rank=Dh,
-        pages=key_block(page, width) // page, interpret=interpret)
+        pages=key_block(page, tables.shape[1]) // page, interpret=interpret)
 
 
 def latent_decode(q, pool, layer, tables, lengths, *, rank: int,
@@ -505,6 +503,52 @@ def latent_decode(q, pool, layer, tables, lengths, *, rank: int,
                    lengths, name="hvd_latent_decode", scale=float(scale),
                    rank=rank, pages=key_block(page, tables.shape[1]) // page,
                    interpret=interpret)
+
+
+def latent_ring_decode(q, rings, layer, slots, positions, *, window: int,
+                       page: int, rank: int, scale: float,
+                       interpret: Optional[bool] = None):
+    """An ``mla_sliding`` layer's decode step, absorbed, each row over
+    its own slot's ring of latents where it lies: ``q`` ``[B, H, row]``
+    (:func:`latent_decode`'s) against ``rings`` ``[layers, n_slots, ring,
+    row]`` at ``layer``, row b at position ``positions[b]`` of the
+    sequence in slot ``slots[b]``, over the ``min(p + 1, window)``
+    positions up to its own. :func:`ring_decode`'s arithmetic tables and
+    ``skip`` over :func:`latent_decode`'s one pool: the ring read as
+    pages of ``page`` places from the page that holds the window's
+    first key, the places before it in that page copied and masked.
+    Returns ``[B, H, rank]`` in ``q``'s dtype (``hvd_latent_decode`` in
+    a device trace, under the caller's scope)."""
+    B, H, row = q.shape
+    n_layers, n_slots, ring = rings.shape[:3]
+    if (rings.ndim != 4 or rings.shape[3] != row or ring % page
+            or rank > row or slots.shape != (B,)
+            or positions.shape != (B,)):
+        raise ValueError(
+            f"latent_ring_decode: q {q.shape}, rings {rings.shape}, slots "
+            f"{slots.shape}, positions {positions.shape}, rank {rank}, a "
+            f"window of {window} in pages of {page}")
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    tables, skip, n_vis = _ring_tables(slots, positions, window, ring, page)
+    return _decode(
+        q, (rings.reshape(n_layers, n_slots * (ring // page), page, row),),
+        jnp.asarray(layer, jnp.int32), tables, skip + n_vis, skip,
+        name="hvd_latent_decode", scale=float(scale), rank=rank,
+        pages=key_block(page, tables.shape[1]) // page, interpret=interpret)
+
+
+def _ring_tables(slots, positions, window: int, ring: int, page: int):
+    """``(tables [B, W], skip [B], n_vis [B])`` of rows at ``positions``
+    in the rings of ``slots`` read as pages of ``page`` places
+    (:func:`ring_decode`'s docstring has the arithmetic)."""
+    per = ring // page
+    width = -(-(min(window, ring) + page - 1) // page)
+    at, n_vis = ring_reach(positions.astype(jnp.int32), window, ring)
+    skip = at % page
+    tables = slots[:, None] * per + (
+        at[:, None] // page + jnp.arange(width, dtype=jnp.int32)) % per
+    return tables, skip, n_vis
 
 
 @functools.partial(jax.jit, static_argnames=(

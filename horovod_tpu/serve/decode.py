@@ -128,7 +128,8 @@ from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
 from horovod_tpu.ops import state_step as state_step_kernel
-from horovod_tpu.ops.paged_decode import (latent_decode, paged_decode,
+from horovod_tpu.ops.paged_decode import (latent_decode,
+                                          latent_ring_decode, paged_decode,
                                           paged_decode_stats, ring_decode,
                                           ring_page)
 from horovod_tpu.parallel.ring_attention import local_attention
@@ -1169,20 +1170,24 @@ def sparse_attend_pages(q, kp, vp, c, table, pos, allowed):
     return jnp.moveaxis(o, 0, 1).reshape(1, C, H * Dh).astype(q.dtype)
 
 
-def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
+def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos, window=None):
     """Latent attention of queries ``qn`` [B, C, H, Dh] (no position)
     and ``qr`` [B, C, H, R] (rotated) at positions ``pos`` [B, C] over
     ``n_blocks`` (traced, at least 1) blocks of cached latents, a block
     of keys at a time, so that no more than one block of them is ever
     gathered or expanded. ``keys_of(j) -> (latent [B, K, C + R],
     key_pos [K])`` gives block j; a key is seen where ``key_pos <=
-    pos``. Float32 scores, softmax and accumulators over operands in the
+    pos`` and, with a ``window`` (an mla_sliding layer's), ``key_pos >
+    pos - window``. Float32 scores, softmax and accumulators over operands in the
     latents' dtype, ``p`` rounded to it for the value sum. Returns
     [B, C, H, Dh].
 
     The form is the **expanded** one (a chunk's queries: many a
-    sequence): a block's latents are expanded to every head's key
-    ``[c W_uk | r]`` and value ``c W_uv`` and attended by the Pallas
+    sequence): a block's latents are expanded to every KV head's key
+    ``[c W_uk | r]`` and value ``c W_uv`` (``n_kv_heads`` of them: as
+    many as query heads unless the heads are grouped, and then a KV
+    head's group of query heads reads it by the kernel's index map)
+    and attended by the Pallas
     flash forward over keys that carry their positions
     (``ops/flash_attention.py::flash_attention_keys``,
     ``hvd_flash_keys_fwd`` in a device trace): one contraction of
@@ -1198,6 +1203,7 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
     A query that sees no key (a position below every key's: none a
     program sends) reads zeros."""
     B, C, H, Dh = qn.shape
+    G = cfg.n_kv_heads
     rank, R = cfg.mla_kv_rank, cfg.mla_rope_dim
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
     scale = tf_lib.mla_scale(cfg)
@@ -1211,12 +1217,12 @@ def _mla_attend(cfg, lp, qn, qr, keys_of, n_blocks, pos):
             c, r = latent[..., :rank], latent[..., rank:rank + R]
             keys = jnp.concatenate(
                 [jnp.einsum("bkc,chd->bhkd", c, w_uk),
-                 jnp.broadcast_to(r[:, None], (B, H, K, R))], -1)
+                 jnp.broadcast_to(r[:, None], (B, G, K, R))], -1)
             vals = jnp.einsum("bkc,chd->bhkd", c, w_uv)
         return flash_attention_keys(
-            q, keys.reshape(B * H, K, Dh + R), vals.reshape(B * H, K, Dh),
+            q, keys.reshape(B * G, K, Dh + R), vals.reshape(B * G, K, Dh),
             pos, jnp.broadcast_to(key_pos[None], (B, K)), scale=scale,
-            carry=seen)
+            window=window, carry=seen)
 
     o, _ = lax.fori_loop(
         0, n_blocks, attend,
@@ -1251,23 +1257,60 @@ def mla_pages(pool, c, tables, key_block: int):
                                              key_blocks)
 
 
-def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions):
+def _absorbed(cfg, lp, h, qn, qr, row, read):
     """A decode step's latent attention, absorbed, for one query a row
-    (``qn`` [B, 1, H, Dh], ``qr`` [B, 1, H, R]) at ``positions`` [B],
-    over the pages of layer ``c`` of the latent ``pool`` behind
-    ``tables`` [B, W], read where they lie by
-    ``ops/paged_decode.py::latent_decode`` (``hvd_latent_decode`` in a
-    device trace): each row's own pages, once, no further than its
-    position, with no gathered copy of a key block and no score tensor
-    in HBM. The two small products stay in
-    XLA around the call: ``q W_uk^T`` before it and ``(sum p c) W_uv``
-    after it. Returns [B, 1, H, Dh]."""
+    (``qn`` [B, 1, H, Dh], ``qr`` [B, 1, H, R]): ``read(q [B, H, row])
+    -> [B, H, rank]`` attends each row's own latents where they lie
+    (``hvd_latent_decode`` in a device trace), ``row`` values wide. The
+    small products stay in XLA around the call: ``q W_uk^T`` before it
+    (a query head by its KV head's ``W_uk``) and ``(sum p c) W_uv``
+    after it, and between the two the differential heads' subtraction,
+    IN THE LATENT (``tf_lib.gdla_diff``; ``h`` the layer's normed input:
+    linear, so ``W_uv`` is applied to the signal heads alone). Returns
+    [B, 1, Hs, Dh], the signal heads (all ``H`` without noise heads)."""
     w_uk, w_uv = tf_lib.mla_up(cfg, lp)
-    q = jnp.concatenate([jnp.einsum("bqhd,chd->bqhc", qn, w_uk), qr], -1)
-    q = jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
-    o = latent_decode(q, pool, c, tables, positions + 1,
-                      rank=cfg.mla_kv_rank, scale=tf_lib.mla_scale(cfg))
-    return jnp.einsum("bqhc,chd->bqhd", o[:, None], w_uv)
+    B, _, H, Dh = qn.shape
+    G = cfg.n_kv_heads
+    # (ungrouped heads keep the two products they had, operation for
+    # operation: tools/serve_programs_digest.py holds Kimi's and Ling's)
+    if G == H:
+        q = jnp.einsum("bqhd,chd->bqhc", qn, w_uk)
+    else:
+        q = jnp.einsum("bqgsd,cgd->bqgsc", qn.reshape(B, 1, G, H // G, Dh),
+                       w_uk).reshape(B, 1, H, -1)
+    q = jnp.concatenate([q, qr], -1)
+    q = jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, row - q.shape[-1])))
+    o = tf_lib.gdla_diff(cfg, lp, h, read(q)[:, None])
+    if G == H:
+        return jnp.einsum("bqhc,chd->bqhd", o, w_uv)
+    signal = o.shape[2] // G
+    return jnp.einsum("bqgsc,cgd->bqgsd",
+                      o.reshape(B, 1, G, signal, -1), w_uv
+                      ).reshape(B, 1, G * signal, Dh)
+
+
+def _mla_decode(cfg, lp, qn, qr, pool, c, tables, positions, h=None):
+    """A decode step's latent attention (:func:`_absorbed`) at
+    ``positions`` [B], over the pages of layer ``c`` of the latent
+    ``pool`` behind ``tables`` [B, W], read where they lie by
+    ``ops/paged_decode.py::latent_decode``: each row's own pages, once,
+    no further than its position, with no gathered copy of a key block
+    and no score tensor in HBM. ``h``: the layer's normed input, where
+    the configuration has noise heads."""
+    return _absorbed(
+        cfg, lp, h, qn, qr, pool.shape[-1], lambda q: latent_decode(
+            q, pool, c, tables, positions + 1, rank=cfg.mla_kv_rank,
+            scale=tf_lib.mla_scale(cfg)))
+
+
+def _mla_ring_decode(cfg, lp, qn, qr, rings, c, slots, positions, page, h):
+    """:func:`_mla_decode` of an mla_sliding layer: each row over its
+    own slot's ring of latents, from the first key its window admits to
+    its own (``ops/paged_decode.py::latent_ring_decode``)."""
+    return _absorbed(
+        cfg, lp, h, qn, qr, rings.shape[-1], lambda q: latent_ring_decode(
+            q, rings, c, slots, positions, window=cfg.attn_window,
+            page=page, rank=cfg.mla_kv_rank, scale=tf_lib.mla_scale(cfg)))
 
 
 def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
@@ -1277,9 +1320,11 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
     is :func:`moe_share_report`'s). The caches ``kc`` and ``vc`` are
     tuples with one array a kind of layer, in
     ``kv_cache.state_kinds(cfg)``'s order (``KVCache`` says which array
-    is what) for the ten kinds of layer: pages behind the block tables
-    for ``full``, ``mla`` and ``sparse`` layers, and rings, recurrent
-    states and convolution rows for ``sliding``, ``kda``, ``mamba``,
+    is what) for the eleven kinds of layer: pages behind the block tables
+    for ``full``, ``mla`` and ``sparse`` layers, and rings (of keys and
+    values, or of latents), recurrent
+    states and convolution rows for ``sliding``, ``mla_sliding``, ``kda``,
+    ``mamba``,
     ``lightning``, ``conv`` and ``mamba2`` layers, one a batch slot (slot 0 is the
     null slot, as block 0 is the null block); an ``eva`` layer alone has
     BOTH halves, its open window's K and V rows by slot and its chunk
@@ -1318,9 +1363,12 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         kind = cfg.kind_of(i)
         plan.append((stack, j, kind, seen[kind]))
         seen[kind] += 1
-    n_win = seen.get("sliding", 0)
+    n_win = seen.get("sliding", 0) + seen.get("mla_sliding", 0)
     S = table_width * block_size
-    latent = latent_row(cfg) if "mla" in place else 0
+    latent = (latent_row(cfg) if set(tf_lib.MLA_KINDS) & set(place) else 0)
+    # the latent kinds' scope: two names where a stack has both
+    mla_scope = ({"mla": "attn_mla_full", "mla_sliding": "attn_mla_window"}
+                 if "mla_sliding" in place else {"mla": "attn_mla"})
     # summaries a page of an eva layer holds
     per_eva = block_size // cfg.eva_chunk if "eva" in place else 0
     # an mla chunk's key blocks: whole pages, the table padded to them
@@ -1335,6 +1383,10 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
             if cfg.embed_multiplier is not None:
                 x = x * jnp.asarray(cfg.embed_multiplier, cfg.dtype)
+            if cfg.mhc_streams > 1:
+                # mHC: the embedding copied to every stream
+                x = jnp.broadcast_to(
+                    x[:, :, None], x.shape[:2] + (cfg.mhc_streams, x.shape[2]))
             # a float32 stream starts here (the norms give cfg.dtype back)
             return x.astype(jnp.float32) if cfg.stream_fp32 else x
 
@@ -1372,6 +1424,13 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         return cache[:n] + (array,) + cache[n + 1:]
 
     def emit(params, x, rows):
+        if cfg.mhc_streams > 1:
+            # mHC: the emitted rows' streams summed, before the norm
+            with jax.named_scope("mhc_mix"):
+                x = rows(x).astype(jnp.float32).sum(-2).astype(cfg.dtype)
+
+            def rows(x):
+                return x
         with jax.named_scope("head"):
             x = rows(tf_lib.stream_norm(cfg, x, params["final_norm"]))
             if cfg.logit_divisor is not None:
@@ -1476,29 +1535,52 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                     vc = put(vc, "kda", (c, call.slot), newest)
         return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o)
 
-    def mla_chunk(call, lp, kc, vc, c, x, i):
-        """Expanded attention: over the chunk itself where it is the
-        whole prompt, else over the sequence's pages."""
-        n = place["mla"]
-        with jax.named_scope("attn_mla"):
-            h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, x, call.pos)
-            new = jnp.pad(new, ((0, 0), (0, 0), (0, latent - new.shape[-1])))
-            if kc is not None:
-                with jax.named_scope("kv_write"):
-                    kc = put(kc, "mla", (c, call.blks),
-                             new[0].reshape(-1, block_size, latent))
-            with jax.named_scope("mla_attend"):
-                if call.local:
-                    # every row's keys are its own prompt's, one block
-                    o = _mla_attend(
-                        cfg, lp, qn, qr, lambda j: (new, call.pos[0]), 1,
-                        call.pos)
-                else:
-                    keys_of, blocks_to = mla_pages(
-                        kc[n], c, call.table[None], chunk_key_block)
-                    o = _mla_attend(cfg, lp, qn, qr, keys_of,
-                                    blocks_to(call.pos[0, -1]), call.pos)
-        return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
+    def latent_chunk(kind):
+        """An mla layer's chunk, or (``kind`` "mla_sliding") one whose
+        latents go into the slot's ring and whose queries see
+        ``window`` keys back."""
+        rings = kind == "mla_sliding"
+        seen = {"window": window} if rings else {}
+
+        def chunk(call, lp, kc, vc, c, x, i):
+            """Expanded attention: over the chunk itself where it is
+            the whole prompt, else over the sequence's pages (the
+            slot's ring, whole: 1168 places at Motif's sizes)."""
+            n = place[kind]
+            with jax.named_scope(mla_scope[kind]):
+                u, mix = tf_lib.stream_in(cfg, lp, "attn", x)
+                h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, u, call.pos)
+                new = jnp.pad(new, ((0, 0), (0, 0),
+                                    (0, latent - new.shape[-1])))
+                if kc is not None:
+                    with jax.named_scope("kv_write"):
+                        if rings:
+                            kc = put(kc, kind,
+                                     (c, call.slot, call.pos[0] % ring),
+                                     new[0])
+                        else:
+                            kc = put(kc, kind, (c, call.blks),
+                                     new[0].reshape(-1, block_size, latent))
+                with jax.named_scope("mla_attend"):
+                    if call.local:
+                        # every row's keys are its own prompt's, one block
+                        o = _mla_attend(
+                            cfg, lp, qn, qr, lambda j: (new, call.pos[0]), 1,
+                            call.pos, **seen)
+                    elif rings:
+                        with jax.named_scope("kv_gather"):
+                            held = kc[n][c, call.slot][None]
+                        o = _mla_attend(
+                            cfg, lp, qn, qr, lambda j: (held, call.held[0]),
+                            1, call.pos, **seen)
+                    else:
+                        keys_of, blocks_to = mla_pages(
+                            kc[n], c, call.table[None], chunk_key_block)
+                        o = _mla_attend(cfg, lp, qn, qr, keys_of,
+                                        blocks_to(call.pos[0, -1]), call.pos)
+                    o = tf_lib.gdla_diff(cfg, lp, h, o)
+            return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o, mix)
+        return chunk
 
     def mamba_chunk(call, lp, kc, vc, c, x, i):
         """The chunk's selective scan from the state and the
@@ -1907,20 +1989,36 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                          jnp.concatenate([before, rows], 1)[:, 1:])
         return kc, vc, tf_lib.kda_residual(cfg, lp, x, h, o[:, None])
 
-    def mla_step(call, lp, kc, vc, c, x, i):
-        """Absorbed attention over each row's own pages, where they lie
-        in the pool (:func:`_mla_decode`)."""
-        with jax.named_scope("attn_mla"):
-            h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, x, call.pos)
-            new = jnp.pad(new, ((0, 0), (0, 0), (0, latent - new.shape[-1])))
-            with jax.named_scope("kv_write"):
-                kc = put(kc, "mla",
-                         (c, call.blk, call.positions % block_size),
-                         new[:, 0])
-            with jax.named_scope("mla_attend"):
-                o = _mla_decode(cfg, lp, qn, qr, kc[place["mla"]], c,
-                                call.tables, call.positions)
-        return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o)
+    def latent_step(kind):
+        """An mla layer's step, or (``kind`` "mla_sliding") one over
+        the slots' rings of latents."""
+        rings = kind == "mla_sliding"
+
+        def step(call, lp, kc, vc, c, x, i):
+            """Absorbed attention over each row's own pages, or its
+            own slot's ring from the first key its window admits, where
+            they lie (:func:`_mla_decode`)."""
+            n = place[kind]
+            with jax.named_scope(mla_scope[kind]):
+                u, mix = tf_lib.stream_in(cfg, lp, "attn", x)
+                h, qn, qr, new = tf_lib.mla_inputs(cfg, lp, u, call.pos)
+                new = jnp.pad(new, ((0, 0), (0, 0),
+                                    (0, latent - new.shape[-1])))
+                with jax.named_scope("kv_write"):
+                    kc = put(kc, kind,
+                             (c, call.slots, call.positions % ring) if rings
+                             else (c, call.blk, call.positions % block_size),
+                             new[:, 0])
+                with jax.named_scope("mla_attend"):
+                    if rings:
+                        o = _mla_ring_decode(
+                            cfg, lp, qn, qr, kc[n], c, call.slots,
+                            call.positions, ring_page(ring, block_size), h)
+                    else:
+                        o = _mla_decode(cfg, lp, qn, qr, kc[n], c,
+                                        call.tables, call.positions, h)
+            return kc, vc, tf_lib.mla_residual(cfg, lp, x, h, o, mix)
+        return step
 
     def mamba_step_layer(call, lp, kc, vc, c, x, i):
         """One step of the selective scan on each row's own state where
@@ -2198,7 +2296,7 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         "sliding": {"chunk": window_chunk, "step": window_step},
         "full": {"chunk": full_chunk, "step": full_step},
         "kda": {"chunk": kda_chunk, "step": kda_step_layer},
-        "mla": {"chunk": mla_chunk, "step": mla_step},
+        "mla": {"chunk": latent_chunk("mla"), "step": latent_step("mla")},
         "mamba": {"chunk": mamba_chunk, "step": mamba_step_layer},
         "sparse": {"chunk": sparse_chunk, "step": sparse_step},
         "lightning": {"chunk": lightning_chunk,
@@ -2206,6 +2304,8 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
         "conv": {"chunk": conv_chunk, "step": conv_step_layer},
         "eva": {"chunk": eva_chunk, "step": eva_step},
         "mamba2": {"chunk": mamba2_chunk, "step": mamba2_step_layer},
+        "mla_sliding": {"chunk": latent_chunk("mla_sliding"),
+                        "step": latent_step("mla_sliding")},
     }
 
     def chunk_program(params, kc, vc, tokens, offset, length, address,
@@ -2304,7 +2404,8 @@ def _mixed_serve_fns(cfg, block_size: int, table_width: int, ring: int,
             raise NotImplementedError(
                 f"{what} is not built for a configuration with layers of "
                 "several kinds or a chip's share of the experts: a window "
-                "layer's ring and a kda or mamba layer's recurrent state "
+                "layer's ring (of keys, or an mla_sliding layer's of "
+                "latents) and a kda or mamba layer's recurrent state "
                 "(a lightning or mamba2 layer's too, a conv layer's rows "
                 "and an eva layer's open window) are not "
                 "pages another engine or "

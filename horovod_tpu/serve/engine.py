@@ -97,7 +97,7 @@ import numpy as np
 from horovod_tpu.ops.paged_decode import key_block, ring_page
 from horovod_tpu.serve import decode as decode_lib
 from horovod_tpu.serve.kv_cache import (
-    NULL_SLOT, SLOT_KINDS, BlockAllocator, hash_chain,
+    NULL_SLOT, RING_KINDS, SLOT_KINDS, BlockAllocator, hash_chain,
     init_kv_cache, pick_bucket, ring_width,
 )
 from horovod_tpu.serve.metrics import ServeMetrics
@@ -462,7 +462,8 @@ class ServeEngine:
             refused = [what for what, there in (
                 (f"prefix_caching (its {' and '.join(by_slot)} layers keep "
                  "a ring or a recurrent state a batch slot: a page behind "
-                 "a window, a kda, mamba, mamba2 or lightning layer's "
+                 "a window (of keys, or an mla_sliding layer's ring of "
+                 "latents), a kda, mamba, mamba2 or lightning layer's "
                  "state, a "
                  "conv layer's rows and an eva layer's open window "
                  "after a prefix, cannot be mapped into another sequence: "
@@ -521,12 +522,15 @@ class ServeEngine:
         # A window layer keeps a ring of this many positions for each
         # batch slot, whatever the sequence's length: the full layers
         # alone draw on the allocator.
+        self._latent_ring_layers = model_cfg.n_layers_of("mla_sliding")
         ring = (ring_width(model_cfg.attn_window,
                            cfg.prefill_chunk or max(self._prefill_buckets),
                            bs)
-                if model_cfg.n_window_layers else 0)
+                if model_cfg.n_window_layers or self._latent_ring_layers
+                else 0)
         # ... of which a decode call reads pages, counted as the full
-        # layers' are (metrics.record_window_decode).
+        # layers' are (metrics.record_window_decode; an mla_sliding
+        # layer's ring of latents: record_latent_ring_decode).
         self._window_layers = model_cfg.n_window_layers
         self._ring_page = ring_page(ring, bs) if ring else 0
         self._free_slots = list(range(cfg.max_batch, 0, -1))
@@ -740,7 +744,7 @@ class ServeEngine:
         if not (self._prefilling or self._active):
             m.record_idle()
         # (the rings have their own gauge)
-        recurrent = (set(SLOT_KINDS) - {"sliding"}) & set(self.cache.kinds)
+        recurrent = (set(SLOT_KINDS) - set(RING_KINDS)) & set(self.cache.kinds)
         if self.cache.ring or recurrent:
             with m.phase("serve:gauges"):
                 in_use = self.cfg.max_batch - len(self._free_slots)
@@ -1024,6 +1028,12 @@ class ServeEngine:
             m.record_sparse(self.model_cfg, offset + chunk,
                             prefill=extra["selected"],
                             kernel=extra["select_kernel"])
+        if self._latent_ring_layers:
+            # places of the slot's ring of latents the chunk's
+            # attention expands, a layer: its own keys where it is the
+            # whole prompt, else the whole ring
+            extra["latent_ring_places"] = (self.cache.ring if offset
+                                           else len(toks))
         if self._eva_window:
             # the chunk ends a window: from here on its summaries are
             # attended and its rows are dead
@@ -1083,8 +1093,11 @@ class ServeEngine:
     def _record_window_positions(self, written: int) -> None:
         """``written`` positions of one sequence have gone into every
         window layer's ring, which keeps the newest ``ring`` of them."""
-        if self.cache.ring:
+        if self._window_layers:
             self.metrics.record_window_positions(
+                min(written, self.cache.ring))
+        if self._latent_ring_layers:
+            self.metrics.record_latent_ring_positions(
                 min(written, self.cache.ring))
 
     def _kinds_by_slot(self) -> List[str]:
@@ -1099,7 +1112,8 @@ class ServeEngine:
             raise NotImplementedError(
                 f"{what} moves a sequence's pages between engines; a "
                 f"configuration with {held} layers keeps a window layer's "
-                "keys in per-slot rings, a kda, mamba, mamba2 or lightning "
+                "keys (an mla_sliding layer's latents) in per-slot rings, "
+                "a kda, mamba, mamba2 or lightning "
                 "layer's recurrent state, a conv layer's rows and an eva "
                 "layer's open window's rows by slot, "
                 "which are not pages (nor "
@@ -1479,6 +1493,14 @@ class ServeEngine:
                 # positions the batch's rows attend in the full layers
                 extra["slots_stepped"] = len(positions)
                 extra["attended"] = int(positions.sum()) + n
+            if self._latent_ring_layers:
+                # what the real rows' absorbed attention has to read, a
+                # layer: the ring places inside each row's window, and
+                # the positions in the latent pages of the mla layers
+                at = positions[[seq is not None for seq in rows]]
+                extra["latent_ring_places"] = int(np.minimum(
+                    at + 1, self.model_cfg.attn_window).sum())
+                extra["latent_positions"] = int(at.sum()) + n
             if self._eva_window:
                 # what the eva layers' attention of this call has to
                 # read, a layer: the open windows' rows up to each real
@@ -1528,6 +1550,10 @@ class ServeEngine:
                 positions + 1, self.model_cfg.attn_window, self.cache.ring,
                 self._ring_page, self.cfg.max_batch + 1,
                 self._window_layers)
+        if self._latent_ring_layers:
+            m.record_latent_ring_decode(
+                positions + 1, self.model_cfg.attn_window, self.cache.ring,
+                self._ring_page, self._latent_ring_layers)
         if prev is not None:
             m.record_decode_ahead()
         elif out.committed:

@@ -293,7 +293,7 @@ class BlockAllocator:
 #: and in this order: the pair the window configurations' programs and
 #: their benchmark read by place).
 STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
-               "lightning", "conv", "eva", "mamba2")
+               "lightning", "conv", "eva", "mamba2", "mla_sliding")
 #: Those of them whose arrays lie by batch slot and not behind the block
 #: tables: nothing of theirs is a page that another sequence, another
 #: engine or a draft could be handed (what ``engine.py`` refuses over
@@ -314,8 +314,14 @@ STATE_KINDS = ("full", "sliding", "kda", "mla", "mamba", "sparse",
 #: ``inject`` move pages alone) and not in ``RECURRENT_KINDS``: the rows
 #: are the window's own keys and values in the cache's dtype, nothing
 #: is folded over positions and a closed window's rows are dead.
+#: ``mla_sliding`` joins ``SLOT_KINDS`` as ``sliding`` does: its ring
+#: of latents lies by slot, so everything refused over a ring of keys
+#: is refused over it. ``RING_KINDS``: the two whose slot holds a ring
+#: of the newest positions (``KVCache.ring`` places; the engine's ring
+#: gauges count them, ``state_slots_in_use`` / ``state_bytes`` do not).
 RECURRENT_KINDS = ("kda", "mamba", "lightning", "mamba2")
-SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv", "eva")
+RING_KINDS = ("sliding", "mla_sliding")
+SLOT_KINDS = ("sliding",) + RECURRENT_KINDS + ("conv", "eva", "mla_sliding")
 
 
 def state_kinds(cfg) -> Tuple[str, ...]:
@@ -417,6 +423,11 @@ class KVCache:
       n_slots + 1, (mamba_d_conv - 1) * (Di + 2 G N)]``. A layer that
       ``layer_types`` names ``"ffn"`` (``one_branch``) keeps nothing and
       has no array.
+    * ``mla_sliding``: rings of latents ``[n, n_slots + 1, ring,
+      latent_row(cfg)]``, one ring a batch slot as a ``sliding`` layer's
+      (position p at ``p % ring``), each place an ``mla`` layer's row,
+      and no second array: 1280 B a place at Motif's 576 -> 640 bf16,
+      where K and V rings of 16 heads of 192 and 128 would be 10 KB.
 
     Pages are the allocator's; rings and states are addressed by batch
     slot, slot 0 the null slot, and take nothing from the allocator
@@ -464,7 +475,8 @@ class KVCache:
 
 
 def latent_row(cfg) -> int:
-    """Values a position takes in the mla layers' pool: the ``C + R``
+    """Values a position takes in the mla layers' pool (and in an
+    mla_sliding layer's ring): the ``C + R``
     the layer caches, up to whole lanes of 128 (576 -> 640, the last 64
     zeros). Rows of 576 are what the chip's (8, 128) tiles pad to 640
     wherever a row is the innermost dimension; an array
@@ -578,6 +590,8 @@ def init_kv_cache(cfg, n_blocks: int, block_size: int,
                        ((n["mamba2"], n_slots + 1,
                          (cfg.mamba_d_conv - 1) * cfg.mamba2_conv_width),
                         dtype)),
+            "mla_sliding": (((n["mla_sliding"], n_slots + 1, ring,
+                              latent_row(cfg)), dtype), None),
         }
         kinds = state_kinds(cfg)
 

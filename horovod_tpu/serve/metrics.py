@@ -436,6 +436,11 @@ class ServeMetrics:
         self.state_bytes = 0
         self.kv_latent_positions_live = 0
         self.kv_latent_positions_max = 0
+        # ... and the most places an mla_sliding layer's ring of latents
+        # held of one sequence, with the pages of such rings the decode
+        # calls read (ops/paged_decode.py::latent_ring_decode).
+        self.kv_latent_ring_positions_max = 0
+        self.latent_ring_decode_pages_total = 0
         # Sparse layers (a selection inside paged attention): the
         # queries of the prefill calls and the rows of the decode calls
         # that chose their blocks (at or past sparse_dense_len), those
@@ -717,6 +722,23 @@ class ServeMetrics:
         sequence."""
         self.kv_window_positions_max = max(self.kv_window_positions_max,
                                            held)
+
+    def record_latent_ring_positions(self, held: int) -> None:
+        """An mla_sliding layer's ring of latents now holds ``held``
+        positions of one sequence."""
+        self.kv_latent_ring_positions_max = max(
+            self.kv_latent_ring_positions_max, held)
+
+    def record_latent_ring_decode(self, lengths, window: int, ring: int,
+                                  page: int, layers: int) -> None:
+        """A decode call was launched whose rows hold ``lengths``
+        positions (an array, a padded row at 1), each seeing its newest
+        ``window`` in a ring of ``ring`` latents read as pages of
+        ``page``, in each of ``layers`` mla_sliding layers
+        (:meth:`record_window_decode`'s count, of the one pool)."""
+        at, seen = ring_reach(lengths - 1, window, ring)
+        self.latent_ring_decode_pages_total += layers * int(
+            (-(-(at % page + seen) // page)).sum())
 
     def record_latent_positions(self, held) -> None:
         """The latent pool holds ``held`` positions for each row of a
@@ -1015,6 +1037,9 @@ class ServeMetrics:
             "state_bytes": self.state_bytes,
             "kv_latent_positions_live": self.kv_latent_positions_live,
             "kv_latent_positions_max": self.kv_latent_positions_max,
+            "kv_latent_ring_positions_max": self.kv_latent_ring_positions_max,
+            "latent_ring_decode_pages_total":
+                self.latent_ring_decode_pages_total,
             # sparse layers (zeros without such layers)
             "sparse_selected_queries_total":
                 self.sparse_selected_queries_total,

@@ -398,7 +398,7 @@ def test_what_is_not_built_over_rows_and_summaries_is_refused_by_name(
 @pytest.mark.parametrize("kw,match", [
     (dict(eva_window=30), "eva_window in whole eva_chunk"),
     (dict(attn_gate=True), "no attn_gate"),
-    (dict(layer_types=("eva", "eva", "window")), "of the 10 kinds"),
+    (dict(layer_types=("eva", "eva", "window")), "of the 11 kinds"),
     (dict(layer_types=None, n_layers=2), "norm_unit_offset, stream_fp32 "
                                          "and head_rows are read by"),
 ])
@@ -421,7 +421,7 @@ def test_a_page_is_whole_chunks_of_a_window_and_a_bucket_fits_one():
 def test_the_kind_has_both_halves_and_is_no_recurrence():
     cfg = tiny(("eva", "full", "eva"))
     assert "eva" in STATE_KINDS and "eva" in SLOT_KINDS
-    assert "eva" not in RECURRENT_KINDS and len(tf_lib.LAYER_KINDS) == 10
+    assert "eva" not in RECURRENT_KINDS and len(tf_lib.LAYER_KINDS) == 11
     cache = init_kv_cache(cfg, 9, BS, n_slots=2)
     kr, ks, vr, vs = cache.of("eva")
     assert kr.shape == vr.shape == (2, 3, W, 4, 16)

@@ -440,7 +440,7 @@ def test_a_configuration_admits_one_branch_layers():
     assert cache.of("full")[0].shape == (1, 9, BS, 2, 16)
     assert cache.slot_bytes == 2 * (8 * 16 * 16 * 4 + 3 * 192 * 4)
     assert "mamba2" in RECURRENT_KINDS and "mamba2" in SLOT_KINDS
-    assert STATE_KINDS[-1] == "mamba2" and "ffn" not in STATE_KINDS
+    assert STATE_KINDS[-2] == "mamba2" and "ffn" not in STATE_KINDS
 
 
 @pytest.mark.parametrize("kw,match", [

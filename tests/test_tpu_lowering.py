@@ -1960,3 +1960,135 @@ def test_the_one_branch_programs_lower_for_the_v5e(program):
         "moe_router", "moe_latent_down", "moe_dispatch", "moe_experts",
         "moe_combine", "moe_latent_up", "moe_shared"}, got
     assert got["temp_bytes"] < out["state_bytes"], got
+
+
+# The serve programs of Motif-3's layers (ISSUE 63): grouped differential
+# latent attention in a window layer over RINGS of latents and a full
+# layer over latent pages, the four-stream mHC residual and PolyNorm, a
+# dense feed-forward and a share of 48 experts, at the published widths
+# and few pages.
+_MOTIF_DRIVER = r"""
+import collections, json, re, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+jax.default_backend = lambda: "tpu"
+
+from horovod_tpu.models import TransformerConfig, init_transformer
+from horovod_tpu.serve import decode as decode_lib
+from horovod_tpu.serve.kv_cache import init_kv_cache, ring_width
+
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+one = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+cfg = TransformerConfig(
+    vocab_size=27520, d_model=4096, n_layers=3, n_heads=80, n_kv_heads=16,
+    d_head=128, d_ff=1280, d_ff_dense=12288, n_dense_layers=1, max_seq=5120,
+    norm_eps=1e-5, layer_types=("mla_sliding", "mla", "mla_sliding"),
+    attn_window=128, layer_rotary={{"mla": {{"theta": 10000.0}}}},
+    mla_kv_rank=512, mla_rope_dim=64, mla_q_rank=1024, mla_head_gate=False,
+    mla_noise_heads=16, mla_elementwise_gate=True, mhc_streams=4,
+    mhc_sinkhorn_iters=20, ffn_activation="polynorm",
+    moe_activation="polynorm", polynorm_scale=0.5, n_experts=384,
+    moe_top_k=8, moe_capacity_factor=None, moe_scoring="sigmoid",
+    moe_route_scale=2.0, moe_shared_expert=True, moe_experts_held=48,
+    moe_expert_offset=48, dtype=jnp.bfloat16, remat=False)
+BS, WIDTH, SLOTS, CHUNK = 16, 320, 64, 1024
+RING = ring_width(cfg.attn_window, CHUNK, BS)
+
+
+def on_chip(tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), tree)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+
+params = on_chip(jax.eval_shape(
+    lambda: init_transformer(cfg, jax.random.PRNGKey(0))))
+kc, vc = on_chip(jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
+    cfg, SLOTS * WIDTH + 1, BS, n_slots=SLOTS, ring=RING))))
+_, resume, decode, _, _ = decode_lib.make_serve_fns(
+    cfg, None, block_size=BS, table_width=WIDTH, ring=RING)
+pool = "bf16[%s]" % ",".join(map(str, kc[0].shape))
+rings = "bf16[%s]" % ",".join(map(str, kc[1].shape))
+GROUPED_PRODUCTS_REPORT
+out = {{"device_kind": topo.devices[0].device_kind, "pool": pool,
+       "rings": rings, "ring": RING}}
+for name, fn, args in (
+        ("decode", decode,
+         (i32(SLOTS), i32(SLOTS), (i32(SLOTS, WIDTH), i32(SLOTS)))),
+        ("prefill_resume", resume,
+         (i32(CHUNK), i32(), i32(), (i32(WIDTH), i32())))):
+    compiled = fn.lower(params, kc, vc, *args).compile()
+    text = compiled.as_text()
+    results = re.findall(r"= (\S+?)\{{\S* ([\w\-]+)\(", text)
+    aliased = re.search(r"input_output_alias=\{{(.*?)\}}, entry", text)
+    names = re.findall(r'op_name="jit\(\w+\)/([^"]*)"', text)
+    out[name] = {{
+        "copies": sum(result in (pool, rings) and opcode in (
+            "copy", "transpose") for result, opcode in results),
+        "aliased": len(re.findall(r"may-alias|must-alias",
+                                  aliased.group(1))),
+        **grouped_products(text),
+        "latent_decode": sorted(re.findall(
+            r"custom-call\([^\n]*/(attn_mla_\w+)/mla_attend/[^\n]*"
+            r"hvd_latent_decode/pallas_call", text)),
+        "flash_keys": sorted(re.findall(
+            r"custom-call\([^\n]*/(attn_mla_\w+)/mla_attend/[^\n]*"
+            r"hvd_flash_keys_fwd", text)),
+        "scopes": sorted({{part for n in names for part in n.split("/")
+                           if part in ("attn_mla_window", "attn_mla_full",
+                                       "mla_attend", "mla_expand",
+                                       "kv_gather", "kv_write", "gdla_diff",
+                                       "mhc_mix", "polynorm", "attn_gate",
+                                       "moe_router", "moe_dispatch",
+                                       "moe_experts", "moe_combine",
+                                       "moe_shared")}}),
+        "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}}
+print("LOWERED " + json.dumps(out))
+""".replace("GROUPED_PRODUCTS_REPORT", _GROUPED_PRODUCTS_REPORT)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_resume"])
+def test_the_gdla_programs_lower_for_the_v5e(program):
+    """ISSUE 63: a decode step at 64 slots and a chunk of 1024 of a
+    dense window layer, a sparse full layer and a sparse window layer at
+    Motif-3-Beta's widths compile for the v5e with the latent pages and
+    the rings of latents (65 slots x 1168 places x 640) aliased in and
+    out and never copied or turned whole; a step's attention is ONE
+    ``hvd_latent_decode`` a layer, under its kind's scope (rings and
+    pages, the same kernel body), a chunk's one ``hvd_flash_keys_fwd`` a
+    layer; the share's PolyNorm experts are THREE ``hvd_grouped_matmul``
+    a sparse layer with no ``ragged-dot`` beside them; every scope the
+    benchmark reads by name is in the program; and a call's temporaries
+    stay under half a gigabyte."""
+    out = _compile_for_v5e(_MOTIF_DRIVER)
+    got = out[program]
+    assert out["ring"] == 1168, out
+    assert out["pool"] == "bf16[1,20481,16,640]", out
+    assert out["rings"] == "bf16[2,65,1168,640]", out
+    assert got["aliased"] == 2, got
+    assert got["copies"] == 0, got
+    assert got["ragged_dots"] == 0, got
+    assert got["grouped_kernels"] == 3 * 2, got
+    assert got["kernel_products_share"] == 1.0, got
+    if program == "decode":
+        assert got["latent_decode"] == [
+            "attn_mla_full", "attn_mla_window", "attn_mla_window"], got
+        assert got["flash_keys"] == [], got
+    else:
+        assert got["latent_decode"] == [], got
+        assert got["flash_keys"] == [
+            "attn_mla_full", "attn_mla_window", "attn_mla_window"], got
+    assert set(got["scopes"]) >= {
+        "attn_mla_window", "attn_mla_full", "mla_attend", "kv_write",
+        "gdla_diff", "mhc_mix", "polynorm", "attn_gate", "moe_router",
+        "moe_dispatch", "moe_experts", "moe_combine", "moe_shared"} | (
+            set() if program == "decode" else {"mla_expand", "kv_gather"}
+        ), got
+    assert got["temp_bytes"] < 0.5e9, got

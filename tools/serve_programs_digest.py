@@ -50,7 +50,11 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          # (its decode and ling's changed again with PR 62:
          # hvd_state_step, a step's mamba2 and kda layers; no chunk
          # program and no other cell's decode did)
-         ("nemotron-3-super-120b-ep4-11l", "agent-backlog")]
+         ("nemotron-3-super-120b-ep4-11l", "agent-backlog"),
+         # the first configuration with a ring of latents, grouped and
+         # differential latent heads, an mHC stream and PolyNorm (PR 63):
+         # a tree before it does not build the configuration and says so
+         ("motif-3-beta-ep8-5l", "mixed-longtail-backlog")]
 
 
 def i32(*shape):
@@ -58,14 +62,19 @@ def i32(*shape):
 
 
 def digests(config: str, traffic: str):
-    cfg = harness.model_config(harness.load_json("configs", config + ".json"))
+    try:
+        cfg = harness.model_config(
+            harness.load_json("configs", config + ".json"))
+    except (TypeError, ValueError, FileNotFoundError) as e:
+        yield f"{config}", f"not built by this tree ({type(e).__name__})"
+        return
     scfg = serve_common.serve_config(
         harness.load_json("traffic", traffic + ".json"))
     bs = scfg.block_size
     width = -(-(-(-scfg.max_prompt // bs) * bs + scfg.max_new_tokens) // bs)
     ring = (ring_width(cfg.attn_window,
                        scfg.prefill_chunk or max(scfg.prefill_buckets), bs)
-            if cfg.n_window_layers else 0)
+            if cfg.n_window_layers or cfg.n_layers_of("mla_sliding") else 0)
     params = jax.eval_shape(
         lambda: init_transformer(cfg, jax.random.PRNGKey(0)))
     kc, vc = jax.eval_shape(lambda: (lambda c: (c.k, c.v))(init_kv_cache(
