@@ -127,6 +127,7 @@ from horovod_tpu.ops.flash_attention import (flash_attention,
 from horovod_tpu.ops import mamba_scan as mamba_scan_kernel
 from horovod_tpu.ops import mamba_step as mamba_step_kernel
 from horovod_tpu.ops import sparse_scores as sparse_scores_kernel
+from horovod_tpu.ops import ssd_scan as ssd_scan_kernel
 from horovod_tpu.ops import state_step as state_step_kernel
 from horovod_tpu.ops.paged_decode import (latent_decode,
                                           latent_ring_decode, paged_decode,
@@ -807,7 +808,9 @@ def ssd_scan(x, dt, a, b, c, state, block: int):
     (exp(L_end - L) dt X)`` (each exponent <= 0: nothing overflows at
     any block size). The products are by GROUP where the operand is
     (``C B^T`` is made once a group, not once a head). Held to
-    :func:`ssd_step` a position at a time in the tests."""
+    :func:`ssd_step` a position at a time in the tests. ``mamba2_chunk``
+    calls this where :func:`ssd_scan_taken` says no; the kernel
+    (``ops/ssd_scan.py``) is held to it."""
     B, T, Hm, P = x.shape
     G, N = b.shape[2:]
     pad = -T % block
@@ -846,6 +849,17 @@ def ssd_scan(x, dt, a, b, c, state, block: int):
 
     state, y = lax.scan(one_block, state, tuple(map(blocks, (x, dt, b, c))))
     return jnp.moveaxis(y, 0, 1).reshape(B, T + pad, Hm, P)[:, :T], state
+
+
+def ssd_scan_taken(cfg, chunk: int) -> bool:
+    """Whether a mamba2 layer's chunk of ``chunk`` positions runs its
+    SSD through the kernel (``ops/ssd_scan.py``, ``hvd_ssd_scan`` in a
+    device trace): by the shapes alone (``ssd_scan.taken``: on a TPU the
+    state whole float32 tiles, the chunk whole blocks of whole lanes).
+    Any other chunk keeps :func:`ssd_scan`."""
+    return ssd_scan_kernel.taken(
+        cfg.mamba2_head_dim, cfg.mamba_d_state, cfg.mamba2_heads,
+        cfg.mamba2_groups, cfg.mamba2_chunk, chunk)
 
 
 def ssd_step(x, dt, a, b, c, state):
@@ -1653,9 +1667,17 @@ def mixed_programs(cfg, block_size: int, table_width: int, ring: int,
                 real = jnp.arange(T)[None, :, None] < call.length
                 step = jnp.where(real, step, 0.0)
             with jax.named_scope("mamba2_scan"):
-                y, state = ssd_scan(xs, step, -jnp.exp(lp["a_log"]), b, cc,
-                                    state, cfg.mamba2_chunk)
-                y = y + lp["d_skip"][:, None] * xs
+                a = -jnp.exp(lp["a_log"])
+                if ssd_scan_taken(cfg, T):
+                    # D x inside the call: behind it, it costs four
+                    # copies of the chunk's rows a layer (ops/ssd_scan.py)
+                    y, state = ssd_scan_kernel.ssd_scan(
+                        xs, step, a, b, cc, state, call.length,
+                        block=cfg.mamba2_chunk, skip=lp["d_skip"])
+                else:
+                    y, state = ssd_scan(xs, step, a, b, cc, state,
+                                        cfg.mamba2_chunk)
+                    y = y + lp["d_skip"][:, None] * xs
             if kc is not None:
                 with jax.named_scope("state_write"):
                     newest = lax.dynamic_slice_in_dim(

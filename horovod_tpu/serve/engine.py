@@ -1012,6 +1012,13 @@ class ServeEngine:
             # positions the selective scan (a mamba2 layer's SSD blocks)
             # runs: the bucket, pads too
             extra["scanned"] = len(toks)
+        if "mamba2" in self.cache.kinds:
+            # those of them whose SSD the kernel ran: all or none, by
+            # the call's bucket
+            extra["scan_kernel"] = (
+                len(toks) if decode_lib.ssd_scan_taken(
+                    self.model_cfg, len(toks)) else 0)
+            m.record_scan(extra["scanned"], extra["scan_kernel"])
         if "conv" in self.cache.kinds:
             # positions the short convolutions run and the mixture
             # dispatches: the bucket, pads too (n_tokens are the real)

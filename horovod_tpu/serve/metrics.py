@@ -451,6 +451,11 @@ class ServeMetrics:
         self.sparse_select_kernel_queries_total = 0
         self.sparse_selected_rows_total = 0
         self.kv_compressed_max = 0
+        # Mamba2 layers (a chunk's SSD over blocks): the positions the
+        # prefill calls scanned (their buckets, pads too) and those of
+        # them whose scan the kernel ran (decode.ssd_scan_taken).
+        self.ssd_scanned_positions_total = 0
+        self.ssd_scan_kernel_positions_total = 0
         # Eva layers (an aligned window's rows by slot beside chunk
         # summaries in pages): the summary pages the rows of the last
         # decode call attend (those of their closed windows; the pages
@@ -760,6 +765,12 @@ class ServeMetrics:
             self.kv_compressed_max,
             (held - cfg.sparse_kernel) // cfg.sparse_stride + 1)
 
+    def record_scan(self, scanned: int, kernel: int) -> None:
+        """A prefill call was launched whose mamba2 layers scan
+        ``scanned`` positions, ``kernel`` of them through the kernel."""
+        self.ssd_scanned_positions_total += scanned
+        self.ssd_scan_kernel_positions_total += kernel
+
     def record_eva(self, pages: Optional[int] = None,
                    closed: int = 0) -> None:
         """A call was launched that closes ``closed`` windows of its eva
@@ -1047,6 +1058,13 @@ class ServeMetrics:
                 self.sparse_select_kernel_queries_total,
             "sparse_selected_rows_total": self.sparse_selected_rows_total,
             "kv_compressed_max": self.kv_compressed_max,
+            # mamba2 layers (zeros without such layers): the share of
+            # the chunks' scanned positions the kernel hvd_ssd_scan ran
+            "ssd_scanned_positions_total": self.ssd_scanned_positions_total,
+            "ssd_scan_kernel_share": (
+                self.ssd_scan_kernel_positions_total
+                / self.ssd_scanned_positions_total
+                if self.ssd_scanned_positions_total else 0.0),
             # eva layers (zeros without such layers)
             "eva_summary_pages_in_use": self.eva_summary_pages_in_use,
             "eva_summary_pages_max": self.eva_summary_pages_max,

@@ -38,9 +38,24 @@ holds:
     chiprun -- python tools/mamba_scan_sweep.py --step --rule ssd
 
 What ``ops/state_step.py::_SSD_HEADS`` and ``_KDA_HEADS`` were chosen
-from (PERF.md, PR 62). Off the chip the interpreter takes an hour at
-these sizes: rehearse with ``--layers 1 --channels 1024`` (or ``--layers
-2 --slots 16`` with ``--step``)."""
+from (PERF.md, PR 62). With ``--scan --rule ssd``, a chunk's SSD of a
+Mamba-2 layer at Nemotron's sizes (``x [1, T, 128, 64]``, ``B`` and
+``C`` ``[1, T, 8, 128]``, a resumed state ``[1, 128, 64, 128]``, blocks
+of 128; ``T`` of 1024, 512 and 256, full and a ``length`` short of the
+bucket): the XLA form ``decode.ssd_scan`` beside the Pallas call
+``ops/ssd_scan.py`` by the heads a grid step holds, each with the
+layer's ``D x``, ms a layer inside a program of five and as the
+difference of a program of twenty and one of five (a program's launch
+costs the chip's machine 0.8 ms whatever it holds), and both forms' gaps
+to the recurrence a position at a time in float64:
+
+    chiprun -- python tools/mamba_scan_sweep.py --scan --rule ssd
+
+What ``ops/ssd_scan.py::_HEADS`` was chosen from (PERF.md, PR 64). Off
+the chip the interpreter takes an hour at these sizes: rehearse with
+``--layers 1 --channels 1024`` (or ``--layers 2 --slots 16`` with
+``--step``, ``--layers 2 --heads 8 --lengths 256`` with ``--scan --rule
+ssd``)."""
 import argparse
 import json
 import sys
@@ -52,6 +67,7 @@ import jax.numpy as jnp
 sys.path.insert(0, ".")
 from horovod_tpu.ops import mamba_scan as scan_lib  # noqa: E402
 from horovod_tpu.ops import mamba_step as step_lib  # noqa: E402
+from horovod_tpu.ops import ssd_scan as ssd_lib  # noqa: E402
 from horovod_tpu.ops import state_step as state_lib  # noqa: E402
 from horovod_tpu.serve import decode as decode_lib  # noqa: E402
 
@@ -405,6 +421,133 @@ def scan_sweep(layers=26, d_inner=DI):
             fastest = [form for _, form in sorted(rows)[:2]]
 
 
+#: The heads a grid step of ``hvd_ssd_scan`` holds.
+SSD_SCAN_HEADS = (8, 16, 32)
+
+
+def ssd_recurrence(x, dt, a, b, c, state, length):
+    """The recurrence a position at a time in float64, on the host:
+    ``(y [T, Hm, P], the state after position length - 1)`` of row 0."""
+    import numpy as np
+    x, dt, a, b, c, state = (np.asarray(v, np.float64)
+                             for v in (x[0], dt[0], a, b[0], c[0], state[0]))
+    per_group = x.shape[1] // b.shape[1]
+    y = np.zeros(x.shape)
+    for t in range(length):
+        bt, ct = (np.repeat(v[t], per_group, axis=0)[:, None] for v in (b, c))
+        state = (np.exp(dt[t] * a)[:, None, None] * state
+                 + (dt[t, :, None] * x[t])[..., None] * bt)
+        y[t] = (state * ct).sum(-1)
+    return y, state
+
+
+def ssd_scan_sweep(layers=None, n_heads=None, lengths=None):
+    """A chunk's SSD of every layer in turn in one program, a layer's
+    ``Delta`` nudged by the result of the layer before it (a few KB: at
+    these sizes a nudge of ``x`` itself, 32 MB read and written a layer,
+    costs more than the scan): ``decode.ssd_scan`` with the padding's
+    step zeroed, as ``mamba2_chunk`` writes it, beside
+    ``ops/ssd_scan.py`` by the heads a grid step holds, at a full bucket
+    and at a ``length`` an eighth and a block short of it. A program of
+    five layers costs the chip's machine about 0.8 ms to launch whatever
+    it holds, so ms a layer is read twice: of a program of five, and as
+    the difference of a program of twenty and one of five."""
+    import numpy as np
+    layers = layers or 5
+    Hm = n_heads or 128
+    P, G, N, block = 64, 8, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    a = -jnp.exp(jax.random.normal(ks[0], (Hm,)))
+    skip = jax.random.normal(ks[6], (Hm,))
+
+    def every_layer(scan, layers):
+        def run(states, x, dt, b, c, length):
+            out = []
+            for layer in range(layers):
+                y, state = scan(x, dt, b, c, states[layer], length)
+                out.append(state)
+                dt = dt + 1e-9 * jnp.abs(y[:, :, :, 0])
+            return jnp.stack(out), dt
+        return jax.jit(run, donate_argnums=(0,))
+
+    def xla(x, dt, b, c, state, length):
+        real = jnp.arange(x.shape[1])[None, :, None] < length
+        y, state = decode_lib.ssd_scan(x, jnp.where(real, dt, 0.0), a, b, c,
+                                       state, block)
+        return y + skip[:, None] * x, state
+
+    def kernel(heads=None):
+        def scan(x, dt, b, c, state, length):
+            real = jnp.arange(x.shape[1])[None, :, None] < length
+            return ssd_lib.ssd_scan(x, jnp.where(real, dt, 0.0), a, b, c,
+                                    state, length, block=block, skip=skip,
+                                    heads=heads)
+        return scan
+
+    def ms_a_program(scan, layers, inputs, length, n=20):
+        fn = every_layer(scan, layers)
+        states = jax.random.normal(ks[5], (layers, 1, Hm, P, N))
+        out = fn(states, *inputs, length)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(out[0], *inputs, length)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / n
+
+    for T in lengths or (1024, 512, 256):
+        inputs = (jax.random.normal(ks[1], (1, T, Hm, P)),
+                  jax.random.uniform(ks[2], (1, T, Hm), minval=1e-3,
+                                     maxval=0.1),
+                  jax.random.normal(ks[3], (1, T, G, N)),
+                  jax.random.normal(ks[4], (1, T, G, N)))
+        short = sorted({T - T // 8 - 1, max(T - block - 1, 1)})
+        state = jax.random.normal(ks[5], (1, Hm, P, N))
+        # the forms on one input against float64, before any is timed
+        for length in (T, *short):
+            y_ref, ref = ssd_recurrence(*inputs[:2], a, *inputs[2:], state,
+                                        length)
+            y_ref += np.asarray(skip, np.float64)[:, None] * np.asarray(
+                inputs[0][0], np.float64)
+            row = {"T": T, "length": length,
+                   "y_max": float(np.abs(y_ref).max()),
+                   "state_max": float(np.abs(ref).max())}
+            got = {}
+            for form, scan in (("xla", xla), ("kernel", kernel())):
+                y, new = jax.jit(scan)(*inputs, state + 0.0,
+                                       jnp.int32(length))
+                got[form] = (y, new)
+                row[form + "_against_float64"] = {
+                    "y_max_gap": float(np.abs(
+                        np.asarray(y[0, :length]) - y_ref[:length]).max()),
+                    "state_max_gap": float(np.abs(
+                        np.asarray(new[0]) - ref).max())}
+            row["kernel_against_xla"] = {
+                "y_max_gap": float(jnp.abs(
+                    got["kernel"][0][:, :length]
+                    - got["xla"][0][:, :length]).max()),
+                "state_max_gap": float(jnp.abs(
+                    got["kernel"][1] - got["xla"][1]).max()),
+                "y_max_in_skipped_blocks": float(jnp.abs(
+                    got["kernel"][0][:, -(-length // block) * block:]).max(
+                        initial=0.0))}
+            print(json.dumps(row), flush=True)
+        for form, scan, how in (
+                [("xla, decode.ssd_scan", xla, {})]
+                + [("hvd_ssd_scan", kernel(heads), {"heads_a_grid_step":
+                                                    heads})
+                   for heads in SSD_SCAN_HEADS if Hm % heads == 0]):
+            row = {"T": T, "form": form, **how}
+            for length in (T, *short):
+                few, many = (ms_a_program(scan, n, inputs, jnp.int32(length))
+                             for n in (layers, 4 * layers))
+                row[f"ms_a_layer_of_{layers}_at_{length}"] = round(
+                    few / layers, 4)
+                row[f"ms_a_layer_by_difference_at_{length}"] = round(
+                    (many - few) / (3 * layers), 4)
+            print(json.dumps(row), flush=True)
+
+
 def xla_forms_sweep():
     """One call at a time: the scan in XLA by positions a loop iteration
     beside ``lax.associative_scan`` inside blocks, and ``mamba_step``
@@ -454,6 +597,14 @@ def main():
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--step", action="store_true",
                         help="the decode step's forms, not the scan's")
+    parser.add_argument("--scan", action="store_true",
+                        help="with --rule ssd: a chunk's SSD "
+                             "(ops/ssd_scan.py)")
+    parser.add_argument("--heads", type=int, default=None,
+                        help="with --scan --rule ssd: the layer's heads, a "
+                             "rehearsal's, off the chip")
+    parser.add_argument("--lengths", type=int, nargs="+", default=None,
+                        help="with --scan --rule ssd: the chunks' widths")
     parser.add_argument("--xla-forms", action="store_true",
                         help="the chunk's scan in XLA alone, by form: what "
                              "PR 47 chose among")
@@ -469,6 +620,8 @@ def main():
                         help="the scan's channels: a rehearsal's, off the "
                              "chip")
     args = parser.parse_args()
+    if args.scan and args.rule == "ssd":
+        return ssd_scan_sweep(args.layers, args.heads, args.lengths)
     if args.step and args.rule != "mamba":
         return state_step_sweep(args.rule, args.layers, args.slots)
     if args.step:

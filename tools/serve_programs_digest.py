@@ -49,7 +49,9 @@ CELLS = [("mistral-7b-v0.3-16l", "batch-prefill"),
          # moe._held_rows); Kimi's share keeps lax.ragged_dot by its shapes
          # (its decode and ling's changed again with PR 62:
          # hvd_state_step, a step's mamba2 and kda layers; no chunk
-         # program and no other cell's decode did)
+         # program and no other cell's decode did; its two chunk
+         # programs, and no other program of any cell, changed with
+         # PR 64: hvd_ssd_scan, a chunk's SSD under mamba2_chunk)
          ("nemotron-3-super-120b-ep4-11l", "agent-backlog"),
          # the first configuration with a ring of latents, grouped and
          # differential latent heads, an mHC stream and PolyNorm (PR 63):
